@@ -889,12 +889,11 @@ def main():
 
     import jax
 
+    from gubernator_tpu.cmd import place_compile_cache
+
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache_cpu")
-    else:
-        jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    place_compile_cache()
 
     for n in sorted(CONFIGS) if args.config == 0 else [args.config]:
         CONFIGS[n]()

@@ -44,8 +44,9 @@ def child(S: int) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", os.path.join(REPO, ".jax_cache_cpu"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from gubernator_tpu.cmd import place_compile_cache
+
+    place_compile_cache()
 
     import numpy as np
 

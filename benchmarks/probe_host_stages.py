@@ -28,8 +28,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache_cpu")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from gubernator_tpu.cmd import place_compile_cache
+
+place_compile_cache()
 
 import numpy as np
 

@@ -23,8 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from gubernator_tpu.cmd import place_compile_cache
+
+place_compile_cache()
 
 C = 262_144
 B = 131_072

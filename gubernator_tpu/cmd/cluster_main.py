@@ -15,9 +15,9 @@ def main(argv=None) -> int:
     parser.add_argument("--nodes", type=int, default=6)
     args = parser.parse_args(argv)
 
-    from . import apply_jax_platform_env
+    from . import place_compile_cache
 
-    apply_jax_platform_env()
+    place_compile_cache()
 
     from ..cluster import Cluster
 

@@ -24,9 +24,9 @@ def main(argv=None) -> int:
         print(f"gubernator-tpu {__version__}")
         return 0
 
-    from . import apply_jax_platform_env
+    from . import place_compile_cache
 
-    apply_jax_platform_env()
+    place_compile_cache()
 
     from ..config import setup_daemon_config
     from ..daemon import spawn_daemon

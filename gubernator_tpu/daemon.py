@@ -108,8 +108,9 @@ class Daemon:
         )
         self.service = V1Service(svc_conf)
         # Compile the device programs BEFORE accepting traffic: a cold
-        # first dispatch (remote-tunnel compiles take tens of seconds)
-        # would otherwise land inside a client's RPC deadline.
+        # first dispatch (an XLA compile: seconds on a CPU, most of a
+        # minute per program on a TPU) would otherwise land inside a
+        # client's RPC deadline.
         self.service.store.warmup(
             self.clock.now_ms(), warm_shapes=self.conf.warmup_shapes
         )

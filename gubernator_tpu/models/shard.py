@@ -416,7 +416,9 @@ def make_columns(algorithm, behavior, hits, limit, duration, n,
 
 # ---------------------------------------------------------------------
 # Device->host readback with the known-flake quarantine: under heavy
-# suite load jax 0.4.x CPU occasionally raises a spurious IndexError
+# suite load the jax CPU backend occasionally raised a spurious
+# IndexError (seen on jax 0.4.x; whether 0.9.0 still raises it is not
+# known — the retry counter says if it ever fires)
 # ("list index out of range") from _copy_single_device_array_to_host_async
 # inside np.asarray of a device array.  The array is intact — an
 # immediate retry succeeds — so the dispatch readback sites retry ONCE

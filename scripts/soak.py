@@ -151,8 +151,13 @@ def main() -> int:
          for r in range(n_regions) for _ in range(per_region)]
         if n_regions else [""] * args.daemons
     )
+    import jax
+
+    # The daemons are in-process, so this process's devices are theirs.
+    devices = jax.devices()
     print(
-        f"soak: {args.daemons} daemons"
+        f"soak: platform {devices[0].platform} ({devices[0].device_kind}) "
+        f"x{len(devices)}, {args.daemons} daemons"
         + (f" in {n_regions} regions of {per_region}" if n_regions else "")
         + f", {args.minutes:.1f} min, "
         f"zipf a={args.zipf_a} over {args.keys} keys, seed {args.seed}, "

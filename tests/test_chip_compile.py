@@ -1,0 +1,152 @@
+"""The served path's XLA programs compile for a TPU v5e at the size
+chip_smoke.py serves: 1,048,576 slots and the 4096-lane wire.
+
+No chip is needed: the TPU compiler is installed and compiles for a
+*described* v5e:2x2 (the on-chip-measurement guide, section 2).  Nothing
+runs, so these say nothing about answers or times — only that a later PR
+has not made a program the chip's compiler refuses, or one that no
+longer fits its memory.  Each compile takes the better part of a minute.
+
+The topology is described inside a fixture, never at import: only one
+process may hold libtpu, and every xdist worker imports this file.
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from gubernator_tpu.ops import buckets, global_ops
+from gubernator_tpu.parallel import mesh as mesh_mod
+
+SLOTS = 1 << 20  # GUBER_CACHE_SIZE of chip_smoke.py
+LANES = 4096  # its frame width, and the bulk entry of GUBER_WARMUP_SHAPES
+G_CAPACITY = 65536  # service.py: min(max(4096, cache_size), 65536)
+NOW_MS = 1_790_000_000_000
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip; keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return Mesh(np.array(topo.devices[:1]), ("shard",))
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("shard",))
+
+
+def _sharded(mesh, tree):
+    """[S, ...] ShapeDtypeStructs laid out as MeshBucketStore lays out its
+    arrays: a leading shard axis over the mesh."""
+    n = mesh.devices.size
+    sharding = NamedSharding(mesh, P("shard"))
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((n, *a.shape), a.dtype, sharding=sharding), tree
+    )
+
+
+def _state(mesh):
+    per_shard = SLOTS // mesh.devices.size
+    return _sharded(mesh, jax.eval_shape(lambda: buckets.init_state(per_shard)))
+
+
+def _dict_wire(mesh, lanes_per_shard):
+    words = 3 * lanes_per_shard + buckets.DICT_WIRE_TABLE_WORDS
+    return _sharded(mesh, jax.ShapeDtypeStruct((words,), jnp.int32))
+
+
+def _compile(what, lowered):
+    t0 = time.monotonic()
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    print(
+        f"\n{what}: compiled in {time.monotonic() - t0:.1f} s; per device: "
+        f"code {mem.generated_code_size_in_bytes}, arguments {mem.argument_size_in_bytes}, "
+        f"outputs {mem.output_size_in_bytes}, temp {mem.temp_size_in_bytes} bytes"
+    )
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES // 4
+    return compiled
+
+
+def test_packed_dict_wire_compiles_for_one_v5e(one_chip):
+    """The program every 4096-lane columnar frame dispatches."""
+    _compile(
+        "dict wire, 1M slots x 4096 lanes, one chip",
+        mesh_mod._rounds_packed_mesh_donated.lower(
+            _state(one_chip), _dict_wire(one_chip, LANES), 1, NOW_MS
+        ),
+    )
+
+
+def test_fused_k2_compiles_for_one_v5e(one_chip):
+    """The launch-fusion program a backlogged coalescer dispatches."""
+    wire = _dict_wire(one_chip, LANES)
+    fused = mesh_mod._mesh_fused_packed_jit(2, False, donate_wires=True)
+    _compile(
+        "fused K=2, 1M slots x 4096 lanes, one chip",
+        fused.lower(
+            _state(one_chip), wire, wire,
+            jax.ShapeDtypeStruct((2,), jnp.int32), jax.ShapeDtypeStruct((2,), jnp.int64),
+        ),
+    )
+
+
+def test_narrow_wire_compiles_for_one_v5e(one_chip):
+    """The per-lane narrow wire: the fall-back of the dict wire, and what
+    warmup compiles beside it."""
+    z = np.zeros((1, LANES), np.int32)
+    batch = buckets.make_batch32(
+        z, z.astype(bool), z, z, z, z, z, z, z, occ=z, write=z.astype(bool)
+    )
+    batch = jax.tree.map(lambda a: _sharded(one_chip, jax.ShapeDtypeStruct(a.shape[1:], a.dtype)), batch)
+    round_id = _sharded(one_chip, jax.ShapeDtypeStruct((LANES,), jnp.int32))
+    _compile(
+        "narrow wire, 1M slots x 4096 lanes, one chip",
+        mesh_mod._rounds32_mesh_jit.lower(_state(one_chip), batch, round_id, 1, NOW_MS),
+    )
+
+
+def test_global_sync_compiles_for_four_v5e(four_chips):
+    """The GLOBAL sync collective — the mesh's one cross-chip program —
+    with the table sharded four ways."""
+    gcols = _sharded(
+        four_chips, jax.eval_shape(lambda: global_ops.init_global_columns(G_CAPACITY))
+    )
+    replicated = NamedSharding(four_chips, P())
+    g = lambda dtype: jax.ShapeDtypeStruct((G_CAPACITY,), dtype, sharding=replicated)  # noqa: E731
+    cfg = global_ops.SyncConfig(
+        owner_slot=g(jnp.int32), owner_shard=g(jnp.int32), algorithm=g(jnp.int32),
+        behavior=g(jnp.int32), limit=g(jnp.int64), duration=g(jnp.int64),
+        greg_expire=g(jnp.int64), greg_duration=g(jnp.int64),
+    )
+    dirty = _sharded(four_chips, jax.ShapeDtypeStruct((G_CAPACITY,), jnp.bool_))
+    compiled = _compile(
+        "GLOBAL sync, 4 x 262,144 slots, four chips",
+        mesh_mod._get_sync_fn(four_chips, "shard").lower(
+            _state(four_chips), gcols, cfg, dirty, NOW_MS
+        ),
+    )
+    assert "all-reduce" in compiled.as_text()

@@ -20,7 +20,7 @@ from gubernator_tpu.k8s_pool import (
 )
 
 
-def wait_until(fn, timeout_s=5.0, every_s=0.02, msg="condition"):
+def wait_until(fn, timeout_s=20.0, every_s=0.02, msg="condition"):
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
         if fn():
@@ -250,7 +250,11 @@ def test_endpoints_list_and_watch(api):
             msg="initial list lands",
         )
         assert updates[-1][0].is_owner
-        # A scale-up arrives via the watch stream.
+        # A scale-up arrives via the watch stream.  The fake does not replay
+        # events from a resourceVersion as the apiserver does: one emitted
+        # between the pool's LIST and its WATCH would be lost, so wait for
+        # the watch (as test_watch_stream_failure_relists does).
+        wait_until(lambda: api.n_watchers() == 1, msg="watch established")
         api.emit("endpoints", "MODIFIED", endpoints_obj("guber", ["10.0.0.1", "10.0.0.2"]))
         wait_until(
             lambda: updates
@@ -278,6 +282,7 @@ def test_pods_watch_skips_not_ready(api):
             and [p.grpc_address for p in updates[-1]] == ["10.0.0.1:81"],
             msg="only the ready+running pod is a peer",
         )
+        wait_until(lambda: api.n_watchers() == 1, msg="watch established")
         api.emit("pods", "MODIFIED", pod_obj("b", "10.0.0.2"))
         wait_until(
             lambda: updates

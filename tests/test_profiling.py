@@ -382,15 +382,25 @@ def test_pprof_named_fraction_on_loaded_daemon():
         for w in range(4)
     ]
     s = profiling._get_sampler(start=True)
-    try:
-        for t in threads:
-            t.start()
-        deadline = time.time() + 2.0
+
+    def tick_for(seconds):
+        deadline = time.time() + seconds
         while time.time() < deadline:
             s.sample_once()
             time.sleep(0.005)
+
+    try:
+        for t in threads:
+            t.start()
+        # The sampler learns thread names once a second: until then a
+        # thread this young folds as `unknown`, which says nothing about
+        # a daemon (its threads live for hours).  So load for two seconds
+        # first, then judge the two seconds after — and only those: the
+        # ring is process-wide and still holds earlier tests' samples.
+        tick_for(2.2)
+        tick_for(2.0)
         st, ctype, payload = handle_request(
-            svc, "GET", "/debug/pprof?format=json&seconds=60", b""
+            svc, "GET", "/debug/pprof?format=json&seconds=2", b""
         )
         assert st == 200 and ctype == "application/json"
         doc = json.loads(payload)
@@ -398,7 +408,7 @@ def test_pprof_named_fraction_on_loaded_daemon():
         assert doc["namedFraction"] >= 0.8, doc["phases"]
         # The collapsed view serves the same window as text.
         st, ctype, text = handle_request(
-            svc, "GET", "/debug/pprof?seconds=60", b""
+            svc, "GET", "/debug/pprof?seconds=2", b""
         )
         assert st == 200 and ctype.startswith("text/plain")
         assert text.decode().splitlines()

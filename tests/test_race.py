@@ -16,6 +16,7 @@ import time
 import numpy as np
 import pytest
 
+from gubernator_tpu.config import BehaviorConfig
 from gubernator_tpu.service import IngressColumns, ServiceConfig, V1Service
 from gubernator_tpu.types import (
     Behavior,
@@ -30,11 +31,12 @@ T0 = 1_573_430_430_000
 faulthandler.enable()
 
 
-def make_service(addr="127.0.0.1:9901"):
+def make_service(addr="127.0.0.1:9901", behaviors=None):
     clock = Clock()
     clock.freeze(T0)
     svc = V1Service(ServiceConfig(cache_size=8192, clock=clock,
-                                  advertise_address=addr))
+                                  advertise_address=addr,
+                                  behaviors=behaviors or BehaviorConfig()))
     svc.set_peers([PeerInfo(grpc_address=addr, is_owner=True)])
     return svc
 
@@ -121,8 +123,16 @@ def test_set_peers_storm_during_traffic():
     """Traffic from 8 threads while the peer list churns between
     self-only and self+unreachable-fakes: requests whose keys re-hash
     to fake owners error per-lane, everything else answers, nothing
-    deadlocks, and the slot tables stay consistent."""
-    svc = make_service()
+    deadlocks, and the slot tables stay consistent.
+
+    The retry back-off is cut to a tenth: at its default every lane
+    forwarded to a fake sleeps out 0.02+0.04+...+0.32 s of re-picks, and
+    those sleeps — not the races under test — made the storm last 128 s
+    alone, past run_storm's 120 s deadlock limit under any load.  Same
+    retries, same churn, same traffic; only the sleeping is shorter."""
+    svc = make_service(behaviors=BehaviorConfig(
+        retry_backoff_base_s=0.002, retry_backoff_max_s=0.1,
+    ))
     me = PeerInfo(grpc_address="127.0.0.1:9901", is_owner=True)
     fakes = [PeerInfo(grpc_address=f"127.0.0.1:1{n}") for n in range(3)]
     state = {"flip": False}
