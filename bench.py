@@ -684,35 +684,6 @@ def _overhead_pairs(set_off, set_on, n_threads: int, iters: int,
         close()
 
 
-def measure_tracing_overhead(n_threads: int = 8, iters: int = 8,
-                             pairs: int = 10):
-    """Same-run tracing overhead: headline ingress checks/s with
-    GUBER_TRACE_SAMPLE=0 (the shipped default — every hook is one
-    comparison returning the no-op singleton) over the same path with
-    tracing force-disabled ('compiled out': tracing.force_disable, the
-    as-if-the-module-did-not-exist baseline).  All legs run
-    back-to-back in THIS process (ABBA interval quads toggled on one
-    continuously loaded warmed service, median quad ratio —
-    _overhead_pairs) so device/host weather cancels; the gate floors
-    the ratio at 0.95 — the guards must cost <5% even on a noisy host,
-    and ~0% in truth.  Returns (ratio, off_cps, s0_cps, noise)."""
-    from gubernator_tpu import tracing
-
-    prev_rate = tracing.sample_rate()
-    try:
-        return _overhead_pairs(
-            lambda: tracing.force_disable(True),
-            lambda: (tracing.force_disable(False),
-                     tracing.set_sample_rate(0.0)),
-            n_threads, iters, pairs,
-        )
-    finally:
-        # One restore covering every leg: an off-leg failure must not
-        # leave the process force-disabled contrary to its environment.
-        tracing.force_disable(False)
-        tracing.set_sample_rate(prev_rate)
-
-
 def measure_xla_telemetry_overhead(n_threads: int = 8, iters: int = 8,
                                    pairs: int = 10):
     """Same-run XLA-telemetry overhead (the PR 4 playbook applied to
@@ -2100,18 +2071,8 @@ def gate() -> int:
     # The plane-overhead rows are SAME-RUN ratios by definition (every
     # leg interleaved in this process), so they never reuse saved rows;
     # each measure returns its own ratio noise (the per-pair spread)
-    # for the noise-adjusted verdict.
-    try:
-        ratio, off_cps, s0_cps, r_noise = measure_tracing_overhead()
-        rows["tracing_overhead_ratio"] = ratio
-        noise["tracing_overhead_ratio"] = r_noise
-        print(
-            f"gate tracing rows: compiled-out {off_cps:.0f} checks/s, "
-            f"sample-0 {s0_cps:.0f} checks/s"
-        )
-    except Exception as e:  # noqa: BLE001 — service spawn can fail
-        print(f"gate tracing_overhead_ratio: SKIP (measure failed: {e})")
-    # Same rule for the XLA-telemetry overhead ratio (telemetry.py).
+    # for the noise-adjusted verdict.  First the XLA-telemetry overhead
+    # ratio (telemetry.py).
     try:
         ratio, off_cps, on_cps, r_noise = measure_xla_telemetry_overhead()
         rows["xla_telemetry_overhead_ratio"] = ratio

@@ -32,12 +32,19 @@ def main(argv=None) -> int:
     from ..daemon import spawn_daemon
     from ..utils.logging import setup_logging
 
+    from .. import telemetry
+
     conf = setup_daemon_config(config_file=args.config)
     if args.debug:
         conf.debug = True
     setup_logging(debug=conf.debug)
+    # Set-up by part (/debug/device `startup`): the interpreter, the
+    # imports (jax among them) and the configuration, from the process's
+    # own start; `Daemon._start` times the parts that follow.
+    telemetry.note_startup("imports", telemetry.process_age_s())
     daemon = spawn_daemon(conf)
     addr = daemon.gateway.address
+    telemetry.note_listening()
     print(f"gubernator-tpu listening on http://{addr} (advertise {daemon.peer_info.grpc_address})")
     sys.stdout.flush()
 

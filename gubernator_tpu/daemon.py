@@ -106,15 +106,28 @@ class Daemon:
             peer_channel_credentials=peer_creds,
             fault_plan=self.conf.fault_plan,
         )
-        self.service = V1Service(svc_conf)
+        import jax
+
+        with telemetry.startup("backend"):
+            jax.devices()  # the backend comes up here, whoever asks first
+        with telemetry.startup("table"):
+            self.service = V1Service(svc_conf)
         # Compile the device programs BEFORE accepting traffic: a cold
         # first dispatch (an XLA compile: seconds on a CPU, most of a
         # minute per program on a TPU) would otherwise land inside a
         # client's RPC deadline.
-        self.service.store.warmup(
-            self.clock.now_ms(), warm_shapes=self.conf.warmup_shapes
-        )
+        with telemetry.startup("warmup"):
+            self.service.store.warmup(
+                self.clock.now_ms(), warm_shapes=self.conf.warmup_shapes
+            )
         telemetry.mark_steady()
+        with telemetry.startup("listen"):
+            self._listen(tls_conf, server_tls)
+        return self
+
+    def _listen(self, tls_conf, server_tls) -> None:
+        """Bring up the gRPC server, the HTTP edge and peer discovery,
+        and wait until every listener accepts."""
         grpc_listen = self.conf.grpc_listen_address
         if not grpc_listen:
             host, _, _ = self.conf.listen_address.partition(":")
@@ -199,7 +212,6 @@ class Daemon:
                 advertise=self.peer_info,
             )
         self.wait_for_connect()
-        return self
 
     # ------------------------------------------------------------------
     @property

@@ -29,6 +29,7 @@ from . import audit as audit_mod
 from . import native as _native
 from . import profiling
 from . import saturation
+from .saturation import phase
 from . import telemetry
 from . import tracing
 from . import wire
@@ -297,6 +298,9 @@ def _handle_request(service: V1Service, method: str, path: str, raw: bytes,
                 # batch.window/queue.wait.
                 return 200, "application/json", _json_bytes({
                     "phases": saturation.phase_snapshot(),
+                    "waterfall": [
+                        {"phase": p, "depth": d} for p, d in saturation.WATERFALL
+                    ],
                     "express": saturation.express_snapshot(),
                     "slo": service.slo.snapshot(),
                 })
@@ -347,46 +351,30 @@ def _handle_request(service: V1Service, method: str, path: str, raw: bytes,
                         # the frame falls into json.loads below and
                         # 400s exactly like a pre-columns build, which
                         # is the client's version probe.
-                        t_parse = time.perf_counter()
-                        with profiling.scope("ingress.parse"):
+                        with phase("ingress.parse"):
                             cols = _decode_ingress_frame_or_400(raw)
-                        saturation.observe_phase(
-                            "ingress.parse", time.perf_counter() - t_parse
-                        )
                         result = service.get_rate_limits_columns(
                             cols, max_lanes=INGRESS_COLUMNS_MAX_LANES
                         )
-                        t_enc = time.perf_counter()
-                        with profiling.scope("response.encode"):
+                        with phase("response.encode"):
                             rendered = wire.encode_ingress_result_frame(result)
-                        saturation.observe_phase(
-                            "response.encode", time.perf_counter() - t_enc
-                        )
                         service.metrics.ingress_columns_batches.labels(
                             encoding="frame"
                         ).inc()
                         return 200, wire.COLUMNS_CONTENT_TYPE, rendered
-                    t_parse = time.perf_counter()
-                    with profiling.scope("ingress.parse"):
+                    with phase("ingress.parse"):
                         cols = parse_body_native(raw) if raw else None
                         native = cols is not None
                         if not native:
                             body = json.loads(raw) if raw else {}
                             cols = parse_columns(body.get("requests", []))
-                    saturation.observe_phase(
-                        "ingress.parse", time.perf_counter() - t_parse
-                    )
                     result = service.get_rate_limits_columns(cols)
-                    t_enc = time.perf_counter()
-                    with profiling.scope("response.encode"):
+                    with phase("response.encode"):
                         rendered = (
                             render_result_native(result) if native else None
                         )
                         if rendered is None:
                             rendered = _json_bytes(render_columns(result))
-                    saturation.observe_phase(
-                        "response.encode", time.perf_counter() - t_enc
-                    )
             return 200, "application/json", rendered
         if path == "/v1/peer.GetPeerRateLimits":
             # Body parsing happens INSIDE the metrics span on BOTH
@@ -822,26 +810,22 @@ def handle_request_async(service: V1Service, method: str, path: str,
             ingress_frame = (
                 service.serves_ingress_columns and wire.is_ingress_frame(raw)
             )
-            t_parse = time.perf_counter()
-            if ingress_frame:
-                # Columnar front door, async edge: the native worker
-                # hands ready column buffers (gt_frame_parse ran with
-                # the GIL released) to the submit path and returns to
-                # the ingress queue; the kind-6 response renders on the
-                # completion thread straight from the result arrays.
-                with profiling.scope("ingress.parse"):
-                    cols = _decode_ingress_frame_or_400(raw)
+            # ingress.parse on the async edge.  For the columnar front
+            # door the native worker hands ready column buffers
+            # (gt_frame_parse ran with the GIL released) to the submit
+            # path and returns to the ingress queue; the kind-6 response
+            # renders on the completion thread straight from the result
+            # arrays.
+            with phase("ingress.parse"):
                 native = False
-            else:
-                with profiling.scope("ingress.parse"):
+                if ingress_frame:
+                    cols = _decode_ingress_frame_or_400(raw)
+                else:
                     cols = parse_body_native(raw) if raw else None
                     native = cols is not None
                     if cols is None:
                         body = json.loads(raw) if raw else {}
                         cols = parse_columns(body.get("requests", []))
-            saturation.observe_phase(
-                "ingress.parse", time.perf_counter() - t_parse
-            )
 
             def cb(result, exc):
                 # Guarded like the sync catch-all: a render failure on a
@@ -851,27 +835,20 @@ def handle_request_async(service: V1Service, method: str, path: str,
                     if exc is not None:
                         finish("1", _error_triplet(exc))
                         return
-                    t_enc = time.perf_counter()
                     if ingress_frame:
-                        with profiling.scope("response.encode"):
+                        with phase("response.encode"):
                             rendered = wire.encode_ingress_result_frame(result)
-                        saturation.observe_phase(
-                            "response.encode", time.perf_counter() - t_enc
-                        )
                         metrics.ingress_columns_batches.labels(
                             encoding="frame"
                         ).inc()
                         finish("0", (200, wire.COLUMNS_CONTENT_TYPE, rendered))
                         return
-                    with profiling.scope("response.encode"):
+                    with phase("response.encode"):
                         rendered = (
                             render_result_native(result) if native else None
                         )
                         if rendered is None:  # native render unavailable/cap
                             rendered = _json_bytes(render_columns(result))
-                    saturation.observe_phase(
-                        "response.encode", time.perf_counter() - t_enc
-                    )
                     finish("0", (200, "application/json", rendered))
                 except Exception as e:  # noqa: BLE001
                     finish("1", _error_triplet(e))
@@ -1004,12 +981,11 @@ class NativeIngressPump:
     @property
     def active(self) -> bool:
         """Whether workers should offer frames to the native lane.
-        Sampled tracing turns it off wholesale — the Python path owns
-        span creation — which keeps GUBER_TRACE_SAMPLE>0 semantics
-        identical to PR 8 at the cost of the fast lane."""
+        Sampled tracing (GUBER_TRACE_SAMPLE > 0) does not change it: a
+        traced daemon serves on the same path, and a sampled take gets
+        a batch trace of its own in `_run`."""
         return (
             not self._stopped.is_set()
-            and not tracing.enabled()
             and not getattr(self.service, "_closed", False)
         )
 
@@ -1100,7 +1076,6 @@ class NativeIngressPump:
     def _run(self) -> None:
         batcher = self.batcher
         tracing.bind_recorder(getattr(self.service, "recorder", None))
-        bb = getattr(self.service, "blackbox", None)
         while not self._stopped.is_set():
             with self._ring_lock:
                 # Check-and-push under ONE lock hold: a set_peers that
@@ -1113,82 +1088,113 @@ class NativeIngressPump:
                     self._push(
                         self._eligible and not self._stopped.is_set()
                     )
-            with profiling.scope("epoll.wait"):
+            with phase("pump.take"):
                 tb = batcher.take(self.take_lanes, timeout_ms=200)
-            # Overload-signal parity with the Python gate: native sheds
-            # happen entirely in C++, so the pump surfaces them into the
-            # flight recorder (the automatic-dump trigger shedding
-            # exists for) and samples the ring depth for /debug/status.
-            st = batcher.stats()
-            saturation.observe_queue_depth(st["pendingLanes"])
-            # Express-lane attribution: NO_BATCHING frames served by
-            # the native express queue (counted in C++ at submit), and
-            # the ring's BULK lanes into the batched denominator — the
-            # hit-rate gauge must reflect the native edge's coalesced
-            # traffic, not just the batchers' windows.
-            xl = st.get("expressLanes", 0)
-            tl = st.get("lanes", 0)
-            d_express = xl - self._express_seen
-            d_bulk = (tl - self._lanes_seen) - d_express
-            if d_express > 0:
-                saturation.note_express("native", d_express)
-            if d_bulk > 0:
-                saturation.note_express("windowed", d_bulk)
-            self._express_seen = xl
-            self._lanes_seen = tl
-            shed = st["shedLanes"]
-            if shed > self._shed_seen:
-                tracing.record_event(
-                    "shed", lanes=shed - self._shed_seen,
-                    queued=st["pendingLanes"],
-                    cap=getattr(
-                        self.service.conf.behaviors,
-                        "ingress_queue_lanes", 0,
-                    ),
-                )
-                self._shed_seen = shed
             if tb is None:
+                self._surface_stats()
                 if batcher.stopped:
                     return
                 continue
+            # A sampled take's trace: its frames were served in C++ and
+            # carry no context of their own, so the dice are rolled here.
+            bt = tracing.new_batch(roll=True)
+            with phase("pump.depth_wait", bt):
+                self._sem.acquire()
+            try:
+                args = self._submit(tb, bt)
+            except BaseException as e:  # noqa: BLE001
+                self._sem.release()
+                self._fail(tb, e)
+                continue
+            self._done_pool.submit(self._complete, *args, time.perf_counter())
+
+    def _surface_stats(self) -> None:
+        """Overload-signal parity with the Python gate: native sheds
+        happen entirely in C++, so the pump surfaces them into the
+        flight recorder (the automatic-dump trigger shedding exists
+        for) and samples the ring depth for /debug/status."""
+        st = self.batcher.stats()
+        saturation.observe_queue_depth(st["pendingLanes"])
+        # Express-lane attribution: NO_BATCHING frames served by
+        # the native express queue (counted in C++ at submit), and
+        # the ring's BULK lanes into the batched denominator — the
+        # hit-rate gauge must reflect the native edge's coalesced
+        # traffic, not just the batchers' windows.
+        xl = st.get("expressLanes", 0)
+        tl = st.get("lanes", 0)
+        d_express = xl - self._express_seen
+        d_bulk = (tl - self._lanes_seen) - d_express
+        if d_express > 0:
+            saturation.note_express("native", d_express)
+        if d_bulk > 0:
+            saturation.note_express("windowed", d_bulk)
+        self._express_seen = xl
+        self._lanes_seen = tl
+        shed = st["shedLanes"]
+        if shed > self._shed_seen:
+            tracing.record_event(
+                "shed", lanes=shed - self._shed_seen,
+                queued=st["pendingLanes"],
+                cap=getattr(
+                    self.service.conf.behaviors,
+                    "ingress_queue_lanes", 0,
+                ),
+            )
+            self._shed_seen = shed
+
+    def _submit(self, tb, bt):
+        """One batch through the funnel's batch-granularity duties
+        (`pump.admit`): the ring's counters, the black-box tap,
+        conservation ledger, tenant fold, hot-key sketch (riding the
+        hashes the native route already computed — zero extra
+        hashing), the attribution of what C++ timed, then ONE columnar
+        dispatch."""
+        svc = self.service
+        with phase("pump.admit", bt, frames=tb.n_frames, lanes=tb.n):
+            self._surface_stats()
+            bb = getattr(svc, "blackbox", None)
             if bb is not None:
-                # Black-box native tap, BEFORE _submit: the batch's
+                # Black-box native tap, BEFORE the dispatch: the batch's
                 # zero-copy views die at complete()/fail(), and this is
                 # the only point where the coalesced frames' bytes can
                 # still be reconstructed (express-lane singles answered
                 # entirely in C++ never surface here — documented
                 # capture slack, architecture.md "Incident black box").
                 bb.tap_taken(tb)
-            self._sem.acquire()
-            try:
-                args = self._submit(tb)
-            except BaseException as e:  # noqa: BLE001
-                self._sem.release()
-                self._fail(tb, e)
-                continue
-            self._done_pool.submit(self._complete, *args)
-
-    def _submit(self, tb):
-        """One batch through the funnel's batch-granularity duties:
-        conservation ledger, tenant fold, hot-key sketch (riding the
-        hashes the native route already computed — zero extra
-        hashing), phase attribution, then ONE columnar dispatch."""
-        svc = self.service
-        audit_mod.note("ingress_hits", int(tb.hits.sum()))
-        tenant_ctx = svc.tenants.fold_admit(tb)
-        svc.hotkeys.update(tb.hashes, tb.hash_keys)
-        nf = max(tb.n_frames, 1)
-        saturation.observe_phase("ingress.parse", tb.parse_ns_total / 1e9 / nf)
-        for age_us in tb.frame_age_us:
-            saturation.observe_phase("batch.window", float(age_us) / 1e6)
+            audit_mod.note("ingress_hits", int(tb.hits.sum()))
+            tenant_ctx = svc.tenants.fold_admit(tb)
+            svc.hotkeys.update(tb.hashes, tb.hash_keys)
+            # Measured in C++ (parse by the worker, a frame's age from its
+            # arrival to this take), so observed, not timed, here.
+            nf = max(tb.n_frames, 1)
+            saturation.observe_phase("ingress.parse", tb.parse_ns_total / 1e9 / nf)
+            for age_us in tb.frame_age_us:
+                saturation.observe_phase("batch.window", float(age_us) / 1e6)
+            if bt is not None:
+                now = time.monotonic_ns()
+                tracing.record_span(
+                    "batch.window", bt.ctx,
+                    start_ns=now - int(tb.frame_age_us.max()) * 1000,
+                    end_ns=now, lanes=tb.n, submissions=tb.n_frames,
+                    lane="native",
+                )
         t0 = time.perf_counter()
-        handle = svc.store.apply_columns_async(
-            tb.hash_keys, tb.algorithm, tb.behavior, tb.hits, tb.limit,
-            tb.duration, svc.clock.now_ms(),
-        )
-        return tb, handle, tenant_ctx, t0
+        tracing.stage_batch_trace(bt)
+        try:
+            handle = svc.store.apply_columns_async(
+                tb.hash_keys, tb.algorithm, tb.behavior, tb.hits, tb.limit,
+                tb.duration, svc.clock.now_ms(),
+            )
+        finally:
+            # A store that raised before consuming the staged trace must
+            # not leak it into this thread's next dispatch.
+            tracing.take_batch_trace()
+        return tb, handle, tenant_ctx, t0, bt
 
-    def _complete(self, tb, handle, tenant_ctx, t0) -> None:
+    def _complete(self, tb, handle, tenant_ctx, t0, bt, t_handoff) -> None:
+        # pump.handoff: queued behind the done pool's two workers.  It
+        # crosses threads, so it is read from the pump's stamp.
+        saturation.observe_phase("pump.handoff", time.perf_counter() - t_handoff)
         svc = self.service
         m = svc.metrics
         rpc = "/pb.gubernator.V1/GetRateLimits"
@@ -1196,36 +1202,37 @@ class NativeIngressPump:
             try:
                 out = handle.result()
                 nf = tb.n_frames
-                # Copies of everything needed past complete() — the
-                # batch's views die inside it.
-                ages_s = tb.frame_age_us.astype(np.float64) / 1e6
-                result = ColumnarResult(
-                    n=tb.n,
-                    status=np.asarray(out["status"], dtype=np.int32),
-                    limit=np.asarray(out["limit"], dtype=np.int64),
-                    remaining=np.asarray(out["remaining"], dtype=np.int64),
-                    reset_time=np.asarray(out["reset_time"], dtype=np.int64),
-                    overrides={},
-                )
-                svc.tenants.fold_outcome(tenant_ctx, result)
-                t_enc = time.perf_counter()
-                with profiling.scope("response.encode"):
+                with phase("pump.outcome", bt):
+                    # Copies of everything needed past complete() — the
+                    # batch's views die inside it.
+                    ages_s = tb.frame_age_us.astype(np.float64) / 1e6
+                    result = ColumnarResult(
+                        n=tb.n,
+                        status=np.asarray(out["status"], dtype=np.int32),
+                        limit=np.asarray(out["limit"], dtype=np.int64),
+                        remaining=np.asarray(out["remaining"], dtype=np.int64),
+                        reset_time=np.asarray(out["reset_time"], dtype=np.int64),
+                        overrides={},
+                    )
+                    svc.tenants.fold_outcome(tenant_ctx, result)
+                # One observation a take, of the whole encode (the sum
+                # over takes divided by the frames answered is a frame's).
+                with phase("response.encode", bt, frames=nf):
                     self.batcher.complete(
                         tb, result.status, result.limit, result.remaining,
                         result.reset_time,
                     )
-                saturation.observe_phase(
-                    "response.encode",
-                    (time.perf_counter() - t_enc) / max(nf, 1),
-                )
-                dt_disp = time.perf_counter() - t0
-                m.ingress_columns_batches.labels(encoding="frame").inc(nf)
-                m.request_counts.labels(status="0", method=rpc).inc(nf)
-                duration = m.request_duration.labels(method=rpc)
-                for age in ages_s:
-                    dt = float(age) + dt_disp
-                    duration.observe(dt)
-                    m.observe_latency(rpc, dt)
+                # pump.account: the answers have left; what follows only
+                # holds this take's slot of the pipeline-depth semaphore.
+                with phase("pump.account", bt):
+                    dt_disp = time.perf_counter() - t0
+                    m.ingress_columns_batches.labels(encoding="frame").inc(nf)
+                    m.request_counts.labels(status="0", method=rpc).inc(nf)
+                    duration = m.request_duration.labels(method=rpc)
+                    for age in ages_s:
+                        dt = float(age) + dt_disp
+                        duration.observe(dt)
+                        m.observe_latency(rpc, dt)
             except BaseException as e:  # noqa: BLE001
                 self._fail(tb, e)
         finally:
@@ -1344,10 +1351,10 @@ class NativeGatewayServer:
             # unchanged path below.
             pump = self.pump
             ingress = pump.batcher if pump is not None and pump.active else None
-            # Cost profiler: time blocked in the native queue pull (the
-            # GIL is released inside edge.next) folds as epoll.wait —
-            # the "GIL-idle in epoll" answer, distinct from parse work.
-            with profiling.scope("epoll.wait"):
+            # Time blocked in the native queue pull (the GIL is released
+            # inside edge.next) is epoll.wait — the "GIL-idle in epoll"
+            # answer, distinct from parse work: this worker has no request.
+            with phase("epoll.wait"):
                 got = edge.next(timeout_ms=200, ingress=ingress)
             if got is None:
                 if edge.stopped:

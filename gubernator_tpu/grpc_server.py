@@ -18,8 +18,8 @@ from typing import Optional
 
 import grpc
 
-from . import profiling
 from . import tracing
+from .saturation import phase
 from . import wire
 from .config import INGRESS_COLUMNS_MAX_LANES, PEER_COLUMNS_MAX_LANES
 from .proto import PEERS_V1_SERVICE, V1_SERVICE
@@ -226,7 +226,7 @@ def _v1_handler(service: V1Service) -> grpc.GenericRpcHandler:
                 raise ApiError(
                     "InvalidArgument", "column length mismatch"
                 )
-            with profiling.scope("ingress.parse"):
+            with phase("ingress.parse"):
                 cols = wire.ingress_from_peer_columns_pb(request)
             if len(cols) and bool(
                 ((cols.algorithm < 0) | (cols.algorithm > 1)).any()
@@ -237,7 +237,7 @@ def _v1_handler(service: V1Service) -> grpc.GenericRpcHandler:
             result = service.get_rate_limits_columns(
                 cols, max_lanes=INGRESS_COLUMNS_MAX_LANES,
             )
-            with profiling.scope("response.encode"):
+            with phase("response.encode"):
                 resp = wire.result_to_ingress_columns_pb(result)
             service.metrics.ingress_columns_batches.labels(
                 encoding="proto"
@@ -294,12 +294,12 @@ def _peers_v1_handler(service: V1Service) -> grpc.GenericRpcHandler:
         decode straight into IngressColumns and the result arrays
         serialize straight back — no per-lane dataclasses either way."""
         try:
-            with profiling.scope("ingress.parse"):
+            with phase("ingress.parse"):
                 cols = wire.ingress_from_peer_columns_pb(request)
             result = service.get_peer_rate_limits_columns(
                 cols, max_lanes=PEER_COLUMNS_MAX_LANES,
             )
-            with profiling.scope("response.encode"):
+            with phase("response.encode"):
                 return wire.result_to_peer_columns_pb(result)
         except ApiError as e:
             _abort_api_error(context, e)

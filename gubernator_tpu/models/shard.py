@@ -27,6 +27,7 @@ from .. import audit
 from .. import native
 from .. import profiling
 from .. import saturation
+from ..saturation import phase
 from .. import telemetry
 from .. import tracing
 from ..ops import buckets
@@ -593,26 +594,21 @@ class ColumnsHandle:
             return self._fetched
 
     def _do_resolve(self) -> None:
-        t0 = time.perf_counter()
+        store = self._store
         try:
-            with profiling.scope("dispatch.fetch"):
+            with phase("dispatch.fetch", self._trace, ticket=self.ticket) as ph:
                 packed_np = self._fetch()
         except Exception as e:  # noqa: BLE001 — launch failure
             self._finish_exc(e)
             return
-        dt = time.perf_counter() - t0
-        self._store._observe_stage("fetch", dt)
-        tracing.stage_span("fetch", dt, self._trace)
-        t1 = time.perf_counter()
+        store._observe_stage("fetch", ph.dt_s)
         try:
-            with profiling.scope("dispatch.commit"):
+            with phase("dispatch.commit", self._trace, ticket=self.ticket) as ph:
                 status, remaining, reset = self._commit_fn(packed_np)
         except Exception as e:  # noqa: BLE001 — surfaced at result()
             self._finish_exc(e)
             return
-        dt = time.perf_counter() - t1
-        self._store._observe_stage("commit", dt)
-        tracing.stage_span("commit", dt, self._trace)
+        store._observe_stage("commit", ph.dt_s)
         # Conservation ledger (audit.py), fed from the decode the commit
         # just produced: hits GRANTED by the device (UNDER_LIMIT lanes)
         # and the negative-remaining tripwire — two vectorized reductions
@@ -760,10 +756,8 @@ class ColumnarPipeline:
 
     # -- observability (metrics.observe_dispatch scrapes these) --------
     def _observe_stage(self, stage: str, dt: float) -> None:
-        # Always-on latency attribution (saturation.py): the same
-        # number feeds the per-scrape stage gauge below and the
-        # gubernator_latency_attribution_seconds{phase} reservoir.
-        saturation.observe_phase(f"dispatch.{stage}", dt)
+        # The per-scrape stage gauge, fed from the reading the stage's
+        # `phase()` took (which already reached the always-on reservoir).
         with self._stats_lock:
             st = self._stage_stats.setdefault(stage, [0, 0.0, 0.0])
             st[0] += 1
@@ -820,7 +814,6 @@ class ColumnarPipeline:
         `_launch_group` (the locked jit call for 1..MAX_FUSE staged
         batches)."""
         bt = tracing.take_batch_trace()  # staged by the batcher (if sampled)
-        t0 = time.perf_counter()
         # Conservation ledger (audit.py): hits entering the device
         # dispatch — the earlier-layer twin of the applied-hits count at
         # commit decode (applied <= dispatched is the device invariant).
@@ -831,34 +824,36 @@ class ColumnarPipeline:
         # host-side evaluation in ops/scalar.py.  Decided BEFORE the
         # plan so the prepare can pin the wide decode path.
         use_scalar = force_wire is None and self._scalar_eligible(cols)
-        with self._plan_lock, profiling.scope("dispatch.prepare"):
-            prep = self._prepare_columns(
-                keys, cols, now_ms, "wide" if use_scalar else force_wire
-            )
-            handle = ColumnsHandle(self, prep.commit, cols.limit, cols.hits)
-            handle._trace = bt
-            handle.ticket = self._next_ticket
-            self._next_ticket += 1
-            self._inflight.append(handle)
-            with self._stats_lock:
-                self._depth_hwm = max(self._depth_hwm, len(self._inflight))
-        dt = time.perf_counter() - t0
-        self._observe_stage("prepare", dt)
-        tracing.stage_span("prepare", dt, bt, ticket=handle.ticket,
-                           lanes=prep.n)
+        # dispatch.prepare keeps its extent (its clock starts before the
+        # plan lock); dispatch.plan_wait inside it is the lock alone.
+        with phase("dispatch.prepare", bt) as ph:
+            with phase("dispatch.plan_wait", bt):
+                self._plan_lock.acquire()
+            try:
+                prep = self._prepare_columns(
+                    keys, cols, now_ms, "wide" if use_scalar else force_wire
+                )
+                handle = ColumnsHandle(self, prep.commit, cols.limit, cols.hits)
+                handle._trace = bt
+                handle.ticket = self._next_ticket
+                self._next_ticket += 1
+                self._inflight.append(handle)
+                with self._stats_lock:
+                    self._depth_hwm = max(self._depth_hwm, len(self._inflight))
+            finally:
+                self._plan_lock.release()
+            ph.note(ticket=handle.ticket, lanes=prep.n)
+        self._observe_stage("prepare", ph.dt_s)
         # Lane utilization: real lanes vs the pow2-padded shape the
         # launch will scatter (saturation plane; drained per scrape).
         saturation.lane_util.add(prep.n, self._padded_lanes(prep))
         try:
-            t1 = time.perf_counter()
-            with profiling.scope("dispatch.stage"):
+            with phase("dispatch.stage", bt, ticket=handle.ticket) as ph:
                 staged = (
                     self._stage_scalar(prep) if use_scalar
                     else self._stage_columns(prep)
                 )
-            dt = time.perf_counter() - t1
-            self._observe_stage("stage", dt)
-            tracing.stage_span("stage", dt, bt)
+            self._observe_stage("stage", ph.dt_s)
         except BaseException as e:
             self._abort_launch_turn(handle, e)
             raise
@@ -900,9 +895,12 @@ class ColumnarPipeline:
     def _launch_in_order(self, handle: "ColumnsHandle",
                          staged: "_Staged") -> None:
         ticket = handle.ticket
+        bt = handle._trace
         group = None
         try:
-            with self._launch_cv:
+            # dispatch.gate_wait: this ticket's launch turn (an older
+            # ticket is still staging or launching).
+            with phase("dispatch.gate_wait", bt, ticket=ticket), self._launch_cv:
                 if self._next_launch != ticket:
                     self._launch_gate[ticket] = (staged, handle)
                     while (self._next_launch != ticket
@@ -931,13 +929,21 @@ class ColumnarPipeline:
             self._abort_launch_turn(group or handle, e)
             raise
         exc: "Optional[BaseException]" = None
-        t0 = time.perf_counter()
-        try:
-            with self._lock, profiling.scope("dispatch.launch"):
+        # dispatch.launch keeps its extent (its clock starts before the
+        # store lock); dispatch.launch_wait inside it is the lock alone.
+        # The span and the profiler event go to the launcher's own
+        # batch; a fused group's other batches get a launch span each
+        # below (each batch's trace sees the one program).
+        with phase("dispatch.launch", bt, ticket=ticket, fused=len(group)) as ph:
+            with phase("dispatch.launch_wait", bt, ticket=ticket):
+                self._lock.acquire()
+            try:
                 self._launch_group(group)
-        except BaseException as e:  # noqa: BLE001
-            exc = e
-        dt = time.perf_counter() - t0
+            except BaseException as e:  # noqa: BLE001
+                exc = e
+            finally:
+                self._lock.release()
+        dt = ph.dt_s
         self._observe_stage("launch", dt)
         # Lane-time pool (profiling.py): these lanes rode a launch of
         # this wall cost — the tenant ledger's proportional-share
@@ -945,10 +951,9 @@ class ColumnarPipeline:
         profiling.note_lane_time(
             sum(len(h._limit) for _, h in group), dt
         )
-        for _, h in group:
-            # One launch span per batch (a fused group launches several
-            # batches in one program; each batch's trace sees it).
-            tracing.stage_span("launch", dt, h._trace, fused=len(group))
+        for _, h in group[1:]:
+            tracing.stage_span("dispatch.launch", dt, h._trace,
+                               ticket=h.ticket, fused=len(group))
         if exc is not None:
             for _, h in group:
                 h._launch_fail(exc)

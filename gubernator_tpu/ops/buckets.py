@@ -75,6 +75,19 @@ _I64 = jnp.int64
 _I32 = jnp.int32
 _U64 = jnp.uint64
 
+# Stable names of the parts of a bucket program (`jax.named_scope`): they
+# ride every operation's `op_name` metadata into the compiled program and
+# the device trace, so a reduction finds "the rounds loop" by name and not
+# as this week's `while.52`.  They change no jitted function's name and no
+# compile-cache key (debug metadata is stripped from the key), so an
+# executable cached before a scope existed still loads — without it.
+SCOPE_WIRE_DECODE = "wire_decode"  # single-buffer wire -> columns, table gathers
+SCOPE_DUP_GROUPS = "dup_groups"    # analytic duplicate groups (occurrence j's level)
+SCOPE_ROUNDS = "rounds"            # the sequential rounds loop, everything inside it
+SCOPE_COMMIT = "commit"            # row scatter into the table
+SCOPE_ANSWER_PACK = "answer_pack"  # per-lane outputs -> one packed array
+SCOPE_GLOBAL_SYNC = "global_sync"  # the GLOBAL collective's body (parallel/mesh.py)
+
 
 def _muldiv128(a, b, d):
     """Exact (floor(a*b/d), a*b mod d) for 0 <= a,b < 2**63, 1 <= d < 2**63.
@@ -364,7 +377,8 @@ def apply_batch(
     vmapped callers must pass cold_cond=False.
     """
     out, new = _apply_compute(state, req, now_ms)
-    state = _commit_rows(state, req, new, cold_cond)
+    with jax.named_scope(SCOPE_COMMIT):
+        state = _commit_rows(state, req, new, cold_cond)
     return state, out
 
 
@@ -469,8 +483,9 @@ def _apply_compute(
     def occ_rem(base):
         if occ64 is None:
             return base
-        taken = jnp.minimum(occ64, base // hs)
-        return jnp.where(hits > 0, base - hits * taken, base)
+        with jax.named_scope(SCOPE_DUP_GROUPS):
+            taken = jnp.minimum(occ64, base // hs)
+            return jnp.where(hits > 0, base - hits * taken, base)
 
     # ---------------- token bucket, existing item ----------------
     # RESET_REMAINING is checked before the algorithm-switch cast in the
@@ -655,11 +670,12 @@ def _pack_output(out: BatchOutput, with_pre: bool = False) -> jax.Array:
     `limit` is an echo of the request and never leaves the device.
     `with_pre` appends pre_expire as row 4 (narrow-wire sentinel input,
     consumed on device — it never reaches the host wire)."""
-    row0 = out.status.astype(_I64) | (out.removed.astype(_I64) << 1)
-    rows = (row0, out.remaining, out.reset_time, out.new_expire)
-    if with_pre:
-        rows = rows + (out.pre_expire,)
-    return jnp.stack(rows)
+    with jax.named_scope(SCOPE_ANSWER_PACK):
+        row0 = out.status.astype(_I64) | (out.removed.astype(_I64) << 1)
+        rows = (row0, out.remaining, out.reset_time, out.new_expire)
+        if with_pre:
+            rows = rows + (out.pre_expire,)
+        return jnp.stack(rows)
 
 
 def unpack_output(packed):
@@ -718,9 +734,10 @@ def _apply_rounds_impl(
         )
         return r + 1, st, packed
 
-    _, state, packed = jax.lax.while_loop(
-        cond, body, (jnp.asarray(0, _I32), state, packed0)
-    )
+    with jax.named_scope(SCOPE_ROUNDS):
+        _, state, packed = jax.lax.while_loop(
+            cond, body, (jnp.asarray(0, _I32), state, packed0)
+        )
     return state, packed
 
 
@@ -829,14 +846,15 @@ def apply_rounds32(
             v == 0, -1, jnp.where(fits, d, jnp.where(v == pre_exp, -2, jnp.clip(d, 0, hi)))
         )
 
-    packed32 = jnp.stack(
-        (
-            packed64[0],
-            jnp.clip(packed64[1], 0, hi),
-            delta(packed64[2]),
-            delta(packed64[3]),
-        )
-    ).astype(_I32)
+    with jax.named_scope(SCOPE_ANSWER_PACK):
+        packed32 = jnp.stack(
+            (
+                packed64[0],
+                jnp.clip(packed64[1], 0, hi),
+                delta(packed64[2]),
+                delta(packed64[3]),
+            )
+        ).astype(_I32)
     return state, packed32
 
 
@@ -880,28 +898,29 @@ def apply_compact32(
     )
     out, new = _apply_compute(state, req, now_ms)
 
-    C = state.hot.shape[0]
-    wl = jnp.clip(wlane, 0, req.slot.shape[0] - 1)
-    wvalid = (wlane >= 0) & new.writes[wl]
-    lane = jnp.arange(wlane.shape[0], dtype=_I32)
-    dst = jnp.where(wvalid, req.slot[wl], C + lane)
-    drop = dict(mode="drop", unique_indices=True)
-    hot_rows = _pack_hot(new.flags, new.rem, new.stamp, new.exp)[wl]
-    new_hot = state.hot.at[dst].set(hot_rows, **drop)
+    with jax.named_scope(SCOPE_COMMIT):
+        C = state.hot.shape[0]
+        wl = jnp.clip(wlane, 0, req.slot.shape[0] - 1)
+        wvalid = (wlane >= 0) & new.writes[wl]
+        lane = jnp.arange(wlane.shape[0], dtype=_I32)
+        dst = jnp.where(wvalid, req.slot[wl], C + lane)
+        drop = dict(mode="drop", unique_indices=True)
+        hot_rows = _pack_hot(new.flags, new.rem, new.stamp, new.exp)[wl]
+        new_hot = state.hot.at[dst].set(hot_rows, **drop)
 
-    ccold = wvalid & new.cold_changed[wl]
-    dst_cold = jnp.where(ccold, req.slot[wl], C + lane)
-    cold_rows = _pack_cold(new.limit, new.dur)[wl]
+        ccold = wvalid & new.cold_changed[wl]
+        dst_cold = jnp.where(ccold, req.slot[wl], C + lane)
+        cold_rows = _pack_cold(new.limit, new.dur)[wl]
 
-    def _scatter_cold(args):
-        cold, idx, rows = args
-        return cold.at[idx].set(rows, **drop)
+        def _scatter_cold(args):
+            cold, idx, rows = args
+            return cold.at[idx].set(rows, **drop)
 
-    new_cold = jax.lax.cond(
-        jnp.any(ccold), _scatter_cold, lambda a: a[0],
-        (state.cold, dst_cold, cold_rows),
-    )
-    state = BucketState(hot=new_hot, cold=new_cold)
+        new_cold = jax.lax.cond(
+            jnp.any(ccold), _scatter_cold, lambda a: a[0],
+            (state.cold, dst_cold, cold_rows),
+        )
+        state = BucketState(hot=new_hot, cold=new_cold)
 
     pre_exp = out.pre_expire
     hi = jnp.asarray((1 << 31) - 1, _I64)
@@ -914,15 +933,16 @@ def apply_compact32(
             jnp.where(fits, d, jnp.where(v == pre_exp, -2, jnp.clip(d, 0, hi))),
         )
 
-    row0 = out.status.astype(_I64) | (out.removed.astype(_I64) << 1)
-    packed32 = jnp.stack(
-        (
-            row0,
-            jnp.clip(out.remaining, 0, hi),
-            delta(out.reset_time),
-            delta(out.new_expire),
-        )
-    ).astype(_I32)
+    with jax.named_scope(SCOPE_ANSWER_PACK):
+        row0 = out.status.astype(_I64) | (out.removed.astype(_I64) << 1)
+        packed32 = jnp.stack(
+            (
+                row0,
+                jnp.clip(out.remaining, 0, hi),
+                delta(out.reset_time),
+                delta(out.new_expire),
+            )
+        ).astype(_I32)
     return state, packed32
 
 
@@ -965,20 +985,21 @@ def apply_rounds_dict(
 ) -> "tuple[BucketState, jax.Array]":
     """apply_rounds32 behind the config-dictionary wire.  round_id8 is
     u8 (planner guarantees n_rounds <= 255 or falls back)."""
-    cfg = reqd.cfg.astype(_I32)
-    req32 = RequestBatch32(
-        slot=reqd.slot,
-        exists=(reqd.flags & 1) != 0,
-        algorithm=reqd.t_algorithm[cfg],
-        behavior=reqd.t_behavior[cfg],
-        hits=reqd.t_hits[cfg],
-        limit=reqd.t_limit[cfg],
-        duration=reqd.t_duration[cfg],
-        greg_expire_delta=reqd.t_greg_expire_delta[cfg],
-        greg_duration=reqd.t_greg_duration[cfg],
-        occ=reqd.occ.astype(_I32),
-        write=(reqd.flags & 2) != 0,
-    )
+    with jax.named_scope(SCOPE_WIRE_DECODE):
+        cfg = reqd.cfg.astype(_I32)
+        req32 = RequestBatch32(
+            slot=reqd.slot,
+            exists=(reqd.flags & 1) != 0,
+            algorithm=reqd.t_algorithm[cfg],
+            behavior=reqd.t_behavior[cfg],
+            hits=reqd.t_hits[cfg],
+            limit=reqd.t_limit[cfg],
+            duration=reqd.t_duration[cfg],
+            greg_expire_delta=reqd.t_greg_expire_delta[cfg],
+            greg_duration=reqd.t_greg_duration[cfg],
+            occ=reqd.occ.astype(_I32),
+            write=(reqd.flags & 2) != 0,
+        )
     return apply_rounds32(
         state, req32, round_id8.astype(_I32), n_rounds, now_ms,
         cold_cond=cold_cond,
@@ -1037,6 +1058,7 @@ def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array
     return w
 
 
+@jax.named_scope(SCOPE_WIRE_DECODE)
 def unpack_dict_wire(w, P: int):
     """Device-side twin of pack_dict_wire for ONE shard row: returns
     (slot, flags, cfg u8, occ, rid, [7 table value arrays — value rows
@@ -1102,20 +1124,21 @@ def apply_compact_packed(
     guarantee)."""
     P = (wire.shape[0] - DICT_WIRE_TABLE_WORDS) // 3
     slot, fl, cfg, occ, _rid, rows = unpack_dict_wire(wire, P)
-    cfg = cfg.astype(_I32)
-    req32 = RequestBatch32(
-        slot=slot,
-        exists=(fl & 1) != 0,
-        algorithm=rows[0][cfg],
-        behavior=rows[1][cfg],
-        hits=rows[2][cfg].astype(_I32),
-        limit=rows[3][cfg].astype(_I32),
-        duration=rows[4][cfg].astype(_I32),
-        greg_expire_delta=rows[5][cfg].astype(_I32),
-        greg_duration=rows[6][cfg].astype(_I32),
-        occ=occ.astype(_I32),
-        write=(fl & 2) != 0,
-    )
+    with jax.named_scope(SCOPE_WIRE_DECODE):
+        cfg = cfg.astype(_I32)
+        req32 = RequestBatch32(
+            slot=slot,
+            exists=(fl & 1) != 0,
+            algorithm=rows[0][cfg],
+            behavior=rows[1][cfg],
+            hits=rows[2][cfg].astype(_I32),
+            limit=rows[3][cfg].astype(_I32),
+            duration=rows[4][cfg].astype(_I32),
+            greg_expire_delta=rows[5][cfg].astype(_I32),
+            greg_duration=rows[6][cfg].astype(_I32),
+            occ=occ.astype(_I32),
+            write=(fl & 2) != 0,
+        )
     return apply_compact32(state, req32, wlane, now_ms)
 
 
@@ -1132,22 +1155,23 @@ def apply_rounds_packed_wide(
     now = jnp.asarray(now_ms, _I64)
     P = (wire.shape[0] - DICT_WIRE_TABLE_WORDS) // 3
     slot, fl, cfg, occ, rid, rows = unpack_dict_wire(wire, P)
-    cfg = cfg.astype(_I32)
-    delta = rows[5][cfg]
-    greg_dur = rows[6][cfg]
-    req = RequestBatch(
-        slot=slot,
-        exists=(fl & 1) != 0,
-        algorithm=rows[0][cfg],
-        behavior=rows[1][cfg],
-        hits=rows[2][cfg],
-        limit=rows[3][cfg],
-        duration=rows[4][cfg],
-        greg_expire=jnp.where(greg_dur != 0, now + delta, 0),
-        greg_duration=greg_dur,
-        occ=occ.astype(_I32),
-        write=(fl & 2) != 0,
-    )
+    with jax.named_scope(SCOPE_WIRE_DECODE):
+        cfg = cfg.astype(_I32)
+        delta = rows[5][cfg]
+        greg_dur = rows[6][cfg]
+        req = RequestBatch(
+            slot=slot,
+            exists=(fl & 1) != 0,
+            algorithm=rows[0][cfg],
+            behavior=rows[1][cfg],
+            hits=rows[2][cfg],
+            limit=rows[3][cfg],
+            duration=rows[4][cfg],
+            greg_expire=jnp.where(greg_dur != 0, now + delta, 0),
+            greg_duration=greg_dur,
+            occ=occ.astype(_I32),
+            write=(fl & 2) != 0,
+        )
     return apply_rounds(state, req, rid, n_rounds, now_ms, cold_cond=cold_cond)
 
 

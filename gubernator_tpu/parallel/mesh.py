@@ -37,6 +37,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
+from ..saturation import phase
 from ..models.shard import (
     ColumnarPipeline,
     RoundPlanner,
@@ -307,6 +308,7 @@ def _get_sync_fn(mesh: Mesh, axis: str):
     fn = _SYNC_FN_CACHE.get(key)
     if fn is None:
 
+        @jax.named_scope(buckets.SCOPE_GLOBAL_SYNC)
         def _sync_body(state, gcols, cfg, dirty, now):
             sq = lambda t: jax.tree.map(lambda a: a[0], t)
             ns, ngc, out, applied, total = global_ops.global_sync(
@@ -1475,7 +1477,6 @@ class MeshBucketStore(ColumnarPipeline):
         )[idx]
 
     # ------------------------------------------------------------------
-    @_drained_locked
     def sync_globals(self, now_ms: int) -> "SyncResult":
         """Run one GLOBAL sync collective (the TPU-native stand-in for
         GlobalSyncWait ticks of all three global.go pipelines).
@@ -1485,25 +1486,36 @@ class MeshBucketStore(ColumnarPipeline):
         (UpdatePeerGlobals broadcast) and aggregated hit totals for keys
         owned by remote daemons (GetPeerRateLimits forward).
 
-        Sets `last_sync_cost_s` to the time spent INSIDE the lock
-        (collective dispatch + readback + decode/commit) — the real
-        recurring cost of a sync pass.  The GlobalManager's window
-        tuner reads this instead of its own wall clock: the
-        drain-then-lock wait ahead of it is serving-pipeline
-        backpressure, and folding that into the window would inflate
+        Two phases, observed only by a pass that runs the collective:
+        `global.sync_drain` (what `_drain_then_lock` waits for: every
+        in-flight batch's commit, then both locks — serving-pipeline
+        backpressure) and `global.sync` (locks held: dispatch, blocking
+        read-back, decode/commit — the real recurring cost of a pass).
+        A pass with nothing to sync still drains; it is counted on its
+        own as `global.tick_idle`.
+
+        Sets `last_sync_cost_s` to the `global.sync` reading.  The
+        GlobalManager's window tuner reads this instead of its own wall
+        clock: folding the drain into the window would inflate
         GlobalSyncWait ~10x under load (observed on the contended CPU
         host: wall-time syncs pinned the auto window at its 1s cap)."""
-        import time as _time
-
-        t0 = _time.perf_counter()
-        with telemetry.program("mesh:global_sync"):
-            res = self._sync_globals_locked(now_ms)
-        if res.did_work:
-            # No-work passes (empty early return) cost ~0 and would pin
-            # a min-of-N window estimator at its floor; only passes that
-            # ran the collective are valid sync-cost observations.
-            self.last_sync_cost_s = _time.perf_counter() - t0
-        return res
+        with phase("global.sync_drain") as drain:
+            self._drain_then_lock()
+            idle = not self.gtable.active_gslots() and not self.dirty.any()
+            if idle:
+                drain.name = "global.tick_idle"
+        try:
+            if idle:
+                return SyncResult(did_work=False)
+            with phase("global.sync") as ph, telemetry.program("mesh:global_sync"):
+                res = self._sync_globals_locked(now_ms)
+            # Only passes that ran the collective are valid sync-cost
+            # observations (a ~0 no-work pass would pin a min-of-N
+            # window estimator at its floor).
+            self.last_sync_cost_s = ph.dt_s
+            return res
+        finally:
+            self._unlock_drained()
 
     def _sync_globals_locked(self, now_ms: int) -> "SyncResult":
         active = self.gtable.active_gslots()
@@ -1835,7 +1847,9 @@ class MeshBucketStore(ColumnarPipeline):
                     wires = [
                         jax.device_put(noop, self._sharding) for _ in range(k)
                     ]
-                    with self._lock:
+                    with self._lock, telemetry.program(
+                        f"mesh:dispatch:fused{k}:narrow"
+                    ):
                         self.state, _ = fn(
                             self.state, *wires,
                             np.ones(k, np.int32),

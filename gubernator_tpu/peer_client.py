@@ -39,9 +39,8 @@ import numpy as np
 
 from . import audit
 from . import faults as faults_mod
-from . import profiling
-from . import saturation
 from . import tracing
+from .saturation import phase
 from . import wire
 from .config import MAX_BATCH_SIZE, PEER_COLUMNS_MAX_LANES, BehaviorConfig
 from .faults import CircuitBreaker, FaultPlan
@@ -798,10 +797,12 @@ class PeerClient:
                     ),
                 )
             trace, links = self._trace_entries(chunk)
-            t0 = time.monotonic_ns()
             rpc_err = None
+            # Always-on attribution: the forwarded hop's round trip is
+            # one of the waterfall's phases (saturation.py).
+            rpc = phase("peer.rpc")
             try:
-                with profiling.scope("peer.rpc"):
+                with rpc:
                     rc = self._send_columns(
                         cols, self.behaviors.batch_timeout_s, _draining=True,
                         trace=trace,
@@ -810,11 +811,6 @@ class PeerClient:
                 rpc_err = e
                 raise
             finally:
-                # Always-on attribution: the forwarded hop's round trip
-                # is one of the waterfall's phases (saturation.py).
-                saturation.observe_phase(
-                    "peer.rpc", (time.monotonic_ns() - t0) / 1e9
-                )
                 bt = tracing.new_batch(links)
                 if bt is not None:
                     # The client half of the cross-daemon hop: one span
@@ -829,9 +825,10 @@ class PeerClient:
                     )
                     if rpc_err is not None:
                         attrs["error"] = str(rpc_err)
+                    end_ns = time.monotonic_ns()
                     tracing.record_span(
                         "peer.rpc", bt.ctx,
-                        start_ns=t0, end_ns=time.monotonic_ns(),
+                        start_ns=end_ns - int(rpc.dt_s * 1e9), end_ns=end_ns,
                         links=links, **attrs,
                     )
         except Exception as e:  # noqa: BLE001

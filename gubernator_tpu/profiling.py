@@ -13,7 +13,7 @@ questions:
   flamegraph "collapsed" form.  Each sample is TAGGED with the phase of
   the request waterfall the thread was executing (the PR 6 taxonomy —
   `ingress.parse`, `dispatch.launch`, `peer.rpc`, ... — declared by
-  lightweight `scope()` hooks at the existing attribution sites) and
+  `saturation.phase()` at every attribution site) and
   with the PR 9 program label when one is in scope, so "Python decode"
   vs "device scatter" vs "GIL-idle in epoll" is answerable per phase.
   Samples land in a ring of one-second windows; `GET /debug/pprof
@@ -97,7 +97,7 @@ _HZ: float = min(max(_env_float("GUBER_PROFILE_HZ", DEFAULT_HZ), 1.0), 1000.0)
 # are GIL-atomic, the tracing._Ring trick)
 # ---------------------------------------------------------------------
 
-# thread ident -> active phase tag (scope() hooks at the PR 6 sites)
+# thread ident -> active phase tag (push_scope/pop_scope, from saturation.phase)
 _scopes: Dict[int, str] = {}
 # thread ident -> active program label (mirrored by telemetry.program)
 _programs: Dict[int, str] = {}
@@ -106,61 +106,36 @@ _programs: Dict[int, str] = {}
 _static: Dict[int, str] = {}
 
 
-class _NoopScope:
-    __slots__ = ()
+def push_scope(tag: str) -> Optional[str]:
+    """Tag the calling thread with phase `tag` (the PR 6 taxonomy):
+    while it stands, profiler samples of this thread attribute to it.
+    Returns the tag it replaced, for `pop_scope`.  Called by
+    `saturation.phase`, the one primitive every phase site uses.
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopScope()
-
-
-class _Scope:
-    __slots__ = ("tag", "_ident", "_prev")
-
-    def __init__(self, tag: str):
-        self.tag = tag
-
-    def __enter__(self):
-        ident = threading.get_ident()
-        self._ident = ident
-        self._prev = _scopes.get(ident)
-        _scopes[ident] = self.tag
-        # NO piggyback here (Sampler.maybe_tick): dispatch-stage scopes
-        # enter INSIDE the pipeline's locked launch/commit critical
-        # sections, and stretching those by even a tick's fold widens
-        # the donated-device-array window enough to flake tier-1.  The
-        # piggyback sites are the lock-free service-level folds.
-        return self
-
-    def __exit__(self, *exc):
-        if self._prev is None:
-            # pop, don't park a None: thread idents recycle, and a dict
-            # of dead idents would otherwise grow with pool churn.
-            _scopes.pop(self._ident, None)
-        else:
-            _scopes[self._ident] = self._prev
-        return False
+    NO piggyback here (Sampler.maybe_tick): dispatch-stage phases enter
+    INSIDE the pipeline's locked launch/commit critical sections, and
+    stretching those by even a tick's fold widens the donated-device-
+    array window enough to flake tier-1.  The piggyback sites are the
+    lock-free service-level folds."""
+    ident = threading.get_ident()
+    prev = _scopes.get(ident)
+    _scopes[ident] = tag
+    return prev
 
 
-def scope(tag: str):
-    """Phase scope for the current thread: while active, profiler
-    samples of this thread attribute to `tag` (the PR 6 phase
-    taxonomy).  Disabled path is one branch returning a shared no-op —
-    the tracing/telemetry compiled-out discipline."""
-    if not _ENABLED:
-        return _NOOP
-    return _Scope(tag)
+def pop_scope(prev: Optional[str]) -> None:
+    if prev is None:
+        # pop, don't park a None: thread idents recycle, and a dict
+        # of dead idents would otherwise grow with pool churn.
+        _scopes.pop(threading.get_ident(), None)
+    else:
+        _scopes[threading.get_ident()] = prev
 
 
 def tag_thread(tag: str) -> None:
     """Register a STATIC role tag for the calling thread (long-lived
     daemon threads: the epoll loop, the batch-window flusher, the
-    auditor).  Unlike scope(), the tag covers idle time too — which is
+    auditor).  Unlike a phase's tag, it covers idle time too — which is
     the point: "GIL-idle in epoll" is an answer, not noise."""
     _static[threading.get_ident()] = tag
 

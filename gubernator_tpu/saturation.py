@@ -6,9 +6,12 @@ module aggregates the same signals ALWAYS-ON, so the operator questions
 "are we burning the error budget") have live answers without sampling:
 
 * **Latency attribution** — per-phase duration reservoirs covering the
-  whole request waterfall (`PHASES`): ingress parse -> batch-window
-  wait -> queue wait -> the five dispatch pipeline stages -> peer-wire
-  RTT -> response encode.  Each observation also feeds the
+  whole request waterfall (`WATERFALL`): ingress parse -> batch-window
+  wait -> queue wait -> the five dispatch pipeline stages and the lock
+  and gate waits between them -> peer-wire RTT -> response encode.
+  Every phase site is one `with phase(...)`, whose reading also reaches
+  the host sampler, the sampled span and the profiler's trace.  Each
+  observation also feeds the
   `gubernator_latency_attribution_seconds{phase}` histogram of the
   registered metrics sink; `GET /debug/latency` serves ceil-rank
   percentile snapshots straight from the reservoirs.
@@ -46,8 +49,9 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from . import tracing
+from . import profiling, tracing
 
 # ---------------------------------------------------------------------
 # Shared ceil-rank percentiles (the bench.py p99 bugfix lives here so
@@ -75,24 +79,49 @@ def percentile(sorted_vals: Sequence[float], q: float) -> float:
 # Latency attribution: per-phase reservoirs
 # ---------------------------------------------------------------------
 
-# The request waterfall, in flight order.  Snapshots list phases in
-# this order so a /debug/latency reader sees the pipeline shape.
-PHASES = (
-    "ingress.parse",     # wire bytes -> IngressColumns (gateway)
-    "batch.window",      # submit -> coalescing-window flush (batchers)
-    "express.submit",    # express bypass: submit -> dispatch staged
-                         # (replaces batch.window + queue.wait for
-                         # express lanes — the express-vs-batched split)
-    "queue.wait",        # flush -> dispatch submit (backstop + concat)
-    "dispatch.prepare",  # slot-table planning (pipeline stage 1)
-    "dispatch.stage",    # wire pack + H2D upload start (stage 2)
-    "dispatch.launch",   # ticket-ordered jit call (stage 3)
-    "dispatch.fetch",    # device->host readback
-    "dispatch.commit",   # decode + table commit
-    "peer.rpc",          # forwarded-hop round trip (peer_client)
-    "response.encode",   # ColumnarResult -> wire bytes (gateway)
-    "ingress.total",     # whole-request wall time (GetRateLimits)
+# The request waterfall, in flight order, as (phase, depth): depth 0 is
+# a top-level phase of a request, a deeper phase lies INSIDE the nearest
+# shallower phase above it (its time is part of that one's and must not
+# be added to it).  Snapshots list phases in this order so a
+# /debug/latency reader sees the pipeline shape; the document's
+# `waterfall` key serves the table itself.
+WATERFALL = (
+    ("epoll.wait", 0),        # a gateway worker has no request (blocked in edge.next)
+    ("pump.take", 0),         # the native pump has no frame (blocked in batcher.take)
+    ("ingress.parse", 0),     # wire bytes -> IngressColumns (gateway)
+    ("window.idle", 0),       # a BatchWindow's flusher has no submission
+    ("window.hold", 0),       # ... holds submissions until the window closes or fills
+    ("batch.window", 0),      # submit -> coalescing-window flush (batchers; a
+                              # native frame: its arrival -> the take)
+    ("pump.depth_wait", 0),   # native take: the pipeline-depth semaphore
+    ("pump.admit", 0),        # native take: ring counters, black-box tap, audit
+                              # note, tenant fold, hot-key sketch
+    ("express.submit", 0),    # express bypass: submit -> dispatch launched
+                              # (replaces batch.window + queue.wait for
+                              # express lanes — the express-vs-batched split;
+                              # the bypass dispatches inline, so this take's
+                              # dispatch.prepare..launch lie INSIDE it)
+    ("queue.wait", 0),        # flush -> dispatch submit (backstop + concat)
+    ("queue.backstop", 1),    # blocked on the oldest unresolved dispatch
+    ("dispatch.prepare", 0),  # slot-table planning (pipeline stage 1)
+    ("dispatch.plan_wait", 1),  # waiting for the plan lock
+    ("dispatch.stage", 0),    # wire pack + H2D upload start (stage 2)
+    ("dispatch.gate_wait", 0),  # waiting for the ticket's launch turn
+    ("dispatch.launch", 0),   # ticket-ordered jit call (stage 3)
+    ("dispatch.launch_wait", 1),  # waiting for the store lock
+    ("pump.handoff", 0),      # native take: queued for a done-pool worker
+    ("dispatch.fetch", 0),    # device->host readback
+    ("dispatch.commit", 0),   # decode + table commit
+    ("pump.outcome", 0),      # native take: result copies + tenant outcome fold
+    ("peer.rpc", 0),          # forwarded-hop round trip (peer_client)
+    ("response.encode", 0),   # ColumnarResult -> wire bytes (gateway)
+    ("pump.account", 0),      # native take: request metrics, after the answers left
+    ("ingress.total", 0),     # whole-request wall time (GetRateLimits)
+    ("global.sync_drain", 0),  # GLOBAL tick: pipeline drain + both locks
+    ("global.sync", 0),       # GLOBAL tick, locks held: dispatch, read-back, commit
+    ("global.tick_idle", 0),  # the drain of a GLOBAL tick that had nothing to sync
 )
+PHASES = tuple(p for p, _ in WATERFALL)
 
 PHASE_RING = 2048  # recent samples kept per phase
 
@@ -172,6 +201,73 @@ def observe_phase(phase: str, dt_s: float) -> None:
             except Exception:  # noqa: BLE001 — a dead registry must not fail requests
                 return
         child.observe(dt_s)
+
+
+_profiler_session_on = TraceAnnotation.is_enabled  # one atomic load
+
+
+class phase:
+    """THE span primitive of the waterfall: `with phase(name, bt, **ids):`
+    takes the clock once around its body and the one reading lands in
+
+    (a) the always-on reservoir `/debug/latency` serves (`observe_phase`);
+    (b) the host sampler's per-thread tag (`/debug/pprof` folds by it);
+    (c) a sampled span under the batch trace `bt`, linked to its member
+        lanes and carrying `ids` (a take's spans share its `ticket`) —
+        only when the batch was sampled (`bt` is not None);
+    (d) a `jax.profiler.TraceAnnotation(name, **ids)` on this thread's
+        line of the profiler's trace, on the device trace's clock — only
+        while a profiler session runs (one check otherwise).
+
+    It closes on an exception too (the span then carries `error`).
+    `dt_s` holds the reading after exit, for a caller that feeds a gauge
+    of its own from it.  `name` may be reassigned inside the interval:
+    the reservoir and the span take the name it has at exit (a GLOBAL
+    tick learns only after its drain whether it has anything to sync).
+    An interval measured elsewhere (in C++, or from a stamp taken on
+    another thread) goes to `observe_phase`."""
+
+    __slots__ = ("name", "bt", "ids", "dt_s", "_t0", "_tagged", "_prev_tag",
+                 "_ann")
+
+    def __init__(self, name: str, bt: "Optional[tracing.BatchTrace]" = None,
+                 **ids):
+        self.name = name
+        self.bt = bt
+        self.ids = ids
+        self.dt_s = 0.0
+
+    def note(self, **ids) -> None:
+        """Identifiers learned inside the interval (a ticket is assigned
+        under the plan lock): they join the sampled span's attributes
+        and the profiler event's."""
+        self.ids.update(ids)
+        if self._ann is not None:
+            self._ann.set_metadata(**ids)
+
+    def __enter__(self) -> "phase":
+        self._ann = None
+        if _profiler_session_on():
+            self._ann = TraceAnnotation(self.name, **self.ids)
+            self._ann.__enter__()
+        self._tagged = profiling.enabled()
+        if self._tagged:
+            self._prev_tag = profiling.push_scope(self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.dt_s = dt = time.perf_counter() - self._t0
+        if self._tagged:
+            profiling.pop_scope(self._prev_tag)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        observe_phase(self.name, dt)
+        if self.bt is not None:
+            if exc_type is not None:
+                self.ids["error"] = str(exc)
+            tracing.stage_span(self.name, dt, self.bt, **self.ids)
+        return False
 
 
 def phase_snapshot() -> Dict[str, dict]:

@@ -27,6 +27,7 @@ from . import audit as audit_mod
 from . import blackbox as blackbox_mod
 from . import profiling
 from . import saturation
+from .saturation import phase
 from . import snapshot as snapshot_mod
 from . import telemetry
 from . import tracing
@@ -320,18 +321,17 @@ class LocalBatcher:
         except IngressShedError as e:
             fut.set_exception(e)
             return fut
-        t0 = time.monotonic()
-        try:
-            resp = self.store.apply([req], self.clock.now_ms())[0]
-            if not fut.done():
-                fut.set_result(resp)
-        except Exception as e:  # noqa: BLE001
-            if not fut.done():
-                fut.set_exception(e)
-        finally:
-            self._gate.release(1)
+        with phase("express.submit"):
+            try:
+                resp = self.store.apply([req], self.clock.now_ms())[0]
+                if not fut.done():
+                    fut.set_result(resp)
+            except Exception as e:  # noqa: BLE001
+                if not fut.done():
+                    fut.set_exception(e)
+            finally:
+                self._gate.release(1)
         saturation.note_express("bypass", 1)
-        saturation.observe_phase("express.submit", time.monotonic() - t0)
         return fut
 
     def _flush(self, batch) -> None:
@@ -1005,26 +1005,25 @@ class ColumnarBatcher:
         except IngressShedError as e:
             fut.set_exception(e)
             return fut
-        t0 = time.monotonic()
-        try:
-            ge = np.zeros(n, np.int64) if greg_expire is None else greg_expire
-            gd = (
-                np.zeros(n, np.int64) if greg_duration is None
-                else greg_duration
-            )
-            handle = self.store.apply_columns_async(
-                keys, algo, behavior, hits, limit, duration,
-                self.clock.now_ms(), ge, gd,
-            )
-            if not fut.done():
-                fut.set_result((handle, 0, n))
-        except Exception as e:  # noqa: BLE001
-            if not fut.done():
-                fut.set_exception(e)
-        finally:
-            self._gate.release(n)
+        with phase("express.submit"):
+            try:
+                ge = np.zeros(n, np.int64) if greg_expire is None else greg_expire
+                gd = (
+                    np.zeros(n, np.int64) if greg_duration is None
+                    else greg_duration
+                )
+                handle = self.store.apply_columns_async(
+                    keys, algo, behavior, hits, limit, duration,
+                    self.clock.now_ms(), ge, gd,
+                )
+                if not fut.done():
+                    fut.set_result((handle, 0, n))
+            except Exception as e:  # noqa: BLE001
+                if not fut.done():
+                    fut.set_exception(e)
+            finally:
+                self._gate.release(n)
         saturation.note_express("bypass", n)
-        saturation.observe_phase("express.submit", time.monotonic() - t0)
         return fut
 
     def _flush(self, batch) -> None:
@@ -1060,48 +1059,14 @@ class ColumnarBatcher:
         saturation.dispatcher_busy.add(time.monotonic() - t_flush)
 
     def _flush_chunk(self, batch) -> None:
-        t_chunk = time.monotonic()
         try:
-            # Overload backstop (see MAX_INFLIGHT): block on the oldest
-            # unresolved dispatch only when the pipeline is pathologically
-            # deep.  Submissions queue behind the wait, so the next flush
-            # merges them.  (Waiters resolve handles concurrently; `done`
-            # flips as they do, and result() is idempotent/thread-safe.)
-            oldest = None
-            with self._inflight_lock:
-                while self._own_inflight and self._own_inflight[0].done:
-                    self._own_inflight.popleft()
-                if len(self._own_inflight) >= self.MAX_INFLIGHT:
-                    oldest = self._own_inflight.popleft()
-            if oldest is not None:
-                oldest.result()
-            if len(batch) == 1:
-                (cols, fut) = batch[0]
-                keys = cols[0]
-                arrays = cols[1:]
-            else:
-                from .native import PackedKeys
-
-                if all(isinstance(c[0], PackedKeys) for c, _ in batch):
-                    # Packed-keys coalesce: concat buffers, never decode
-                    # per-lane strings.
-                    keys = PackedKeys.concat([c[0] for c, _ in batch])
-                else:
-                    keys = []
-                    for (c, _) in batch:
-                        keys.extend(c[0])
-                arrays = tuple(
-                    np.concatenate([c[i] for c, _ in batch])
-                    for i in range(1, 8)
-                )
-            algo, beh, hits, limit, duration, ge, gd = arrays
             # queue.wait: flush start -> dispatch submit — the backstop
             # wait on a pathologically deep pipeline plus the concat
             # (near-zero in steady state; the phase that grows when the
             # device falls behind the arrival rate).
-            saturation.observe_phase(
-                "queue.wait", time.monotonic() - t_chunk
-            )
+            with phase("queue.wait"):
+                keys, arrays = self._chunk_columns(batch)
+            algo, beh, hits, limit, duration, ge, gd = arrays
             bt = self._batch_trace(batch)
             if bt is not None:
                 tracing.stage_batch_trace(bt)
@@ -1131,6 +1096,40 @@ class ColumnarBatcher:
             for _, fut in batch:
                 if not fut.done():
                     fut.set_exception(e)
+
+    def _chunk_columns(self, batch):
+        """(keys, the seven arrays) of one chunk, behind the overload
+        backstop (see MAX_INFLIGHT): block on the oldest unresolved
+        dispatch only when the pipeline is pathologically deep
+        (`queue.backstop`, inside `queue.wait`).  Submissions queue
+        behind the wait, so the next flush merges them.  (Waiters
+        resolve handles concurrently; `done` flips as they do, and
+        result() is idempotent/thread-safe.)"""
+        oldest = None
+        with self._inflight_lock:
+            while self._own_inflight and self._own_inflight[0].done:
+                self._own_inflight.popleft()
+            if len(self._own_inflight) >= self.MAX_INFLIGHT:
+                oldest = self._own_inflight.popleft()
+        if oldest is not None:
+            with phase("queue.backstop"):
+                oldest.result()
+        if len(batch) == 1:
+            (cols, _fut) = batch[0]
+            return cols[0], cols[1:]
+        from .native import PackedKeys
+
+        if all(isinstance(c[0], PackedKeys) for c, _ in batch):
+            # Packed-keys coalesce: concat buffers, never decode
+            # per-lane strings.
+            keys = PackedKeys.concat([c[0] for c, _ in batch])
+        else:
+            keys = []
+            for (c, _) in batch:
+                keys.extend(c[0])
+        return keys, tuple(
+            np.concatenate([c[i] for c, _ in batch]) for i in range(1, 8)
+        )
 
     def _batch_trace(self, batch):
         """Join the chunk's sampled submissions into one BatchTrace and

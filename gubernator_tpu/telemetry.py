@@ -48,6 +48,7 @@ the hot path) and the listener body returns immediately.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -55,16 +56,27 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from . import profiling, tracing
+from .saturation import phase
 from .utils.logging import category_logger
 
 logger = category_logger("telemetry")
 
 # The jax.monitoring duration event one XLA backend compile emits
 # (jax 0.9: _src/dispatch.py BACKEND_COMPILE_EVENT; a persistent-cache
-# hit emits it too, with the seconds the load took).  Trace/lowering events are
-# deliberately NOT counted — one logical compile emits several of
-# them, and the backend compile is the one that costs real time.
+# hit emits it too, with the seconds the load took).  One logical
+# compile emits a trace and a lowering event as well: those are NOT
+# counted as compiles — the backend compile is the one that costs real
+# time — but before `mark_steady()` every one of the four is summed per
+# program label, which is what says where a start's seconds went.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_STARTUP_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _COMPILE_EVENT: "backend_s",
+    # Inside the backend event: reading, deserialising and loading a
+    # persistent-cache entry (only a hit emits it).
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
 
 _UNLABELED = "unlabeled"
 
@@ -121,6 +133,14 @@ _exec_stats: Dict[str, list] = {}
 # distinct jitted callables created by the program caches
 # (buckets.fused_packed_jit and the mesh twin note creations here)
 _programs_created: Dict[str, int] = {}
+# Set-up, cumulative over the process: seconds per part of a daemon
+# start (`startup(part)`), per program label what its launches before
+# `mark_steady()` spent (`_STARTUP_EVENTS` fields, `cache_hits`, and
+# `call_s`, the wall of the labelled launches themselves), and the
+# process's age when it last said `listening`.
+_startup_parts: Dict[str, float] = {}
+_startup_programs: Dict[str, Dict[str, float]] = {}
+_startup_listening_s: List[Optional[float]] = [None]
 _steady = False
 _recent_steady_compiles: "deque[float]" = deque()
 _storms = 0
@@ -179,9 +199,18 @@ def listener_active() -> bool:
 
 
 def _on_duration_event(name: str, dur_s: float, **_kw) -> None:
-    if not _ENABLED or name != _COMPILE_EVENT:
+    field = _STARTUP_EVENTS.get(name)
+    if not _ENABLED or field is None:
         return
     label = getattr(_tls, "program", None) or _UNLABELED
+    if not _steady:
+        with _lock:
+            row = _startup_programs.setdefault(label, {})
+            row[field] = row.get(field, 0.0) + dur_s
+            if field == "cache_load_s":
+                row["cache_hits"] = row.get("cache_hits", 0) + 1
+    if name != _COMPILE_EVENT:
+        return
     lazy = bool(getattr(_tls, "program_lazy", False))
     now = time.monotonic()
     storm = None
@@ -266,6 +295,10 @@ class _Program:
             st[0] += 1
             st[1] += dt
             st[2] = max(st[2], dt)
+            if not _steady:
+                row = _startup_programs.setdefault(self.label, {})
+                row["calls"] = row.get("calls", 0) + 1
+                row["call_s"] = row.get("call_s", 0.0) + dt
         return False
 
 
@@ -295,6 +328,73 @@ def note_program_created(label: str) -> None:
         return
     with _lock:
         _programs_created[label] = _programs_created.get(label, 0) + 1
+
+
+# ---------------------------------------------------------------------
+# Set-up by part (process start -> `listening`)
+# ---------------------------------------------------------------------
+def process_age_s() -> float:
+    """Seconds since the kernel started this process (Linux /proc; 0.0
+    where that cannot be read, so a part then reads short, never wrong
+    by more than itself)."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime_s = float(f.read().split()[0])
+        return max(uptime_s - start_ticks / os.sysconf("SC_CLK_TCK"), 0.0)
+    except (OSError, ValueError, IndexError):
+        return 0.0
+
+
+@contextlib.contextmanager
+def startup(part: str):
+    """One part of a daemon start, as the phase `startup.<part>` (so it
+    is in `/debug/latency`, and in a profile taken across a start) and
+    summed under `/debug/device`'s `startup`."""
+    ph = phase("startup." + part)
+    try:
+        with ph:
+            yield
+    finally:
+        note_startup(part, ph.dt_s)
+
+
+def note_startup(part: str, dur_s: float) -> None:
+    with _lock:
+        _startup_parts[part] = _startup_parts.get(part, 0.0) + dur_s
+
+
+def note_listening() -> None:
+    """The server entry calls this as it prints `listening`."""
+    _startup_listening_s[0] = process_age_s()
+
+
+def startup_snapshot() -> dict:
+    """`/debug/device`'s `startup`: seconds per part, and per program
+    label `trace_s`, `lower_s`, `cache_load_s` (persistent-cache hits),
+    `compile_s` (the backend event less the cache load: the compiler on
+    a miss, little more than the cache key on a hit) and `run_s` (the
+    label's launches before `mark_steady()` less all of those: upload,
+    enqueue, and whatever the first execution made the caller wait)."""
+    with _lock:
+        parts = {k: round(v, 6) for k, v in _startup_parts.items()}
+        programs = {}
+        for label, row in sorted(_startup_programs.items()):
+            backend = row.get("backend_s", 0.0)
+            load = row.get("cache_load_s", 0.0)
+            spent = row.get("trace_s", 0.0) + row.get("lower_s", 0.0) + backend
+            programs[label] = {
+                "calls": int(row.get("calls", 0)),
+                "cache_hits": int(row.get("cache_hits", 0)),
+                "trace_s": round(row.get("trace_s", 0.0), 6),
+                "lower_s": round(row.get("lower_s", 0.0), 6),
+                "cache_load_s": round(load, 6),
+                "compile_s": round(max(backend - load, 0.0), 6),
+                "run_s": round(max(row.get("call_s", 0.0) - spent, 0.0), 6),
+            }
+    return {"parts_s": parts, "programs": programs,
+            "listening_s": _startup_listening_s[0]}
 
 
 # ---------------------------------------------------------------------
@@ -381,6 +481,7 @@ def snapshot() -> dict:
         "stormWindowS": STORM_WINDOW_S,
         "programRuns": exec_view,
         "programsCreated": created,
+        "startup": startup_snapshot(),
     }
 
 
@@ -438,6 +539,9 @@ def reset(steady: bool = False) -> None:
         _exec_stats.clear()
         _programs_created.clear()
         _recent_steady_compiles.clear()
+        _startup_parts.clear()
+        _startup_programs.clear()
+        _startup_listening_s[0] = None
         _steady = steady
         _storms = 0
         _last_storm[0] = -float("inf")

@@ -53,9 +53,6 @@ logger = category_logger("tracing")
 # degrades to a single comparison and the wire carries no trace bytes
 # (the GUBER_TRACE_SAMPLE=0 wire-parity contract).
 _SAMPLE: float = 0.0
-# Bench-only "compiled out" switch: the overhead gate compares the
-# sample-rate-0 guards against this fully-disabled baseline.
-_FORCE_DISABLED: bool = False
 
 def _env_ring(default: int = 4096) -> int:
     """GUBER_TRACE_RING, warn-and-default on garbage — module import
@@ -116,16 +113,9 @@ def sample_rate() -> float:
     return _SAMPLE
 
 
-def force_disable(flag: bool) -> None:
-    """Bench hook: behave as if the module did not exist (the
-    'tracing-compiled-out' baseline of the overhead gate)."""
-    global _FORCE_DISABLED
-    _FORCE_DISABLED = bool(flag)
-
-
 def enabled() -> bool:
     """One branch — THE hot-path guard every layer uses."""
-    return _SAMPLE > 0.0 and not _FORCE_DISABLED
+    return _SAMPLE > 0.0
 
 
 def sampled() -> bool:
@@ -615,10 +605,14 @@ class BatchTrace:
         self.links = tuple(links)
 
 
-def new_batch(links: Sequence[SpanContext]) -> Optional[BatchTrace]:
+def new_batch(links: Sequence[SpanContext] = (),
+              roll: bool = False) -> Optional[BatchTrace]:
     """BatchTrace for `links`, or None when there is nothing to link
-    (the unsampled fast path: callers pass the None straight through)."""
-    if not links or not enabled():
+    (the unsampled fast path: callers pass the None straight through).
+    `roll=True` is for a batch whose members carry no context of their
+    own (a native-lane take: its frames were served in C++): the
+    sampling dice are rolled for the batch instead."""
+    if not enabled() or not (links or (roll and _rng().random() < _SAMPLE)):
         return None
     return BatchTrace(links)
 
@@ -637,16 +631,17 @@ def take_batch_trace() -> Optional[BatchTrace]:
     return bt
 
 
-def stage_span(stage: str, dur_s: float, bt: Optional[BatchTrace],
+def stage_span(name: str, dur_s: float, bt: Optional[BatchTrace],
                **attrs) -> None:
-    """One completed dispatch-pipeline stage span
-    (dispatch.prepare/stage/launch/fetch/commit), parented under the
-    batch's window span and linked to every member lane."""
+    """One completed phase span of a sampled batch (`saturation.phase`
+    calls this at exit: dispatch.prepare/stage/launch/fetch/commit, the
+    native pump's pump.*), parented under the batch's root span and
+    linked to every member lane."""
     if bt is None:
         return
     end = time.monotonic_ns()
     record_span(
-        f"dispatch.{stage}",
+        name,
         SpanContext(bt.ctx.trace_id, _rng().getrandbits(64) or 1),
         parent_id=bt.ctx.span_id,
         start_ns=end - int(dur_s * 1e9),

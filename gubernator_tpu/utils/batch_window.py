@@ -41,6 +41,8 @@ import time
 from queue import Empty, Queue
 from typing import Callable, List, Optional
 
+from ..saturation import phase
+
 
 class BatchWindow:
     # EMA smoothing for the adaptive arrival-rate estimate: 0.5 tracks
@@ -107,26 +109,36 @@ class BatchWindow:
             wait = min(wait, self.cap_s)
         return wait
 
-    def _run(self) -> None:
+    def _first(self):
+        """The submission that opens the next window, or None once stopped."""
         while not self._stopped.is_set():
             try:
-                first = self._queue.get(timeout=0.05)
+                return self._queue.get(timeout=0.05)
             except Empty:
                 continue
+        return None
+
+    def _run(self) -> None:
+        while True:
+            with phase("window.idle"):  # nothing is waiting on this flusher
+                first = self._first()
+            if first is None:
+                return
             t_first = time.monotonic()
             batch = [first]
             count = self._weight(first)
             deadline = t_first + self.effective_wait_s()
-            while count < self.limit:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    break
-                try:
-                    item = self._queue.get(timeout=remaining)
-                except Empty:
-                    break
-                batch.append(item)
-                count += self._weight(item)
+            with phase("window.hold"):  # submissions wait for the window
+                while count < self.limit:
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        break
+                    try:
+                        item = self._queue.get(timeout=remaining)
+                    except Empty:
+                        break
+                    batch.append(item)
+                    count += self._weight(item)
             if self.adaptive:
                 now = time.monotonic()
                 # Rate over the whole inter-flush period (idle time

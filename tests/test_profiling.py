@@ -20,6 +20,7 @@ from gubernator_tpu import audit as audit_mod
 from gubernator_tpu import profiling, saturation, tracing
 from gubernator_tpu.gateway import handle_request
 from gubernator_tpu.metrics import Metrics
+from gubernator_tpu.saturation import phase
 from gubernator_tpu.service import (
     ColumnarResult,
     IngressColumns,
@@ -75,12 +76,12 @@ def _assert_conserves(snap):
 # ---------------------------------------------------------------------
 # Sampler: scopes, tags, fold, compiled-out discipline
 # ---------------------------------------------------------------------
-def test_scope_nesting_restores_and_pops():
+def test_phase_tag_nesting_restores_and_pops():
     ident = threading.get_ident()
     assert ident not in profiling._scopes
-    with profiling.scope("ingress.parse"):
+    with phase("ingress.parse"):
         assert profiling._scopes[ident] == "ingress.parse"
-        with profiling.scope("response.encode"):
+        with phase("response.encode"):
             assert profiling._scopes[ident] == "response.encode"
         assert profiling._scopes[ident] == "ingress.parse"
     # Outermost exit POPS (thread idents recycle; a parked None would
@@ -88,13 +89,10 @@ def test_scope_nesting_restores_and_pops():
     assert ident not in profiling._scopes
 
 
-def test_scope_disabled_is_shared_noop():
+def test_phase_with_profiler_off_leaves_no_tag():
     profiling.set_enabled(False)
     try:
-        s1 = profiling.scope("ingress.parse")
-        s2 = profiling.scope("dispatch.launch")
-        assert s1 is s2  # the one-branch compiled-out contract
-        with s1:
+        with phase("ingress.parse"):  # the one-branch compiled-out contract
             assert threading.get_ident() not in profiling._scopes
     finally:
         profiling.set_enabled(True)
@@ -105,7 +103,7 @@ def test_sampler_folds_scoped_and_tagged_threads():
     release = threading.Event()
 
     def scoped_worker():
-        with profiling.scope("dispatch.launch"):
+        with phase("dispatch.launch"):
             ready.set()
             release.wait(10)
 
@@ -153,7 +151,7 @@ def test_profile_snapshot_and_collapsed_render():
     started = threading.Event()
 
     def worker():
-        with profiling.scope("ingress.parse"):
+        with phase("ingress.parse"):
             started.set()
             release.wait(10)
 

@@ -93,7 +93,7 @@ def test_sampled_span_links_and_filter(sampled):
         lane_ctx = tracing.current()
         assert lane_ctx is sp.ctx
     bt = tracing.new_batch([lane_ctx])
-    tracing.stage_span("prepare", 0.001, bt, lanes=4)
+    tracing.stage_span("dispatch.prepare", 0.001, bt, lanes=4)
     spans = tracing.spans_snapshot(lane_ctx.trace_hex)
     names = {s["name"] for s in spans}
     # dispatch.prepare matches via its LINK, not its own trace id
