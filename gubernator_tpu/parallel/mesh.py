@@ -432,9 +432,9 @@ class SyncResult:
 
     broadcast_cols: Optional[GlobalsColumns] = None
     remote_hit_cols: Optional[HitColumns] = None
-    # False only for the empty early return (no active gslots, nothing
-    # dirty): such passes never ran the collective, so observers tuning
-    # windows from sync cost must ignore them.
+    # False only for a tick that found no GLOBAL lane pending: it never
+    # ran the collective, so observers tuning windows from sync cost
+    # must ignore it.
     did_work: bool = True
 
     @property
@@ -535,6 +535,11 @@ class MeshBucketStore(ColumnarPipeline):
         )
         self.gtable = GlobalKeyTable(g_capacity)
         self.dirty = np.zeros((self.n_shards, g_capacity), dtype=bool)
+        # A GLOBAL lane was planned since the last sync pass (owner dirt
+        # OR device-side ghits).  Raised in `apply` and lowered by the
+        # pass, both under the drained lock; `sync_globals` reads it
+        # lock-free to skip a tick with nothing pending.
+        self._global_pending = False
         # Device programs dispatched by replica-batch commits — the
         # O(1)-dispatch-per-broadcast contract is pinned by counting,
         # not timing (tests/test_global_plane.py).
@@ -635,6 +640,8 @@ class MeshBucketStore(ColumnarPipeline):
             owner = shard_of_key(p.key, self.n_shards)
             target = owner
             if has_behavior(p.req.behavior, Behavior.GLOBAL):
+                # Owner dirt or non-owner ghits: either owes a sync pass.
+                self._global_pending = True
                 owner_mark = -1 if remote_global else owner
                 g, evicted = self.gtable.lookup_or_assign(p.key, owner_mark)
                 if evicted is not None:
@@ -1486,27 +1493,30 @@ class MeshBucketStore(ColumnarPipeline):
         (UpdatePeerGlobals broadcast) and aggregated hit totals for keys
         owned by remote daemons (GetPeerRateLimits forward).
 
-        Two phases, observed only by a pass that runs the collective:
-        `global.sync_drain` (what `_drain_then_lock` waits for: every
-        in-flight batch's commit, then both locks — serving-pipeline
-        backpressure) and `global.sync` (locks held: dispatch, blocking
-        read-back, decode/commit — the real recurring cost of a pass).
-        A pass with nothing to sync still drains; it is counted on its
-        own as `global.tick_idle`.
+        A tick with no GLOBAL lane planned since the last pass
+        (`_global_pending` down) returns first: no drain, no lock, no
+        upload, no program, no read-back — the pass would be an identity
+        (ghits all zero, nothing dirty).  It is counted as
+        `global.tick_idle`.  A lane planned just after the test waits
+        for the next tick, as one planned just after a pass always did.
+
+        A pending tick observes two phases: `global.sync_drain` (what
+        `_drain_then_lock` waits for: every in-flight batch's commit,
+        then both locks — serving-pipeline backpressure) and
+        `global.sync` (locks held: dispatch, blocking read-back,
+        decode/commit — the real recurring cost of a pass).
 
         Sets `last_sync_cost_s` to the `global.sync` reading.  The
         GlobalManager's window tuner reads this instead of its own wall
         clock: folding the drain into the window would inflate
         GlobalSyncWait ~10x under load (observed on the contended CPU
         host: wall-time syncs pinned the auto window at its 1s cap)."""
-        with phase("global.sync_drain") as drain:
-            self._drain_then_lock()
-            idle = not self.gtable.active_gslots() and not self.dirty.any()
-            if idle:
-                drain.name = "global.tick_idle"
-        try:
-            if idle:
+        if not self._global_pending:
+            with phase("global.tick_idle"):
                 return SyncResult(did_work=False)
+        with phase("global.sync_drain"):
+            self._drain_then_lock()
+        try:
             with phase("global.sync") as ph, telemetry.program("mesh:global_sync"):
                 res = self._sync_globals_locked(now_ms)
             # Only passes that ran the collective are valid sync-cost
@@ -1519,8 +1529,6 @@ class MeshBucketStore(ColumnarPipeline):
 
     def _sync_globals_locked(self, now_ms: int) -> "SyncResult":
         active = self.gtable.active_gslots()
-        if not active and not self.dirty.any():
-            return SyncResult(did_work=False)
 
         # Owner-slot resolution fast path: re-verifying every active
         # gslot's slot each pass is O(active) host work — at 50k-gslot
@@ -1665,6 +1673,7 @@ class MeshBucketStore(ColumnarPipeline):
         # shards untouched until the next sync verify nothing then.
         self._sync_gen = [getattr(t, "generation", None) for t in self.tables]
         self.dirty[:] = False
+        self._global_pending = False
         return result
 
     # ------------------------------------------------------------------
@@ -1756,9 +1765,9 @@ class MeshBucketStore(ColumnarPipeline):
         readiness gate as WaitForConnect (daemon.go:242-248).  Uses a
         reserved key with a 1ms duration so the slot recycles on the
         next eviction scan.  The request carries Behavior.GLOBAL so the
-        sync pass has an active gslot and actually dispatches the
-        collective program — a plain request would early-return before
-        compiling it."""
+        sync pass finds a GLOBAL lane pending and actually dispatches
+        the collective program — after a plain request it would return
+        idle before compiling it."""
         req = RateLimitRequest(
             name="__warmup__", unique_key="__warmup__", hits=0, limit=1,
             duration=1, behavior=Behavior.GLOBAL,

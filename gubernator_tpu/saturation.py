@@ -119,7 +119,7 @@ WATERFALL = (
     ("ingress.total", 0),     # whole-request wall time (GetRateLimits)
     ("global.sync_drain", 0),  # GLOBAL tick: pipeline drain + both locks
     ("global.sync", 0),       # GLOBAL tick, locks held: dispatch, read-back, commit
-    ("global.tick_idle", 0),  # the drain of a GLOBAL tick that had nothing to sync
+    ("global.tick_idle", 0),  # a GLOBAL tick with nothing pending: returns before drain and locks
 )
 PHASES = tuple(p for p, _ in WATERFALL)
 

@@ -15,12 +15,14 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# The dispatch pipeline and the GLOBAL tick, crossed by every path.
+# The dispatch pipeline and the GLOBAL tick, crossed by every path.  No cell
+# sends a GLOBAL lane, so every tick of the traced seconds is an idle one.
 PIPELINE = {
     "dispatch.prepare", "dispatch.plan_wait", "dispatch.stage", "dispatch.gate_wait",
     "dispatch.launch", "dispatch.launch_wait", "dispatch.fetch", "dispatch.commit",
-    "response.encode", "epoll.wait", "global.sync_drain", "global.sync",
+    "response.encode", "epoll.wait", "global.tick_idle",
 }
+SYNC_PASS = {"global.sync_drain", "global.sync"}
 CROSSED = {
     # One connection, GUBC frames: the native ingress lane.
     "v5e1-1m.frames": PIPELINE | {
@@ -30,7 +32,7 @@ CROSSED = {
 }
 METRICS = {
     "v5e1-1m.frames": {
-        "plan.lock_wait_ms", "launch.lock_wait_ms", "launch.sync_stall_ms",
+        "plan.lock_wait_ms", "launch.lock_wait_ms",
         "batcher.pump_ms_per_take", "edge.unattributed_ms_per_req",
         "device.idle_unattributed_share", "device.idle_no_request_share", "xla.program_load_s"},
     "v5e1-1m.singles": {
@@ -54,6 +56,8 @@ def test_traced_rehearsal_holds_the_phases_its_path_crosses(cell):
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["checks_ok"] is True
     assert METRICS[cell] <= set(line["metrics"]), sorted(line["metrics"])
+    # No pass ran between the snapshots, so the tick's stall has no reading.
+    assert "launch.sync_stall_ms" not in line["metrics"]
     assert line["metrics"]["device.idle_unattributed_share"]["value"] < 50.0
     named = {name for name, _ in line["breakdown"]["idle_gaps"]}
     assert any(n.startswith("host: dispatch.") or n == "host: epoll.wait" for n in named), named
@@ -66,3 +70,4 @@ def test_traced_rehearsal_holds_the_phases_its_path_crosses(cell):
             for ln in plane.lines:
                 found.update(ev.name for ev in ln.events)
     assert CROSSED[cell] <= found, sorted(CROSSED[cell] - found)
+    assert not SYNC_PASS & found, sorted(SYNC_PASS & found)

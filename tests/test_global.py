@@ -10,10 +10,17 @@ the in-process equivalent of waiting out GlobalSyncWait ticks as
 TestGlobalRateLimits does by polling metrics (functional_test.go:478-546).
 """
 
+import random
+import sys
+import threading
+import time
+
 import pytest
 
 from gubernator_tpu.parallel.mesh import MeshBucketStore, shard_of_key
-from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, Status
+from gubernator_tpu.types import (
+    Algorithm, Behavior, RateLimitRequest, RateLimitResponse, Status, UpdatePeerGlobal,
+)
 from gubernator_tpu.utils.clock import Clock
 
 T0 = 1_573_430_430_000
@@ -332,3 +339,130 @@ def test_sync_fast_path_steady_state_skips_verification():
     assert store.tables[owner].get_slot("glob_s1") == int(
         store.gtable.owner_slot[g]
     )
+
+
+def _count_calls(store):
+    """Count `_drain_then_lock` and `_sync_fn` calls on one store."""
+    calls = {"drain": 0, "sync_fn": 0}
+    drain, sync_fn = store._drain_then_lock, store._sync_fn
+
+    def counting_drain():
+        calls["drain"] += 1
+        return drain()
+
+    def counting_sync_fn(*args):
+        calls["sync_fn"] += 1
+        return sync_fn(*args)
+
+    store._drain_then_lock = counting_drain
+    store._sync_fn = counting_sync_fn
+    return calls
+
+
+@pytest.mark.parametrize("where", ["owner", "non_owner_home_shard", "remote_global"])
+def test_a_tick_runs_the_pass_only_when_a_global_lane_is_pending(where):
+    """A GLOBAL apply raises the store's pending flag on every branch
+    (owner dirt, non-owner ghits, remote-owner ghits); the next tick
+    runs the pass and lowers it; a tick with no apply in between
+    returns before the drain, the locks and the program."""
+    store = MeshBucketStore(capacity_per_shard=64, g_capacity=32)
+    owner, other = owner_and_other(store, "p1")
+    assert not store._global_pending
+    # A plain request never raises it.
+    store.apply([mk("plain", behavior=0)], T0, home_shard=owner)
+    assert not store._global_pending
+
+    kwargs = {
+        "owner": {"home_shard": owner},
+        "non_owner_home_shard": {"home_shard": other},
+        "remote_global": {"remote_global": True},
+    }[where]
+    r = store.apply([mk("p1", hits=4)], T0, **kwargs)[0]
+    assert r.remaining == 6
+    assert store._global_pending
+
+    calls = _count_calls(store)
+    res = store.sync_globals(T0 + 1)
+    assert calls == {"drain": 1, "sync_fn": 1}
+    assert res.did_work and not store._global_pending
+    if where == "remote_global":
+        # The host forwards the aggregated hits; nothing to broadcast.
+        assert res.broadcast_count == 0
+        (hit,) = res.remote_hits
+        assert (hit.unique_key, hit.hits) == ("p1", 4)
+    else:
+        assert res.remote_hit_cols is None
+        (b,) = res.broadcasts
+        assert b.key == "glob_p1" and b.status.remaining == 6
+
+    # Nothing applied since: the tick costs nothing and changes nothing.
+    rep_expire = store.gtable.rep_expire.copy()
+    res = store.sync_globals(T0 + 2)
+    assert res.did_work is False
+    assert res.broadcast_cols is None and res.remote_hit_cols is None
+    assert calls == {"drain": 1, "sync_fn": 1}
+    assert (store.gtable.rep_expire == rep_expire).all()
+
+    # A received broadcast writes the replica columns directly: no pass owed.
+    store.set_replica(
+        UpdatePeerGlobal(
+            key="glob_other", algorithm=Algorithm.TOKEN_BUCKET,
+            status=RateLimitResponse(limit=10, remaining=3, reset_time=T0 + 60_000),
+        ),
+        T0 + 2,
+    )
+    assert not store._global_pending
+
+
+def test_global_applies_racing_ticks_lose_no_hit():
+    """GLOBAL hits applied on every branch while 200 ticks run beside
+    them: a tick that reads the flag just before an apply raises it
+    leaves the hits to the next tick, never drops them."""
+    store = MeshBucketStore(capacity_per_shard=64, g_capacity=32)
+    owner, other = owner_and_other(store, "race")
+    limit = 1_000_000
+    sent = {"local": 0, "remote": 0}
+    stop = threading.Event()
+    errors = []
+    pauses = random.Random(26)
+
+    def applier():
+        i = 0
+        try:
+            while not stop.is_set():
+                home = owner if i % 2 else other
+                store.apply([mk("race", hits=1, limit=limit)], T0, home_shard=home)
+                sent["local"] += 1
+                store.apply([mk("far", hits=2, limit=limit)], T0, remote_global=True)
+                sent["remote"] += 2
+                i += 1
+                time.sleep(pauses.uniform(0.0, 0.01))  # bursts, and idle ticks between
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    results = []
+    t = threading.Thread(target=applier)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # hand the interpreter over mid-apply and mid-tick
+    t.start()
+    try:
+        for _ in range(200):
+            results.append(store.sync_globals(T0))
+            time.sleep(0.001)
+    finally:
+        stop.set()
+        t.join(timeout=60)
+        sys.setswitchinterval(switch)
+    assert not t.is_alive()
+    results.append(store.sync_globals(T0))  # what the last applies left pending
+    assert not errors, errors
+    assert not store._global_pending
+
+    passes = [r for r in results if r.did_work]
+    assert passes and len(passes) < len(results)  # ticks of both kinds raced
+    forwarded = sum(
+        int(r.remote_hit_cols.hits.sum()) for r in passes if r.remote_hit_cols is not None
+    )
+    assert sent["remote"] > 0 and forwarded == sent["remote"]
+    last = [b for r in passes for b in r.broadcasts if b.key == "glob_race"][-1]
+    assert sent["local"] > 0 and last.status.remaining == limit - sent["local"]

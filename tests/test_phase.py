@@ -9,6 +9,7 @@ from __future__ import annotations
 import glob
 import json
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -172,12 +173,95 @@ def test_an_idle_global_tick_is_counted_on_its_own():
     svc = V1Service(ServiceConfig(cache_size=512))
     try:
         svc.set_peers([PeerInfo(grpc_address="127.0.0.1:1", is_owner=True)])
+        svc.global_mgr._interval.stop()  # the ticks counted here are this test's own
         res = svc.store.sync_globals(svc.clock.now_ms())
         assert res.did_work is False
         assert _stats("global.tick_idle")["count"] == 1
         assert _stats("global.sync_drain") is None and _stats("global.sync") is None
+        # Warm-up runs the one pass that loads the sync program and leaves
+        # its `__warmup__` gslot active; the ticks after it are idle still.
+        svc.store.warmup(svc.clock.now_ms())
+        assert svc.store.gtable.active_gslots()
+        assert _stats("global.sync_drain")["count"] == 1
+        assert _stats("global.sync")["count"] == 1
+        res = svc.store.sync_globals(svc.clock.now_ms())
+        assert res.did_work is False
+        assert _stats("global.tick_idle")["count"] == 2
+        assert _stats("global.sync_drain")["count"] == 1
+        assert _stats("global.sync")["count"] == 1
     finally:
         svc.close()
+
+
+@pytest.mark.skipif(not native.available(), reason="native runtime unavailable")
+def test_a_daemon_without_global_traffic_runs_no_sync_pass_until_one_arrives():
+    """Plain frames across many ticks: every tick is `global.tick_idle`
+    and `mesh:global_sync` does not run.  One GLOBAL request: answered,
+    then exactly one pass on the next tick, then idle ticks again."""
+    behaviors = fast_test_behaviors()  # a tick every 50 ms
+    behaviors.native_ingress = True
+    d = Daemon(
+        DaemonConfig(
+            listen_address="127.0.0.1:0", grpc_listen_address="127.0.0.1:0",
+            cache_size=4096, global_cache_size=256, behaviors=behaviors,
+            peer_discovery_type="static", native_http=True,
+        ),
+    ).start()
+    base = f"http://{d.gateway.address}"
+
+    def fetch(path, data=None, content_type="application/json"):
+        req = urllib.request.Request(
+            base + path, data=data, headers={"Content-Type": content_type}
+        )
+        with urllib.request.urlopen(req, timeout=30) as resp:
+            return resp.read()
+
+    def count(name):
+        phases = json.loads(fetch("/debug/latency"))["phases"]
+        return (phases.get(name) or {"count": 0})["count"]
+
+    def passes():
+        """Runs of the sync program, and the two phases only a pass observes."""
+        runs = json.loads(fetch("/debug/device"))["programRuns"]["mesh:global_sync"]
+        return runs["count"], count("global.sync"), count("global.sync_drain")
+
+    n = 8
+    frame = wire.encode_ingress_frame((
+        ["plain"] * n, [f"k{i}" for i in range(n)],
+        np.zeros(n, np.int32), np.zeros(n, np.int32),
+        np.ones(n, np.int64), np.full(n, 100, np.int64),
+        np.full(n, 3_600_000, np.int64),
+    ))
+
+    def serve_frames_for_ticks(ticks):
+        until = count("global.tick_idle") + ticks
+        deadline = time.monotonic() + 30
+        while count("global.tick_idle") < until:
+            assert time.monotonic() < deadline, "the GLOBAL tick stopped"
+            fetch("/v1/GetRateLimits", frame, wire.COLUMNS_CONTENT_TYPE)
+
+    try:
+        d.set_peers([d.peer_info])
+        before = passes()
+        assert before[0] >= 1  # warm-up's own pass
+        serve_frames_for_ticks(6)
+        assert passes() == before
+
+        body = json.dumps({"requests": [{
+            "name": "g", "uniqueKey": "k", "hits": 3, "limit": 10,
+            "duration": 60_000, "behavior": "GLOBAL",
+        }]}).encode()
+        (ans,) = json.loads(fetch("/v1/GetRateLimits", body))["responses"]
+        assert int(ans["remaining"]) == 7 and not ans.get("error")
+        deadline = time.monotonic() + 30
+        while passes() == before:
+            assert time.monotonic() < deadline, "the GLOBAL request was never synced"
+            time.sleep(0.01)
+        serve_frames_for_ticks(6)
+        assert passes() == tuple(x + 1 for x in before)
+        assert not d.service.store._global_pending
+    finally:
+        d.close()
 
 
 def test_waterfall_lists_every_phase_once_and_nests_under_a_parent():
