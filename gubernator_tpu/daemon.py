@@ -115,8 +115,11 @@ class Daemon:
         # Compile the device programs BEFORE accepting traffic: a cold
         # first dispatch (an XLA compile: seconds on a CPU, most of a
         # minute per program on a TPU) would otherwise land inside a
-        # client's RPC deadline.
-        with telemetry.startup("warmup"):
+        # client's RPC deadline.  The GLOBAL manager, ticking since the
+        # service was built, is held off meanwhile: the pass that syncs
+        # warm-up's own GLOBAL key loads the sync program and must be
+        # warm-up's, not a tick's (see GlobalManager.tick_lock).
+        with telemetry.startup("warmup"), self.service.global_mgr.tick_lock:
             self.service.store.warmup(
                 self.clock.now_ms(), warm_shapes=self.conf.warmup_shapes
             )

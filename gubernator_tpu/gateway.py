@@ -314,6 +314,7 @@ def _handle_request(service: V1Service, method: str, path: str, raw: bytes,
                 # memory / live-buffer samples.
                 doc = telemetry.snapshot()
                 doc["devices"] = telemetry.device_snapshot()
+                doc["mesh"] = saturation.mesh_tally.snapshot()
                 return 200, "application/json", _json_bytes(doc)
             if qpath == "/debug/audit":
                 # Conservation audit (audit.py): ledger deltas +

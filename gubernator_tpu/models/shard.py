@@ -842,13 +842,21 @@ class ColumnarPipeline:
                     self._depth_hwm = max(self._depth_hwm, len(self._inflight))
             finally:
                 self._plan_lock.release()
-            ph.note(ticket=handle.ticket, lanes=prep.n)
+            # What the mesh adds: every shard pads to the fullest one's
+            # bucket, so one launch scatters `shards * prep.padded` lanes.
+            shards, fullest = self._shard_fill(prep)
+            padded = shards * prep.padded
+            ph.note(ticket=handle.ticket, lanes=prep.n, shards=shards,
+                    fullest=fullest, padded=padded)
         self._observe_stage("prepare", ph.dt_s)
         # Lane utilization: real lanes vs the pow2-padded shape the
-        # launch will scatter (saturation plane; drained per scrape).
-        saturation.lane_util.add(prep.n, self._padded_lanes(prep))
+        # launch will scatter (saturation plane; drained per scrape),
+        # and the same with the shards' fill, cumulative (/debug/device).
+        saturation.lane_util.add(prep.n, padded)
+        saturation.mesh_tally.add(shards, prep.n, padded, fullest, prep.n_rounds)
         try:
-            with phase("dispatch.stage", bt, ticket=handle.ticket) as ph:
+            with phase("dispatch.stage", bt, ticket=handle.ticket,
+                       shards=shards, fullest=fullest, padded=padded) as ph:
                 staged = (
                     self._stage_scalar(prep) if use_scalar
                     else self._stage_columns(prep)
@@ -964,10 +972,10 @@ class ColumnarPipeline:
         if exc is not None:
             raise exc
 
-    def _padded_lanes(self, prep) -> int:
-        """Total padded lanes one launch of `prep` scatters (the mesh
-        store overrides: its pad is per shard)."""
-        return prep.padded
+    def _shard_fill(self, prep) -> "Tuple[int, int]":
+        """(shards a take is split over, lanes on its fullest shard);
+        the mesh store overrides."""
+        return 1, prep.n
 
     # -- launch implementations (shared by ShardStore / MeshBucketStore)
     def _pre_launch(self) -> None:

@@ -28,7 +28,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 import threading
 from functools import partial
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -411,6 +411,7 @@ class _MeshPrep:
     now_ms: int
     force_wire: Optional[str]
     n: int
+    fullest: int  # lanes on the fullest shard: what `padded` pads
     padded: int
     n_rounds: int
     narrow: bool
@@ -692,7 +693,7 @@ class MeshBucketStore(ColumnarPipeline):
         runtime present, no synchronous Store SPI callbacks)."""
         return self._native and self.store is None
 
-    def describe_topology(self) -> "Tuple[str, str]":
+    def describe_topology(self) -> Tuple[str, str]:
         """(backend platform, mesh shape string) for the
         gubernator_build_info gauge: e.g. ("tpu", "8") for a flat
         8-device mesh."""
@@ -753,7 +754,8 @@ class MeshBucketStore(ColumnarPipeline):
 
         n = len(keys)
         mp = _native.NativeMeshPlanner(self.tables, keys, now_ms)
-        padded = pad_size(max(int(mp.counts.max()) if n else 1, 1))
+        fullest = int(mp.counts.max()) if n else 0
+        padded = pad_size(max(fullest, 1))
         n_rounds = mp.plan_grouped(
             cols, int(Behavior.RESET_REMAINING), padded
         )
@@ -776,7 +778,7 @@ class MeshBucketStore(ColumnarPipeline):
 
         return _MeshPrep(
             cols=cols, now_ms=now_ms, force_wire=force_wire, n=n,
-            padded=padded, n_rounds=n_rounds, narrow=narrow,
+            fullest=fullest, padded=padded, n_rounds=n_rounds, narrow=narrow,
             mp=mp, pos=pos, commit=commit,
         )
 
@@ -856,9 +858,8 @@ class MeshBucketStore(ColumnarPipeline):
             solo=lambda state: fn(state, batch, rid_dev, n_rounds, now_ms)
         )
 
-    def _padded_lanes(self, prep) -> int:
-        # Mesh pads PER SHARD: one launch scatters S * padded lanes.
-        return prep.padded * self.n_shards
+    def _shard_fill(self, prep) -> Tuple[int, int]:
+        return self.n_shards, prep.fullest
 
     def _pre_launch(self) -> None:
         # Tier moves queued by the group's plans (and any stale window)

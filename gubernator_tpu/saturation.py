@@ -308,6 +308,37 @@ class LaneUtil:
         return out
 
 
+class MeshTally:
+    """What the mesh adds to a dispatch, summed over every columnar
+    dispatch since the process started (cumulative: `GET /debug/device`
+    serves it as `mesh` and a reader takes differences).  A take pads to
+    the pad bucket of its FULLEST shard, so `fullest` against `lanes`
+    says how uneven the shards were and `lanes` against `padded` how
+    much of the launched shape was real."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._shards = 0
+        self._sums = dict.fromkeys(
+            ("dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds"), 0
+        )
+
+    def add(self, shards: int, lanes: int, padded: int, fullest: int,
+            rounds: int) -> None:
+        with self._lock:
+            self._shards = shards
+            s = self._sums
+            s["dispatches"] += 1
+            s["lanes"] += lanes
+            s["paddedLanes"] += padded
+            s["fullestShardLanes"] += fullest
+            s["rounds"] += rounds
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            return {"shards": self._shards, **self._sums}
+
+
 class BusyFraction:
     """Busy-seconds accumulator for the dispatcher (batch-window flush
     worker): take() returns (busy_s, elapsed_s) since the last take, so
@@ -413,6 +444,7 @@ class ExpressStats:
 
 
 lane_util = LaneUtil()
+mesh_tally = MeshTally()
 dispatcher_busy = BusyFraction()
 _queue_depths = _DepthRing()
 express = ExpressStats()
@@ -676,9 +708,10 @@ class HotKeySketch:
 # ---------------------------------------------------------------------
 def reset() -> None:
     """Test hook: clear every module-global reservoir/accumulator."""
-    global _phases, lane_util, dispatcher_busy, _queue_depths, express
+    global _phases, lane_util, mesh_tally, dispatcher_busy, _queue_depths, express
     _phases = {p: _PhaseStats() for p in PHASES}
     lane_util = LaneUtil()
+    mesh_tally = MeshTally()
     dispatcher_busy = BusyFraction()
     _queue_depths = _DepthRing()
     express = ExpressStats()

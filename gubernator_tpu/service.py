@@ -3317,12 +3317,20 @@ class GlobalManager:
         # Bounded fan-out pool, created on first use (idle daemons and
         # non-GLOBAL deployments spawn no threads).
         self._fanout_pool: "Optional[ThreadPoolExecutor]" = None
+        # Held around every tick.  A daemon holds it around its warm-up
+        # (daemon.py): the pass that syncs warm-up's own GLOBAL key loads
+        # or compiles the sync program, and a tick that makes that pass
+        # feeds the load to the tuner as its first "sync cost" (window
+        # 1 s, where warm-up's own call leaves the 0.1 s fall-back) and
+        # makes the start seconds longer: two ways for a daemon to start.
+        self.tick_lock = threading.Lock()
         self._interval = Interval(self.sync_wait_s, self._tick)
         self._interval.next()
 
     def _tick(self) -> None:
         try:
-            did_work = self.run_once()
+            with self.tick_lock:
+                did_work = self.run_once()
             if did_work and self._auto and self._last_sync_cost_s is not None:
                 self._observe_sync_cost(self._last_sync_cost_s)
         finally:

@@ -21,7 +21,7 @@ from gubernator_tpu.config import DaemonConfig
 from gubernator_tpu.daemon import Daemon
 from gubernator_tpu.saturation import phase
 from gubernator_tpu.service import ServiceConfig, V1Service
-from gubernator_tpu.types import PeerInfo
+from gubernator_tpu.types import Behavior, PeerInfo, RateLimitRequest
 from gubernator_tpu.utils.clock import Clock
 
 
@@ -262,6 +262,60 @@ def test_a_daemon_without_global_traffic_runs_no_sync_pass_until_one_arrives():
         assert not d.service.store._global_pending
     finally:
         d.close()
+
+
+def test_a_daemons_start_runs_one_sync_pass_and_feeds_the_tuner_nothing():
+    """Warm-up syncs its own GLOBAL key; the manager, ticking since the
+    service was built, is held off meanwhile.  So a start runs ONE pass, on
+    the starting thread, and the tuner's window stays at its fall-back: the
+    tick no longer races warm-up for the pass that loads the sync program."""
+    behaviors = fast_test_behaviors()
+    behaviors.global_sync_wait_s = None  # the auto-tuned window, as a daemon's
+    d = Daemon(
+        DaemonConfig(
+            listen_address="127.0.0.1:0", grpc_listen_address="127.0.0.1:0",
+            cache_size=4096, global_cache_size=256, behaviors=behaviors,
+            peer_discovery_type="static", warmup_shapes=[],
+        ),
+    ).start()
+    try:
+        mgr = d.service.global_mgr
+        deadline = time.monotonic() + 30
+        while (_stats("global.tick_idle") or {"count": 0})["count"] < 3:
+            assert time.monotonic() < deadline, "the GLOBAL tick never came back"
+            time.sleep(0.02)
+        assert _stats("global.sync")["count"] == 1
+        assert mgr.measured_sync_cost_s is None
+        assert mgr.sync_wait_s == mgr.SYNC_WAIT_FALLBACK_S
+    finally:
+        d.close()
+
+
+def test_a_held_tick_lock_keeps_the_manager_from_the_pass_until_released():
+    clock = Clock()
+    clock.freeze(1_790_000_000_000)
+    behaviors = fast_test_behaviors()  # a tick every 50 ms
+    svc = V1Service(ServiceConfig(
+        cache_size=256, global_cache_size=64, behaviors=behaviors, clock=clock,
+    ))
+    try:
+        svc.set_peers([PeerInfo(grpc_address="127.0.0.1:1", is_owner=True)])
+        req = RateLimitRequest(
+            name="g", unique_key="held", hits=1, limit=10, duration=60_000,
+            behavior=Behavior.GLOBAL,
+        )
+        with svc.global_mgr.tick_lock:
+            svc.store.apply([req], clock.now_ms())
+            assert svc.store._global_pending
+            time.sleep(0.3)  # six tick periods
+            assert _stats("global.sync") is None
+        deadline = time.monotonic() + 30
+        while _stats("global.sync") is None:
+            assert time.monotonic() < deadline, "the released tick never ran the pass"
+            time.sleep(0.01)
+        assert not svc.store._global_pending
+    finally:
+        svc.close()
 
 
 def test_waterfall_lists_every_phase_once_and_nests_under_a_parent():
