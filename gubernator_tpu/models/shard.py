@@ -785,6 +785,11 @@ class ColumnarPipeline:
                 "capacity": int(t.capacity),
                 "evictions": int(t.evictions),
             }
+            if hasattr(t, "index_stats"):
+                # The native table's key index: lookups, probes,
+                # refused hash hits, entries (probes a lookup is the
+                # chain length a key pays).
+                row["index"] = t.index_stats
             if back_cap:
                 # tier_stats: (total, back_keys, demotions, promotions,
                 # back_evictions).
@@ -831,7 +836,8 @@ class ColumnarPipeline:
                 self._plan_lock.acquire()
             try:
                 prep = self._prepare_columns(
-                    keys, cols, now_ms, "wide" if use_scalar else force_wire
+                    keys, cols, now_ms, "wide" if use_scalar else force_wire,
+                    bt,
                 )
                 handle = ColumnsHandle(self, prep.commit, cols.limit, cols.hits)
                 handle._trace = bt
@@ -1268,7 +1274,8 @@ class ShardStore(ColumnarPipeline):
         return r["status"], r["remaining"], r["reset_time"]
 
     def _prepare_columns(self, keys: List[str], cols: "_Columns", now_ms: int,
-                         force_wire: Optional[str] = None) -> "_ShardPrep":
+                         force_wire: Optional[str] = None,
+                         bt=None) -> "_ShardPrep":
         """Stage 1 (under `_plan_lock`): everything that touches the
         slot table — the C++ grouped plan, the pass-through expiry
         snapshot — plus the cheap padded plan-column scatters.  No
@@ -1276,10 +1283,11 @@ class ShardStore(ColumnarPipeline):
         batch N+1's planning starts the moment batch N's plan is done,
         regardless of where batch N is in its flight."""
         n = len(keys)
-        planner = native.NativeBatchPlanner(self.table, keys, now_ms)
-        round_id, slots, exists, occ, write, n_rounds = planner.plan_grouped(
-            cols, int(Behavior.RESET_REMAINING)
-        )
+        with phase("dispatch.plan_native", bt):
+            planner = native.NativeBatchPlanner(self.table, keys, now_ms)
+            round_id, slots, exists, occ, write, n_rounds = planner.plan_grouped(
+                cols, int(Behavior.RESET_REMAINING)
+            )
         padded = pad_size(n)
         slot_col = np.full(padded, -1, dtype=np.int32)
         slot_col[:n] = slots

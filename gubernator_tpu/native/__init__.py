@@ -128,10 +128,13 @@ def _build() -> Optional[ctypes.CDLL]:
     c = ctypes
     lib.gt_table_new.restype = c.c_void_p
     lib.gt_table_new.argtypes = [c.c_int64]
+    lib.gt_table_new_hashed.restype = c.c_void_p
+    lib.gt_table_new_hashed.argtypes = [c.c_int64, c.c_uint64, c.c_uint64]
     lib.gt_table_free.argtypes = [c.c_void_p]
     lib.gt_table_len.restype = c.c_int64
     lib.gt_table_len.argtypes = [c.c_void_p]
     lib.gt_table_stats.argtypes = [c.c_void_p, c.POINTER(c.c_int64)]
+    lib.gt_table_index_stats.argtypes = [c.c_void_p, c.POINTER(c.c_int64)]
     lib.gt_table_evictions.restype = c.c_int64
     lib.gt_table_evictions.argtypes = [c.c_void_p]
     lib.gt_table_generation.restype = c.c_uint64
@@ -547,7 +550,10 @@ class NativeSlotTable:
     expiry (cache.go:138-163), LRU eviction at capacity (cache.go:115-130).
     """
 
-    def __init__(self, capacity: int):
+    def __init__(self, capacity: int, _hash_bits: Optional[Tuple[int, int]] = None):
+        """`_hash_bits` is for tests alone: (and, or) masks over every
+        key's index hash, so that keys collide in the index on purpose
+        (gt_table_new_hashed).  No caller in the package passes it."""
         if capacity <= 0:
             raise ValueError("capacity must be positive")
         lib = _get_lib()
@@ -555,7 +561,10 @@ class NativeSlotTable:
             raise RuntimeError(_lib_err or "native runtime unavailable")
         self._lib = lib
         self.capacity = capacity
-        self._ptr = lib.gt_table_new(capacity)
+        if _hash_bits is None:
+            self._ptr = lib.gt_table_new(capacity)
+        else:
+            self._ptr = lib.gt_table_new_hashed(capacity, *_hash_bits)
 
     def __del__(self):
         ptr = getattr(self, "_ptr", None)
@@ -580,6 +589,18 @@ class NativeSlotTable:
     @property
     def misses(self) -> int:
         return self._stats[1]
+
+    @property
+    def index_stats(self) -> dict:
+        """The key index's health since the table was made: `lookups`,
+        `probes` (index entries inspected for them; probes a lookup is
+        the chain length a key pays), `refused` (entries whose hash bits
+        matched and whose key did not) and `entries` (index size).  A
+        degenerate key set shows here as a number, not as a slow plan."""
+        out = (ctypes.c_int64 * 4)()
+        self._lib.gt_table_index_stats(self._ptr, out)
+        return dict(zip(("lookups", "probes", "refused", "entries"),
+                        (int(v) for v in out)))
 
     @property
     def generation(self) -> int:

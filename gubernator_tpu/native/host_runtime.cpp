@@ -11,25 +11,43 @@
 // chasing that Python does 50-100x slower than C++ — this module exists
 // so the device kernel, not the host, is the bottleneck.
 //
+// Shape of the table (Table below): ONE 64-byte record a slot (SlotRec:
+// key bytes, expiry, pending-write count, LRU links, mapped flag — what
+// a lookup, a touch, a commit and an eviction read for a slot is one
+// cache line) and ONE flat open-addressing index (KeyIndex: 8-byte
+// entries of 32 hash bits + slot) keyed by the FNV-1a 64 of the key
+// bytes that the mesh planner already computes for shard routing.  A
+// hit on the hash bits is always confirmed against the record's own key
+// bytes.  A frame's keys are resolved together (gt_batch_plan_grouped):
+// index line and record of the keys ahead are prefetched while the
+// current key's LRU touch runs, so the misses overlap instead of
+// queueing behind one another.
+//
 // Exposed as a plain C ABI for ctypes (no pybind11 in this image).
 // Thread-safety contract: each Table carries its own recursive mutex,
-// taken by every extern-C entry that touches it.  This is what lets the
-// overlapped dispatch pipeline run batch N+1's PLANNING concurrently
-// with batch N's in-flight DECODE/COMMIT (models/shard.py
-// ColumnarPipeline): the two stages hold different Python locks, and
-// ctypes releases the GIL for the call's duration, so without internal
-// locking they would race on the same hash map.  Interleaving at call
-// granularity is safe by the same argument as pipelined planning
-// itself — a plan that runs before an older batch's commit observes
-// expiry lagging by the unresolved depth (revalidated device-side),
-// and pending_write refcounts keep in-flight slots uneviction-able.
-// Cross-batch ORDERING is the Python tier's job (plan-order tickets +
-// the FIFO drain); this mutex only makes each call atomic.
+// taken by every extern-C entry that touches it.  It guards every
+// member of the Table: the records, both indexes and their counters,
+// the free list, the LRU ends, the move queues and the plan scratch
+// (which is why a plan can keep its scratch on the table and reuse it).
+// This is what lets the overlapped dispatch pipeline run batch N+1's
+// PLANNING concurrently with batch N's in-flight DECODE/COMMIT
+// (models/shard.py ColumnarPipeline): the two stages hold different
+// Python locks, and ctypes releases the GIL for the call's duration, so
+// without internal locking they would race on the same index.
+// Interleaving at call granularity is safe by the same argument as
+// pipelined planning itself — a plan that runs before an older batch's
+// commit observes expiry lagging by the unresolved depth (revalidated
+// device-side), and pending_write refcounts keep in-flight slots
+// uneviction-able.  Cross-batch ORDERING is the Python tier's job
+// (plan-order tickets + the FIFO drain); this mutex only makes each
+// call atomic.
 
 #include <algorithm>
 #include <cstdint>
+#include <cstdlib>
 #include <cstring>
 #include <mutex>
+#include <random>
 #include <string>
 #include <string_view>
 #include <unordered_map>
@@ -39,7 +57,8 @@ namespace {
 
 // FNV-1 / FNV-1a 64: the shard-routing hash (replicated_hash.go:31).
 // Single definitions shared by gt_fnv1_batch and the mesh planner so
-// shard routing cannot diverge between the two.
+// shard routing cannot diverge between the two.  The 1a value of a key
+// is also what the slot table's index is keyed by (KeyIndex::mix).
 inline uint64_t fnv1a64(const char* p, const char* end) {
   uint64_t h = 14695981039346656037ull;
   for (; p < end; ++p) {
@@ -58,27 +77,171 @@ inline uint64_t fnv1_64(const char* p, const char* end) {
   return h;
 }
 
+// Everything the table keeps for one slot, in one cache line.  `hash`
+// is the key's plain FNV-1a 64 (the index re-mixes it when an erase
+// has to re-home a neighbour).  Keys up to kInline bytes live in the
+// record; a longer key is one heap block the record owns.
+struct alignas(64) SlotRec {
+  static constexpr uint32_t kInline = 24;
+  int64_t expire_ms = 0;
+  uint64_t hash = 0;
+  // LRU intrusive list over slots; -1 = null.
+  int32_t lru_prev = -1, lru_next = -1;
+  // In-flight (planned, not yet committed) device writes.  While >0 the
+  // device row is fresher than expire_ms, so liveness is
+  // device-authoritative — the pipelined twin of the planner's chained
+  // lanes (see gt_batch_plan).  Nonzero only between a columnar batch's
+  // plan and its commit.
+  int32_t pending_write = 0;
+  // Two-tier: index into mv_promo_* of a queued-but-undrained promotion
+  // (-1 none).  The row is not on device yet, so eviction must prefer
+  // other slots and, if forced, CANCEL the record.
+  int32_t pending_promo = -1;
+  uint32_t key_len = 0;
+  uint8_t mapped = 0;  // 0 = free (no key)
+  union {
+    char inl[kInline];
+    char* heap;
+  } key;
+
+  const char* key_ptr() const { return key_len <= kInline ? key.inl : key.heap; }
+  bool key_is(const char* p, size_t len) const {
+    return mapped && key_len == len && std::memcmp(key_ptr(), p, len) == 0;
+  }
+  void clear_key() {
+    if (key_len > kInline) std::free(key.heap);
+    key_len = 0;
+  }
+  void set_key(const char* p, size_t len) {
+    clear_key();
+    if (len > kInline) {
+      key.heap = (char*)std::malloc(len);
+      std::memcpy(key.heap, p, len);
+    } else {
+      std::memcpy(key.inl, p, len);
+    }
+    key_len = (uint32_t)len;
+  }
+};
+static_assert(sizeof(SlotRec) == 64, "one record a cache line");
+
+// Open-addressing index key -> slot: linear probing over 8-byte entries
+// ((32 hash bits) << 32 | slot + 1; 0 = empty), at most half full, and
+// erased by shifting the chain back (no tombstones, so a chain's length
+// is a function of what is resident, not of history).  The index knows
+// no key bytes: a caller confirms a hash-bit hit against the slot's own
+// key (`eq`), always, so two keys that agree in all 64 bits are still
+// two entries.  The position comes from the low bits of the mixed hash
+// and the stored bits from the high 32, so the two are independent.
+struct KeyIndex {
+  std::vector<uint64_t> ent;
+  uint64_t mask = 0;
+  // Drawn at start: where a key lands is not a constant of the build.
+  uint64_t seed = 0;
+  // Test-only (gt_table_new_hashed): force keys onto the same hash bits.
+  uint64_t h_and = ~0ull, h_or = 0;
+  // Health, served a shard by occupancy_stats(): resolutions, entries
+  // inspected for them, and hash-bit hits the key compare refused.
+  int64_t lookups = 0, probes = 0, refused = 0;
+
+  void init(int64_t cap) {
+    size_t n = 8;
+    while (n < (size_t)cap * 2) n <<= 1;
+    ent.assign(n, 0);
+    mask = n - 1;
+    std::random_device rd;
+    seed = ((uint64_t)rd() << 32) | rd();
+  }
+
+  uint64_t mix(uint64_t h) const {
+    h ^= seed;
+    h *= 0xff51afd7ed558ccdull;
+    h ^= h >> 33;
+    h *= 0xc4ceb9fe1a85ec53ull;
+    h ^= h >> 33;
+    return (h & h_and) | h_or;
+  }
+
+  static uint64_t entry(uint64_t mh, int32_t slot) {
+    return (mh & 0xffffffff00000000ull) | (uint32_t)(slot + 1);
+  }
+  static int32_t slot_of(uint64_t e) { return (int32_t)(uint32_t)e - 1; }
+
+  void prefetch(uint64_t mh) const { __builtin_prefetch(&ent[mh & mask]); }
+
+  // The slot of the first entry of mh's chain with mh's hash bits, or
+  // -1: the batched pass's probe (the caller confirms the key later,
+  // when the record it prefetches has arrived, and walks on refusal).
+  int32_t probe_bits(uint64_t mh) {
+    ++lookups;
+    for (uint64_t p = mh & mask;; p = (p + 1) & mask) {
+      ++probes;
+      uint64_t e = ent[p];
+      if (e == 0) return -1;
+      if ((e ^ mh) >> 32 == 0) return slot_of(e);
+    }
+  }
+
+  // Walk mh's chain for the entry whose slot `eq` accepts; -1 if none.
+  template <class Eq>
+  int32_t walk(uint64_t mh, Eq eq) {
+    for (uint64_t p = mh & mask;; p = (p + 1) & mask) {
+      ++probes;
+      uint64_t e = ent[p];
+      if (e == 0) return -1;
+      if ((e ^ mh) >> 32 != 0) continue;
+      int32_t s = slot_of(e);
+      if (eq(s)) return s;
+      ++refused;
+    }
+  }
+
+  template <class Eq>
+  int32_t find(uint64_t mh, Eq eq) {
+    ++lookups;
+    return walk(mh, eq);
+  }
+
+  // The caller has established that the key is absent.
+  void insert(uint64_t mh, int32_t slot) {
+    uint64_t p = mh & mask;
+    while (ent[p] != 0) p = (p + 1) & mask;
+    ent[p] = entry(mh, slot);
+  }
+
+  // Remove `slot`'s entry and close the gap: each later entry of the
+  // run moves back unless that would put it before its home position
+  // (`mixed_of(slot)` gives a resident slot's mixed hash).
+  template <class MixedOf>
+  void erase(uint64_t mh, int32_t slot, MixedOf mixed_of) {
+    uint64_t want = entry(mh, slot);
+    uint64_t i = mh & mask;
+    while (ent[i] != want) {
+      if (ent[i] == 0) return;  // not indexed: nothing to close
+      i = (i + 1) & mask;
+    }
+    for (uint64_t j = (i + 1) & mask; ent[j] != 0; j = (j + 1) & mask) {
+      uint64_t home = mixed_of(slot_of(ent[j])) & mask;
+      if (((j - home) & mask) >= ((j - i) & mask)) {
+        ent[i] = ent[j];
+        i = j;
+      }
+    }
+    ent[i] = 0;
+  }
+};
+
 struct Table {
   // Guards every member below against concurrent extern-C calls
   // (recursive: gt_mesh_* entries call gt_batch_* entries on the same
   // table).  See the thread-safety contract at the top of the file.
   std::recursive_mutex mu;
   int64_t capacity;
-  // slot -> key (empty string + mapped=false when free)
-  std::vector<std::string> slot_key;
-  std::vector<uint8_t> slot_mapped;
-  std::vector<int64_t> expire_ms;
-  // In-flight (planned, not yet committed) device writes per slot.
-  // While >0 the device row is fresher than expire_ms, so liveness is
-  // device-authoritative — the pipelined twin of the planner's chained
-  // lanes (see gt_batch_plan).  Nonzero only between a columnar batch's
-  // plan and its commit.
-  std::vector<int32_t> pending_write;
-  // LRU intrusive list over slots; head = least recent. -1 = null.
-  std::vector<int32_t> lru_prev, lru_next;
-  int32_t lru_head = -1, lru_tail = -1;
+  std::vector<SlotRec> recs;  // slot -> its record (mapped = 0 when free)
+  KeyIndex index;             // key -> front slot
+  int64_t size = 0;           // mapped front slots
+  int32_t lru_head = -1, lru_tail = -1;  // head = least recent
   std::vector<int32_t> free_slots;  // stack, top = back
-  std::unordered_map<std::string, int32_t> key_to_slot;
   int64_t hits = 0, misses = 0, evictions = 0;
   // Bumped on every key->front-slot MAPPING change (assign, remap,
   // evict, remove).  NOT bumped by in-place expiry reuse (same key,
@@ -100,8 +263,9 @@ struct Table {
   // cursor) — only then is bucket state truly lost, matching the
   // reference's plain LRU loss semantics at total capacity.
   int64_t back_capacity = 0;
-  std::unordered_map<std::string, int32_t> key_to_back;
+  KeyIndex back_index;                // key -> back slot (same index type)
   std::vector<std::string> back_key;  // back slot -> key
+  std::vector<uint64_t> back_hash;    // ... and its plain FNV-1a 64
   std::vector<uint8_t> back_mapped;
   std::vector<int64_t> back_expire;
   int64_t back_clock = 0;  // FIFO allocation cursor
@@ -115,36 +279,52 @@ struct Table {
   std::vector<int32_t> mv_demo_src, mv_demo_dst;
   // back slot -> index into mv_demo (this window) for cycle rewrite
   std::unordered_map<int32_t, int32_t> pending_demo_by_back;
-  // per front slot: index into mv_promo_* of a queued-but-undrained
-  // promotion (-1 none).  The row is not on device yet, so eviction
-  // must prefer other slots and, if forced, CANCEL the record.
-  std::vector<int32_t> pending_promo;
 
-  explicit Table(int64_t cap)
-      : capacity(cap),
-        slot_key(cap),
-        slot_mapped(cap, 0),
-        expire_ms(cap, 0),
-        pending_write(cap, 0),
-        lru_prev(cap, -1),
-        lru_next(cap, -1),
-        pending_promo(cap, -1) {
+  // ---- grouped-plan scratch (gt_batch_plan_grouped) -----------------
+  // Kept here and reused so a dispatch allocates nothing a lane: the
+  // flat group table over the frame's 64-bit hashes, the CSR of groups,
+  // and the per-group probe results of the prefetched pass.
+  struct PlanScratch {
+    std::vector<int32_t> gtab, gid, gfirst, gcount, goff, gmembers, cursor,
+        cand, slow, r0;
+    std::vector<uint64_t> mh;
+  } scratch;
+
+  explicit Table(int64_t cap) : capacity(cap), recs((size_t)cap) {
+    index.init(cap);
     free_slots.reserve(cap);
     for (int64_t i = cap - 1; i >= 0; --i) free_slots.push_back((int32_t)i);
-    key_to_slot.reserve((size_t)cap * 2);
+  }
+
+  ~Table() {
+    for (SlotRec& r : recs) r.clear_key();
+  }
+
+  uint64_t mixed_of_slot(int32_t s) const { return index.mix(recs[s].hash); }
+
+  void index_erase(int32_t s) {
+    index.erase(mixed_of_slot(s), s,
+                [this](int32_t o) { return mixed_of_slot(o); });
+  }
+
+  int32_t find_slot(const char* key, size_t len, uint64_t h) {
+    return index.find(index.mix(h), [&](int32_t s) {
+      return recs[s].key_is(key, len);
+    });
   }
 
   void lru_unlink(int32_t s) {
-    int32_t p = lru_prev[s], n = lru_next[s];
-    if (p >= 0) lru_next[p] = n; else if (lru_head == s) lru_head = n;
-    if (n >= 0) lru_prev[n] = p; else if (lru_tail == s) lru_tail = p;
-    lru_prev[s] = lru_next[s] = -1;
+    SlotRec& r = recs[s];
+    int32_t p = r.lru_prev, n = r.lru_next;
+    if (p >= 0) recs[p].lru_next = n; else if (lru_head == s) lru_head = n;
+    if (n >= 0) recs[n].lru_prev = p; else if (lru_tail == s) lru_tail = p;
+    r.lru_prev = r.lru_next = -1;
   }
 
   void lru_push_back(int32_t s) {  // most recently used
-    lru_prev[s] = lru_tail;
-    lru_next[s] = -1;
-    if (lru_tail >= 0) lru_next[lru_tail] = s;
+    recs[s].lru_prev = lru_tail;
+    recs[s].lru_next = -1;
+    if (lru_tail >= 0) recs[lru_tail].lru_next = s;
     lru_tail = s;
     if (lru_head < 0) lru_head = s;
   }
@@ -156,11 +336,13 @@ struct Table {
   }
 
   void unmap_slot(int32_t s) {
-    if (!slot_mapped[s]) return;
-    key_to_slot.erase(slot_key[s]);
-    slot_key[s].clear();
-    slot_mapped[s] = 0;
-    expire_ms[s] = 0;
+    SlotRec& r = recs[s];
+    if (!r.mapped) return;
+    index_erase(s);
+    r.clear_key();
+    r.mapped = 0;
+    r.expire_ms = 0;
+    --size;
     lru_unlink(s);
     free_slots.push_back(s);
     ++map_generation;
@@ -168,15 +350,27 @@ struct Table {
 
   void enable_back(int64_t cap) {
     back_capacity = cap;
+    back_index.init(cap);
+    back_index.h_and = index.h_and;
+    back_index.h_or = index.h_or;
     back_key.resize(cap);
+    back_hash.assign(cap, 0);
     back_mapped.assign(cap, 0);
     back_expire.assign(cap, 0);
-    key_to_back.reserve((size_t)cap * 2);
+  }
+
+  int32_t find_back(const char* key, size_t len, uint64_t h) {
+    return back_index.find(back_index.mix(h), [&](int32_t b) {
+      return back_mapped[b] && back_key[b].size() == len &&
+             std::memcmp(back_key[b].data(), key, len) == 0;
+    });
   }
 
   void unmap_back(int32_t b) {
     if (!back_mapped[b]) return;
-    key_to_back.erase(back_key[b]);
+    back_index.erase(back_index.mix(back_hash[b]), b, [this](int32_t o) {
+      return back_index.mix(back_hash[o]);
+    });
     back_key[b].clear();
     back_mapped[b] = 0;
     back_expire[b] = 0;
@@ -194,11 +388,11 @@ struct Table {
     }
   }
 
-  // A back slot mid-promotion: lookup_or_assign resolves the promo
-  // source BEFORE allocating the front slot, and that allocation's
-  // eviction can demote another key — alloc_back must not wrap the
-  // FIFO cursor onto the in-flight source, or the promoted key would
-  // adopt the victim's row (found by round-4 review, repro'd with
+  // A back slot mid-promotion: assign() resolves the promo source
+  // BEFORE allocating the front slot, and that allocation's eviction
+  // can demote another key — alloc_back must not wrap the FIFO cursor
+  // onto the in-flight source, or the promoted key would adopt the
+  // victim's row (found by round-4 review, repro'd with
   // front=1/back=1).
   int32_t promo_in_flight = -1;
 
@@ -206,7 +400,7 @@ struct Table {
   // two-tier design's only true state loss).  Returns -1 when no slot
   // is usable (back_capacity==1 and that slot is mid-promotion): the
   // caller drops the row instead of demoting.
-  int32_t alloc_back(const std::string& key) {
+  int32_t alloc_back(const char* key, size_t len, uint64_t h) {
     int32_t b = (int32_t)(back_clock % back_capacity);
     ++back_clock;
     if (b == promo_in_flight) {
@@ -220,9 +414,10 @@ struct Table {
       ++evictions;
     }
     cancel_pending_demo(b);
-    back_key[b] = key;
+    back_key[b].assign(key, len);
+    back_hash[b] = h;
     back_mapped[b] = 1;
-    key_to_back.emplace(key, b);
+    back_index.insert(back_index.mix(h), b);
     ++back_size;
     return b;
   }
@@ -233,9 +428,10 @@ struct Table {
   // back slot.
   void evict_front(int32_t s, int64_t now_ms) {
     lru_unlink(s);
-    const std::string k = std::move(slot_key[s]);
-    key_to_slot.erase(k);
-    slot_mapped[s] = 0;
+    SlotRec& r = recs[s];
+    index_erase(s);
+    r.mapped = 0;
+    --size;
     // Demotion preserves state ONLY when the device row at s really is
     // this key's current state.  Under the all-pending starvation
     // fallback the chosen slot may have (a) a queued promotion whose
@@ -245,15 +441,15 @@ struct Table {
     // batch write (pending_write) — the row is mid-air, drop.  Both
     // degrade to the documented reference-grade loss, never to serving
     // another key's counters.
-    if (pending_promo[s] >= 0) {
-      mv_promo_src[(size_t)pending_promo[s]] = -1;  // device no-op
-      pending_promo[s] = -1;
+    if (r.pending_promo >= 0) {
+      mv_promo_src[(size_t)r.pending_promo] = -1;  // device no-op
+      r.pending_promo = -1;
       ++back_evictions;  // the promoted state is lost
-    } else if (back_capacity > 0 && pending_write[s] == 0 &&
-               expire_ms[s] >= now_ms) {
-      int32_t b = alloc_back(k);
+    } else if (back_capacity > 0 && r.pending_write == 0 &&
+               r.expire_ms >= now_ms) {
+      int32_t b = alloc_back(r.key_ptr(), r.key_len, r.hash);
       if (b >= 0) {
-        back_expire[b] = expire_ms[s];
+        back_expire[b] = r.expire_ms;
         pending_demo_by_back[b] = (int32_t)mv_demo_src.size();
         mv_demo_src.push_back(s);
         mv_demo_dst.push_back(b);
@@ -262,8 +458,21 @@ struct Table {
         ++back_evictions;  // degenerate: nowhere to park the row
       }
     }
-    expire_ms[s] = 0;
+    r.clear_key();
+    r.expire_ms = 0;
     ++evictions;
+    ++map_generation;
+  }
+
+  // Give free slot s to `key` (hash h) as the most recently used.
+  void map_slot(int32_t s, const char* key, size_t len, uint64_t h) {
+    SlotRec& r = recs[s];
+    r.set_key(key, len);
+    r.hash = h;
+    r.mapped = 1;
+    ++size;
+    index.insert(index.mix(h), s);
+    lru_push_back(s);
     ++map_generation;
   }
 
@@ -272,12 +481,9 @@ struct Table {
   // device).  Returns false when the key is meanwhile mapped elsewhere.
   // Negative expire is the narrow-wire keep-sentinel; an unmapped slot
   // has no prior value to keep, so it clamps to 0 (already expired).
-  bool remap(int32_t s, const char* key, size_t len, int64_t expire) {
-    std::string k(key, len);
-    if (!key_to_slot.emplace(k, s).second) return false;
-    slot_key[s] = std::move(k);
-    slot_mapped[s] = 1;
-    expire_ms[s] = expire >= 0 ? expire : 0;
+  bool remap(int32_t s, const char* key, size_t len, uint64_t h,
+             int64_t expire) {
+    if (find_slot(key, len, h) >= 0) return false;
     for (size_t j = free_slots.size(); j > 0; --j) {
       if (free_slots[j - 1] == s) {
         free_slots[j - 1] = free_slots.back();
@@ -285,42 +491,37 @@ struct Table {
         break;
       }
     }
-    lru_push_back(s);
-    ++map_generation;
+    map_slot(s, key, len, h);
+    recs[s].expire_ms = expire >= 0 ? expire : 0;
     return true;
   }
 
-  // (slot, exists): exists=false means kernel treats as fresh create.
-  // Mirrors slot_table.py::lookup_or_assign, except for pipelining
-  // state the Python twin does not model: pending_write liveness and
-  // pending-aware eviction only matter between a columnar batch's plan
-  // and commit, and the pipelined path requires the native runtime —
-  // the Python twin never observes in-flight writes, so the twins agree
-  // on every state the Python table can reach.
-  std::pair<int32_t, bool> lookup_or_assign(const char* key, size_t len,
-                                            int64_t now_ms) {
-    std::string k(key, len);
-    auto it = key_to_slot.find(k);
-    if (it != key_to_slot.end()) {
-      int32_t s = it->second;
-      touch(s);
-      // Strict expiry (cache.go:151); an uncommitted in-flight write
-      // makes the device row authoritative regardless of the stale
-      // host expire (pipelined batches — the kernel revalidates).
-      if (expire_ms[s] >= now_ms || pending_write[s] > 0) {
-        ++hits;
-        return {s, true};
-      }
-      ++misses;  // expired: recycle same slot in place
-      return {s, false};
+  // A lookup that found the key at front slot s.
+  std::pair<int32_t, bool> hit(int32_t s, int64_t now_ms) {
+    touch(s);
+    // Strict expiry (cache.go:151); an uncommitted in-flight write
+    // makes the device row authoritative regardless of the stale
+    // host expire (pipelined batches — the kernel revalidates).
+    if (recs[s].expire_ms >= now_ms || recs[s].pending_write > 0) {
+      ++hits;
+      return {s, true};
     }
+    ++misses;  // expired: recycle same slot in place
+    return {s, false};
+  }
+
+  // A lookup that found the key in no front slot: promotion from the
+  // back tier, a free slot, or an eviction.  Order-dependent (which
+  // slot is free, who is the LRU victim), so a frame's misses take this
+  // path one at a time, in request order.
+  std::pair<int32_t, bool> assign(const char* key, size_t len, uint64_t h,
+                                  int64_t now_ms) {
     // Two-tier: a live row demoted to the back tier promotes (a
     // logical cache hit — the state survives the round trip).
     int32_t promo_b = -1;
     if (back_capacity > 0) {
-      auto itb = key_to_back.find(k);
-      if (itb != key_to_back.end()) {
-        int32_t b = itb->second;
+      int32_t b = find_back(key, len, h);
+      if (b >= 0) {
         if (back_expire[b] >= now_ms) {
           promo_b = b;
         } else {
@@ -351,15 +552,15 @@ struct Table {
       // head (pending promo: evict_front cancels the record — loss,
       // never corruption).
       s = -1;
-      for (int32_t cand = lru_head; cand >= 0; cand = lru_next[cand]) {
-        if (pending_write[cand] == 0 && pending_promo[cand] < 0) {
+      for (int32_t cand = lru_head; cand >= 0; cand = recs[cand].lru_next) {
+        if (recs[cand].pending_write == 0 && recs[cand].pending_promo < 0) {
           s = cand;
           break;
         }
       }
       if (s < 0) {
-        for (int32_t cand = lru_head; cand >= 0; cand = lru_next[cand]) {
-          if (pending_promo[cand] < 0) {
+        for (int32_t cand = lru_head; cand >= 0; cand = recs[cand].lru_next) {
+          if (recs[cand].pending_promo < 0) {
             s = cand;
             break;
           }
@@ -368,13 +569,9 @@ struct Table {
       if (s < 0) s = lru_head;
       evict_front(s, now_ms);
     }
-    key_to_slot.emplace(std::move(k), s);
-    slot_key[s].assign(key, len);
-    slot_mapped[s] = 1;
-    lru_push_back(s);
-    ++map_generation;
+    map_slot(s, key, len, h);
     if (promo_b >= 0) {
-      expire_ms[s] = back_expire[promo_b];
+      recs[s].expire_ms = back_expire[promo_b];
       // Queue the device move.  A demo still pending for this back
       // slot (same drain window) means the row never left the front
       // table — copy front->front (kind 1) instead of reading the
@@ -391,15 +588,29 @@ struct Table {
         mv_promo_src.push_back(promo_b);
       }
       mv_promo_dst.push_back(s);
-      pending_promo[s] = (int32_t)mv_promo_dst.size() - 1;
+      recs[s].pending_promo = (int32_t)mv_promo_dst.size() - 1;
       unmap_back(promo_b);
       promo_in_flight = -1;
       ++promotions;
       return {s, true};
     }
     promo_in_flight = -1;
-    expire_ms[s] = 0;
+    recs[s].expire_ms = 0;
     return {s, false};
+  }
+
+  // (slot, exists): exists=false means kernel treats as fresh create.
+  // Mirrors slot_table.py::lookup_or_assign, except for pipelining
+  // state the Python twin does not model: pending_write liveness and
+  // pending-aware eviction only matter between a columnar batch's plan
+  // and commit, and the pipelined path requires the native runtime —
+  // the Python twin never observes in-flight writes, so the twins agree
+  // on every state the Python table can reach.  `h` is the key's plain
+  // FNV-1a 64.
+  std::pair<int32_t, bool> lookup_or_assign(const char* key, size_t len,
+                                            uint64_t h, int64_t now_ms) {
+    int32_t s = find_slot(key, len, h);
+    return s >= 0 ? hit(s, now_ms) : assign(key, len, h, now_ms);
   }
 };
 
@@ -407,6 +618,10 @@ struct Batch {
   Table* table;
   const char* keys;        // concatenated key bytes (borrowed)
   const int64_t* offsets;  // n+1 offsets into keys (borrowed)
+  // FNV-1a 64 of every key: the mesh planner's (borrowed; it hashed
+  // them for shard routing) or, from gt_batch_begin, the batch's own.
+  const uint64_t* hashes;
+  std::vector<uint64_t> own_hashes;
   int64_t n;
   int64_t now_ms;
   // Lanes not yet scheduled, in request order (per-key order is what
@@ -423,15 +638,33 @@ struct Batch {
   // rounds, consumed by gt_batch_commit_plan
   std::vector<int32_t> plan_order;
 
-  Batch(Table* t, const char* k, const int64_t* off, int64_t n_, int64_t now)
-      : table(t), keys(k), offsets(off), n(n_), now_ms(now),
+  Batch(Table* t, const char* k, const int64_t* off, int64_t n_, int64_t now,
+        const uint64_t* hs)
+      : table(t), keys(k), offsets(off), hashes(hs), n(n_), now_ms(now),
         slot(n_, -1), exists(n_, 0), resolved(n_, 0) {
+    if (hashes == nullptr) {
+      own_hashes.resize((size_t)n_);
+      for (int64_t i = 0; i < n_; ++i)
+        own_hashes[(size_t)i] = fnv1a64(k + off[i], k + off[i + 1]);
+      hashes = own_hashes.data();
+    }
     pending.reserve(n_);
     for (int64_t i = 0; i < n_; ++i) pending.push_back((int32_t)i);
   }
 
   const char* key_ptr(int64_t i) const { return keys + offsets[i]; }
   size_t key_len(int64_t i) const { return (size_t)(offsets[i + 1] - offsets[i]); }
+  std::string_view key_view(int64_t i) const { return {key_ptr(i), key_len(i)}; }
+
+  void resolve(int64_t i) {
+    auto [s, e] = table->lookup_or_assign(key_ptr(i), key_len(i), hashes[i], now_ms);
+    slot[i] = s;
+    exists[i] = e ? 1 : 0;
+  }
+  // Does front slot s still map lane i's key?
+  bool owns(int64_t i, int32_t s) const {
+    return table->recs[s].key_is(key_ptr(i), key_len(i));
+  }
 };
 
 // Per-table lock for the extern-C surface (see the thread-safety
@@ -443,16 +676,36 @@ struct Batch {
 extern "C" {
 
 void* gt_table_new(int64_t capacity) { return new Table(capacity); }
+
+// Test-only: a table whose index keeps `h_and` of a key's mixed hash
+// and sets `h_or` (0, 0: every key on the same hash bits; ~7, 7: every
+// chain starts at position 7).  The daemon never calls it.
+void* gt_table_new_hashed(int64_t capacity, uint64_t h_and, uint64_t h_or) {
+  Table* t = new Table(capacity);
+  t->index.h_and = h_and;
+  t->index.h_or = h_or;
+  return t;
+}
+
 void gt_table_free(void* t) { delete (Table*)t; }
 int64_t gt_table_len(void* t) {
   GT_LOCK((Table*)t);
-  return (int64_t)((Table*)t)->key_to_slot.size();
+  return ((Table*)t)->size;
 }
 
 void gt_table_stats(void* tv, int64_t* out) {  // hits, misses, evictions
   Table* t = (Table*)tv;
   GT_LOCK(t);
   out[0] = t->hits; out[1] = t->misses; out[2] = t->evictions;
+}
+
+// The front index's health: lookups, probes (entries inspected for
+// them), hash-bit hits the key compare refused, entries allocated.
+void gt_table_index_stats(void* tv, int64_t* out) {
+  Table* t = (Table*)tv;
+  GT_LOCK(t);
+  out[0] = t->index.lookups; out[1] = t->index.probes;
+  out[2] = t->index.refused; out[3] = (int64_t)t->index.ent.size();
 }
 
 // Single-counter read: plan_grouped_python polls this around every
@@ -474,8 +727,7 @@ uint64_t gt_table_generation(void* tv) {
 int32_t gt_table_get_slot(void* tv, const char* key, int64_t len) {
   Table* t = (Table*)tv;
   GT_LOCK(t);
-  auto it = t->key_to_slot.find(std::string(key, (size_t)len));
-  return it == t->key_to_slot.end() ? -1 : it->second;
+  return t->find_slot(key, (size_t)len, fnv1a64(key, key + len));
 }
 
 // Single-key resolve (Store-SPI path drives lookups one at a time).
@@ -483,7 +735,8 @@ void gt_table_lookup_or_assign(void* tv, const char* key, int64_t len,
                                int64_t now_ms, int32_t* out_slot,
                                uint8_t* out_exists) {
   GT_LOCK((Table*)tv);
-  auto [s, e] = ((Table*)tv)->lookup_or_assign(key, (size_t)len, now_ms);
+  auto [s, e] = ((Table*)tv)->lookup_or_assign(
+      key, (size_t)len, fnv1a64(key, key + len), now_ms);
   *out_slot = s;
   *out_exists = e ? 1 : 0;
 }
@@ -491,14 +744,14 @@ void gt_table_lookup_or_assign(void* tv, const char* key, int64_t len,
 void gt_table_remove(void* tv, const char* key, int64_t len) {
   Table* t = (Table*)tv;
   GT_LOCK(t);
-  std::string k(key, (size_t)len);
-  auto it = t->key_to_slot.find(k);
-  if (it != t->key_to_slot.end()) t->unmap_slot(it->second);
+  uint64_t h = fnv1a64(key, key + len);
+  int32_t s = t->find_slot(key, (size_t)len, h);
+  if (s >= 0) t->unmap_slot(s);
   if (t->back_capacity > 0) {
-    auto itb = t->key_to_back.find(k);
-    if (itb != t->key_to_back.end()) {
-      t->cancel_pending_demo(itb->second);
-      t->unmap_back(itb->second);
+    int32_t b = t->find_back(key, (size_t)len, h);
+    if (b >= 0) {
+      t->cancel_pending_demo(b);
+      t->unmap_back(b);
     }
   }
 }
@@ -515,7 +768,7 @@ void gt_table_enable_back(void* tv, int64_t back_capacity) {
 void gt_table_tier_stats(void* tv, int64_t* out) {
   Table* t = (Table*)tv;
   GT_LOCK(t);
-  out[0] = (int64_t)t->key_to_slot.size() + t->back_size;
+  out[0] = t->size + t->back_size;
   out[1] = t->back_size;
   out[2] = t->demotions;
   out[3] = t->promotions;
@@ -549,7 +802,7 @@ void gt_table_take_moves(void* tv, int32_t* promo_kind, int32_t* promo_src,
               t->mv_demo_src.size() * sizeof(int32_t));
   std::memcpy(demo_dst, t->mv_demo_dst.data(),
               t->mv_demo_dst.size() * sizeof(int32_t));
-  for (int32_t s : t->mv_promo_dst) t->pending_promo[s] = -1;
+  for (int32_t s : t->mv_promo_dst) t->recs[s].pending_promo = -1;
   t->mv_promo_kind.clear();
   t->mv_promo_src.clear();
   t->mv_promo_dst.clear();
@@ -560,13 +813,15 @@ void gt_table_take_moves(void* tv, int32_t* promo_kind, int32_t* promo_src,
 
 // Snapshot protocol for the back tier (Loader.Save needs every live
 // item): gt_table_back_size for buffer sizing, then gt_table_back_keys
-// fills (back_slots, expire, offsets[count+1], key bytes).
+// fills (back_slots, expire, offsets[count+1], key bytes), in back-slot
+// order.
 void gt_table_back_size(void* tv, int64_t* count, int64_t* total_bytes) {
   Table* t = (Table*)tv;
   GT_LOCK(t);
   *count = t->back_size;
   int64_t bytes = 0;
-  for (auto& kv : t->key_to_back) bytes += (int64_t)kv.first.size();
+  for (int64_t b = 0; b < t->back_capacity; ++b)
+    if (t->back_mapped[b]) bytes += (int64_t)t->back_key[b].size();
   *total_bytes = bytes;
 }
 
@@ -575,12 +830,13 @@ void gt_table_back_keys(void* tv, int32_t* slots, int64_t* expire,
   Table* t = (Table*)tv;
   GT_LOCK(t);
   int64_t i = 0, off = 0;
-  for (auto& kv : t->key_to_back) {
-    slots[i] = kv.second;
-    expire[i] = t->back_expire[kv.second];
+  for (int64_t b = 0; b < t->back_capacity; ++b) {
+    if (!t->back_mapped[b]) continue;
+    slots[i] = (int32_t)b;
+    expire[i] = t->back_expire[b];
     offsets[i] = off;
-    std::memcpy(bytes + off, kv.first.data(), kv.first.size());
-    off += (int64_t)kv.first.size();
+    std::memcpy(bytes + off, t->back_key[b].data(), t->back_key[b].size());
+    off += (int64_t)t->back_key[b].size();
     ++i;
   }
   offsets[i] = off;
@@ -588,7 +844,7 @@ void gt_table_back_keys(void* tv, int32_t* slots, int64_t* expire,
 
 void gt_table_set_expire(void* tv, int32_t slot, int64_t expire) {
   GT_LOCK((Table*)tv);
-  ((Table*)tv)->expire_ms[slot] = expire;
+  ((Table*)tv)->recs[slot].expire_ms = expire;
 }
 
 // Bulk expiry read for the narrow-wire keep-sentinel decode: lanes
@@ -599,7 +855,7 @@ void gt_table_get_expire(void* tv, const int32_t* slots, int64_t n,
   Table* t = (Table*)tv;
   GT_LOCK(t);
   for (int64_t i = 0; i < n; ++i)
-    out[i] = (slots[i] >= 0 && slots[i] < t->capacity) ? t->expire_ms[slots[i]] : 0;
+    out[i] = (slots[i] >= 0 && slots[i] < t->capacity) ? t->recs[slots[i]].expire_ms : 0;
 }
 
 // Fold kernel outputs back (slot_table.py::commit): slots<0 skipped.
@@ -611,7 +867,7 @@ void gt_table_commit(void* tv, const int32_t* slots, const int64_t* expire,
     int32_t s = slots[i];
     if (s < 0) continue;
     if (removed[i]) t->unmap_slot(s);
-    else t->expire_ms[s] = expire[i];
+    else t->recs[s].expire_ms = expire[i];
   }
 }
 
@@ -629,26 +885,29 @@ void gt_table_commit_keys(void* tv, const int32_t* slots,
   for (int64_t i = 0; i < n; ++i) {
     int32_t s = slots[i];
     if (s < 0) continue;
+    const char* key = keys + offsets[i];
     size_t len = (size_t)(offsets[i + 1] - offsets[i]);
-    if (!t->slot_mapped[s]) {
-      if (!removed[i]) t->remap(s, keys + offsets[i], len, expire[i]);
+    SlotRec& r = t->recs[s];
+    if (!r.mapped) {
+      if (!removed[i]) t->remap(s, key, len, fnv1a64(key, key + len), expire[i]);
       continue;
     }
-    if (t->slot_key[s].compare(0, std::string::npos, keys + offsets[i], len) != 0)
+    if (!r.key_is(key, len))
       continue;  // slot remapped mid-batch; this lane is stale
     if (removed[i]) t->unmap_slot(s);
-    else t->expire_ms[s] = expire[i];
+    else r.expire_ms = expire[i];
   }
 }
 
 // Snapshot protocol: first call gt_table_keys_size for total bytes, then
-// gt_table_keys to fill (slots, offsets[count+1], bytes).
+// gt_table_keys to fill (slots, offsets[count+1], bytes), in slot order.
 void gt_table_keys_size(void* tv, int64_t* count, int64_t* total_bytes) {
   Table* t = (Table*)tv;
   GT_LOCK(t);
-  *count = (int64_t)t->key_to_slot.size();
+  *count = t->size;
   int64_t bytes = 0;
-  for (auto& kv : t->key_to_slot) bytes += (int64_t)kv.first.size();
+  for (const SlotRec& r : t->recs)
+    if (r.mapped) bytes += (int64_t)r.key_len;
   *total_bytes = bytes;
 }
 
@@ -656,11 +915,13 @@ void gt_table_keys(void* tv, int32_t* slots, int64_t* offsets, char* bytes) {
   Table* t = (Table*)tv;
   GT_LOCK(t);
   int64_t i = 0, off = 0;
-  for (auto& kv : t->key_to_slot) {
-    slots[i] = kv.second;
+  for (int64_t s = 0; s < t->capacity; ++s) {
+    const SlotRec& r = t->recs[(size_t)s];
+    if (!r.mapped) continue;
+    slots[i] = (int32_t)s;
     offsets[i] = off;
-    std::memcpy(bytes + off, kv.first.data(), kv.first.size());
-    off += (int64_t)kv.first.size();
+    std::memcpy(bytes + off, r.key_ptr(), r.key_len);
+    off += (int64_t)r.key_len;
     ++i;
   }
   offsets[i] = off;
@@ -668,7 +929,7 @@ void gt_table_keys(void* tv, int32_t* slots, int64_t* offsets, char* bytes) {
 
 void* gt_batch_begin(void* tv, const char* keys, const int64_t* offsets,
                      int64_t n, int64_t now_ms) {
-  return new Batch((Table*)tv, keys, offsets, n, now_ms);
+  return new Batch((Table*)tv, keys, offsets, n, now_ms, nullptr);
 }
 
 // Emit the next round: walk the pending lanes in request order, taking
@@ -685,7 +946,7 @@ int64_t gt_batch_next_round(void* bv, int32_t* lane_idx, int32_t* slots,
   Table* t = b->table;
   GT_LOCK(t);
   if (b->pending.empty()) return 0;
-  std::unordered_map<std::string, int> seen_keys;
+  std::unordered_map<std::string_view, int> seen_keys;
   std::unordered_map<int32_t, int> used_slots;
   seen_keys.reserve(b->pending.size() * 2);
   used_slots.reserve(b->pending.size() * 2);
@@ -693,27 +954,25 @@ int64_t gt_batch_next_round(void* bv, int32_t* lane_idx, int32_t* slots,
   std::vector<int32_t> deferred;
   int64_t m = 0;
   for (int32_t i : b->pending) {
-    std::string k(b->key_ptr(i), b->key_len(i));
+    std::string_view k = b->key_view(i);
     if (seen_keys.count(k)) {  // duplicate: must see this round's commit
       deferred.push_back(i);
       continue;
     }
     if (!b->resolved[i]) {
-      auto [s, e] = t->lookup_or_assign(b->key_ptr(i), b->key_len(i), b->now_ms);
-      b->slot[i] = s;
-      b->exists[i] = e ? 1 : 0;
+      b->resolve(i);
       b->resolved[i] = 1;
     }
     if (used_slots.count(b->slot[i])) {  // eviction collision: defer as-is
       deferred.push_back(i);
-      seen_keys.emplace(std::move(k), 1);  // later same-key lanes defer too
+      seen_keys.emplace(k, 1);  // later same-key lanes defer too
       continue;
     }
     lane_idx[m] = i;
     slots[m] = b->slot[i];
     exists[m] = b->exists[i];
     b->round_lane.push_back(i);
-    seen_keys.emplace(std::move(k), 1);
+    seen_keys.emplace(k, 1);
     used_slots.emplace(b->slot[i], 1);
     ++m;
   }
@@ -733,12 +992,9 @@ void gt_batch_commit_round(void* bv, const int64_t* new_expire,
     if (s < 0) continue;
     // Staleness guard (slot_table.py::commit keys check): only commit
     // if the slot still maps this lane's key.
-    if (!t->slot_mapped[s] ||
-        t->slot_key[s].compare(0, std::string::npos, b->key_ptr(i),
-                               b->key_len(i)) != 0)
-      continue;
+    if (!b->owns(i, s)) continue;
     if (removed[j]) t->unmap_slot(s);
-    else t->expire_ms[s] = new_expire[j];
+    else t->recs[s].expire_ms = new_expire[j];
   }
 }
 
@@ -777,15 +1033,13 @@ static int64_t plan_rounds(Batch* b, int64_t round, int32_t* round_id,
     used_slots.reserve(b->pending.size() * 2);
     std::vector<int32_t> deferred;
     for (int32_t i : b->pending) {
-      std::string_view k(b->key_ptr(i), b->key_len(i));
+      std::string_view k = b->key_view(i);
       if (seen_keys.count(k)) {
         deferred.push_back(i);
         continue;
       }
       if (!b->resolved[i]) {
-        auto [s, e] = t->lookup_or_assign(b->key_ptr(i), b->key_len(i), b->now_ms);
-        b->slot[i] = s;
-        b->exists[i] = e ? 1 : 0;
+        b->resolve(i);
         b->resolved[i] = 1;
       }
       // Slot takeover: a DIFFERENT key's create (mid-batch eviction)
@@ -793,11 +1047,7 @@ static int64_t plan_rounds(Batch* b, int64_t round, int32_t* round_id,
       // here would corrupt the new owner's device state.  Re-resolve:
       // this key is no longer mapped, so it gets a fresh slot.
       auto so = slot_owner.find(b->slot[i]);
-      if (so != slot_owner.end() && so->second != k) {
-        auto [s, e] = t->lookup_or_assign(b->key_ptr(i), b->key_len(i), b->now_ms);
-        b->slot[i] = s;
-        b->exists[i] = e ? 1 : 0;
-      }
+      if (so != slot_owner.end() && so->second != k) b->resolve(i);
       if (used_slots.count(b->slot[i])) {  // eviction collision: defer as-is
         deferred.push_back(i);
         seen_keys.emplace(k, 1);
@@ -812,7 +1062,7 @@ static int64_t plan_rounds(Batch* b, int64_t round, int32_t* round_id,
                       ? 1  // chained: device state authoritative
                       : b->exists[i];
       b->plan_order.push_back(i);
-      ++t->pending_write[b->slot[i]];
+      ++t->recs[b->slot[i]].pending_write;
       seen_keys.emplace(k, 1);
       slot_owner[b->slot[i]] = k;
       used_slots.emplace(b->slot[i], 1);
@@ -848,13 +1098,19 @@ void gt_batch_commit_plan(void* bv, const int64_t* new_expire,
   Table* t = b->table;
   GT_LOCK(t);
   b->committed = true;
-  for (int32_t i : b->plan_order) {
+  const size_t m = b->plan_order.size();
+  constexpr size_t kAhead = 8;  // records of the lanes ahead, on their way
+  for (size_t j = 0; j < m; ++j) {
+    if (j + kAhead < m) {
+      int32_t sa = b->slot[b->plan_order[j + kAhead]];
+      if (sa >= 0) __builtin_prefetch(&t->recs[sa]);
+    }
+    int32_t i = b->plan_order[j];
     int32_t s = b->slot[i];
     if (s < 0) continue;
-    if (t->pending_write[s] > 0) --t->pending_write[s];
-    bool mine = t->slot_mapped[s] &&
-                t->slot_key[s].compare(0, std::string::npos, b->key_ptr(i),
-                                       b->key_len(i)) == 0;
+    SlotRec& r = t->recs[s];
+    if (r.pending_write > 0) --r.pending_write;
+    bool mine = b->owns(i, s);
     if (removed[i]) {
       if (mine) t->unmap_slot(s);
       continue;
@@ -863,9 +1119,9 @@ void gt_batch_commit_plan(void* bv, const int64_t* new_expire,
       // Negative expire is the narrow-wire "unchanged" sentinel
       // (ops/buckets.py unpack_output32): the kernel passed the slot's
       // pre-batch expiry through, so the host value is already right.
-      if (new_expire[i] >= 0) t->expire_ms[s] = new_expire[i];
-    } else if (!t->slot_mapped[s]) {
-      t->remap(s, b->key_ptr(i), b->key_len(i), new_expire[i]);
+      if (new_expire[i] >= 0) r.expire_ms = new_expire[i];
+    } else if (!r.mapped) {
+      t->remap(s, b->key_ptr(i), b->key_len(i), b->hashes[i], new_expire[i]);
     }
   }
 }
@@ -888,6 +1144,17 @@ void gt_batch_commit_plan(void* bv, const int64_t* new_expire,
 // within a uniform group; 0 otherwise), write (1 when this lane's lane
 // scatters state: the last occurrence of a uniform group, or every
 // round-scheme lane).  Returns the round count.
+//
+// The frame's distinct keys are resolved in ONE prefetched pass, at
+// every width (a batch of 4 runs the same loop as one of 4096): while
+// group g takes its LRU touch and liveness test, group g+3D's index
+// line, group g+2D's slot record (the hash-bit probe names it) and
+// group g+D's LRU neighbours are on their way from memory.  What the
+// probe saw may be stale by the time its group's turn comes (an earlier
+// group's miss evicted the key): the record's own key bytes decide,
+// and a refusal re-walks the chain.  A key the probe found ABSENT stays
+// absent until its own turn — within a plan only a key's own group
+// inserts it — so it goes straight to Table::assign, in request order.
 int64_t gt_batch_plan_grouped(void* bv, const int32_t* algo,
                               const int32_t* behavior, const int64_t* hits,
                               const int64_t* limit, const int64_t* duration,
@@ -898,54 +1165,78 @@ int64_t gt_batch_plan_grouped(void* bv, const int32_t* algo,
   Batch* b = (Batch*)bv;
   Table* t = b->table;
   GT_LOCK(t);
+  const int64_t n = b->n;
   b->plan_order.clear();
-  b->plan_order.reserve((size_t)b->n);
+  b->plan_order.reserve((size_t)n);
 
-  // Group lanes by key, preserving first-appearance order.  Keys view
-  // the borrowed packed buffer — no per-lane allocation — and members
-  // live in a flat CSR layout (gid pass -> counting sort) instead of
-  // one heap-allocated vector per group: at service batch sizes the
-  // planner runs once per dispatch over tens of thousands of MOSTLY
-  // UNIQUE keys, where per-group vectors cost one malloc per lane and
-  // dominated the whole plan (native-service-loop profiling, PR 13).
-  std::unordered_map<std::string_view, int32_t> group_of;
-  group_of.reserve((size_t)b->n * 2);
-  std::vector<int32_t> gid((size_t)b->n);
-  std::vector<int32_t> gcount;
-  gcount.reserve((size_t)b->n);
-  int32_t n_groups = 0;
-  for (int64_t i = 0; i < b->n; ++i) {
-    std::string_view k(b->key_ptr(i), b->key_len(i));
-    auto [it, fresh] = group_of.emplace(k, n_groups);
-    if (fresh) {
-      ++n_groups;
-      gcount.push_back(0);
+  // Group lanes by key, preserving first-appearance order, in a flat
+  // table over the hashes the batch carries (a hash match is confirmed
+  // against the first member's key bytes in the borrowed buffer), and
+  // lay the members out as a CSR (gid pass -> counting sort): no node
+  // and no vector a group.  Every array is the table's scratch.
+  Table::PlanScratch& sc = t->scratch;
+  size_t gsz = 8;
+  int gshift = 61;  // 64 - log2(gsz)
+  while (gsz < (size_t)n * 2) { gsz <<= 1; --gshift; }
+  const size_t gmask = gsz - 1;
+  sc.gtab.assign(gsz, -1);
+  sc.gid.resize((size_t)n);
+  sc.gfirst.clear();
+  sc.gcount.clear();
+  for (int64_t i = 0; i < n; ++i) {
+    const uint64_t h = b->hashes[i];
+    size_t p = (size_t)((h * 0x9e3779b97f4a7c15ull) >> gshift);
+    int32_t g;
+    while ((g = sc.gtab[p]) >= 0) {
+      int32_t f = sc.gfirst[(size_t)g];
+      if (b->hashes[f] == h && b->key_view(f) == b->key_view(i)) break;
+      p = (p + 1) & gmask;
     }
-    gid[(size_t)i] = it->second;
-    ++gcount[(size_t)it->second];
+    if (g < 0) {
+      g = (int32_t)sc.gfirst.size();
+      sc.gtab[p] = g;
+      sc.gfirst.push_back((int32_t)i);
+      sc.gcount.push_back(0);
+    }
+    sc.gid[(size_t)i] = g;
+    ++sc.gcount[(size_t)g];
   }
+  const int64_t n_groups = (int64_t)sc.gfirst.size();
   // CSR offsets + member fill (members of one group stay in request
   // order — the occurrence index below depends on it).
-  std::vector<int32_t> goff((size_t)n_groups + 1);
-  goff[0] = 0;
-  for (int32_t g = 0; g < n_groups; ++g) goff[(size_t)g + 1] = goff[(size_t)g] + gcount[(size_t)g];
-  std::vector<int32_t> gmembers((size_t)b->n);
-  {
-    std::vector<int32_t> cursor(goff.begin(), goff.end() - 1);
-    for (int64_t i = 0; i < b->n; ++i)
-      gmembers[(size_t)cursor[(size_t)gid[(size_t)i]]++] = (int32_t)i;
-  }
+  sc.goff.resize((size_t)n_groups + 1);
+  sc.goff[0] = 0;
+  for (int64_t g = 0; g < n_groups; ++g) sc.goff[(size_t)g + 1] = sc.goff[(size_t)g] + sc.gcount[(size_t)g];
+  sc.gmembers.resize((size_t)n);
+  sc.cursor.assign(sc.goff.begin(), sc.goff.end() - 1);
+  for (int64_t i = 0; i < n; ++i)
+    sc.gmembers[(size_t)sc.cursor[(size_t)sc.gid[(size_t)i]]++] = (int32_t)i;
 
-  std::unordered_map<int32_t, int> used0;  // slots written in round 0
-  used0.reserve((size_t)n_groups * 2);
-  // Seed the slot-owner map with round-0 groups so slow lanes detect
-  // takeovers of (and chain onto) grouped slots.
-  std::unordered_map<int32_t, std::string_view> slot_owner;
-  slot_owner.reserve((size_t)b->n * 2);
-  std::vector<int32_t> slow;  // lanes for the round scheme
-  for (int32_t g = 0; g < n_groups; ++g) {
-    const int32_t* mem = gmembers.data() + goff[(size_t)g];
-    size_t g_size = (size_t)(goff[(size_t)g + 1] - goff[(size_t)g]);
+  sc.mh.resize((size_t)n_groups);
+  sc.cand.resize((size_t)n_groups);
+  // Round-0 groups' first lanes: all a slow lane needs to learn who
+  // owns a round-0 slot, and nothing reads it before one exists.
+  sc.r0.clear();
+  sc.slow.clear();  // lanes for the round scheme
+  constexpr int64_t D = 16;  // groups between the stages of the pass
+  for (int64_t g = -3 * D; g < n_groups; ++g) {
+    if (int64_t a = g + 3 * D; a < n_groups) {
+      sc.mh[(size_t)a] = t->index.mix(b->hashes[sc.gfirst[(size_t)a]]);
+      t->index.prefetch(sc.mh[(size_t)a]);
+    }
+    if (int64_t p = g + 2 * D; p >= 0 && p < n_groups) {
+      int32_t c = sc.cand[(size_t)p] = t->index.probe_bits(sc.mh[(size_t)p]);
+      if (c >= 0) __builtin_prefetch(&t->recs[c]);
+    }
+    if (int64_t q = g + D; q >= 0 && q < n_groups && sc.cand[(size_t)q] >= 0) {
+      const SlotRec& r = t->recs[sc.cand[(size_t)q]];
+      if (r.lru_prev >= 0) __builtin_prefetch(&t->recs[r.lru_prev]);
+      if (r.lru_next >= 0) __builtin_prefetch(&t->recs[r.lru_next]);
+    }
+    if (g < 0) continue;
+
+    const int32_t* mem = sc.gmembers.data() + sc.goff[(size_t)g];
+    size_t g_size = (size_t)(sc.goff[(size_t)g + 1] - sc.goff[(size_t)g]);
     int32_t first = mem[0];
     bool uniform = (behavior[first] & reset_mask) == 0;
     for (size_t j = 1; uniform && j < g_size; ++j) {
@@ -955,9 +1246,15 @@ int64_t gt_batch_plan_grouped(void* bv, const int32_t* algo,
                 duration[i] == duration[first] &&
                 greg_e[i] == greg_e[first] && greg_d[i] == greg_d[first];
     }
+    const char* key = b->key_ptr(first);
+    const size_t len = b->key_len(first);
+    const uint64_t h = b->hashes[first];
     int64_t ev_before = t->evictions;
-    auto [s, e] =
-        t->lookup_or_assign(b->key_ptr(first), b->key_len(first), b->now_ms);
+    int32_t c = sc.cand[(size_t)g];
+    if (c >= 0 && !t->recs[c].key_is(key, len))
+      c = t->index.walk(sc.mh[(size_t)g],
+                        [&](int32_t o) { return t->recs[o].key_is(key, len); });
+    auto [s, e] = c >= 0 ? t->hit(c, b->now_ms) : t->assign(key, len, h, b->now_ms);
     b->slot[first] = s;
     b->exists[first] = e ? 1 : 0;
     b->resolved[first] = 1;
@@ -965,11 +1262,12 @@ int64_t gt_batch_plan_grouped(void* bv, const int32_t* algo,
     // lanes in this batch; scheduling this group in round 0 would run
     // the create before the victim's lanes.  Demote to the slow path,
     // whose per-round slot-collision deferral orders it correctly.
+    // (Without an eviction s cannot already carry a round-0 group: a
+    // hit's slot maps this key and no other, and a free slot maps none.)
     bool evicted = t->evictions != ev_before;
-    if (uniform && !evicted && !used0.count(s)) {
-      used0.emplace(s, 1);
-      slot_owner[s] = std::string_view(b->key_ptr(first), b->key_len(first));
-      ++t->pending_write[s];
+    if (uniform && !evicted) {
+      sc.r0.push_back(first);
+      ++t->recs[s].pending_write;
       for (size_t j = 0; j < g_size; ++j) {
         int32_t i = mem[j];
         round_id[i] = 0;
@@ -981,15 +1279,20 @@ int64_t gt_batch_plan_grouped(void* bv, const int32_t* algo,
         if (write[i]) b->plan_order.push_back(i);
       }
     } else {
-      for (size_t j = 0; j < g_size; ++j) slow.push_back(mem[j]);
+      for (size_t j = 0; j < g_size; ++j) sc.slow.push_back(mem[j]);
     }
   }
-  if (slow.empty()) return 1;
+  if (sc.slow.empty()) return 1;
 
   // Round scheme for the leftovers, starting at round 1 (round 0 is the
   // grouped dispatch).  Same chaining/deferral rules as gt_batch_plan.
-  std::sort(slow.begin(), slow.end());
-  b->pending.assign(slow.begin(), slow.end());
+  // Seed the slot-owner map with round-0 groups so slow lanes detect
+  // takeovers of (and chain onto) grouped slots.
+  std::unordered_map<int32_t, std::string_view> slot_owner;
+  slot_owner.reserve((size_t)n * 2);
+  for (int32_t f : sc.r0) slot_owner[b->slot[f]] = b->key_view(f);
+  std::sort(sc.slow.begin(), sc.slow.end());
+  b->pending.assign(sc.slow.begin(), sc.slow.end());
   return plan_rounds(b, 1, round_id, slots, exists, occ, write, slot_owner);
 }
 
@@ -1004,7 +1307,7 @@ void gt_batch_free(void* bv) {
     GT_LOCK(t);
     for (int32_t i : b->plan_order) {
       int32_t s = b->slot[i];
-      if (s >= 0 && t->pending_write[s] > 0) --t->pending_write[s];
+      if (s >= 0 && t->recs[s].pending_write > 0) --t->recs[s].pending_write;
     }
   }
   delete b;
@@ -1051,6 +1354,7 @@ struct MeshPlan {
   std::vector<Table*> tables;
   std::vector<std::vector<char>> skeys;      // per-shard packed key bytes
   std::vector<std::vector<int64_t>> soffs;   // per-shard offsets [m+1]
+  std::vector<std::vector<uint64_t>> shash;  // per-shard FNV-1a 64 [m]
   std::vector<std::vector<int32_t>> lanes;   // per-shard original lane ids
   std::vector<void*> batches;                // per-shard Batch* (plan phase)
   std::vector<std::vector<int32_t>> pslot;   // per-shard planned slots [m]
@@ -1062,8 +1366,9 @@ struct MeshPlan {
 extern "C" {
 
 // Phase 1: hash every key (fnv1a-64 % S, the static shardmap of
-// parallel/mesh.py shard_of_key) and bucket keys/lanes per shard.
-// Fills counts[S]; returns the handle.
+// parallel/mesh.py shard_of_key) and bucket keys/lanes per shard; the
+// hash goes with the key, so the shard's table is probed by it and no
+// key is hashed twice.  Fills counts[S]; returns the handle.
 void* gt_mesh_begin(void** tables, int64_t S, const char* keys,
                     const int64_t* offsets, int64_t n, int64_t now_ms,
                     int64_t* counts) {
@@ -1074,16 +1379,19 @@ void* gt_mesh_begin(void** tables, int64_t S, const char* keys,
   mp->tables.assign((Table**)tables, (Table**)tables + S);
   mp->skeys.resize(S);
   mp->soffs.resize(S);
+  mp->shash.resize(S);
   mp->lanes.resize(S);
   mp->batches.assign(S, nullptr);
   mp->pslot.resize(S);
   mp->pre_exp.resize(S);
 
   std::vector<int32_t> shard_of((size_t)n);
+  std::vector<uint64_t> hash_of((size_t)n);
   std::vector<int64_t> bytes_of((size_t)S, 0);
   for (int64_t i = 0; i < n; ++i) {
     uint64_t h = fnv1a64(keys + offsets[i], keys + offsets[i + 1]);
     int32_t s = (int32_t)(h % (uint64_t)S);
+    hash_of[i] = h;
     shard_of[i] = s;
     counts[s]++;
     bytes_of[s] += offsets[i + 1] - offsets[i];
@@ -1092,6 +1400,7 @@ void* gt_mesh_begin(void** tables, int64_t S, const char* keys,
     mp->skeys[s].reserve((size_t)bytes_of[s]);
     mp->soffs[s].reserve((size_t)counts[s] + 1);
     mp->soffs[s].push_back(0);
+    mp->shash[s].reserve((size_t)counts[s]);
     mp->lanes[s].reserve((size_t)counts[s]);
   }
   for (int64_t i = 0; i < n; ++i) {
@@ -1099,6 +1408,7 @@ void* gt_mesh_begin(void** tables, int64_t S, const char* keys,
     mp->skeys[s].insert(mp->skeys[s].end(), keys + offsets[i],
                         keys + offsets[i + 1]);
     mp->soffs[s].push_back((int64_t)mp->skeys[s].size());
+    mp->shash[s].push_back(hash_of[i]);
     mp->lanes[s].push_back((int32_t)i);
   }
   return mp;
@@ -1145,8 +1455,9 @@ int64_t gt_mesh_plan_grouped(void* mpv, const int32_t* algo,
     }
     rid_t.assign(m, 0); slot_t.resize(m); occ_t.assign(m, 0);
     ex_t.resize(m); wr_t.resize(m);
-    void* b = gt_batch_begin(mp->tables[s], mp->skeys[s].data(),
-                             mp->soffs[s].data(), m, mp->now_ms);
+    void* b = new Batch(mp->tables[s], mp->skeys[s].data(),
+                        mp->soffs[s].data(), m, mp->now_ms,
+                        mp->shash[s].data());
     mp->batches[s] = b;
     int64_t nr = gt_batch_plan_grouped(
         b, a32.data(), b32.data(), h64.data(), l64.data(), d64.data(),
@@ -1168,7 +1479,7 @@ int64_t gt_mesh_plan_grouped(void* mpv, const int32_t* algo,
       // (models/shard.py decode_narrow passthrough semantics).
       int32_t sl = slot_t[j];
       mp->pre_exp[s][j] =
-          (sl >= 0 && sl < t->capacity) ? t->expire_ms[sl] : 0;
+          (sl >= 0 && sl < t->capacity) ? t->recs[sl].expire_ms : 0;
     }
   }
   return n_rounds;
@@ -1211,10 +1522,8 @@ void gt_mesh_finish_narrow(void* mpv, const int32_t* packed, int64_t now_ms,
         // Keep-sentinel: prefer the live table value while the slot
         // still maps this lane's key (decode_narrow defense in depth).
         int32_t sl = mp->pslot[s][j];
-        bool mine = sl >= 0 && sl < t->capacity && t->slot_mapped[sl] &&
-                    t->slot_key[sl].compare(0, std::string::npos,
-                                            b->key_ptr(j), b->key_len(j)) == 0;
-        reset_time[orig] = mine ? t->expire_ms[sl] : mp->pre_exp[s][j];
+        bool mine = sl >= 0 && sl < t->capacity && b->owns(j, sl);
+        reset_time[orig] = mine ? t->recs[sl].expire_ms : mp->pre_exp[s][j];
       } else {
         reset_time[orig] = (int64_t)d2 + now_ms;
       }

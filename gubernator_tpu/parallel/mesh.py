@@ -740,7 +740,8 @@ class MeshBucketStore(ColumnarPipeline):
         return self._submit_pipelined(keys, cols, now_ms, force_wire)
 
     def _prepare_columns(self, keys, cols, now_ms: int,
-                         force_wire: Optional[str] = None) -> "_MeshPrep":
+                         force_wire: Optional[str] = None,
+                         bt=None) -> "_MeshPrep":
         """Stage 1 of the overlapped dispatch (under `_plan_lock`): the
         slot-table work only — gt_mesh_begin + gt_mesh_plan_grouped
         (hash/bucket every key, per-shard grouped round planning,
@@ -753,12 +754,15 @@ class MeshBucketStore(ColumnarPipeline):
         from .. import native as _native
 
         n = len(keys)
-        mp = _native.NativeMeshPlanner(self.tables, keys, now_ms)
-        fullest = int(mp.counts.max()) if n else 0
-        padded = pad_size(max(fullest, 1))
-        n_rounds = mp.plan_grouped(
-            cols, int(Behavior.RESET_REMAINING), padded
-        )
+        # dispatch.plan_native: the two C++ calls alone, inside
+        # dispatch.prepare — what of a prepare is the slot table.
+        with phase("dispatch.plan_native", bt):
+            mp = _native.NativeMeshPlanner(self.tables, keys, now_ms)
+            fullest = int(mp.counts.max()) if n else 0
+            padded = pad_size(max(fullest, 1))
+            n_rounds = mp.plan_grouped(
+                cols, int(Behavior.RESET_REMAINING), padded
+            )
         pos = mp.pos[:n]
         narrow = narrow_ok(cols, now_ms) and force_wire != "wide"
 

@@ -105,6 +105,7 @@ WATERFALL = (
     ("queue.backstop", 1),    # blocked on the oldest unresolved dispatch
     ("dispatch.prepare", 0),  # slot-table planning (pipeline stage 1)
     ("dispatch.plan_wait", 1),  # waiting for the plan lock
+    ("dispatch.plan_native", 1),  # the C++ slot-table plan alone (begin + grouped plan)
     ("dispatch.stage", 0),    # wire pack + H2D upload start (stage 2)
     ("dispatch.gate_wait", 0),  # waiting for the ticket's launch turn
     ("dispatch.launch", 0),   # ticket-ordered jit call (stage 3)

@@ -18,7 +18,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # The dispatch pipeline and the GLOBAL tick, crossed by every path.  No cell
 # sends a GLOBAL lane, so every tick of the traced seconds is an idle one.
 PIPELINE = {
-    "dispatch.prepare", "dispatch.plan_wait", "dispatch.stage", "dispatch.gate_wait",
+    "dispatch.prepare", "dispatch.plan_wait", "dispatch.plan_native", "dispatch.stage", "dispatch.gate_wait",
     "dispatch.launch", "dispatch.launch_wait", "dispatch.fetch", "dispatch.commit",
     "response.encode", "epoll.wait", "global.tick_idle",
 }
@@ -32,11 +32,11 @@ CROSSED = {
 }
 METRICS = {
     "v5e1-1m.frames": {
-        "plan.lock_wait_ms", "launch.lock_wait_ms",
+        "plan.lock_wait_ms", "plan.native_ms_per_dispatch", "launch.lock_wait_ms",
         "batcher.pump_ms_per_take", "edge.unattributed_ms_per_req",
         "device.idle_unattributed_share", "device.idle_no_request_share", "xla.program_load_s"},
     "v5e1-1m.singles": {
-        "plan.lock_wait_ms", "launch.lock_wait_ms", "device.idle_unattributed_share",
+        "plan.lock_wait_ms", "plan.native_ms_per_dispatch", "launch.lock_wait_ms", "device.idle_unattributed_share",
         "device.idle_no_request_share", "xla.program_load_s"},
 }
 

@@ -363,3 +363,381 @@ def test_passthrough_reset_survives_pipelined_eviction(monkeypatch):
     assert int(captured[0][0]) == now + 60_000
     assert int(ra["remaining"][0]) == 9
     assert int(ra["reset_time"][0]) == now + 60_000
+
+
+# ---------------------------------------------------------------------
+# The flat key index (KeyIndex) and the one-record-a-slot table, held to
+# the Python twin step by step
+# ---------------------------------------------------------------------
+ALL_BITS = (1 << 64) - 1
+
+
+class _TwoTierTwin:
+    """models/slot_table.SlotTable with a back tier that never wraps: a
+    live key evicted from the front is parked with its expiry, and a
+    lookup of a parked, still-live key is a hit that gets it back (what
+    Table::assign does when `back_capacity` holds every key)."""
+
+    def __init__(self, capacity: int):
+        self.t = SlotTable(capacity)
+        self.back = {}
+
+    def __getattr__(self, name):
+        return getattr(self.t, name)
+
+    def __len__(self):
+        return len(self.t)
+
+    def lookup_or_assign(self, key, now):
+        t = self.t
+        if t.get_slot(key) is not None:
+            return t.lookup_or_assign(key, now)
+        parked = self.back.pop(key, None)
+        victim = None
+        if not t._free:
+            vs = next(iter(t._lru))
+            victim = (t.key_of(vs), int(t.expire_ms[vs]))
+        slot, _ = t.lookup_or_assign(key, now)
+        if victim is not None and victim[1] >= now:
+            self.back[victim[0]] = victim[1]
+        if parked is not None and parked >= now:
+            t.expire_ms[slot] = parked
+            t.misses -= 1
+            t.hits += 1
+            return slot, True
+        return slot, False
+
+    def remove(self, key):
+        self.t.remove(key)
+        self.back.pop(key, None)
+
+
+def _plan_then_commit(rng, nat, py, batches, now):
+    """Pipelined: every batch is planned before the first is committed
+    (the native side holds their pending writes meanwhile); the twin
+    resolves each batch's distinct keys in first-appearance order."""
+    planned = []
+    for keys in batches:
+        n = len(keys)
+        cols = _Cols(n)
+        p = native.NativeBatchPlanner(nat, keys, now)
+        rid, slots, exists, occ, write, n_rounds = p.plan_grouped(cols, 8)
+        first, ev = {}, py.evictions
+        for k in keys:
+            if k not in first:
+                first[k] = py.lookup_or_assign(k, now)
+        got = [(int(slots[i]), bool(exists[i])) for i in range(n)]
+        want = [first[k] for k in keys]
+        assert got == want, next((i, keys[i], g, w) for i, (g, w) in enumerate(zip(got, want)) if g != w)
+        if nat.move_counts() != (0, 0):
+            nat.take_moves()  # the launch drains a plan's tier moves before the next plan's evictions
+        # A group whose assign evicted goes to the rounds, after round 0.
+        assert int(rid.max()) == n_rounds - 1 and (n_rounds == 1 or py.evictions > ev)
+        last = {k: i for i, k in enumerate(keys)}
+        assert [bool(w) for w in write] == [last[k] == i for i, k in enumerate(keys)]
+        nth = {}
+        for i, k in enumerate(keys):  # a lane's occurrence index within its key's group
+            assert occ[i] == nth.get(k, 0), (i, k)
+            nth[k] = nth.get(k, 0) + 1
+        # The plan's commit order: round 0's groups as they first appear,
+        # then the rounds' lanes.
+        order = sorted(last.values(), key=lambda i: (int(rid[i]), keys.index(keys[i])))
+        planned.append((p, keys, slots, order))
+    for p, keys, slots, lanes in planned:
+        n = len(keys)
+        exp = now + rng.randint(-50, 400, size=n).astype(np.int64)
+        rm = (rng.random_sample(n) < 0.15).astype(np.uint8)
+        p.commit_plan(exp, rm)
+        py.commit([int(slots[i]) for i in lanes], [int(exp[i]) for i in lanes],
+                  [bool(rm[i]) for i in lanes], keys=[keys[i] for i in lanes])
+
+
+class _Cols:
+    """One uniform configuration a lane: every duplicate group collapses."""
+
+    def __init__(self, n):
+        self.algo = np.zeros(n, np.int32)
+        self.behavior = np.zeros(n, np.int32)
+        self.hits = np.ones(n, np.int64)
+        self.limit = np.full(n, 10, np.int64)
+        self.duration = np.full(n, 1000, np.int64)
+        self.greg_expire = np.zeros(n, np.int64)
+        self.greg_duration = np.zeros(n, np.int64)
+
+
+INDEX_CASES = {
+    # capacity, key bytes, (and, or) over the index hash, two-tier
+    "chains_wrap_the_index": (3, 5, (ALL_BITS ^ 7, 7), False),  # 8 entries, every chain starts at the last
+    "all_keys_on_the_same_hash_bits": (24, 9, (0, 0), False),
+    "same_hash_bits_two_tier": (6, 9, (0, 0), True),
+    "keys_of_1_byte": (12, 1, None, False),
+    "keys_of_15_bytes": (12, 15, None, False),
+    "keys_of_16_bytes": (12, 16, None, True),
+    "keys_of_22_bytes": (16, 22, None, False),
+    "keys_of_24_bytes_the_last_inline": (12, 24, None, True),
+    "keys_of_25_bytes_the_first_on_the_heap": (12, 25, None, False),
+    "keys_of_200_bytes": (12, 200, None, True),
+    "two_tier_small": (4, 22, None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INDEX_CASES))
+def test_native_table_equals_its_twin_after_every_step(case):
+    """Random lookups, removals, expiry, eviction at capacity, the
+    remove-then-recreate remap and pipelined plan-then-commit through
+    NativeSlotTable and the Python twin: the same slot and `exists` at
+    every step, the same hits / misses / evictions, the mapping
+    generation moving in the same steps (the native table counts an
+    evicting assign twice, so the values differ), and at the end the same
+    eviction order slot by slot."""
+    capacity, width, bits, two_tier = INDEX_CASES[case]
+    rng = np.random.RandomState(len(case) * 31 + width)
+    alphabet = "abcdefghijklmnopqrstuvwxyz"[: max(2, min(26, capacity * 3))]
+    if width == 1:
+        keys = list(alphabet)
+    else:
+        keys = [(f"{i:03d}{c}" * width)[:width] for i, c in enumerate(alphabet)]
+    assert len(set(keys)) == len(keys) and all(len(k) == width for k in keys)
+    nat = native.NativeSlotTable(capacity, _hash_bits=bits)
+    py = SlotTable(capacity)
+    if two_tier:
+        nat.enable_back(4 * len(keys))
+        py = _TwoTierTwin(capacity)
+    now = 1000
+    for step in range(1500):
+        gen = (nat.generation, py.generation)
+        op = rng.randint(0, 12)
+        key = keys[rng.randint(0, len(keys))]
+        if op < 5:
+            assert nat.lookup_or_assign(key, now) == py.lookup_or_assign(key, now), (step, key)
+        elif op < 7:
+            slot = py.get_slot(key)
+            assert slot == nat.get_slot(key), (step, key)
+            if slot is not None:
+                exp = now + int(rng.randint(-100, 500))
+                py.commit([slot], [exp], [False])
+                nat.commit([slot], [exp], [False])
+        elif op == 7:
+            py.remove(key)
+            nat.remove(key)
+        elif op == 8:
+            # Remove through a keyed commit, then the keyed commit of a
+            # later lane of the same key: the slot is re-mapped.
+            slot = py.get_slot(key)
+            if slot is not None:
+                for t in (py, nat):
+                    t.commit([slot], [0], [True], keys=[key])
+                    t.commit([slot], [now + 300], [False], keys=[key])
+                assert nat.get_slot(key) == py.get_slot(key) == slot
+        elif op == 9:
+            now += int(rng.randint(0, 200))
+        else:
+            # Two batches in flight together.  They share no key and fit
+            # the table (the twin has no pending writes: it agrees only
+            # while no plan must evict around one), and a batch repeats
+            # keys only while no eviction can send a group to the rounds.
+            distinct = list(rng.permutation(keys)[: max(2, min(capacity, len(keys)) // 2 * 2)])
+            half = len(distinct) // 2
+            batches = []
+            for part in (distinct[:half], distinct[half:]):
+                part = [str(k) for k in part]
+                if len(py.t._free if two_tier else py._free) >= len(distinct):
+                    part = part + [part[i] for i in rng.randint(0, len(part), size=len(part))]
+                batches.append(part)
+            _plan_then_commit(rng, nat, py, batches, now)
+        if two_tier:
+            nat.take_moves()  # as every dispatch does: a queued promotion shields its slot from eviction
+        assert len(nat) == len(py), step
+        assert (nat.hits, nat.misses) == (py.hits, py.misses), step
+        if not two_tier:  # a back row dropped for room counts as an eviction too
+            assert nat.evictions == py.evictions, step
+        assert (nat.generation != gen[0]) == (py.generation != gen[1]), step
+    assert sorted(nat.keys()) == sorted(py.keys())
+    assert py.evictions > 0 and py.hits > 0  # the sequence did reach capacity
+    if two_tier:
+        total, back_keys, demotions, promotions, lost = nat.tier_stats
+        assert demotions > 0 and promotions > 0 and lost == 0
+        assert back_keys == len(py.back) and sorted(nat.back_entries()[0]) == sorted(py.back)
+    stats = nat.index_stats
+    assert stats["lookups"] > 0 and stats["probes"] >= stats["lookups"]
+    if bits == (0, 0):
+        assert stats["refused"] > 0  # distinct keys on the same 64 bits: the key compare told them apart
+    elif bits is None:
+        assert stats["refused"] == 0
+    # The eviction order, slot by slot: fresh keys push everything out.
+    for i in range(capacity):
+        fresh = f"fresh-{i}"
+        assert nat.lookup_or_assign(fresh, now) == py.lookup_or_assign(fresh, now), i
+
+
+# ---------------------------------------------------------------------
+# The grouped plan on the benchmark cells' own shapes, held lane for lane
+# to the Python plan
+# ---------------------------------------------------------------------
+PLAN_CASES = {
+    "4096_lanes_1_shard": (4096, 1),  # v5e1-1m.frames
+    "1028_lanes_4_shards": (1028, 4),  # v5e4-mesh-1m.frames
+    "4096_lanes_4_shards": (4096, 4),
+    "1028_lanes_1_shard": (1028, 1),
+}
+PLAN_KEYS = 20_000  # the harness's rehearsal size
+PLAN_T0 = 1_790_000_000_000
+HOUR = 3_600_000
+
+
+@pytest.fixture(scope="module")
+def cell_population():
+    import json
+    import os
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    from chipbench.population import Population
+
+    with open(os.path.join(repo, "chipbench", "configs", "v5e1-1m.json")) as f:
+        return Population(json.load(f)["population"], PLAN_KEYS, 28)
+
+
+def _cell_frames(pop, lanes):
+    """[(keys, algo, behavior, limit, now)]: the load, Zipfian-0.99 frames
+    (hot keys repeat), a frame with RESET_REMAINING lanes (one alone, one in
+    the middle of a hot key's group), a frame half of fresh keys that the full
+    table must evict for, more Zipfian frames, and one after every bucket
+    has expired."""
+    rng = np.random.default_rng([28, lanes])
+    resident = [f"bench_{pop.unique_key(i)}" for i in range(pop.n)]
+    frames, now = [], PLAN_T0
+
+    def frame(idx, fresh=()):
+        keys = [resident[i] for i in idx]
+        algo = pop.algo[idx].astype(np.int32)
+        limit = pop.limit[idx].astype(np.int64)
+        if len(fresh):
+            at = np.sort(rng.choice(len(keys) + len(fresh), size=len(fresh), replace=False))
+            for a, k in zip(at, fresh):
+                keys.insert(int(a), k)
+            algo = np.insert(algo, at - np.arange(len(at)), 0).astype(np.int32)
+            limit = np.insert(limit, at - np.arange(len(at)), 100).astype(np.int64)
+        return [keys, algo, np.zeros(len(keys), np.int32), limit, now]
+
+    for lo in range(0, pop.n, lanes):
+        frames.append(frame(np.arange(lo, min(lo + lanes, pop.n))))
+        now += 7
+    for _ in range(3):
+        frames.append(frame(pop.draw(rng, lanes)))
+        now += 900
+    f = frame(pop.draw(rng, lanes))
+    counts = {}
+    for k in f[0]:
+        counts[k] = counts.get(k, 0) + 1
+    hot = max(counts, key=counts.get)
+    alone = next(i for i, k in enumerate(f[0]) if counts[k] == 1)
+    second = [i for i, k in enumerate(f[0]) if k == hot][1]
+    assert counts[hot] >= 3
+    f[2][[alone, second]] = int(Behavior.RESET_REMAINING)
+    frames.append(f)
+    now += 900
+    fresh = [f"bench_fresh{i:07d}kffff" for i in range(lanes // 2)]
+    frames.append(frame(pop.draw(rng, lanes - len(fresh)), fresh))
+    now += 900
+    for _ in range(2):
+        frames.append(frame(pop.draw(rng, lanes)))
+        now += 900
+    now += 2 * HOUR
+    frames.append(frame(pop.draw(rng, lanes)))
+    return frames
+
+
+@pytest.mark.parametrize("case", sorted(PLAN_CASES))
+def test_mesh_plan_gives_the_python_plans_arrays_lane_for_lane(case, cell_population):
+    """`gt_mesh_begin` + `gt_mesh_plan_grouped` over loaded tables against
+    `plan_grouped_python` over the twin's: `slot`, `rid`, `exists`, `occ`,
+    `write`, `pos` and the round count, frame after frame, each committed
+    on both sides with the same results."""
+    from gubernator_tpu.models.shard import _Prepared, pad_size, plan_grouped_python
+    from gubernator_tpu.parallel.mesh import shard_of_key
+
+    lanes, S = PLAN_CASES[case]
+    pop = cell_population
+    cap = int(PLAN_KEYS / S * 1.005)  # full after the load: the fresh keys evict
+    nat = [native.NativeSlotTable(cap) for _ in range(S)]
+    py = [SlotTable(cap) for _ in range(S)]
+    saw = {"rounds": 0, "evictions": 0, "groups": 0, "expired": 0}
+    frames = _cell_frames(pop, lanes)
+    for f, (keys, algo, behavior, limit, now) in enumerate(frames):
+        n = len(keys)
+        cols = _Cols(n)
+        cols.algo, cols.behavior, cols.limit = algo, behavior, limit
+        cols.duration = np.full(n, HOUR, np.int64)
+        mp = native.NativeMeshPlanner(nat, keys, now)
+        P = pad_size(max(int(mp.counts.max()), 1))
+        n_rounds = mp.plan_grouped(cols, int(Behavior.RESET_REMAINING), P)
+
+        by_shard = [[] for _ in range(S)]
+        for i, k in enumerate(keys):
+            req = RateLimitRequest(
+                name="bench", unique_key=k[6:], hits=1, limit=int(limit[i]), duration=HOUR,
+                algorithm=Algorithm(int(algo[i])), behavior=int(behavior[i]))
+            by_shard[shard_of_key(k, S)].append(_Prepared(pos=i, slot=-1, exists=False, req=req, key=k))
+        ev = sum(t.evictions for t in py)
+        want_rounds = 1
+        packed = np.zeros((S, 4, P), np.int64)
+        for s, chunk in enumerate(by_shard):
+            m = len(chunk)
+            assert m == mp.counts[s]
+            rid, occ, write, nr = plan_grouped_python(py[s], chunk, now)
+            want_rounds = max(want_rounds, nr)
+            for name, got, want in (
+                ("slot", mp.slot[s, :m], [p.slot for p in chunk]),
+                ("rid", mp.rid[s, :m], rid),
+                ("exists", mp.exists[s, :m], [p.exists for p in chunk]),
+                ("occ", mp.occ[s, :m], occ),
+                ("write", mp.write[s, :m], write),
+                ("pos", mp.pos[[p.pos for p in chunk]], s * P + np.arange(m)),
+            ):
+                bad = np.flatnonzero(np.asarray(got).astype(np.int64) != np.asarray(want).astype(np.int64))
+                assert not len(bad), (f, s, name, int(bad[0]), chunk[int(bad[0])].key)
+            assert (mp.slot[s, m:] == -1).all() and not mp.write[s, m:].any()
+            # The same results on both sides: a RESET_REMAINING lane removes
+            # its bucket, every other lane's lasts an hour from now.
+            removed = np.array([bool(p.req.behavior) for p in chunk])
+            packed[s, 0, :m] = removed.astype(np.int64) << 1
+            packed[s, 3, :m] = np.where(removed, 0, now + HOUR)
+            commits = [j for j in range(m) if write[j]]
+            py[s].commit([chunk[j].slot for j in commits], [int(packed[s, 3, j]) for j in commits],
+                         [bool(removed[j]) for j in commits], keys=[chunk[j].key for j in commits])
+            saw["groups"] += int((np.asarray(occ) > 0).sum())
+            if f == len(frames) - 1:
+                saw["expired"] += sum(1 for p in chunk if not p.exists)
+        assert n_rounds == want_rounds, f
+        mp.finish_wide(packed)
+        saw["rounds"] = max(saw["rounds"], n_rounds)
+        saw["evictions"] += sum(t.evictions for t in py) - ev
+        for s in range(S):
+            assert len(nat[s]) == len(py[s]) and nat[s].evictions == py[s].evictions, (f, s)
+    # The frames held what they are meant to hold.
+    assert saw["rounds"] >= 3 and saw["evictions"] >= lanes // 4 and saw["groups"] > 0, saw
+    assert saw["expired"] > 0.9 * lanes, saw  # two hours on, a resident key is a recycled slot
+    for s in range(S):
+        assert sorted(nat[s].keys()) == sorted(py[s].keys())
+        stats = nat[s].index_stats
+        assert stats["refused"] == 0 and stats["probes"] < 2 * stats["lookups"]
+
+
+def test_occupancy_rows_serve_the_key_indexs_health():
+    """Each shard's row of `occupancy_stats()` (the `occupancy.shards` of
+    GET /debug/status) carries the native index's lookups, probes, refused
+    hash hits and size; the Python table has no index and no such key."""
+    import json
+
+    st = ShardStore(capacity=64, use_native=True)
+    st.apply([_req(f"o{i % 9}") for i in range(30)], 1_700_000_000_000)
+    (row,) = st.occupancy_stats()
+    assert row["used"] == 9
+    assert set(row["index"]) == {"lookups", "probes", "refused", "entries"}
+    assert row["index"]["lookups"] >= 9 and row["index"]["probes"] >= row["index"]["lookups"]
+    assert row["index"]["entries"] == 128 and row["index"]["refused"] == 0
+    json.dumps(row)
+    (row,) = ShardStore(capacity=64, use_native=False).occupancy_stats()
+    assert "index" not in row

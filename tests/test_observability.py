@@ -362,6 +362,9 @@ def _check_debug_payloads(get):
     assert status["version"]
     assert status["occupancy"]["capacity"] > 0
     assert status["occupancy"]["used"] >= 1
+    if native.available():  # the native table's key index, a shard
+        for row in status["occupancy"]["shards"]:
+            assert row["index"]["probes"] >= row["index"]["lookups"] >= 0 and row["index"]["refused"] == 0
     assert "queuedLanes" in status["ingress"]
     assert "slo" in status and "hotkeys" in status
     latency = json.loads(get("/debug/latency"))

@@ -328,6 +328,31 @@ def test_waterfall_lists_every_phase_once_and_nests_under_a_parent():
 
 
 @pytest.mark.skipif(not native.available(), reason="native runtime unavailable")
+@pytest.mark.parametrize("store", ["mesh", "shard"])
+def test_the_native_plan_is_a_phase_inside_every_prepare(store):
+    """`dispatch.plan_native` (the C++ slot-table plan alone) is a depth-1
+    phase listed after `dispatch.plan_wait`, and both columnar stores observe
+    it once a prepare, inside `dispatch.prepare`'s time."""
+    from gubernator_tpu.models.shard import ShardStore
+    from gubernator_tpu.parallel.mesh import MeshBucketStore
+
+    names = [p for p, _ in saturation.WATERFALL]
+    at = names.index("dispatch.plan_native")
+    assert saturation.WATERFALL[at] == ("dispatch.plan_native", 1)
+    assert names[at - 2:at] == ["dispatch.prepare", "dispatch.plan_wait"]
+    st = MeshBucketStore(capacity_per_shard=64) if store == "mesh" else ShardStore(capacity=64)
+    n = 12
+    for t in range(5):
+        st.apply_columns(
+            [f"pn{i % 7}" for i in range(n)], np.zeros(n, np.int32), np.zeros(n, np.int32),
+            np.ones(n, np.int64), np.full(n, 100, np.int64), np.full(n, 60_000, np.int64),
+            1_700_000_000_000 + t)
+    plan, prepare = _stats("dispatch.plan_native"), _stats("dispatch.prepare")
+    assert plan["count"] == prepare["count"] == 5
+    assert 0 < plan["sum_ms"] <= prepare["sum_ms"]
+
+
+@pytest.mark.skipif(not native.available(), reason="native runtime unavailable")
 def test_sampling_daemon_serves_frames_on_the_native_lane(sampled):
     """GUBER_TRACE_SAMPLE=1 no longer switches the native lane off: the
     frame is served by it (the ingress counters show it) and
