@@ -1,5 +1,5 @@
 # Reference Makefile:1-35 equivalents for the TPU build.
-.PHONY: test tier1 chaos bench bench-gate bench-trend soak soak-smoke soak-regions replay-smoke proto certs docker release clean native
+.PHONY: test tier1 chaos soak soak-smoke soak-regions replay-smoke proto certs docker release clean native
 
 # Compile the C++ host runtime for the CURRENT source of
 # gubernator_tpu/native/host_runtime.cpp.  Flags are pinned in ONE
@@ -35,31 +35,6 @@ tier1:
 chaos:
 	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m chaos \
 		-p no:cacheprovider
-
-# One JSON line: {"metric", "value", "unit", "vs_baseline", ...},
-# then the failing regression gate on the stable device rows
-# (benchmarks/gate_thresholds.json), then the bench-history trend gate
-# (each bench run appends its stamped row to benchmarks/history/;
-# scripts/bench_trend.py prints the per-metric trajectory across runs
-# — the BENCH_r* seeds included — and fails on a >20% noise-adjusted
-# regression vs the rolling same-backend median).
-bench:
-	python bench.py
-	python bench.py --gate
-	python scripts/bench_trend.py
-
-# Just the regression gate (reuses rows a bench run saved <1h ago,
-# measures fresh otherwise): the one-command CI check.
-bench-gate:
-	python bench.py --gate
-
-# Just the cross-run trend view/gate over benchmarks/history/.
-bench-trend:
-	python scripts/bench_trend.py
-
-# The five BASELINE.json configs (one JSON line each); --smoke for CI
-bench-full:
-	python bench_full.py
 
 # CPU-backend soak smoke: a short long_soak-derived run (slow-marked,
 # excluded from tier-1) driving mixed traffic at a 2-daemon cluster

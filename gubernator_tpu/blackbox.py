@@ -18,8 +18,7 @@ Three pieces:
   bytes); `tap_taken()` reconstructs the kind-5 frames a native-edge
   take batch coalesced (the one choke point that no longer holds the
   original bytes).  Disabled (`GUBER_BLACKBOX=0` or force_disable) the
-  tap is one branch per frame — bench-gated like tracing/profiling
-  (blackbox_overhead_ratio >= 0.95).
+  tap is one branch per frame.
 
 * **Bundles** — `on_trigger` rides tracing.Recorder.dump_hooks: every
   _DUMP_KINDS event (plus POST /debug/incident) wakes an off-thread
@@ -67,7 +66,7 @@ logger = category_logger("blackbox")
 # Process-wide switches (the tracing/profiling plane pattern): the
 # daemon applies its parsed GUBER_BLACKBOX via set_enabled; library
 # embedders get the import-time env default (on).  force_disable is
-# the bench's "compiled out" baseline for the overhead gate.
+# the "compiled out" baseline an overhead measurement compares with.
 # ---------------------------------------------------------------------
 _FORCE_DISABLED: bool = False
 
@@ -201,8 +200,7 @@ def decode_frame_log(raw: bytes, name: str = "frame log"
 class _WireRing:
     """Byte-budgeted frame ring: append evicts oldest until under
     budget.  A small lock per record — the tap sites already sit next
-    to an HTTP round trip or a device dispatch, and the bench gate
-    bounds the total (blackbox_overhead_ratio >= 0.95)."""
+    to an HTTP round trip or a device dispatch."""
 
     __slots__ = ("budget", "frames", "nbytes", "frames_total",
                  "bytes_total", "_mu")

@@ -228,8 +228,7 @@ class BehaviorConfig:
     # timings + device memory sampling, exported as gubernator_xla_* /
     # gubernator_device_* and GET /debug/device.  False disables the
     # plane entirely: the launch-site hook degrades to one branch
-    # returning a shared no-op (the bench gate pins the overhead ratio
-    # >= 0.95 either way).  Env: GUBER_XLA_TELEMETRY.
+    # returning a shared no-op.  Env: GUBER_XLA_TELEMETRY.
     xla_telemetry: bool = True
     # Recompile-storm trip: >= xla_storm steady-state compiles within
     # xla_storm_window_s seconds fires the flight-recorder auto-dump.
@@ -243,8 +242,7 @@ class BehaviorConfig:
     # thread's stack ~profile_hz times/s into phase-tagged flamegraph
     # windows (GET /debug/pprof).  False compiles the plane out: the
     # sampler tick is one branch, every scope hook one comparison
-    # returning a shared no-op (the bench gate pins the overhead ratio
-    # >= 0.95 — profiling_overhead_ratio).  Env: GUBER_PROFILE.
+    # returning a shared no-op.  Env: GUBER_PROFILE.
     profile: bool = True
     # Sampling rate in Hz (out-of-range [1, 1000] values are rejected
     # loudly at boot, never clamped; the default 67 is deliberately not
@@ -305,8 +303,7 @@ class BehaviorConfig:
     # trigger fires (breaker-open, audit-violation, slo-fast-burn, ...)
     # or an operator POSTs /debug/incident — replayable with
     # scripts/replay.py.  False = one branch per frame (the tap and
-    # trigger hooks go dark; bench-gated blackbox_overhead_ratio).
-    # Env: GUBER_BLACKBOX.
+    # trigger hooks go dark).  Env: GUBER_BLACKBOX.
     blackbox: bool = True
     # Total in-memory capture budget in MiB, split across the five wire
     # rings (public/peer/global/transfer/region).  Env:
@@ -343,8 +340,8 @@ class DaemonConfig:
     # HTTP edge: True serves the gateway from the C++ epoll edge
     # (NativeGatewayServer — better tail latency and per-request
     # overhead; startup error if the native runtime is missing or TLS
-    # is on).  Default/False: the stdlib gateway (wins bulk-batch
-    # throughput on few-core hosts — measured A/B in RESULTS.md).
+    # is on).  Default/False: the stdlib gateway (its unbounded blocked
+    # threads keep more device windows in flight on few-core hosts).
     # Env: GUBER_NATIVE_HTTP=1/0.
     native_http: "bool | None" = None
     # Native-edge Python worker count (parse + submit only — the async

@@ -139,12 +139,11 @@ class Daemon:
             self.service, grpc_listen, tls_conf=tls_conf,
             max_conn_age_s=getattr(self.conf, "grpc_max_conn_age_s", 0),
         ).start()
-        # HTTP edge selection (measured A/B in RESULTS.md round 5): the
-        # C++ epoll edge (NativeGatewayServer) wins tail latency (1000-
-        # lane p99 85ms -> 15ms) and per-request overhead, but on a
-        # 1-core host the stdlib gateway's unbounded blocked threads
-        # keep more device windows in flight and win bulk-batch
-        # throughput ~15-20%.  Default is therefore the stdlib gateway;
+        # HTTP edge selection: the C++ epoll edge (NativeGatewayServer)
+        # wins tail latency and per-request overhead, but on a 1-core
+        # host the stdlib gateway's unbounded blocked threads keep more
+        # device windows in flight and win bulk-batch throughput.
+        # Default is therefore the stdlib gateway;
         # GUBER_NATIVE_HTTP=1 / native_http=True opts into the native
         # edge (latency-sensitive or many-core deployments).  TLS always
         # uses the Python+ssl gateway.

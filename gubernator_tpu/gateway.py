@@ -945,9 +945,9 @@ class NativeIngressPump:
     #: past any useful deadline — the native ring's shed bound still
     #: caps total admitted lanes).
     DEPTH = 6
-    #: Take/dispatch threads.  Two, like the headline bench loop: the
-    #: PREPARE of take N+1 (the C++ mesh plan, under `_plan_lock`)
-    #: overlaps take N's STAGE/LAUNCH (store lock) — on one thread the
+    #: Take/dispatch threads.  Two: the PREPARE of take N+1 (the C++
+    #: mesh plan, under `_plan_lock`) overlaps take N's STAGE/LAUNCH
+    #: (store lock) — on one thread the
     #: two stages serialize and the ~equal-cost halves each idle while
     #: the other runs (measured ~1.6x at 60k-lane takes on the 2-core
     #: dev box).
@@ -1281,10 +1281,9 @@ class NativeGatewayServer:
     thread owns accept/read/frame/write for every connection; N Python
     workers pull parsed requests (GIL released while blocked) and run
     the same handle_request path as the stdlib gateway.  Replaces the
-    measured ~1.1 ms/request Python HTTP layer and the thread-per-
-    connection model that convoys at 100-way concurrency (RESULTS.md
-    cfg8/cfg5).  No TLS — the daemon selects the stdlib gateway when
-    TLS is configured."""
+    Python HTTP layer and the thread-per-connection model that convoys
+    at 100-way concurrency.  No TLS — the daemon selects the stdlib
+    gateway when TLS is configured."""
 
     # Workers only parse + SUBMIT (handle_request_async): the device
     # round completes through the service's drainer pool and responds

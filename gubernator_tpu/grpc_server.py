@@ -99,8 +99,8 @@ class GrpcServer:
         tls_conf=None,  # Optional[tls.TLSConfig] (file paths already resolved)
         # Handlers BLOCK on device rounds, so this pool caps in-flight
         # RPCs — and therefore how many concurrent callers one
-        # coalescing window can merge (the convoy measured on the HTTP
-        # edge, RESULTS.md round-5 A/B).  128 covers the reference's
+        # coalescing window can merge (the same convoy as a bounded
+        # HTTP worker pool).  128 covers the reference's
         # 100-way benchmark fan-in; idle-blocked threads are cheap.
         max_workers: int = 128,
         max_conn_age_s: int = 0,

@@ -685,27 +685,19 @@ class Metrics:
 
     def set_build_info(self, store) -> None:
         """Pin the build-info series: version from the package, backend
-        and mesh shape from the store's device topology (stores without
-        a mesh report their shard layout)."""
+        and mesh shape from the store's device topology."""
         from . import __version__
 
-        describe = getattr(store, "describe_topology", None)
-        backend, mesh = ("unknown", "none")
-        if describe is not None:
-            try:
-                backend, mesh = describe()
-            except Exception:  # noqa: BLE001 — labels must never fail startup
-                pass
+        backend, mesh = store.describe_topology()
         self.build_info.labels(
             version=__version__, backend=backend, mesh=mesh
         ).set(1)
 
     def observe_cache(self, store) -> None:
-        """Refresh cache gauges from a ShardStore/MeshBucketStore."""
+        """Refresh cache gauges from the MeshBucketStore."""
         self.cache_size.set(store.size())
-        tables = getattr(store, "tables", None) or [store.table]
-        hits = sum(t.hits for t in tables)
-        misses = sum(t.misses for t in tables)
+        hits = sum(t.hits for t in store.tables)
+        misses = sum(t.misses for t in store.tables)
         # Counters are monotonic: set via inc of the delta.
         self._bump(self.cache_access_count.labels(type="hit"), hits)
         self._bump(self.cache_access_count.labels(type="miss"), misses)
@@ -730,10 +722,7 @@ class Metrics:
         (collect-on-scrape).  Per-stage series are cleared first — the
         stats are deltas since the last scrape (the PR 1 breaker-gauge
         convention), so departed stages drop off instead of freezing."""
-        take = getattr(store, "take_pipeline_stats", None)
-        if take is None:
-            return
-        stats, depth, hwm = take()
+        stats, depth, hwm = store.take_pipeline_stats()
         self.dispatch_inflight.set(depth)
         self.dispatch_inflight_hwm.set(hwm)
         self.dispatch_stage_seconds.clear()
@@ -749,24 +738,22 @@ class Metrics:
         Everything read here is host-side state the dispatch path
         already maintains — the scrape launches no device program."""
         store = service.store
-        occupancy = getattr(store, "occupancy_stats", None)
         self.occupancy_slots.clear()
         self.occupancy_capacity.clear()
-        if occupancy is not None:
-            for row in occupancy():
-                sh = str(row["shard"])
-                slots, caps = self.occupancy_slots, self.occupancy_capacity
-                slots.labels(shard=sh, tier="front").set(row["used"])
-                caps.labels(shard=sh, tier="front").set(row["capacity"])
-                self._bump(
-                    self.occupancy_evictions.labels(shard=sh),
-                    row["evictions"],
+        for row in store.occupancy_stats():
+            sh = str(row["shard"])
+            slots, caps = self.occupancy_slots, self.occupancy_capacity
+            slots.labels(shard=sh, tier="front").set(row["used"])
+            caps.labels(shard=sh, tier="front").set(row["capacity"])
+            self._bump(
+                self.occupancy_evictions.labels(shard=sh),
+                row["evictions"],
+            )
+            if "back_used" in row:
+                slots.labels(shard=sh, tier="back").set(row["back_used"])
+                caps.labels(shard=sh, tier="back").set(
+                    row["back_capacity"]
                 )
-                if "back_used" in row:
-                    slots.labels(shard=sh, tier="back").set(row["back_used"])
-                    caps.labels(shard=sh, tier="back").set(
-                        row["back_capacity"]
-                    )
         self.ingress_queue_lanes.set(service.ingress_queued_lanes())
         self.batch_window_wait_seconds.set(
             service.columnar_batcher._window.effective_wait_s()

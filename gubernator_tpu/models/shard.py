@@ -1,9 +1,11 @@
-"""Single-shard bucket store: host slot table + device state columns.
+"""Host side of a device bucket store: request planning, the columnar
+dispatch pipeline and the Store-SPI conversions.
 
-One ShardStore is the TPU-native unit that replaces a reference peer's
-`LRUCache` + mutex + per-request algorithm call (`gubernator.go:335-354`):
-a whole batch of requests is resolved to device slots host-side, then
-evaluated in one jitted kernel call per duplicate-round.
+The device store itself is `parallel.mesh.MeshBucketStore` (one shard
+per device; one device is a one-shard mesh).  It replaces a reference
+peer's `LRUCache` + mutex + per-request algorithm call
+(`gubernator.go:335-354`): a whole batch of requests is resolved to
+device slots host-side, then evaluated in one jitted program.
 
 Request order within a batch is preserved for duplicate keys (the k-th
 request for a key sees the state left by the (k-1)-th), matching the
@@ -14,7 +16,6 @@ from __future__ import annotations
 
 import datetime as _dt
 import threading
-import time
 from collections import deque
 from dataclasses import dataclass
 from functools import partial
@@ -24,14 +25,12 @@ import jax
 import numpy as np
 
 from .. import audit
-from .. import native
 from .. import profiling
 from .. import saturation
 from ..saturation import phase
 from .. import telemetry
 from .. import tracing
 from ..ops import buckets
-from ..ops import scalar as scalar_ops
 from ..types import (
     Algorithm,
     Behavior,
@@ -332,27 +331,8 @@ class _Columns:
         self.greg_expire = np.zeros(n, dtype=np.int64)
         self.greg_duration = np.zeros(n, dtype=np.int64)
 
-    def set(self, j: int, req: RateLimitRequest, ge: int, gd: int) -> None:
-        self.algo[j] = int(req.algorithm)
-        self.behavior[j] = int(req.behavior)
-        self.hits[j] = req.hits
-        self.limit[j] = req.limit
-        self.duration[j] = req.duration
-        self.greg_expire[j] = ge
-        self.greg_duration[j] = gd
-
-    def trim(self, m: int) -> None:
-        for f in self.__slots__:
-            setattr(self, f, getattr(self, f)[:m])
-
 
 _I32_MAX = (1 << 31) - 1
-
-
-def _pad(src: np.ndarray, padded: int, dtype) -> np.ndarray:
-    out = np.zeros(padded, dtype=dtype)
-    out[: len(src)] = src
-    return out
 
 
 def narrow_ok(cols: "_Columns", now_ms: int) -> bool:
@@ -368,32 +348,6 @@ def narrow_ok(cols: "_Columns", now_ms: int) -> bool:
         if int(d.min()) < 0 or int(d.max()) > hi or int(cols.greg_duration.max()) > hi:
             return False
     return True
-
-
-def decode_narrow(table, keys, slots, pn, now_ms: int, passthrough_exp):
-    """Decode one narrow-wire packed result (i32[4, n] lanes).
-
-    -2 keep-sentinel lanes reconstruct the device's pre-THIS-batch
-    expiry.  A sentinel value is unrepresentable (>i32 delta), which
-    requires a stored duration the narrow wire also can't carry — so no
-    in-flight NARROW batch can have written it, and any narrow request
-    on such a key triggers duration-change re-expiry instead of a
-    pass-through.  Hence the value always predates every in-flight
-    batch and the dispatch-time snapshot is correct even if a later
-    batch's all-pending eviction fallback steals the slot and zeroes
-    the mirror before this resolve.  Defense in depth: when the slot
-    still maps this batch's key, prefer the resolve-time table value
-    (older in-flight commits have folded in by now via the FIFO drain).
-    """
-    te = passthrough_exp
-    sent = np.nonzero(pn[2] == -2)[0]
-    if sent.size:
-        te = passthrough_exp.copy()
-        cur = table.get_expire_bulk(slots)
-        for j in sent:
-            if table.get_slot(keys[j]) == slots[j]:
-                te[j] = cur[j]
-    return buckets.unpack_output32(pn, now_ms, te)
 
 
 def make_columns(algorithm, behavior, hits, limit, duration, n,
@@ -514,29 +468,9 @@ class _Staged:
     scalar: "Optional[Callable]" = None
 
 
-@dataclass
-class _ShardPrep:
-    """Output of ShardStore's prepare stage: the plan columns plus the
-    commit closure, handed to the unlocked stage step."""
-
-    cols: "_Columns"
-    now_ms: int
-    force_wire: Optional[str]
-    n: int
-    padded: int
-    n_rounds: int
-    narrow: bool
-    slot_col: np.ndarray
-    rid_col: np.ndarray
-    ex_col: np.ndarray
-    occ_col: np.ndarray
-    wr_col: np.ndarray
-    commit: "Callable"
-
-
 class ColumnsHandle:
     """Deferred result of one pipelined columnar batch
-    (ShardStore.apply_columns_async).  Commits apply strictly in
+    (MeshBucketStore.apply_columns_async).  Commits apply strictly in
     dispatch order — result() drains every older in-flight batch —
     but the device->host READBACK runs outside the ordering locks:
     concurrent waiters overlap their transfers (on a remote device each
@@ -772,13 +706,11 @@ class ColumnarPipeline:
         """Per-shard occupancy from the HOST slot tables the dispatch
         commits already maintain — THE one occupancy read of the
         saturation plane (zero device programs; consumed by
-        Metrics.observe_saturation and V1Service.debug_status).  Works
-        for both stores: ShardStore exposes `table`, the mesh store
-        `tables` (+ the optional back tier)."""
-        tables = getattr(self, "tables", None) or [self.table]
-        back_cap = int(getattr(self, "back_capacity_per_shard", 0) or 0)
+        Metrics.observe_saturation and V1Service.debug_status): one
+        row per shard table (+ the optional back tier)."""
+        back_cap = self.back_capacity_per_shard
         out = []
-        for s, t in enumerate(tables):
+        for s, t in enumerate(self.tables):
             row = {
                 "shard": s,
                 "used": len(t),
@@ -983,7 +915,7 @@ class ColumnarPipeline:
         the mesh store overrides."""
         return 1, prep.n
 
-    # -- launch implementations (shared by ShardStore / MeshBucketStore)
+    # -- launch hooks (the store's device topology) ---------------------
     def _pre_launch(self) -> None:
         """Hook: device work that must precede the group's programs
         (the mesh drains its queued tier moves here)."""
@@ -1004,14 +936,13 @@ class ColumnarPipeline:
         raise NotImplementedError
 
     def _program_label(self, group) -> str:
-        """XLA-telemetry program identity for one launch group: store
-        topology (mesh twin vs single shard), solo vs fused-K, and the
-        wire width — the axes along which distinct programs compile."""
-        kind = "mesh" if getattr(self, "tables", None) is not None else "shard"
+        """XLA-telemetry program identity for one launch group: solo vs
+        fused-K and the wire width — the axes along which distinct
+        programs compile."""
         staged = group[0][0]
         shape = "solo" if len(group) == 1 else f"fused{len(group)}"
         width = "wide" if staged.wide else "narrow"
-        return f"{kind}:dispatch:{shape}:{width}"
+        return f"mesh:dispatch:{shape}:{width}"
 
     def _launch_group(self, group) -> None:
         """Stage 3 (ticket order, under `_lock`): just the
@@ -1128,693 +1059,12 @@ def build_round_arrays(chunk: Sequence[_Prepared], padded: int) -> Tuple[np.ndar
     return slot, exists, algo, behavior, hits, limit, duration, greg_expire, greg_duration
 
 
-class ShardStore(ColumnarPipeline):
-    """Bucket table for one shard, pinned to (at most) one device.
-
-    `store` is the optional persistence SPI (gubernator_tpu.store.Store):
-    get() fulfills misses, on_change() observes every applied request,
-    remove() fires on explicit removals — the call pattern of
-    algorithms.go:26-33,64-68,176-177.
-    """
-
-    def __init__(
-        self,
-        capacity: int = 50_000,
-        device: Optional[jax.Device] = None,
-        store=None,
-        use_native: bool = True,
-    ):
-        self.capacity = capacity
-        # The C++ host runtime (native/host_runtime.cpp) handles key
-        # resolution + round planning at C speed; Python twin is the
-        # compiler-less fallback.
-        self._native = use_native and native.available()
-        self.table = (
-            native.NativeSlotTable(capacity) if self._native else SlotTable(capacity)
-        )
-        self.device = device
-        self.store = store
-        # Serializes buffer-donating mutators for multi-threaded callers.
-        self._lock = threading.RLock()
-        state = buckets.init_state(capacity)
-        if device is not None:
-            state = jax.device_put(state, device)
-        self.state = state
-        # host mirror of per-slot algorithm, for store-SPI removal detection
-        self.algo_mirror = np.zeros(capacity, dtype=np.int32)
-        self._init_pipeline()  # FIFO of unresolved pipelined batches
-
-    def describe_topology(self) -> "Tuple[str, str]":
-        """(backend platform, mesh shape) for gubernator_build_info —
-        a single-shard store reports a 1-wide mesh."""
-        try:
-            d = self.device if self.device is not None else jax.devices()[0]
-            return d.platform, "1"
-        except Exception:  # noqa: BLE001
-            return "unknown", "1"
-
-    # ------------------------------------------------------------------
-    def apply(
-        self, requests: Sequence[RateLimitRequest], now_ms: int
-    ) -> List[RateLimitResponse]:
-        """Evaluate a batch; responses come back in request order."""
-        responses: List[Optional[RateLimitResponse]] = [None] * len(requests)
-        if self._native and self.store is None:
-            # Rides the columnar pipeline: dispatch under the store
-            # lock, resolve outside it (ColumnarPipeline ordering).
-            self._apply_native(requests, now_ms, responses)
-            return [r if r is not None else RateLimitResponse() for r in responses]
-        # Store-SPI / fallback path: interleaved per-round host
-        # callbacks need the lock across the whole batch.
-        self._drain_then_lock()
-        try:
-            prepared = prepare_requests(requests, now_ms, responses)
-            resolver = self._store_resolver(now_ms) if self.store is not None else None
-            planner = RoundPlanner(self.table, prepared, now_ms, resolver=resolver)
-            while True:
-                chunk = planner.next_chunk()
-                if not chunk:
-                    break
-                self._run_round(chunk, now_ms, responses)
-            return [r if r is not None else RateLimitResponse() for r in responses]
-        finally:
-            self._unlock_drained()
-
-    # ------------------------------------------------------------------
-    # Native (C++) fast path: resolve + round-plan in host_runtime.cpp,
-    # column math in numpy, responses in one pass.
-    # ------------------------------------------------------------------
-    def _apply_native(self, requests, now_ms: int, responses) -> None:
-        n = len(requests)
-        if n == 0:
-            return
-        greg_bit = int(Behavior.DURATION_IS_GREGORIAN)
-        behavior = np.fromiter((r.behavior for r in requests), np.int32, count=n)
-        if not (behavior & greg_bit).any():
-            # Common case: no calendar lanes — extract each field in one
-            # tight comprehension pass instead of a per-request loop
-            # (the dataclass API's host cost is exactly this extraction).
-            keys = [r.hash_key() for r in requests]
-            cols = make_columns(
-                np.fromiter((r.algorithm for r in requests), np.int32, count=n),
-                behavior,
-                np.fromiter((r.hits for r in requests), np.int64, count=n),
-                np.fromiter((r.limit for r in requests), np.int64, count=n),
-                np.fromiter((r.duration for r in requests), np.int64, count=n),
-                n,
-            )
-            status, remaining, reset = self._run_columns(keys, cols, now_ms)
-            limit = cols.limit
-            for j in range(n):
-                responses[j] = RateLimitResponse(
-                    status=int(status[j]),
-                    limit=int(limit[j]),
-                    remaining=int(remaining[j]),
-                    reset_time=int(reset[j]),
-                )
-            return
-        keys: List[str] = []
-        vidx = np.empty(n, dtype=np.int64)
-        cols = _Columns(n)
-        greg = GregResolver(now_ms)
-        m = 0
-        for i, req in enumerate(requests):
-            ge = gd = 0
-            if has_behavior(req.behavior, Behavior.DURATION_IS_GREGORIAN):
-                cached = greg.resolve(req.duration)
-                if isinstance(cached, gregorian.GregorianError):
-                    responses[i] = RateLimitResponse(error=str(cached))
-                    continue
-                ge, gd = cached
-            keys.append(req.hash_key())
-            vidx[m] = i
-            cols.set(m, req, ge, gd)
-            m += 1
-        if m == 0:
-            return
-        cols.trim(m)
-        status, remaining, reset = self._run_columns(keys, cols, now_ms)
-        limit = cols.limit
-        for j in range(m):
-            responses[int(vidx[j])] = RateLimitResponse(
-                status=int(status[j]),
-                limit=int(limit[j]),
-                remaining=int(remaining[j]),
-                reset_time=int(reset[j]),
-            )
-
-    def _run_columns(self, keys: List[str], cols: "_Columns", now_ms: int):
-        """Single-dispatch kernel path over pre-validated columns: the
-        C++ planner assigns every lane a (round, slot, exists) upfront,
-        the whole duplicate-round loop runs inside one jitted program
-        (buckets.apply_rounds), and all outputs come back in ONE packed
-        device->host transfer.  Returns (status, remaining, reset_time)
-        arrays aligned to keys."""
-        r = self._submit_pipelined(keys, cols, now_ms).result()
-        return r["status"], r["remaining"], r["reset_time"]
-
-    def _prepare_columns(self, keys: List[str], cols: "_Columns", now_ms: int,
-                         force_wire: Optional[str] = None,
-                         bt=None) -> "_ShardPrep":
-        """Stage 1 (under `_plan_lock`): everything that touches the
-        slot table — the C++ grouped plan, the pass-through expiry
-        snapshot — plus the cheap padded plan-column scatters.  No
-        device work and no packing: those run unlocked in stage 2, so
-        batch N+1's planning starts the moment batch N's plan is done,
-        regardless of where batch N is in its flight."""
-        n = len(keys)
-        with phase("dispatch.plan_native", bt):
-            planner = native.NativeBatchPlanner(self.table, keys, now_ms)
-            round_id, slots, exists, occ, write, n_rounds = planner.plan_grouped(
-                cols, int(Behavior.RESET_REMAINING)
-            )
-        padded = pad_size(n)
-        slot_col = np.full(padded, -1, dtype=np.int32)
-        slot_col[:n] = slots
-        rid_col = np.zeros(padded, dtype=np.int32)
-        rid_col[:n] = round_id
-        ex_col = np.zeros(padded, dtype=bool)
-        ex_col[:n] = exists
-        occ_col = np.zeros(padded, dtype=np.int32)
-        occ_col[:n] = occ
-        wr_col = np.zeros(padded, dtype=bool)
-        wr_col[:n] = write
-        narrow = narrow_ok(cols, now_ms) and force_wire != "wide"
-        # Snapshot the pass-through expiry NOW: the -2 keep-sentinel means
-        # "the kernel left this slot's pre-batch expiry unchanged", and
-        # pre-batch is defined at plan time.  A later pipelined batch's
-        # planning can evict/reassign these slots (zeroing expire_ms)
-        # before resolve() runs, so reading the table at resolve time
-        # would reconstruct a wrong reset_time for far-future
-        # pass-through lanes.
-        passthrough_exp = self.table.get_expire_bulk(slots) if narrow else None
-
-        def commit(packed_np):
-            with self._lock:
-                if narrow:
-                    status, removed, remaining, reset, new_exp = decode_narrow(
-                        self.table, keys, slots, packed_np[:, :n], now_ms,
-                        passthrough_exp,
-                    )
-                else:
-                    status, removed, remaining, reset, new_exp = buckets.unpack_output(
-                        packed_np[:, :n]
-                    )
-                planner.commit_plan(new_exp, removed)
-                self.algo_mirror[slots] = cols.algo
-                return status, remaining, reset
-
-        return _ShardPrep(
-            cols=cols, now_ms=now_ms, force_wire=force_wire, n=n,
-            padded=padded, n_rounds=n_rounds, narrow=narrow,
-            slot_col=slot_col, rid_col=rid_col, ex_col=ex_col,
-            occ_col=occ_col, wr_col=wr_col, commit=commit,
-        )
-
-    def _stage_columns(self, prep: "_ShardPrep") -> "_Staged":
-        """Stage 2 (no locks): encode the wire and START the H2D
-        upload.  The dict-wire path uploads ONE buffer and is
-        fuse-eligible; the fallback array wires launch solo."""
-        cols, now_ms, padded = prep.cols, prep.now_ms, prep.padded
-        n_rounds, narrow = prep.n_rounds, prep.narrow
-        dict_enc = None
-        if (prep.force_wire is None and n_rounds <= 255
-                and int(prep.occ_col.max(initial=0)) <= 65535):
-            # The dict wire carries values in its 256-row i64 table, so
-            # it works at ANY magnitude — wide batches (monthly/yearly
-            # Gregorian, big limits) only switch the OUTPUT width.
-            dict_enc = buckets.build_config_dict(cols, now_ms)
-        if dict_enc is not None:
-            cfg_idx, table = dict_enc
-            # Single-buffer wire: one host->device transfer per batch
-            # instead of 12 (per-call overhead dominates at service
-            # batch sizes).
-            wire = buckets.pack_dict_wire(
-                prep.slot_col[None, :], prep.ex_col[None, :],
-                prep.wr_col[None, :],
-                _pad(cfg_idx, padded, np.uint8)[None, :],
-                prep.occ_col[None, :], prep.rid_col[None, :], table,
-            )[0]
-            wire_dev = (
-                jax.device_put(wire, self.device)
-                if self.device is not None else jax.device_put(wire)
-            )
-            if _wire_donate_ok(self.device):
-                kern = (
-                    buckets.apply_rounds_packed_donated
-                    if narrow
-                    else buckets.apply_rounds_packed_wide_donated
-                )
-            else:
-                kern = (
-                    buckets.apply_rounds_packed_jit
-                    if narrow
-                    else buckets.apply_rounds_packed_wide_jit
-                )
-            return _Staged(
-                solo=lambda state: kern(state, wire_dev, n_rounds, now_ms),
-                fuse_key=("dict", narrow, wire.shape[0]),
-                wire_dev=wire_dev, n_rounds=n_rounds, now_ms=now_ms,
-                wide=not narrow,
-            )
-        if narrow:
-            greg_delta = np.where(
-                cols.greg_duration != 0, cols.greg_expire - now_ms, 0
-            ).astype(np.int32)
-            batch = buckets.make_batch32(
-                prep.slot_col,
-                prep.ex_col,
-                _pad(cols.algo, padded, np.int32),
-                _pad(cols.behavior, padded, np.int32),
-                _pad(cols.hits, padded, np.int32),
-                _pad(cols.limit, padded, np.int32),
-                _pad(cols.duration, padded, np.int32),
-                _pad(greg_delta, padded, np.int32),
-                _pad(cols.greg_duration, padded, np.int32),
-                occ=prep.occ_col,
-                write=prep.wr_col,
-            )
-            return _Staged(
-                solo=lambda state: buckets.apply_rounds32_jit(
-                    state, batch, prep.rid_col, n_rounds, now_ms
-                )
-            )
-        batch = buckets.make_batch(
-            prep.slot_col,
-            prep.ex_col,
-            _pad(cols.algo, padded, np.int32),
-            _pad(cols.behavior, padded, np.int32),
-            _pad(cols.hits, padded, np.int64),
-            _pad(cols.limit, padded, np.int64),
-            _pad(cols.duration, padded, np.int64),
-            _pad(cols.greg_expire, padded, np.int64),
-            _pad(cols.greg_duration, padded, np.int64),
-            occ=prep.occ_col,
-            write=prep.wr_col,
-        )
-        return _Staged(
-            solo=lambda state: buckets.apply_rounds_jit(
-                state, batch, prep.rid_col, n_rounds, now_ms
-            )
-        )
-
-    def _fused_launch_fn(self, k: int, wide: bool):
-        return buckets.fused_packed_jit(
-            k, wide, donate_wires=_wire_donate_ok(self.device)
-        )
-
-    # -- express scalar slot (ops/scalar.py) ---------------------------
-    def _scalar_eligible(self, cols) -> bool:
-        """Small batches on a CPU backend take the host scalar path
-        when the service enabled it (scalar_fast_path) and the one-time
-        writable-buffer capability probe passed.  Lanes apply
-        sequentially in submission order — exactly the semantics the
-        kernel's round/duplicate-group machinery reproduces — so width
-        is a cost cap, not a correctness bound."""
-        if not self.scalar_fast_path:
-            return False
-        if not 1 <= len(cols.hits) <= self.scalar_max_lanes:
-            return False
-        if not (self._native and self.store is None):
-            return False
-        if self._scalar_ok is None:
-            with self._lock:
-                # In-flight async programs must finish before the probe
-                # writes a spare lane of the live buffer.
-                jax.block_until_ready(self.state)
-                self._scalar_ok = scalar_ops.device_is_cpu(
-                    self.device
-                ) and scalar_ops.probe(self.state.hot, sharded=False)
-        return self._scalar_ok
-
-    def _stage_scalar(self, prep: "_ShardPrep") -> "_Staged":
-        """Express stage: capture the plan's slot rows and return the
-        host-evaluation closure.  The closure runs at the launch turn
-        under `_lock` (ColumnarPipeline._launch_group) and returns a
-        packed [4, n] wide output the ordinary commit decodes."""
-        cols = prep.cols
-        n = prep.n
-        slots = prep.slot_col[:n].copy()
-        exists = prep.ex_col[:n].copy()
-        occ = prep.occ_col[:n].copy()
-        now_ms = prep.now_ms
-
-        def run():
-            hot = scalar_ops.single_view(self.state.hot)
-            cold = scalar_ops.single_view(self.state.cold)
-            if hot is None or cold is None:
-                raise RuntimeError("scalar fast path: state view unavailable")
-            packed = np.zeros((4, n), dtype=np.int64)
-            for i in range(n):
-                slot = int(slots[i])
-                # Exists per lane: the planner's claim, EXCEPT that a
-                # later occurrence of an analytic duplicate group
-                # (occ > 0) shares the FIRST occurrence's pre-group
-                # claim — sequentially, the prior occurrence's write
-                # made the row live.  Round-1+ same-key lanes already
-                # carry exists=True from the planner, and a mid-batch
-                # slot TAKEOVER (different key, occ == 0,
-                # exists=False) must keep creating.
-                ex = bool(exists[i]) or int(occ[i]) > 0
-                st, rem, reset, n_exp, removed = scalar_ops.apply_one(
-                    hot[slot], cold[slot],
-                    exists=ex,
-                    algorithm=int(cols.algo[i]),
-                    behavior=int(cols.behavior[i]),
-                    hits=int(cols.hits[i]),
-                    limit=int(cols.limit[i]),
-                    duration=int(cols.duration[i]),
-                    greg_expire=int(cols.greg_expire[i]),
-                    greg_duration=int(cols.greg_duration[i]),
-                    now_ms=now_ms,
-                )
-                packed[0, i] = st | (int(removed) << 1)
-                packed[1, i] = rem
-                packed[2, i] = reset
-                packed[3, i] = n_exp
-            return packed
-
-        return _Staged(solo=None, scalar=run)
-
-    @property
-    def supports_columns(self) -> bool:
-        """True when the zero-dataclass bulk path is usable."""
-        return self._native and self.store is None
-
-    def apply_columns(
-        self,
-        keys: List[str],
-        algorithm,
-        behavior,
-        hits,
-        limit,
-        duration,
-        now_ms: int,
-        greg_expire=None,
-        greg_duration=None,
-        force_wire=None,
-    ):
-        """Columnar bulk API: the zero-dataclass ingress path.
-
-        `keys` are full hash keys (name + '_' + unique_key); the array
-        args align with them.  Gregorian expiry/duration must be
-        precomputed by the caller when DURATION_IS_GREGORIAN is set
-        (utils.gregorian).  Returns a dict of numpy arrays:
-        status/limit/remaining/reset_time.  Requires the native runtime
-        and no Store SPI (use `apply` otherwise).
-        """
-        cols = self._make_columns(algorithm, behavior, hits, limit, duration,
-                                  len(keys), greg_expire, greg_duration)
-        return self._submit_pipelined(keys, cols, now_ms, force_wire).result()
-
-    def apply_columns_async(
-        self,
-        keys: List[str],
-        algorithm,
-        behavior,
-        hits,
-        limit,
-        duration,
-        now_ms: int,
-        greg_expire=None,
-        greg_duration=None,
-        force_wire=None,
-    ) -> ColumnsHandle:
-        """Pipelined apply_columns: plans and enqueues the batch, then
-        returns immediately with a ColumnsHandle; `handle.result()`
-        blocks on the device readback.  Dispatching batch i+1 before
-        resolving batch i overlaps host planning and transfer with
-        device compute — the throughput shape of a batching ingress
-        pipeline (the reference's interval-drained queues,
-        peer_client.go:272-312, feeding a device instead of a socket).
-
-        Pipelined planning reads slot-table expiry that is stale by the
-        unresolved depth; the kernel revalidates expiry device-side, so
-        the only observable effect is eviction under pressure acting on
-        slightly old expire times."""
-        cols = self._make_columns(algorithm, behavior, hits, limit, duration,
-                                  len(keys), greg_expire, greg_duration)
-        return self._submit_pipelined(keys, cols, now_ms, force_wire)
-
-    def _make_columns(self, algorithm, behavior, hits, limit, duration, n,
-                      greg_expire, greg_duration) -> "_Columns":
-        if not (self._native and self.store is None):
-            raise RuntimeError(
-                "apply_columns requires the native host runtime and no Store SPI"
-            )
-        return make_columns(algorithm, behavior, hits, limit, duration, n,
-                            greg_expire, greg_duration)
-
-    # ------------------------------------------------------------------
-    # Store SPI integration
-    # ------------------------------------------------------------------
-    def _store_resolver(self, now_ms: int):
-        return make_store_resolver(
-            self.table, self.algo_mirror, self.store, self._inject, now_ms
-        )
-
-    def _inject(self, slot: int, item) -> None:
-        """Write one CacheItem into the device row + host mirrors."""
-        rows = item_to_rows(item)
-        self.algo_mirror[slot] = int(rows.algo[0])
-        self.state = buckets.write_rows(self.state, np.array([slot], np.int32), rows)
-        self.table.set_expire(slot, item.expire_at)
-
-    def load_item(self, item) -> None:
-        """Loader.Load path: place one persisted item (gubernator.go:78-90)."""
-        self._drain_then_lock()
-        try:
-            slot, _ = self.table.lookup_or_assign(item.key, 0)
-            self._inject(slot, item)
-        finally:
-            self._unlock_drained()
-
-    def snapshot_items(self):
-        """Loader.Save path: every mapped slot as a CacheItem
-        (gubernator.go:93-111); drains in-flight batches first so the
-        snapshot reflects every dispatched batch's committed state."""
-        self._drain_then_lock()
-        try:
-            keys = self.table.keys()
-            if not keys:
-                return []
-            slots = [self.table.get_slot(k) for k in keys]
-            rows = buckets.read_rows(self.state, np.asarray(slots, np.int32))
-            return _rows_to_items(keys, rows)
-        finally:
-            self._unlock_drained()
-
-    # ------------------------------------------------------------------
-    # Elastic membership: columnar state handoff (reshard.py) — the
-    # single-shard twin of MeshBucketStore.drain_keys/commit_transfer.
-    # ------------------------------------------------------------------
-    def resident_keys(self) -> List[str]:
-        """Keys currently resident in the slot table (ring-delta scan
-        input).  Host-only, no device programs — held under the plan
-        lock (like snapshot_items): the native key enumeration is a
-        size-then-fill marshal that a concurrent planner growing the
-        table would overrun."""
-        self._drain_then_lock()
-        try:
-            return list(self.table.keys())
-        finally:
-            self._unlock_drained()
-
-    def resident_mask(self, keys) -> np.ndarray:
-        """Which keys currently map to a slot (the handoff peek's
-        observe-don't-create filter; see MeshBucketStore)."""
-        out = np.zeros(len(keys), dtype=bool)
-        for j, k in enumerate(keys):
-            out[j] = self.table.get_slot(k) is not None
-        return out
-
-    def drain_keys(self, keys, now_ms: int, remove: bool = True):
-        """Drain moved keys: ONE gather program for the whole batch
-        (atomic w.r.t. dispatches — the pipeline is drained and the
-        plan lock held).  remove=False leaves the table untouched (the
-        handoff's gather-then-forget-on-ack protocol); expired rows are
-        never shipped."""
-        self._drain_then_lock()
-        try:
-            return self._gather_transfer_locked(keys, now_ms, remove)
-        finally:
-            self._unlock_drained()
-
-    def snapshot_columns(self, now_ms: int):
-        """Durability dump (snapshot.py): every resident key's full
-        bucket row in ONE gather program — drain_keys' all-keys variant
-        (gather-only, nothing removed).  Warmup keys are synthetic
-        compile fodder and stay out of the file."""
-        self._drain_then_lock()
-        try:
-            keys = [
-                k for k in self.table.keys()
-                if not k.startswith("__warmup__")
-            ]
-            return self._gather_transfer_locked(keys, now_ms, remove=False)
-        finally:
-            self._unlock_drained()
-
-    def _gather_transfer_locked(self, keys, now_ms: int, remove: bool):
-        from ..reshard import TransferColumns
-
-        found = [
-            (k, s) for k in keys
-            if (s := self.table.get_slot(k)) is not None
-        ]
-        if not found:
-            return TransferColumns.empty()
-        slots = np.asarray([s for _, s in found], np.int32)
-        rows = jax.tree.map(
-            np.asarray, buckets.read_rows(self.state, slots)
-        )
-        self.device_dispatches += 1
-        if remove:
-            for k, _ in found:
-                self.table.remove(k)
-        live = np.nonzero(np.asarray(rows.expire_at) >= now_ms)[0]
-        return TransferColumns(
-            keys=[found[int(i)][0] for i in live],
-            algorithm=np.asarray(rows.algo)[live].astype(np.int32),
-            status=np.asarray(rows.status)[live].astype(np.int32),
-            limit=np.asarray(rows.limit)[live].astype(np.int64),
-            remaining=np.asarray(rows.remaining)[live].astype(np.int64),
-            duration=np.asarray(rows.duration)[live].astype(np.int64),
-            stamp=np.asarray(rows.stamp)[live].astype(np.int64),
-            expire_at=np.asarray(rows.expire_at)[live].astype(np.int64),
-        )
-
-    def forget_keys(self, keys) -> None:
-        """Drop keys from the table after a transfer ACK (no device
-        program; see MeshBucketStore.forget_keys)."""
-        self._drain_then_lock()
-        try:
-            for k in keys:
-                self.table.remove(k)
-        finally:
-            self._unlock_drained()
-
-    def commit_transfer(self, cols, now_ms: int) -> int:
-        """Receive side of an ownership transfer: assign slots, gather
-        the CURRENT rows for already-resident keys, merge monotonically
-        (reshard.merge_transfer_rows — idempotent under re-delivery),
-        and scatter back.  O(1) device programs per batch (gather +
-        scatter), counted in `device_dispatches`."""
-        from ..reshard import merge_transfer_rows
-
-        n = len(cols)
-        if n == 0:
-            return 0
-        self._drain_then_lock()
-        try:
-            fresh = np.nonzero(np.asarray(cols.expire_at) >= now_ms)[0]
-            seen: Dict[str, int] = {}
-            for j in fresh:
-                seen[cols.keys[int(j)]] = int(j)
-            idx = np.fromiter(seen.values(), np.int64, count=len(seen))
-            if not idx.size:
-                return 0
-            slots = np.empty(idx.size, np.int32)
-            exists = np.zeros(idx.size, dtype=bool)
-            for j, i in enumerate(idx):
-                slots[j], exists[j] = self.table.lookup_or_assign(
-                    cols.keys[int(i)], now_ms
-                )
-            cur = jax.tree.map(
-                np.asarray, buckets.read_rows(self.state, slots)
-            )
-            merged = merge_transfer_rows(
-                {
-                    "algo": cur.algo, "status": cur.status,
-                    "limit": cur.limit, "remaining": cur.remaining,
-                    "stamp": cur.stamp, "expire_at": cur.expire_at,
-                },
-                cols, idx, now_ms, exists,
-            )
-            self.state = buckets.write_rows(
-                self.state, slots,
-                buckets.BucketRows(
-                    algo=merged["algo"], limit=merged["limit"],
-                    remaining=merged["remaining"],
-                    duration=merged["duration"], stamp=merged["stamp"],
-                    expire_at=merged["expire_at"], status=merged["status"],
-                ),
-            )
-            self.device_dispatches += 2
-            self.algo_mirror[slots] = merged["algo"]
-            for j in range(idx.size):
-                self.table.set_expire(
-                    int(slots[j]), int(merged["expire_at"][j])
-                )
-            return int(idx.size)
-        finally:
-            self._unlock_drained()
-
-    # ------------------------------------------------------------------
-    def _run_round(
-        self,
-        chunk: List[_Prepared],
-        now_ms: int,
-        responses: List[Optional[RateLimitResponse]],
-    ) -> None:
-        b = len(chunk)
-        arrays = build_round_arrays(chunk, pad_size(b))
-        batch = buckets.make_batch(*arrays)
-        self.state, out = buckets.apply_batch_jit(self.state, batch, now_ms)
-
-        # device_get on the whole pytree overlaps the transfers (one
-        # round-trip instead of five sequential blocking readbacks).
-        out = jax.device_get(out)
-        out_status = out.status
-        out_rem = out.remaining
-        out_reset = out.reset_time
-        out_exp = out.new_expire
-        out_removed = out.removed
-
-        slot = arrays[0]
-        self.table.commit(
-            slot[:b], out_exp[:b], out_removed[:b], keys=[p.key for p in chunk]
-        )
-        for i, p in enumerate(chunk):
-            self.algo_mirror[p.slot] = int(p.req.algorithm)
-            responses[p.pos] = RateLimitResponse(
-                status=int(out_status[i]),
-                limit=int(p.req.limit),
-                remaining=int(out_rem[i]),
-                reset_time=int(out_reset[i]),
-            )
-        if self.store is not None:
-            self._fire_store_callbacks(chunk, out_removed)
-
-    # ------------------------------------------------------------------
-    def _fire_store_callbacks(self, chunk, out_removed) -> None:
-        """Post-round Store calls: remove for freed slots
-        (algorithms.go:38-40), on_change with the post-apply item for
-        everything else (the deferred s.OnChange, algorithms.go:64-68)."""
-        live = [(i, p) for i, p in enumerate(chunk) if not out_removed[i]]
-        for i, p in enumerate(chunk):
-            if out_removed[i]:
-                self.store.remove(p.key)
-        if not live:
-            return
-        rows = buckets.read_rows(
-            self.state, np.asarray([p.slot for _, p in live], np.int32)
-        )
-        items = _rows_to_items([p.key for _, p in live], rows)
-        for (_, p), item in zip(live, items):
-            self.store.on_change(p.req, item)
-
-    # ------------------------------------------------------------------
-    def size(self) -> int:
-        return len(self.table)
-
-
 def make_store_resolver(table, algo_mirror, store, inject_fn, now_ms: int):
     """Slot resolution wrapped with the reference's Store call pattern:
     cache miss -> store.get -> inject (algorithms.go:26-33); cached item
     with switched algorithm -> store.remove + re-get
-    (algorithms.go:54-62,196-204).  Shared by ShardStore and
-    MeshBucketStore (per-shard tables, one store)."""
+    (algorithms.go:54-62,196-204).  One resolver per shard table,
+    one store."""
 
     def resolve(p):
         slot, exists = table.lookup_or_assign(p.key, now_ms)

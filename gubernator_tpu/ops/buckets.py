@@ -316,8 +316,8 @@ class BatchOutput(NamedTuple):
     removed: jax.Array  # bool[B] token RESET_REMAINING freed the slot
     # The slot's stored expiry as this lane's round GATHERED it (free:
     # the kernel reads it anyway).  The narrow wire's -2 keep-sentinel
-    # detector; replaces a separate whole-batch pre-gather that round 4
-    # measured at ~1ms/131k batch on TPU (probe_r4b_narrow).
+    # detector; replaces a separate whole-batch pre-gather (measured
+    # at ~1ms per 131k-lane batch on a TPU; see git history).
     pre_expire: jax.Array  # i64[B]
 
 
@@ -367,7 +367,7 @@ def apply_batch(
 
     Pure function: returns (new_state, responses).  Slots must be unique
     within the batch (the host splits duplicate-key batches into
-    flush-separated rounds; see ShardStore.apply) so the gather/scatter
+    flush-separated rounds; see RoundPlanner) so the gather/scatter
     is race-free.
 
     `cold_cond` (static) guards the cold-row scatter with a lax.cond so
@@ -659,9 +659,6 @@ def _apply_compute(
     return out, new
 
 
-apply_batch_jit = jax.jit(apply_batch, donate_argnums=0)
-
-
 def _pack_output(out: BatchOutput, with_pre: bool = False) -> jax.Array:
     """Fuse the per-lane outputs into ONE i64[4, B] array so the host
     pays a single device->host transfer per batch instead of five (each
@@ -678,19 +675,6 @@ def _pack_output(out: BatchOutput, with_pre: bool = False) -> jax.Array:
         return jnp.stack(rows)
 
 
-def unpack_output(packed):
-    """Host-side twin of _pack_output: (status, removed, remaining,
-    reset_time, new_expire) numpy views from the packed i64[4, B]."""
-    row0 = packed[0]
-    return (
-        (row0 & 1).astype("int32"),
-        (row0 >> 1).astype(bool),
-        packed[1],
-        packed[2],
-        packed[3],
-    )
-
-
 def apply_rounds(
     state: BucketState, req: RequestBatch, round_id, n_rounds, now_ms,
     cold_cond: bool = True,
@@ -705,8 +689,8 @@ def apply_rounds(
     between rounds.  `n_rounds` is a traced scalar: one compilation
     serves every round count at a given batch width.
 
-    Returns (new_state, packed_output i64[4, B]); decode with
-    unpack_output.
+    Returns (new_state, packed_output i64[4, B]): the layout of
+    _pack_output, decoded host-side by gt_mesh_finish_wide.
     """
     return _apply_rounds_impl(
         state, req, round_id, n_rounds, now_ms, cold_cond, with_pre=False
@@ -739,11 +723,6 @@ def _apply_rounds_impl(
             cond, body, (jnp.asarray(0, _I32), state, packed0)
         )
     return state, packed
-
-
-apply_rounds_jit = jax.jit(
-    apply_rounds, donate_argnums=0, static_argnames=("cold_cond",)
-)
 
 
 class RequestBatch32(NamedTuple):
@@ -796,13 +775,13 @@ def apply_rounds32(
     Input columns upcast on device; the packed result narrows to
     i32[4, B] (row 0 bit-packs status/removed; rows 1-3 are remaining,
     reset_time - now, new_expire - now).  Callers must guarantee the
-    narrow preconditions (ShardStore checks them host-side):
+    narrow preconditions (narrow_ok checks them host-side):
     limit/hits/duration in [0, 2**31) and Gregorian deltas in range.
     Those bound every value the kernel COMPUTES this batch; a time the
     kernel merely passes through unchanged (a live bucket's stored
     expiry, which may lie arbitrarily far in the future from a wide
     batch) is encoded as the sentinel -2 ("unchanged") and reconstructed
-    host-side from the slot table (unpack_output32), never clipped.
+    host-side from the slot table (gt_mesh_finish_narrow), never clipped.
     """
     now = jnp.asarray(now_ms, _I64)
     req = RequestBatch(
@@ -856,97 +835,6 @@ def apply_rounds32(
             )
         ).astype(_I32)
     return state, packed32
-
-
-apply_rounds32_jit = jax.jit(
-    apply_rounds32, donate_argnums=0, static_argnames=("cold_cond",)
-)
-
-
-def apply_compact32(
-    state: BucketState, req32: RequestBatch32, wlane, now_ms,
-) -> "tuple[BucketState, jax.Array]":
-    """Single-round narrow kernel with a COMPACTED commit.
-
-    XLA's random-row scatter prices per SUBMITTED row — ~21ns each on
-    TPU v5e — whether or not mode='drop' discards it, so the per-lane
-    commit pays for all B lanes even when the grouped planner marked
-    only ~25% as writers (measured Zipf write fraction 0.235,
-    probe/bench round 4).  Here the host ALSO sends `wlane` (i32[Pw]):
-    the batch lanes that commit state, compacted and padded with -1.
-    The kernel computes all lanes as usual, then gathers just the
-    write lanes' rows and scatters Pw rows instead of B.
-
-    Legal ONLY for single-round plans (n_rounds == 1 — the grouped
-    planner's common case): multi-round batches need the scatter
-    between rounds.  Callers guarantee wlane lists exactly the plan's
-    write lanes.  Output packing is identical to apply_rounds32.
-    """
-    now = jnp.asarray(now_ms, _I64)
-    req = RequestBatch(
-        slot=req32.slot,
-        exists=req32.exists,
-        algorithm=req32.algorithm,
-        behavior=req32.behavior,
-        hits=req32.hits.astype(_I64),
-        limit=req32.limit.astype(_I64),
-        duration=req32.duration.astype(_I64),
-        greg_expire=now + req32.greg_expire_delta.astype(_I64),
-        greg_duration=req32.greg_duration.astype(_I64),
-        occ=req32.occ,
-        write=req32.write,
-    )
-    out, new = _apply_compute(state, req, now_ms)
-
-    with jax.named_scope(SCOPE_COMMIT):
-        C = state.hot.shape[0]
-        wl = jnp.clip(wlane, 0, req.slot.shape[0] - 1)
-        wvalid = (wlane >= 0) & new.writes[wl]
-        lane = jnp.arange(wlane.shape[0], dtype=_I32)
-        dst = jnp.where(wvalid, req.slot[wl], C + lane)
-        drop = dict(mode="drop", unique_indices=True)
-        hot_rows = _pack_hot(new.flags, new.rem, new.stamp, new.exp)[wl]
-        new_hot = state.hot.at[dst].set(hot_rows, **drop)
-
-        ccold = wvalid & new.cold_changed[wl]
-        dst_cold = jnp.where(ccold, req.slot[wl], C + lane)
-        cold_rows = _pack_cold(new.limit, new.dur)[wl]
-
-        def _scatter_cold(args):
-            cold, idx, rows = args
-            return cold.at[idx].set(rows, **drop)
-
-        new_cold = jax.lax.cond(
-            jnp.any(ccold), _scatter_cold, lambda a: a[0],
-            (state.cold, dst_cold, cold_rows),
-        )
-        state = BucketState(hot=new_hot, cold=new_cold)
-
-    pre_exp = out.pre_expire
-    hi = jnp.asarray((1 << 31) - 1, _I64)
-
-    def delta(v):
-        d = v - now
-        fits = (d >= 0) & (d <= hi)
-        return jnp.where(
-            v == 0, -1,
-            jnp.where(fits, d, jnp.where(v == pre_exp, -2, jnp.clip(d, 0, hi))),
-        )
-
-    with jax.named_scope(SCOPE_ANSWER_PACK):
-        row0 = out.status.astype(_I64) | (out.removed.astype(_I64) << 1)
-        packed32 = jnp.stack(
-            (
-                row0,
-                jnp.clip(out.remaining, 0, hi),
-                delta(out.reset_time),
-                delta(out.new_expire),
-            )
-        ).astype(_I32)
-    return state, packed32
-
-
-apply_compact32_jit = jax.jit(apply_compact32, donate_argnums=0)
 
 
 class RequestBatchDict(NamedTuple):
@@ -1108,46 +996,12 @@ def apply_rounds_packed(
     return apply_rounds_dict(state, reqd, rid, n_rounds, now_ms, cold_cond=cold_cond)
 
 
-apply_rounds_packed_jit = jax.jit(
-    apply_rounds_packed, donate_argnums=0, static_argnames=("cold_cond",)
-)
-
-
-def apply_compact_packed(
-    state: BucketState, wire, wlane, now_ms
-) -> "tuple[BucketState, jax.Array]":
-    """apply_compact32 behind the single-buffer dict wire: the
-    production fast path for SINGLE-ROUND narrow batches — the compact
-    commit scatters only the plan's write lanes (wlane i32[Pw],
-    -1-padded) instead of all B lanes.  The wire's round_id words are
-    ignored (every lane is round 0 by the caller's n_rounds==1
-    guarantee)."""
-    P = (wire.shape[0] - DICT_WIRE_TABLE_WORDS) // 3
-    slot, fl, cfg, occ, _rid, rows = unpack_dict_wire(wire, P)
-    with jax.named_scope(SCOPE_WIRE_DECODE):
-        cfg = cfg.astype(_I32)
-        req32 = RequestBatch32(
-            slot=slot,
-            exists=(fl & 1) != 0,
-            algorithm=rows[0][cfg],
-            behavior=rows[1][cfg],
-            hits=rows[2][cfg].astype(_I32),
-            limit=rows[3][cfg].astype(_I32),
-            duration=rows[4][cfg].astype(_I32),
-            greg_expire_delta=rows[5][cfg].astype(_I32),
-            greg_duration=rows[6][cfg].astype(_I32),
-            occ=occ.astype(_I32),
-            write=(fl & 2) != 0,
-        )
-    return apply_compact32(state, req32, wlane, now_ms)
-
-
 def apply_rounds_packed_wide(
     state: BucketState, wire, n_rounds, now_ms, cold_cond: bool = True
 ) -> "tuple[BucketState, jax.Array]":
     """Wide-output twin of apply_rounds_packed: same single-buffer wire,
-    int64 compute and a packed i64[4, B] result (decode with
-    unpack_output).  This is what keeps monthly/yearly Gregorian
+    int64 compute and a packed i64[4, B] result (the layout of
+    _pack_output).  This is what keeps monthly/yearly Gregorian
     batches on the dict wire: their far-future expiries exceed the
     narrow output's i32 deltas, but per-lane bytes are identical —
     only the readback doubles.  Matches interval.go:82-146 being
@@ -1173,84 +1027,6 @@ def apply_rounds_packed_wide(
             write=(fl & 2) != 0,
         )
     return apply_rounds(state, req, rid, n_rounds, now_ms, cold_cond=cold_cond)
-
-
-apply_rounds_packed_wide_jit = jax.jit(
-    apply_rounds_packed_wide, donate_argnums=0, static_argnames=("cold_cond",)
-)
-
-# Donating twins for the overlapped dispatch pipeline (models/shard.py):
-# the wire buffer is a fresh per-batch device upload that nothing reads
-# after the kernel, so donating it lets XLA recycle its bytes into the
-# outputs instead of allocating per batch.  Separate wrappers — the
-# plain _jit forms accept host numpy wires (tests, fallback callers),
-# which donation would spam warnings about.
-apply_rounds_packed_donated = jax.jit(
-    apply_rounds_packed, donate_argnums=(0, 1), static_argnames=("cold_cond",)
-)
-apply_rounds_packed_wide_donated = jax.jit(
-    apply_rounds_packed_wide, donate_argnums=(0, 1), static_argnames=("cold_cond",)
-)
-
-
-def apply_rounds_packed_fused(state, wires, n_rounds_vec, now_vec,
-                              wide: bool = False, cold_cond: bool = True):
-    """Apply K same-shape packed-wire batches SEQUENTIALLY inside one
-    program (the launch-fusion kernel of the overlapped dispatch
-    pipeline, models/shard.py ColumnarPipeline._launch_group).
-
-    Semantically identical to K solo apply_rounds_packed[_wide] calls in
-    order — batch i+1 sees the state batch i left — but the host pays
-    ONE dispatch (and the caller one readback) for the group, so the
-    fixed per-dispatch cost (the per-call enqueue) amortizes over K
-    batches.  `wires` is a tuple of K
-    equal-shape wire buffers; n_rounds_vec/now_vec are [K] arrays
-    (traced, so one compilation per (K, wire-shape) serves every round
-    count and timestamp).  Returns (state, stacked [K, 4, P] results).
-    """
-    fn = apply_rounds_packed_wide if wide else apply_rounds_packed
-    outs = []
-    for i, w in enumerate(wires):
-        state, packed = fn(state, w, n_rounds_vec[i], now_vec[i],
-                           cold_cond=cold_cond)
-        outs.append(packed)
-    return state, jnp.stack(outs)
-
-
-_FUSED_PACKED_JIT: dict = {}
-
-
-def fused_packed_jit(k: int, wide: bool, cold_cond: bool = True,
-                     donate_wires: bool = True):
-    """Jitted apply_rounds_packed_fused for a fixed group size `k`
-    (call as fn(state, w_0, ..., w_{k-1}, n_rounds_vec, now_vec)).
-    State is always donated; wires too unless `donate_wires` is False
-    (CPU zero-copies uploads from host numpy, so their buffers are not
-    donatable there — the caller passes the platform's verdict).
-    Cached module-wide so all stores in a process share one compilation
-    per (k, wide, cold_cond, shape)."""
-    key = (k, wide, cold_cond, donate_wires)
-    fn = _FUSED_PACKED_JIT.get(key)
-    if fn is None:
-
-        def run(state, *args):
-            return apply_rounds_packed_fused(
-                state, args[:k], args[k], args[k + 1],
-                wide=wide, cold_cond=cold_cond,
-            )
-
-        donate = tuple(range(k + 1)) if donate_wires else (0,)
-        fn = jax.jit(run, donate_argnums=donate)
-        _FUSED_PACKED_JIT[key] = fn
-        # XLA telemetry (telemetry.py): one more distinct jitted
-        # callable in the program population — the compile itself is
-        # counted by the monitoring listener when it happens.
-        from .. import telemetry
-
-        telemetry.note_program_created(
-            f"fused_packed:k{k}:{'wide' if wide else 'narrow'}"
-        )
-    return fn
 
 
 def build_config_dict(cols, now_ms: int):
@@ -1293,34 +1069,6 @@ def build_config_dict(cols, now_ms: int):
         row[: len(uq)] = c[idx_first]
         table.append(row)
     return inv.astype(np.uint8), tuple(table)
-
-
-def unpack_output32(packed, now_ms: int, table_expire):
-    """Host-side twin of apply_rounds32's packing: (status, removed,
-    remaining, reset_time, new_expire) with absolute int64 times.
-
-    Sentinels: -1 decodes to absolute 0 (removed/no-reset); -2 means
-    "unchanged pass-through" — reset_time reconstructs from
-    `table_expire` (the slot table's pre-commit value, identical to the
-    device's pre-batch expire), and new_expire stays -1 so commit_plan
-    skips the (already correct) host bookkeeping.
-    """
-    import numpy as np
-
-    row0 = packed[0]
-    te = np.asarray(table_expire, dtype="int64")
-
-    def undelta(row, keep):
-        d = row.astype("int64")
-        return np.where(d == -2, keep, np.where(d == -1, 0, d + now_ms))
-
-    return (
-        (row0 & 1).astype("int32"),
-        ((row0 >> 1) & 1).astype(bool),
-        packed[1].astype("int64"),
-        undelta(packed[2], te),
-        undelta(packed[3], np.int64(-1)),
-    )
 
 
 @jax.jit

@@ -120,11 +120,6 @@ def shard_view(dev_arr, s: int) -> Optional[np.ndarray]:
     return None
 
 
-def single_view(dev_arr) -> Optional[np.ndarray]:
-    """Writable view of an unsharded (single-device) jax array."""
-    return _writable_view(dev_arr)
-
-
 def device_is_cpu(device) -> bool:
     try:
         if device is not None:
@@ -136,13 +131,13 @@ def device_is_cpu(device) -> bool:
         return False
 
 
-def probe(state_hot, sharded: bool = False) -> bool:
+def probe(state_hot) -> bool:
     """One-time capability probe: can we obtain a writable view of this
     state array's buffer AND does the write alias the buffer jax reads?
     Probes the first row's spare lane (hot lane 7 — always zero and
     ignored by the kernel) and restores it.  Called once per store,
     under the store lock."""
-    v = shard_view(state_hot, 0) if sharded else single_view(state_hot)
+    v = shard_view(state_hot, 0)
     if v is None:
         return False
     flat = v.reshape(-1)

@@ -723,10 +723,18 @@ class MeshBucketStore(ColumnarPipeline):
         self, keys, algorithm, behavior, hits, limit, duration, now_ms: int,
         greg_expire=None, greg_duration=None, force_wire=None,
     ) -> ColumnsHandle:
-        """Pipelined apply_columns (see ShardStore.apply_columns_async):
-        dispatch returns immediately; `handle.result()` blocks on the
-        one packed readback.  Concurrent ingress threads overlap host
-        planning with device compute via the ColumnarPipeline locks."""
+        """Pipelined apply_columns: plans and enqueues the batch, then
+        returns immediately with a ColumnsHandle; `handle.result()`
+        blocks on the one packed readback.  Dispatching batch i+1
+        before resolving batch i overlaps host planning and transfer
+        with device compute via the ColumnarPipeline locks (the
+        reference's interval-drained queues, peer_client.go:272-312,
+        feeding a device instead of a socket).
+
+        Pipelined planning reads slot-table expiry that is stale by the
+        unresolved depth; the kernel revalidates expiry device-side, so
+        the only observable effect is eviction under pressure acting on
+        slightly old expire times."""
         if not (self._native and self.store is None):
             raise RuntimeError(
                 "apply_columns requires the native host runtime and no Store SPI"
@@ -810,12 +818,8 @@ class MeshBucketStore(ColumnarPipeline):
                 mp.slot, mp.exists, mp.write, cfg_a, mp.occ, mp.rid, cfg_table
             )
             wire_dev = jax.device_put(wire, self._sharding)
-            # (A compacted-commit variant — scatter only the write
-            # lanes, buckets.apply_compact32 — measured SLOWER on TPU
-            # v5e despite submitting ~4x fewer rows: the scatter's
-            # price at these shapes is not per-submitted-row.  See
-            # benchmarks/RESULTS.md round-4 notes; the kernel remains
-            # available and equivalence-tested.)
+            # (A single-round compacted scatter — commit only the write
+            # lanes — measured slower on TPU; see git history.)
             if self._wire_donate:
                 fn_packed = (
                     _rounds_packed_mesh_donated if narrow
@@ -878,11 +882,15 @@ class MeshBucketStore(ColumnarPipeline):
 
     # -- express scalar slot (ops/scalar.py) ---------------------------
     def _scalar_eligible(self, cols) -> bool:
-        """Mesh twin of ShardStore._scalar_eligible: each lane of a
-        small batch lives in exactly one shard, so the host evaluates
-        them sequentially against the shards' rows through writable
-        shard views — no mesh-wide program.  Two-tier stores are
-        excluded (their plans queue tier moves that only the device
+        """Small batches on a CPU backend take the host scalar path
+        when the service enabled it (scalar_fast_path) and the one-time
+        writable-buffer capability probe passed.  Each lane lives in
+        exactly one shard, so the host evaluates them sequentially, in
+        submission order, against the shards' rows through writable
+        shard views — no mesh-wide program; that order is exactly what
+        the kernel's round/duplicate-group machinery reproduces, so
+        width is a cost cap, not a correctness bound.  Two-tier stores
+        are excluded (their plans queue tier moves that only the device
         launch drains)."""
         if not self.scalar_fast_path:
             return False
@@ -897,17 +905,16 @@ class MeshBucketStore(ColumnarPipeline):
                 jax.block_until_ready(self.state)
                 self._scalar_ok = scalar_ops.device_is_cpu(
                     self.mesh.devices.flat[0]
-                ) and scalar_ops.probe(self.state.hot, sharded=True)
+                ) and scalar_ops.probe(self.state.hot)
         return self._scalar_ok
 
     def _stage_scalar(self, prep: "_MeshPrep") -> "_Staged":
         """Express stage: locate each lane's (shard, row) from the mesh
         plan and return the host-evaluation closure; its packed
         [S, 4, P] wide output feeds the unchanged mp.finish_wide commit
-        (decode + slot-table commit + original-order scatter).  Lanes
-        apply sequentially in submission order — the semantics the
-        kernel's round/duplicate-group machinery reproduces (see
-        ShardStore._stage_scalar for the exists rule)."""
+        (decode + slot-table commit + original-order scatter).  The
+        closure runs at the launch turn under `_lock`
+        (ColumnarPipeline._launch_group)."""
         cols, mp, padded = prep.cols, prep.mp, prep.padded
         n = prep.n
         pos = prep.pos[:n].copy()
@@ -930,6 +937,14 @@ class MeshBucketStore(ColumnarPipeline):
                     views[s] = (hot, cold)
                 hot, cold = views[s]
                 slot = int(mp.slot[s, j])
+                # Exists per lane: the planner's claim, EXCEPT that a
+                # later occurrence of an analytic duplicate group
+                # (occ > 0) shares the FIRST occurrence's pre-group
+                # claim — sequentially, the prior occurrence's write
+                # made the row live.  Round-1+ same-key lanes already
+                # carry exists=True from the planner, and a mid-batch
+                # slot TAKEOVER (different key, occ == 0,
+                # exists=False) must keep creating.
                 ex = bool(mp.exists[s, j]) or int(mp.occ[s, j]) > 0
                 st, rem, reset, n_exp, removed = scalar_ops.apply_one(
                     hot[slot], cold[slot],
@@ -1072,7 +1087,9 @@ class MeshBucketStore(ColumnarPipeline):
                     self._fire_store_callbacks(s, chunk, cached_np[s], removed_np[s])
 
     # ------------------------------------------------------------------
-    # Store SPI (persistence) — same call pattern as ShardStore.
+    # Store SPI (persistence): get() fulfills misses, on_change()
+    # observes every applied request, remove() fires on explicit
+    # removals — the call pattern of algorithms.go:26-33,64-68,176-177.
     # ------------------------------------------------------------------
     def _store_resolver(self, s: int, now_ms: int):
         return make_store_resolver(

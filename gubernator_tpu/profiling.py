@@ -19,9 +19,8 @@ questions:
   Samples land in a ring of one-second windows; `GET /debug/pprof
   ?seconds=N` merges the last N windows into collapsed text (default)
   or a JSON top-N view.  `GUBER_PROFILE=0` is the compiled-out mode:
-  the sampler tick is one branch, every scope hook is one comparison
-  returning a shared no-op, and the bench gate pins the enabled-vs-out
-  throughput ratio at >= 0.95 (the PR 4/PR 9 discipline).
+  the sampler tick is one branch and every scope hook is one
+  comparison returning a shared no-op.
 
 * **Who is spending the capacity?** — `TenantLedger`, cardinality-
   bounded per-tenant cost attribution keyed by rate-limit NAME (the
@@ -238,8 +237,8 @@ class Sampler(threading.Thread):
         that hold no store/pipeline lock) and the run-loop fallback.
         A dedicated sampler thread waking
         at 67 Hz on a saturated box costs ~3x the fold itself in GIL
-        handoffs and coalescing disruption (measured on the 2-core
-        bench); a thread that is ALREADY running folds for free and
+        handoffs and coalescing disruption (measured on a 2-core
+        box); a thread that is ALREADY running folds for free and
         lands the pause at a phase boundary, where no batch window is
         mid-flush.  Cost when not due: one clock read + one compare.
         The sample skips the calling thread's own stack (sample_once's
@@ -269,8 +268,8 @@ class Sampler(threading.Thread):
 
     def sample_once(self) -> None:
         """One profiling tick: snapshot every thread's stack and fold.
-        Public so tests (and the bench) can drive deterministic ticks
-        without sleeping."""
+        Public so tests can drive deterministic ticks without
+        sleeping."""
         now = time.time()
         if now - self._names_at > 1.0:
             # Thread names refresh at 1Hz, not per tick: enumerate()

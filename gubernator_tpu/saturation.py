@@ -54,18 +54,18 @@ from jax.profiler import TraceAnnotation
 from . import profiling, tracing
 
 # ---------------------------------------------------------------------
-# Shared ceil-rank percentiles (the bench.py p99 bugfix lives here so
-# every percentile site — bench rows, /debug/latency, queue-depth
-# snapshots — indexes the same way).
+# Shared ceil-rank percentiles (one definition, so every percentile
+# site — /debug/latency, queue-depth snapshots — indexes the same
+# way).
 # ---------------------------------------------------------------------
 
 
 def percentile_rank(n: int, q: float) -> int:
     """0-based index of the q-quantile in a sorted n-sample list, by
-    the NEAREST-RANK definition: 1-based rank ceil(q*n).  The previous
-    bench.py form `min(n-1, int(n*q))` floor-indexed — at small n it
-    lands a rank off the nearest-rank tail value, so gate verdicts on
-    thin tails were judged against the wrong sample."""
+    the NEAREST-RANK definition: 1-based rank ceil(q*n).  The floor
+    form `min(n-1, int(n*q))` lands a rank off the nearest-rank tail
+    value at small n, so thin tails would be judged against the wrong
+    sample."""
     if n <= 0:
         raise ValueError("percentile of an empty sample")
     return min(n - 1, max(0, math.ceil(q * n) - 1))
@@ -491,8 +491,7 @@ class SloEngine:
     FAST_WINDOW_S = 300
     # Volume floor for the fast-burn trip: a page-level verdict from a
     # handful of requests is noise shaped like an incident (one bad
-    # warmup request after a restart would read burn=100) — the same
-    # thin-tail rule the bench gate's min_samples enforces.
+    # warmup request after a restart would read burn=100).
     FAST_MIN_TOTAL = 100
     CHECK_INTERVAL_S = 1.0    # fast-burn evaluation cadence
     TRIP_MIN_INTERVAL_S = 30.0

@@ -161,8 +161,7 @@ class ServiceConfig:
     # NO separate GLOBAL key cap — GLOBAL keys share its 50k cache
     # (global.go:83-91) — so a working set that fits the cache must fit
     # the replica table.  The sync collective scans every gslot each
-    # pass (cost is linear in this capacity, ~us/gslot; see
-    # benchmarks/RESULTS.md "GLOBAL capacity" row), and the auto-tuned
+    # pass (cost is linear in this capacity), and the auto-tuned
     # GlobalSyncWait stretches to keep that overhead ≤10%, so
     # convergence lag grows with the capacity you provision.
     global_cache_size: Optional[int] = None
@@ -253,8 +252,7 @@ class _ExpressPolicy:
             return False
         if gate.queued + n > self.queue_depth:
             return False
-        depth = getattr(store, "pipeline_depth", None)
-        return depth is None or depth() <= self.MAX_DEPTH
+        return store.pipeline_depth() <= self.MAX_DEPTH
 
 
 class LocalBatcher:
@@ -364,7 +362,7 @@ class LocalBatcher:
 @dataclass
 class IngressColumns:
     """A GetRateLimits batch parsed straight into parallel columns —
-    the zero-dataclass ingress representation (VERDICT: the reference's
+    the zero-dataclass ingress representation (the reference's
     hot path is the whole service, gubernator.go:116-227, so the edge
     must feed the kernel without per-request object churn)."""
 
@@ -1273,17 +1271,12 @@ class V1Service:
             # pattern, one device commit instead of one row scatter per
             # item): the whole load() stream merges in a single
             # gather+scatter program via the reshard monotone merge.
-            # Stores without the columnar commit keep the legacy
-            # one-placement-per-item path.
             items = list(conf.loader.load())
-            if items and hasattr(self.store, "commit_transfer"):
+            if items:
                 self.store.commit_transfer(
                     snapshot_mod.items_to_columns(items),
                     self.clock.now_ms(),
                 )
-            else:
-                for item in items:
-                    self.store.load_item(item)
         # Durability plane (snapshot.py): restore the last crash-safe
         # device-state snapshot (one H2D merge-commit; corrupt files
         # reject loudly to a cold start), then run the background save
@@ -1312,7 +1305,6 @@ class V1Service:
         if (
             getattr(conf.behaviors, "express", False)
             and getattr(conf.behaviors, "express_scalar", False)
-            and hasattr(self.store, "scalar_fast_path")
         ):
             self.store.scalar_fast_path = True
             self.store.scalar_max_lanes = int(
@@ -2371,8 +2363,7 @@ class V1Service:
                 # The previous owner is THIS daemon (we are draining
                 # away): read our own store — only if the bucket is
                 # actually resident (peeks observe, never create).
-                mask_fn = getattr(self.store, "resident_mask", None)
-                if mask_fn is not None and not mask_fn([r0.hash_key()])[0]:
+                if not self.store.resident_mask([r0.hash_key()])[0]:
                     return None
                 return self.store.apply([r0], self.clock.now_ms())[0]
             return prev_peer.get_peer_rate_limit(r0)
@@ -2562,9 +2553,8 @@ class V1Service:
         worker hands off and returns to the ingress queue immediately,
         so the number of in-flight requests — and therefore how many
         callers one coalescing window can merge — is bounded by the
-        ingress queue, not by a blocked-thread pool (the measured
-        convoy that cost the native edge its bulk throughput,
-        benchmarks/RESULTS.md round-5 A/B)."""
+        ingress queue, not by a blocked-thread pool (the convoy that
+        cost the native edge its bulk throughput)."""
         try:
             if len(cols) > max_lanes:
                 raise ApiError(
@@ -2752,13 +2742,7 @@ class V1Service:
                 f"'UpdatePeerGlobals' columns list too large; "
                 f"max size is '{PEER_COLUMNS_MAX_LANES}'",
             )
-        now = self.clock.now_ms()
-        batch = getattr(self.store, "set_replica_batch", None)
-        if batch is not None:
-            batch(cols, now)
-            return
-        for u in cols.to_updates():
-            self.store.set_replica(u, now)
+        self.store.set_replica_batch(cols, self.clock.now_ms())
 
     def update_region_columns(self, cols) -> int:
         """Receive side of the multi-region federation plane
@@ -2959,8 +2943,7 @@ class V1Service:
                 ),
             })
         store = self.store
-        occupancy = getattr(store, "occupancy_stats", None)
-        shards = occupancy() if occupancy is not None else []
+        shards = store.occupancy_stats()
         used_total = sum(r["used"] for r in shards)
         cap_total = sum(r["capacity"] for r in shards)
         ev_total = sum(r["evictions"] for r in shards)
@@ -2996,10 +2979,8 @@ class V1Service:
                 ),
             },
             "dispatch": {
-                "inflight": int(getattr(store, "pipeline_depth", lambda: 0)()),
-                "deviceDispatches": int(
-                    getattr(store, "device_dispatches", 0)
-                ),
+                "inflight": store.pipeline_depth(),
+                "deviceDispatches": store.device_dispatches,
             },
             "slo": self.slo.snapshot(),
             # Express lane: knobs + hit rate + the host scalar slot's
@@ -3014,9 +2995,7 @@ class V1Service:
                 "maxLanes": int(
                     getattr(self.conf.behaviors, "express_max_lanes", 0)
                 ),
-                "scalarApplies": int(
-                    getattr(store, "scalar_applies", 0)
-                ),
+                "scalarApplies": store.scalar_applies,
                 **saturation.express_snapshot(),
             },
             "hotkeys": self.hotkeys.snapshot()["topk"][:5],
@@ -3269,9 +3248,8 @@ class GlobalManager:
     # (config.go:113); here the honest basis is the measured in-situ
     # cost of the REAL sync passes — no synthetic measurement, no
     # extra collectives, no stall of serving traffic.  The estimator is
-    # the MIN over the last SYNC_COST_SAMPLES work ticks (the bench
-    # suite's best-of-N philosophy): a sync's true cost is its
-    # least-contended run, and an estimator that averages in outliers
+    # the MIN over the last SYNC_COST_SAMPLES work ticks: a sync's
+    # true cost is its least-contended run, and an estimator that averages in outliers
     # is unstable here because the window feeds back into the sample
     # rate — round 4 observed a single contaminated ~300ms startup
     # sample seeding an EMA whose 1s window then starved itself of the
@@ -3287,8 +3265,7 @@ class GlobalManager:
     @classmethod
     def window_for_cost(cls, cost_s: float) -> float:
         """The sync window this policy derives from a measured per-sync
-        cost (single source of truth for the service, the bench suite,
-        and the tests)."""
+        cost (single source of truth for the service and the tests)."""
         return min(
             max(cost_s / cls.SYNC_OVERHEAD_TARGET, cls.SYNC_WAIT_MIN_S),
             cls.SYNC_WAIT_MAX_S,

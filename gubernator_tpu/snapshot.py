@@ -320,11 +320,7 @@ class SnapshotManager:
         self.service = service
         self.path = path or ""
         self.interval_s = max(float(interval_s or 0.0), 0.0)
-        # A custom Store-SPI object without the columnar gather/commit
-        # pair cannot ride this plane; its persistence is the Loader.
-        self.enabled = bool(self.path) and hasattr(
-            service.store, "snapshot_columns"
-        ) and hasattr(service.store, "commit_transfer")
+        self.enabled = bool(self.path)
         # Host-side counters (exported via Metrics.observe_snapshot and
         # served raw in GET /debug/status).
         self.saves_ok = 0

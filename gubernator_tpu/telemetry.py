@@ -21,9 +21,8 @@ plane adds ZERO device programs):
   attribution is exact.
 
 * **Steady-state recompiles** — after `mark_steady()` (the daemon
-  calls it once startup warmup finishes; bench legs call it between
-  warm and measured epochs) any further backend compile is SHAPE CHURN
-  by definition and is counted per label.  A burst of them
+  calls it once startup warmup finishes) any further backend compile
+  is SHAPE CHURN by definition and is counted per label.  A burst of them
   (`GUBER_XLA_STORM` compiles inside `GUBER_XLA_STORM_WINDOW` seconds)
   fires the PR 4 flight-recorder auto-dump (`recompile-storm` event)
   while the evidence of WHICH programs churned is still in the rings.
@@ -131,7 +130,7 @@ _steady_recompiles: Dict[str, int] = {}
 # per metrics scrape (the dispatch-stage gauge convention)
 _exec_stats: Dict[str, list] = {}
 # distinct jitted callables created by the program caches
-# (buckets.fused_packed_jit and the mesh twin note creations here)
+# (mesh._mesh_fused_packed_jit notes creations here)
 _programs_created: Dict[str, int] = {}
 # Set-up, cumulative over the process: seconds per part of a daemon
 # start (`startup(part)`), per program label what its launches before
@@ -192,9 +191,8 @@ def _ensure_listener() -> None:
 def listener_active() -> bool:
     """Whether compile counting can actually observe compiles: the
     plane is on AND the jax.monitoring listener registered.  Consumers
-    that would read an always-0 count as a verdict (the bench
-    steady_state_recompiles gate) must SKIP instead when this is
-    False."""
+    that would read an always-0 count as a verdict must SKIP instead
+    when this is False."""
     return _ENABLED and _listener_registered[0]
 
 
@@ -322,7 +320,7 @@ def program(label: str, lazy: bool = False):
 
 def note_program_created(label: str) -> None:
     """One distinct jitted callable materialized by a program cache
-    (buckets.fused_packed_jit / the mesh twin): counted so the
+    (mesh._mesh_fused_packed_jit): counted so the
     program-population growth is visible even before first dispatch."""
     if not _ENABLED:
         return

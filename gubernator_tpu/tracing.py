@@ -563,9 +563,8 @@ def ingress_span(edge: str, name: str, traceparent: Optional[str] = None,
     its sampled flag neither forces nor suppresses recording here.
     Headers arrive from untrusted clients: honoring flag=01 would let
     any caller stamp itself into 100% sampling (recorder flooding,
-    trace bytes on every peer RPC — the overhead the bench gate
-    bounds), and honoring flag=00 would let a proxy blind an operator
-    running at sample 1.0."""
+    trace bytes on every peer RPC), and honoring flag=00 would let a
+    proxy blind an operator running at sample 1.0."""
     if not enabled() or _rng().random() >= _SAMPLE:
         return _NOOP
     parent = parse_traceparent(traceparent) if traceparent else None

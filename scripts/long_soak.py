@@ -11,8 +11,7 @@ Run from the repo root:
 
 The peer deadline is provisioned generously (60 s) -- the same
 GUBER_BATCH_TIMEOUT tuning a real deployment applies for its device
-latency; the default deadline would measure expiry, not the software
-(the cfg5 lesson, benchmarks/RESULTS.md)."""
+latency; the default deadline would measure expiry, not the software."""
 import json
 import socket
 import threading
@@ -29,9 +28,9 @@ SOAK_S = 600
 CHURN_AT_S = 240
 CHURN_WINDOW_S = 90  # restart + re-peer + client reconnect grace (daemon warmup)
 
-# Generously provisioned peer deadline (the cfg5 lesson, RESULTS.md):
-# each forwarded leg waits on device rounds plus queueing; where those
-# outlast the default deadline it measures expiry, not the software.
+# Generously provisioned peer deadline: each forwarded leg waits on
+# device rounds plus queueing; where those outlast the default
+# deadline it measures expiry, not the software.
 # A real deployment sets GUBER_BATCH_TIMEOUT for its device.
 beh = fast_test_behaviors()
 beh.batch_timeout_s = 60.0

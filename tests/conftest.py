@@ -8,6 +8,7 @@ Tests always run on the CPU: `jax.config.update('jax_platforms', 'cpu')`
 below holds whatever JAX_PLATFORMS says.
 """
 
+import functools
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -29,3 +30,27 @@ def frozen_clock():
     c = Clock()
     c.freeze(1_573_430_400_000)  # 2019-11-11T00:00:00Z
     return c
+
+
+def _store_over(n_devices, capacity, **kw):
+    from gubernator_tpu.parallel.mesh import MeshBucketStore
+
+    return MeshBucketStore(
+        capacity_per_shard=capacity, devices=jax.devices()[:n_devices], **kw
+    )
+
+
+def one_device_store(capacity, **kw):
+    """The daemon's store over ONE device: the shape of `v5e1-1m`, and
+    the store for tests that count on one table (the default
+    `MeshBucketStore()` is 8 shards under this harness)."""
+    return _store_over(1, capacity, **kw)
+
+
+@pytest.fixture(params=[1, 4], ids=["one-device", "four-shard"])
+def make_store(request):
+    """`make_store(capacity, **kw)`: the daemon's store in the two
+    shapes the benchmark serves, `v5e1-1m` (one device) and
+    `v5e4-mesh-1m` (four shards).  Tests that count on ONE table's
+    eviction order take `one_device_store` instead."""
+    return functools.partial(_store_over, request.param)

@@ -13,7 +13,6 @@ import random
 
 import pytest
 
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.types import (
     Algorithm,
     Behavior,
@@ -27,6 +26,7 @@ from gubernator_tpu.utils.clock import Clock
 from gubernator_tpu.utils import gregorian
 
 from . import oracle
+from .conftest import one_device_store
 
 T0 = 1_573_430_430_000  # 2019-11-11T00:00:30Z
 
@@ -42,8 +42,8 @@ def one(store, req, now):
     return store.apply([req], now)[0]
 
 
-def test_over_the_limit():
-    store = ShardStore(capacity=64)
+def test_over_the_limit(make_store):
+    store = make_store(64)
     now = T0
     expect = [(1, Status.UNDER_LIMIT), (0, Status.UNDER_LIMIT), (0, Status.OVER_LIMIT)]
     for remaining, status in expect:
@@ -54,8 +54,8 @@ def test_over_the_limit():
         assert r.reset_time != 0
 
 
-def test_token_bucket():
-    store = ShardStore(capacity=64)
+def test_token_bucket(make_store):
+    store = make_store(64)
     clock = Clock()
     clock.freeze(T0)
     table = [
@@ -71,8 +71,8 @@ def test_token_bucket():
         clock.advance(sleep_ms)
 
 
-def test_token_bucket_gregorian():
-    store = ShardStore(capacity=64)
+def test_token_bucket_gregorian(make_store):
+    store = make_store(64)
     clock = Clock()
     clock.freeze(T0)
     table = [
@@ -95,8 +95,8 @@ def test_token_bucket_gregorian():
         clock.advance(sleep_ms)
 
 
-def test_leaky_bucket():
-    store = ShardStore(capacity=64)
+def test_leaky_bucket(make_store):
+    store = make_store(64)
     clock = Clock()
     clock.freeze(T0)
     table = [
@@ -126,8 +126,8 @@ def test_leaky_bucket():
         clock.advance(sleep_ms)
 
 
-def test_leaky_bucket_gregorian():
-    store = ShardStore(capacity=64)
+def test_leaky_bucket_gregorian(make_store):
+    store = make_store(64)
     clock = Clock()
     clock.freeze(T0)
     table = [
@@ -150,8 +150,8 @@ def test_leaky_bucket_gregorian():
         clock.advance(sleep_ms)
 
 
-def test_change_limit():
-    store = ShardStore(capacity=64)
+def test_change_limit(make_store):
+    store = make_store(64)
     now = T0
     table = [
         # algorithm, limit, expected_remaining
@@ -172,8 +172,8 @@ def test_change_limit():
         assert r.reset_time != 0
 
 
-def test_reset_remaining():
-    store = ShardStore(capacity=64)
+def test_reset_remaining(make_store):
+    store = make_store(64)
     now = T0
     table = [
         (Behavior.BATCHING, 99),
@@ -187,8 +187,8 @@ def test_reset_remaining():
         assert r.remaining == remaining
 
 
-def test_leaky_bucket_div_bug():
-    store = ShardStore(capacity=64)
+def test_leaky_bucket_div_bug(make_store):
+    store = make_store(64)
     now = T0
     r = one(store, mk(name="div", limit=2000, duration=1000, algo=Algorithm.LEAKY_BUCKET), now)
     assert r.status == Status.UNDER_LIMIT
@@ -199,9 +199,9 @@ def test_leaky_bucket_div_bug():
     assert r.limit == 2000
 
 
-def test_hits_greater_than_limit_on_create():
+def test_hits_greater_than_limit_on_create(make_store):
     """algorithms.go:161-166 / :318-323"""
-    store = ShardStore(capacity=64)
+    store = make_store(64)
     now = T0
     r = one(store, mk(name="big", hits=1000, limit=100, duration=9000), now)
     assert r.status == Status.OVER_LIMIT
@@ -211,9 +211,9 @@ def test_hits_greater_than_limit_on_create():
     assert r.remaining == 0  # leaky drains to 0
 
 
-def test_over_limit_does_not_mutate():
+def test_over_limit_does_not_mutate(make_store):
     """algorithms.go:126-130: a rejected over-sized request leaves state."""
-    store = ShardStore(capacity=64)
+    store = make_store(64)
     now = T0
     one(store, mk(name="nm", hits=1, limit=100, duration=9000), now)  # rem 99
     r = one(store, mk(name="nm", hits=1000, limit=100, duration=9000), now)
@@ -224,10 +224,10 @@ def test_over_limit_does_not_mutate():
     assert r.remaining == 0
 
 
-def test_expiry_boundary_exact_ms():
+def test_expiry_boundary_exact_ms(make_store):
     """At now == ExpireAt the bucket is still live (cache.go:151 is a
     strict `<`); one ms later it recreates."""
-    store = ShardStore(capacity=64)
+    store = make_store(64)
     clock = Clock()
     clock.freeze(T0)
     req = mk(name="edge", hits=2, limit=2, duration=1000)
@@ -241,13 +241,13 @@ def test_expiry_boundary_exact_ms():
     assert r.status == Status.UNDER_LIMIT and r.remaining == 1
 
 
-def test_leaky_nonrepresentable_rate():
+def test_leaky_nonrepresentable_rate(make_store):
     """Non-binary-representable rates (duration=1000, limit=30): the
     kernel computes leak = elapsed*limit/duration exactly, where the
     reference double-rounds through float64 and can under-count by one
     token at exact multiples.  Pin exactness and the <=1-token bound
     vs the float oracle."""
-    store = ShardStore(capacity=64)
+    store = make_store(64)
     ocache = oracle.OracleCache()
     clock = Clock()
     clock.freeze(T0)
@@ -264,9 +264,9 @@ def test_leaky_nonrepresentable_rate():
     assert abs(got.remaining - want.remaining) <= 1
 
 
-def test_leaky_huge_limit_no_overflow():
+def test_leaky_huge_limit_no_overflow(make_store):
     """elapsed*limit exceeding int64 must not wrap (128-bit muldiv)."""
-    store = ShardStore(capacity=64)
+    store = make_store(64)
     clock = Clock()
     clock.freeze(T0)
     month = 30 * 24 * 3600 * 1000  # 2.59e9 ms
@@ -280,9 +280,9 @@ def test_leaky_huge_limit_no_overflow():
     assert abs(r.remaining - big // 2) <= 1
 
 
-def test_duplicate_keys_in_one_batch():
+def test_duplicate_keys_in_one_batch(make_store):
     """Duplicate keys in a single batch behave like sequential requests."""
-    store = ShardStore(capacity=64)
+    store = make_store(64)
     now = T0
     reqs = [mk(name="dup", hits=3, limit=10, duration=9000) for _ in range(4)]
     resps = store.apply(reqs, now)
@@ -296,7 +296,7 @@ def test_eviction_collision_with_reset_does_not_drop_new_key():
     """Regression: a RESET_REMAINING lane whose slot gets evicted and
     remapped mid-batch must not delete the new key's mapping when its
     removed-flag commits (key-guarded commit)."""
-    store = ShardStore(capacity=2)
+    store = one_device_store(2)
     now = T0
     one(store, mk(name="x", key="A", hits=1, limit=10, duration=9000), now)
     one(store, mk(name="x", key="B", hits=1, limit=10, duration=9000), now)
@@ -318,7 +318,7 @@ def test_eviction_collision_with_reset_does_not_drop_new_key():
 def test_padding_lanes_do_not_corrupt_last_slot():
     """Regression: jax .at[-1] wraps, so padding lanes (slot=-1) used to
     scatter garbage into the table's last slot."""
-    store = ShardStore(capacity=2)
+    store = one_device_store(2)
     now = T0
     one(store, mk(name="p", key="K0", hits=1, limit=10, duration=9000), now)
     one(store, mk(name="p", key="K1", hits=1, limit=10, duration=9000), now)  # slot 1 (last)
@@ -329,22 +329,22 @@ def test_padding_lanes_do_not_corrupt_last_slot():
 
 
 def test_lru_eviction():
-    store = ShardStore(capacity=4)
+    store = one_device_store(4)
     now = T0
     for i in range(6):
         one(store, mk(name="ev", key=f"k{i}", hits=1, limit=10, duration=9000), now)
     assert store.size() == 4
-    assert store.table.evictions == 2
+    assert store.tables[0].evictions == 2
     # k0 was evicted; hitting it again recreates a fresh bucket
     r = one(store, mk(name="ev", key="k0", hits=1, limit=10, duration=9000), now)
     assert r.remaining == 9
 
 
 @pytest.mark.parametrize("algo", [Algorithm.TOKEN_BUCKET, Algorithm.LEAKY_BUCKET])
-def test_differential_vs_oracle(algo):
+def test_differential_vs_oracle(algo, make_store):
     """Randomized sequences must match the sequential reference oracle."""
     rng = random.Random(1234 + algo)
-    store = ShardStore(capacity=256)
+    store = make_store(256)
     ocache = oracle.OracleCache()
     clock = Clock()
     clock.freeze(T0)
@@ -373,10 +373,10 @@ def test_differential_vs_oracle(algo):
         clock.advance(rng.choice([0, 0, 1, 7, 100, 1500, 6000]))
 
 
-def test_differential_mixed_algo_switches():
+def test_differential_mixed_algo_switches(make_store):
     """Algorithm switches mid-stream reset buckets (algorithms.go:54-62)."""
     rng = random.Random(99)
-    store = ShardStore(capacity=256)
+    store = make_store(256)
     ocache = oracle.OracleCache()
     clock = Clock()
     clock.freeze(T0)

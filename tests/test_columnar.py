@@ -21,6 +21,8 @@ from gubernator_tpu.types import (
 )
 from gubernator_tpu.utils.clock import Clock
 
+from .conftest import one_device_store
+
 NOW = 1_573_430_400_000
 
 
@@ -262,7 +264,7 @@ def test_wide_gregorian_stays_on_dict_wire_and_matches_wide():
     wire (interval.go:82-146 is first-class in the reference)."""
     import numpy as np
 
-    from gubernator_tpu.models.shard import GregResolver, ShardStore
+    from gubernator_tpu.models.shard import GregResolver
     from gubernator_tpu.types import Behavior
     from gubernator_tpu.utils import gregorian
 
@@ -297,8 +299,8 @@ def test_wide_gregorian_stays_on_dict_wire_and_matches_wide():
     )
     assert buckets.build_config_dict(cols, NOW) is not None
 
-    a = ShardStore(capacity=256)
-    b = ShardStore(capacity=256)
+    a = one_device_store(256)
+    b = one_device_store(256)
     for step in range(3):
         ra = a.apply_columns(keys, now_ms=NOW + step, **kw)
         rb = b.apply_columns(keys, now_ms=NOW + step, force_wire="wide", **kw)
@@ -308,62 +310,3 @@ def test_wide_gregorian_stays_on_dict_wire_and_matches_wide():
     assert int((kw["greg_expire"] - NOW).max()) > (1 << 31) - 1
 
 
-def test_compact_commit_matches_rounds_kernel():
-    """apply_compact32 (single-round compacted scatter) must be
-    byte-identical to apply_rounds32 for the same grouped plan —
-    responses AND resulting state (round 4: the per-lane scatter prices
-    every submitted row, so the production dispatch compacts)."""
-    import jax.numpy as jnp
-
-    from gubernator_tpu.ops import buckets
-
-    rng = np.random.RandomState(9)
-    C, B = 512, 256
-    ids = rng.randint(0, 96, size=B)  # heavy duplicates
-    # a grouped single-round plan shape: occ within groups, last writes
-    order = np.argsort(ids, kind="stable")
-    occ = np.zeros(B, np.int32)
-    write = np.zeros(B, bool)
-    slot_of = {k: i for i, k in enumerate(np.unique(ids))}
-    slots = np.array([slot_of[k] for k in ids], np.int32)
-    seen = {}
-    for i in range(B):
-        seen[ids[i]] = seen.get(ids[i], -1) + 1
-        occ[i] = seen[ids[i]]
-    last = {}
-    for i in range(B):
-        last[ids[i]] = i
-    for i in last.values():
-        write[i] = True
-
-    def mk(exists):
-        return buckets.make_batch32(
-            slots, np.full(B, exists, bool), (ids % 2).astype(np.int32),
-            np.zeros(B, np.int32), np.ones(B, np.int32),
-            np.full(B, 1000, np.int32), np.full(B, 60_000, np.int32),
-            occ=occ, write=write,
-        )
-
-    now = 1_700_000_000_000
-    rid = jnp.zeros(B, jnp.int32)
-    one = jnp.asarray(1, jnp.int32)
-
-    sa = buckets.init_state(C)
-    sa, pa = buckets.apply_rounds32(sa, mk(False), rid, one, now)
-
-    wl = np.nonzero(write)[0].astype(np.int32)
-    wlane = np.full(128, -1, np.int32)
-    wlane[: len(wl)] = wl
-    sb = buckets.init_state(C)
-    sb, pb = buckets.apply_compact32(sb, mk(False), jnp.asarray(wlane), now)
-
-    assert np.array_equal(np.asarray(pa), np.asarray(pb))
-    assert np.array_equal(np.asarray(sa.hot), np.asarray(sb.hot))
-    assert np.array_equal(np.asarray(sa.cold), np.asarray(sb.cold))
-
-    # steady-state second batch too (exists=True, no cold rewrite)
-    sa2, pa2 = buckets.apply_rounds32(sa, mk(True), rid, one, now + 500)
-    sb2, pb2 = buckets.apply_compact32(sb, mk(True), jnp.asarray(wlane), now + 500)
-    assert np.array_equal(np.asarray(pa2), np.asarray(pb2))
-    assert np.array_equal(np.asarray(sa2.hot), np.asarray(sb2.hot))
-    assert np.array_equal(np.asarray(sa2.cold), np.asarray(sb2.cold))

@@ -24,8 +24,9 @@ import pytest
 
 from gubernator_tpu import native
 from gubernator_tpu.faults import DELAY, FaultPlan, FaultRule
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.parallel.mesh import MeshBucketStore
+
+from .conftest import one_device_store
 
 pytestmark = pytest.mark.skipif(
     not native.available(), reason="columnar pipeline needs the native runtime"
@@ -117,13 +118,13 @@ def _assert_matches_serial(make_store, batches, raced, force_wire):
 
 @pytest.mark.parametrize("seed", [7, 1234])
 @pytest.mark.parametrize("force_wire", [None, "wide"])
-def test_shard_interleaved_matches_serial(seed, force_wire):
-    store = ShardStore(capacity=4096)
+def test_one_device_interleaved_matches_serial(seed, force_wire):
+    store = one_device_store(4096)
     batches = _make_batches(seed, n_batches=12, lanes=96, n_keys=64,
                             wide=force_wire == "wide")
     raced = _race(store, batches, n_threads=3, force_wire=force_wire)
     _assert_matches_serial(
-        lambda: ShardStore(capacity=4096), batches, raced, force_wire
+        lambda: one_device_store(4096), batches, raced, force_wire
     )
 
 
@@ -170,7 +171,7 @@ def test_launch_fusion_under_backlog(monkeypatch):
     """Stall ticket 0 in its STAGE step; tickets 1..3 stage behind it
     and wait at the launch gate, so ticket 0's launch fuses all four
     into one program — and the results still match the serial replay."""
-    store = ShardStore(capacity=4096)
+    store = one_device_store(4096)
     batches = _make_batches(21, n_batches=4, lanes=64, n_keys=32,
                             wide=False)
     orig = store._stage_columns
@@ -190,15 +191,19 @@ def test_launch_fusion_under_backlog(monkeypatch):
     assert stats["prepare"][0] == 4
     assert stats["launch"][0] < 4, stats
     _assert_matches_serial(
-        lambda: ShardStore(capacity=4096), batches, raced, None
+        lambda: one_device_store(4096), batches, raced, None
     )
 
 
 def test_fused_kernel_matches_solo_sequence():
-    """The fused launch program is bit-equivalent to the same wires
-    applied by consecutive solo dispatches (state threading included)."""
+    """The fused launch program (one shard of the mesh's) is
+    bit-equivalent to the same wires applied by consecutive solo runs
+    of the kernel it vmaps (state threading included)."""
+    import jax
+
     from gubernator_tpu.models.shard import make_columns
     from gubernator_tpu.ops import buckets
+    from gubernator_tpu.parallel import mesh
 
     lanes, cap = 64, 256
     slot = np.arange(lanes, dtype=np.int32)
@@ -220,28 +225,25 @@ def test_fused_kernel_matches_solo_sequence():
     wires = [wire(1, False), wire(2, True), wire(3, True), wire(5, True)]
     nows = [NOW, NOW + 10, NOW + 20, NOW + 30]
 
+    solo = jax.jit(buckets.apply_rounds_packed)
     solo_state = buckets.init_state(cap)
     solo_out = []
     for w, t in zip(wires, nows):
-        solo_state, packed = buckets.apply_rounds_packed_jit(
-            solo_state, np.array(w), 1, t
-        )
+        solo_state, packed = solo(solo_state, np.array(w), 1, t)
         solo_out.append(np.asarray(packed))
 
-    fused_state = buckets.init_state(cap)
-    fn = buckets.fused_packed_jit(4, wide=False, donate_wires=False)
+    # One shard: every array carries the leading [S=1] axis.
+    fused_state = jax.tree.map(lambda a: a[None], buckets.init_state(cap))
+    fn = mesh._mesh_fused_packed_jit(4, wide=False, donate_wires=False)
     fused_state, stacked = fn(
-        fused_state, *[np.array(w) for w in wires],
+        fused_state, *[np.array(w)[None] for w in wires],
         np.ones(4, np.int32), np.asarray(nows, np.int64),
     )
-    stacked = np.asarray(stacked)
+    stacked = np.asarray(stacked)  # [k, S, 4, P]
     for i in range(4):
-        assert np.array_equal(stacked[i], solo_out[i]), f"sub-batch {i}"
-    for a, b in zip(
-        __import__("jax").tree.leaves(solo_state),
-        __import__("jax").tree.leaves(fused_state),
-    ):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert np.array_equal(stacked[i, 0], solo_out[i]), f"sub-batch {i}"
+    for a, b in zip(jax.tree.leaves(solo_state), jax.tree.leaves(fused_state)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)[0])
 
 
 def test_ingress_queue_sheds_with_429_error():
@@ -302,7 +304,7 @@ def test_ingress_queue_env_knob():
 def test_dispatch_metrics_cleared_per_scrape():
     from gubernator_tpu.metrics import Metrics
 
-    store = ShardStore(capacity=1024)
+    store = one_device_store(1024)
     b = _make_batches(5, 1, 32, 16, wide=False)[0]
     _dispatch(store, b, None).result()
     m = Metrics()
@@ -317,17 +319,3 @@ def test_dispatch_metrics_cleared_per_scrape():
     text2 = m.render().decode()
     assert 'stage="prepare"' not in text2
     assert "gubernator_dispatch_inflight 0.0" in text2
-
-
-def test_gate_verdict_noise_adjusted():
-    import bench
-
-    # Round-5's failing shape: tiny point estimate, big timer noise —
-    # the noise-adjusted bound is still far under the limit: PASS.
-    assert bench.gate_verdict(4.7, {"fail_above_us": 250.0}, 77.2)[0] == "PASS"
-    # A real regression clears the limit even after subtracting noise.
-    assert bench.gate_verdict(400.0, {"fail_above_us": 250.0}, 20.0)[0] == "FAIL"
-    # Noise straddling the limit is inconclusive, never a flip.
-    assert bench.gate_verdict(240.0, {"fail_above_us": 250.0}, 30.0)[0] == "SKIP"
-    assert bench.gate_verdict(0.9, {"fail_below": 0.65})[0] == "PASS"
-    assert bench.gate_verdict(0.5, {"fail_below": 0.65})[0] == "FAIL"

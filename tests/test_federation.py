@@ -419,10 +419,9 @@ def test_seeded_duplicate_on_region_wire_is_caught(two_region_pair):
 
 @pytest.mark.chaos
 def test_chaos_carry_requeues_and_delivers_exactly_once(two_region_pair):
-    """The carry/requeue regression the bench gate rides on: a
-    partition toward the remote region carries the flush; heal delivers
-    the carried hits EXACTLY once (remote remaining moves by the summed
-    hits, audits silent)."""
+    """The carry/requeue regression: a partition toward the remote
+    region carries the flush; heal delivers the carried hits EXACTLY
+    once (remote remaining moves by the summed hits, audits silent)."""
     a, b = two_region_pair
     plan = faults.FaultPlan(seed=23)
     rule = plan.partition(b.peer_info.grpc_address,

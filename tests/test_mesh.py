@@ -12,10 +12,11 @@ import numpy as np
 import pytest
 
 from gubernator_tpu import native
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.parallel.mesh import MeshBucketStore, make_mesh, shard_of_key
 from gubernator_tpu.types import Algorithm, RateLimitRequest, Status
 from gubernator_tpu.utils.clock import Clock
+
+from . import oracle
 
 T0 = 1_573_430_430_000
 
@@ -50,11 +51,12 @@ def test_shard_assignment_is_stable_and_covers():
 
 
 def test_mesh_matches_single_shard_semantics():
-    """The sharded store must give byte-identical responses to a single
-    ShardStore fed the same sequential workload."""
+    """The sharded store must give the sequential reference's answers
+    (tests/oracle.py: one cache, one request at a time) on the same
+    workload."""
     rng = random.Random(7)
     mesh_store = MeshBucketStore(capacity_per_shard=256)
-    ref = ShardStore(capacity=4096)
+    ref = oracle.OracleCache()
     clock = Clock()
     clock.freeze(T0)
     for _ in range(30):
@@ -71,7 +73,7 @@ def test_mesh_matches_single_shard_semantics():
             )
         now = clock.now_ms()
         got = mesh_store.apply(batch, now)
-        want = ref.apply(batch, now)
+        want = [oracle.apply(ref, req, now) for req in batch]
         for g, w, req in zip(got, want, batch):
             assert (g.status, g.limit, g.remaining, g.reset_time) == (
                 w.status, w.limit, w.remaining, w.reset_time,

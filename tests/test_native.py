@@ -9,10 +9,11 @@ import numpy as np
 import pytest
 
 from gubernator_tpu import native
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.models.slot_table import SlotTable
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, SECOND
 from gubernator_tpu.utils import hashing
+
+from .conftest import one_device_store
 
 pytestmark = pytest.mark.skipif(
     not native.available(), reason=f"native runtime unavailable: {native.build_error()}"
@@ -114,12 +115,12 @@ def _req(key, hits=1, limit=10, duration=9 * SECOND, algo=Algorithm.TOKEN_BUCKET
     )
 
 
-def test_shardstore_native_vs_python_sequences():
+def test_store_native_vs_python_sequences():
     """Same request stream through the native fast path and the Python
     fallback gives byte-identical responses."""
     now = 1_700_000_000_000
-    a = ShardStore(capacity=64, use_native=True)
-    b = ShardStore(capacity=64, use_native=False)
+    a = one_device_store(64, use_native=True)
+    b = one_device_store(64, use_native=False)
     assert a._native and not b._native
     rng = np.random.RandomState(3)
     for t in range(20):
@@ -139,9 +140,9 @@ def test_shardstore_native_vs_python_sequences():
 
 def test_apply_columns_matches_apply():
     now = 1_700_000_000_000
-    st = ShardStore(capacity=128)
+    st = one_device_store(128)
     reqs = [_req(f"c{i % 7}", hits=1, limit=100) for i in range(32)]
-    expect = ShardStore(capacity=128).apply(reqs, now)
+    expect = one_device_store(128).apply(reqs, now)
     out = st.apply_columns(
         keys=[r.hash_key() for r in reqs],
         algorithm=[int(r.algorithm) for r in reqs],
@@ -160,12 +161,12 @@ def test_apply_columns_matches_apply():
 def test_native_store_capacity_eviction_parity():
     """Under capacity pressure both paths evict LRU and keep working."""
     now = 1_700_000_000_000
-    a = ShardStore(capacity=8, use_native=True)
-    b = ShardStore(capacity=8, use_native=False)
+    a = one_device_store(8, use_native=True)
+    b = one_device_store(8, use_native=False)
     for t in range(40):
         reqs = [_req(f"e{(t + j) % 20}", limit=1000) for j in range(6)]
         assert a.apply(reqs, now + t) == b.apply(reqs, now + t)
-    assert sorted(a.table.keys()) == sorted(b.table.keys())
+    assert sorted(a.tables[0].keys()) == sorted(b.tables[0].keys())
 
 
 def test_plan_single_dispatch_round_ids():
@@ -193,8 +194,8 @@ def test_reset_remaining_then_hit_same_batch():
     the recreated bucket must survive into the next batch (the remove-
     then-recreate commit chain)."""
     now = 1_700_000_000_000
-    a = ShardStore(capacity=32, use_native=True)
-    b = ShardStore(capacity=32, use_native=False)
+    a = one_device_store(32, use_native=True)
+    b = one_device_store(32, use_native=False)
     warm = [_req("rr", hits=4, limit=10)]
     batch = [
         _req("rr", hits=0, behavior=int(Behavior.RESET_REMAINING), limit=10),
@@ -206,15 +207,15 @@ def test_reset_remaining_then_hit_same_batch():
         st.apply(batch, now + 1)
         (r,) = st.apply(after, now + 2)
         assert r.remaining == 6, r  # 10 - 3 - 1: recreation persisted
-    assert a.table.get_slot("nat_rr") is not None
+    assert a.tables[0].get_slot("nat_rr") is not None
 
 
 def test_plan_path_overlimit_chain():
     """Duplicate chain crossing the limit: k-th request sees (k-1)-th's
     state exactly as the mutex-serialized reference would."""
     now = 1_700_000_000_000
-    a = ShardStore(capacity=32, use_native=True)
-    b = ShardStore(capacity=32, use_native=False)
+    a = one_device_store(32, use_native=True)
+    b = one_device_store(32, use_native=False)
     # remaining=5: [hits=7 OVER no-mutate, hits=3 UNDER ->2, hits=3 OVER, hits=2 UNDER ->0]
     reqs = [_req("ol", hits=h, limit=5) for h in (7, 3, 3, 2)]
     ra, rb = a.apply(reqs, now), b.apply(reqs, now)
@@ -228,8 +229,8 @@ def test_plan_path_random_stress_vs_python():
     capacity pressure) through the single-dispatch path vs the Python
     twin."""
     now = 1_700_000_000_000
-    a = ShardStore(capacity=16, use_native=True)
-    b = ShardStore(capacity=16, use_native=False)
+    a = one_device_store(16, use_native=True)
+    b = one_device_store(16, use_native=False)
     rng = np.random.RandomState(11)
     for t in range(30):
         reqs = []
@@ -248,7 +249,7 @@ def test_plan_path_random_stress_vs_python():
         step = now + t * 150
         ra, rb = a.apply(reqs, step), b.apply(reqs, step)
         assert ra == rb, t
-    assert sorted(a.table.keys()) == sorted(b.table.keys())
+    assert sorted(a.tables[0].keys()) == sorted(b.tables[0].keys())
 
 
 def test_eviction_skips_pending_write_slots():
@@ -315,54 +316,48 @@ def test_eviction_falls_back_when_all_pending():
     assert 0 <= s < 2  # evicted the LRU head despite the pending claim
 
 
-def test_passthrough_reset_survives_pipelined_eviction(monkeypatch):
-    """The narrow-wire keep-sentinel reconstructs an unchanged reset_time
-    from the host expiry mirror; that value must be snapshotted at
-    dispatch time, because a later pipelined batch's planning can evict
-    and reassign the slot (zeroing expire_ms) before the earlier batch
-    resolves (advisor finding, shard.py _dispatch_columns).
+def test_passthrough_reset_survives_pipelined_eviction():
+    """The narrow-wire keep-sentinel (-2) reconstructs an unchanged
+    reset_time from the host expiry bookkeeping; that value must be the
+    PLAN-time snapshot once a later pipelined batch's planning has
+    evicted and reassigned the slot before the earlier batch resolves,
+    and the live table value while the slot still maps the lane's key.
 
     The sentinel itself only fires for far-future expiries the i32 wire
-    can't carry, so instead of driving the kernel there this asserts the
-    snapshot timing directly: the expiry array handed to unpack_output32
-    must hold dispatch-time values even when the table mutates before
-    resolve."""
-    from gubernator_tpu.ops import buckets
+    can't carry, so instead of driving the kernel there this hands
+    `finish_narrow` a packed result that says "unchanged" directly."""
+    from gubernator_tpu.models.shard import make_columns
 
     now = 1_700_000_000_000
-    st = ShardStore(capacity=4, use_native=True)
+    cols = make_columns([0], [0], [0], [10], [60_000], 1)
+    padded = 64
+    keep = np.zeros((1, 4, padded), np.int32)
+    keep[0, 1, 0] = 9
+    keep[0, 2, 0] = keep[0, 3, 0] = -2  # reset_time, new_expire: unchanged
 
-    def cols_for(key, hits):
-        return dict(
-            keys=[key], algorithm=[0], behavior=[0], hits=[hits],
-            limit=[10], duration=[60_000],
-        )
+    def planned(table):
+        slot, _ = table.lookup_or_assign("a", now)
+        table.set_expire(slot, now + 60_000)  # "a" committed earlier
+        mp = native.NativeMeshPlanner([table], ["a"], now + 1)
+        mp.plan_grouped(cols, int(Behavior.RESET_REMAINING), padded)
+        return slot, mp
 
-    # Create "a": reset = now + 60s, committed.
-    r0 = st.apply_columns(**cols_for("a", 1), now_ms=now)
-    assert int(r0["reset_time"][0]) == now + 60_000
-    slot_a = st.table.get_slot(st.table.keys()[0])
+    # Stolen: with every slot pending, "b" takes the LRU head (the
+    # all-pending fallback) and the slot's expiry is zeroed.
+    t = native.NativeSlotTable(1)
+    slot, mp = planned(t)
+    assert t.lookup_or_assign("b", now + 2) == (slot, False)
+    assert t.get_slot("a") is None
+    status, remaining, reset = mp.finish_narrow(keep, now + 1)
+    assert (int(status[0]), int(remaining[0])) == (0, 9)
+    assert int(reset[0]) == now + 60_000  # the snapshot, not the table's 0
 
-    captured = []
-    real_unpack = buckets.unpack_output32
-
-    def spy(packed, now_ms, table_expire):
-        captured.append(np.array(table_expire, copy=True))
-        return real_unpack(packed, now_ms, table_expire)
-
-    monkeypatch.setattr(buckets, "unpack_output32", spy)
-
-    # Dispatch a status query on "a", then clobber the table's expiry
-    # (as a later pipelined batch's eviction would) BEFORE resolving.
-    ha = st.apply_columns_async(**cols_for("a", 0), now_ms=now + 1)
-    st.table.set_expire(slot_a, 0)
-    ra = ha.result()
-
-    assert len(captured) == 1
-    # Snapshot taken at dispatch: pre-clobber value.
-    assert int(captured[0][0]) == now + 60_000
-    assert int(ra["remaining"][0]) == 9
-    assert int(ra["reset_time"][0]) == now + 60_000
+    # Still mapped: the live value wins (older commits have folded in).
+    t = native.NativeSlotTable(4)
+    slot, mp = planned(t)
+    t.set_expire(slot, now + 90_000)
+    _, _, reset = mp.finish_narrow(keep, now + 1)
+    assert int(reset[0]) == now + 90_000
 
 
 # ---------------------------------------------------------------------
@@ -731,7 +726,7 @@ def test_occupancy_rows_serve_the_key_indexs_health():
     hash hits and size; the Python table has no index and no such key."""
     import json
 
-    st = ShardStore(capacity=64, use_native=True)
+    st = one_device_store(64, use_native=True)
     st.apply([_req(f"o{i % 9}") for i in range(30)], 1_700_000_000_000)
     (row,) = st.occupancy_stats()
     assert row["used"] == 9
@@ -739,5 +734,5 @@ def test_occupancy_rows_serve_the_key_indexs_health():
     assert row["index"]["lookups"] >= 9 and row["index"]["probes"] >= row["index"]["lookups"]
     assert row["index"]["entries"] == 128 and row["index"]["refused"] == 0
     json.dumps(row)
-    (row,) = ShardStore(capacity=64, use_native=False).occupancy_stats()
+    (row,) = one_device_store(64, use_native=False).occupancy_stats()
     assert "index" not in row

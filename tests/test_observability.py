@@ -16,6 +16,8 @@ from gubernator_tpu.metrics import Metrics
 from gubernator_tpu.service import IngressColumns, ServiceConfig, V1Service
 from gubernator_tpu.types import PeerInfo
 
+from .conftest import one_device_store
+
 T0 = 1_573_430_430_000
 
 
@@ -47,7 +49,7 @@ def _service(**kw):
 
 
 # ---------------------------------------------------------------------
-# Ceil-rank percentiles (the bench.py p99 bugfix)
+# Ceil-rank percentiles
 # ---------------------------------------------------------------------
 def test_percentile_nearest_rank():
     # n=100, q=0.99: nearest rank is 99 (1-based) -> index 98.  The old
@@ -62,32 +64,6 @@ def test_percentile_nearest_rank():
     assert saturation.percentile_rank(10, 0.5) == 4  # rank 5 of 10
     with pytest.raises(ValueError):
         saturation.percentile([], 0.5)
-
-
-def test_bench_shares_the_percentile():
-    import bench
-
-    assert bench.percentile is saturation.percentile
-
-
-def test_gate_verdict_ceiling_rows():
-    import bench
-
-    spec = {"fail_above": 650.0}
-    assert bench.gate_verdict(200.0, spec) == ("PASS", 650.0)
-    assert bench.gate_verdict(651.0, spec) == ("FAIL", 650.0)
-    # Noise straddling the ceiling is inconclusive, never a flip.
-    assert bench.gate_verdict(640.0, spec, noise=50.0) == ("SKIP", 650.0)
-    assert bench.gate_verdict(700.0, spec, noise=100.0) == ("SKIP", 650.0)
-
-
-def test_gate_thresholds_carry_latency_ceilings():
-    with open("benchmarks/gate_thresholds.json") as f:
-        th = json.load(f)
-    for row in ("service_ingress_latency_ms_p50",
-                "service_ingress_latency_ms_p99"):
-        assert "fail_above" in th[row], row
-        assert th[row]["min_samples"] >= 1, row
 
 
 # ---------------------------------------------------------------------
@@ -179,8 +155,7 @@ def test_slo_fast_burn_trips_flight_recorder():
     clock = [50_000.0]
     slo = saturation.SloEngine(10.0, 0.999, time_fn=lambda: clock[0])
     # Below the volume floor nothing trips, no matter how bad: a lone
-    # post-restart warmup request must not read as a page (the burn
-    # analogue of the bench gate's min_samples thin-tail rule).
+    # post-restart warmup request must not read as a page.
     for _ in range(saturation.SloEngine.FAST_MIN_TOTAL - 1):
         slo.observe(5.0)
         clock[0] += 0.05
@@ -286,10 +261,8 @@ def test_hash_ring_feeds_sketch():
 # ---------------------------------------------------------------------
 @pytest.mark.skipif(not native.available(), reason="native runtime unavailable")
 def test_occupancy_and_evictions_vs_oracle():
-    from gubernator_tpu.models.shard import ShardStore
-
     cap = 64
-    store = ShardStore(capacity=cap)
+    store = one_device_store(cap)
     n_batches, per_batch = 3, 64
 
     def batch(salt):
@@ -310,7 +283,7 @@ def test_occupancy_and_evictions_vs_oracle():
     # fills, each later distinct key evicts exactly one.
     assert store.size() == cap
     expected_evictions = n_batches * per_batch - cap
-    assert store.table.evictions == expected_evictions
+    assert store.tables[0].evictions == expected_evictions
 
     # ZERO-extra-dispatch pin (the replica_commit_dispatches playbook):
     # scraping occupancy/saturation and serving /debug/status must not

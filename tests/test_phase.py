@@ -24,6 +24,8 @@ from gubernator_tpu.service import ServiceConfig, V1Service
 from gubernator_tpu.types import Behavior, PeerInfo, RateLimitRequest
 from gubernator_tpu.utils.clock import Clock
 
+from .conftest import one_device_store
+
 
 @pytest.fixture(autouse=True)
 def _clean():
@@ -328,19 +330,18 @@ def test_waterfall_lists_every_phase_once_and_nests_under_a_parent():
 
 
 @pytest.mark.skipif(not native.available(), reason="native runtime unavailable")
-@pytest.mark.parametrize("store", ["mesh", "shard"])
+@pytest.mark.parametrize("store", ["mesh", "one-device"])
 def test_the_native_plan_is_a_phase_inside_every_prepare(store):
     """`dispatch.plan_native` (the C++ slot-table plan alone) is a depth-1
-    phase listed after `dispatch.plan_wait`, and both columnar stores observe
-    it once a prepare, inside `dispatch.prepare`'s time."""
-    from gubernator_tpu.models.shard import ShardStore
+    phase listed after `dispatch.plan_wait`, and the store observes it once
+    a prepare, inside `dispatch.prepare`'s time, over 8 shards and over one."""
     from gubernator_tpu.parallel.mesh import MeshBucketStore
 
     names = [p for p, _ in saturation.WATERFALL]
     at = names.index("dispatch.plan_native")
     assert saturation.WATERFALL[at] == ("dispatch.plan_native", 1)
     assert names[at - 2:at] == ["dispatch.prepare", "dispatch.plan_wait"]
-    st = MeshBucketStore(capacity_per_shard=64) if store == "mesh" else ShardStore(capacity=64)
+    st = MeshBucketStore(capacity_per_shard=64) if store == "mesh" else one_device_store(64)
     n = 12
     for t in range(5):
         st.apply_columns(

@@ -5,9 +5,8 @@ per-tenant cost ledger (Zipf-oracle accuracy, exact other-rollup
 conservation through promotion/eviction churn, bounded metric
 cardinality under 10k distinct names, the audit-pairing rule), the
 /debug/pprof & /debug/tenants surfaces, the /debug/profile host-window
-pairing, config plumbing, and the bench-history trend gate."""
+pairing, and config plumbing."""
 
-import importlib.util
 import json
 import os
 import threading
@@ -512,111 +511,3 @@ def test_service_tenant_topk_from_behaviors():
         assert svc.tenants.topk == 3
     finally:
         svc.close()
-
-
-# ---------------------------------------------------------------------
-# Bench gate row + bench-history trend tooling
-# ---------------------------------------------------------------------
-def test_gate_thresholds_carry_profiling_floor():
-    with open("benchmarks/gate_thresholds.json") as f:
-        th = json.load(f)
-    assert th["profiling_overhead_ratio"]["fail_below"] == 0.95
-
-
-def _load_trend():
-    spec = importlib.util.spec_from_file_location(
-        "bench_trend", os.path.join("scripts", "bench_trend.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_trend_helpers():
-    bt = _load_trend()
-    assert bt.median([3.0, 1.0, 2.0]) == 2.0
-    assert bt.median([4.0, 1.0, 2.0, 3.0]) == 2.5
-    assert bt.lower_is_better("service_ingress_latency_ms_p99")
-    assert bt.lower_is_better("device_batch_us")
-    assert not bt.lower_is_better("service_ingress_checks_per_sec")
-    assert len(bt.spark([1, 2, 3])) == 3
-
-
-def _write_history(tmp_path, rows):
-    hist = tmp_path / "benchmarks" / "history"
-    hist.mkdir(parents=True)
-    for i, row in enumerate(rows):
-        row.setdefault("time", float(i + 1))
-        (hist / f"run{i}.json").write_text(json.dumps(row))
-
-
-def test_bench_trend_regression_gate(tmp_path, monkeypatch, capsys):
-    bt = _load_trend()
-    monkeypatch.setattr(bt, "REPO", str(tmp_path))
-    _write_history(tmp_path, [
-        {"backend": "cpu", "service_ingress_checks_per_sec": 100_000.0},
-        {"backend": "cpu", "service_ingress_checks_per_sec": 110_000.0},
-        {"backend": "cpu", "service_ingress_checks_per_sec": 105_000.0},
-        # Newest: >20% below the rolling median (105k) -> FAIL.
-        {"backend": "cpu", "service_ingress_checks_per_sec": 70_000.0},
-    ])
-    monkeypatch.setattr("sys.argv", ["bench_trend.py"])
-    assert bt.main() == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "service_ingress_checks_per_sec" in out
-    # --no-gate always passes (the readable-history mode).
-    monkeypatch.setattr("sys.argv", ["bench_trend.py", "--no-gate"])
-    assert bt.main() == 0
-
-
-def test_bench_trend_backend_partition_and_small_n(tmp_path, monkeypatch):
-    bt = _load_trend()
-    monkeypatch.setattr(bt, "REPO", str(tmp_path))
-    # The fast prior runs are TPU; the slow newest is CPU — not
-    # comparable, and a single same-backend prior is weather, not a
-    # trend: both rules must keep the gate green.
-    _write_history(tmp_path, [
-        {"backend": "tpu", "service_ingress_checks_per_sec": 1_000_000.0},
-        {"backend": "tpu", "service_ingress_checks_per_sec": 1_100_000.0},
-        {"backend": "cpu", "service_ingress_checks_per_sec": 90_000.0},
-        {"backend": "cpu", "service_ingress_checks_per_sec": 50_000.0},
-    ])
-    monkeypatch.setattr("sys.argv", ["bench_trend.py"])
-    assert bt.main() == 0
-
-
-def test_bench_trend_lower_is_better_and_noise(tmp_path, monkeypatch):
-    bt = _load_trend()
-    monkeypatch.setattr(bt, "REPO", str(tmp_path))
-    _write_history(tmp_path, [
-        {"backend": "cpu", "device_batch_us": 100.0},
-        {"backend": "cpu", "device_batch_us": 110.0},
-        {"backend": "cpu", "device_batch_us": 105.0},
-        # 40% above the median: a latency regression...
-        {"backend": "cpu", "device_batch_us": 147.0,
-         # ...but the recorded noise covers the excess -> inconclusive,
-         # never a FAIL (the bench-gate SKIP discipline).
-         "device_batch_us_noise_us": 50.0},
-    ])
-    monkeypatch.setattr("sys.argv", ["bench_trend.py"])
-    assert bt.main() == 0
-    # Without the noise allowance the same run fails.
-    hist = tmp_path / "benchmarks" / "history"
-    row = json.loads((hist / "run3.json").read_text())
-    del row["device_batch_us_noise_us"]
-    (hist / "run3.json").write_text(json.dumps(row))
-    assert bt.main() == 1
-
-
-def test_bench_appends_history(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.chdir(tmp_path)
-    bench.append_history({"metric": "rate_limit_checks_per_sec",
-                          "value": 123.0})
-    files = list((tmp_path / "benchmarks" / "history").glob("*.json"))
-    assert len(files) == 1
-    row = json.loads(files[0].read_text())
-    assert row["value"] == 123.0
-    assert row["backend"]  # jax backend stamped
-    assert "git_sha" in row and "time" in row

@@ -16,7 +16,6 @@ import pytest
 from gubernator_tpu import wire
 from gubernator_tpu.parallel.hash_ring import ReplicatedConsistentHash
 from gubernator_tpu.parallel.mesh import MeshBucketStore
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.reshard import (
     TransferColumns,
     ring_fingerprint,
@@ -31,6 +30,8 @@ from gubernator_tpu.types import (
     SECOND,
 )
 from gubernator_tpu.utils.clock import Clock
+
+from .conftest import one_device_store
 
 T0 = 1_573_430_430_000
 
@@ -271,10 +272,10 @@ def test_commit_algorithm_switch_takes_incoming(clock):
     assert out[0].remaining == 98
 
 
-def test_shard_store_drain_commit_roundtrip(clock):
-    """The single-shard twin (ShardStore) speaks the same drain/commit
-    contract — Store-SPI deployments reshard too."""
-    src, dst = ShardStore(capacity=64), ShardStore(capacity=64)
+def test_one_device_drain_commit_roundtrip(clock):
+    """The one-device mesh (the shape of `v5e1-1m`) speaks the same
+    drain/commit contract, at the same dispatch counts."""
+    src, dst = one_device_store(64), one_device_store(64)
     now = clock.now_ms()
     src.apply([_req(f"ss{i}", hits=4) for i in range(6)], now)
     keys = [_req(f"ss{i}").hash_key() for i in range(6)]

@@ -2,6 +2,8 @@
 
 from gubernator_tpu.models.slot_table import SlotTable
 
+from .conftest import one_device_store
+
 
 def test_assign_and_hit():
     t = SlotTable(4)
@@ -71,14 +73,13 @@ class TestColumnarNarrowAndPipelined:
     def test_narrow_matches_wide(self):
         import numpy as np
 
-        from gubernator_tpu.models.shard import ShardStore
 
         rng = np.random.RandomState(7)
         now = 1_700_000_000_000
         n = 257
         keys, cols = self._cols(n, rng, now)
-        narrow = ShardStore(capacity=1024)
-        wide = ShardStore(capacity=1024)
+        narrow = one_device_store(1024)
+        wide = one_device_store(1024)
         # Force the wide path by pushing one value over int32.
         wide_cols = dict(cols)
         for step in range(3):
@@ -124,7 +125,7 @@ class TestColumnarNarrowAndPipelined:
         back; the lane->config mapping is exact."""
         import numpy as np
 
-        from gubernator_tpu.models.shard import ShardStore, make_columns
+        from gubernator_tpu.models.shard import make_columns
         from gubernator_tpu.ops import buckets
 
         rng = np.random.RandomState(11)
@@ -162,8 +163,8 @@ class TestColumnarNarrowAndPipelined:
         # End-to-end: the dict wire must match the WIDE path lane for
         # lane on identical values (wide forced by one int64 lane,
         # which is excluded from the comparison).
-        a = ShardStore(capacity=1024)
-        b = ShardStore(capacity=1024)
+        a = one_device_store(1024)
+        b = one_device_store(1024)
         wide_keys = keys + ["dw:wide"]
         for step in range(3):
             r1 = a.apply_columns(keys, now_ms=now + step, **few)
@@ -181,14 +182,13 @@ class TestColumnarNarrowAndPipelined:
     def test_pipelined_matches_sync_with_duplicates(self):
         import numpy as np
 
-        from gubernator_tpu.models.shard import ShardStore
 
         rng = np.random.RandomState(3)
         now = 1_700_000_000_000
         n = 128
         keys, cols = self._cols(n, rng, now)
-        sync = ShardStore(capacity=512)
-        pipe = ShardStore(capacity=512)
+        sync = one_device_store(512)
+        pipe = one_device_store(512)
         sync_res = [sync.apply_columns(keys, now_ms=now + i, **cols) for i in range(4)]
         handles = [pipe.apply_columns_async(keys, now_ms=now + i, **cols) for i in range(4)]
         pipe_res = [h.result() for h in handles]
@@ -208,12 +208,11 @@ class TestGroupedDuplicates:
     def _differential(self, make_req, steps=60, seed=0):
         import numpy as np
 
-        from gubernator_tpu.models.shard import ShardStore
         from gubernator_tpu.types import RateLimitRequest
 
         rng = np.random.RandomState(seed)
-        grouped = ShardStore(capacity=256)
-        serial = ShardStore(capacity=256)
+        grouped = one_device_store(256)
+        serial = one_device_store(256)
         now = 1_700_000_000_000
         for step in range(steps):
             reqs = make_req(rng, step)
@@ -334,12 +333,11 @@ def test_narrow_batch_preserves_wide_expiry():
     clipping the delta to ~24.8 days."""
     import numpy as np
 
-    from gubernator_tpu.models.shard import ShardStore
     from gubernator_tpu.types import Algorithm
 
     now = 1_700_000_000_000
     thirty_days = 30 * 24 * 3600 * 1000  # > 2**31 ms
-    store = ShardStore(capacity=64)
+    store = one_device_store(64)
     store.apply_columns(
         ["long_k"],
         algorithm=np.array([Algorithm.LEAKY_BUCKET], np.int32),
@@ -349,8 +347,8 @@ def test_narrow_batch_preserves_wide_expiry():
         duration=np.array([thirty_days], np.int64),
         now_ms=now,
     )
-    slot = store.table.get_slot("long_k")
-    assert int(store.table.get_expire_bulk([slot])[0]) == now + thirty_days
+    slot = store.tables[0].get_slot("long_k")
+    assert int(store.tables[0].get_expire_bulk([slot])[0]) == now + thirty_days
 
     # Narrow batch (every column fits int32): a status query on the
     # long-lived key.  hits=0 on a leaky bucket mutates nothing — the
@@ -369,4 +367,4 @@ def test_narrow_batch_preserves_wide_expiry():
     # The regression: a clipped delta would have rewritten this to
     # later + ~2**31 ms (~24.8 days), silently shortening the bucket's
     # life by ~5 days.
-    assert int(store.table.get_expire_bulk([slot])[0]) == now + thirty_days
+    assert int(store.tables[0].get_expire_bulk([slot])[0]) == now + thirty_days

@@ -16,7 +16,6 @@ import numpy as np
 import pytest
 
 from gubernator_tpu import snapshot as snap
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.parallel.mesh import MeshBucketStore
 from gubernator_tpu.reshard import TransferColumns
 from gubernator_tpu.service import ServiceConfig, V1Service
@@ -33,6 +32,8 @@ from gubernator_tpu.types import (
     RateLimitRequest,
 )
 from gubernator_tpu.utils.clock import Clock
+
+from .conftest import one_device_store
 
 NOW = 1_573_430_430_000
 
@@ -230,27 +231,15 @@ def test_torn_temp_file_is_not_the_snapshot(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# Store twins: one gather to dump, one merge-commit to restore
+# One gather to dump, one merge-commit to restore: over one device (the
+# shape of `v5e1-1m`) and over the harness's 8 shards
 # ---------------------------------------------------------------------
-def test_shard_store_snapshot_roundtrip_o1_dispatches():
-    src, dst = ShardStore(capacity=64), ShardStore(capacity=64)
-    src.apply([_req(f"s{i}", hits=4) for i in range(6)], NOW)
-    before = src.device_dispatches
-    cols = src.snapshot_columns(NOW)
-    assert src.device_dispatches - before == 1  # ONE gather program
-    assert len(cols) == 6
-    # Gather-only: unlike drain_keys the table keeps every key.
-    assert len(src.resident_keys()) == 6
-    before = dst.device_dispatches
-    assert dst.commit_transfer(cols, NOW) == 6
-    assert dst.device_dispatches - before == 2  # gather + scatter
-    out = dst.apply([_req(f"s{i}", hits=0) for i in range(6)], NOW)
-    assert [r.remaining for r in out] == [96] * 6
-
-
-def test_mesh_store_snapshot_roundtrip_o1_dispatches():
-    src = MeshBucketStore(capacity_per_shard=64, g_capacity=32)
-    dst = MeshBucketStore(capacity_per_shard=64, g_capacity=32)
+@pytest.mark.parametrize("mk", [
+    lambda: one_device_store(64, g_capacity=32),
+    lambda: MeshBucketStore(capacity_per_shard=64, g_capacity=32),
+], ids=["one-device", "mesh"])
+def test_mesh_store_snapshot_roundtrip_o1_dispatches(mk):
+    src, dst = mk(), mk()
     src.apply([_req(f"m{i}", hits=2) for i in range(12)], NOW)
     before = src.device_dispatches
     cols = src.snapshot_columns(NOW)
@@ -258,6 +247,8 @@ def test_mesh_store_snapshot_roundtrip_o1_dispatches():
     assert sorted(cols.keys) == sorted(
         _req(f"m{i}").hash_key() for i in range(12)
     )
+    # Gather-only: unlike drain_keys the table keeps every key.
+    assert len(src.resident_keys()) == 12
     before = dst.device_dispatches
     assert dst.commit_transfer(cols, NOW) == 12
     assert dst.device_dispatches - before == 2  # O(1): gather + scatter
@@ -274,7 +265,7 @@ def test_warmup_keys_stay_out_of_the_file():
 
 
 def test_restore_drops_expired_rows():
-    dst = ShardStore(capacity=64)
+    dst = one_device_store(64)
     cols = _cols(["live", "dead"], remaining=[5, 5],
                  expire=[NOW + 1000, NOW - 1])
     assert dst.commit_transfer(cols, NOW) == 1

@@ -55,8 +55,8 @@ def test_soak_smoke_status_invariants():
     beh = fast_test_behaviors()
     beh.batch_timeout_s = 30.0
     # SLO engine live for the soak: a generous CPU-box target — the
-    # invariant checked is "the plane reports", the bench gate owns
-    # latency regression verdicts.
+    # invariant checked is "the plane reports"; latency verdicts are
+    # the chip benchmark's.
     beh.latency_target_ms = 30_000.0
     cl = Cluster().start_with(["", ""], behaviors=beh)
     stop = threading.Event()

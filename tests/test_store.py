@@ -1,10 +1,9 @@
 """Store/Loader SPI tests: exact call-count sequences from
 store_test.go:125-287 (TestStore) and :75-123 (TestLoader), applied at
-the ShardStore level."""
+the store level, over one device and over four shards (`make_store`)."""
 
 import pytest
 
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.store import (
     CacheItem,
     LeakyBucketItem,
@@ -38,9 +37,9 @@ def get_remaining(item):
     ],
     ids=["token-empty", "token-preloaded", "leaky-empty", "leaky-preloaded"],
 )
-def test_store_call_sequences(algo, switch_algo, preload, first_rem, first_status, second_rem, second_status):
+def test_store_call_sequences(make_store, algo, switch_algo, preload, first_rem, first_status, second_rem, second_status):
     store = MockStore()
-    shard = ShardStore(capacity=64, store=store)
+    shard = make_store(64, store=store)
     req = mk(algo)
 
     if preload:
@@ -78,12 +77,12 @@ def test_store_call_sequences(algo, switch_algo, preload, first_rem, first_statu
     assert store.cache_items[req.hash_key()].algorithm == switch_algo
 
 
-def test_reset_remaining_removes_from_store():
+def test_reset_remaining_removes_from_store(make_store):
     """algorithms.go:36-47: token RESET_REMAINING removes cache + store."""
     from gubernator_tpu.types import Behavior
 
     store = MockStore()
-    shard = ShardStore(capacity=64, store=store)
+    shard = make_store(64, store=store)
     shard.apply([mk(Algorithm.TOKEN_BUCKET)], T0)
     assert store.called["OnChange()"] == 1
     req = mk(Algorithm.TOKEN_BUCKET)
@@ -95,10 +94,10 @@ def test_reset_remaining_removes_from_store():
     assert store.called["OnChange()"] == 1  # reset lane fires no OnChange
 
 
-def test_loader_roundtrip():
+def test_loader_roundtrip(make_store):
     """TestLoader (store_test.go:75-123): load at start, save at stop."""
     loader = MockLoader()
-    shard = ShardStore(capacity=64)
+    shard = make_store(64)
     for item in loader.load():
         shard.load_item(item)
     assert loader.called["Load()"] == 1 and loader.called["Save()"] == 0
@@ -120,7 +119,7 @@ def test_loader_roundtrip():
     assert item.value.status == Status.UNDER_LIMIT
 
 
-def test_loader_preload_then_hit():
+def test_loader_preload_then_hit(make_store):
     """Preloaded items serve subsequent traffic."""
     loader = MockLoader()
     loader.cache_items.append(
@@ -131,7 +130,7 @@ def test_loader_preload_then_hit():
             expire_at=T0 + 60_000,
         )
     )
-    shard = ShardStore(capacity=64)
+    shard = make_store(64)
     for item in loader.load():
         shard.load_item(item)
     req = RateLimitRequest(name="ns", unique_key="k", hits=1, limit=10, duration=60_000)

@@ -15,9 +15,10 @@ import numpy as np
 import pytest
 
 from gubernator_tpu import native
-from gubernator_tpu.models.shard import ShardStore
 from gubernator_tpu.parallel.mesh import MeshBucketStore
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, Status
+
+from . import oracle
 
 pytestmark = pytest.mark.skipif(
     not native.available(), reason="native runtime required"
@@ -94,17 +95,17 @@ def churn_workload(rng, n_keys, steps):
 @pytest.mark.parametrize("algo", [Algorithm.TOKEN_BUCKET, Algorithm.LEAKY_BUCKET])
 def test_two_tier_matches_unevicted_reference(algo):
     """front=8 forces constant demote/promote churn; responses must be
-    byte-identical to a store that never evicts."""
+    byte-identical to the sequential reference, which never evicts."""
     rng = random.Random(11)
     two = MeshBucketStore(capacity_per_shard=2, back_capacity_per_shard=512)
-    ref = ShardStore(capacity=4096)
+    ref = oracle.OracleCache()
     now = T0
     for step in range(300):
         key = f"k{rng.randrange(40)}"
         r = mk(key, hits=rng.choice([0, 1, 1, 2]), algo=algo)
         now += rng.randrange(0, 500)
         got = two.apply([r], now)[0]
-        want = ref.apply([r], now)[0]
+        want = oracle.apply(ref, r, now)
         assert (got.status, got.remaining, got.reset_time) == (
             want.status, want.remaining, want.reset_time,
         ), (step, key, got, want)
@@ -123,7 +124,7 @@ def test_two_tier_columnar_matches_unevicted_reference():
     loss), but consecutive windows force constant demote/promote."""
     rng = np.random.RandomState(5)
     two = MeshBucketStore(capacity_per_shard=16, back_capacity_per_shard=2048)
-    ref = ShardStore(capacity=8192)
+    ref = oracle.OracleCache()  # never evicts
     now = T0
     for step in range(12):
         n = 200
@@ -136,9 +137,14 @@ def test_two_tier_columnar_matches_unevicted_reference():
         duration = np.full(n, 60_000, np.int64)
         now += 700
         got = two.apply_columns(keys, algo, behavior, hits, limit, duration, now)
-        want = ref.apply_columns(keys, algo, behavior, hits, limit, duration, now)
+        want = [
+            oracle.apply(ref, RateLimitRequest(
+                name="c", unique_key=str(k), hits=1, limit=50,
+                duration=60_000, algorithm=int(a)), now)
+            for k, a in zip(ids, algo)
+        ]
         for f in ("status", "remaining", "reset_time"):
-            assert np.array_equal(got[f], want[f]), (step, f)
+            assert got[f].tolist() == [getattr(w, f) for w in want], (step, f)
     assert sum(t.tier_stats[2] for t in two.tables) > 100
     two.check_consistency()
 
