@@ -187,11 +187,12 @@ class BehaviorConfig:
     # WHEN a dispatch launches, never what it computes).
     # Env: GUBER_EXPRESS.
     express: bool = True
-    # Bypass shallow-queue threshold, in queued LANES: a submission
-    # takes the express bypass only while fewer than this many lanes
-    # are queued at its batcher (deeper queues mean the window is
-    # already coalescing real backlog — bypassing it would only add
-    # dispatches without helping latency).  Env: GUBER_EXPRESS_QUEUE_DEPTH.
+    # Once the bypass's shallow-queue threshold, in queued lanes.  The
+    # admission rule (service._ExpressPolicy) now reads what it
+    # observes — a submission bypasses only when no dispatch is under
+    # way and NOTHING is queued at its batcher — so this value is
+    # parsed, validated and reported (/debug/status) and changes no
+    # outcome.  Env: GUBER_EXPRESS_QUEUE_DEPTH.
     express_queue_depth: int = 64
     # Bypass small-batch ceiling, in lanes: submissions wider than this
     # always take the window (a wide batch amortizes its own dispatch;

@@ -702,6 +702,16 @@ class ColumnarPipeline:
         """Batches dispatched but not yet resolved (gauge value)."""
         return len(self._inflight)
 
+    def dispatch_under_way(self) -> bool:
+        """The host's dispatch section is occupied: a batch is being
+        planned, or holds a ticket and has not launched yet.  A batch
+        that has launched (on the device, or being read back) does not
+        count.  Read without a lock: the express admission rule
+        (service._ExpressPolicy) takes it as a hint of whether a
+        dispatch made now would wait its turn behind another."""
+        return (self._next_ticket != self._next_launch
+                or self._plan_lock.locked())
+
     def occupancy_stats(self) -> "List[dict]":
         """Per-shard occupancy from the HOST slot tables the dispatch
         commits already maintain — THE one occupancy read of the

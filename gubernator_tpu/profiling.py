@@ -526,6 +526,7 @@ _CMS_SALTS = np.array(
 )
 
 _STATS = ("hits", "lanes", "over_limit", "shed", "ingress_bytes")
+_MASK64 = (1 << 64) - 1
 
 
 class _TenantRow:
@@ -595,9 +596,33 @@ def _name_columns(cols):
     return names, names.__getitem__, lens, uk_lens
 
 
+def _lane_names(cols):
+    """(name_at, name_lens, uk_lens) of any ingress column shape, lane
+    by lane and without building an array: what `_name_columns` gives
+    a batch, for the few lanes of a classic call."""
+    pj = getattr(cols, "_pj", None)
+    if pj is not None:  # LazyIngressColumns: (off, len) spans into body
+        return pj.name_at, pj.nspan[1::2], pj.ukspan[1::2]
+    if getattr(cols, "_nb", None) is not None:  # FrameIngressColumns
+        no, uo = cols._no, cols._uo
+        return (
+            cols._name_at,
+            [no[i + 1] - no[i] for i in range(len(cols))],
+            [uo[i + 1] - uo[i] for i in range(len(cols))],
+        )
+    names = cols.names  # plain lists (classic JSON / proto decode)
+    return (
+        names.__getitem__,
+        [len(s) for s in names],
+        [len(s) for s in cols.unique_keys],
+    )
+
+
 class TenantLedger:
     """Cardinality-bounded per-tenant cost accounting (see module
-    docstring).  All folds are per BATCH and vectorized over lanes;
+    docstring).  All folds are per BATCH and vectorized over lanes —
+    except a batch of at most `topk` lanes, which folds lane by lane
+    in plain Python (`_fold_few`) — and
     Python touches at most `topk` tenants per fold.  Conservation holds
     exactly for every stat: `sum(rows) + other == totals` — promotion
     moves a tenant's CURRENT batch out of `other` into its new row, and
@@ -610,6 +635,7 @@ class TenantLedger:
         self._lock = threading.Lock()
         self._tab = np.zeros((self.depth, self.width), dtype=np.int64)
         self._salts = _CMS_SALTS[: self.depth]
+        self._salt_ints = [int(x) for x in self._salts]
         self._rows: Dict[int, _TenantRow] = {}  # name hash -> row
         self._row_hashes = np.zeros(0, dtype=np.uint64)  # sorted, for isin
         self._other = dict.fromkeys(_STATS, 0)
@@ -631,6 +657,12 @@ class TenantLedger:
             s = _sampler
             if s is not None:
                 s.maybe_tick()
+        if n <= self.topk:
+            return self._fold_few(cols, n)
+        return self._fold_batch(cols)
+
+    def _fold_batch(self, cols) -> _TenantCtx:
+        """fold_admit, vectorized over the lanes of a batch."""
         from . import native
 
         names, name_at, name_lens, uk_lens = _name_columns(cols)
@@ -676,6 +708,85 @@ class TenantLedger:
                 self._promote_locked(
                     un, est, uh, first, name_at,
                     hits_u, lanes_u, bytes_u,
+                )
+        return ctx
+
+    def _fold_few(self, cols, n: int) -> _TenantCtx:
+        """fold_admit for the few lanes of a classic call (n <= topk,
+        the bound on a fold's Python that the ledger already keeps):
+        the same accounting and the same context, lane by lane.  The
+        vector fold spends some twenty numpy calls on two lanes, a
+        dozen of them under the ledger's lock; every request of every
+        edge worker passes through that lock, and a holder that loses
+        the interpreter mid-fold parks them all (PERF.md §6, PR 30: on
+        the chip the sampler found 89% of the edge workers' samples
+        here).  This one holds the lock for a few scalar updates."""
+        from .utils import hashing
+
+        name_at, name_lens, uk_lens = _lane_names(cols)
+        hits = cols.hits
+        by_name: dict = {}  # name -> [first lane, lanes, hits, bytes]
+        lane_names = []
+        for i in range(n):
+            name = name_at(i)
+            lane_names.append(name)
+            g = by_name.get(name)
+            if g is None:
+                g = by_name[name] = [i, 0, 0, 0]
+            g[1] += 1
+            g[2] += int(hits[i])
+            g[3] += int(name_lens[i]) + int(uk_lens[i]) + NUMERIC_LANE_BYTES
+        # Uniques in hash order, as np.unique leaves them in the vector
+        # fold: a context (and a promotion) reads the same either way.
+        uniq = sorted(
+            (hashing.fnv1_64(name.encode("utf-8")), name, g)
+            for name, g in by_name.items()
+        )
+        m = len(uniq)
+        place = {name: j for j, (_, name, _) in enumerate(uniq)}
+        ctx = _TenantCtx(
+            np.fromiter((place[nm] for nm in lane_names), np.intp, count=n),
+            np.fromiter((h for h, _, _ in uniq), np.uint64, count=m),
+            np.fromiter((g[0] for _, _, g in uniq), np.int64, count=m),
+            name_at,
+        )
+        width, tab = self.width, self._tab
+        cells = [
+            [((h * salt & _MASK64) >> 17) % width for salt in self._salt_ints]
+            for h, _, _ in uniq
+        ]
+        with self._lock:
+            self.batches += 1
+            for (_, _, g), cs in zip(uniq, cells):
+                for r, c in enumerate(cs):
+                    tab[r, c] += g[2]
+            est = [
+                min(int(tab[r, c]) for r, c in enumerate(cs)) for cs in cells
+            ]
+            untracked = []
+            for j, (h, _, g) in enumerate(uniq):
+                self._totals["hits"] += g[2]
+                self._totals["lanes"] += g[1]
+                self._totals["ingress_bytes"] += g[3]
+                row = self._rows.get(h)
+                if row is not None:
+                    row.est = est[j]
+                    row.hits += g[2]
+                    row.lanes += g[1]
+                    row.ingress_bytes += g[3]
+                else:
+                    self._other["hits"] += g[2]
+                    self._other["lanes"] += g[1]
+                    self._other["ingress_bytes"] += g[3]
+                    untracked.append(j)
+            if untracked:
+                self._promote_locked(
+                    np.array(untracked, dtype=np.intp),
+                    np.array(est, dtype=np.int64), ctx.uh, ctx.first,
+                    name_at,
+                    np.array([g[2] for _, _, g in uniq], dtype=np.int64),
+                    np.array([g[1] for _, _, g in uniq], dtype=np.int64),
+                    np.array([g[3] for _, _, g in uniq], dtype=np.int64),
                 )
         return ctx
 

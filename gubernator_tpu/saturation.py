@@ -404,17 +404,29 @@ class ExpressStats:
                        window flush OR the native ring's bulk path
                        (the pump feeds both into this denominator)
 
+    and each submission the batchers' admission rule sent to the window
+    notes why it did not bypass (service._ExpressPolicy):
+
+      * ``wide``      — more lanes than GUBER_EXPRESS_MAX_LANES
+      * ``launching`` — a dispatch was under way (being planned, or
+                        planned and not launched; or the batcher's
+                        flusher inside its flush)
+      * ``queued``    — lanes were waiting at the batcher
+
     `take()` drains per-scrape deltas for the gubernator_express_*
     counters; `snapshot()` serves cumulative counts + the hit rate at
     /debug/latency and /debug/status."""
 
     PATHS = ("bypass", "scalar", "native", "windowed")
+    DECLINED = ("wide", "launching", "queued")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._lanes = {p: 0 for p in self.PATHS}
         self._dispatches = {p: 0 for p in self.PATHS}
         self._delta_lanes = {p: 0 for p in self.PATHS}
+        self._declined_lanes = {r: 0 for r in self.DECLINED}
+        self._declined = {r: 0 for r in self.DECLINED}
 
     def note(self, path: str, lanes: int) -> None:
         with self._lock:
@@ -423,6 +435,11 @@ class ExpressStats:
             self._delta_lanes[path] = (
                 self._delta_lanes.get(path, 0) + int(lanes)
             )
+
+    def note_declined(self, reason: str, lanes: int) -> None:
+        with self._lock:
+            self._declined_lanes[reason] += int(lanes)
+            self._declined[reason] += 1
 
     def take(self) -> Dict[str, int]:
         with self._lock:
@@ -440,6 +457,10 @@ class ExpressStats:
             return {
                 "lanes": dict(self._lanes),
                 "dispatches": dict(self._dispatches),
+                "declined": {
+                    "lanes": dict(self._declined_lanes),
+                    "submissions": dict(self._declined),
+                },
                 "hitRate": round(express / total, 4) if total else 0.0,
             }
 
@@ -454,6 +475,11 @@ express = ExpressStats()
 def note_express(path: str, lanes: int) -> None:
     """Record one express/batched dispatch (see ExpressStats)."""
     express.note(path, lanes)
+
+
+def note_express_declined(reason: str, lanes: int) -> None:
+    """Record one submission the admission rule sent to the window."""
+    express.note_declined(reason, lanes)
 
 
 def express_snapshot() -> dict:

@@ -97,10 +97,11 @@ class _IngressGate:
     """Shared lane accounting for the bounded ingress queue
     (GUBER_INGRESS_QUEUE_LANES): admit at submit, release at flush.
     cap <= 0 disables the bound.  `track` keeps lane COUNTING on even
-    with the bound off — the express bypass reads `queued` as its
-    shallow-queue signal, which must work whether or not the shed
-    bound is armed; with both the cap and the express lane off
-    (`track=False`), admit/release are the pre-express no-ops."""
+    with the bound off — the express bypass takes `queued == 0` as
+    "nothing waits at this batcher" (`admit_idle`), which must work
+    whether or not the shed bound is armed; with both the cap and the
+    express lane off (`track=False`), admit/release are the
+    pre-express no-ops."""
 
     def __init__(self, cap: int, metrics: Optional[Metrics],
                  track: bool = False):
@@ -138,6 +139,19 @@ class _IngressGate:
                 "shed", lanes=lanes, queued=queued, cap=self.cap
             )
             raise IngressShedError(queued, self.cap)
+
+    def admit_idle(self, lanes: int) -> bool:
+        """Reserve `lanes` only if nothing else is admitted: the express
+        bypass's test and its admission in one step, so two arrivals at
+        an idle batcher cannot both find it idle.  False (nothing
+        reserved) when lanes are queued, or when the bound would shed
+        them (the windowed path's admit then sheds, and counts it)."""
+        with self._mu:
+            if self._queued or 0 < self.cap < lanes:
+                return False
+            self._queued = lanes
+        saturation.observe_queue_depth(lanes)
+        return True
 
     def release(self, lanes: int) -> None:
         if self.cap <= 0 and not self.track:
@@ -199,20 +213,36 @@ class ServiceConfig:
 
 
 class _ExpressPolicy:
-    """The express-lane bypass rule, shared by both batchers
+    """The express-lane admission rule, shared by both batchers
     (architecture.md "Express lane"): a submission of n lanes skips the
-    coalescing window entirely when
+    coalescing window and dispatches on its caller's thread only when
+    NO DISPATCH IS UNDER WAY and nothing is queued.  Otherwise it joins
+    the queue and rides the next dispatch: a caller that would wait its
+    turn at the launch gate behind an older ticket anyway costs every
+    other caller a whole host dispatch section for its few lanes.
 
-      * the lane is enabled (GUBER_EXPRESS),
-      * n <= GUBER_EXPRESS_MAX_LANES (the small interactive shapes the
-        warm fused size-1/2/4 programs serve),
-      * the batcher queue is SHALLOW — fewer than
-        GUBER_EXPRESS_QUEUE_DEPTH lanes admitted and unflushed (a deep
-        queue means the window is coalescing real backlog; bypassing it
-        would add dispatches without helping anyone's latency), and
-      * the dispatch pipeline is shallow (<= MAX_DEPTH unresolved
-        batches — commits are FIFO, so an express dispatch behind a
-        deep pipeline would wait out every older readback anyway).
+    A submission bypasses when the lane is enabled (GUBER_EXPRESS) and
+    none of these holds, tested in this order (the first that holds is
+    the reason `saturation.ExpressStats` counts):
+
+      * ``wide`` — n > GUBER_EXPRESS_MAX_LANES: the bypass serves the
+        small interactive shapes whose solo programs warm-up compiles;
+      * ``launching`` — a dispatch is under way: the store is planning
+        a batch or holds a ticket planned and not yet launched
+        (`dispatch_under_way()`, whoever submitted it), or this
+        batcher's flusher is inside its flush (`BatchWindow.flushing`,
+        raised before the lanes it took leave the gate, so an arrival
+        never finds the queue empty and no dispatch under way between
+        the two);
+      * ``queued`` — lanes are admitted at this batcher and not yet
+        handed to a dispatch: the window's queue, or another bypass
+        between its admission and its launch.
+
+    A dispatch that has LAUNCHED and is on the device or being read
+    back does not make the path busy: the host's dispatch section is
+    free, so a lone sequential client, and any client that arrives
+    between dispatches, bypasses on every call.  Nothing here is a
+    tuned number: the rule reads only what the code observes.
 
     The bypass changes WHEN a dispatch launches, never what it
     computes: results are byte-identical to the windowed path.
@@ -223,18 +253,10 @@ class _ExpressPolicy:
     window owns span creation — the same rule that turns the native
     fast lane off under sampling (NativeIngressPump.active)."""
 
-    #: Unresolved-pipeline ceiling for the bypass: past two in-flight
-    #: batches the FIFO commit wait dominates whatever the window
-    #: would have cost.
-    MAX_DEPTH = 2
-
-    __slots__ = ("enabled", "queue_depth", "max_lanes")
+    __slots__ = ("enabled", "max_lanes")
 
     def __init__(self, behaviors: BehaviorConfig):
         self.enabled = bool(getattr(behaviors, "express", False))
-        self.queue_depth = int(
-            getattr(behaviors, "express_queue_depth", 64)
-        )
         self.max_lanes = int(getattr(behaviors, "express_max_lanes", 4))
 
     def window_cap_s(self, behaviors: BehaviorConfig) -> "Optional[float]":
@@ -247,12 +269,23 @@ class _ExpressPolicy:
             return None
         return target_ms / 2000.0
 
-    def bypass_ok(self, n: int, gate: "_IngressGate", store) -> bool:
-        if not self.enabled or n > self.max_lanes:
+    def admit(self, n: int, gate: "_IngressGate", store,
+              flushing: bool) -> bool:
+        """True: the submission bypasses, its lanes admitted at `gate`
+        (the caller releases them once its dispatch has launched).
+        False: it takes the window, and the reason is counted."""
+        if not self.enabled:
             return False
-        if gate.queued + n > self.queue_depth:
-            return False
-        return store.pipeline_depth() <= self.MAX_DEPTH
+        if n > self.max_lanes:
+            why = "wide"
+        elif flushing or store.dispatch_under_way():
+            why = "launching"
+        elif not gate.admit_idle(n):
+            why = "queued"
+        else:
+            return True
+        saturation.note_express_declined(why, n)
+        return False
 
 
 class LocalBatcher:
@@ -266,8 +299,8 @@ class LocalBatcher:
     (batch_wait/batch_limit, config.go:107-109), same defeat-the-
     thundering-herd purpose, applied at the ingress edge.  Requests
     flagged NO_BATCHING bypass the window (proto/gubernator.proto:74-78
-    semantics), and under the express lane (GUBER_EXPRESS) shallow-queue
-    submissions bypass it too."""
+    semantics), and under the express lane (GUBER_EXPRESS) so does a
+    submission that finds no dispatch under way (_ExpressPolicy)."""
 
     def __init__(self, store, behaviors: BehaviorConfig, clock: Clock,
                  metrics: Optional[Metrics] = None):
@@ -291,8 +324,8 @@ class LocalBatcher:
         if self._window.stopped:
             fut.set_exception(PeerError(ERR_BATCHER_CLOSED))
             return fut
-        if tracing.current() is None and self._express.bypass_ok(
-            1, self._gate, self.store
+        if tracing.current() is None and self._express.admit(
+            1, self._gate, self.store, self._window.flushing
         ):
             return self._submit_express(req, fut)
         try:
@@ -313,12 +346,7 @@ class LocalBatcher:
         same store.apply a one-element window flush would run, minus
         the window.  The caller blocks on fut.result() immediately
         after submit, so the inline evaluation moves the wait, it does
-        not add one."""
-        try:
-            self._gate.admit(1)
-        except IngressShedError as e:
-            fut.set_exception(e)
-            return fut
+        not add one.  The policy admitted the lane at the gate."""
         with phase("express.submit"):
             try:
                 resp = self.store.apply([req], self.clock.now_ms())[0]
@@ -675,7 +703,8 @@ class _HandleDrainer:
     MAX_THREADS = 32
 
     def __init__(self):
-        self._q: "deque" = deque()
+        self._q: "deque" = deque()  # handles awaiting a worker
+        self._waiters: dict = {}  # id(handle) -> callbacks, while unresolved
         self._cv = threading.Condition()
         self._stopped = False
         self._threads: list = []
@@ -697,7 +726,11 @@ class _HandleDrainer:
 
     def register(self, handle, cb) -> None:
         """cb(value, exc) fires exactly once from a drainer thread (or
-        inline with a shutdown error when the drainer has stopped)."""
+        inline with a shutdown error when the drainer has stopped).
+        Callbacks registered for ONE handle share one resolution: the
+        worker that took the handle reads it back once and fires them
+        in registration order, so k waiters of a coalesced dispatch
+        cost one thread, not k racing for the interpreter."""
         # Backlog hint: ask for the handle's device->host copy NOW so a
         # deep pipeline's transfers overlap even while every worker is
         # parked on an older readback (the launch stage already
@@ -711,7 +744,12 @@ class _HandleDrainer:
                 pass
         with self._cv:
             if not self._stopped:
-                self._q.append((handle, cb))
+                waiting = self._waiters.get(id(handle))
+                if waiting is not None:
+                    waiting.append(cb)  # its worker fires this one too
+                    return
+                cbs = self._waiters[id(handle)] = [cb]
+                self._q.append((handle, cbs))
                 # Backlog deeper than the idle workers that will drain
                 # it => the dispatch depth outgrew the pool; add one
                 # thread per register until they match (bounded).
@@ -733,16 +771,21 @@ class _HandleDrainer:
                 self._idle -= 1
                 if not self._q:
                     return  # stopped and drained
-                handle, cb = self._q.popleft()
+                handle, cbs = self._q.popleft()
             value, exc = None, None
             try:
                 value = handle.result()
             except Exception as e:  # noqa: BLE001
                 exc = e
-            try:
-                cb(value, exc)
-            except Exception:  # noqa: BLE001 — a callback must not kill the pool
-                logger.exception("columns drainer callback failed")
+            with self._cv:
+                # Closed under the lock: a later register() of this
+                # handle opens a resolution of its own.
+                del self._waiters[id(handle)]
+            for cb in cbs:
+                try:
+                    cb(value, exc)
+                except Exception:  # noqa: BLE001 — a callback must not kill the pool
+                    logger.exception("columns drainer callback failed")
 
     def stop(self, timeout_s: float = 30.0) -> None:
         """Resolve everything already registered (workers drain the
@@ -958,8 +1001,8 @@ class ColumnarBatcher:
             fut.set_exception(PeerError(ERR_BATCHER_CLOSED))
             return fut
         n = len(keys)
-        if not trace_links and self._express.bypass_ok(
-            n, self._gate, self.store
+        if not trace_links and self._express.admit(
+            n, self._gate, self.store, self._window.flushing
         ):
             return self._submit_express(
                 keys, algo, behavior, hits, limit, duration,
@@ -996,13 +1039,9 @@ class ColumnarBatcher:
         singleton).  The future resolves immediately with the handle
         slice; the caller's readback overlaps like any other waiter's.
         Only unsampled submissions arrive here (submit gates on
-        trace_links), so no span bookkeeping is owed."""
+        trace_links), so no span bookkeeping is owed.  The policy
+        admitted the lanes at the gate; they leave it once launched."""
         n = len(keys)
-        try:
-            self._gate.admit(n)
-        except IngressShedError as e:
-            fut.set_exception(e)
-            return fut
         with phase("express.submit"):
             try:
                 ge = np.zeros(n, np.int64) if greg_expire is None else greg_expire

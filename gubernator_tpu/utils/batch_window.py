@@ -29,6 +29,11 @@ Two extensions over the reference shape:
   budget coalescing.  None (the default) keeps the occupancy-driven
   window untouched.
 
+A flusher that returns from a flush to a queue that is NOT empty takes
+what is there at once (up to `limit`) and flushes it: the window exists
+to gather company for the first submission of an idle queue, and a
+backlog is company.  `wait_s` still bounds that first submission's wait.
+
 `stop()` joins the worker FIRST and then drains + flushes anything
 still queued — including items that raced past a closing check into
 the queue — so no submitted item is ever silently dropped.
@@ -69,6 +74,9 @@ class BatchWindow:
         self._rate: float = 0.0  # EMA weighted-items/s (adaptive only)
         self._last_flush_t: Optional[float] = None
         self._queue: "Queue" = Queue()
+        # The worker is inside `flush`: what it took has left the queue
+        # and is not dispatched yet (read by the express admission rule).
+        self.flushing = False
         self._stopped = threading.Event()
         self._worker: "threading.Thread | None" = None
         self._worker_lock = threading.Lock()
@@ -119,6 +127,9 @@ class BatchWindow:
         return None
 
     def _run(self) -> None:
+        # What queued up behind a flush is company already: the flusher
+        # takes it as it finds it, without holding a window over it.
+        backlog = False
         while True:
             with phase("window.idle"):  # nothing is waiting on this flusher
                 first = self._first()
@@ -130,11 +141,14 @@ class BatchWindow:
             deadline = t_first + self.effective_wait_s()
             with phase("window.hold"):  # submissions wait for the window
                 while count < self.limit:
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
                     try:
-                        item = self._queue.get(timeout=remaining)
+                        if backlog:
+                            item = self._queue.get_nowait()
+                        else:
+                            remaining = deadline - time.monotonic()
+                            if remaining <= 0:
+                                break
+                            item = self._queue.get(timeout=remaining)
                     except Empty:
                         break
                     batch.append(item)
@@ -153,7 +167,12 @@ class BatchWindow:
                     inst if self._rate == 0.0
                     else (1 - self.RATE_EMA) * self._rate + self.RATE_EMA * inst
                 )
-            self._flush(batch)
+            self.flushing = True
+            try:
+                self._flush(batch)
+            finally:
+                self.flushing = False
+            backlog = not self._queue.empty()
 
     def stop(self, timeout_s: float = 5.0) -> None:
         """Stop the worker, then drain-and-flush every leftover item."""

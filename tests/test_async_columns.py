@@ -7,6 +7,7 @@ delivery, error conversion, shutdown behavior, and the no-blocked-
 worker property (in-flight requests > worker threads)."""
 
 import json
+import functools
 import threading
 import time
 import urllib.request
@@ -345,6 +346,61 @@ def test_handle_drainer_contract():
     assert len(late) == 1
     v, e = late[0]
     assert v is None and isinstance(e, PeerError)
+
+
+def test_handle_drainer_resolves_a_shared_handle_once():
+    """k waiters of ONE handle (a coalesced dispatch) cost one
+    resolution and one thread: the callbacks fire in registration
+    order, a raising one does not starve the rest, and a registration
+    after the handle resolved opens a resolution of its own."""
+    from gubernator_tpu.service import _HandleDrainer
+
+    class Handle:
+        def __init__(self):
+            self.calls = 0
+            self.gate = threading.Event()
+
+        def result(self):
+            self.calls += 1
+            assert self.gate.wait(10)
+            return "v"
+
+    d = _HandleDrainer()
+    d.start()
+    try:
+        h, other = Handle(), Handle()
+        got, done = [], threading.Event()
+        deadline = time.monotonic() + 10
+        while d._idle < d.MIN_THREADS and time.monotonic() < deadline:
+            time.sleep(0.001)  # both workers parked: none is spawned below
+
+        def cb(i, v, e):
+            got.append((i, v, e, threading.current_thread().name))
+            if i == 2:
+                raise RuntimeError("consumer bug")
+            if i == 5:
+                done.set()
+
+        for i in range(6):
+            d.register(h, functools.partial(cb, i))
+        other_done = threading.Event()
+        d.register(other, lambda v, e: other_done.set())
+        assert len(d._threads) == d.MIN_THREADS  # two handles, two workers
+        other.gate.set()
+        assert other_done.wait(10)  # not queued behind the shared handle
+        assert not got
+        h.gate.set()
+        assert done.wait(10)
+        assert h.calls == 1
+        assert [(i, v, e) for i, v, e, _ in got] == [
+            (i, "v", None) for i in range(6)
+        ]
+        assert len({name for *_, name in got}) == 1
+        late = threading.Event()
+        d.register(h, lambda v, e: late.set())
+        assert late.wait(10) and h.calls == 2
+    finally:
+        d.stop(timeout_s=5.0)
 
 
 def test_async_callback_exception_does_not_wedge(service):

@@ -6,6 +6,7 @@ NO_BATCHING on the native hot path."""
 
 from __future__ import annotations
 
+import dataclasses
 import random
 import threading
 import time
@@ -30,6 +31,7 @@ from gubernator_tpu.types import (
 )
 from gubernator_tpu.utils.batch_window import BatchWindow
 
+from . import oracle
 from .conftest import one_device_store
 
 
@@ -136,12 +138,113 @@ def _drive_stream(svc: V1Service, seed: int):
     return out
 
 
+class _HeldTicket:
+    """A dispatch held between plan and launch: its stage step (after
+    the ticket is taken, before the launch gate) blocks until released,
+    so every younger ticket waits at the gate behind it."""
+
+    def __init__(self, store, now_ms: int = 1_700_000_000_000):
+        # 100 lanes a shard: another pad bucket than a few calls', so
+        # the launch cannot fuse the two into one program.
+        lanes = 100 * store.n_shards
+        self.release = threading.Event()
+        self.handle = None
+        staged = threading.Event()
+        real = store._stage_columns
+
+        def stage(prep):
+            store._stage_columns = real  # this batch alone is held
+            staged.set()
+            assert self.release.wait(60)
+            return real(prep)
+
+        store._stage_columns = stage
+        self.reqs = [
+            RateLimitRequest(name="xt", unique_key=f"held{i}", hits=1,
+                             limit=20, duration=60_000)
+            for i in range(lanes)
+        ]
+
+        def run():
+            self.handle = store.apply_columns_async(
+                [r.hash_key() for r in self.reqs],
+                np.zeros(lanes, np.int32), np.zeros(lanes, np.int32),
+                np.ones(lanes, np.int64), np.full(lanes, 20, np.int64),
+                np.full(lanes, 60_000, np.int64), now_ms,
+            )
+
+        self._thread = threading.Thread(target=run, daemon=True)
+        self._thread.start()
+        assert staged.wait(60)
+
+    def launch(self) -> dict:
+        self.release.set()
+        self._thread.join(60)
+        return self.handle.result()
+
+
+def _call_reqs(i: int, algo: int = 0):
+    """The two checks of one classic call; key 0 is shared by every
+    call, so the calls' order shows in the answers."""
+    return [
+        RateLimitRequest(name="xt", unique_key=k, hits=1, limit=6,
+                         duration=60_000, algorithm=algo)
+        for k in ("shared", f"own{i}")
+    ]
+
+
+def _submit_reqs(svc: V1Service, reqs):
+    """One submission to the columnar batcher, as _dispatch_fast makes it."""
+    n = len(reqs)
+    return svc.columnar_batcher.submit(
+        [r.hash_key() for r in reqs],
+        np.array([r.algorithm for r in reqs], np.int32),
+        np.zeros(n, np.int32),
+        np.array([r.hits for r in reqs], np.int64),
+        np.array([r.limit for r in reqs], np.int64),
+        np.array([r.duration for r in reqs], np.int64),
+        None, None,
+    )
+
+
+def _triples(fut):
+    handle, lo, hi = fut.result(timeout=60)
+    out = handle.result()
+    return [
+        (int(out["status"][i]), int(out["remaining"][i]),
+         int(out["reset_time"][i]))
+        for i in range(lo, hi)
+    ]
+
+
+def _wait_for(cond, what: str, timeout_s: float = 30.0) -> None:
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.002)
+
+
+def _held_burst(svc: V1Service, k: int = 5):
+    """k two-check calls submitted while an older ticket is held
+    between plan and launch; the futures, then the held batch's
+    answers once released."""
+    store = svc.store
+    held = _HeldTicket(store)
+    futs = [_submit_reqs(svc, _call_reqs(i, algo=i % 2)) for i in range(k)]
+    # The flusher took them and waits at the launch gate (ticket 1).
+    _wait_for(lambda: store._next_ticket >= 2, "the burst was never planned")
+    held.launch()
+    return futs
+
+
 @pytest.mark.parametrize("store_kind", ["one-device", "mesh"])
 @pytest.mark.parametrize("seed", [21, 22])
 def test_bypass_vs_windowed_byte_identical(store_kind, seed):
     """The express bypass changes WHEN a dispatch launches, never what
     it computes: the same seeded request stream through an express-on
-    and an express-off service answers identically."""
+    and an express-off service answers identically, and so does a
+    burst that arrives while a dispatch is under way (the on-service
+    sends it through the window as ONE dispatch)."""
     def mk(express: bool):
         store = (
             one_device_store(512) if store_kind == "one-device"
@@ -160,9 +263,249 @@ def test_bypass_vs_windowed_byte_identical(store_kind, seed):
         assert on.store.scalar_applies > 0
         assert off.store.scalar_applies == 0
         assert off.store.scalar_fast_path is False
+        burst_on = [_triples(f) for f in _held_burst(on)]
+        burst_off = [_triples(f) for f in _held_burst(off)]
+        assert burst_on == burst_off
+        assert _drive_stream(on, seed + 100) == _drive_stream(off, seed + 100)
     finally:
         on.close()
         off.close()
+
+
+# ---------------------------------------------------------------------
+# The admission rule: bypass only when no dispatch is under way
+# ---------------------------------------------------------------------
+
+def _oracle_triples(cache, reqs, now):
+    return [
+        (int(r.status), int(r.remaining), int(r.reset_time))
+        for r in (oracle.apply(cache, q, now) for q in reqs)
+    ]
+
+
+@pytest.mark.parametrize(
+    "case", ["idle", "held", "launched-unresolved", "queued", "wide"]
+)
+def test_admission_rule(make_store, case):
+    """A submission bypasses the window only when no dispatch is under
+    way and nothing is queued; answers and final rows are the
+    sequential oracle's for the submission order either way."""
+    saturation.reset()
+    clock = _FixedClock()
+    now = clock.now_ms()
+    store = make_store(512)
+    # The scalar slot off, as on the chip: every dispatch is a program.
+    svc = _service(
+        BehaviorConfig(batch_wait_s=0.25, express_scalar=False),
+        store=store, clock=clock,
+    )
+    cache = oracle.OracleCache()
+    asked = []  # every request, in the order the store must apply them
+
+    def declined():
+        return saturation.express_snapshot()["declined"]["submissions"]
+
+    def bypassed():
+        return saturation.express_snapshot()["dispatches"]["bypass"]
+
+    try:
+        assert not store.dispatch_under_way()
+        if case == "idle":
+            d0 = store.device_dispatches
+            for i in range(3):  # a lone sequential client: every call
+                reqs = _call_reqs(i)
+                fut = _submit_reqs(svc, reqs)
+                assert fut.done()  # dispatched on the caller's thread
+                assert _triples(fut) == _oracle_triples(cache, reqs, now)
+                asked += reqs
+            assert bypassed() == 3 and sum(declined().values()) == 0
+            assert store.device_dispatches == d0 + 3
+        elif case == "held":
+            k = 5
+            held = _HeldTicket(store, now_ms=now)
+            assert store.dispatch_under_way()
+            d0 = store.device_dispatches
+            calls = [_call_reqs(i, algo=i % 2) for i in range(k)]
+            futs = [_submit_reqs(svc, c) for c in calls]
+            assert not any(f.done() for f in futs)
+            _wait_for(lambda: store._next_ticket >= 2, "never planned")
+            held_out = held.launch()
+            got = [f.result(timeout=60) for f in futs]
+            # ONE dispatch of 2k lanes: one handle, the calls its slices.
+            assert len({id(h) for h, _, _ in got}) == 1
+            assert [(lo, hi) for _, lo, hi in got] == [
+                (2 * i, 2 * i + 2) for i in range(k)
+            ]
+            assert store.device_dispatches == d0 + 2  # the held one + 1
+            assert declined() == {"wide": 0, "launching": k, "queued": 0}
+            assert bypassed() == 0
+            want_held = _oracle_triples(cache, held.reqs, now)
+            assert [
+                (int(held_out["status"][i]), int(held_out["remaining"][i]),
+                 int(held_out["reset_time"][i]))
+                for i in range(len(held.reqs))
+            ] == want_held
+            for f, c in zip(futs, calls):
+                assert _triples(f) == _oracle_triples(cache, c, now)
+            asked += held.reqs + [r for c in calls for r in c]
+        elif case == "launched-unresolved":
+            # Three dispatches launched and nobody reading them back:
+            # the host's dispatch section is free, so a call bypasses.
+            handles, first = [], []
+            for i in range(3):
+                reqs = _call_reqs(100 + i)
+                first.append(reqs)
+                handles.append(store.apply_columns_async(
+                    [r.hash_key() for r in reqs], np.zeros(2, np.int32),
+                    np.zeros(2, np.int32), np.ones(2, np.int64),
+                    np.full(2, 6, np.int64), np.full(2, 60_000, np.int64),
+                    now,
+                ))
+            assert store.pipeline_depth() == 3
+            assert not store.dispatch_under_way()
+            reqs = _call_reqs(0)
+            fut = _submit_reqs(svc, reqs)
+            assert fut.done() and bypassed() == 1
+            assert sum(declined().values()) == 0
+            for q in first:
+                _oracle_triples(cache, q, now)
+            assert _triples(fut) == _oracle_triples(cache, reqs, now)
+            asked += [r for q in first for r in q] + reqs
+        else:
+            # A wide submission takes the window; a call that arrives
+            # while it waits there joins it and rides its dispatch.
+            d0 = store.device_dispatches
+            wide = [
+                RateLimitRequest(name="xt", unique_key=f"w{i % 3}", hits=1,
+                                 limit=6, duration=60_000)
+                for i in range(8)
+            ]
+            f_wide = _submit_reqs(svc, wide)
+            assert not f_wide.done()
+            assert declined() == {"wide": 1, "launching": 0, "queued": 0}
+            futs, calls = [f_wide], [wide]
+            if case == "queued":
+                calls.append(_call_reqs(0))
+                futs.append(_submit_reqs(svc, calls[-1]))
+                assert declined()["queued"] == 1
+            got = [f.result(timeout=60) for f in futs]
+            assert len({id(h) for h, _, _ in got}) == 1
+            assert store.device_dispatches == d0 + 1 and bypassed() == 0
+            for f, c in zip(futs, calls):
+                assert _triples(f) == _oracle_triples(cache, c, now)
+                asked += c
+        # Final rows: a zero-hit read of every key against the oracle's.
+        first = {}
+        for r in asked:
+            first.setdefault((r.unique_key, r.algorithm), r)
+        peeks = [dataclasses.replace(r, hits=0) for r in first.values()]
+        rows = svc.get_rate_limits(
+            GetRateLimitsRequest(requests=peeks)
+        ).responses
+        assert [
+            (int(r.status), int(r.remaining), int(r.reset_time)) for r in rows
+        ] == _oracle_triples(cache, peeks, now)
+        assert svc.auditor.check_now() == []
+    finally:
+        svc.close()
+        saturation.reset()
+
+
+def test_steady_concurrency_coalesces_and_accounts_exactly():
+    """32 closed-loop callers of 2-check calls on a one-device store:
+    every arrival but the first finds a dispatch under way, so calls
+    ride dispatches of many lanes; every hit is accounted exactly.
+
+    The store's stage step is given the chip's cost (a flush of
+    `v5e1-1m.singles` spends 3.0 ms in dispatch.stage and 2.5 ms in
+    dispatch.launch, PERF.md §5; the CPU's 0.6 ms would leave the
+    callers' own Python, not the dispatch section, setting the pace):
+    5 ms asleep, outside the interpreter, as an upload is."""
+    threads, calls_each, keys, limit = 32, 25, 24, 40
+    saturation.reset()
+    store = one_device_store(512)
+    stage = store._stage_columns
+
+    def stage_at_the_chips_cost(prep):
+        time.sleep(0.005)
+        return stage(prep)
+
+    store._stage_columns = stage_at_the_chips_cost
+    svc = _service(
+        BehaviorConfig(express_scalar=False), store=store,
+        clock=_FixedClock(),
+    )
+    granted = [[0] * keys for _ in range(threads)]
+    errors = []
+    deadline = time.monotonic() + 120.0  # this test's own time limit
+
+    def caller(t: int):
+        rng = random.Random(1000 + t)
+        try:
+            for _ in range(calls_each):
+                assert time.monotonic() < deadline, "time limit"
+                ks = rng.sample(range(keys), 2)
+                cols = IngressColumns(
+                    names=["st"] * 2, unique_keys=[f"k{k}" for k in ks],
+                    algorithm=np.zeros(2, np.int32),
+                    behavior=np.zeros(2, np.int32),
+                    hits=np.ones(2, np.int64),
+                    limit=np.full(2, limit, np.int64),
+                    duration=np.full(2, 3_600_000, np.int64),
+                )
+                rc = svc.get_rate_limits_columns(cols)
+                for i, k in enumerate(ks):
+                    resp = rc.response_at(i)
+                    assert resp.error == "" and resp.limit == limit
+                    if resp.status == 0:
+                        granted[t][k] += 1
+                        assert 0 <= resp.remaining < limit
+                    else:
+                        assert resp.remaining == 0
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+
+    try:
+        d0 = store.device_dispatches
+        pool = [threading.Thread(target=caller, args=(t,), daemon=True)
+                for t in range(threads)]
+        for th in pool:
+            th.start()
+        for th in pool:
+            th.join(max(deadline - time.monotonic(), 0.1))
+        assert not any(th.is_alive() for th in pool), "time limit"
+        assert errors == []
+        calls = threads * calls_each
+        # The oracle's accounting of a token bucket with no refill in
+        # the run (frozen clock): a key grants min(limit, asked) hits.
+        asked = [0] * keys
+        for t in range(threads):
+            rng = random.Random(1000 + t)
+            for _ in range(calls_each):
+                for k in rng.sample(range(keys), 2):
+                    asked[k] += 1
+        total = [sum(g[k] for g in granted) for k in range(keys)]
+        assert total == [min(limit, a) for a in asked]
+        assert any(a > limit for a in asked)  # OVER_LIMIT was exercised
+        rows = svc.get_rate_limits(GetRateLimitsRequest(requests=[
+            RateLimitRequest(name="st", unique_key=f"k{k}", hits=0,
+                             limit=limit, duration=3_600_000)
+            for k in range(keys)
+        ])).responses
+        assert [r.remaining for r in rows] == [limit - g for g in total]
+        assert svc.auditor.check_now() == []
+        # At least 8 lanes a dispatch: a quarter as many dispatches as
+        # calls.  Measured: 53-55 dispatches of 800 calls on two cores,
+        # 69-70 on six; the old rule's 400-430 (most of them bypasses,
+        # one for every call or two; 195-198 once the tenant ledger's
+        # fold no longer throttles the callers).
+        dispatches = store.device_dispatches - d0
+        assert dispatches <= calls // 4, (dispatches, calls)
+        snap = saturation.express_snapshot()
+        assert snap["declined"]["submissions"]["launching"] > 0
+    finally:
+        svc.close()
+        saturation.reset()
 
 
 # ---------------------------------------------------------------------

@@ -323,6 +323,71 @@ def test_tenant_scalar_fold_matches_vector_twin():
     assert sa["topk"][0]["tenant"] == sb["topk"][0]["tenant"]
 
 
+def _few_cols(shape, names, uks, hits):
+    """The few lanes of one classic call, in each shape the ledger is
+    handed: plain lists, the native JSON parse (spans into the body),
+    a decoded GUBC frame (blobs and offsets)."""
+    if shape == "lists":
+        return _cols(names, hits=hits, uk=uks)
+    if shape == "json":
+        from gubernator_tpu.gateway import parse_body_native
+
+        cols = parse_body_native(json.dumps({"requests": [
+            {"name": n, "uniqueKey": u, "hits": str(int(h)),
+             "limit": "1000000", "duration": "3600000"}
+            for n, u, h in zip(names, uks, hits)
+        ]}).encode())
+        if cols is None:
+            pytest.skip("native JSON parse unavailable")
+        return cols
+    from gubernator_tpu import wire
+
+    n = len(names)
+    cols = wire.decode_ingress_frame(wire.encode_ingress_frame((
+        list(names), list(uks), np.zeros(n, np.int32), np.zeros(n, np.int32),
+        np.asarray(hits, np.int64), np.full(n, 1_000_000, np.int64),
+        np.full(n, 3_600_000, np.int64),
+    )))
+    assert isinstance(cols, wire.FrameIngressColumns)
+    return cols
+
+
+@pytest.mark.parametrize("shape", ["lists", "json", "frame"])
+def test_tenant_few_lane_fold_is_the_batch_fold(shape):
+    """A call of at most `topk` lanes folds lane by lane
+    (`_fold_few`); the same calls through the vectorized fold leave
+    the same ledger, cell for cell and row for row, and hand the
+    outcome folds the same context."""
+    rng = np.random.RandomState(5)
+    a = profiling.TenantLedger(topk=4, width=64, depth=4)  # cells collide
+    b = profiling.TenantLedger(topk=4, width=64, depth=4)
+    for step in range(300):
+        n = int(rng.randint(1, 5))
+        names = [f"tenant-{rng.zipf(1.4) % 9}" for _ in range(n)]
+        uks = [f"k{rng.randint(1000)}" for _ in range(n)]
+        hits = rng.randint(0, 5, n)
+        cols = _few_cols(shape, names, uks, hits)
+        ca, cb = a.fold_admit(cols), b._fold_batch(cols)
+        assert ca.m == cb.m
+        assert np.array_equal(ca.inv, cb.inv)
+        assert np.array_equal(ca.uh, cb.uh) and ca.uh.dtype == cb.uh.dtype
+        assert np.array_equal(ca.first, cb.first)
+        over = rng.randint(0, 2, n)
+        for led, ctx in ((a, ca), (b, cb)):
+            led._route_stat_locked(
+                "over_limit", ctx,
+                np.bincount(ctx.inv, weights=over, minlength=ctx.m)
+                .astype(np.int64),
+            )
+            led.fold_shed(ctx, np.flatnonzero(over == 0)[:1])
+    assert np.array_equal(a._tab, b._tab) and a._tab.any()
+    assert a.batches == b.batches == 300
+    sa, sb = a.snapshot(), b.snapshot()
+    assert sa == sb
+    _assert_conserves(sa)
+    assert len(sa["topk"]) == 4 and sa["other"]["lanes"] > 0
+
+
 # ---------------------------------------------------------------------
 # Service pairing: every audit ingress note has a tenant fold beside it
 # ---------------------------------------------------------------------
