@@ -336,8 +336,9 @@ class Metrics:
         )
         self.occupancy_evictions = Counter(
             "gubernator_occupancy_evictions",
-            "LRU evictions per shard (capacity pressure; an eviction "
-            "under load is reference-grade state loss).",
+            "Buckets evicted per shard (capacity pressure; an eviction "
+            "under load is reference-grade state loss; with a back "
+            "tier a demotion keeps the bucket and is not one).",
             ["shard"],
             registry=self.registry,
         )

@@ -193,9 +193,9 @@ def plan_grouped_python(table, prepared: Sequence[_Prepared], now_ms: int):
                 and q.greg_expire == f.greg_expire
                 and q.greg_duration == f.greg_duration
             )
-        ev_before = table.evictions
+        ev_before = table.front_evictions
         slot, exists = table.lookup_or_assign(key, now_ms)
-        evicted = table.evictions != ev_before
+        evicted = table.front_evictions != ev_before
         for j in lanes:
             prepared[j].slot = slot
             prepared[j].exists = exists
@@ -733,10 +733,15 @@ class ColumnarPipeline:
                 # chain length a key pays).
                 row["index"] = t.index_stats
             if back_cap:
-                # tier_stats: (total, back_keys, demotions, promotions,
-                # back_evictions).
-                row["back_used"] = int(t.tier_stats[1])
+                # `used`, `capacity` and the ratio are the FRONT's (the
+                # table the kernel addresses); `evictions` counts
+                # buckets lost from either tier, a demotion is not one.
+                _, back_used, demotions, promotions, back_ev = t.tier_stats
+                row["back_used"] = back_used
                 row["back_capacity"] = back_cap
+                row["demotions"] = demotions
+                row["promotions"] = promotions
+                row["back_evictions"] = back_ev
             out.append(row)
         return out
 

@@ -48,6 +48,12 @@ class SlotTable:
         # mapping is unchanged between them (the GLOBAL sync fast path).
         self.generation = 0
 
+    @property
+    def front_evictions(self) -> int:
+        """The planners' signal that a lookup stole a slot (C++ twin:
+        Table::front_evictions).  One tier here: every eviction is one."""
+        return self.evictions
+
     def __len__(self) -> int:
         return len(self._key_to_slot)
 

@@ -137,6 +137,8 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.gt_table_index_stats.argtypes = [c.c_void_p, c.POINTER(c.c_int64)]
     lib.gt_table_evictions.restype = c.c_int64
     lib.gt_table_evictions.argtypes = [c.c_void_p]
+    lib.gt_table_front_evictions.restype = c.c_int64
+    lib.gt_table_front_evictions.argtypes = [c.c_void_p]
     lib.gt_table_generation.restype = c.c_uint64
     lib.gt_table_generation.argtypes = [c.c_void_p]
     lib.gt_table_get_slot.restype = c.c_int32
@@ -203,7 +205,10 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.gt_table_move_counts.argtypes = [
         c.c_void_p, c.POINTER(c.c_int64), c.POINTER(c.c_int64),
     ]
-    lib.gt_table_take_moves.argtypes = [c.c_void_p] + [c.c_void_p] * 5
+    lib.gt_table_take_moves.restype = c.c_int32
+    lib.gt_table_take_moves.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_int64, c.POINTER(c.c_int64)
+    ]
     lib.gt_table_back_size.argtypes = [
         c.c_void_p, c.POINTER(c.c_int64), c.POINTER(c.c_int64),
     ]
@@ -610,9 +615,16 @@ class NativeSlotTable:
 
     @property
     def evictions(self) -> int:
-        # Hot: plan_grouped_python reads this around every lookup, so
-        # it takes the single-counter FFI call, not the stats marshal.
+        """Buckets that left the table for want of room; with a back
+        tier a demotion keeps the bucket and is not one."""
         return int(self._lib.gt_table_evictions(self._ptr))
+
+    @property
+    def front_evictions(self) -> int:
+        """Front slots taken from their keys, demoted or dropped alike.
+        Hot: plan_grouped_python reads this around every lookup, so it
+        takes the single-counter FFI call, not the stats marshal."""
+        return int(self._lib.gt_table_front_evictions(self._ptr))
 
     # ------------------------------------------------------------------
     def get_slot(self, key: str) -> Optional[int]:
@@ -665,8 +677,9 @@ class NativeSlotTable:
     # -- two-tier back tier (front/back split, Table two-tier mode) ----
     def enable_back(self, back_capacity: int) -> None:
         """Turn on the back tier: front LRU evictions demote rows to a
-        FIFO back table instead of dropping them; lookups promote them
-        back.  Device moves queue in the table until take_moves."""
+        back table instead of dropping them (a freed back slot first,
+        then the one under the ring cursor); lookups promote them back.  Device moves
+        queue in the table until take_moves_into."""
         self._lib.gt_table_enable_back(self._ptr, back_capacity)
 
     @property
@@ -683,23 +696,21 @@ class NativeSlotTable:
         )
         return int(np_.value), int(nd.value)
 
-    def take_moves(self):
-        """Drain the queued device moves: (promo_kind, promo_src,
-        promo_dst, demo_src, demo_dst) i32 arrays.  The caller MUST
-        apply them (ops/buckets.apply_moves) before any other device
-        program touches the front rows."""
-        n_promo, n_demo = self.move_counts()
-        pk = np.empty(max(n_promo, 1), dtype=np.int32)
-        ps = np.empty(max(n_promo, 1), dtype=np.int32)
-        pd = np.empty(max(n_promo, 1), dtype=np.int32)
-        ds = np.empty(max(n_demo, 1), dtype=np.int32)
-        dd = np.empty(max(n_demo, 1), dtype=np.int32)
-        self._lib.gt_table_take_moves(
-            self._ptr, pk.ctypes.data, ps.ctypes.data, pd.ctypes.data,
-            ds.ctypes.data, dd.ctypes.data,
-        )
-        return (pk[:n_promo], ps[:n_promo], pd[:n_promo],
-                ds[:n_demo], dd[:n_demo])
+    def take_moves_into(self, block: np.ndarray) -> "tuple[int, int] | None":
+        """Drain the queued device moves into `block`, a C-contiguous
+        i32[5, P] the caller has filled with its padding (rows: promo
+        kind, promo src, promo dst, demo src, demo dst; src = -1 is a
+        no-op), and close the drain window.  Returns (promotions,
+        demotions) written, or None with nothing drained when either
+        count is over P.  The caller MUST apply the block
+        (ops/buckets.apply_moves) before any other device program
+        touches the front rows."""
+        counts = (ctypes.c_int64 * 2)()
+        if self._lib.gt_table_take_moves(
+            self._ptr, block.ctypes.data, block.shape[1], counts
+        ):
+            return None
+        return int(counts[0]), int(counts[1])
 
     def back_entries(self):
         """(keys, back_slots i32, expire i64) of every back-tier row."""

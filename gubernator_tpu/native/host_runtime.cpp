@@ -242,7 +242,11 @@ struct Table {
   int64_t size = 0;           // mapped front slots
   int32_t lru_head = -1, lru_tail = -1;  // head = least recent
   std::vector<int32_t> free_slots;  // stack, top = back
-  int64_t hits = 0, misses = 0, evictions = 0;
+  // `evictions` counts buckets that LEFT the table for want of room
+  // (both tiers: a demotion keeps the bucket and is not one);
+  // `front_evictions` counts every front slot taken from its key, kept
+  // or not: the planners' signal that a lookup stole a slot.
+  int64_t hits = 0, misses = 0, evictions = 0, front_evictions = 0;
   // Bumped on every key->front-slot MAPPING change (assign, remap,
   // evict, remove).  NOT bumped by in-place expiry reuse (same key,
   // same slot) or value/expire writes.  Lets the GLOBAL sync skip
@@ -259,9 +263,16 @@ struct Table {
   // lookup PROMOTES it back (cheap device gather).  The host tracks
   // key locations and queues the device moves; dispatchers drain them
   // (gt_table_take_moves -> ops/buckets.apply_moves) before any
-  // program that reads front rows.  The back tier evicts FIFO (ring
-  // cursor) — only then is bucket state truly lost, matching the
-  // reference's plain LRU loss semantics at total capacity.
+  // program that reads front rows.  A back slot that a promotion, an
+  // expiry or a removal freed is taken before the ring's next; only
+  // with no free slot does the back tier evict, the slot under the
+  // ring cursor: by ring position, NOT by age (a refilled slot keeps
+  // its place in the ring, so the victim may be the newest demotion,
+  // and a back row that has expired is freed only when it is looked
+  // up, so a live bucket can go while dead rows hold slots) — then
+  // alone is bucket state truly lost, so a population that fits front
+  // + back loses nothing, like the reference's LRU at a capacity above
+  // it.
   int64_t back_capacity = 0;
   KeyIndex back_index;                // key -> back slot (same index type)
   std::vector<std::string> back_key;  // back slot -> key
@@ -269,6 +280,7 @@ struct Table {
   std::vector<uint8_t> back_mapped;
   std::vector<int64_t> back_expire;
   int64_t back_clock = 0;  // FIFO allocation cursor
+  std::vector<int32_t> back_free;  // freed back slots (stack); never holds a mapped one
   int64_t back_size = 0, back_evictions = 0, demotions = 0, promotions = 0;
   // Pending device moves.  promo kind: 0 = gather from back slot, 1 =
   // gather from FRONT slot (a key demoted and re-promoted inside one
@@ -366,8 +378,8 @@ struct Table {
     });
   }
 
-  void unmap_back(int32_t b) {
-    if (!back_mapped[b]) return;
+  // Take back slot b's key off it; the slot is the caller's to reuse.
+  void unlink_back(int32_t b) {
     back_index.erase(back_index.mix(back_hash[b]), b, [this](int32_t o) {
       return back_index.mix(back_hash[o]);
     });
@@ -375,6 +387,12 @@ struct Table {
     back_mapped[b] = 0;
     back_expire[b] = 0;
     --back_size;
+  }
+
+  void unmap_back(int32_t b) {
+    if (!back_mapped[b]) return;
+    unlink_back(b);
+    back_free.push_back(b);
   }
 
   // Neutralize a queued demo targeting back slot b (src=-1 device
@@ -390,26 +408,39 @@ struct Table {
 
   // A back slot mid-promotion: assign() resolves the promo source
   // BEFORE allocating the front slot, and that allocation's eviction
-  // can demote another key — alloc_back must not wrap the FIFO cursor
-  // onto the in-flight source, or the promoted key would adopt the
-  // victim's row (found by round-4 review, repro'd with
-  // front=1/back=1).
+  // can demote another key.  The source stays mapped (and so off the
+  // free list) until the promotion is queued, and the ring skips it:
+  // handing it over by eviction gave the promoted key the victim's
+  // row (found by round-4 review, repro'd with front=1/back=1).
   int32_t promo_in_flight = -1;
 
-  // FIFO ring allocation; wrapping onto a live entry drops it (the
-  // two-tier design's only true state loss).  Returns -1 when no slot
-  // is usable (back_capacity==1 and that slot is mid-promotion): the
-  // caller drops the row instead of demoting.
+  // A back slot for a demoted key, in this order: the ring's next
+  // while the ring is on its first lap (a slot never used); one that a
+  // promotion, an expiry or a removal freed; with a promotion in flight
+  // and the back tier full, that promotion's own source (a swap: the
+  // move program reads the promoted row and writes the demoted one in
+  // one window, and assign() has taken what it needs of the source's
+  // record by then); last, the ring's next though it is live, which
+  // drops that bucket — the two-tier design's only true state loss,
+  // and only with front and back both full.  Returns -1 when no slot
+  // is usable (back_capacity==1 and that slot is mid-promotion: a
+  // one-slot back tier keeps its documented behaviour, the caller
+  // drops the row instead of demoting).
   int32_t alloc_back(const char* key, size_t len, uint64_t h) {
-    int32_t b = (int32_t)(back_clock % back_capacity);
-    ++back_clock;
-    if (b == promo_in_flight) {
+    int32_t b;
+    if (back_clock < back_capacity) {
+      b = (int32_t)back_clock++;
+    } else if (!back_free.empty()) {
+      b = back_free.back();
+      back_free.pop_back();
+    } else if (promo_in_flight >= 0) {
       if (back_capacity == 1) return -1;
-      b = (int32_t)(back_clock % back_capacity);
-      ++back_clock;
-    }
-    if (back_mapped[b]) {
-      unmap_back(b);
+      b = promo_in_flight;  // the swap; the ring stays where it is
+      unlink_back(b);
+      promo_in_flight = -1;
+    } else {
+      b = (int32_t)(back_clock++ % back_capacity);
+      unlink_back(b);  // live: with no slot free every slot is
       ++back_evictions;
       ++evictions;
     }
@@ -441,6 +472,7 @@ struct Table {
     // batch write (pending_write) — the row is mid-air, drop.  Both
     // degrade to the documented reference-grade loss, never to serving
     // another key's counters.
+    bool kept = false;
     if (r.pending_promo >= 0) {
       mv_promo_src[(size_t)r.pending_promo] = -1;  // device no-op
       r.pending_promo = -1;
@@ -454,13 +486,15 @@ struct Table {
         mv_demo_src.push_back(s);
         mv_demo_dst.push_back(b);
         ++demotions;
+        kept = true;
       } else {
         ++back_evictions;  // degenerate: nowhere to park the row
       }
     }
     r.clear_key();
     r.expire_ms = 0;
-    ++evictions;
+    if (!kept) ++evictions;  // a demoted bucket is still resident
+    ++front_evictions;
     ++map_generation;
   }
 
@@ -518,12 +552,29 @@ struct Table {
                                   int64_t now_ms) {
     // Two-tier: a live row demoted to the back tier promotes (a
     // logical cache hit — the state survives the round trip).
-    int32_t promo_b = -1;
+    // What the promotion needs of its source's record is taken here,
+    // before the front slot is allocated: that allocation's eviction
+    // may hand the source slot itself to the demoted key (alloc_back's
+    // swap).  A demo still pending for the source (same drain window)
+    // means the row never left the front table — the device copies
+    // front->front (kind 1) instead of reading the not-yet-written
+    // back slot, and the parked demo copy is cancelled (its
+    // destination is free for same-window reuse).
+    int32_t promo_b = -1, promo_kind = 0, promo_src = -1;
+    int64_t promo_expire = 0;
     if (back_capacity > 0) {
       int32_t b = find_back(key, len, h);
       if (b >= 0) {
         if (back_expire[b] >= now_ms) {
-          promo_b = b;
+          promo_b = promo_src = b;
+          promo_expire = back_expire[b];
+          auto pd = pending_demo_by_back.find(b);
+          if (pd != pending_demo_by_back.end()) {
+            promo_kind = 1;
+            promo_src = mv_demo_src[(size_t)pd->second];
+            mv_demo_src[(size_t)pd->second] = -1;
+            pending_demo_by_back.erase(pd);
+          }
         } else {
           cancel_pending_demo(b);
           unmap_back(b);  // expired in back: plain miss-create
@@ -531,7 +582,7 @@ struct Table {
       }
     }
     if (promo_b >= 0) ++hits; else ++misses;
-    promo_in_flight = promo_b;  // shield the source from FIFO reuse
+    promo_in_flight = promo_b;  // shield the source from reuse by eviction
     int32_t s;
     if (!free_slots.empty()) {
       s = free_slots.back();
@@ -571,25 +622,12 @@ struct Table {
     }
     map_slot(s, key, len, h);
     if (promo_b >= 0) {
-      recs[s].expire_ms = back_expire[promo_b];
-      // Queue the device move.  A demo still pending for this back
-      // slot (same drain window) means the row never left the front
-      // table — copy front->front (kind 1) instead of reading the
-      // not-yet-written back slot, and cancel the parked demo copy
-      // (its destination is now free for same-window reuse).
-      auto pd = pending_demo_by_back.find(promo_b);
-      if (pd != pending_demo_by_back.end()) {
-        mv_promo_kind.push_back(1);
-        mv_promo_src.push_back(mv_demo_src[(size_t)pd->second]);
-        mv_demo_src[(size_t)pd->second] = -1;
-        pending_demo_by_back.erase(pd);
-      } else {
-        mv_promo_kind.push_back(0);
-        mv_promo_src.push_back(promo_b);
-      }
+      recs[s].expire_ms = promo_expire;
+      mv_promo_kind.push_back(promo_kind);
+      mv_promo_src.push_back(promo_src);
       mv_promo_dst.push_back(s);
       recs[s].pending_promo = (int32_t)mv_promo_dst.size() - 1;
-      unmap_back(promo_b);
+      if (promo_in_flight >= 0) unmap_back(promo_in_flight);  // not swapped away: free
       promo_in_flight = -1;
       ++promotions;
       return {s, true};
@@ -708,12 +746,18 @@ void gt_table_index_stats(void* tv, int64_t* out) {
   out[2] = t->index.refused; out[3] = (int64_t)t->index.ent.size();
 }
 
-// Single-counter read: plan_grouped_python polls this around every
-// lookup to detect evictions, so it must not marshal the whole stats
-// array per call.
+// Buckets that left the table for want of room (Table::evictions).
 int64_t gt_table_evictions(void* tv) {
   GT_LOCK((Table*)tv);
   return ((Table*)tv)->evictions;
+}
+
+// Front slots taken from their keys, demoted or dropped alike
+// (Table::front_evictions): plan_grouped_python polls it around every
+// lookup to learn that the lookup stole a slot.
+int64_t gt_table_front_evictions(void* tv) {
+  GT_LOCK((Table*)tv);
+  return ((Table*)tv)->front_evictions;
 }
 
 // Mapping-change generation (see Table::map_generation): equal reads
@@ -782,26 +826,31 @@ void gt_table_move_counts(void* tv, int64_t* n_promo, int64_t* n_demo) {
   *n_demo = (int64_t)t->mv_demo_src.size();
 }
 
-// Drain the queued device moves into caller arrays (sized from
-// gt_table_move_counts) and close the drain window: after this call
-// the rows are considered ON DEVICE in their new homes, so the
-// dispatcher MUST run the move program (ops/buckets.apply_moves)
-// with exactly these records before any other device program.
-void gt_table_take_moves(void* tv, int32_t* promo_kind, int32_t* promo_src,
-                         int32_t* promo_dst, int32_t* demo_src,
-                         int32_t* demo_dst) {
+// Drain the queued device moves into ONE caller block of 5 rows of
+// `stride` int32 each (promo kind, promo src, promo dst, demo src, demo
+// dst; the caller has filled it with its padding: src = -1) and close
+// the drain window: after this call the rows are considered ON DEVICE
+// in their new homes, so the dispatcher MUST run the move program
+// (ops/buckets.apply_moves) with exactly these records before any
+// other device program.  counts = (promotions, demotions) queued.
+// Returns 0 when done; 1, with nothing drained, when either count is
+// over `stride` (a planner queued more since the caller sized the
+// block: it sizes again).
+int32_t gt_table_take_moves(void* tv, int32_t* block, int64_t stride,
+                            int64_t* counts) {
   Table* t = (Table*)tv;
   GT_LOCK(t);
-  std::memcpy(promo_kind, t->mv_promo_kind.data(),
-              t->mv_promo_kind.size() * sizeof(int32_t));
-  std::memcpy(promo_src, t->mv_promo_src.data(),
-              t->mv_promo_src.size() * sizeof(int32_t));
-  std::memcpy(promo_dst, t->mv_promo_dst.data(),
-              t->mv_promo_dst.size() * sizeof(int32_t));
-  std::memcpy(demo_src, t->mv_demo_src.data(),
-              t->mv_demo_src.size() * sizeof(int32_t));
-  std::memcpy(demo_dst, t->mv_demo_dst.data(),
-              t->mv_demo_dst.size() * sizeof(int32_t));
+  const size_t n_promo = t->mv_promo_src.size();
+  const size_t n_demo = t->mv_demo_src.size();
+  counts[0] = (int64_t)n_promo;
+  counts[1] = (int64_t)n_demo;
+  if ((int64_t)n_promo > stride || (int64_t)n_demo > stride) return 1;
+  const std::vector<int32_t>* rows[5] = {
+      &t->mv_promo_kind, &t->mv_promo_src, &t->mv_promo_dst,
+      &t->mv_demo_src, &t->mv_demo_dst};
+  for (int r = 0; r < 5; ++r)
+    std::memcpy(block + r * stride, rows[r]->data(),
+                rows[r]->size() * sizeof(int32_t));
   for (int32_t s : t->mv_promo_dst) t->recs[s].pending_promo = -1;
   t->mv_promo_kind.clear();
   t->mv_promo_src.clear();
@@ -809,6 +858,7 @@ void gt_table_take_moves(void* tv, int32_t* promo_kind, int32_t* promo_src,
   t->mv_demo_src.clear();
   t->mv_demo_dst.clear();
   t->pending_demo_by_back.clear();
+  return 0;
 }
 
 // Snapshot protocol for the back tier (Loader.Save needs every live
@@ -1249,7 +1299,7 @@ int64_t gt_batch_plan_grouped(void* bv, const int32_t* algo,
     const char* key = b->key_ptr(first);
     const size_t len = b->key_len(first);
     const uint64_t h = b->hashes[first];
-    int64_t ev_before = t->evictions;
+    int64_t ev_before = t->front_evictions;
     int32_t c = sc.cand[(size_t)g];
     if (c >= 0 && !t->recs[c].key_is(key, len))
       c = t->index.walk(sc.mh[(size_t)g],
@@ -1264,7 +1314,7 @@ int64_t gt_batch_plan_grouped(void* bv, const int32_t* algo,
     // whose per-round slot-collision deferral orders it correctly.
     // (Without an eviction s cannot already carry a round-0 group: a
     // hit's slot maps this key and no other, and a free slot maps none.)
-    bool evicted = t->evictions != ev_before;
+    bool evicted = t->front_evictions != ev_before;
     if (uniform && !evicted) {
       sc.r0.push_back(first);
       ++t->recs[s].pending_write;

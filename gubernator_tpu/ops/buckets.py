@@ -1160,9 +1160,11 @@ def apply_moves(
     from_front = (promo_kind == 1)[:, None]
     psrc_b = jnp.clip(promo_src, 0, Cb - 1)
     psrc_f = jnp.clip(promo_src, 0, Cf - 1)
-    # kind 0 reads the PRE-demo back rows (input `back`): a promo source
-    # overlapping a same-window demo destination is impossible by the
-    # host's rewrite/cancel rules, so input rows are always current.
+    # kind 0 reads the PRE-demo back rows (input `back`, never
+    # `new_back`): the host hands a promotion's freed source slot to a
+    # demotion of the same window (a swap), so a promo source may be a
+    # same-window demo destination, and the row read is the one that
+    # was there before the window.
     ph = jnp.where(from_front, state.hot[psrc_f], back.hot[psrc_b])
     pc = jnp.where(from_front, state.cold[psrc_f], back.cold[psrc_b])
     lane_p = jnp.arange(np_, dtype=_I32)

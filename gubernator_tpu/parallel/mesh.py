@@ -25,6 +25,7 @@ the host tier in both designs.
 
 from __future__ import annotations
 
+import collections
 from dataclasses import dataclass
 import threading
 from functools import partial
@@ -256,11 +257,26 @@ def _clear_jit(gcols, idx):
     return jax.vmap(global_ops.clear_gslots, in_axes=(0, None))(gcols, idx)
 
 
+# A tier-move block: i32[S, 5, P], the five columns of one drain window
+# of every shard in ONE upload.  Rows: promo kind, promo src, promo dst,
+# demo src, demo dst; a record whose src is -1 is a no-op (padding, or a
+# move the host cancelled).
+_MOVE_SRC_ROWS = (1, 3)
+
+
+def _move_block(shards: int, padded: int) -> np.ndarray:
+    block = np.zeros((shards, 5, padded), dtype=np.int32)
+    block[:, _MOVE_SRC_ROWS, :] = -1
+    return block
+
+
 @partial(jax.jit, donate_argnums=(0, 1))
-def _moves_mesh_jit(state, back, pk, ps, pd, ds, dd):
+def _moves_mesh_jit(state, back, moves):
     """Apply one drain window of tier moves on every shard (see
-    buckets.apply_moves; padded [S, Pm] move arrays, src=-1 no-ops)."""
-    return jax.vmap(buckets.apply_moves)(state, back, pk, ps, pd, ds, dd)
+    buckets.apply_moves; `moves` is a tier-move block)."""
+    return jax.vmap(lambda st, bk, mv: buckets.apply_moves(st, bk, *mv))(
+        state, back, moves
+    )
 
 
 @partial(jax.jit, donate_argnums=0)
@@ -486,9 +502,12 @@ class MeshBucketStore(ColumnarPipeline):
         small FRONT table (capacity_per_shard) absorbs every kernel
         scatter — whose cost scales with the table it targets — while
         front LRU evictions DEMOTE rows to a big device-resident back
-        tier (FIFO) instead of dropping them, and later lookups PROMOTE
-        them back.  Total capacity = front + back per shard; state is
-        lost only when the back tier itself wraps.  Requires the native
+        tier instead of dropping them, and later lookups PROMOTE them
+        back.  Total capacity = front + back per shard; a demoted row
+        takes a back slot that a promotion, an expiry or a removal
+        freed before any other, so state is lost only when front and
+        back together are full (then the back slot under the ring cursor
+        goes: by ring position, not by age).  Requires the native
         runtime; incompatible with the Store SPI (whose resolver
         injects rows synchronously mid-round).
 
@@ -556,12 +575,30 @@ class MeshBucketStore(ColumnarPipeline):
         # the wire buffer is recyclable; CPU zero-copies host numpy.
         self._wire_donate = _wire_donate_ok(self.mesh.devices.flat[0])
         self.state = self._stack_and_shard(buckets.init_state(capacity_per_shard))
+        # The back tier starts all zero (buckets.init_back) and is the
+        # big one (64 B a slot): zero pages go up from the host as they
+        # are, where _stack_and_shard would make it on the device, fetch
+        # it, copy it and put it back (20 s against 2 s for 32.5M slots).
         self.back = (
-            self._stack_and_shard(buckets.init_back(back_capacity_per_shard))
+            jax.tree.map(
+                lambda a: jax.device_put(
+                    np.zeros((self.n_shards,) + a.shape, a.dtype), self._sharding
+                ),
+                jax.eval_shape(lambda: buckets.init_back(back_capacity_per_shard)),
+            )
             if back_capacity_per_shard > 0
             else None
         )
         self.gcols = self._stack_and_shard(global_ops.init_global_columns(g_capacity))
+        # Drain windows the plans have closed and no launch has applied
+        # yet, oldest first, a tier-move block each.  A plan closes its
+        # own window into a block of ITS pad bucket (a take
+        # of n lanes queues at most n promotions and n demotions), so
+        # the move program has one shape a pad bucket, the ones warm-up
+        # compiles (`_move_buckets`), and a backlog of several plans
+        # drains in as many launches.
+        self._move_backlog: collections.deque = collections.deque()
+        self._move_buckets: List[int] = []
 
         # Jitted programs are MODULE-level (or cached per mesh) so every
         # store/daemon in a process shares one XLA compilation cache —
@@ -579,42 +616,54 @@ class MeshBucketStore(ColumnarPipeline):
         )
         return jax.tree.map(lambda c: jax.device_put(c, self._sharding), stacked)
 
-    def _drain_moves(self) -> None:
-        """Apply every queued tier move (caller holds the store lock).
+    def _close_move_window(self, padded: int = 0) -> None:
+        """Close every table's drain window into one tier-move block on
+        the backlog (caller holds the plan lock, so nothing queues
+        meanwhile).  `padded` is the pad bucket of the plan that queued
+        the moves; without one (a mutator outside the columnar path)
+        the block takes the smallest bucket warm-up compiled that holds
+        them.  Nothing queued: nothing appended."""
+        counts = [t.move_counts() for t in self.tables]
+        need = max(max(c) for c in counts)
+        if need == 0:
+            return
+        if padded < need:
+            padded = next(
+                (b for b in self._move_buckets if b >= need), pad_size(need)
+            )
+        block = _move_block(self.n_shards, padded)
+        for s, t in enumerate(self.tables):
+            if any(counts[s]):
+                taken = t.take_moves_into(block[s])
+                assert taken == counts[s], (taken, counts[s])
+        self._move_backlog.append(block)
 
-        Planning queues promotions/demotions in the C++ tables; this
-        dispatches ONE small move program for the whole mesh so the
-        rows are in their new homes before any program that reads
-        front rows.  No-op (no dispatch) when nothing is queued — the
-        steady state for front-resident traffic."""
+    def _launch_moves(self) -> None:
+        """Apply the backlog's drain windows, oldest first, one launch
+        of the move program each (caller holds the store lock), so the
+        rows are in their new homes before any program that reads front
+        rows.  No-op (no dispatch, no phase) when nothing is queued —
+        the steady state for front-resident traffic."""
+        if not self._move_backlog:
+            return
+        with phase("dispatch.moves"):
+            while self._move_backlog:
+                block = self._move_backlog.popleft()
+                with telemetry.program("mesh:tier_moves"):
+                    self.state, self.back = _moves_mesh_jit(
+                        self.state, self.back,
+                        jax.device_put(block, self._sharding),
+                    )
+
+    def _drain_moves(self) -> None:
+        """Apply every queued tier move (caller holds the plan lock and
+        the store lock: the mutators outside the columnar path, which
+        queue moves in the C++ tables and need them on the device at
+        once)."""
         if self.back is None:
             return
-        counts = [t.move_counts() for t in self.tables]
-        max_p = max(p for p, _ in counts)
-        max_d = max(d for _, d in counts)
-        if max_p == 0 and max_d == 0:
-            return
-        S = self.n_shards
-        pp, dp = _pad_pow2(max_p), _pad_pow2(max_d)
-        pk = np.zeros((S, pp), dtype=np.int32)
-        ps = np.full((S, pp), -1, dtype=np.int32)
-        pd = np.zeros((S, pp), dtype=np.int32)
-        ds = np.full((S, dp), -1, dtype=np.int32)
-        dd = np.zeros((S, dp), dtype=np.int32)
-        for s, t in enumerate(self.tables):
-            n_p, n_d = counts[s]
-            if n_p == 0 and n_d == 0:
-                continue
-            tpk, tps, tpd, tds, tdd = t.take_moves()
-            pk[s, :n_p] = tpk
-            ps[s, :n_p] = tps
-            pd[s, :n_p] = tpd
-            ds[s, :n_d] = tds
-            dd[s, :n_d] = tdd
-        put = lambda a: jax.device_put(a, self._sharding)  # noqa: E731
-        self.state, self.back = _moves_mesh_jit(
-            self.state, self.back, put(pk), put(ps), put(pd), put(ds), put(dd)
-        )
+        self._close_move_window()
+        self._launch_moves()
 
     # ------------------------------------------------------------------
     @_drained_locked
@@ -753,9 +802,9 @@ class MeshBucketStore(ColumnarPipeline):
         """Stage 1 of the overlapped dispatch (under `_plan_lock`): the
         slot-table work only — gt_mesh_begin + gt_mesh_plan_grouped
         (hash/bucket every key, per-shard grouped round planning,
-        padded [S, P] fill).  Tier moves queued by this plan stay
-        queued; the LAUNCH stage drains them, ordered against the
-        device program.  The commit side stays ONE C++ call
+        padded [S, P] fill).  Tier moves queued by this plan are closed
+        into one block of the plan's pad bucket; the LAUNCH stage
+        applies the backlog, ordered against the device program.  The commit side stays ONE C++ call
         (gt_mesh_finish_*: decode, slot-table commit, original-order
         scatter), safe against the NEXT batch's concurrent planning via
         the per-table native mutex."""
@@ -771,6 +820,8 @@ class MeshBucketStore(ColumnarPipeline):
             n_rounds = mp.plan_grouped(
                 cols, int(Behavior.RESET_REMAINING), padded
             )
+        if self.back is not None:
+            self._close_move_window(padded)
         pos = mp.pos[:n]
         narrow = narrow_ok(cols, now_ms) and force_wire != "wide"
 
@@ -870,12 +921,12 @@ class MeshBucketStore(ColumnarPipeline):
         return self.n_shards, prep.fullest
 
     def _pre_launch(self) -> None:
-        # Tier moves queued by the group's plans (and any stale window)
-        # must land before the batch programs read front rows.  One
-        # drain covers the group: moves queued by a LATER plan are safe
-        # to apply early — the pending-write guard keeps every
-        # in-flight batch's slots out of the mover's reach.
-        self._drain_moves()
+        # Tier moves queued by the group's plans must land before the
+        # batch programs read front rows.  The whole backlog goes:
+        # moves queued by a LATER plan are safe to apply early — the
+        # pending-write guard keeps every in-flight batch's slots out
+        # of the mover's reach.
+        self._launch_moves()
 
     def _fused_launch_fn(self, k: int, wide: bool):
         return _mesh_fused_packed_jit(k, wide, donate_wires=self._wire_donate)
@@ -1812,19 +1863,6 @@ class MeshBucketStore(ColumnarPipeline):
             ),
             now_ms,
         )
-        if self.back is not None:
-            # Compile the tier-move program at its smallest pad bucket
-            # (all-noop records): the first real demotion otherwise pays
-            # the compile inside a client's deadline.
-            S = self.n_shards
-            noop = np.full((S, 8), -1, dtype=np.int32)
-            z = np.zeros((S, 8), dtype=np.int32)
-            put = lambda a: jax.device_put(a, self._sharding)  # noqa: E731
-            with self._lock:
-                self.state, self.back = _moves_mesh_jit(
-                    self.state, self.back, put(z), put(noop), put(z),
-                    put(noop), put(z),
-                )
         if self._native and self.store is None:
             # Compile the columnar ingress kernels too (the gateway/gRPC
             # hot path).  Each pad_size bucket is its own XLA program,
@@ -1886,9 +1924,30 @@ class MeshBucketStore(ColumnarPipeline):
                             np.ones(k, np.int32),
                             np.full(k, now_ms, np.int64),
                         )
+            if self.back is not None:
+                # Compile the tier-move program at every pad bucket the
+                # warm shapes dispatch (all-noop blocks): a plan closes
+                # its moves into a block of its own bucket, so these are
+                # the shapes the load and the window launch, and the
+                # first real demotion pays no compile inside a client's
+                # deadline.
+                self._move_buckets = sorted({
+                    (W - buckets.DICT_WIRE_TABLE_WORDS) // 3 for W, _ in shapes
+                })
+                for padded in self._move_buckets:
+                    with self._lock, telemetry.program("mesh:tier_moves"):
+                        self.state, self.back = _moves_mesh_jit(
+                            self.state, self.back,
+                            jax.device_put(_move_block(S, padded), self._sharding),
+                        )
 
     def size(self) -> int:
-        return sum(len(t) for t in self.tables)
+        """Every resident bucket, whichever tier it lies in (what
+        `gubernator_cache_size` reports, as upstream's gauge counts every
+        cached item)."""
+        if self.back is None:
+            return sum(len(t) for t in self.tables)
+        return sum(t.tier_stats[0] for t in self.tables)
 
     @_drained_locked
     def check_consistency(self) -> None:
@@ -1908,4 +1967,16 @@ class MeshBucketStore(ColumnarPipeline):
             )
             assert all(0 <= x < self.capacity_per_shard for x in slots), (
                 f"shard {s}: slot out of range"
+            )
+            if self.back is None:
+                continue
+            back_keys, back_slots, _ = t.back_entries()
+            assert len(back_keys) == t.tier_stats[1], (
+                f"shard {s}: back size {t.tier_stats[1]} != back keys {len(back_keys)}"
+            )
+            assert len(set(back_slots.tolist())) == len(back_slots), (
+                f"shard {s}: back slot aliasing"
+            )
+            assert not set(back_keys) & set(keys), (
+                f"shard {s}: a key in both tiers"
             )

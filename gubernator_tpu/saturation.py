@@ -110,6 +110,8 @@ WATERFALL = (
     ("dispatch.gate_wait", 0),  # waiting for the ticket's launch turn
     ("dispatch.launch", 0),   # ticket-ordered jit call (stage 3)
     ("dispatch.launch_wait", 1),  # waiting for the store lock
+    ("dispatch.moves", 1),    # two-tier table: the backlog's tier-move launches,
+                              # entered only when a plan queued moves
     ("pump.handoff", 0),      # native take: queued for a done-pool worker
     ("dispatch.fetch", 0),    # device->host readback
     ("dispatch.commit", 0),   # decode + table commit

@@ -47,6 +47,18 @@ def one_device_store(capacity, **kw):
     return _store_over(1, capacity, **kw)
 
 
+def take_moves(table):
+    """Drain a native table's queued tier moves as the store does
+    (`take_moves_into`, one block), split into its five columns: promo
+    kind, promo src, promo dst, demo src, demo dst."""
+    import numpy as np
+
+    n_promo, n_demo = table.move_counts()
+    block = np.empty((5, max(n_promo, n_demo, 1)), dtype=np.int32)
+    assert table.take_moves_into(block) == (n_promo, n_demo)
+    return (*block[:3, :n_promo], *block[3:, :n_demo])
+
+
 @pytest.fixture(params=[1, 4], ids=["one-device", "four-shard"])
 def make_store(request):
     """`make_store(capacity, **kw)`: the daemon's store in the two

@@ -150,3 +150,21 @@ def test_global_sync_compiles_for_four_v5e(four_chips):
         ),
     )
     assert "all-reduce" in compiled.as_text()
+
+
+def test_tier_moves_compile_for_one_v5e_and_copy_no_tier(one_chip):
+    """The two-tier table's move program at the size PR 31 ran on the chip
+    (32M keys; the benchmark's `ycsb-f-32m` is the one tier, `PERF.md` §6): a
+    front of 1,048,576 slots, a back tier of 32,505,856 (2.08 GB) and one
+    4096-record block.  Both tiers are donated and must be updated in place:
+    a program that copied the back tier would move 2 GB a launch."""
+    back = _sharded(one_chip, jax.eval_shape(lambda: buckets.init_back(32_505_856)))
+    moves = _sharded(one_chip, jax.ShapeDtypeStruct((5, LANES), jnp.int32))
+    compiled = _compile(
+        "tier moves, 1M front + 32.5M back x 4096 records, one chip",
+        mesh_mod._moves_mesh_jit.lower(_state(one_chip), back, moves),
+    )
+    mem = compiled.memory_analysis()
+    tiers = 64 * (SLOTS + 32_505_856)
+    assert mem.alias_size_in_bytes == tiers  # every row of both tiers in place
+    assert mem.temp_size_in_bytes < tiers // 64

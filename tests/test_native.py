@@ -13,7 +13,7 @@ from gubernator_tpu.models.slot_table import SlotTable
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, SECOND
 from gubernator_tpu.utils import hashing
 
-from .conftest import one_device_store
+from .conftest import one_device_store, take_moves
 
 pytestmark = pytest.mark.skipif(
     not native.available(), reason=f"native runtime unavailable: {native.build_error()}"
@@ -425,7 +425,7 @@ def _plan_then_commit(rng, nat, py, batches, now):
         want = [first[k] for k in keys]
         assert got == want, next((i, keys[i], g, w) for i, (g, w) in enumerate(zip(got, want)) if g != w)
         if nat.move_counts() != (0, 0):
-            nat.take_moves()  # the launch drains a plan's tier moves before the next plan's evictions
+            take_moves(nat)  # the launch drains a plan's tier moves before the next plan's evictions
         # A group whose assign evicted goes to the rounds, after round 0.
         assert int(rid.max()) == n_rounds - 1 and (n_rounds == 1 or py.evictions > ev)
         last = {k: i for i, k in enumerate(keys)}
@@ -541,7 +541,7 @@ def test_native_table_equals_its_twin_after_every_step(case):
                 batches.append(part)
             _plan_then_commit(rng, nat, py, batches, now)
         if two_tier:
-            nat.take_moves()  # as every dispatch does: a queued promotion shields its slot from eviction
+            take_moves(nat)  # as every dispatch does: a queued promotion shields its slot from eviction
         assert len(nat) == len(py), step
         assert (nat.hits, nat.misses) == (py.hits, py.misses), step
         if not two_tier:  # a back row dropped for room counts as an eviction too

@@ -354,6 +354,38 @@ def test_the_native_plan_is_a_phase_inside_every_prepare(store):
 
 
 @pytest.mark.skipif(not native.available(), reason="native runtime unavailable")
+def test_the_tier_moves_are_a_phase_inside_the_launch():
+    """`dispatch.moves` (the two-tier table's move launches) is a depth-1
+    phase whose parent is `dispatch.launch`; a store with a back tier
+    observes it inside the launch's time, and only for a launch that had
+    moves to apply; a store without one never enters it."""
+    names = [p for p, _ in saturation.WATERFALL]
+    at = names.index("dispatch.moves")
+    assert saturation.WATERFALL[at] == ("dispatch.moves", 1)
+    assert next(p for p, d in reversed(saturation.WATERFALL[:at]) if d == 0) == "dispatch.launch"
+
+    def frame(st, keys, t):
+        n = len(keys)
+        st.apply_columns(
+            keys, np.zeros(n, np.int32), np.zeros(n, np.int32), np.ones(n, np.int64),
+            np.full(n, 100, np.int64), np.full(n, 60_000, np.int64), 1_700_000_000_000 + t)
+
+    plain = one_device_store(16)
+    for t in range(3):
+        frame(plain, [f"mv{t}_{i}" for i in range(8)], t)
+    assert _stats("dispatch.moves") is None
+    tiered = one_device_store(16, back_capacity_per_shard=64)
+    frame(tiered, [f"mv0_{i}" for i in range(8)], 10)  # fits the front: nothing moves
+    assert _stats("dispatch.moves") is None
+    for t in range(1, 4):  # each frame's 8 creates demote 8 rows (the first: none, 16 slots)
+        frame(tiered, [f"mv{t}_{i}" for i in range(8)], 10 + t)
+    moves, launch = _stats("dispatch.moves"), _stats("dispatch.launch")
+    assert moves["count"] == 2 and launch["count"] == 7
+    assert 0 < moves["sum_ms"] < launch["sum_ms"]
+    assert tiered.tables[0].tier_stats[2] == 16  # demotions
+
+
+@pytest.mark.skipif(not native.available(), reason="native runtime unavailable")
 def test_sampling_daemon_serves_frames_on_the_native_lane(sampled):
     """GUBER_TRACE_SAMPLE=1 no longer switches the native lane off: the
     frame is served by it (the ingress counters show it) and

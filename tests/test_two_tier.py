@@ -19,6 +19,7 @@ from gubernator_tpu.parallel.mesh import MeshBucketStore
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, Status
 
 from . import oracle
+from .conftest import take_moves
 
 pytestmark = pytest.mark.skipif(
     not native.available(), reason="native runtime required"
@@ -51,7 +52,7 @@ def test_native_table_demote_promote_records():
     assert e4 is True  # state survived: logical hit
     np_, nd = t.move_counts()
     assert (np_, nd) == (1, 2)
-    pk, ps, pdst, ds, dd = t.take_moves()
+    pk, ps, pdst, ds, dd = take_moves(t)
     # the promo source is front slot s1's parked copy or a back slot;
     # the same-window re-promotion must be front-sourced (kind 1)
     assert pk[0] == 1 and pdst[0] == s4
@@ -230,7 +231,7 @@ def test_fifo_wrap_during_promotion_preserves_both_keys():
     t.set_expire(sa, T0 + 60_000)
     sb, _ = t.lookup_or_assign("b", T0)  # evicts+demotes a
     t.set_expire(sb, T0 + 50_000)
-    t.take_moves()
+    take_moves(t)
     sa2, ea = t.lookup_or_assign("a", T0)  # promote a; evict+demote b
     assert ea is True
     assert t.get_expire_bulk([sa2])[0] == T0 + 60_000  # a's OWN expiry
@@ -248,7 +249,7 @@ def test_back_capacity_one_degenerates_to_loss_not_corruption():
     t.set_expire(sa, T0 + 60_000)
     sb, _ = t.lookup_or_assign("b", T0)
     t.set_expire(sb, T0 + 50_000)
-    t.take_moves()
+    take_moves(t)
     sa2, ea = t.lookup_or_assign("a", T0)  # promote a; b has nowhere to go
     assert ea is True
     assert t.get_expire_bulk([sa2])[0] == T0 + 60_000
@@ -270,7 +271,7 @@ def test_starved_fallback_never_serves_another_keys_row():
     for k in ("kc", "kd"):  # demote ka, kb
         s, _ = t.lookup_or_assign(k, T0)
         t.set_expire(s, T0 + 60_000)
-    t.take_moves()
+    take_moves(t)
     # One window: promote ka and kb (both slots pending-promo), then a
     # miss forces the starved fallback.
     sa, ea = t.lookup_or_assign("ka", T0)
@@ -278,7 +279,7 @@ def test_starved_fallback_never_serves_another_keys_row():
     assert ea and eb
     se, ee = t.lookup_or_assign("ke", T0)
     assert ee is False
-    pk, ps, pdst, ds, dd = t.take_moves()
+    pk, ps, pdst, ds, dd = take_moves(t)
     # the evicted promo was cancelled (src -1), and no demo record may
     # target a slot whose row never arrived
     live_promos = [(int(k), int(s), int(d))
@@ -290,3 +291,147 @@ def test_starved_fallback_never_serves_another_keys_row():
         "ka" if se == sa else "kb", T0
     )
     assert e_again is False
+
+
+# ---------------------------------------------------------------------
+# A population that fits front + back loses nothing (PR 31)
+# ---------------------------------------------------------------------
+def _ask(t, key, now=T0):
+    """One lookup of a live bucket: (front slot, whether its state survived)."""
+    s, e = t.lookup_or_assign(key, now)
+    t.set_expire(s, now + 60_000)
+    return s, e
+
+
+@pytest.mark.parametrize("front,back,drain_every", [(1, 2, 1), (4, 8, 3), (8, 16, 7)])
+def test_keys_cycling_through_exactly_front_plus_back_slots_lose_nothing(front, back, drain_every):
+    """Three keys in front 1 + back 2 (and 12 in 4 + 8, 24 in 8 + 16), a
+    thousand laps, every lookup a front miss: each finds its state, whether
+    the launch drains the moves after every lookup or a window holds several
+    (fewer than the front has slots: a front slot whose promotion is still
+    queued cannot be demoted).  With the ring cursor alone the back tier
+    dropped a key a lap: the cursor landed on a live row while the slot the
+    promotion had just left stood free."""
+    t = native.NativeSlotTable(front)
+    t.enable_back(back)
+    keys = [f"k{i}" for i in range(front + back)]
+    for k in keys:
+        assert _ask(t, k)[1] is False
+    take_moves(t)
+    asked = 0
+    for lap in range(1000):
+        for k in keys:
+            assert _ask(t, k)[1] is True, (lap, k)
+            asked += 1
+            if asked % drain_every == 0:
+                take_moves(t)
+    total, back_keys, demotions, promotions, back_ev = t.tier_stats
+    assert (total, back_keys, back_ev) == (front + back, back, 0)
+    assert promotions == asked and demotions == back + asked
+    assert t.evictions == 0 and t.front_evictions == demotions and len(t) == front
+
+
+def test_three_keys_cycling_through_the_store_equal_the_oracle():
+    """The same cycle through the device: the swap (a demoted row written to
+    the back slot the promoted row is read from, in one move program) keeps
+    both rows."""
+    from .conftest import one_device_store
+
+    two = one_device_store(1, back_capacity_per_shard=2)
+    ref = oracle.OracleCache()
+    now = T0
+    for step in range(120):
+        r = mk("xyz"[step % 3], limit=1000)
+        now += 7
+        got, want = two.apply([r], now)[0], oracle.apply(ref, r, now)
+        assert (got.status, got.remaining, got.reset_time) == (
+            want.status, want.remaining, want.reset_time), step
+    assert two.tables[0].tier_stats[4] == 0 and two.size() == 3  # back_evictions
+    two.check_consistency()
+
+
+def test_a_freed_back_slot_is_taken_before_a_live_one():
+    """Front 1, back 2, both back slots live: a removal frees one, and the
+    next demotion takes it instead of evicting the other."""
+    t = native.NativeSlotTable(1)
+    t.enable_back(2)
+    for k in "abc":
+        _ask(t, k)
+    take_moves(t)
+    assert sorted(t.back_entries()[0]) == ["a", "b"]
+    t.remove("a")
+    _ask(t, "d")  # demotes c: into a's slot
+    assert sorted(t.back_entries()[0]) == ["b", "c"]
+    assert t.tier_stats[4] == 0 and t.evictions == 0
+    for k in "bc":
+        assert _ask(t, k)[1] is True
+        take_moves(t)
+
+
+def test_an_expired_back_row_gives_its_slot_to_the_next_demotion():
+    t = native.NativeSlotTable(1)
+    t.enable_back(2)
+    s, _ = t.lookup_or_assign("a", T0)
+    t.set_expire(s, T0 + 10)
+    for k in "bc":
+        _ask(t, k)
+    take_moves(t)
+    assert sorted(t.back_entries()[0]) == ["a", "b"]
+    # a has expired in the back: asked for again it is a plain create, and c,
+    # demoted to make room for it, takes the slot a's dead row gave up.
+    assert _ask(t, "a", T0 + 1000)[1] is False
+    assert sorted(t.back_entries()[0]) == ["b", "c"]
+    assert t.tier_stats[4] == 0 and t.evictions == 0
+
+
+def test_more_keys_than_slots_still_lose_the_oldest():
+    t = native.NativeSlotTable(1)
+    t.enable_back(2)
+    for k in "abcd":
+        _ask(t, k)
+    assert t.tier_stats[4] == 1 and sorted(t.back_entries()[0]) == ["b", "c"]
+    assert _ask(t, "a")[1] is False
+
+
+def test_evictions_count_lost_buckets_and_sizes_count_both_tiers():
+    """`evictions` (what `gubernator_occupancy_evictions` and /debug/status
+    serve) grows with buckets lost, not with demotions; `store.size()`
+    (`gubernator_cache_size`) is every resident bucket; `len(table)` and the
+    occupancy ratio stay the front's."""
+    from .conftest import one_device_store
+
+    two = one_device_store(4, back_capacity_per_shard=8)
+    now = T0
+    for i in range(12):
+        two.apply([mk(f"r{i}", limit=100)], now)
+    (row,) = two.occupancy_stats()
+    assert two.size() == 12 and len(two.tables[0]) == 4
+    assert (row["used"], row["capacity"], row["back_used"], row["back_capacity"]) == (4, 4, 8, 8)
+    assert (row["demotions"], row["promotions"], row["back_evictions"], row["evictions"]) == (8, 0, 0, 0)
+    assert two.tables[0].front_evictions == 8
+    two.apply([mk("r0", limit=100)], now)  # promoted; its victim takes the slot it left
+    two.apply([mk("one-too-many", limit=100)], now)  # 13 keys in 12 slots: the oldest goes
+    (row,) = two.occupancy_stats()
+    assert (row["promotions"], row["back_evictions"], row["evictions"]) == (1, 1, 1)
+    assert two.size() == 12
+    two.check_consistency()
+    one = one_device_store(4)
+    for i in range(6):
+        one.apply([mk(f"r{i}")], now)
+    (row,) = one.occupancy_stats()
+    assert row["evictions"] == 2 and one.size() == 4 and "demotions" not in row
+
+
+def test_take_moves_into_refuses_a_block_too_small_and_drains_nothing():
+    t = native.NativeSlotTable(1)
+    t.enable_back(8)
+    for k in "abcd":
+        _ask(t, k)
+    assert t.move_counts() == (0, 3)
+    small = np.full((5, 2), -1, np.int32)
+    assert t.take_moves_into(small) is None and t.move_counts() == (0, 3)
+    block = np.zeros((5, 4), np.int32)
+    block[[1, 3]] = -1
+    assert t.take_moves_into(block) == (0, 3)
+    assert block[3].tolist()[3] == -1 and (block[3, :3] >= 0).all()
+    assert sorted(block[4, :3].tolist()) == [0, 1, 2] and t.move_counts() == (0, 0)
