@@ -1146,9 +1146,11 @@ class NativeIngressPump:
     def _submit(self, tb, bt):
         """One batch through the funnel's batch-granularity duties
         (`pump.admit`): the ring's counters, the black-box tap,
-        conservation ledger, tenant fold, hot-key sketch (riding the
-        hashes the native route already computed — zero extra
-        hashing), the attribution of what C++ timed, then ONE columnar
+        conservation ledger (the take summed its hits in C++), tenant
+        fold and hot-key sketch (both native passes over the take's own
+        columns, the sketch riding the hashes the native route already
+        computed — zero extra hashing, no interpreter in the lanes),
+        the attribution of what C++ timed, then ONE columnar
         dispatch."""
         svc = self.service
         with phase("pump.admit", bt, frames=tb.n_frames, lanes=tb.n):
@@ -1162,7 +1164,7 @@ class NativeIngressPump:
                 # entirely in C++ never surface here — documented
                 # capture slack, architecture.md "Incident black box").
                 bb.tap_taken(tb)
-            audit_mod.note("ingress_hits", int(tb.hits.sum()))
+            audit_mod.note("ingress_hits", tb.hits_total)
             tenant_ctx = svc.tenants.fold_admit(tb)
             svc.hotkeys.update(tb.hashes, tb.hash_keys)
             # Measured in C++ (parse by the worker, a frame's age from its

@@ -272,6 +272,16 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.gt_ingress_stop.argtypes = [c.c_void_p]
     lib.gt_ingress_stats.argtypes = [c.c_void_p, c.c_void_p]
     lib.gt_ingress_free.argtypes = [c.c_void_p]
+    lib.gt_name_groups.restype = c.c_int64
+    lib.gt_name_groups.argtypes = (
+        [c.c_void_p, c.c_void_p, c.c_int64] + [c.c_void_p] * 3
+        + [c.c_int64, c.c_void_p]
+    )
+    lib.gt_cms_fold.restype = c.c_int64
+    lib.gt_cms_fold.argtypes = (
+        [c.c_void_p, c.c_int32, c.c_int64] + [c.c_void_p] * 3 + [c.c_int64]
+        + [c.c_void_p, c.c_int64, c.c_int64, c.c_int64, c.c_void_p]
+    )
     return lib
 
 
@@ -546,6 +556,119 @@ def fnv1_batch(keys, variant_1a: bool = False) -> np.ndarray:
         1 if variant_1a else 0, out.ctypes.data,
     )
     return out
+
+
+def _i64(a) -> np.ndarray:
+    return np.ascontiguousarray(a, dtype=np.int64)
+
+
+def name_groups(names, hits, name_lens, uk_lens, lane_const: int):
+    """The tenant fold's aggregation of one batch (gt_name_groups):
+    `names` a list of strings or a PackedKeys, the rest one value a
+    lane.  Returns (uh, first, inv, lanes, hits, bytes): the distinct
+    FNV-1 name hashes ascending, each one's first lane, every lane's
+    position in `uh`, and lanes / hits / ingress bytes by name (a
+    lane's bytes are its two lengths plus `lane_const`), in one pass
+    that holds no interpreter; numpy's unique and three sums when the
+    native build is unavailable."""
+    lib = _get_lib()
+    hits, name_lens, uk_lens = _i64(hits), _i64(name_lens), _i64(uk_lens)
+    if lib is None:
+        uh, first, inv = np.unique(
+            fnv1_batch(names), return_index=True, return_inverse=True
+        )
+        sums = np.zeros((3, len(uh)), dtype=np.int64)
+        np.add.at(sums[0], inv, 1)
+        np.add.at(sums[1], inv, hits)
+        np.add.at(sums[2], inv, name_lens + uk_lens + int(lane_const))
+        return uh, first, inv, sums[0], sums[1], sums[2]
+    buf, off = as_packed(names)
+    off = _i64(off)
+    n = len(off) - 1
+    out = np.empty((6, n), dtype=np.int64)
+    m = lib.gt_name_groups(
+        buf.ctypes.data, off.ctypes.data, n, hits.ctypes.data,
+        name_lens.ctypes.data, uk_lens.ctypes.data, int(lane_const),
+        out.ctypes.data,
+    )
+    return (out[0, :m].view(np.uint64), out[1, :m], out[5].view(np.intp),
+            out[2, :m], out[3, :m], out[4, :m])
+
+
+def cms_fold(tab: np.ndarray, salts: np.ndarray, hashes, weights, tracked,
+             floor: int, topk: int):
+    """Fold one batch into a count-min table (gt_cms_fold): `tab`
+    i64[depth, width] and `salts` u64[depth] are the CALLER's arrays,
+    written in place — the one copy of the counts, under whatever lock
+    the caller guards them with.  `hashes` u64[n]; `weights` one i64 a
+    hash, None for 1 each.  Returns (ud, first, est, t_idx, cand): the
+    distinct hashes in order of first occurrence, each one's first lane
+    and its estimate after the adds; for each hash of `tracked` (u64,
+    or None) its position in `ud` or -1; and the positions of the
+    untracked hashes whose estimate is above `floor` — at most `topk`,
+    the largest, the later position among equals.  The interpreter is
+    released for the pass; the same answer from numpy when the native
+    build is unavailable."""
+    if (tab.dtype != np.int64 or not tab.flags.c_contiguous
+            or salts.dtype != np.uint64 or len(salts) != tab.shape[0]):
+        raise ValueError("cms_fold wants i64[depth, width] and u64[depth]")
+    hashes = np.ascontiguousarray(hashes, dtype=np.uint64)
+    n = len(hashes)
+    if weights is not None:
+        weights = _i64(weights)
+        if len(weights) != n:
+            raise ValueError("cms_fold wants one weight a hash")
+    tracked = np.ascontiguousarray(
+        () if tracked is None else tracked, dtype=np.uint64
+    )
+    nt, topk = len(tracked), max(int(topk), 0)
+    lib = _get_lib()
+    if lib is None:
+        return _cms_fold_numpy(tab, salts, hashes, weights, tracked,
+                               floor, topk)
+    out = np.empty(3 * n + nt + 1 + topk, dtype=np.int64)
+    m = lib.gt_cms_fold(
+        tab.ctypes.data, tab.shape[0], tab.shape[1], salts.ctypes.data,
+        hashes.ctypes.data,
+        None if weights is None else weights.ctypes.data, n,
+        tracked.ctypes.data, nt, int(floor), topk, out.ctypes.data,
+    )
+    t0 = 3 * n
+    c0 = t0 + nt + 1
+    return (out[:m].view(np.uint64), out[n:n + m], out[2 * n:2 * n + m],
+            out[t0:t0 + nt], out[c0:c0 + int(out[c0 - 1])])
+
+
+def _cms_fold_numpy(tab, salts, hashes, weights, tracked, floor, topk):
+    """cms_fold without the native build: the same cells, the same
+    answer, position for position."""
+    uh, first, inv = np.unique(
+        hashes, return_index=True, return_inverse=True
+    )
+    order = np.argsort(first)  # np.unique sorts; the fold keeps arrival
+    ud, first = uh[order], first[order].astype(np.int64)
+    w = np.zeros(len(ud), dtype=np.int64)
+    pos = np.empty(len(ud), dtype=np.intp)
+    pos[order] = np.arange(len(ud))
+    np.add.at(w, pos[inv], 1 if weights is None else weights)
+    width = np.uint64(tab.shape[1])
+    idx = (((ud[None, :] * salts[:, None]) >> np.uint64(17)) % width).astype(
+        np.intp
+    )
+    for r in range(tab.shape[0]):
+        np.add.at(tab[r], idx[r], w)
+    est = tab[np.arange(tab.shape[0])[:, None], idx].min(axis=0)
+    t_idx = np.full(len(tracked), -1, dtype=np.int64)
+    if len(ud) and len(tracked):
+        at = np.searchsorted(uh, tracked).clip(max=len(uh) - 1)
+        hit = uh[at] == tracked
+        t_idx[hit] = pos[at[hit]]
+    untracked = np.ones(len(ud), dtype=bool)
+    untracked[t_idx[t_idx >= 0]] = False
+    cand = np.flatnonzero(untracked & (est > floor))
+    if len(cand) > topk:
+        cand = cand[np.lexsort((cand, est[cand]))][len(cand) - topk:]
+    return ud, first, est, t_idx, cand.astype(np.int64)
 
 
 class NativeSlotTable:
@@ -1097,6 +1220,7 @@ class _GtTakenInfo(ctypes.Structure):
         ("frame_lanes", ctypes.POINTER(ctypes.c_int64)),
         ("frame_age_us", ctypes.POINTER(ctypes.c_int64)),
         ("parse_ns_total", ctypes.c_int64),
+        ("hits_total", ctypes.c_int64),
     ]
 
 
@@ -1124,8 +1248,8 @@ class IngressTakenBatch:
 
     __slots__ = ("_ptr", "n", "n_frames", "algorithm", "behavior", "hits",
                  "limit", "duration", "hash_keys", "hashes", "frame_lanes",
-                 "frame_age_us", "parse_ns_total", "_nb", "_no", "_ub",
-                 "_uo", "trace_ctx")
+                 "frame_age_us", "parse_ns_total", "hits_total", "_nb", "_no",
+                 "_ub", "_uo", "trace_ctx")
 
     def __init__(self, ptr, info: _GtTakenInfo):
         self._ptr = ptr
@@ -1149,6 +1273,7 @@ class IngressTakenBatch:
         self.frame_lanes = _view(info.frame_lanes, self.n_frames, np.int64)
         self.frame_age_us = _view(info.frame_age_us, self.n_frames, np.int64)
         self.parse_ns_total = int(info.parse_ns_total)
+        self.hits_total = int(info.hits_total)
         self.trace_ctx = None  # fast lane never carries sampled frames
 
     def __len__(self) -> int:

@@ -3028,6 +3028,7 @@ typedef struct {
   const int64_t* frame_lanes;
   const int64_t* frame_age_us;
   int64_t parse_ns_total;
+  int64_t hits_total;  // sum of `hits`: the audit's ingress_hits
 } GtTakenInfo;
 
 void* gt_ingress_new(void) { return new IngressBatcher; }
@@ -3346,6 +3347,8 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
   out->frame_lanes = tb->frame_lanes.data();
   out->frame_age_us = tb->frame_age_us.data();
   out->parse_ns_total = tb->parse_ns_total;
+  out->hits_total = 0;
+  for (int64_t h : tb->hits) out->hits_total += h;
   *out_tb = tb.release();
   return 1;
 }
@@ -3451,6 +3454,212 @@ void gt_ingress_free(void* bv) {
   for (IngressFrame* f : b->q) ingress_free_frame(f);
   for (IngressFrame* f : b->xq) ingress_free_frame(f);
   delete b;
+}
+
+}  // extern "C"
+
+// ======================================================================
+// Batch folds of the observability planes (gt_name_groups,
+// gt_cms_fold): the tenant ledger's per-name aggregation and the
+// count-min adds of both sketches
+// (profiling.TenantLedger, saturation.HotKeySketch), one pass each over
+// columns the caller already holds.  The Python objects stay the only
+// holders of their state: a fold adds into the numpy table's own
+// buffer, under the object's own lock, and hands back what the top-K
+// bookkeeping needs — at most `topk` candidates — so the interpreter
+// never walks a batch's lanes.
+// ======================================================================
+
+namespace {
+
+// First-occurrence group-by of 64-bit hashes: an open-addressing set of
+// positions into the caller's array of distinct hashes.  The slots are
+// a thread's scratch, kept between folds: a take's worth is 32 KB, and
+// a fresh block that size a fold is page faults, not arithmetic.
+struct HashGroups {
+  std::vector<uint32_t>& slots;  // position + 1; 0 = empty
+  const uint64_t* keys;          // the distinct hashes, by position
+  uint64_t mask;
+  int shift;
+  static std::vector<uint32_t>& scratch() {
+    static thread_local std::vector<uint32_t> v;
+    return v;
+  }
+  HashGroups(int64_t n, const uint64_t* distinct)
+      : slots(scratch()), keys(distinct) {
+    int bits = 4;
+    while ((int64_t(1) << bits) < 2 * n) ++bits;
+    size_t size = size_t(1) << bits;
+    if (slots.size() < size) slots.resize(size);
+    memset(slots.data(), 0, size * sizeof(uint32_t));
+    mask = size - 1;
+    shift = 64 - bits;
+  }
+  // The slot `h` lives in, or the empty one it would take.
+  uint32_t& find(uint64_t h) {
+    uint64_t i = (h * 0x9E3779B97F4A7C15ull) >> shift;
+    while (slots[i] != 0 && keys[slots[i] - 1] != h) i = (i + 1) & mask;
+    return slots[i];
+  }
+};
+
+// memcmp(p, q, len) == 0 for the short strings names are, without the
+// call: eight bytes a step, then the tail.
+inline bool same_bytes(const char* p, const char* q, size_t len) {
+  for (; len >= 8; p += 8, q += 8, len -= 8) {
+    uint64_t a, b;
+    memcpy(&a, p, 8);
+    memcpy(&b, q, 8);
+    if (a != b) return false;
+  }
+  for (; len; ++p, ++q, --len)
+    if (*p != *q) return false;
+  return true;
+}
+
+}  // namespace
+
+extern "C" {
+
+// The tenant fold's aggregation (TenantLedger._fold_batch): lanes,
+// hits and ingress bytes by FNV-1 name hash, as np.unique(hashes,
+// return_index, return_inverse) plus three bincounts leave them.
+// out = i64[6][n]: rows {distinct hashes ASCENDING (u64 bits), first
+// lane, lanes, hits, bytes}, each filled to m, then inv = each lane's
+// position among the distinct.  A lane's bytes are name_len + uk_len +
+// lane_const.  Returns m.
+int64_t gt_name_groups(const char* names, const int64_t* name_off, int64_t n,
+                       const int64_t* hits, const int64_t* name_len,
+                       const int64_t* uk_len, int64_t lane_const,
+                       int64_t* out) {
+  if (n <= 0) return 0;
+  uint64_t* uh = (uint64_t*)out;
+  int64_t* inv = out + 5 * n;
+  HashGroups groups(n, uh);
+  int64_t m = 0;
+  const char* prev = nullptr;
+  size_t prev_len = 0;
+  int64_t prev_g = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const char* p = names + name_off[i];
+    size_t len = (size_t)(name_off[i + 1] - name_off[i]);
+    int64_t g;
+    if (prev && len == prev_len && same_bytes(p, prev, len)) {
+      g = prev_g;  // a frame is mostly one name: no hash, no probe
+    } else {
+      uint64_t h = fnv1_64(p, p + len);
+      uint32_t& s = groups.find(h);
+      if (s == 0) {
+        uh[m] = h;
+        out[n + m] = i;
+        out[2 * n + m] = out[3 * n + m] = out[4 * n + m] = 0;
+        s = (uint32_t)++m;
+      }
+      g = (int64_t)s - 1;
+      prev = p;
+      prev_len = len;
+      prev_g = g;
+    }
+    inv[i] = g;
+    out[2 * n + g] += 1;
+    out[3 * n + g] += hits[i];
+    out[4 * n + g] += name_len[i] + uk_len[i] + lane_const;
+  }
+  if (m == 1) return 1;
+  // np.unique's order: ascending hash.
+  std::vector<int64_t> order((size_t)m), rank((size_t)m);
+  for (int64_t g = 0; g < m; ++g) order[(size_t)g] = g;
+  std::sort(order.begin(), order.end(),
+            [&](int64_t a, int64_t b) { return uh[a] < uh[b]; });
+  std::vector<int64_t> sorted((size_t)(5 * m));
+  for (int64_t k = 0; k < m; ++k) {
+    int64_t g = order[(size_t)k];
+    rank[(size_t)g] = k;
+    for (int r = 0; r < 5; ++r) sorted[(size_t)(r * m + k)] = out[r * n + g];
+  }
+  for (int r = 0; r < 5; ++r)
+    memcpy(out + r * n, sorted.data() + r * m, (size_t)m * 8);
+  for (int64_t i = 0; i < n; ++i) inv[i] = rank[(size_t)inv[i]];
+  return m;
+}
+
+// One batch into a count-min table and out again as top-K candidates.
+// tab = i64[depth][width], the caller's own (its lock held): every
+// distinct hash adds its summed weight (weights NULL = 1 a lane) into
+// the `depth` cells ((h * salt) >> 17) % width, then reads its estimate
+// back, the least of its cells after ALL the adds.  out = i64[3 * n +
+// n_tracked + 1 + topk]: per distinct hash in order of first
+// occurrence, filled to the returned m, rows of n {the hash (u64
+// bits), its first lane, its estimate}; then where each of the caller's
+// tracked hashes landed in that order, -1 if not in the batch; then the
+// count of candidates and their positions — the untracked with an
+// estimate ABOVE `floor`, ascending in position; more than `topk` of
+// them are cut to the topk largest estimates, ascending by estimate.
+int64_t gt_cms_fold(int64_t* tab, int32_t depth, int64_t width,
+                    const uint64_t* salts, const uint64_t* hashes,
+                    const int64_t* weights, int64_t n,
+                    const uint64_t* tracked, int64_t n_tracked,
+                    int64_t floor, int64_t topk, int64_t* out) {
+  if (n < 0) n = 0;
+  if (topk < 0) topk = 0;
+  uint64_t* ud = (uint64_t*)out;
+  int64_t* ufirst = out + n;
+  int64_t* uest = out + 2 * n;  // a hash's weight until its estimate is read
+  int64_t* t_idx = out + 3 * n;
+  int64_t* n_cand = t_idx + n_tracked;
+  int64_t* cand = n_cand + 1;
+  *n_cand = 0;
+  for (int64_t t = 0; t < n_tracked; ++t) t_idx[t] = -1;
+  if (n == 0 || depth <= 0 || width <= 0) return 0;
+  HashGroups groups(n, ud);
+  int64_t m = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    uint32_t& s = groups.find(hashes[i]);
+    int64_t w = weights ? weights[i] : 1;
+    if (s == 0) {
+      ud[m] = hashes[i];
+      ufirst[m] = i;
+      uest[m] = w;
+      s = (uint32_t)++m;
+    } else {
+      uest[s - 1] += w;
+    }
+  }
+  const bool pow2 = (width & (width - 1)) == 0;
+  const uint64_t wmask = (uint64_t)width - 1;
+  auto cell = [&](int64_t j, int32_t r) -> int64_t& {
+    uint64_t x = (ud[j] * salts[r]) >> 17;
+    return tab[(int64_t)r * width + (pow2 ? x & wmask : x % (uint64_t)width)];
+  };
+  for (int64_t j = 0; j < m; ++j)
+    for (int32_t r = 0; r < depth; ++r) cell(j, r) += uest[j];
+  for (int64_t j = 0; j < m; ++j) {
+    int64_t est = cell(j, 0);
+    for (int32_t r = 1; r < depth; ++r) est = std::min(est, cell(j, r));
+    uest[j] = est;
+  }
+  std::vector<uint8_t> is_tracked((size_t)m, 0);
+  for (int64_t t = 0; t < n_tracked; ++t) {
+    uint32_t s = groups.find(tracked[t]);
+    if (s != 0) {
+      t_idx[t] = (int64_t)s - 1;
+      is_tracked[s - 1] = 1;
+    }
+  }
+  std::vector<int64_t> q;
+  for (int64_t j = 0; j < m; ++j)
+    if (!is_tracked[(size_t)j] && uest[j] > floor) q.push_back(j);
+  if ((int64_t)q.size() > topk) {
+    auto weaker = [&](int64_t a, int64_t b) {
+      return uest[a] != uest[b] ? uest[a] < uest[b] : a < b;
+    };
+    std::nth_element(q.begin(), q.end() - topk, q.end(), weaker);
+    q.erase(q.begin(), q.end() - topk);
+    std::sort(q.begin(), q.end(), weaker);
+  }
+  *n_cand = (int64_t)q.size();
+  if (!q.empty()) memcpy(cand, q.data(), q.size() * 8);
+  return m;
 }
 
 }  // extern "C"

@@ -24,8 +24,9 @@ questions:
 
 * **Who is spending the capacity?** — `TenantLedger`, cardinality-
   bounded per-tenant cost attribution keyed by rate-limit NAME (the
-  tenant unit).  A count-min sketch over vectorized FNV-1 name hashes
-  (the `hash_ring.get_batch_codes` machinery, PR 6) ranks tenants; the
+  tenant unit).  A count-min sketch over FNV-1 name hashes, folded a
+  batch at a time in native code (`native.name_groups`,
+  `native.cms_fold`), ranks tenants; the
   top `GUBER_TENANT_TOPK` keep EXACT accumulator rows (hits, lanes,
   over-limit, shed lanes, ingress bytes) and everyone else rolls into
   ONE `other` bucket — so 10k distinct names cost K+1 metric series,
@@ -60,6 +61,8 @@ from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+
+from . import native
 
 # ---------------------------------------------------------------------
 # Knobs (module-level env reads cover library embeddings; daemons
@@ -544,7 +547,7 @@ class _TenantRow:
 
 
 class _TenantCtx:
-    """Per-batch fold context: the vectorized name aggregation computed
+    """Per-batch fold context: the name aggregation computed
     once at admit and reused by the outcome/shed folds (same arrays,
     zero re-hashing)."""
 
@@ -564,8 +567,6 @@ def _name_columns(cols):
     LazyIngressColumns (spans into the request body), or a
     FrameIngressColumns (blob + offsets) — WITHOUT materializing
     per-lane strings on the packed shapes."""
-    from . import native
-
     pj = getattr(cols, "_pj", None)
     if pj is not None:  # LazyIngressColumns: (off, len) spans into body
         body = np.frombuffer(pj.body, dtype=np.uint8)
@@ -620,9 +621,10 @@ def _lane_names(cols):
 
 class TenantLedger:
     """Cardinality-bounded per-tenant cost accounting (see module
-    docstring).  All folds are per BATCH and vectorized over lanes —
-    except a batch of at most `topk` lanes, which folds lane by lane
-    in plain Python (`_fold_few`) — and
+    docstring).  All folds are per BATCH; a batch's lanes fold in
+    native code (`_fold_batch`) into this object's table and rows, the
+    one holder of the counts — except a batch of at most `topk` lanes,
+    which folds lane by lane in plain Python (`_fold_few`) — and
     Python touches at most `topk` tenants per fold.  Conservation holds
     exactly for every stat: `sum(rows) + other == totals` — promotion
     moves a tenant's CURRENT batch out of `other` into its new row, and
@@ -641,6 +643,7 @@ class TenantLedger:
         self._other = dict.fromkeys(_STATS, 0)
         self._totals = dict.fromkeys(_STATS, 0)
         self.batches = 0
+        self.candidates = 0  # promotion candidates Python touched (_fold_batch)
 
     # -- admit-side folds (beside every audit ingress note) ------------
     def fold_admit(self, cols) -> Optional[_TenantCtx]:
@@ -662,52 +665,49 @@ class TenantLedger:
         return self._fold_batch(cols)
 
     def _fold_batch(self, cols) -> _TenantCtx:
-        """fold_admit, vectorized over the lanes of a batch."""
-        from . import native
-
+        """fold_admit for a batch, off the interpreter: the lanes
+        aggregate by name in one native pass (native.name_groups), and
+        a second (native.cms_fold), under the ledger's lock, adds the
+        names' hits into this ledger's own count-min table and picks
+        the candidates.  Python touches the tracked rows and at most
+        `topk` candidates."""
         names, name_at, name_lens, uk_lens = _name_columns(cols)
-        hashes = native.fnv1_batch(names)
-        uh, first, inv = np.unique(
-            hashes, return_index=True, return_inverse=True
+        uh, first, inv, lanes_u, hits_u, bytes_u = native.name_groups(
+            names, cols.hits, name_lens, uk_lens, NUMERIC_LANE_BYTES
         )
         ctx = _TenantCtx(inv, uh, first, name_at)
-        lanes_u = np.bincount(inv, minlength=ctx.m).astype(np.int64)
-        hits_u = np.bincount(
-            inv, weights=np.asarray(cols.hits, dtype=np.float64),
-            minlength=ctx.m,
-        ).astype(np.int64)
-        lane_bytes = name_lens + uk_lens + NUMERIC_LANE_BYTES
-        bytes_u = np.bincount(
-            inv, weights=lane_bytes.astype(np.float64), minlength=ctx.m
-        ).astype(np.int64)
+        stats = {"hits": hits_u, "lanes": lanes_u, "ingress_bytes": bytes_u}
+        sums = {k: int(col.sum()) for k, col in stats.items()}
         with self._lock:
             self.batches += 1
-            idx = (
-                (uh[None, :] * self._salts[:, None]) >> np.uint64(17)
-            ) % np.uint64(self.width)
-            for r in range(self.depth):
-                np.add.at(self._tab[r], idx[r].astype(np.intp), hits_u)
-            est = self._tab[
-                np.arange(self.depth)[:, None], idx.astype(np.intp)
-            ].min(axis=0)
-            self._totals["hits"] += int(hits_u.sum())
-            self._totals["lanes"] += int(lanes_u.sum())
-            self._totals["ingress_bytes"] += int(bytes_u.sum())
-            tracked = np.isin(uh, self._row_hashes)
-            for j in np.nonzero(tracked)[0]:
-                row = self._rows[int(uh[j])]
+            rows, other = self._rows, self._other
+            # The floor BEFORE this batch lifts the tracked rows'
+            # estimates: no higher than the one _promote_locked reads
+            # after, so its pick holds every candidate that clears that.
+            floor = (
+                min(r.est for r in rows.values())
+                if len(rows) >= self.topk else -1
+            )
+            _, _, est, tracked, cand = native.cms_fold(
+                self._tab, self._salts, uh, hits_u, self._row_hashes,
+                floor, self.topk,
+            )
+            self.candidates += len(cand)
+            for k, v in sums.items():
+                self._totals[k] += v
+                other[k] += v
+            for h, j in zip(self._row_hashes, tracked):
+                if j < 0:
+                    continue  # a tracked tenant with no lane in the batch
+                row = rows[int(h)]
                 row.est = int(est[j])
-                row.hits += int(hits_u[j])
-                row.lanes += int(lanes_u[j])
-                row.ingress_bytes += int(bytes_u[j])
-            un = np.nonzero(~tracked)[0]
-            if un.size:
-                self._other["hits"] += int(hits_u[un].sum())
-                self._other["lanes"] += int(lanes_u[un].sum())
-                self._other["ingress_bytes"] += int(bytes_u[un].sum())
+                for k, col in stats.items():
+                    v = int(col[j])  # out of `other`, into the row
+                    setattr(row, k, getattr(row, k) + v)
+                    other[k] -= v
+            if len(cand):
                 self._promote_locked(
-                    un, est, uh, first, name_at,
-                    hits_u, lanes_u, bytes_u,
+                    cand, est, uh, first, name_at, hits_u, lanes_u, bytes_u
                 )
         return ctx
 
@@ -715,8 +715,9 @@ class TenantLedger:
         """fold_admit for the few lanes of a classic call (n <= topk,
         the bound on a fold's Python that the ledger already keeps):
         the same accounting and the same context, lane by lane.  The
-        vector fold spends some twenty numpy calls on two lanes, a
-        dozen of them under the ledger's lock; every request of every
+        batch fold spent some twenty numpy calls on two lanes, a dozen
+        of them under the ledger's lock (two native calls and their
+        arrays since PR 32); every request of every
         edge worker passes through that lock, and a holder that loses
         the interpreter mid-fold parks them all (PERF.md §6, PR 30: on
         the chip the sampler found 89% of the edge workers' samples
@@ -736,8 +737,8 @@ class TenantLedger:
             g[1] += 1
             g[2] += int(hits[i])
             g[3] += int(name_lens[i]) + int(uk_lens[i]) + NUMERIC_LANE_BYTES
-        # Uniques in hash order, as np.unique leaves them in the vector
-        # fold: a context (and a promotion) reads the same either way.
+        # Uniques in hash order, as the batch fold leaves them: a
+        # context (and a promotion) reads the same either way.
         uniq = sorted(
             (hashing.fnv1_64(name.encode("utf-8")), name, g)
             for name, g in by_name.items()
@@ -847,9 +848,9 @@ class TenantLedger:
     def fold_one(self, name: str, hits: int, nbytes: int) -> None:
         """Single-lane fold (the async single-key fast path, which
         bypasses both routers): scalar twin of fold_admit — identical
-        accounting under the same lock, none of the vector machinery
-        (unique/bincount/padding string) that exists to amortize over
-        a batch this path deliberately skips."""
+        accounting under the same lock, none of the batch machinery
+        (packed names, group-by, candidate pick) that exists to amortize
+        over a batch this path deliberately skips."""
         from .utils import hashing
 
         if _ENABLED:
@@ -918,8 +919,6 @@ class TenantLedger:
         ]
         if not over_names:
             return
-        from . import native
-
         hashes = native.fnv1_batch(over_names)
         uh, first, inv = np.unique(
             hashes, return_index=True, return_inverse=True
@@ -1018,7 +1017,7 @@ class TenantLedger:
 
 class _RequestView:
     """Minimal column view over a dataclass request list so
-    fold_requests reuses the one vectorized fold."""
+    fold_requests reuses the one batch fold."""
 
     __slots__ = ("names", "unique_keys", "hits")
 

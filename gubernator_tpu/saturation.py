@@ -51,7 +51,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from . import profiling, tracing
+from . import native, profiling, tracing
 
 # ---------------------------------------------------------------------
 # Shared ceil-rank percentiles (one definition, so every percentile
@@ -641,8 +641,10 @@ _CMS_SALTS = np.array(
 
 class HotKeySketch:
     """Count-min sketch over per-lane key hashes plus an exact top-K
-    candidate list.  update() is fully vectorized over a batch; key
-    STRINGS are materialized only for the handful of lanes whose
+    candidate list.  update() folds a batch in one native pass
+    (native.cms_fold) that adds into this object's table, the one
+    holder of the counts, and hands back at most `topk` candidates;
+    key STRINGS are materialized only for those, the lanes whose
     estimate crosses the current top-K floor, so the hot path never
     builds per-lane Python objects.  Counts decay by halving every
     `decay_s` seconds — the sketch answers "hot NOW", not "hot ever"."""
@@ -661,6 +663,7 @@ class HotKeySketch:
         self._last_decay = time_fn()
         self.total_lanes = 0
         self.batches = 0
+        self.candidates = 0  # top-K candidates Python touched, all folds
 
     def update(self, hashes: np.ndarray, keys) -> None:
         """Fold one batch: `hashes` u64[n] (the ring lookup's fnv1
@@ -676,33 +679,22 @@ class HotKeySketch:
                 self._tab >>= 1
                 for rec in self._top.values():
                     rec[0] >>= 1
-            uh, first, counts = np.unique(
-                hs, return_index=True, return_counts=True
+            # Top-K maintenance: only candidates at/above the current
+            # floor materialize a key string, and never more than the K
+            # largest — uniform traffic concentrates estimates near the
+            # floor, and a 1000-unique batch must not loop 1000 lanes
+            # in Python.  While the list is still filling the floor is
+            # 0, which every estimate clears.
+            floor = (
+                min(rec[0] for rec in self._top.values())
+                if len(self._top) >= self.topk else 0
             )
-            idx = ((uh[None, :] * self._salts[:, None])
-                   >> np.uint64(17)) % np.uint64(self.width)
-            for r in range(self.depth):
-                np.add.at(self._tab[r], idx[r].astype(np.intp), counts)
-            est = self._tab[
-                np.arange(self.depth)[:, None], idx.astype(np.intp)
-            ].min(axis=0)
+            uh, first, est, _, cand = native.cms_fold(
+                self._tab, self._salts, hs, None, None, floor - 1, self.topk
+            )
             self.total_lanes += n
             self.batches += 1
-            # Top-K maintenance: only candidates at/above the current
-            # floor materialize a key string.  While the list is still
-            # filling the floor is 0, so bound the candidate scan to
-            # the K largest estimates — a 1000-unique batch must not
-            # loop 1000 lanes in Python.
-            if len(self._top) >= self.topk:
-                floor = min(rec[0] for rec in self._top.values())
-                cand = np.nonzero(est >= floor)[0]
-                if cand.size > self.topk:
-                    # Uniform traffic concentrates estimates near the
-                    # floor: without this cap, ~every unique hash would
-                    # qualify and loop in Python per batch.
-                    cand = cand[np.argsort(est[cand])[-self.topk:]]
-            else:
-                cand = np.argsort(est)[max(0, est.size - self.topk):]
+            self.candidates += len(cand)
             for j in cand:
                 h = int(uh[j])
                 rec = self._top.get(h)

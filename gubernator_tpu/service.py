@@ -25,6 +25,7 @@ import numpy as np
 
 from . import audit as audit_mod
 from . import blackbox as blackbox_mod
+from . import native
 from . import profiling
 from . import saturation
 from .saturation import phase
@@ -3036,6 +3037,14 @@ class V1Service:
                 ),
                 "scalarApplies": store.scalar_applies,
                 **saturation.express_snapshot(),
+            },
+            # The batch folds (native.cms_fold): whether the native
+            # pass runs them, and the top-K candidates they have handed
+            # Python, by sketch — the bound on a fold's interpreter time.
+            "folds": {
+                "native": native.available(),
+                "keyCandidates": self.hotkeys.candidates,
+                "tenantCandidates": self.tenants.candidates,
             },
             "hotkeys": self.hotkeys.snapshot()["topk"][:5],
             # Cost observatory (profiling.py): top tenants by cost and

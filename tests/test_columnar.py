@@ -257,6 +257,37 @@ def test_columnar_fallback_without_native():
         svc.close()
 
 
+def test_service_starts_and_folds_without_the_native_build(monkeypatch):
+    """A host with no compiler: `native.available()` is False, the
+    store plans with the Python slot table, and the service still
+    starts — the tenant ledger and the hot-key sketch it constructs
+    fold a batch (40 lanes, above `topk`: the batch fold) from numpy."""
+    from gubernator_tpu import native
+    from gubernator_tpu.types import PeerInfo
+
+    monkeypatch.setattr(native, "_get_lib", lambda: None)
+    clock = Clock()
+    clock.freeze(NOW)
+    store = MeshBucketStore(capacity_per_shard=256)
+    assert not store._native
+    svc = V1Service(ServiceConfig(store=store, clock=clock,
+                                  advertise_address="127.0.0.1:9998"))
+    svc.set_peers([PeerInfo(grpc_address="127.0.0.1:9998", is_owner=True)])
+    try:
+        lanes0 = svc.tenants.totals()["lanes"]
+        r = svc.get_rate_limits_columns(make_cols(40, prefix="nocc"))
+        for i in range(40):
+            assert r.response_at(i).remaining == 9
+        assert svc.tenants.totals()["lanes"] - lanes0 == 40
+        assert svc.tenants.batches >= 1
+        hs = native.fnv1_batch([f"nocc{i % 7}" for i in range(40)])
+        svc.hotkeys.update(hs, [f"nocc{i % 7}" for i in range(40)])
+        assert svc.hotkeys.snapshot()["total_lanes"] >= 40
+        assert svc.debug_status()["folds"]["native"] is False
+    finally:
+        svc.close()
+
+
 def test_wide_gregorian_stays_on_dict_wire_and_matches_wide():
     """Yearly Gregorian expiries exceed the narrow wire's i32 deltas;
     the dict wire must still carry them (int64 table rows + wide-output

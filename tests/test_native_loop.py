@@ -152,6 +152,68 @@ def test_fast_lane_serves_frames_natively(daemons):
     assert after["lanes"] == before["lanes"] + 16
 
 
+def test_pump_folds_natively_and_sums_exactly(daemons):
+    """A real NativeIngressPump: the audit's `ingress_hits` (summed in
+    gt_ingress_take), the tenant ledger's `totals["hits"]` (summed by
+    the native fold) and the hits sent agree exactly, every take folds
+    once into each sketch, and /debug/status `folds` says the native
+    pass ran them and how few candidates Python touched."""
+    import urllib.request
+
+    from gubernator_tpu import audit as audit_mod
+
+    fast, _pr8, _clock, _sock = daemons
+    port = fast.gateway._edge.port
+
+    def status():
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/debug/status", timeout=30
+        ) as r:
+            return json.loads(r.read())
+
+    before = status()["folds"]
+    assert set(before) == {"native", "keyCandidates", "tenantCandidates"}
+    assert before["native"] is True  # the pump exists: so does the library
+    folds0 = (fast.service.hotkeys.batches, fast.service.tenants.batches)
+    pump0 = fast.gateway.pump.stats()
+    audit0 = audit_mod.ledger_snapshot().get("ingress_hits", 0)
+    led0 = fast.service.tenants.totals()
+    sent_hits = sent_lanes = 0
+    for t, hits in enumerate((0, 1, 7, 3)):
+        n = 40 + t  # above the ledger's topk: the batch fold, not _fold_few
+        raw, body = _post(port, _frame(
+            f"folds-{t % 2}", [f"fold{t}-{i % 30}" for i in range(n)],
+            hits=hits, limit=10**9,
+        ))
+        assert raw.startswith(b"HTTP/1.1 200 OK")
+        assert wire.decode_ingress_result_frame(body).n == n
+        sent_hits += hits * n
+        sent_lanes += n
+    pump1 = fast.gateway.pump.stats()
+    takes = pump1["batches"] - pump0["batches"]
+    assert takes == 4 and pump1["lanes"] - pump0["lanes"] == sent_lanes
+    assert (
+        audit_mod.ledger_snapshot().get("ingress_hits", 0) - audit0
+        == sent_hits
+    )
+    led1 = fast.service.tenants.totals()
+    assert led1["hits"] - led0["hits"] == sent_hits == 464
+    assert led1["lanes"] - led0["lanes"] == sent_lanes
+    after = status()["folds"]
+    assert (fast.service.hotkeys.batches - folds0[0],
+            fast.service.tenants.batches - folds0[1]) == (takes, takes)
+    # Python's share of a take: the candidates, never the lanes.
+    for plane in ("keyCandidates", "tenantCandidates"):
+        assert 0 <= after[plane] - before[plane] <= 16 * takes
+    assert after["keyCandidates"] > before["keyCandidates"]
+    snap = fast.service.tenants.snapshot()
+    for stat in ("hits", "lanes", "ingressBytes"):
+        assert (
+            sum(r[stat] for r in snap["topk"]) + snap["other"][stat]
+            == snap["totals"][stat]
+        )
+
+
 def test_fast_lane_byte_identical_to_python_edge(daemons):
     """The knob-off interop line: the native loop's kind-6 fill (and
     its HTTP envelope) must be byte-identical to the PR 8

@@ -6,6 +6,8 @@ telemetry vs an oracle (with the ZERO-extra-device-dispatch pin), the
 sample-0 wire-parity contract with the plane active."""
 
 import json
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -254,6 +256,191 @@ def test_hash_ring_feeds_sketch():
     assert snap["total_lanes"] == 60
     assert snap["topk"][0]["key"] == "viral"
     assert snap["topk"][0]["estimate"] >= 50
+
+
+# ---------------------------------------------------------------------
+# The native sketch fold against the numpy fold it replaced
+# (tests/fold_reference.py), on the cells' own shapes
+# ---------------------------------------------------------------------
+def _take_hashes(names, uks):
+    keys = native.PackedKeys(*native.pack_keys(
+        [f"{a}_{b}" for a, b in zip(names, uks)]
+    ))
+    return native.fnv1_batch(keys), keys
+
+
+def _assert_same_sketch(a, b):
+    """Sketch `a` (the package's fold) and `b` (the reference's) after
+    the same batches: the table cell for cell, and the top-K wherever
+    estimates are distinct — entries AT a sketch's floor may be other
+    keys of the same estimate (the two folds break ties their own
+    way), every entry above both floors is the same key and count."""
+    assert np.array_equal(a._tab, b._tab)
+    assert (a.total_lanes, a.batches) == (b.total_lanes, b.batches)
+    assert len(a._top) == len(b._top)
+    if not a._top:
+        return
+    assert sorted(r[0] for r in a._top.values()) == sorted(
+        r[0] for r in b._top.values())
+    floor = min(r[0] for r in a._top.values())
+    above_a = {h: r for h, r in a._top.items() if r[0] > floor}
+    above_b = {h: r for h, r in b._top.items() if r[0] > floor}
+    assert above_a == above_b
+
+
+@pytest.mark.parametrize("shape", [
+    "zipf-4096", "zipf-1028", "load-64", "coalesced", "hits-0-and-many",
+])
+def test_native_sketch_fold_is_the_numpy_fold(shape):
+    from .fold_reference import ref_sketch_update, takes
+
+    clock = [0.0]
+    a, b = (
+        saturation.HotKeySketch(width=4096, depth=4, topk=8, decay_s=10.0,
+                                time_fn=lambda: clock[0])
+        for _ in range(2)
+    )
+    a.update(np.zeros(0, np.uint64), [])  # an empty take folds nothing
+    assert a.batches == 0 and not a._tab.any()
+    for t, (names, uks, _hits) in enumerate(takes(shape)):
+        if t == 3:
+            clock[0] += 11.0  # a decay between two folds: both halve
+        hs, keys = _take_hashes(names, uks)
+        a.update(hs, keys)
+        ref_sketch_update(b, hs, keys)
+        _assert_same_sketch(a, b)
+    assert a._last_decay == b._last_decay == 11.0
+    assert a.batches == len(takes(shape))
+    assert a.total_lanes == sum(len(t[0]) for t in takes(shape))
+    assert 0 < a.candidates <= 8 * a.batches  # Python's share
+
+
+def test_native_sketch_fold_distinct_estimates_same_topk():
+    """Where no two estimates are equal the top-K is the reference's
+    entry for entry: key i sent i + 1 times, through a list that fills,
+    a cap (more candidates than K) and floors that rise."""
+    from .fold_reference import ref_sketch_update
+
+    a = saturation.HotKeySketch(width=8192, depth=4, topk=8)
+    b = saturation.HotKeySketch(width=8192, depth=4, topk=8)
+    rng = np.random.RandomState(4)
+    for step in range(6):
+        ids = np.repeat(np.arange(40), np.arange(40) + 1 + 50 * step)
+        rng.shuffle(ids)
+        keys = [f"rank:{i}" for i in ids]
+        hs = native.fnv1_batch(keys)
+        a.update(hs, keys)
+        ref_sketch_update(b, hs, keys)
+        assert np.array_equal(a._tab, b._tab)
+        assert a._top == b._top
+    assert {r[1] for r in a._top.values()} == {
+        f"rank:{i}" for i in range(32, 40)}
+
+
+def test_native_sketch_fold_odd_width_and_depth():
+    """A width that is no power of two takes the modulo, not the mask:
+    the same cells as numpy's."""
+    from .fold_reference import ref_sketch_update
+
+    a = saturation.HotKeySketch(width=1000, depth=3, topk=4)
+    b = saturation.HotKeySketch(width=1000, depth=3, topk=4)
+    rng = np.random.RandomState(8)
+    for _ in range(5):
+        keys = [f"odd:{i}" for i in rng.zipf(1.2, 900) % 5000]
+        hs = native.fnv1_batch(keys)
+        a.update(hs, keys)
+        ref_sketch_update(b, hs, keys)
+        _assert_same_sketch(a, b)
+
+
+@pytest.mark.parametrize("shape", [
+    "zipf-4096", "zipf-1028", "load-64", "coalesced", "hits-0-and-many",
+])
+def test_sketch_folds_the_same_without_the_native_build(shape, monkeypatch):
+    """A host with no compiler (`native.available()` False) still
+    constructs a sketch and folds into it: `native.cms_fold` answers
+    from numpy there, the same answer position for position — tied
+    candidates included — so the table AND the top-K come out equal."""
+    from .fold_reference import takes
+
+    a = saturation.HotKeySketch(width=4096, depth=4, topk=8)
+    batches = [_take_hashes(n, u) for n, u, _ in takes(shape)]
+    for hs, keys in batches:
+        a.update(hs, keys)
+    monkeypatch.setattr(native, "_get_lib", lambda: None)
+    assert not native.available()
+    b = saturation.HotKeySketch(width=4096, depth=4, topk=8)
+    b.update(np.zeros(0, np.uint64), [])
+    for hs, keys in batches:
+        b.update(hs, keys)
+    assert np.array_equal(a._tab, b._tab)
+    assert a._top == b._top and list(a._top) == list(b._top)
+    assert (a.batches, a.total_lanes, a.candidates) == (
+        b.batches, b.total_lanes, b.candidates)
+
+
+def test_cms_fold_numpy_twin_answers_position_for_position(monkeypatch):
+    """`native.cms_fold` itself, both bodies: weights, tracked hashes in
+    and out of the batch, a floor that cuts, more candidates than topk
+    with tied estimates, an odd width, an empty batch."""
+    rng = np.random.RandomState(11)
+    salts = saturation._CMS_SALTS[:3]
+    calls = []
+    for n in (0, 1, 7, 900, 900):
+        hs = native.fnv1_batch([f"c:{i}" for i in rng.zipf(1.3, n) % 400])
+        w = rng.randint(0, 4, n).astype(np.int64) if n != 7 else None
+        tracked = np.concatenate([hs[:5], np.arange(3, dtype=np.uint64)])
+        calls.append((hs, w, tracked, int(rng.randint(-1, 3)), 6))
+    outs = []
+    for lib_off in (False, True):
+        if lib_off:
+            monkeypatch.setattr(native, "_get_lib", lambda: None)
+        tab = np.zeros((3, 1000), dtype=np.int64)
+        outs.append([
+            [np.array(x) for x in native.cms_fold(tab, salts, *c)] + [
+                tab.copy()]
+            for c in calls
+        ])
+    for got, want in zip(*outs):
+        for x, y in zip(got, want):
+            assert x.dtype == y.dtype and np.array_equal(x, y)
+    assert outs[0][-1][-1].any()
+
+
+def test_native_sketch_fold_threads_lose_no_count():
+    """Two threads folding into one sketch: the lock still covers the
+    native call, so no add is lost (every cell is the serial sum)."""
+    from .fold_reference import takes
+
+    batches = [_take_hashes(n, u) for n, u, _ in takes("zipf-1028")]
+    shared = saturation.HotKeySketch(width=256, depth=4, topk=8,
+                                     decay_s=1e9)
+    serial = saturation.HotKeySketch(width=256, depth=4, topk=8,
+                                     decay_s=1e9)
+    reps = 40
+
+    def run():
+        for _ in range(reps):
+            for hs, keys in batches:
+                shared.update(hs, keys)
+
+    ts = [threading.Thread(target=run) for _ in range(2)]
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # hand the interpreter over mid-fold
+    try:
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(prev)
+    assert not any(t.is_alive() for t in ts)
+    for _ in range(2 * reps):
+        for hs, keys in batches:
+            serial.update(hs, keys)
+    assert np.array_equal(shared._tab, serial._tab)
+    assert shared._tab[0].sum() == 2 * reps * sum(len(h) for h, _ in batches)
+    assert shared.total_lanes == serial.total_lanes
 
 
 # ---------------------------------------------------------------------

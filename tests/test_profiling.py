@@ -9,6 +9,7 @@ pairing, and config plumbing."""
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -336,7 +337,7 @@ def _few_cols(shape, names, uks, hits):
             {"name": n, "uniqueKey": u, "hits": str(int(h)),
              "limit": "1000000", "duration": "3600000"}
             for n, u, h in zip(names, uks, hits)
-        ]}).encode())
+        ]}, ensure_ascii=False).encode())
         if cols is None:
             pytest.skip("native JSON parse unavailable")
         return cols
@@ -386,6 +387,164 @@ def test_tenant_few_lane_fold_is_the_batch_fold(shape):
     assert sa == sb
     _assert_conserves(sa)
     assert len(sa["topk"]) == 4 and sa["other"]["lanes"] > 0
+
+
+# ---------------------------------------------------------------------
+# The native batch fold against the numpy fold it replaced
+# (tests/fold_reference.py), on the cells' own shapes
+# ---------------------------------------------------------------------
+def _rows_of(led):
+    return {
+        h: (r.name, r.est, r.hits, r.lanes, r.over_limit, r.shed,
+            r.ingress_bytes)
+        for h, r in led._rows.items()
+    }
+
+
+def _assert_same_fold(a, b, ca, cb, rows=True):
+    """Ledger `a` (the package's fold) and `b` (the reference's) after
+    the same batches, and the contexts of the last one."""
+    assert ca.m == cb.m
+    assert np.array_equal(ca.inv, cb.inv) and ca.inv.dtype == cb.inv.dtype
+    assert np.array_equal(ca.uh, cb.uh) and ca.uh.dtype == cb.uh.dtype
+    assert np.array_equal(ca.first, cb.first)
+    assert np.array_equal(a._tab, b._tab)  # cell for cell
+    assert a._totals == b._totals and a.batches == b.batches
+    for led in (a, b):
+        _assert_conserves(led.snapshot())
+    if rows:
+        assert a._other == b._other
+        assert _rows_of(a) == _rows_of(b)
+        assert np.array_equal(a._row_hashes, b._row_hashes)
+
+
+@pytest.mark.parametrize("shape", [
+    "zipf-4096", "zipf-1028", "load-64", "coalesced", "names-10k",
+    "hits-0-and-many",
+])
+def test_native_tenant_fold_is_the_numpy_fold(shape):
+    """Every take of the shape through `_fold_batch` (native) and
+    through the numpy reference: the count-min tables equal cell for
+    cell, totals / other / rows equal, the context equal, and the
+    outcome and shed folds on that context equal.  `names-10k` folds
+    500 names of ONE hit each a batch, so its candidates' estimates tie
+    by the hundred and which of them the rows keep is the one thing the
+    two folds may answer differently: there the rows are held to
+    conservation and to the same estimates, not to the same names."""
+    from .fold_reference import frame_cols, ref_fold_batch, takes
+
+    ties = shape == "names-10k"
+    a = profiling.TenantLedger(topk=8, width=4096, depth=4)
+    b = profiling.TenantLedger(topk=8, width=4096, depth=4)
+    rng = np.random.RandomState(2)
+    for names, uks, hits in takes(shape):
+        cols = frame_cols(names, uks, hits)
+        ca, cb = a._fold_batch(cols), ref_fold_batch(b, cols)
+        _assert_same_fold(a, b, ca, cb, rows=not ties)
+        res = ColumnarResult.empty(len(names))
+        res.status = rng.randint(0, 2, len(names)).astype(np.int32)
+        shed = np.flatnonzero(rng.randint(0, 8, len(names)) == 0)
+        for led, ctx in ((a, ca), (b, cb)):
+            led.fold_outcome(ctx, res)
+            led.fold_shed(ctx, shed)
+        _assert_same_fold(a, b, ca, cb, rows=not ties)
+    assert a._tab.any() or shape == "load-64"  # a load's hits are 0
+    assert a._totals["lanes"] == sum(len(t[0]) for t in takes(shape))
+    if ties:
+        assert sorted(r.est for r in a._rows.values()) == sorted(
+            r.est for r in b._rows.values())
+    assert a.batches == len(takes(shape))
+    # Python's share of a fold: at most topk candidates, never the lanes.
+    assert 0 <= a.candidates <= 8 * a.batches
+    assert b.candidates == 0  # the reference walks every untracked name
+
+
+@pytest.mark.parametrize("shape", ["lists", "json", "frame"])
+def test_native_tenant_fold_every_column_shape(shape):
+    """The three column shapes the ledger is handed (plain lists, the
+    native JSON parse, a decoded frame), with non-ASCII names, and an
+    empty take: the native fold and the reference agree on each."""
+    from .fold_reference import ref_fold_batch
+
+    rng = np.random.RandomState(9)
+    a = profiling.TenantLedger(topk=4, width=64, depth=4)  # cells collide
+    b = profiling.TenantLedger(topk=4, width=64, depth=4)
+    assert a.fold_admit(_few_cols(shape, [], [], [])) is None
+    for step in range(60):
+        n = int(rng.randint(5, 40))
+        names = [f"t\u00e9nant-{rng.zipf(1.4) % 9}" for _ in range(n)]
+        uks = [f"k{rng.randint(1000)}" for _ in range(n)]
+        hits = rng.randint(0, 5, n)
+        cols = _few_cols(shape, names, uks, hits)
+        _assert_same_fold(
+            a, b, a.fold_admit(cols), ref_fold_batch(b, cols)
+        )
+    assert len(a._rows) == 4 and a._other["lanes"] > 0
+    assert a.batches == 60
+
+
+@pytest.mark.parametrize("shape", [
+    "zipf-1028", "coalesced", "names-10k", "hits-0-and-many",
+])
+def test_tenant_ledger_folds_the_same_without_the_native_build(
+        shape, monkeypatch):
+    """A host with no compiler (`native.available()` False) still
+    constructs a ledger and folds into it: `native.name_groups` and
+    `native.cms_fold` answer from numpy there, the same answer position
+    for position — tied candidates included — so the table, the context
+    AND the rows come out equal."""
+    from gubernator_tpu import native
+
+    from .fold_reference import frame_cols, takes
+
+    batches = [frame_cols(*t) for t in takes(shape)]
+    a = profiling.TenantLedger(topk=8, width=4096, depth=4)
+    ctxs = [a.fold_admit(cols) for cols in batches]
+    monkeypatch.setattr(native, "_get_lib", lambda: None)
+    assert not native.available()
+    b = profiling.TenantLedger(topk=8, width=4096, depth=4)
+    for cols, ca in zip(batches, ctxs):
+        cb = b.fold_admit(cols)
+        for col in ("inv", "uh", "first"):
+            x, y = getattr(ca, col), getattr(cb, col)
+            assert x.dtype == y.dtype and np.array_equal(x, y)
+    _assert_same_fold(a, b, ca, cb)  # the ledgers, after the same takes
+    assert a.candidates == b.candidates
+
+
+def test_native_tenant_fold_threads_lose_no_count():
+    """Two threads folding batches into one ledger: the lock covers the
+    native call, so every cell and every total is the serial sum."""
+    from .fold_reference import frame_cols, takes
+
+    batches = [frame_cols(*t) for t in takes("hits-0-and-many")]
+    shared = profiling.TenantLedger(topk=8, width=256, depth=4)
+    serial = profiling.TenantLedger(topk=8, width=256, depth=4)
+    reps = 40
+
+    def run():
+        for _ in range(reps):
+            for cols in batches:
+                shared.fold_admit(cols)
+
+    ts = [threading.Thread(target=run) for _ in range(2)]
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # hand the interpreter over mid-fold
+    try:
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(prev)
+    assert not any(t.is_alive() for t in ts)
+    for _ in range(2 * reps):
+        for cols in batches:
+            serial.fold_admit(cols)
+    assert np.array_equal(shared._tab, serial._tab)
+    assert shared._totals == serial._totals
+    assert shared.batches == serial.batches == 2 * reps * len(batches)
+    _assert_conserves(shared.snapshot())
 
 
 # ---------------------------------------------------------------------
