@@ -459,7 +459,14 @@ class _Staged:
     wire_dev: object = None   # uploaded packed wire (dict-wire path)
     n_rounds: int = 1
     now_ms: int = 0
-    wide: bool = False
+    wide: bool = False        # the ANSWER's width: i64, not i32 deltas
+    # The wire that carried it, for the mesh tally and the launch's
+    # label: the per-lane wire (a column a value) or, by default, the
+    # dictionary wire; the distinct configurations counted on the way
+    # (0 where the dictionary was not tried); the stage's transfer calls.
+    lane_wire: bool = False
+    config_rows: int = 0
+    uploads: int = 0
     # Express scalar slot (ops/scalar.py): a host-side closure that
     # evaluates the single lane and writes its bucket row in place,
     # returning the packed output array the ordinary commit closure
@@ -803,10 +810,8 @@ class ColumnarPipeline:
                     fullest=fullest, padded=padded)
         self._observe_stage("prepare", ph.dt_s)
         # Lane utilization: real lanes vs the pow2-padded shape the
-        # launch will scatter (saturation plane; drained per scrape),
-        # and the same with the shards' fill, cumulative (/debug/device).
+        # launch will scatter (saturation plane; drained per scrape).
         saturation.lane_util.add(prep.n, padded)
-        saturation.mesh_tally.add(shards, prep.n, padded, fullest, prep.n_rounds)
         try:
             with phase("dispatch.stage", bt, ticket=handle.ticket,
                        shards=shards, fullest=fullest, padded=padded) as ph:
@@ -818,6 +823,12 @@ class ColumnarPipeline:
         except BaseException as e:
             self._abort_launch_turn(handle, e)
             raise
+        # The same with the shards' fill and the wire the stage took,
+        # cumulative (/debug/device `mesh`).
+        saturation.mesh_tally.add(
+            shards, prep.n, padded, fullest, prep.n_rounds,
+            staged.lane_wire, staged.config_rows, staged.uploads,
+        )
         self._launch_in_order(handle, staged)
         return handle
 
@@ -952,12 +963,18 @@ class ColumnarPipeline:
 
     def _program_label(self, group) -> str:
         """XLA-telemetry program identity for one launch group: solo vs
-        fused-K and the wire width — the axes along which distinct
-        programs compile."""
+        fused-K, then the wire and the answer's width — the axes along
+        which distinct programs compile.  A dictionary-wire launch is
+        named by its answer alone (`narrow`: i32 deltas, `wide`: i64);
+        a per-lane launch is `lanes` (i32 columns, narrow answer) or
+        `lanes64`."""
         staged = group[0][0]
         shape = "solo" if len(group) == 1 else f"fused{len(group)}"
-        width = "wide" if staged.wide else "narrow"
-        return f"mesh:dispatch:{shape}:{width}"
+        if staged.lane_wire:
+            form = "lanes64" if staged.wide else "lanes"
+        else:
+            form = "wide" if staged.wide else "narrow"
+        return f"mesh:dispatch:{shape}:{form}"
 
     def _launch_group(self, group) -> None:
         """Stage 3 (ticket order, under `_lock`): just the

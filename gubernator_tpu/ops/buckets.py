@@ -1031,13 +1031,13 @@ def apply_rounds_packed_wide(
 
 def build_config_dict(cols, now_ms: int):
     """Host half of the dict wire: map each lane's 7 value columns to a
-    row index in a <=256-row table.  Returns (cfg_idx u8[B], table
-    7x i32[DICT_TABLE_ROWS]) or None when the batch has too many
-    distinct configs (caller falls back to RequestBatch32).  Exact by
-    construction: lanes group by a 64-bit polynomial mix of the
-    columns, then every lane is VERIFIED equal to its group
-    representative — a hash collision degrades to fallback, never to a
-    wrong config."""
+    row index in a <=256-row table.  Returns (rows, enc): `rows` the
+    distinct configs counted, `enc` = (cfg_idx u8[B], table 7x
+    i64[DICT_TABLE_ROWS]) or None when the batch has too many (caller
+    falls back to RequestBatch32).  Exact by construction: lanes group
+    by a 64-bit polynomial mix of the columns, then every lane is
+    VERIFIED equal to its group representative — a hash collision
+    degrades to fallback, never to a wrong config."""
     import numpy as np
 
     greg_delta = np.where(
@@ -1049,17 +1049,17 @@ def build_config_dict(cols, now_ms: int):
     )
     n = len(cols.algo)
     if n == 0:
-        return None
+        return 0, None
     with np.errstate(over="ignore"):
         h = np.zeros(n, np.int64)
         for c in arrays:
             h = h * np.int64(1000003) + c.astype(np.int64)
     uq, idx_first, inv = np.unique(h, return_index=True, return_inverse=True)
     if len(uq) > DICT_TABLE_ROWS:
-        return None
+        return len(uq), None
     for c in arrays:
         if not np.array_equal(c[idx_first][inv], c):
-            return None  # collision: correctness over compactness
+            return len(uq), None  # collision: correctness over compactness
     table = []
     for c in arrays:
         # i64 rows: the table is 256 entries, so wide values (monthly/
@@ -1068,7 +1068,7 @@ def build_config_dict(cols, now_ms: int):
         row = np.zeros(DICT_TABLE_ROWS, np.int64)
         row[: len(uq)] = c[idx_first]
         table.append(row)
-    return inv.astype(np.uint8), tuple(table)
+    return len(uq), (inv.astype(np.uint8), tuple(table))
 
 
 @jax.jit

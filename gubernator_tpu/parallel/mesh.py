@@ -434,6 +434,7 @@ class _MeshPrep:
     mp: object  # NativeMeshPlanner
     pos: np.ndarray
     commit: object
+    bt: object = None  # the take's sampled trace, for the stage's phases
 
 
 @dataclass
@@ -842,21 +843,35 @@ class MeshBucketStore(ColumnarPipeline):
         return _MeshPrep(
             cols=cols, now_ms=now_ms, force_wire=force_wire, n=n,
             fullest=fullest, padded=padded, n_rounds=n_rounds, narrow=narrow,
-            mp=mp, pos=pos, commit=commit,
+            mp=mp, pos=pos, commit=commit, bt=bt,
         )
 
     def _stage_columns(self, prep: "_MeshPrep") -> "_Staged":
         """Stage 2 (no locks): encode the wire and start the sharded
-        H2D upload while older batches compute/transfer."""
+        H2D upload while older batches compute/transfer.
+
+        Two wires carry a batch, and `prep.narrow` is neither: it is
+        the width of the ANSWER (every value fits the i32 deltas), which
+        picks the program on either wire.  The DICTIONARY wire holds a
+        batch of at most DICT_TABLE_ROWS distinct configurations: one
+        i32 buffer of 3 words a lane plus the table, ONE transfer.  A
+        batch of more (a limit a key), of more than 255 rounds, or with
+        `force_wire` set ("narrow" / "wide": the PER-LANE wire, named by
+        the answer width it pins; warm-up and tests use it) rides the
+        per-lane wire: a column a value, each put on the default device
+        and then placed on the mesh.  `dispatch.upload` times the
+        transfer calls of either; what the stage takes beyond it is the
+        encode.  The wire taken, the configurations counted and the
+        transfer calls made ride the _Staged into the mesh tally."""
         cols, now_ms, padded = prep.cols, prep.now_ms, prep.padded
         mp, pos, n_rounds, narrow = prep.mp, prep.pos, prep.n_rounds, prep.narrow
         S = self.n_shards
-        dict_enc = None
+        dict_enc, config_rows = None, 0
         if prep.force_wire is None and n_rounds <= 255:
             # Values live in the dict wire's 256-row i64 table, so wide
             # batches (monthly/yearly Gregorian) stay on it too — only
             # the output width switches (apply_rounds_packed_wide).
-            dict_enc = buckets.build_config_dict(cols, now_ms)
+            config_rows, dict_enc = buckets.build_config_dict(cols, now_ms)
 
         if dict_enc is not None and int(mp.occ.max()) <= 65535:
             cfg_full, cfg_table = dict_enc
@@ -868,7 +883,8 @@ class MeshBucketStore(ColumnarPipeline):
             wire = buckets.pack_dict_wire(
                 mp.slot, mp.exists, mp.write, cfg_a, mp.occ, mp.rid, cfg_table
             )
-            wire_dev = jax.device_put(wire, self._sharding)
+            with phase("dispatch.upload", prep.bt, wire="dict"):
+                wire_dev = jax.device_put(wire, self._sharding)
             # (A single-round compacted scatter — commit only the write
             # lanes — measured slower on TPU; see git history.)
             if self._wire_donate:
@@ -887,8 +903,10 @@ class MeshBucketStore(ColumnarPipeline):
                 solo=lambda state: fn_packed(state, wire_dev, n_rounds, now_ms),
                 fuse_key=("dict", narrow, wire.shape[1]),
                 wire_dev=wire_dev, n_rounds=n_rounds, now_ms=now_ms,
-                wide=not narrow,
+                wide=not narrow, config_rows=config_rows, uploads=1,
             )
+        # The per-lane wire: i32 columns for a narrow answer, i64 for a
+        # wide one.
         vdt = np.int32 if narrow else np.int64
 
         def scatter(col, dtype):
@@ -903,18 +921,26 @@ class MeshBucketStore(ColumnarPipeline):
         else:
             ge = cols.greg_expire
         mk = buckets.make_batch32 if narrow else buckets.make_batch
-        batch = mk(
+        columns = (
             mp.slot, mp.exists.astype(bool), scatter(cols.algo, np.int32),
             scatter(cols.behavior, np.int32), scatter(cols.hits, vdt),
             scatter(cols.limit, vdt), scatter(cols.duration, vdt),
             scatter(ge, vdt), scatter(cols.greg_duration, vdt),
-            occ=mp.occ, write=mp.write.astype(bool),
         )
-        batch = jax.tree.map(lambda a: jax.device_put(a, self._sharding), batch)
-        rid_dev = jax.device_put(jnp.asarray(mp.rid), self._sharding)
+        write = mp.write.astype(bool)
+        with phase("dispatch.upload", prep.bt, wire="lanes"):
+            batch = mk(*columns, occ=mp.occ, write=write)
+            batch = jax.tree.map(lambda a: jax.device_put(a, self._sharding), batch)
+            rid_dev = jax.device_put(jnp.asarray(mp.rid), self._sharding)
         fn = _rounds32_mesh_jit if narrow else _rounds64_mesh_jit
         return _Staged(
-            solo=lambda state: fn(state, batch, rid_dev, n_rounds, now_ms)
+            solo=lambda state: fn(state, batch, rid_dev, n_rounds, now_ms),
+            wide=not narrow, lane_wire=True, config_rows=config_rows,
+            # Reckoned from the columns (a NamedTuple, none of them
+            # None here): `mk` puts each on the default device (a
+            # jnp.asarray), the tree.map places each on the mesh, and
+            # the round ids take the same two steps.
+            uploads=2 * len(batch) + 2,
         )
 
     def _shard_fill(self, prep) -> Tuple[int, int]:
@@ -1876,10 +1902,12 @@ class MeshBucketStore(ColumnarPipeline):
             # one shard, compiling the pad_size(lanes) bucket a
             # duplicate-heavy batch dispatches — without this, a
             # hot-key storm's first dispatch pays that compile or load
-            # inside a client RPC deadline).  Both the
-            # dict wire and the per-lane narrow-wire fallback get
-            # compiled (the wide int64 path is rare enough to pay its
-            # compile lazily).  1ms duration so the slots recycle.
+            # inside a client RPC deadline).  Both wires get compiled
+            # with the narrow (i32) answer: the dictionary wire and,
+            # forced by "narrow", the per-lane wire that a batch of
+            # more than 256 configurations takes (the wide int64
+            # answer of either is rare enough to pay its compile
+            # lazily).  1ms duration so the slots recycle.
             for lanes in sorted(set(warm_shapes or (1,))):
                 lanes = max(int(lanes), 1)
                 for keys in (

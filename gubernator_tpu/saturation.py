@@ -106,7 +106,9 @@ WATERFALL = (
     ("dispatch.prepare", 0),  # slot-table planning (pipeline stage 1)
     ("dispatch.plan_wait", 1),  # waiting for the plan lock
     ("dispatch.plan_native", 1),  # the C++ slot-table plan alone (begin + grouped plan)
-    ("dispatch.stage", 0),    # wire pack + H2D upload start (stage 2)
+    ("dispatch.stage", 0),    # wire encode + H2D upload start (stage 2)
+    ("dispatch.upload", 1),   # the stage's transfer calls alone: one on the
+                              # dictionary wire, two a column on the per-lane wire
     ("dispatch.gate_wait", 0),  # waiting for the ticket's launch turn
     ("dispatch.launch", 0),   # ticket-ordered jit call (stage 3)
     ("dispatch.launch_wait", 1),  # waiting for the store lock
@@ -317,17 +319,32 @@ class MeshTally:
     serves it as `mesh` and a reader takes differences).  A take pads to
     the pad bucket of its FULLEST shard, so `fullest` against `lanes`
     says how uneven the shards were and `lanes` against `padded` how
-    much of the launched shape was real."""
+    much of the launched shape was real.
+
+    And which wire carried it (MeshBucketStore._stage_columns): a
+    dispatch of at most 256 distinct configurations rides the dictionary
+    wire (one i32 buffer, one transfer); one of more rides the per-lane
+    wire (a column a value, a transfer or two a column) and counts under
+    `laneWireDispatches` / `laneWireLanes`, so the dictionary's share is
+    the difference from `dispatches` / `lanes`.  `configRows` sums the
+    distinct configurations buckets.build_config_dict counted (0 where
+    the dictionary was not tried: a forced wire, more than 255 rounds),
+    `uploads` the host-to-device transfer calls the stages made."""
+
+    WIRE_KEYS = ("dispatches", "lanes", "laneWireDispatches", "laneWireLanes",
+                 "configRows", "uploads")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._shards = 0
         self._sums = dict.fromkeys(
-            ("dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds"), 0
+            ("dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
+             "laneWireDispatches", "laneWireLanes", "configRows", "uploads"), 0
         )
 
     def add(self, shards: int, lanes: int, padded: int, fullest: int,
-            rounds: int) -> None:
+            rounds: int, lane_wire: bool = False, config_rows: int = 0,
+            uploads: int = 0) -> None:
         with self._lock:
             self._shards = shards
             s = self._sums
@@ -336,10 +353,20 @@ class MeshTally:
             s["paddedLanes"] += padded
             s["fullestShardLanes"] += fullest
             s["rounds"] += rounds
+            if lane_wire:
+                s["laneWireDispatches"] += 1
+                s["laneWireLanes"] += lanes
+            s["configRows"] += config_rows
+            s["uploads"] += uploads
 
     def snapshot(self) -> Dict[str, int]:
         with self._lock:
             return {"shards": self._shards, **self._sums}
+
+    def wire_snapshot(self) -> Dict[str, int]:
+        """The wire's split alone (`/debug/status` `wire`)."""
+        with self._lock:
+            return {k: self._sums[k] for k in self.WIRE_KEYS}
 
 
 class BusyFraction:

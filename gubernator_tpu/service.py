@@ -3038,6 +3038,11 @@ class V1Service:
                 "scalarApplies": store.scalar_applies,
                 **saturation.express_snapshot(),
             },
+            # Which wire the columnar dispatches took (the `mesh` block
+            # of /debug/device has the same counters): the per-lane
+            # wire's dispatches and lanes, the dictionary's being the
+            # difference; configurations counted; transfer calls made.
+            "wire": saturation.mesh_tally.wire_snapshot(),
             # The batch folds (native.cms_fold): whether the native
             # pass runs them, and the top-K candidates they have handed
             # Python, by sketch — the bound on a fold's interpreter time.

@@ -328,7 +328,7 @@ def test_wide_gregorian_stays_on_dict_wire_and_matches_wide():
         kw["algorithm"], kw["behavior"], kw["hits"], kw["limit"],
         kw["duration"], n, kw["greg_expire"], kw["greg_duration"],
     )
-    assert buckets.build_config_dict(cols, NOW) is not None
+    assert buckets.build_config_dict(cols, NOW)[1] is not None
 
     a = one_device_store(256)
     b = one_device_store(256)

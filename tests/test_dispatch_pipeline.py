@@ -214,7 +214,7 @@ def test_fused_kernel_matches_solo_sequence():
             np.full(lanes, hits, np.int64), np.full(lanes, 100, np.int64),
             np.full(lanes, 60_000, np.int64), lanes,
         )
-        cfg, table = buckets.build_config_dict(cols, NOW)
+        _, (cfg, table) = buckets.build_config_dict(cols, NOW)
         return buckets.pack_dict_wire(
             slot[None, :], np.full((1, lanes), exists, bool),
             np.ones((1, lanes), bool), cfg[None, :].astype(np.uint8),

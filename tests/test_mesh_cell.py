@@ -203,7 +203,8 @@ def test_debug_device_serves_the_mesh_block(served):
     mesh = json.loads(body)["mesh"]
     assert mesh == saturation.mesh_tally.snapshot()
     assert set(mesh) == {
-        "shards", "dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds"}
+        "shards", "dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
+        "laneWireDispatches", "laneWireLanes", "configRows", "uploads"}
     assert mesh["dispatches"] >= len(TAKE_FRAMES) and mesh["paddedLanes"] >= mesh["lanes"] > 0
 
 

@@ -143,8 +143,8 @@ class TestColumnarNarrowAndPipelined:
         # few-configs batch dict-encodes: 2 algos x 3 hits x 4 durations
         cols = make_columns(few["algorithm"], few["behavior"], few["hits"],
                             few["limit"], few["duration"], n)
-        enc = buckets.build_config_dict(cols, now)
-        assert enc is not None
+        rows, enc = buckets.build_config_dict(cols, now)
+        assert enc is not None and 0 < rows <= 24
         cfg_idx, table = enc
         for j in range(0, n, 37):  # spot-check exact lane->config mapping
             k = cfg_idx[j]
@@ -158,7 +158,7 @@ class TestColumnarNarrowAndPipelined:
         cols_many = make_columns(many["algorithm"], many["behavior"],
                                  many["hits"], many["limit"],
                                  many["duration"], n)
-        assert buckets.build_config_dict(cols_many, now) is None
+        assert buckets.build_config_dict(cols_many, now) == (n, None)
 
         # End-to-end: the dict wire must match the WIDE path lane for
         # lane on identical values (wide forced by one int64 lane,
