@@ -461,9 +461,11 @@ class _Staged:
     now_ms: int = 0
     wide: bool = False        # the ANSWER's width: i64, not i32 deltas
     # The wire that carried it, for the mesh tally and the launch's
-    # label: the per-lane wire (a column a value) or, by default, the
+    # label: the per-lane wire (a word a value) or, by default, the
     # dictionary wire; the distinct configurations counted on the way
-    # (0 where the dictionary was not tried); the stage's transfer calls.
+    # (0 where the dictionary was not tried); the stage's transfer
+    # calls, set beside each call: one buffer, one device_put, on
+    # either wire.
     lane_wire: bool = False
     config_rows: int = 0
     uploads: int = 0

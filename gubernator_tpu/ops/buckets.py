@@ -329,37 +329,6 @@ def init_state(capacity: int) -> BucketState:
     )
 
 
-def make_batch(
-    slot,
-    exists,
-    algorithm,
-    behavior,
-    hits,
-    limit,
-    duration,
-    greg_expire=None,
-    greg_duration=None,
-    occ=None,
-    write=None,
-) -> RequestBatch:
-    """Convenience constructor coercing host arrays to kernel dtypes."""
-    slot = jnp.asarray(slot, _I32)
-    z = jnp.zeros_like(jnp.asarray(hits, _I64))
-    return RequestBatch(
-        slot=slot,
-        exists=jnp.asarray(exists, bool),
-        algorithm=jnp.asarray(algorithm, _I32),
-        behavior=jnp.asarray(behavior, _I32),
-        hits=jnp.asarray(hits, _I64),
-        limit=jnp.asarray(limit, _I64),
-        duration=jnp.asarray(duration, _I64),
-        greg_expire=z if greg_expire is None else jnp.asarray(greg_expire, _I64),
-        greg_duration=z if greg_duration is None else jnp.asarray(greg_duration, _I64),
-        occ=None if occ is None else jnp.asarray(occ, _I32),
-        write=None if write is None else jnp.asarray(write, bool),
-    )
-
-
 def apply_batch(
     state: BucketState, req: RequestBatch, now_ms, cold_cond: bool = True
 ) -> "tuple[BucketState, BatchOutput]":
@@ -731,7 +700,12 @@ class RequestBatch32(NamedTuple):
     usable whenever the batch's values fit (the common case: hits,
     limit, duration < 2**31 and no monthly/yearly Gregorian resets).
     The kernel computes in int64 regardless — only the WIRE narrows,
-    which is what matters when the device sits across a thin link."""
+    which is what matters when the device sits across a thin link.
+
+    It exists on the device only: the host never builds one.  Both
+    wires decode into it inside the jitted program — the dictionary
+    wire by table gathers (apply_rounds_dict), the per-lane wire by
+    slices of its one buffer (unpack_lane_wire)."""
 
     slot: jax.Array  # i32[B]
     exists: jax.Array  # bool[B]
@@ -744,26 +718,6 @@ class RequestBatch32(NamedTuple):
     greg_duration: jax.Array  # i32[B]
     occ: "jax.Array | None" = None  # i32[B]
     write: "jax.Array | None" = None  # bool[B]
-
-
-def make_batch32(
-    slot, exists, algorithm, behavior, hits, limit, duration,
-    greg_expire_delta=None, greg_duration=None, occ=None, write=None,
-) -> RequestBatch32:
-    z = jnp.zeros_like(jnp.asarray(hits, _I32))
-    return RequestBatch32(
-        slot=jnp.asarray(slot, _I32),
-        exists=jnp.asarray(exists, bool),
-        algorithm=jnp.asarray(algorithm, _I32),
-        behavior=jnp.asarray(behavior, _I32),
-        hits=jnp.asarray(hits, _I32),
-        limit=jnp.asarray(limit, _I32),
-        duration=jnp.asarray(duration, _I32),
-        greg_expire_delta=z if greg_expire_delta is None else jnp.asarray(greg_expire_delta, _I32),
-        greg_duration=z if greg_duration is None else jnp.asarray(greg_duration, _I32),
-        occ=None if occ is None else jnp.asarray(occ, _I32),
-        write=None if write is None else jnp.asarray(write, bool),
-    )
 
 
 def apply_rounds32(
@@ -1027,6 +981,112 @@ def apply_rounds_packed_wide(
             write=(fl & 2) != 0,
         )
     return apply_rounds(state, req, rid, n_rounds, now_ms, cold_cond=cold_cond)
+
+
+# The per-lane wire: what a batch the dictionary cannot hold rides (more
+# than DICT_TABLE_ROWS configurations: a limit a key; more than 255
+# rounds; an `occ` past 65,535).  Six words a lane that are what they
+# are on either answer width, then the five values: a word each for the
+# narrow answer (narrow_ok: they fit), a lo/hi pair each for the wide.
+_LANE_SLOT, _LANE_FLAGS, _LANE_ALGO, _LANE_BEHAVIOR, _LANE_OCC, _LANE_RID = range(6)
+_LANE_VALUES = 6  # hits, limit, duration, greg_expire(_delta), greg_duration
+LANE_WIRE_WORDS = _LANE_VALUES + 5
+LANE_WIRE_WORDS_WIDE = _LANE_VALUES + 2 * 5
+
+
+def pack_lane_wire(slot, exists, write, occ, round_id, pos, values, wide: bool):
+    """Serialize one per-lane batch into a SINGLE i32 buffer, as
+    pack_dict_wire does for the dictionary wire and for the same
+    reason: a transfer call costs the host more than its bytes.  The
+    buffer is [S, words * P], column k of a shard at words
+    [kP, (k+1)P):
+
+      0  slot                     4  occ       (a whole word each: this
+      1  exists | write << 1      5  round id   wire is also the one for
+      2  algorithm                              occ > 65,535 and for
+      3  behavior                               more than 255 rounds)
+      6… hits, limit, duration, greg_expire, greg_duration: one word
+         each (LANE_WIRE_WORDS = 11) or, `wide`, lo then hi of each
+         (LANE_WIRE_WORDS_WIDE = 16)
+
+    `slot` … `round_id` are the plan's [S, P] arrays.  `values` are the
+    seven columns algorithm … greg_duration in REQUEST order and `pos`
+    each request's place in the flattened [S, P] plan; they are written
+    straight into the buffer's slices.  greg_expire is the delta from
+    now on the narrow wire (RequestBatch32) and the absolute time on
+    the wide one (RequestBatch).  Every value rides exactly; lanes no
+    request fills (slot -1) read zero."""
+    import numpy as np
+
+    S, P = slot.shape
+    words = LANE_WIRE_WORDS_WIDE if wide else LANE_WIRE_WORDS
+    w = np.zeros((S, words * P), dtype=np.int32)
+
+    def col(k):
+        return w[:, k * P:(k + 1) * P]
+
+    col(_LANE_SLOT)[:] = slot
+    col(_LANE_FLAGS)[:] = exists | (write << 1)
+    col(_LANE_OCC)[:] = occ
+    col(_LANE_RID)[:] = round_id
+    # Request i lies at word pos[i] of column 0 of ITS shard's row; a
+    # row is `words` columns long, so later shards shift by the rest.
+    flat = w.reshape(-1)
+    base = pos + (pos // P) * ((words - 1) * P)
+
+    def scatter(k, v):
+        flat[k * P:][base] = v
+
+    scatter(_LANE_ALGO, values[0])
+    scatter(_LANE_BEHAVIOR, values[1])
+    k = _LANE_VALUES
+    for v in values[2:]:
+        if wide:
+            scatter(k, (v & 0xFFFFFFFF).astype(np.uint32).view(np.int32))
+            scatter(k + 1, (v >> 32).astype(np.int32))
+            k += 2
+        else:
+            scatter(k, v)
+            k += 1
+    return w
+
+
+@jax.named_scope(SCOPE_WIRE_DECODE)
+def unpack_lane_wire(w, wide: bool):
+    """Device-side twin of pack_lane_wire for ONE shard row: returns
+    (RequestBatch32, round ids), or (RequestBatch, round ids) from the
+    wide buffer, its values composed to i64.  Slices and shifts only."""
+    P = w.shape[0] // (LANE_WIRE_WORDS_WIDE if wide else LANE_WIRE_WORDS)
+
+    def col(k):
+        return w[k * P:(k + 1) * P]
+
+    if wide:
+        values = [
+            _compose64(col(k), col(k + 1))
+            for k in range(_LANE_VALUES, LANE_WIRE_WORDS_WIDE, 2)
+        ]
+    else:
+        values = [col(k) for k in range(_LANE_VALUES, LANE_WIRE_WORDS)]
+    flags = col(_LANE_FLAGS)
+    make = RequestBatch if wide else RequestBatch32
+    req = make(
+        col(_LANE_SLOT), (flags & 1) != 0, col(_LANE_ALGO), col(_LANE_BEHAVIOR),
+        *values, occ=col(_LANE_OCC), write=(flags & 2) != 0,
+    )
+    return req, col(_LANE_RID)
+
+
+def apply_rounds_lanes(
+    state: BucketState, wire, n_rounds, now_ms, wide: bool = False,
+    cold_cond: bool = True,
+) -> "tuple[BucketState, jax.Array]":
+    """The rounds kernel behind the single-buffer per-lane wire:
+    apply_rounds32 and its packed i32[4, B] answer (host precondition:
+    narrow_ok) or, `wide`, apply_rounds and i64[4, B]."""
+    req, rid = unpack_lane_wire(wire, wide)
+    rounds = apply_rounds if wide else apply_rounds32
+    return rounds(state, req, rid, n_rounds, now_ms, cold_cond=cold_cond)
 
 
 def build_config_dict(cols, now_ms: int):

@@ -152,26 +152,24 @@ def _answer_rounds_jit(state, gcols, batch, extra, round_id, n_rounds, now):
     return jax.vmap(one)(state, gcols, batch, extra, round_id)
 
 
-@partial(jax.jit, donate_argnums=0)
-def _rounds32_mesh_jit(state, batch32, round_id, n_rounds, now):
-    """Narrow-wire fused rounds across all shards: the columnar ingress
-    kernel (no GLOBAL lanes, so gcols never ride the dispatch).  One
-    i32[S, 4, B] packed result."""
+def _rounds_lanes_mesh(state, wire, n_rounds, now, wide=False):
+    """Per-lane rounds behind the single-buffer wire ([S, 11P] i32, see
+    buckets.pack_lane_wire): what a batch the dictionary cannot hold
+    dispatches, and one sharded transfer like the dictionary's.  One
+    i32[S, 4, B] packed result; `wide` (values exceeding int32) reads
+    [S, 16P], lo/hi pairs, and answers i64[S, 4, B]."""
 
-    def one(state_s, batch_s, rid_s):
-        return buckets.apply_rounds32(state_s, batch_s, rid_s, n_rounds, now, cold_cond=False)
+    def one(state_s, w_s):
+        return buckets.apply_rounds_lanes(
+            state_s, w_s, n_rounds, now, wide=wide, cold_cond=False
+        )
 
-    return jax.vmap(one)(state, batch32, round_id)
+    return jax.vmap(one)(state, wire)
 
 
-@partial(jax.jit, donate_argnums=0)
-def _rounds64_mesh_jit(state, batch, round_id, n_rounds, now):
-    """Wide-wire twin of _rounds32_mesh_jit (values exceeding int32)."""
-
-    def one(state_s, batch_s, rid_s):
-        return buckets.apply_rounds(state_s, batch_s, rid_s, n_rounds, now, cold_cond=False)
-
-    return jax.vmap(one)(state, batch, round_id)
+_rounds_lanes_mesh_jit = jax.jit(
+    _rounds_lanes_mesh, donate_argnums=0, static_argnames="wide"
+)
 
 
 def _rounds_packed_mesh(state, wire, n_rounds, now):
@@ -858,11 +856,13 @@ class MeshBucketStore(ColumnarPipeline):
         batch of more (a limit a key), of more than 255 rounds, or with
         `force_wire` set ("narrow" / "wide": the PER-LANE wire, named by
         the answer width it pins; warm-up and tests use it) rides the
-        per-lane wire: a column a value, each put on the default device
-        and then placed on the mesh.  `dispatch.upload` times the
-        transfer calls of either; what the stage takes beyond it is the
-        encode.  The wire taken, the configurations counted and the
-        transfer calls made ride the _Staged into the mesh tally."""
+        per-lane wire: one i32 buffer too, of 11 words a lane (16 with
+        the i64 answer) and no table, and ONE transfer.  Either wire is
+        packed by numpy on the host and unpacked by slices inside the
+        jitted program.  `dispatch.upload` times the one transfer call;
+        what the stage takes beyond it is the encode.  The wire taken,
+        the configurations counted and the transfer calls made ride the
+        _Staged into the mesh tally."""
         cols, now_ms, padded = prep.cols, prep.now_ms, prep.padded
         mp, pos, n_rounds, narrow = prep.mp, prep.pos, prep.n_rounds, prep.narrow
         S = self.n_shards
@@ -905,42 +905,29 @@ class MeshBucketStore(ColumnarPipeline):
                 wire_dev=wire_dev, n_rounds=n_rounds, now_ms=now_ms,
                 wide=not narrow, config_rows=config_rows, uploads=1,
             )
-        # The per-lane wire: i32 columns for a narrow answer, i64 for a
-        # wide one.
-        vdt = np.int32 if narrow else np.int64
-
-        def scatter(col, dtype):
-            a = np.zeros((S, padded), dtype=dtype)
-            a.reshape(-1)[pos] = col
-            return a
-
+        # The per-lane wire: a word a value for a narrow answer, a lo/hi
+        # pair for a wide one, packed into one buffer like the
+        # dictionary's.
         if narrow:
             ge = np.where(
                 cols.greg_duration != 0, cols.greg_expire - now_ms, 0
             )
         else:
             ge = cols.greg_expire
-        mk = buckets.make_batch32 if narrow else buckets.make_batch
-        columns = (
-            mp.slot, mp.exists.astype(bool), scatter(cols.algo, np.int32),
-            scatter(cols.behavior, np.int32), scatter(cols.hits, vdt),
-            scatter(cols.limit, vdt), scatter(cols.duration, vdt),
-            scatter(ge, vdt), scatter(cols.greg_duration, vdt),
+        wire = buckets.pack_lane_wire(
+            mp.slot, mp.exists, mp.write, mp.occ, mp.rid, pos,
+            (cols.algo, cols.behavior, cols.hits, cols.limit, cols.duration,
+             ge, cols.greg_duration),
+            wide=not narrow,
         )
-        write = mp.write.astype(bool)
         with phase("dispatch.upload", prep.bt, wire="lanes"):
-            batch = mk(*columns, occ=mp.occ, write=write)
-            batch = jax.tree.map(lambda a: jax.device_put(a, self._sharding), batch)
-            rid_dev = jax.device_put(jnp.asarray(mp.rid), self._sharding)
-        fn = _rounds32_mesh_jit if narrow else _rounds64_mesh_jit
+            wire_dev = jax.device_put(wire, self._sharding)
         return _Staged(
-            solo=lambda state: fn(state, batch, rid_dev, n_rounds, now_ms),
+            solo=lambda state: _rounds_lanes_mesh_jit(
+                state, wire_dev, n_rounds, now_ms, wide=not narrow
+            ),
             wide=not narrow, lane_wire=True, config_rows=config_rows,
-            # Reckoned from the columns (a NamedTuple, none of them
-            # None here): `mk` puts each on the default device (a
-            # jnp.asarray), the tree.map places each on the mesh, and
-            # the round ids take the same two steps.
-            uploads=2 * len(batch) + 2,
+            uploads=1,
         )
 
     def _shard_fill(self, prep) -> Tuple[int, int]:

@@ -107,8 +107,8 @@ WATERFALL = (
     ("dispatch.plan_wait", 1),  # waiting for the plan lock
     ("dispatch.plan_native", 1),  # the C++ slot-table plan alone (begin + grouped plan)
     ("dispatch.stage", 0),    # wire encode + H2D upload start (stage 2)
-    ("dispatch.upload", 1),   # the stage's transfer calls alone: one on the
-                              # dictionary wire, two a column on the per-lane wire
+    ("dispatch.upload", 1),   # the stage's transfer call alone: one device_put
+                              # of one buffer, on either wire
     ("dispatch.gate_wait", 0),  # waiting for the ticket's launch turn
     ("dispatch.launch", 0),   # ticket-ordered jit call (stage 3)
     ("dispatch.launch_wait", 1),  # waiting for the store lock
@@ -324,7 +324,7 @@ class MeshTally:
     And which wire carried it (MeshBucketStore._stage_columns): a
     dispatch of at most 256 distinct configurations rides the dictionary
     wire (one i32 buffer, one transfer); one of more rides the per-lane
-    wire (a column a value, a transfer or two a column) and counts under
+    wire (a word a value, one buffer and one transfer too) and counts under
     `laneWireDispatches` / `laneWireLanes`, so the dictionary's share is
     the difference from `dispatches` / `lanes`.  `configRows` sums the
     distinct configurations buckets.build_config_dict counted (0 where

@@ -359,7 +359,13 @@ def test_debug_incident_endpoint_and_surfaces(tmp_path):
         assert manifest["triggers"][0]["kind"] == "manual"
         assert manifest["service"]["advertiseAddress"] == "bbtest:0"
         # debug_status carries the blackbox section cluster_status reads.
+        # The bundle is on disk (renamed, its directory fsynced) before the
+        # writer's thread counts it: give the count the moment it needs.
+        deadline = time.monotonic() + 10.0
         snap = svc.debug_status()["blackbox"]
+        while snap["bundles"] < 1 and time.monotonic() < deadline:
+            time.sleep(0.02)
+            snap = svc.debug_status()["blackbox"]
         assert snap["enabled"] and snap["bundles"] >= 1
         assert snap["ringBudgetBytes"] > 0
         # /metrics: the gubernator_blackbox_* families render.

@@ -115,17 +115,15 @@ def test_fused_k2_compiles_for_one_v5e(one_chip):
 
 
 def test_narrow_wire_compiles_for_one_v5e(one_chip):
-    """The per-lane narrow wire: the fall-back of the dict wire, and what
-    warmup compiles beside it."""
-    z = np.zeros((1, LANES), np.int32)
-    batch = buckets.make_batch32(
-        z, z.astype(bool), z, z, z, z, z, z, z, occ=z, write=z.astype(bool)
+    """The per-lane wire with the narrow answer, one buffer of eleven words a
+    lane: the fall-back of the dict wire (a limit a key), and what warmup
+    compiles beside it."""
+    wire = _sharded(
+        one_chip, jax.ShapeDtypeStruct((buckets.LANE_WIRE_WORDS * LANES,), jnp.int32)
     )
-    batch = jax.tree.map(lambda a: _sharded(one_chip, jax.ShapeDtypeStruct(a.shape[1:], a.dtype)), batch)
-    round_id = _sharded(one_chip, jax.ShapeDtypeStruct((LANES,), jnp.int32))
     _compile(
-        "narrow wire, 1M slots x 4096 lanes, one chip",
-        mesh_mod._rounds32_mesh_jit.lower(_state(one_chip), batch, round_id, 1, NOW_MS),
+        "per-lane wire, 1M slots x 4096 lanes, one chip",
+        mesh_mod._rounds_lanes_mesh_jit.lower(_state(one_chip), wire, 1, NOW_MS),
     )
 
 

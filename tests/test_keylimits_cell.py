@@ -58,9 +58,10 @@ CELL = "v5e1-1m-keylimits.frames"
 TWIN = "v5e1-1m.frames"
 SHARDS = [1, 4]
 WIRE_COUNTERS = ("dispatches", "lanes", "laneWireDispatches", "laneWireLanes", "configRows", "uploads")
-# A column is put on the default device and then placed on the mesh; so are
-# the round ids: RequestBatch32's eleven columns make 24 transfer calls.
-LANE_WIRE_UPLOADS = 24
+# One packed buffer, one device_put: the per-lane wire's stage makes the one
+# transfer call the dictionary wire's makes (tests/test_lane_wire.py counts
+# the real calls).
+LANE_WIRE_UPLOADS = 1
 LABEL_LANES = "mesh:dispatch:solo:lanes"
 LABEL_DICT = "mesh:dispatch:solo:narrow"
 

@@ -1,0 +1,216 @@
+"""The per-lane wire as ONE buffer (`buckets.pack_lane_wire` on the host,
+`buckets.unpack_lane_wire` inside the jitted program): what the host packs
+the device unpacks bit for bit, on one device and on four shards, at the
+smallest pad bucket and at the cell's, for the i32 and the i64 answer; the
+program behind it answers what the rounds kernel answers over the same
+columns handed to it directly; and a staged batch makes the transfer calls
+`_Staged.uploads` says it made, counted from JAX and not by hand."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from gubernator_tpu import native
+from gubernator_tpu.ops import buckets
+from gubernator_tpu.parallel import mesh as mesh_mod
+
+from .conftest import _store_over
+
+SEED = 34
+NOW = 1_790_000_000_000
+I32_MAX = 2**31 - 1
+VALUE_NAMES = ("algorithm", "behavior", "hits", "limit", "duration", "greg_expire", "greg_duration")
+
+
+def _plan(shards: int, pad: int, wide: bool):
+    """A plan's arrays as NativeMeshPlanner fills them ([S, P]; slot -1
+    where no request lies) and the seven value columns in REQUEST order,
+    with the values this wire exists for among the lanes."""
+    rng = np.random.default_rng([SEED, shards, pad, wide])
+    n = (shards * pad * 3) // 4
+    pos = rng.permutation(shards * pad)[:n].astype(np.int64)
+    slot = np.full((shards, pad), -1, np.int32)
+    slot.reshape(-1)[pos] = rng.integers(0, 1 << 20, n)
+    exists = np.zeros((shards, pad), np.uint8)
+    exists.reshape(-1)[pos] = rng.integers(0, 2, n)
+    write = np.zeros((shards, pad), np.uint8)
+    write.reshape(-1)[pos] = rng.integers(0, 2, n)
+    occ = np.zeros((shards, pad), np.int32)
+    occ.reshape(-1)[pos] = rng.integers(0, 1000, n)
+    rid = np.zeros((shards, pad), np.int32)
+    rid.reshape(-1)[pos] = rng.integers(0, 256, n)
+    occ.reshape(-1)[pos[0]] = 70_000  # past the dictionary wire's u16
+    rid.reshape(-1)[pos[1]] = 300  # past its u8
+    top = 2**62 if wide else I32_MAX
+    values = {
+        "algorithm": rng.integers(0, 2, n).astype(np.int32),
+        "behavior": rng.integers(0, 64, n).astype(np.int32),
+        "hits": rng.integers(0, top, n),
+        "limit": rng.integers(0, top, n),
+        "duration": rng.integers(0, top, n),
+        "greg_expire": rng.integers(0, top, n),
+        "greg_duration": rng.integers(0, top, n),
+    }
+    values["limit"][2] = I32_MAX
+    values["greg_expire"][3] = -5  # a negative delta rides as it is
+    values["behavior"][4] = -(2**31)  # a whole word, not a few bits of one
+    if wide:
+        values["hits"][5] = 2**63 - 1
+        values["duration"][6] = -(2**63)
+        values["limit"][7] = 2**32  # lo word all zero
+        values["greg_duration"][8] = 2**31  # lo word's sign bit alone
+    return dict(slot=slot, exists=exists, write=write, occ=occ, rid=rid, pos=pos, values=values)
+
+
+def _sharding(shards: int) -> NamedSharding:
+    return NamedSharding(Mesh(np.array(jax.devices()[:shards]), ("shard",)), P("shard"))
+
+
+def _placed(shape, pos, col, dtype):
+    """A request-order column where the plan puts it: [S, P], zero elsewhere."""
+    a = np.zeros(shape, dtype)
+    a.reshape(-1)[pos] = col
+    return a
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("pad", [64, 4096])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_what_the_host_packs_the_device_unpacks_bit_for_bit(shards, pad, wide):
+    plan = _plan(shards, pad, wide)
+    wire = buckets.pack_lane_wire(
+        plan["slot"], plan["exists"], plan["write"], plan["occ"], plan["rid"], plan["pos"],
+        tuple(plan["values"][k] for k in VALUE_NAMES), wide=wide)
+    words = buckets.LANE_WIRE_WORDS_WIDE if wide else buckets.LANE_WIRE_WORDS
+    assert (words, buckets.LANE_WIRE_WORDS) == (16 if wide else 11, 11)
+    assert wire.dtype == np.int32 and wire.shape == (shards, words * pad)
+
+    sharding = _sharding(shards)
+    unpack = jax.jit(jax.vmap(lambda w: buckets.unpack_lane_wire(w, wide)))
+    req, rid = unpack(jax.device_put(wire, sharding))
+    assert type(req) is (buckets.RequestBatch if wide else buckets.RequestBatch32)
+
+    vdt = np.int64 if wide else np.int32
+    place = lambda k, dtype: _placed((shards, pad), plan["pos"], plan["values"][k], dtype)  # noqa: E731
+    want = {
+        "slot": plan["slot"], "exists": plan["exists"].astype(bool),
+        "write": plan["write"].astype(bool), "occ": plan["occ"],
+        "algorithm": place("algorithm", np.int32), "behavior": place("behavior", np.int32),
+        **{k: place(k, vdt) for k in VALUE_NAMES[2:]},
+    }
+    if not wide:
+        want["greg_expire_delta"] = want.pop("greg_expire")
+    assert set(want) == set(req._fields)
+    for name, col in want.items():
+        got = np.asarray(getattr(req, name))
+        assert got.dtype == col.dtype and got.shape == col.shape, name
+        assert (got == col).all(), name
+    assert (np.asarray(rid) == plan["rid"]).all() and np.asarray(rid).dtype == np.int32
+    flat = lambda a: a.reshape(-1)[plan["pos"]]  # noqa: E731
+    assert flat(want["occ"])[0] == 70_000 and flat(plan["rid"])[1] == 300
+    assert flat(want["limit"])[2] == I32_MAX
+    assert flat(np.asarray(getattr(req, "greg_expire" if wide else "greg_expire_delta")))[3] == -5
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_program_behind_the_buffer_answers_as_the_kernel_over_the_columns(shards, wide):
+    """`_rounds_lanes_mesh` (pack, one buffer, unpack inside the jit) against
+    `apply_rounds32` / `apply_rounds` handed the same columns as device
+    arrays, a column a value: the same answers and the same table, over
+    three rounds of duplicates."""
+    pad, slots = 64, 256
+    rng = np.random.default_rng([SEED, shards, wide, 1])
+    n = shards * pad - 7
+    pos = np.sort(rng.permutation(shards * pad)[:n]).astype(np.int64)
+    place = lambda col, dtype: _placed((shards, pad), pos, col, dtype)  # noqa: E731
+    # Slots distinct within a round: round r of a shard owns slots r, r+3, ...
+    rid_req = rng.integers(0, 3, n)
+    slot_req = np.empty(n, np.int64)
+    for s in range(shards):
+        for r in range(3):
+            mine = np.flatnonzero((pos // pad == s) & (rid_req == r))
+            slot_req[mine] = r + 3 * rng.permutation(slots // 3)[: len(mine)]
+    slot = np.full((shards, pad), -1, np.int32)
+    slot.reshape(-1)[pos] = slot_req
+    rid = place(rid_req, np.int32)
+    zeros8 = np.zeros((shards, pad), np.uint8)
+    write = place(np.ones(n), np.uint8)
+    occ = np.zeros((shards, pad), np.int32)
+    big = 2**40 if wide else 1
+    values = (
+        rng.integers(0, 2, n).astype(np.int32), np.zeros(n, np.int32),
+        rng.integers(0, 5, n), rng.integers(1, 50, n) * big,
+        np.full(n, 60_000, np.int64), np.zeros(n, np.int64), np.zeros(n, np.int64),
+    )
+    wire = buckets.pack_lane_wire(slot, zeros8, write, occ, rid, pos, values, wide=wide)
+
+    vdt = np.int64 if wide else np.int32
+    make = buckets.RequestBatch if wide else buckets.RequestBatch32
+    req = make(
+        slot, zeros8.astype(bool), place(values[0], np.int32), place(values[1], np.int32),
+        *(place(v, vdt) for v in values[2:]), occ=occ, write=write.astype(bool))
+    rounds = buckets.apply_rounds if wide else buckets.apply_rounds32
+    direct = jax.jit(jax.vmap(
+        lambda st, rq, rd: rounds(st, rq, rd, 3, NOW, cold_cond=False)))
+
+    sharding = _sharding(shards)
+    put = lambda tree: jax.device_put(tree, sharding)  # noqa: E731
+    fresh = lambda: put(jax.vmap(lambda _: buckets.init_state(slots))(jnp.arange(shards)))  # noqa: E731
+    want_state, want = direct(fresh(), put(req), put(rid))
+    got_state, got = mesh_mod._rounds_lanes_mesh_jit(fresh(), put(wire), 3, NOW, wide=wide)
+    assert got.dtype == want.dtype == vdt and got.shape == (shards, 4, pad)
+    assert (np.asarray(got) == np.asarray(want)).all()
+    assert np.asarray(got)[:, 1].max() > (2**32 if wide else 0)  # `remaining`: real answers
+    for a, b in zip(jax.tree.leaves(got_state), jax.tree.leaves(want_state)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+def _frame(lanes: int, configurations: int):
+    keys = [f"lw_{i}" for i in range(lanes)]
+    limit = 100 + np.arange(lanes, dtype=np.int64) % configurations
+    return keys, limit
+
+
+@pytest.mark.skipif(not native.available(), reason="the columnar path needs the native host runtime")
+@pytest.mark.parametrize("wire", ["narrow", "wide", "too-many-rows", "dictionary"])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_a_staged_batch_makes_the_transfer_calls_it_counts(shards, wire, monkeypatch):
+    """`_Staged.uploads` feeds `wire.uploads_per_dispatch`; it is held here to
+    the calls the stage really makes: every `jax.device_put` and every
+    `jnp.asarray` between the plan and the launch."""
+    store = _store_over(shards, 2048)
+    lanes = 512
+    keys, limit = _frame(lanes, 16 if wire == "dictionary" else 300)
+    force = wire if wire in ("narrow", "wide") else None
+    staged, calls = [], []
+    real_stage, real_put, real_asarray = store._stage_columns, jax.device_put, jnp.asarray
+
+    def counting(name, real):
+        def call(*a, **kw):
+            calls.append(name)
+            return real(*a, **kw)
+        return call
+
+    def stage(prep):
+        with monkeypatch.context() as m:
+            m.setattr(jax, "device_put", counting("device_put", real_put))
+            m.setattr(jnp, "asarray", counting("asarray", real_asarray))
+            staged.append(real_stage(prep))
+        return staged[-1]
+
+    monkeypatch.setattr(store, "_stage_columns", stage)
+    got = store.apply_columns(
+        keys, np.zeros(lanes, np.int32), np.zeros(lanes, np.int32), np.ones(lanes, np.int64),
+        limit, np.full(lanes, 60_000, np.int64), NOW, force_wire=force)
+    assert (got["remaining"] == limit - 1).all()  # the real stage ran, and answered
+    (st,) = staged
+    assert st.lane_wire == (wire != "dictionary")
+    assert st.wide == (wire == "wide")
+    assert st.config_rows == {"too-many-rows": 300, "dictionary": 16}.get(wire, 0)
+    assert calls == ["device_put"]
+    assert st.uploads == len(calls)
