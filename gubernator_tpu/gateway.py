@@ -743,6 +743,28 @@ def _error_triplet(e: BaseException):
     )
 
 
+def observe_edge_sends(edges) -> list:
+    """Drain the native edges' answered requests and observe `edge.send`
+    (the answer handed to the acceptor -> its last byte accepted by the
+    kernel) once each.  Returns the [token, t_staged, t_last_byte]
+    records, for a caller that also puts them into a trace."""
+    sends = [rec for e in edges for rec in e.drain_sends()]
+    if sends:
+        saturation.observe_phases(
+            "edge.send", [(last - staged) / 1e9 for _, staged, last in sends])
+    return sends
+
+
+def observe_edge_arrivals(stamps) -> None:
+    """`edge.recv` and `edge.handoff` of requests by the C++ edge's
+    stamps, rows of (t_first_byte, t_body, arrival): an observation a
+    request, the lock of each phase taken once."""
+    saturation.observe_phases(
+        "edge.recv", [(body - first) / 1e9 for first, body, _ in stamps])
+    saturation.observe_phases(
+        "edge.handoff", [(arrival - body) / 1e9 for _, body, arrival in stamps])
+
+
 def handle_request_async(service: V1Service, method: str, path: str,
                          raw: bytes, respond, headers=None) -> None:
     """Async twin of handle_request for the device-bound POST paths:
@@ -1093,6 +1115,7 @@ class NativeIngressPump:
                 tb = batcher.take(self.take_lanes, timeout_ms=200)
             if tb is None:
                 self._surface_stats()
+                self._edge_sends()  # the last take's answer, once idle
                 if batcher.stopped:
                     return
                 continue
@@ -1153,7 +1176,10 @@ class NativeIngressPump:
         the attribution of what C++ timed, then ONE columnar
         dispatch."""
         svc = self.service
-        with phase("pump.admit", bt, frames=tb.n_frames, lanes=tb.n):
+        with phase("pump.admit", bt, frames=tb.n_frames, lanes=tb.n) as ph:
+            # The anchor that ties the C++ edge's stamps to the trace's
+            # clock is read beside the event's start (_trace_edge).
+            anchor_ns = time.monotonic_ns() if ph.traced else 0
             self._surface_stats()
             bb = getattr(svc, "blackbox", None)
             if bb is not None:
@@ -1173,6 +1199,13 @@ class NativeIngressPump:
             saturation.observe_phase("ingress.parse", tb.parse_ns_total / 1e9 / nf)
             for age_us in tb.frame_age_us:
                 saturation.observe_phase("batch.window", float(age_us) / 1e6)
+            # The C++ edge's stamps are observed after the answers have
+            # left (`pump.account`); only a take that is being traced
+            # reads them here, where its event is open.
+            if bt is not None or ph.traced:
+                stamps = tb.frame_stamps.tolist()
+                take_ns = stamps[0][3] + int(tb.frame_age_us[0]) * 1000
+                self._trace_edge(ph, bt, anchor_ns, take_ns, stamps)
             if bt is not None:
                 now = time.monotonic_ns()
                 tracing.record_span(
@@ -1194,6 +1227,37 @@ class NativeIngressPump:
             tracing.take_batch_trace()
         return tb, handle, tenant_ctx, t0, bt
 
+    @staticmethod
+    def _trace_edge(ph, bt, anchor_ns, take_ns, stamps) -> None:
+        """The edge's way in of a take that is being traced.  Sampled
+        (`bt`): a span each under the take's trace, carrying the frame's
+        token, which the take's `pump.admit` span lists beside the ids
+        its dispatch spans share.  In a profiler session: the stamps as
+        metadata of the `pump.admit` event (saturation.edge_trace_note),
+        for a reader to rebuild on the trace's clock."""
+        if bt is not None:
+            ph.note(tokens=";".join(str(s[0]) for s in stamps))
+            for token, t_first_byte, t_body, t_arrival in stamps:
+                tracing.batch_span("edge.recv", bt, t_first_byte, t_body, token=token)
+                tracing.batch_span("edge.handoff", bt, t_body, t_arrival, token=token)
+        if ph.traced:
+            ph.note(**saturation.edge_trace_note(anchor_ns, take_ns, stamps))
+
+    def _edge_sends(self, ph=None, bt=None, anchor_ns=0) -> None:
+        """The edge's way out: `edge.send` of the answers whose last byte
+        has left since the last drain by any thread (earlier takes', as a
+        rule; the token says whose).  Inside a take's `pump.account`
+        (`ph`) that is being traced they also become spans under `bt` and
+        metadata of the event, as in `_trace_edge`."""
+        sends = observe_edge_sends(getattr(self.service, "native_edges", ()))
+        if not sends or ph is None:
+            return
+        if bt is not None:
+            for token, t_staged, t_last_byte in sends:
+                tracing.batch_span("edge.send", bt, t_staged, t_last_byte, token=token)
+        if ph.traced:
+            ph.note(**saturation.edge_trace_note(anchor_ns, sends=sends))
+
     def _complete(self, tb, handle, tenant_ctx, t0, bt, t_handoff) -> None:
         # pump.handoff: queued behind the done pool's two workers.  It
         # crosses threads, so it is read from the pump's stamp.
@@ -1209,6 +1273,7 @@ class NativeIngressPump:
                     # Copies of everything needed past complete() — the
                     # batch's views die inside it.
                     ages_s = tb.frame_age_us.astype(np.float64) / 1e6
+                    edge_stamps = tb.frame_stamps[:, 1:].tolist()
                     result = ColumnarResult(
                         n=tb.n,
                         status=np.asarray(out["status"], dtype=np.int32),
@@ -1227,7 +1292,8 @@ class NativeIngressPump:
                     )
                 # pump.account: the answers have left; what follows only
                 # holds this take's slot of the pipeline-depth semaphore.
-                with phase("pump.account", bt):
+                with phase("pump.account", bt) as ph:
+                    anchor_ns = time.monotonic_ns() if ph.traced else 0
                     dt_disp = time.perf_counter() - t0
                     m.ingress_columns_batches.labels(encoding="frame").inc(nf)
                     m.request_counts.labels(status="0", method=rpc).inc(nf)
@@ -1236,6 +1302,14 @@ class NativeIngressPump:
                         dt = float(age) + dt_disp
                         duration.observe(dt)
                         m.observe_latency(rpc, dt)
+                    # The C++ edge's stamps of each frame: its socket
+                    # reads, and its way from the acceptor to the worker's
+                    # submit (`arrival`, where batch.window starts; the
+                    # native parse lies inside that window); and the
+                    # answers whose last byte has left, earlier takes' as
+                    # a rule.  Here, off the frame's way through the pump.
+                    observe_edge_arrivals(edge_stamps)
+                    self._edge_sends(ph, bt, anchor_ns)
             except BaseException as e:  # noqa: BLE001
                 self._fail(tb, e)
         finally:
@@ -1293,6 +1367,9 @@ class NativeGatewayServer:
     # ingress queue, not this pool — a handful of workers keeps the
     # submit path fed even on a 1-core host.
     N_WORKERS = 4
+    # JSON requests a worker gathers before it observes their edge
+    # phases together (_flush_edge); an idle worker flushes at once.
+    EDGE_FLUSH = 32
 
     def __init__(self, service: V1Service, listen_address: str = "127.0.0.1:0",
                  n_workers: "Optional[int]" = None, acceptors: int = 1,
@@ -1344,27 +1421,42 @@ class NativeGatewayServer:
         from .native import FAST_LANE
 
         edge, service = self._edge, self.service
+        # The C++ edge's stamps of the requests this worker has taken on
+        # the JSON path, rows of (t_first_byte, t_body, arrival), observed
+        # together once EDGE_FLUSH have gathered or the worker idles: on
+        # this path the interpreter is the limit, and an observation a
+        # phase a request, taken singly, is interpreter time.
+        arrivals: list = []
         while not self._stopped.is_set():
             # The native fast lane: when the pump is attached, a kind-5
             # ingress frame is validated/hashed/routed/enqueued INSIDE
-            # edge.next (one GIL-released native call) and this worker
-            # never sees its bytes — Python's per-frame cost is the
-            # token round trip.  Fallback reasons fall through to the
-            # unchanged path below.
+            # edge.next and this worker never sees its bytes.  That is
+            # two GIL-released native calls with the interpreter between
+            # them (gt_http_next, the body's sniff in Python, then
+            # gt_ingress_submit): Python's per-frame cost is the token
+            # round trip and that wake-up, which `edge.handoff` measures.
+            # Fallback reasons fall through to the unchanged path below.
             pump = self.pump
             ingress = pump.batcher if pump is not None and pump.active else None
             # Time blocked in the native queue pull (the GIL is released
             # inside edge.next) is epoll.wait — the "GIL-idle in epoll"
             # answer, distinct from parse work: this worker has no request.
+            # (The socket's reads and writes are the acceptor threads':
+            # edge.recv and edge.send.)
             with phase("epoll.wait"):
                 got = edge.next(timeout_ms=200, ingress=ingress)
             if got is None:
+                self._flush_edge(arrivals)
                 if edge.stopped:
                     return
                 continue
             if got is FAST_LANE:
                 continue
-            token, method, path, body = got
+            token, method, path, body, (t_first_byte, t_body) = got
+            # `arrival` on this path: a worker holds the request, here.
+            arrivals.append((t_first_byte, t_body, time.monotonic_ns()))
+            if len(arrivals) >= self.EDGE_FLUSH:
+                self._flush_edge(arrivals)
             if getattr(service, "_closed", False):
                 edge.respond(token, 503, b'{"code": 14, "message": "shutting down"}')
                 continue
@@ -1373,6 +1465,18 @@ class NativeGatewayServer:
             handle_request_async(
                 service, method, path, body, partial(self._respond, token)
             )
+        self._flush_edge(arrivals)
+
+    def _flush_edge(self, arrivals: list) -> None:
+        """Observe `edge.recv` and `edge.handoff` of the gathered
+        requests and empty the list; and, as the JSON path has no take
+        to drain the edge's send ring in, `edge.send` of the answers that
+        have left.  A worker that gathered nothing leaves the ring alone:
+        its records are the native pump's to read (and to trace)."""
+        if arrivals:
+            observe_edge_arrivals(arrivals)
+            arrivals.clear()
+            observe_edge_sends((self._edge,))
 
     def _respond(self, token: int, status: int, ctype: str,
                  payload: bytes) -> None:

@@ -241,6 +241,11 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.gt_http_acceptor_count.restype = c.c_int
     lib.gt_http_acceptor_count.argtypes = [c.c_void_p]
     lib.gt_http_acceptor_stats.argtypes = [c.c_void_p, c.c_void_p]
+    lib.gt_http_stats.argtypes = [c.c_void_p, c.c_void_p]
+    lib.gt_http_drain_sends.restype = c.c_int64
+    lib.gt_http_drain_sends.argtypes = [c.c_void_p, c.c_void_p, c.c_int64]
+    lib.gt_mono_ns.restype = c.c_int64
+    lib.gt_mono_ns.argtypes = []
     lib.gt_http_next.restype = c.c_int
     lib.gt_http_next.argtypes = [c.c_void_p, c.c_int64, c.c_void_p]
     lib.gt_http_respond.argtypes = [
@@ -1067,6 +1072,8 @@ class _GtHttpReq(ctypes.Structure):
         ("body_len", ctypes.c_int64),
         ("path", ctypes.c_char_p),
         ("body", ctypes.POINTER(ctypes.c_char)),
+        ("t_first_byte_ns", ctypes.c_int64),
+        ("t_body_ns", ctypes.c_int64),
     ]
 
 
@@ -1118,6 +1125,9 @@ class HttpEdge:
         self.stopped = False
         self._freed = False
         self._stop_lock = threading.Lock()
+        # drain_sends' one buffer, and the lock that lets any thread use it.
+        self._drain_buf = np.empty(self._DRAIN_ROWS * 3, dtype=np.int64)
+        self._drain_lock = threading.Lock()
 
     def acceptor_stats(self):
         """Per-acceptor counters: list of dicts {uds, accepted,
@@ -1136,17 +1146,59 @@ class HttpEdge:
             for i in range(n)
         ]
 
+    STAT_KEYS = ("reads", "readBytes", "sends", "sendBytes", "epolloutRounds",
+                 "requests", "sendRingDropped")
+    _DRAIN_ROWS = 256
+
+    def stats(self) -> dict:
+        """The socket's own work, summed over the acceptors
+        (`/debug/status` `edge`), all cumulative: `reads`, `readBytes`,
+        `sends`, `sendBytes`, `epolloutRounds`, `requests` (reads and
+        sends a request are these over `requests`), and
+        `sendRingDropped`: answered requests that fell off the send
+        ring's bound before `drain_sends` took them, by which many
+        `edge.send` is under-observed.  A freed edge reads as zeros."""
+        out = np.zeros(len(self.STAT_KEYS), dtype=np.int64)
+        if self._ptr is not None:
+            self._lib.gt_http_stats(self._ptr, out.ctypes.data)
+        return dict(zip(self.STAT_KEYS, out.tolist()))
+
+    def drain_sends(self) -> list:
+        """[[token, t_staged_ns, t_last_byte_ns]] of the requests whose
+        answer's last byte the kernel has accepted since the last call,
+        oldest first, on the clock of time.monotonic_ns() (`edge.send` is
+        the difference).  Each record is handed out once, to whichever
+        thread asks first; an empty ring costs one native call that
+        takes no lock of the server's."""
+        out: list = []
+        with self._drain_lock:
+            buf = self._drain_buf
+            while self._ptr is not None:
+                n = int(self._lib.gt_http_drain_sends(
+                    self._ptr, buf.ctypes.data, self._DRAIN_ROWS
+                ))
+                out += buf[: n * 3].reshape(-1, 3).tolist()
+                if n < self._DRAIN_ROWS:
+                    break
+        return out
+
     def next(self, timeout_ms: int = 200, ingress=None):
         """Blocks up to timeout_ms for one parsed request.  Returns
-        (token, method, path, body_bytes), None (timeout/stopping), or
-        FAST_LANE when `ingress` (an IngressBatcher) consumed the
-        request natively — a POST /v1/GetRateLimits whose body sniffs
-        as a kind-5 frame goes through gt_ingress_submit WITHOUT
-        copying the body into Python; any fallback reason (malformed,
-        slow lanes, remote owners, disabled) falls through to the
-        ordinary copy-out so the Python path serves it unchanged.
-        The copied body means the token may be answered from any
-        thread at any later time."""
+        (token, method, path, body_bytes, (t_first_byte_ns, t_body_ns)),
+        None (timeout/stopping), or FAST_LANE when `ingress` (an
+        IngressBatcher) consumed the request natively — a POST
+        /v1/GetRateLimits whose body sniffs as a kind-5 frame goes
+        through gt_ingress_submit WITHOUT copying the body into Python;
+        any fallback reason (malformed, slow lanes, remote owners,
+        disabled) falls through to the ordinary copy-out so the Python
+        path serves it unchanged.  That is two GIL-released native calls
+        with the interpreter between them: gt_http_next hands the request
+        over, this thread takes the interpreter back to sniff the body,
+        then gt_ingress_submit parses and enqueues (`edge.handoff` runs
+        from the body's last byte to that submit's entry).  The stamps
+        are the C++ edge's, on the clock of time.monotonic_ns().  The
+        copied body means the token may be answered from any thread at
+        any later time."""
         if self.stopped:
             return None
         req = _GtHttpReq()
@@ -1167,7 +1219,7 @@ class HttpEdge:
         method = _HTTP_METHODS.get(req.method, "OTHER")
         path = req.path.decode("utf-8", "replace") if req.path else ""
         body = ctypes.string_at(req.body, req.body_len) if req.body_len else b""
-        return req.token, method, path, body
+        return req.token, method, path, body, (req.t_first_byte_ns, req.t_body_ns)
 
     def respond(self, token: int, status: int, body: bytes,
                 reason: str = "OK", content_type: str = "application/json"):
@@ -1219,6 +1271,7 @@ class _GtTakenInfo(ctypes.Structure):
         ("uk_bytes", ctypes.c_int64),
         ("frame_lanes", ctypes.POINTER(ctypes.c_int64)),
         ("frame_age_us", ctypes.POINTER(ctypes.c_int64)),
+        ("frame_stamps", ctypes.POINTER(ctypes.c_int64)),
         ("parse_ns_total", ctypes.c_int64),
         ("hits_total", ctypes.c_int64),
     ]
@@ -1248,8 +1301,8 @@ class IngressTakenBatch:
 
     __slots__ = ("_ptr", "n", "n_frames", "algorithm", "behavior", "hits",
                  "limit", "duration", "hash_keys", "hashes", "frame_lanes",
-                 "frame_age_us", "parse_ns_total", "hits_total", "_nb", "_no",
-                 "_ub", "_uo", "trace_ctx")
+                 "frame_age_us", "frame_stamps", "parse_ns_total",
+                 "hits_total", "_nb", "_no", "_ub", "_uo", "trace_ctx")
 
     def __init__(self, ptr, info: _GtTakenInfo):
         self._ptr = ptr
@@ -1272,6 +1325,12 @@ class IngressTakenBatch:
         self._uo = _view(info.uk_off, n + 1, np.int64)
         self.frame_lanes = _view(info.frame_lanes, self.n_frames, np.int64)
         self.frame_age_us = _view(info.frame_age_us, self.n_frames, np.int64)
+        # A row a frame: (token, t_first_byte, t_body, arrival), the C++
+        # edge's stamps on the clock of time.monotonic_ns(); a frame's
+        # arrival plus its age is the take's own clock reading.
+        self.frame_stamps = _view(
+            info.frame_stamps, self.n_frames * 4, np.int64
+        ).reshape(-1, 4)
         self.parse_ns_total = int(info.parse_ns_total)
         self.hits_total = int(info.hits_total)
         self.trace_ctx = None  # fast lane never carries sampled frames

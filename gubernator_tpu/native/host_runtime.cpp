@@ -2217,6 +2217,19 @@ namespace {
 constexpr size_t kMaxHeaderBytes = 64 * 1024;
 constexpr size_t kMaxBodyBytes = 32 * 1024 * 1024;  // > 1000-lane batches
 constexpr size_t kMaxReadyQueue = 4096;
+// Answers whose last byte the kernel has accepted, kept until Python
+// drains them (gt_http_drain_sends); the oldest is dropped beyond this.
+constexpr size_t kMaxSendRing = 4096;
+
+// The edge's clock: steady_clock is CLOCK_MONOTONIC here, the clock of
+// Python's time.monotonic_ns() (gt_mono_ns lets a test hold that), so a
+// stamp taken in C++ subtracts from one taken in Python.
+inline int64_t ns_of(std::chrono::steady_clock::time_point t) {
+  return (int64_t)std::chrono::duration_cast<std::chrono::nanoseconds>(
+             t.time_since_epoch())
+      .count();
+}
+inline int64_t mono_ns() { return ns_of(std::chrono::steady_clock::now()); }
 
 struct HttpServer;
 struct HttpAcceptor;
@@ -2229,21 +2242,57 @@ struct HttpPending {
   bool keep_alive;
   std::string path;
   std::string body;
+  // Stamps of the edge (mono_ns): the read that brought the request's
+  // first byte, and the moment its last body byte was framed.
+  int64_t t_first_byte = 0, t_body = 0;
+};
+
+// One finished response on its way out: the bytes, and when they were
+// handed to the acceptor (0 = answered inside the loop: no send record).
+struct HttpResponse {
+  std::string bytes;
+  int64_t t_staged = 0;
+};
+
+// Where one response ends inside HttpConn::out, so that the send which
+// passes `end` can stamp that response's last byte.
+struct HttpOutMark {
+  size_t end;
+  uint64_t token;
+  int64_t t_staged;
+};
+
+// (token, t_staged, t_last_byte) of one answered request: edge.send.
+struct HttpSendRec {
+  uint64_t token;
+  int64_t t_staged, t_last_byte;
+};
+
+// A read of one wakeup: the bytes of HttpConn::in up to `end` had
+// arrived by `t`.
+struct HttpReadStamp {
+  size_t end;
+  int64_t t;
 };
 
 struct HttpConn {
   int fd = -1;
   HttpAcceptor* acc = nullptr;
   std::string in;
+  // The read that brought the first byte `in` holds (the head request's
+  // t_first_byte); meaningless while `in` is empty.
+  int64_t t_first_byte = 0;
   // parsed-but-unanswered request count (pipelined clients): responses
   // write in arrival order because tokens are handed out in order and
   // the out buffer is appended in respond order per connection --
   // workers MAY finish out of order, so per-conn ordering is enforced
   // by queueing responses by token sequence.
   std::deque<uint64_t> awaiting;          // tokens awaiting response
-  std::unordered_map<uint64_t, std::string> done;  // token -> response
+  std::unordered_map<uint64_t, HttpResponse> done;  // token -> response
   std::string out;
   size_t out_off = 0;
+  std::deque<HttpOutMark> marks;  // ends of the responses `out` holds
+
   bool want_close = false;
   // Read side hit EOF (client close or shutdown(SHUT_WR)): stop
   // watching EPOLLIN — level-triggered EOF would otherwise re-fire
@@ -2271,11 +2320,15 @@ struct HttpAcceptor {
   std::thread loop;
   std::unordered_map<int, HttpConn*> conns;  // guarded by srv->mu
   // responses staged by Python / the fast lane, drained by this loop
-  std::deque<std::pair<uint64_t, std::string>> resp_queue;  // srv->mu
+  std::deque<std::pair<uint64_t, HttpResponse>> resp_queue;  // srv->mu
   // stats (guarded by srv->mu): the per-acceptor fairness surface
   // (gubernator_ingress_acceptor_*).
   int64_t accepted = 0, requests = 0, ingress_frames = 0,
           ingress_lanes = 0, wakeups = 0;
+  // The socket's own work (srv->mu; the loop counts on its own and
+  // folds in once a wakeup): `/debug/status` `edge`.
+  int64_t reads = 0, read_bytes = 0, sends = 0, send_bytes = 0,
+          epollout_rounds = 0;
 };
 
 struct HttpServer {
@@ -2291,6 +2344,12 @@ struct HttpServer {
   // token -> (acceptor idx, fd): which conn answers the token.
   std::unordered_map<uint64_t, std::pair<int, int>> token_addr;
   uint64_t next_token = 1;
+  // Answered requests not yet drained by Python (bounded: kMaxSendRing).
+  // `send_ring_n` is its depth, written under mu and read without it, so
+  // that a drain of an empty ring takes no lock.
+  std::deque<HttpSendRec> send_ring;
+  std::atomic<int64_t> send_ring_n{0};
+  int64_t send_ring_dropped = 0;
 };
 
 void http_close_conn(HttpServer* s, HttpConn* c) {
@@ -2347,11 +2406,12 @@ std::string http_simple_response(int code, const char* reason,
 // wake that loop.  The shared exit of gt_http_respond and the ingress
 // fast lane's native response fill.
 void http_stage_response(HttpServer* s, uint64_t token, std::string resp) {
+  int64_t t_staged = mono_ns();
   std::lock_guard<std::mutex> lk(s->mu);
   auto it = s->token_addr.find(token);
   if (it == s->token_addr.end()) return;  // conn died
   HttpAcceptor* a = s->acceptors[(size_t)it->second.first].get();
-  a->resp_queue.emplace_back(token, std::move(resp));
+  a->resp_queue.emplace_back(token, HttpResponse{std::move(resp), t_staged});
   // After shutdown the eventfd is closed (and its number may be
   // reused elsewhere in the process) — never write it while
   // stopping.  Checked and written under s->mu: gt_http_shutdown
@@ -2368,15 +2428,22 @@ void http_stage_done(HttpConn* c) {
   while (!c->awaiting.empty()) {
     auto it = c->done.find(c->awaiting.front());
     if (it == c->done.end()) break;
-    c->out += it->second;
+    c->out += it->second.bytes;
+    if (it->second.t_staged) {
+      c->marks.push_back({c->out.size(), it->first, it->second.t_staged});
+    }
     c->done.erase(it);
     c->awaiting.pop_front();
   }
 }
 
 // Parse as many complete requests as the buffer holds.  Returns false
-// when the connection must die (malformed / oversize).
-bool http_drain_input(HttpServer* s, HttpConn* c) {
+// when the connection must die (malformed / oversize).  `reads` are this
+// wakeup's reads: a pipelined request whose first bytes arrived with its
+// predecessor's tail takes the time of the read that brought them.
+bool http_drain_input(HttpServer* s, HttpConn* c, const HttpReadStamp* reads,
+                      int n_reads) {
+  size_t consumed = 0;  // bytes of `in`, as the reads saw it, framed so far
   for (;;) {
     size_t he = c->in.find("\r\n\r\n");
     if (he == std::string::npos) {
@@ -2401,7 +2468,7 @@ bool http_drain_input(HttpServer* s, HttpConn* c) {
         t = s->next_token++;
         c->awaiting.push_back(t);
       }
-      c->done[t] = http_simple_response(
+      c->done[t].bytes = http_simple_response(
           501, "Not Implemented",
           "{\"code\": 12, \"message\": \"method not implemented\"}", false);
       http_stage_done(c);
@@ -2448,8 +2515,16 @@ bool http_drain_input(HttpServer* s, HttpConn* c) {
     p->method = method;
     p->keep_alive = keep_alive;
     p->path = std::move(path);
+    p->t_first_byte = c->t_first_byte;
+    p->t_body = mono_ns();
     p->body.assign(c->in, he + 4, content_len);
     c->in.erase(0, total);
+    consumed += total;
+    if (!c->in.empty() && n_reads > 0) {
+      int i = 0;
+      while (i < n_reads - 1 && reads[i].end <= consumed) ++i;
+      c->t_first_byte = reads[i].t;
+    }
     if (!keep_alive) c->want_close = true;
 
     std::unique_lock<std::mutex> lk(s->mu);
@@ -2462,7 +2537,7 @@ bool http_drain_input(HttpServer* s, HttpConn* c) {
       uint64_t t = p->token;
       lk.unlock();
       delete p;
-      c->done[t] = http_simple_response(
+      c->done[t].bytes = http_simple_response(
           503, "Service Unavailable",
           "{\"code\": 14, \"message\": \"ingress queue full\"}", keep_alive);
       http_stage_done(c);
@@ -2491,6 +2566,12 @@ void http_loop(HttpAcceptor* a) {
   // block costs nothing in liveness; the old fixed 200 ms tick burned
   // idle CPU per acceptor once there were N loops).
   bool need_tick = false;
+  // This wakeup's own count of the socket's work and the answers whose
+  // last byte left in it: folded into the acceptor's counters and the
+  // server's send ring under the sweep's lock hold, below.
+  int64_t reads = 0, read_bytes = 0, sends = 0, send_bytes = 0,
+          epollout_rounds = 0;
+  std::vector<HttpSendRec> sent;
   for (;;) {
     int n = epoll_wait(a->epfd, evs, 64, need_tick ? 200 : -1);
     if (s->stopping.load()) return;
@@ -2558,10 +2639,21 @@ void http_loop(HttpAcceptor* a) {
       if (!dead && (evs[i].events & EPOLLIN)) {
         char buf[65536];
         bool eof = false;
+        // The reads of this wakeup, for the first byte of a pipelined
+        // request; beyond the array's length the last entry stands for
+        // every later read.
+        HttpReadStamp stamps[32];
+        int n_stamps = 0;
         for (;;) {
           ssize_t r = read(fd, buf, sizeof buf);
           if (r > 0) {
+            int64_t t_read = mono_ns();
+            if (c->in.empty()) c->t_first_byte = t_read;
             c->in.append(buf, (size_t)r);
+            if (n_stamps < 32) ++n_stamps;
+            stamps[n_stamps - 1] = {c->in.size(), t_read};
+            ++reads;
+            read_bytes += r;
             if (c->in.size() > kMaxHeaderBytes + kMaxBodyBytes) { dead = true; break; }
           } else if (r == 0) { eof = true; break; }
           else { if (errno != EAGAIN && errno != EWOULDBLOCK) dead = true; break; }
@@ -2571,7 +2663,7 @@ void http_loop(HttpAcceptor* a) {
         // half-closes with shutdown(SHUT_WR) and still reads).  Killing
         // the conn on r==0 without draining would DROP fully-received
         // requests — observed as lost hits under load.
-        if (!dead && !http_drain_input(s, c)) dead = true;
+        if (!dead && !http_drain_input(s, c, stamps, n_stamps)) dead = true;
         if (!dead && eof) {
           // Half-close semantics: serve what was fully received, flush
           // any responses (the write side may still be open), then
@@ -2583,6 +2675,7 @@ void http_loop(HttpAcceptor* a) {
         }
       }
       if (!dead && (evs[i].events & EPOLLOUT) && c->out.size() > c->out_off) {
+        ++epollout_rounds;
         // MSG_NOSIGNAL: a peer that closed after its FIN must surface
         // as EPIPE, not SIGPIPE (Python ignores SIGPIPE; a non-Python
         // embedder would die).
@@ -2590,6 +2683,18 @@ void http_loop(HttpAcceptor* a) {
                          c->out.size() - c->out_off, MSG_NOSIGNAL);
         if (w > 0) {
           c->out_off += (size_t)w;
+          ++sends;
+          send_bytes += w;
+          // The send that passes a response's end stamps its last byte:
+          // the kernel has accepted it (not: the client has read it).
+          if (!c->marks.empty() && c->marks.front().end <= c->out_off) {
+            int64_t t_last_byte = mono_ns();
+            do {
+              const HttpOutMark& m = c->marks.front();
+              sent.push_back({m.token, m.t_staged, t_last_byte});
+              c->marks.pop_front();
+            } while (!c->marks.empty() && c->marks.front().end <= c->out_off);
+          }
           if (c->out_off == c->out.size()) { c->out.clear(); c->out_off = 0; }
           c->stall_start = {};  // progress: restart the stall clock
         } else if (w < 0 && errno != EAGAIN && errno != EWOULDBLOCK) {
@@ -2623,6 +2728,22 @@ void http_loop(HttpAcceptor* a) {
       need_tick = false;
       {
         std::lock_guard<std::mutex> lk(s->mu);
+        a->reads += reads;
+        a->read_bytes += read_bytes;
+        a->sends += sends;
+        a->send_bytes += send_bytes;
+        a->epollout_rounds += epollout_rounds;
+        reads = read_bytes = sends = send_bytes = epollout_rounds = 0;
+        for (const HttpSendRec& r : sent) {
+          if (s->send_ring.size() >= kMaxSendRing) {
+            s->send_ring.pop_front();
+            ++s->send_ring_dropped;
+          }
+          s->send_ring.push_back(r);
+        }
+        sent.clear();
+        s->send_ring_n.store((int64_t)s->send_ring.size(),
+                             std::memory_order_release);
         for (auto& [fd, c] : a->conns) {
           if (!c->saw_eof || c->out.size() <= c->out_off) continue;
           if (c->stall_start == std::chrono::steady_clock::time_point{}) {
@@ -2660,6 +2781,8 @@ typedef struct {
   int64_t body_len;
   const char* path;
   const char* body;
+  int64_t t_first_byte_ns;  // the edge's stamps (mono_ns), see HttpPending
+  int64_t t_body_ns;
 } GtHttpReq;
 
 // Start the edge: `n_acceptors` SO_REUSEPORT TCP listeners on
@@ -2783,6 +2906,51 @@ void gt_http_acceptor_stats(void* sv, int64_t* out) {
   }
 }
 
+// The socket's own work, summed over the acceptors (`/debug/status`
+// `edge`): out is i64[7] = {reads, read bytes, sends, send bytes,
+// EPOLLOUT rounds, requests, answered requests the send ring dropped
+// before Python drained them (edge.send is under-observed by that
+// many)}.  All cumulative, and all written under mu (the acceptor's own
+// counts are folded in under its sweep's lock hold).
+void gt_http_stats(void* sv, int64_t* out) {
+  auto* s = (HttpServer*)sv;
+  std::lock_guard<std::mutex> lk(s->mu);
+  for (int i = 0; i < 7; ++i) out[i] = 0;
+  for (auto& a : s->acceptors) {
+    out[0] += a->reads;
+    out[1] += a->read_bytes;
+    out[2] += a->sends;
+    out[3] += a->send_bytes;
+    out[4] += a->epollout_rounds;
+    out[5] += a->requests;
+  }
+  out[6] = s->send_ring_dropped;
+}
+
+// Hand Python the answered requests since its last call, oldest first:
+// out is i64[cap * 3] rows of {token, t_staged, t_last_byte}.  Returns the
+// rows written; what does not fit waits for the next call.
+int64_t gt_http_drain_sends(void* sv, int64_t* out, int64_t cap) {
+  auto* s = (HttpServer*)sv;
+  if (s->send_ring_n.load(std::memory_order_acquire) == 0) return 0;
+  std::lock_guard<std::mutex> lk(s->mu);
+  int64_t n = 0;
+  while (n < cap && !s->send_ring.empty()) {
+    const HttpSendRec& r = s->send_ring.front();
+    out[n * 3 + 0] = (int64_t)r.token;
+    out[n * 3 + 1] = r.t_staged;
+    out[n * 3 + 2] = r.t_last_byte;
+    s->send_ring.pop_front();
+    ++n;
+  }
+  s->send_ring_n.store((int64_t)s->send_ring.size(),
+                       std::memory_order_release);
+  return n;
+}
+
+// The edge's clock, for the test that holds it to time.monotonic_ns().
+int64_t gt_mono_ns(void) { return mono_ns(); }
+
 // Blocks (GIL released by ctypes) until a request is ready, the server
 // stops (-1), or timeout_ms elapses (0).  1 = *out filled; pointers
 // stay valid until gt_http_respond/gt_ingress_submit for that token.
@@ -2803,6 +2971,8 @@ int gt_http_next(void* sv, int64_t timeout_ms, GtHttpReq* out) {
   out->body_len = (int64_t)p->body.size();
   out->path = p->path.c_str();
   out->body = p->body.data();
+  out->t_first_byte_ns = p->t_first_byte;
+  out->t_body_ns = p->t_body;
   return 1;
 }
 
@@ -2960,6 +3130,7 @@ struct IngressFrame {
   std::vector<uint64_t> hashes;   // ring hash per lane
   std::chrono::steady_clock::time_point arrival;
   int64_t parse_ns;
+  int64_t t_first_byte = 0, t_body = 0;  // the HttpPending's stamps
 };
 
 struct TakenBatch {
@@ -2973,6 +3144,9 @@ struct TakenBatch {
   std::string name_blob, uk_blob;
   std::vector<int64_t> name_off, uk_off;
   std::vector<int64_t> frame_lanes, frame_age_us;
+  // Per frame {token, t_first_byte, t_body, arrival} (mono_ns): the
+  // edge's stamps of the request, for edge.recv and edge.handoff.
+  std::vector<int64_t> frame_stamps;
   int64_t parse_ns_total = 0;
 };
 
@@ -3027,6 +3201,7 @@ typedef struct {
   int64_t uk_bytes;
   const int64_t* frame_lanes;
   const int64_t* frame_age_us;
+  const int64_t* frame_stamps;  // i64[n_frames * 4], see TakenBatch
   int64_t parse_ns_total;
   int64_t hits_total;  // sum of `hits`: the audit's ingress_hits
 } GtTakenInfo;
@@ -3165,6 +3340,8 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   frame->n = n;
   frame->info = info;
   frame->arrival = t0;
+  frame->t_first_byte = p->t_first_byte;
+  frame->t_body = p->t_body;
   frame->parse_ns = (int64_t)std::chrono::duration_cast<std::chrono::nanoseconds>(
                         std::chrono::steady_clock::now() - t0)
                         .count();
@@ -3290,6 +3467,7 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
   tb->hashes.resize((size_t)n);
   tb->frame_lanes.resize(tb->frames.size());
   tb->frame_age_us.resize(tb->frames.size());
+  tb->frame_stamps.resize(tb->frames.size() * 4);
   auto now = std::chrono::steady_clock::now();
   int64_t lo = 0;
   tb->hkoff[0] = tb->name_off[0] = tb->uk_off[0] = 0;
@@ -3324,6 +3502,10 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
         (int64_t)std::chrono::duration_cast<std::chrono::microseconds>(
             now - f->arrival)
             .count();
+    tb->frame_stamps[fi * 4 + 0] = (int64_t)f->token;
+    tb->frame_stamps[fi * 4 + 1] = f->t_first_byte;
+    tb->frame_stamps[fi * 4 + 2] = f->t_body;
+    tb->frame_stamps[fi * 4 + 3] = ns_of(f->arrival);
     tb->parse_ns_total += f->parse_ns;
     lo += m;
   }
@@ -3346,6 +3528,7 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
   out->uk_bytes = (int64_t)tb->uk_blob.size();
   out->frame_lanes = tb->frame_lanes.data();
   out->frame_age_us = tb->frame_age_us.data();
+  out->frame_stamps = tb->frame_stamps.data();
   out->parse_ns_total = tb->parse_ns_total;
   out->hits_total = 0;
   for (int64_t h : tb->hits) out->hits_total += h;

@@ -6,11 +6,15 @@ module aggregates the same signals ALWAYS-ON, so the operator questions
 "are we burning the error budget") have live answers without sampling:
 
 * **Latency attribution** — per-phase duration reservoirs covering the
-  whole request waterfall (`WATERFALL`): ingress parse -> batch-window
-  wait -> queue wait -> the five dispatch pipeline stages and the lock
-  and gate waits between them -> peer-wire RTT -> response encode.
+  whole request waterfall (`WATERFALL`): the C++ edge's socket reads
+  and hand-off -> ingress parse -> batch-window wait -> queue wait ->
+  the five dispatch pipeline stages and the lock and gate waits between
+  them -> peer-wire RTT -> response encode -> the edge's socket writes.
   Every phase site is one `with phase(...)`, whose reading also reaches
-  the host sampler, the sampled span and the profiler's trace.  Each
+  the host sampler, the sampled span and the profiler's trace; the
+  edge's three (`edge.recv`, `edge.handoff`, `edge.send`) are stamped in
+  C++ by the acceptor threads, which run no Python, and observed from
+  those stamps (`observe_phase`), one observation a request.  Each
   observation also feeds the
   `gubernator_latency_attribution_seconds{phase}` histogram of the
   registered metrics sink; `GET /debug/latency` serves ceil-rank
@@ -86,8 +90,16 @@ def percentile(sorted_vals: Sequence[float], q: float) -> float:
 # /debug/latency reader sees the pipeline shape; the document's
 # `waterfall` key serves the table itself.
 WATERFALL = (
-    ("epoll.wait", 0),        # a gateway worker has no request (blocked in edge.next)
+    ("epoll.wait", 0),        # a gateway worker has no request (blocked in
+                              # edge.next); the socket's own reads and writes
+                              # are the acceptor threads' and are edge.recv /
+                              # edge.send, not this
     ("pump.take", 0),         # the native pump has no frame (blocked in batcher.take)
+    ("edge.recv", 0),         # C++ edge: the read that brought a request's first
+                              # byte -> its last body byte framed
+    ("edge.handoff", 0),      # C++ edge: body complete -> a worker holds the
+                              # request (ready queue, wake-up, the interpreter
+                              # taken back, the sniff)
     ("ingress.parse", 0),     # wire bytes -> IngressColumns (gateway)
     ("window.idle", 0),       # a BatchWindow's flusher has no submission
     ("window.hold", 0),       # ... holds submissions until the window closes or fills
@@ -120,6 +132,9 @@ WATERFALL = (
     ("pump.outcome", 0),      # native take: result copies + tenant outcome fold
     ("peer.rpc", 0),          # forwarded-hop round trip (peer_client)
     ("response.encode", 0),   # ColumnarResult -> wire bytes (gateway)
+    ("edge.send", 0),         # C++ edge: the answer handed to the acceptor ->
+                              # the kernel has accepted its last byte (eventfd
+                              # wake, staging copy, EPOLLOUT round, sends)
     ("pump.account", 0),      # native take: request metrics, after the answers left
     ("ingress.total", 0),     # whole-request wall time (GetRateLimits)
     ("global.sync_drain", 0),  # GLOBAL tick: pipeline drain + both locks
@@ -155,6 +170,19 @@ class _PhaseStats:
                 self._buf[self.count % PHASE_RING] = dt_s
             else:
                 self._buf.append(dt_s)
+
+    def observe_many(self, dts_s: Sequence[float]) -> None:
+        with self._lock:
+            buf = self._buf
+            for dt_s in dts_s:
+                self.count += 1
+                self.sum_s += dt_s
+                if dt_s > self.max_s:
+                    self.max_s = dt_s
+                if len(buf) >= PHASE_RING:
+                    buf[self.count % PHASE_RING] = dt_s
+                else:
+                    buf.append(dt_s)
 
     def snapshot(self) -> Optional[dict]:
         with self._lock:
@@ -208,6 +236,27 @@ def observe_phase(phase: str, dt_s: float) -> None:
         child.observe(dt_s)
 
 
+def observe_phases(phase: str, dts_s: Sequence[float]) -> None:
+    """Record several completed intervals of one phase, an observation
+    each, under one hold of the reservoir's lock: for intervals measured
+    elsewhere and read together (the C++ edge's stamps of a take's
+    frames, the answers drained from its send ring)."""
+    st = _phases.get(phase)
+    if st is None:  # unknown phase: record rather than drop
+        st = _phases.setdefault(phase, _PhaseStats())
+    st.observe_many(dts_s)
+    sink = _sink
+    if sink is not None:
+        child = sink[1].get(phase)
+        if child is None:
+            try:
+                child = sink[1][phase] = sink[0].labels(phase=phase)
+            except Exception:  # noqa: BLE001 — a dead registry must not fail requests
+                return
+        for dt_s in dts_s:
+            child.observe(dt_s)
+
+
 _profiler_session_on = TraceAnnotation.is_enabled  # one atomic load
 
 
@@ -242,6 +291,12 @@ class phase:
         self.ids = ids
         self.dt_s = 0.0
 
+    @property
+    def traced(self) -> bool:
+        """Inside the interval: whether it is an event of a running
+        profiler session (what `note` adds then reaches the trace)."""
+        return self._ann is not None
+
     def note(self, **ids) -> None:
         """Identifiers learned inside the interval (a ticket is assigned
         under the plan lock): they join the sampled span's attributes
@@ -273,6 +328,33 @@ class phase:
                 self.ids["error"] = str(exc)
             tracing.stage_span(self.name, dt, self.bt, **self.ids)
         return False
+
+
+def edge_trace_note(anchor_ns: int, take_ns: Optional[int] = None,
+                    stamps=(), sends=()) -> Dict[str, object]:
+    """The C++ edge's stamps as metadata of a profiler event of the native
+    pump (`phase.note`): an acceptor thread can write no event of its
+    own, so its readings ride events that are written anyway, a take's
+    `pump.admit` (its frames' stamps) and `pump.account` (the answers
+    drained there).  `anchor_ns` is `time.monotonic_ns()` read beside the
+    event's start: a reader takes the event's own start less `mono_ns`
+    for the offset between the stamps' clock and the trace's, and every
+    other value is nanoseconds from the anchor.  `edge` holds a
+    `token:t_first_byte:t_body:arrival` a frame of the take and `take`
+    the take's own clock reading; `sends` a `token:t_staged:t_last_byte`
+    an answer drained (of earlier takes, as a rule); `;` between records.
+    No `,`, `=` or `#`: the profiler splits an event's name on those."""
+    note: Dict[str, object] = {"mono_ns": anchor_ns}
+    if take_ns is not None:
+        note["take"] = take_ns - anchor_ns
+        note["edge"] = ";".join(
+            ":".join(str(v) for v in (tok, fb - anchor_ns, body - anchor_ns, arr - anchor_ns))
+            for tok, fb, body, arr in stamps)
+    if sends:
+        note["sends"] = ";".join(
+            ":".join(str(v) for v in (tok, staged - anchor_ns, last - anchor_ns))
+            for tok, staged, last in sends)
+    return note
 
 
 def phase_snapshot() -> Dict[str, dict]:

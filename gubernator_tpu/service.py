@@ -2940,6 +2940,18 @@ class V1Service:
 
     _BREAKER_NAMES = {0: "closed", 1: "half-open", 2: "open"}
 
+    def _edge_status(self) -> dict:
+        """{"edge": counters} summed over the daemon's native edges;
+        empty where it serves from the stdlib edge."""
+        edges = list(getattr(self, "native_edges", ()))
+        if not edges:
+            return {}
+        total: dict = {}
+        for e in edges:
+            for k, v in e.stats().items():
+                total[k] = total.get(k, 0) + v
+        return {"edge": total}
+
     def debug_status(self) -> dict:
         """The cluster-status surface (GET /debug/status): one JSON doc
         aggregating version, health, per-peer breaker state, bucket-
@@ -3043,6 +3055,10 @@ class V1Service:
             # wire's dispatches and lanes, the dictionary's being the
             # difference; configurations counted; transfer calls made.
             "wire": saturation.mesh_tally.wire_snapshot(),
+            # The C++ edge's own socket work, summed over its acceptors
+            # (native.HttpEdge.stats; absent on the stdlib edge): reads
+            # and sends a request are these over `requests`.
+            **self._edge_status(),
             # The batch folds (native.cms_fold): whether the native
             # pass runs them, and the top-K candidates they have handed
             # Python, by sketch — the bound on a fold's interpreter time.

@@ -34,10 +34,14 @@ METRICS = {
     "v5e1-1m.frames": {
         "plan.lock_wait_ms", "plan.native_ms_per_dispatch", "launch.lock_wait_ms",
         "batcher.pump_ms_per_take", "edge.unattributed_ms_per_req",
-        "device.idle_unattributed_share", "device.idle_no_request_share", "xla.program_load_s"},
+        "device.idle_unattributed_share", "device.idle_no_request_share", "xla.program_load_s",
+        "edge.recv_ms_per_req", "edge.handoff_ms_per_req", "edge.send_ms_per_req", "device.idle_edge_io_share"},
+    # No take on the native lane: the edge's phases are observed on the JSON
+    # path, and no `pump.admit` event carries stamps into the trace.
     "v5e1-1m.singles": {
         "plan.lock_wait_ms", "plan.native_ms_per_dispatch", "launch.lock_wait_ms", "device.idle_unattributed_share",
-        "device.idle_no_request_share", "xla.program_load_s"},
+        "device.idle_no_request_share", "xla.program_load_s",
+        "edge.recv_ms_per_req", "edge.handoff_ms_per_req", "edge.send_ms_per_req"},
 }
 
 
@@ -62,6 +66,12 @@ def test_traced_rehearsal_holds_the_phases_its_path_crosses(cell):
     named = {name for name, _ in line["breakdown"]["idle_gaps"]}
     assert any(n.startswith("host: dispatch.") or n == "host: epoll.wait" for n in named), named
     assert "idle seconds of the first device by phase" in proc.stdout
+    if cell == "v5e1-1m.frames":
+        assert "idle seconds of the first device by edge phase" in proc.stdout
+        assert (line["metrics"]["device.idle_edge_io_share"]["value"]
+                <= line["metrics"]["device.idle_no_request_share"]["value"])
+    else:
+        assert "device.idle_edge_io_share" not in line["metrics"]
     (path,) = glob.glob(os.path.join(
         REPO, "chipbench", "out", f"{cell}.seed{seed}.trace1.trace", "plugins", "profile", "*", "*.xplane.pb"))
     found = set()
