@@ -457,8 +457,6 @@ class _Staged:
     solo: "Optional[Callable]"  # state -> (state, packed); None = scalar
     fuse_key: object = None   # None = not fuse-eligible (fallback wire)
     wire_dev: object = None   # uploaded packed wire (dict-wire path)
-    n_rounds: int = 1
-    now_ms: int = 0
     wide: bool = False        # the ANSWER's width: i64, not i32 deltas
     # The wire that carried it, for the mesh tally and the launch's
     # label: the per-lane wire (a word a value) or, by default, the
@@ -980,9 +978,11 @@ class ColumnarPipeline:
 
     def _launch_group(self, group) -> None:
         """Stage 3 (ticket order, under `_lock`): just the
-        state-threading jit call.  A multi-batch group rides ONE fused
-        program; each handle's fetch reads its slice of the shared
-        stacked result, transferred once.
+        state-threading jit call, on device arrays alone (the state and
+        the staged wires: no host->device transfer, which
+        tests/test_wire_header.py lets JAX refuse).  A multi-batch
+        group rides ONE fused program; each handle's fetch reads its
+        slice of the shared stacked result, transferred once.
 
         A scalar-staged batch (the express singleton slot) never fuses
         (fuse_key None) and launches as a host-side evaluation instead:
@@ -1021,10 +1021,8 @@ class ColumnarPipeline:
                 _prefetch_async(packed)
                 return
             fn = self._fused_launch_fn(len(group), group[0][0].wide)
-            nr = np.asarray([s.n_rounds for s, _ in group], np.int32)
-            nowv = np.asarray([s.now_ms for s, _ in group], np.int64)
             self.state, stacked = fn(
-                self.state, *[s.wire_dev for s, _ in group], nr, nowv
+                self.state, *[s.wire_dev for s, _ in group]
             )
             shared = _FusedFetch(stacked)
             for i, (_, h) in enumerate(group):

@@ -850,6 +850,48 @@ def apply_rounds_dict(
 
 DICT_WIRE_TABLE_WORDS = 2 * DICT_TABLE_ROWS + 5 * 2 * DICT_TABLE_ROWS
 
+# The wire's header: the last words of every shard's row, on either
+# wire.  What a dispatch program reads besides the state and the lanes
+# rides the ONE buffer the stage uploads, so a launch hands the runtime
+# device arrays alone and makes no host->device transfer of its own (a
+# Python or numpy scalar argument is a transfer call each, 0.2 ms on a
+# TPU whatever it carries).  At the row's END, so the lanes' columns
+# and the table keep their offsets (multiples of P and of 256).
+#
+#   word 0  n_rounds          word 2  now_ms, high word
+#   word 1  now_ms, low word  word 3  spare, zero
+#
+# The same in every shard's row (the buffer stays rectangular and
+# sharded as it is), so every device reads its own copy.  A packer
+# leaves it zero, and zero rounds answer nothing: `set_wire_header`
+# fills it.
+WIRE_HEADER_WORDS = 4
+
+
+def set_wire_header(w, n_rounds: int, now_ms: int) -> None:
+    """Fill the header of a packed [S, W] wire (either wire), in place."""
+    lo = now_ms & _MASK32
+    h = w[:, w.shape[1] - WIRE_HEADER_WORDS:]
+    h[:, 0] = n_rounds
+    h[:, 1] = lo - ((lo >> 31) << 32)  # the low word's bits as an i32
+    h[:, 2] = now_ms >> 32
+
+
+def wire_header(wire):
+    """Device-side twin of set_wire_header: (n_rounds i32, now_ms i64)
+    as unbatched scalars, read from the first of the [s, W] rows it is
+    handed (under the mesh's `shard_map` a device holds its own shard's
+    row, so that is its own copy).  Call it outside the `vmap` over
+    the rows: a rounds loop whose bound is batched runs predicated,
+    and selects the whole carried table every round."""
+    h = wire[0, wire.shape[1] - WIRE_HEADER_WORDS:]
+    return h[0], _compose64(h[1], h[2])
+
+
+def dict_wire_lanes(words: int) -> int:
+    """P of a dictionary wire whose shard row is `words` long."""
+    return (words - DICT_WIRE_TABLE_WORDS - WIRE_HEADER_WORDS) // 3
+
 
 def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array":
     """Serialize one dict-wire batch into a SINGLE i32 buffer.
@@ -857,9 +899,9 @@ def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array
     The dict wire's 12 separate arrays cost 12 host->device transfers
     per dispatch; at service batch sizes (<=4096 lanes) the per-call
     overhead dwarfs the bytes, so everything rides one
-    [S, 3P + DICT_WIRE_TABLE_WORDS] i32 array instead (host packs with
-    numpy views, device unpacks with free slices/shifts inside the
-    jit):
+    [S, 3P + DICT_WIRE_TABLE_WORDS + WIRE_HEADER_WORDS] i32 array
+    instead (host packs with numpy views, device unpacks with free
+    slices/shifts inside the jit):
 
       words [0,P)    slot (i32)
       words [P,2P)   occ | flags<<16 | cfg<<24   (flags: bit0 exists,
@@ -868,6 +910,8 @@ def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array
       words [3P,..)  config-table rows: algo(256), behavior(256), then
                      hits/limit/duration/greg_expire_delta/
                      greg_duration as i64 lo/hi word pairs (512 each)
+      last 4 words   the header (WIRE_HEADER_WORDS: n_rounds, now_ms),
+                     zero here; set_wire_header fills it
 
     The value rows are 64-bit so ANY magnitude (monthly Gregorian
     expiries, >2^31 limits) rides the dict wire — per-lane bytes are
@@ -880,7 +924,9 @@ def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array
     import numpy as np
 
     S, P = slot.shape
-    w = np.empty((S, 3 * P + DICT_WIRE_TABLE_WORDS), dtype=np.int32)
+    w = np.empty(
+        (S, 3 * P + DICT_WIRE_TABLE_WORDS + WIRE_HEADER_WORDS), dtype=np.int32
+    )
     w[:, :P] = slot
     meta = occ.astype(np.int32) & 0xFFFF
     meta |= (exists.astype(np.int32) | (write.astype(np.int32) << 1)) << 16
@@ -897,6 +943,7 @@ def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array
         pos += DICT_TABLE_ROWS
         w[:, pos:pos + DICT_TABLE_ROWS] = (v >> 32).astype(np.int32)
         pos += DICT_TABLE_ROWS
+    w[:, pos:] = 0
     return w
 
 
@@ -932,7 +979,7 @@ def apply_rounds_packed(
     """Narrow-output dict kernel behind the single-buffer wire.  Host
     precondition (narrow_ok): every value and every time the kernel
     computes fits the i32 output deltas."""
-    P = (wire.shape[0] - DICT_WIRE_TABLE_WORDS) // 3
+    P = dict_wire_lanes(wire.shape[0])
     slot, fl, cfg, occ, rid, rows = unpack_dict_wire(wire, P)
     reqd = RequestBatchDict(
         slot=slot,
@@ -961,7 +1008,7 @@ def apply_rounds_packed_wide(
     only the readback doubles.  Matches interval.go:82-146 being
     first-class in the reference."""
     now = jnp.asarray(now_ms, _I64)
-    P = (wire.shape[0] - DICT_WIRE_TABLE_WORDS) // 3
+    P = dict_wire_lanes(wire.shape[0])
     slot, fl, cfg, occ, rid, rows = unpack_dict_wire(wire, P)
     with jax.named_scope(SCOPE_WIRE_DECODE):
         cfg = cfg.astype(_I32)
@@ -998,8 +1045,9 @@ def pack_lane_wire(slot, exists, write, occ, round_id, pos, values, wide: bool):
     """Serialize one per-lane batch into a SINGLE i32 buffer, as
     pack_dict_wire does for the dictionary wire and for the same
     reason: a transfer call costs the host more than its bytes.  The
-    buffer is [S, words * P], column k of a shard at words
-    [kP, (k+1)P):
+    buffer is [S, words * P + WIRE_HEADER_WORDS], column k of a shard
+    at words [kP, (k+1)P), the header (n_rounds, now_ms: zero here,
+    set_wire_header fills it) in the row's last four:
 
       0  slot                     4  occ       (a whole word each: this
       1  exists | write << 1      5  round id   wire is also the one for
@@ -1020,7 +1068,8 @@ def pack_lane_wire(slot, exists, write, occ, round_id, pos, values, wide: bool):
 
     S, P = slot.shape
     words = LANE_WIRE_WORDS_WIDE if wide else LANE_WIRE_WORDS
-    w = np.zeros((S, words * P), dtype=np.int32)
+    row = words * P + WIRE_HEADER_WORDS
+    w = np.zeros((S, row), dtype=np.int32)
 
     def col(k):
         return w[:, k * P:(k + 1) * P]
@@ -1030,9 +1079,10 @@ def pack_lane_wire(slot, exists, write, occ, round_id, pos, values, wide: bool):
     col(_LANE_OCC)[:] = occ
     col(_LANE_RID)[:] = round_id
     # Request i lies at word pos[i] of column 0 of ITS shard's row; a
-    # row is `words` columns long, so later shards shift by the rest.
+    # row is `words` columns and the header long, so later shards shift
+    # by the rest.
     flat = w.reshape(-1)
-    base = pos + (pos // P) * ((words - 1) * P)
+    base = pos + (pos // P) * (row - P)
 
     def scatter(k, v):
         flat[k * P:][base] = v
@@ -1056,7 +1106,9 @@ def unpack_lane_wire(w, wide: bool):
     """Device-side twin of pack_lane_wire for ONE shard row: returns
     (RequestBatch32, round ids), or (RequestBatch, round ids) from the
     wide buffer, its values composed to i64.  Slices and shifts only."""
-    P = w.shape[0] // (LANE_WIRE_WORDS_WIDE if wide else LANE_WIRE_WORDS)
+    P = (w.shape[0] - WIRE_HEADER_WORDS) // (
+        LANE_WIRE_WORDS_WIDE if wide else LANE_WIRE_WORDS
+    )
 
     def col(k):
         return w[k * P:(k + 1) * P]

@@ -152,90 +152,120 @@ def _answer_rounds_jit(state, gcols, batch, extra, round_id, n_rounds, now):
     return jax.vmap(one)(state, gcols, batch, extra, round_id)
 
 
-def _rounds_lanes_mesh(state, wire, n_rounds, now, wide=False):
-    """Per-lane rounds behind the single-buffer wire ([S, 11P] i32, see
-    buckets.pack_lane_wire): what a batch the dictionary cannot hold
-    dispatches, and one sharded transfer like the dictionary's.  One
-    i32[S, 4, B] packed result; `wide` (values exceeding int32) reads
-    [S, 16P], lo/hi pairs, and answers i64[S, 4, B]."""
+# The dispatch programs take (state, wire...) and nothing else: the
+# round count and the clock ride the wire's header (buckets.wire_header),
+# read by slices INSIDE the program, so a launch hands the runtime device
+# arrays alone and uploads nothing (a Python or numpy scalar argument is
+# a transfer call of its own in front of every program: two of them were
+# 0.44 ms of a launch on a TPU v5e).
+#
+# Each runs under `shard_map` over the store's mesh (_dispatch_jit):
+# every device runs the body on ITS OWN shard's rows, so the header it
+# reads is its own row's copy and no device waits for another.  (Under
+# a plain `jit` the partitioner turns "shard 0's word, replicated" into
+# an all-reduce: the four chips would rendezvous at the start of every
+# dispatch, the first waiting until the host has launched the last, and
+# a dispatch program would be a collective: see _SYNC_COLLECTIVE_LOCK.)
+# The header is read outside the `vmap` over the device's rows: a rounds
+# loop whose bound is batched runs predicated, and selects the whole
+# carried table every round.
+
+
+def _rounds_over_rows(kernel, state, wire, **kw):
+    n_rounds, now = buckets.wire_header(wire)
 
     def one(state_s, w_s):
-        return buckets.apply_rounds_lanes(
-            state_s, w_s, n_rounds, now, wide=wide, cold_cond=False
-        )
+        return kernel(state_s, w_s, n_rounds, now, cold_cond=False, **kw)
 
     return jax.vmap(one)(state, wire)
 
 
-_rounds_lanes_mesh_jit = jax.jit(
-    _rounds_lanes_mesh, donate_argnums=0, static_argnames="wide"
-)
+def _rounds_lanes_mesh(state, wire):
+    """Per-lane rounds behind the single-buffer wire ([S, 11P+4] i32,
+    see buckets.pack_lane_wire): what a batch the dictionary cannot
+    hold dispatches, and one sharded transfer like the dictionary's.
+    One i32[S, 4, B] packed result."""
+    return _rounds_over_rows(buckets.apply_rounds_lanes, state, wire)
 
 
-def _rounds_packed_mesh(state, wire, n_rounds, now):
-    """Dict-wire rounds behind the single-buffer wire ([S, 3P+1792]
+def _rounds_lanes_wide_mesh(state, wire):
+    """The per-lane wire of values exceeding int32: [S, 16P+4], lo/hi
+    pairs, and an i64[S, 4, B] answer."""
+    return _rounds_over_rows(buckets.apply_rounds_lanes, state, wire, wide=True)
+
+
+def _rounds_packed_mesh(state, wire):
+    """Dict-wire rounds behind the single-buffer wire ([S, 3P+1796]
     i32, see buckets.pack_dict_wire): one sharded transfer per batch."""
-
-    def one(state_s, w_s):
-        return buckets.apply_rounds_packed(state_s, w_s, n_rounds, now, cold_cond=False)
-
-    return jax.vmap(one)(state, wire)
+    return _rounds_over_rows(buckets.apply_rounds_packed, state, wire)
 
 
-def _rounds_packed_wide_mesh(state, wire, n_rounds, now):
+def _rounds_packed_wide_mesh(state, wire):
     """Wide-output packed dict wire (values beyond int32 — monthly/
     yearly Gregorian expiries; i64[S, 4, B] result)."""
+    return _rounds_over_rows(buckets.apply_rounds_packed_wide, state, wire)
 
-    def one(state_s, w_s):
-        return buckets.apply_rounds_packed_wide(
-            state_s, w_s, n_rounds, now, cold_cond=False
+
+def _per_device(mesh: Mesh, body, stacked: bool = False):
+    """`body(state, *wires)` run by every device of `mesh` on its own
+    shard's rows.  `stacked`: the answer carries a leading axis in front
+    of the shards' (the fused programs' [k, S, 4, P]).  No `check_vma`:
+    the rounds loop starts its carry from constants and ends it
+    per-device, which the check refuses and the loop means."""
+    axis = mesh.axis_names[0]
+    return shard_map(
+        body, mesh=mesh, in_specs=P(axis),
+        out_specs=(P(axis), P(None, axis) if stacked else P(axis)),
+        check_vma=False,
+    )
+
+
+# One jitted program per (mesh, body, donation), module-wide: stores
+# over the same devices share their compiled programs.
+_DISPATCH_JIT: dict = {}
+
+
+def _dispatch_jit(mesh: Mesh, body, donate_wire: bool = False):
+    """The solo dispatch program `body` (one of the `_rounds_*_mesh`)
+    over `mesh`, the state donated.  `donate_wire`: the twin for the
+    overlapped dispatch pipeline — the wire is a fresh per-batch
+    sharded upload nothing reads afterwards, so on real accelerators
+    (not CPU, which zero-copies uploads) XLA can recycle its bytes into
+    the outputs."""
+    key = (mesh, body, donate_wire)
+    fn = _DISPATCH_JIT.get(key)
+    if fn is None:
+        fn = _DISPATCH_JIT[key] = jax.jit(
+            _per_device(mesh, body),
+            donate_argnums=(0, 1) if donate_wire else 0,
         )
+    return fn
 
-    return jax.vmap(one)(state, wire)
-
-
-_rounds_packed_mesh_jit = jax.jit(_rounds_packed_mesh, donate_argnums=0)
-_rounds_packed_wide_mesh_jit = jax.jit(_rounds_packed_wide_mesh, donate_argnums=0)
-# Donating twins for the overlapped dispatch pipeline: the wire is a
-# fresh per-batch sharded upload nothing reads afterwards, so on real
-# accelerators (not CPU, which zero-copies uploads) XLA can recycle its
-# bytes into the outputs.
-_rounds_packed_mesh_donated = jax.jit(_rounds_packed_mesh, donate_argnums=(0, 1))
-_rounds_packed_wide_mesh_donated = jax.jit(
-    _rounds_packed_wide_mesh, donate_argnums=(0, 1)
-)
 
 # Launch-fusion programs (ColumnarPipeline._launch_group): K same-shape
 # dict-wire batches applied SEQUENTIALLY inside one sharded program —
 # batch i+1 sees batch i's state, exactly as K solo dispatches would,
 # but the host pays one dispatch and one stacked readback for the
-# group.  Cached per (k, wide, donate) module-wide.
+# group.  Cached per (mesh, k, wide, donate) module-wide.
 _MESH_FUSED_JIT: dict = {}
 
 
-def _mesh_fused_packed_jit(k: int, wide: bool, donate_wires: bool = True):
-    key = (k, wide, donate_wires)
+def _mesh_fused_packed_jit(mesh: Mesh, k: int, wide: bool,
+                           donate_wires: bool = True):
+    key = (mesh, k, wide, donate_wires)
     fn = _MESH_FUSED_JIT.get(key)
     if fn is None:
-        base = (
-            buckets.apply_rounds_packed_wide if wide
-            else buckets.apply_rounds_packed
-        )
+        base = _rounds_packed_wide_mesh if wide else _rounds_packed_mesh
 
-        def run(state, *args):
-            wires, nr, now = args[:k], args[k], args[k + 1]
+        def run(state, *wires):
             outs = []
-            for i in range(k):
-
-                def one(state_s, w_s):
-                    return base(state_s, w_s, nr[i], now[i], cold_cond=False)
-
-                state, packed = jax.vmap(one)(state, wires[i])
+            for wire in wires:
+                state, packed = base(state, wire)
                 outs.append(packed)
             return state, jnp.stack(outs)  # [k, S, 4, P]
 
         donate = tuple(range(k + 1)) if donate_wires else (0,)
-        fn = jax.jit(run, donate_argnums=donate)
+        fn = jax.jit(_per_device(mesh, run, stacked=True), donate_argnums=donate)
         _MESH_FUSED_JIT[key] = fn
         telemetry.note_program_created(
             f"mesh_fused:k{k}:{'wide' if wide else 'narrow'}"
@@ -859,10 +889,12 @@ class MeshBucketStore(ColumnarPipeline):
         per-lane wire: one i32 buffer too, of 11 words a lane (16 with
         the i64 answer) and no table, and ONE transfer.  Either wire is
         packed by numpy on the host and unpacked by slices inside the
-        jitted program.  `dispatch.upload` times the one transfer call;
-        what the stage takes beyond it is the encode.  The wire taken,
-        the configurations counted and the transfer calls made ride the
-        _Staged into the mesh tally."""
+        jitted program, and either carries the round count and the
+        clock in its header (buckets.set_wire_header): the launch passes
+        device arrays alone and uploads nothing.  `dispatch.upload`
+        times the one transfer call; what the stage takes beyond it is
+        the encode.  The wire taken, the configurations counted and the
+        transfer calls made ride the _Staged into the mesh tally."""
         cols, now_ms, padded = prep.cols, prep.now_ms, prep.padded
         mp, pos, n_rounds, narrow = prep.mp, prep.pos, prep.n_rounds, prep.narrow
         S = self.n_shards
@@ -883,26 +915,22 @@ class MeshBucketStore(ColumnarPipeline):
             wire = buckets.pack_dict_wire(
                 mp.slot, mp.exists, mp.write, cfg_a, mp.occ, mp.rid, cfg_table
             )
+            buckets.set_wire_header(wire, n_rounds, now_ms)
             with phase("dispatch.upload", prep.bt, wire="dict"):
                 wire_dev = jax.device_put(wire, self._sharding)
             # (A single-round compacted scatter — commit only the write
             # lanes — measured slower on TPU; see git history.)
-            if self._wire_donate:
-                fn_packed = (
-                    _rounds_packed_mesh_donated if narrow
-                    else _rounds_packed_wide_mesh_donated
-                )
-            else:
-                fn_packed = (
-                    _rounds_packed_mesh_jit if narrow
-                    else _rounds_packed_wide_mesh_jit
-                )
+            fn_packed = _dispatch_jit(
+                self.mesh,
+                _rounds_packed_mesh if narrow else _rounds_packed_wide_mesh,
+                donate_wire=self._wire_donate,
+            )
             with self._stats_lock:
                 self._seen_wire_shapes.add((wire.shape[1], narrow))
             return _Staged(
-                solo=lambda state: fn_packed(state, wire_dev, n_rounds, now_ms),
+                solo=lambda state: fn_packed(state, wire_dev),
                 fuse_key=("dict", narrow, wire.shape[1]),
-                wire_dev=wire_dev, n_rounds=n_rounds, now_ms=now_ms,
+                wire_dev=wire_dev,
                 wide=not narrow, config_rows=config_rows, uploads=1,
             )
         # The per-lane wire: a word a value for a narrow answer, a lo/hi
@@ -920,12 +948,14 @@ class MeshBucketStore(ColumnarPipeline):
              ge, cols.greg_duration),
             wide=not narrow,
         )
+        buckets.set_wire_header(wire, n_rounds, now_ms)
         with phase("dispatch.upload", prep.bt, wire="lanes"):
             wire_dev = jax.device_put(wire, self._sharding)
+        fn_lanes = _dispatch_jit(
+            self.mesh, _rounds_lanes_mesh if narrow else _rounds_lanes_wide_mesh
+        )
         return _Staged(
-            solo=lambda state: _rounds_lanes_mesh_jit(
-                state, wire_dev, n_rounds, now_ms, wide=not narrow
-            ),
+            solo=lambda state: fn_lanes(state, wire_dev),
             wide=not narrow, lane_wire=True, config_rows=config_rows,
             uploads=1,
         )
@@ -942,7 +972,9 @@ class MeshBucketStore(ColumnarPipeline):
         self._launch_moves()
 
     def _fused_launch_fn(self, k: int, wide: bool):
-        return _mesh_fused_packed_jit(k, wide, donate_wires=self._wire_donate)
+        return _mesh_fused_packed_jit(
+            self.mesh, k, wide, donate_wires=self._wire_donate
+        )
 
     # -- express scalar slot (ops/scalar.py) ---------------------------
     def _scalar_eligible(self, cols) -> bool:
@@ -1921,24 +1953,19 @@ class MeshBucketStore(ColumnarPipeline):
             for W, narrow in shapes:
                 if not narrow:
                     continue  # wide dict batches are rare: compile lazily
-                P_lanes = (W - buckets.DICT_WIRE_TABLE_WORDS) // 3
                 noop = np.zeros((S, W), dtype=np.int32)
-                noop[:, :P_lanes] = -1  # slot=-1: every lane inert
+                # slot=-1: every lane inert
+                noop[:, :buckets.dict_wire_lanes(W)] = -1
+                buckets.set_wire_header(noop, 1, now_ms)
                 for k in (2, 4):
-                    fn = _mesh_fused_packed_jit(
-                        k, False, donate_wires=self._wire_donate
-                    )
+                    fn = self._fused_launch_fn(k, False)
                     wires = [
                         jax.device_put(noop, self._sharding) for _ in range(k)
                     ]
                     with self._lock, telemetry.program(
                         f"mesh:dispatch:fused{k}:narrow"
                     ):
-                        self.state, _ = fn(
-                            self.state, *wires,
-                            np.ones(k, np.int32),
-                            np.full(k, now_ms, np.int64),
-                        )
+                        self.state, _ = fn(self.state, *wires)
             if self.back is not None:
                 # Compile the tier-move program at every pad bucket the
                 # warm shapes dispatch (all-noop blocks): a plan closes
@@ -1947,7 +1974,7 @@ class MeshBucketStore(ColumnarPipeline):
                 # first real demotion pays no compile inside a client's
                 # deadline.
                 self._move_buckets = sorted({
-                    (W - buckets.DICT_WIRE_TABLE_WORDS) // 3 for W, _ in shapes
+                    buckets.dict_wire_lanes(W) for W, _ in shapes
                 })
                 for padded in self._move_buckets:
                     with self._lock, telemetry.program("mesh:tier_moves"):

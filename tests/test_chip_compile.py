@@ -74,7 +74,8 @@ def _state(mesh):
 
 
 def _dict_wire(mesh, lanes_per_shard):
-    words = 3 * lanes_per_shard + buckets.DICT_WIRE_TABLE_WORDS
+    words = 3 * lanes_per_shard + buckets.DICT_WIRE_TABLE_WORDS + buckets.WIRE_HEADER_WORDS
+    assert buckets.dict_wire_lanes(words) == lanes_per_shard
     return _sharded(mesh, jax.ShapeDtypeStruct((words,), jnp.int32))
 
 
@@ -95,22 +96,34 @@ def test_packed_dict_wire_compiles_for_one_v5e(one_chip):
     """The program every 4096-lane columnar frame dispatches."""
     _compile(
         "dict wire, 1M slots x 4096 lanes, one chip",
-        mesh_mod._rounds_packed_mesh_donated.lower(
-            _state(one_chip), _dict_wire(one_chip, LANES), 1, NOW_MS
-        ),
+        mesh_mod._dispatch_jit(
+            one_chip, mesh_mod._rounds_packed_mesh, donate_wire=True
+        ).lower(_state(one_chip), _dict_wire(one_chip, LANES)),
     )
+
+
+def test_packed_dict_wire_compiles_for_four_v5e_without_a_collective(four_chips):
+    """The four-chip cell's program (1028-lane frames pad to 1024 a shard):
+    every chip reads the round count and the clock from its own row of the
+    wire, so no chip waits for another inside a dispatch."""
+    compiled = _compile(
+        "dict wire, 1M slots x 4 x 1024 lanes, four chips",
+        mesh_mod._dispatch_jit(
+            four_chips, mesh_mod._rounds_packed_mesh, donate_wire=True
+        ).lower(_state(four_chips), _dict_wire(four_chips, 1024)),
+    )
+    text = compiled.as_text()
+    for op in ("all-reduce", "all-gather", "collective-permute", "all-to-all"):
+        assert f" {op}(" not in text and f" {op}-start(" not in text, op
 
 
 def test_fused_k2_compiles_for_one_v5e(one_chip):
     """The launch-fusion program a backlogged coalescer dispatches."""
     wire = _dict_wire(one_chip, LANES)
-    fused = mesh_mod._mesh_fused_packed_jit(2, False, donate_wires=True)
+    fused = mesh_mod._mesh_fused_packed_jit(one_chip, 2, False, donate_wires=True)
     _compile(
         "fused K=2, 1M slots x 4096 lanes, one chip",
-        fused.lower(
-            _state(one_chip), wire, wire,
-            jax.ShapeDtypeStruct((2,), jnp.int32), jax.ShapeDtypeStruct((2,), jnp.int64),
-        ),
+        fused.lower(_state(one_chip), wire, wire),
     )
 
 
@@ -119,11 +132,16 @@ def test_narrow_wire_compiles_for_one_v5e(one_chip):
     lane: the fall-back of the dict wire (a limit a key), and what warmup
     compiles beside it."""
     wire = _sharded(
-        one_chip, jax.ShapeDtypeStruct((buckets.LANE_WIRE_WORDS * LANES,), jnp.int32)
+        one_chip,
+        jax.ShapeDtypeStruct(
+            (buckets.LANE_WIRE_WORDS * LANES + buckets.WIRE_HEADER_WORDS,), jnp.int32
+        ),
     )
     _compile(
         "per-lane wire, 1M slots x 4096 lanes, one chip",
-        mesh_mod._rounds_lanes_mesh_jit.lower(_state(one_chip), wire, 1, NOW_MS),
+        mesh_mod._dispatch_jit(one_chip, mesh_mod._rounds_lanes_mesh).lower(
+            _state(one_chip), wire
+        ),
     )
 
 

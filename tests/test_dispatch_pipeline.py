@@ -208,22 +208,29 @@ def test_fused_kernel_matches_solo_sequence():
     lanes, cap = 64, 256
     slot = np.arange(lanes, dtype=np.int32)
 
-    def wire(hits, exists):
+    def wire(hits, exists, now):
         cols = make_columns(
             np.zeros(lanes, np.int32), np.zeros(lanes, np.int32),
             np.full(lanes, hits, np.int64), np.full(lanes, 100, np.int64),
             np.full(lanes, 60_000, np.int64), lanes,
         )
         _, (cfg, table) = buckets.build_config_dict(cols, NOW)
-        return buckets.pack_dict_wire(
+        w = buckets.pack_dict_wire(
             slot[None, :], np.full((1, lanes), exists, bool),
             np.ones((1, lanes), bool), cfg[None, :].astype(np.uint8),
             np.zeros((1, lanes), np.int32), np.zeros((1, lanes), np.int32),
             table,
-        )[0]
+        )
+        # The fused program reads each wire's round count and clock
+        # from its header; the solo kernel below takes them by hand.
+        buckets.set_wire_header(w, 1, now)
+        return w[0]
 
-    wires = [wire(1, False), wire(2, True), wire(3, True), wire(5, True)]
     nows = [NOW, NOW + 10, NOW + 20, NOW + 30]
+    wires = [
+        wire(1, False, nows[0]), wire(2, True, nows[1]),
+        wire(3, True, nows[2]), wire(5, True, nows[3]),
+    ]
 
     solo = jax.jit(buckets.apply_rounds_packed)
     solo_state = buckets.init_state(cap)
@@ -234,11 +241,9 @@ def test_fused_kernel_matches_solo_sequence():
 
     # One shard: every array carries the leading [S=1] axis.
     fused_state = jax.tree.map(lambda a: a[None], buckets.init_state(cap))
-    fn = mesh._mesh_fused_packed_jit(4, wide=False, donate_wires=False)
-    fused_state, stacked = fn(
-        fused_state, *[np.array(w)[None] for w in wires],
-        np.ones(4, np.int32), np.asarray(nows, np.int64),
-    )
+    one_device = mesh.make_mesh(jax.devices()[:1])
+    fn = mesh._mesh_fused_packed_jit(one_device, 4, wide=False, donate_wires=False)
+    fused_state, stacked = fn(fused_state, *[np.array(w)[None] for w in wires])
     stacked = np.asarray(stacked)  # [k, S, 4, P]
     for i in range(4):
         assert np.array_equal(stacked[i, 0], solo_out[i]), f"sub-batch {i}"

@@ -87,7 +87,9 @@ def test_what_the_host_packs_the_device_unpacks_bit_for_bit(shards, pad, wide):
         tuple(plan["values"][k] for k in VALUE_NAMES), wide=wide)
     words = buckets.LANE_WIRE_WORDS_WIDE if wide else buckets.LANE_WIRE_WORDS
     assert (words, buckets.LANE_WIRE_WORDS) == (16 if wide else 11, 11)
-    assert wire.dtype == np.int32 and wire.shape == (shards, words * pad)
+    assert wire.dtype == np.int32
+    assert wire.shape == (shards, words * pad + buckets.WIRE_HEADER_WORDS)
+    assert not wire[:, words * pad:].any()  # the header: the stage's to fill
 
     sharding = _sharding(shards)
     unpack = jax.jit(jax.vmap(lambda w: buckets.unpack_lane_wire(w, wide)))
@@ -148,6 +150,7 @@ def test_the_program_behind_the_buffer_answers_as_the_kernel_over_the_columns(sh
         np.full(n, 60_000, np.int64), np.zeros(n, np.int64), np.zeros(n, np.int64),
     )
     wire = buckets.pack_lane_wire(slot, zeros8, write, occ, rid, pos, values, wide=wide)
+    buckets.set_wire_header(wire, 3, NOW)
 
     vdt = np.int64 if wide else np.int32
     make = buckets.RequestBatch if wide else buckets.RequestBatch32
@@ -162,7 +165,9 @@ def test_the_program_behind_the_buffer_answers_as_the_kernel_over_the_columns(sh
     put = lambda tree: jax.device_put(tree, sharding)  # noqa: E731
     fresh = lambda: put(jax.vmap(lambda _: buckets.init_state(slots))(jnp.arange(shards)))  # noqa: E731
     want_state, want = direct(fresh(), put(req), put(rid))
-    got_state, got = mesh_mod._rounds_lanes_mesh_jit(fresh(), put(wire), 3, NOW, wide=wide)
+    program = mesh_mod._dispatch_jit(
+        sharding.mesh, mesh_mod._rounds_lanes_wide_mesh if wide else mesh_mod._rounds_lanes_mesh)
+    got_state, got = program(fresh(), put(wire))
     assert got.dtype == want.dtype == vdt and got.shape == (shards, 4, pad)
     assert (np.asarray(got) == np.asarray(want)).all()
     assert np.asarray(got)[:, 1].max() > (2**32 if wide else 0)  # `remaining`: real answers
