@@ -18,9 +18,9 @@ def frame_payload(pop, idx, hits, host: str) -> bytes:
     body = gubc.encode_frame(
         gubc.fixed_width_column(name * n, n, len(name)),
         gubc.fixed_width_column(pop.keys_blob(idx), n, pop.key_width),
-        pop.algo[idx], np.zeros(n, np.int32),
+        pop.algo[idx], pop.behavior[idx],
         np.broadcast_to(np.asarray(hits, np.int64), (n,)), pop.limit[idx],
-        np.full(n, pop.duration_ms, np.int64),
+        pop.duration[idx],
     )
     return gubc.http_request(host, gubc.COLUMNS_CONTENT_TYPE, body)
 

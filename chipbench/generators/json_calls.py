@@ -18,7 +18,7 @@ def build_pool(pop, params: dict, rng, host: str) -> list:
     for row in idx:
         body = gubc.encode_json_call([
             (pop.name, pop.unique_key(i), int(pop.algo[i]), hits, int(pop.limit[i]),
-             pop.duration_ms)
+             int(pop.duration[i]), int(pop.behavior[i]))
             for i in row.tolist()
         ])
         pool.append(Request(gubc.http_request(host, gubc.JSON_CONTENT_TYPE, body), row, hits))

@@ -78,11 +78,13 @@ def decode_answer_frame(raw: bytes, n_sent: int):
 
 
 def encode_json_call(checks) -> bytes:
-    """`checks`: (name, unique_key, algorithm, hits, limit, duration) tuples."""
+    """`checks`: (name, unique_key, algorithm, hits, limit, duration, behavior)
+    tuples; `behavior` is the proto's bit set as a number (0, or 4 with
+    `duration` a calendar interval number)."""
     return json.dumps({"requests": [
         {"name": name, "uniqueKey": key, "hits": str(hits), "limit": str(limit),
-         "duration": str(duration), "algorithm": ALGORITHM_NAMES[algo], "behavior": 0}
-        for name, key, algo, hits, limit, duration in checks
+         "duration": str(duration), "algorithm": ALGORITHM_NAMES[algo], "behavior": behavior}
+        for name, key, algo, hits, limit, duration, behavior in checks
     ]}, separators=(",", ":")).encode()
 
 

@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from . import gubc, reference, trace_reduce
+from . import gregorian, gubc, reference, trace_reduce
 from .generators import frames
 from .daemon import OUT_DIR, REPO, BenchFailure, DaemonProc, Http, metric_sum
 from .loadgen import LoadGen, wall_ceil_ms, wall_floor_ms
@@ -35,6 +35,8 @@ REHEARSE_KEYS = 20_000
 REHEARSE_SLOTS = 32_768
 READBACK_SAMPLE = 65_536
 READBACK_HOTTEST = 1_024
+NATIVE_INGRESS = "gubernator_native_ingress_batches_total"  # {stat=...}: what the C++ ingress lane has counted
+BOUNDARY_MARGIN_S = 2.0  # past a calendar boundary by this much before the first key is loaded
 
 
 def say(msg: str) -> None:
@@ -69,6 +71,37 @@ def find_cell(bench: dict, name: str) -> "tuple[dict, dict, dict]":
 # ----------------------------------------------------------------------
 # Phases outside the window
 # ----------------------------------------------------------------------
+def wait_past_boundary(pop: Population, clock=time.time, sleep=time.sleep) -> float:
+    """A calendar bucket resets at its interval's end (midnight UTC for a daily
+    quota), and the reference's closed forms hold for buckets that do not reset
+    between their load and their read-back.  So no key is loaded while a
+    boundary of a unit the population holds lies within `horizon_s`, the
+    longest the load, ramp, window and read-back take together: the run waits
+    until the boundary has passed.  It waits HERE, after `listening`, so that a
+    cold start's compiles run meanwhile.  Returns the seconds waited."""
+    def ahead_s() -> float:
+        now_ms = int(clock() * 1000)
+        return (int(gregorian.boundary_ms(now_ms, pop.calendar_units).min()) - now_ms) / 1e3
+
+    if not pop.calendar_units or ahead_s() > pop.calendar_horizon_s:
+        return 0.0
+    pause = ahead_s() + BOUNDARY_MARGIN_S
+    say(f"  calendar: a boundary of units {pop.calendar_units} lies {pause - BOUNDARY_MARGIN_S:.1f} s "
+        f"ahead, inside the {pop.calendar_horizon_s:g} s a run's load, window and read-back may "
+        f"take: waiting {pause:.1f} s (it counts in setup_s)")
+    sleep(pause)
+    if ahead_s() <= pop.calendar_horizon_s:
+        raise BenchFailure(f"calendar units {pop.calendar_units} meet a boundary every "
+                           f"{pop.calendar_horizon_s:g} s or less: no run fits between two")
+    return pause
+
+
+def boundary_crossed(pop: Population, from_ms: float, to_ms: float) -> bool:
+    """Whether a boundary of a unit the population holds fell in [from_ms, to_ms]."""
+    return bool(pop.calendar_units) and bool(
+        (gregorian.boundary_ms(int(from_ms), pop.calendar_units) <= to_ms).any())
+
+
 def load_population(http: Http, pop: Population, lanes: int, host: str) -> "tuple[np.ndarray, np.ndarray, int]":
     """Every key once with one hit, in frames of exactly `lanes` lanes, one in
     flight.  The tail frame is filled with re-reads (hits=0) of loaded token
@@ -192,8 +225,13 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
             raise BenchFailure(f"{device['count']} devices in the daemon, the cell asks for {chips}")
         compiles = doc.get("compiles") or {}
         compile_s = sum(float(r.get("total_s", 0.0)) for r in compiles.values())
-        size_at_start = metric_sum(http.scrape(), "gubernator_cache_size")
+        at_start = http.scrape()
+        size_at_start = metric_sum(at_start, "gubernator_cache_size")
 
+        if wait_past_boundary(pop):  # the daemon may have dropped a connection that idled so long
+            http.close()
+            http = Http(daemon.http)
+        load_begin_ms = wall_floor_ms()
         t = time.perf_counter()
         load_lo, load_hi, load_wrong = load_population(http, pop, int(traffic["load_lanes"]), host)
         load_s = time.perf_counter() - t
@@ -248,11 +286,22 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
             pop, asked, answers, sample, load_lo[sample], load_hi[sample], rb[4], rb[5], *rb[:4]
         )
         readback_s = time.perf_counter() - t
+        if boundary_crossed(pop, load_begin_ms, wall_ceil_ms()):
+            raise BenchFailure(
+                f"a calendar boundary of units {pop.calendar_units} fell between the load and the "
+                f"read-back, which took {(wall_ceil_ms() - load_begin_ms) / 1e3:.1f} s where the "
+                f"configuration's calendar.horizon_s says at most {pop.calendar_horizon_s:g}: buckets "
+                "reset inside the run, so it has no verdict")
 
         # ---- verify: what the daemon counts ------------------------------
         final = snapshot(http)
         rows = final["device"]["devices"]
         compared += daemon_counts(final, http.get_json("/debug/audit"), pop.n, size_at_start)
+        ingress = "; ".join(
+            f"{when}: " + ", ".join(f"{stat} {metric_sum(rows_at, NATIVE_INGRESS, label):g}"
+                                   for stat, label in (("frames", '"frames"'), ("fallbacks", '"fallbacks"')))
+            for when, rows_at in (("before the load", at_start), ("after the read-back", final["metrics"])))
+        say(f"  native ingress lane: {ingress} (fallbacks: frames the C++ lane handed to the Python path whole)")
         recompiles = steady_recompiles.read({"before": before, "after": after}, {})
         say(f"  xla.compiles_in_window {recompiles:g} (programs compiled after warm-up, ramp and window)")
 
@@ -325,6 +374,9 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
         )
         line["device"].update(extra["device"])
         line["breakdown"] = extra["breakdown"]
+    # Every number compared beside its limit, last in the line: what the driver's
+    # record keeps of a run that is not correct.
+    line["compared"] = {c.name: {"value": c.value, "limit": c.limit, "ok": c.ok} for c in compared}
     if "jax" in sys.modules:  # the trace reader imports it; no backend may have come up
         from jax._src import xla_bridge
 

@@ -6,6 +6,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import gregorian
+
 _HEX = np.frombuffer(b"0123456789abcdef", np.uint8)
 
 
@@ -18,7 +20,17 @@ class Population:
     Limits come in `limit_tiers` values spaced evenly in the logarithm between
     `limit_min` and `limit_max`: a deployment sets limits per plan, not per
     user, and the program's dictionary wire holds 256 distinct
-    (algorithm, hits, limit, duration) rows a dispatch."""
+    (algorithm, hits, limit, duration) rows a dispatch.
+
+    Every key also has a `behavior` and a `duration`, which a frame carries a
+    lane at a time: 0 and the configuration's `duration_ms`, unless the
+    configuration has `calendar`: `{"share": s, "units": {"days": a, "months":
+    b}, "horizon_s": h}` makes a share `s` of the keys calendar quotas
+    (`DURATION_IS_GREGORIAN`, duration = upstream's interval number), their
+    units in the given shares; `horizon_s` is the longest that a run's load,
+    ramp, window and read-back take together (`harness.wait_past_boundary`).
+    The calendar's draws come from a stream of their own, so a configuration
+    without it keeps every other array bit for bit."""
 
     def __init__(self, spec: dict, n: int, seed: int):
         self.n = n
@@ -48,6 +60,21 @@ class Population:
         self._cdf = np.cumsum(weights)
         self._cdf /= self._cdf[-1]
         self.key_of_rank = rng.permutation(n).astype(np.int32)
+        self.behavior = np.zeros(n, np.int32)
+        self.duration = np.full(n, self.duration_ms, np.int64)
+        self.calendar_units: list = []  # the interval numbers some key holds
+        self.calendar_horizon_s = 0.0
+        calendar = spec.get("calendar")
+        if calendar:
+            rng = np.random.default_rng([seed, 0x63616C])
+            names = sorted(calendar["units"])
+            shares = np.array([calendar["units"][u] for u in names], np.float64)
+            unit = rng.choice([gregorian.UNITS[u] for u in names], size=n, p=shares / shares.sum())
+            quota = rng.random(n) < float(calendar["share"])
+            self.behavior[quota] = gregorian.GREGORIAN
+            self.duration[quota] = unit[quota]
+            self.calendar_units = np.unique(unit[quota]).tolist()
+            self.calendar_horizon_s = float(calendar["horizon_s"])
 
     def draw(self, rng, size: int) -> np.ndarray:
         """`size` key indices, Zipfian over the resident set."""

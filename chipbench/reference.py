@@ -18,6 +18,18 @@ t} and M(t) = sup X over [t_load, t], the level is limit + X(t) - max(1, M(t))
 admitted hit later, or the load later, or the reading earlier, can only lower
 the level read; so the latest instants of the hits with the earliest of the
 reading bound it below, and the reverse above.
+
+Calendar quotas (`population.calendar`: `DURATION_IS_GREGORIAN`, a duration of
+2 = days or 4 = months; `gregorian.py`) change two things and nothing else,
+because no run holds a calendar boundary (`harness.wait_past_boundary`), so no
+bucket resets inside one.  (1) A calendar TOKEN bucket's `reset_time` is not
+its creation plus a duration but, exactly, the last millisecond of the UTC
+calendar interval that holds its load: upstream's `GregorianExpiration`,
+`boundary_ms - 1`.  (2) A calendar LEAKY bucket leaks `limit` tokens in
+upstream's `GregorianDuration` of that interval: 86,400,000 ms for a day, and
+for a month upstream's nanoseconds-less-milliseconds (`interval.go:97`, some
+1.8e18 ms: next to nothing leaks in a run), which the program reproduces and
+the reference holds it to; a plain key leaks them in `duration_ms`.
 """
 
 from __future__ import annotations
@@ -25,6 +37,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import numpy as np
+
+from . import gregorian
 
 TOKEN, LEAKY = 0, 1
 UNDER, OVER = 0, 1
@@ -84,14 +98,26 @@ def token_accounting(pop, a: Answers) -> "tuple[list, np.ndarray]":
     ], asked
 
 
+def leak_duration_ms(pop, keys, at_ms) -> np.ndarray:
+    """Milliseconds in which each of `keys` leaks its whole `limit`: the
+    configuration's `duration_ms`, or for a calendar quota upstream's
+    `GregorianDuration` of the interval that holds `at_ms` (its load)."""
+    out = np.full(len(keys), float(pop.duration_ms))
+    quota = pop.behavior[keys] == gregorian.GREGORIAN
+    if quota.any():
+        out[quota] = gregorian.interval_ms(at_ms[quota], pop.duration[keys][quota])
+    return out
+
+
 def leaky_levels(limit, duration_ms, load_lo, load_hi, read_lo, read_hi, key_slot,
                  hit_lo, hit_hi) -> "tuple[np.ndarray, np.ndarray]":
     """Bounds on the level (in tokens, not yet floored) of K leaky buckets at
-    their read-back.  `limit`, `load_*`, `read_*` have one entry a bucket: the
-    bracket of the load's and of the reading's instant.  `key_slot`, `hit_lo`,
-    `hit_hi` have one entry an ADMITTED hit: which bucket, and its bracket."""
+    their read-back.  `limit`, `duration_ms`, `load_*`, `read_*` have one entry
+    a bucket: what it leaks its limit in, and the bracket of the load's and of
+    the reading's instant.  `key_slot`, `hit_lo`, `hit_hi` have one entry an
+    ADMITTED hit: which bucket, and its bracket."""
     limit = limit.astype(np.float64)
-    rate = float(duration_ms) / limit  # ms a token
+    rate = duration_ms / limit  # ms a token
 
     def level(t_load, t_read, t_hit):
         order = np.lexsort((t_hit, key_slot))
@@ -121,16 +147,22 @@ def readback(pop, asked, a: Answers, sample, load_lo, load_hi, read_lo, read_hi,
     want_status = np.where(asked[sample] > lim - 1, OVER, UNDER)
     token_wrong = token & ((remaining != lim - 1 - u) | (status != want_status))
     # A token bucket's reset time is its creation plus the duration: one that
-    # was evicted and made again would show a later one.
+    # was evicted and made again would show a later one.  A calendar quota's
+    # is the last millisecond of the interval that holds its creation, exactly:
+    # of the one or (a load in flight at a boundary) two that its bracket meets.
+    quota = pop.behavior[sample] == gregorian.GREGORIAN
+    ends = [gregorian.expiry_ms(t[quota], pop.duration[sample][quota]) for t in (load_lo, load_hi)]
     born_wrong = token & (
         (reset_time < load_lo + pop.duration_ms) | (reset_time > load_hi + pop.duration_ms)
     )
+    born_wrong[quota] = token[quota] & (reset_time[quota] != ends[0]) & (reset_time[quota] != ends[1])
     slot_of = np.full(pop.n, -1, np.int64)
     leaky_keys = sample[~token]
     slot_of[leaky_keys] = np.arange(len(leaky_keys))
     hit = (a.status == UNDER) & (slot_of[a.key] >= 0)
     low, high = leaky_levels(
-        lim[~token], pop.duration_ms, load_lo[~token], load_hi[~token],
+        lim[~token], leak_duration_ms(pop, leaky_keys, load_lo[~token]),
+        load_lo[~token], load_hi[~token],
         read_lo[~token], read_hi[~token], slot_of[a.key[hit]],
         a.sent_ms[hit].astype(np.float64), a.recv_ms[hit].astype(np.float64),
     )
@@ -161,6 +193,7 @@ def leaky_admissions(pop, a: Answers, load_lo_all, t_end_ms: float) -> Compared:
     leaky = pop.algo == LEAKY
     admitted = np.bincount(a.key[a.status == UNDER], minlength=pop.n).astype(np.float64)
     lim = pop.limit.astype(np.float64)
-    room = (lim - 1.0) + (t_end_ms - load_lo_all) * lim / float(pop.duration_ms) + 1.0
+    duration = leak_duration_ms(pop, np.arange(pop.n), load_lo_all)
+    room = (lim - 1.0) + (t_end_ms - load_lo_all) * lim / duration + 1.0
     over = np.where(leaky, admitted - room, -np.inf)
     return Compared("accounting.leaky_admitted_beyond_leak", float(max(over.max(), 0.0)), 0.0)

@@ -3,7 +3,9 @@
 
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-The last line of standard output is the result (see chipbench/README.md).  It
+The last line of standard output is the result (see chipbench/README.md); its
+last key, `compared`, and the last lines of standard error give every number
+compared beside its limit.  It
 exits non-zero, with no result line, when the daemon's device is not a TPU or
 there are fewer chips than the cell asks for.  `--rehearse` runs the cell at
 20,000 keys on whatever backend JAX finds and always ends `correct: false`,
@@ -54,6 +56,9 @@ def main(argv=None) -> int:
         print(f"FAILED:\n{traceback.format_exc()}", flush=True)
         return 1
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():  # and as the last lines of standard error
+        print(f"compared: {name} {c['value']:g} (limit {c['limit']:g}) {'ok' if c['ok'] else 'WRONG'}",
+              file=sys.stderr, flush=True)
     return status
 
 

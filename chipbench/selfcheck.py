@@ -6,7 +6,9 @@
 BENCHMARK.json keeps to the contract's names and limits; every cell's
 configuration, traffic, metric and reader files are found by name; the
 accounting check passes a sound stream and fails one with a lost and one with a
-doubled hit; the leaky bracket holds the sequential oracle; `trace_reduce`
+doubled hit; the leaky bracket holds the sequential oracle; calendar quotas pass
+sound and fail, each by its own comparison alone, with a token bucket that
+ignores the bit and a daily leaky bucket that leaks by the hour; `trace_reduce`
 gives the known busy share of the recorded trace; the bytes function matches
 the program's shapes.  Exit status 0 when all hold."""
 
@@ -23,7 +25,7 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from chipbench import oracle, reference, roofline, trace_reduce  # noqa: E402
+from chipbench import gregorian, oracle, reference, roofline, trace_reduce  # noqa: E402
 from chipbench.population import Population  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -111,31 +113,50 @@ POP_SPEC = {"name": "t", "leaky_share": 0.5, "duration_ms": 3_600_000, "limit_mi
             "limit_max": 40, "limit_tiers": 6, "zipf_theta": 0.99}
 
 
-def drive_oracle(pop, rng, fault=None):
+ROOMY = dict(limit_min=2000, limit_max=4000)  # no bucket runs dry, and a daily one leaks 2-3 tokens in a stream
+CALENDAR_SPEC = dict(POP_SPEC, calendar={"share": 0.7, "units": {"days": 0.5, "months": 0.5},
+                                        "horizon_s": 300})
+T0_MS = 1_700_000_000_000  # 2023-11-14 22:13:20 UTC: no calendar boundary within a stream
+
+
+def drive_oracle(pop, rng, fault=None, start_ms=T0_MS, pace=1.0):
     """A daemon that is the sequential oracle: load every key, then 60 frames
-    of 64 Zipfian checks in time order, then read every key back.  `fault`
+    of 64 Zipfian checks in time order, then read every key back (about 70 s of
+    the oracle's clock from `start_ms`; `pace` scales every step).  Returns the
+    numbers compared and the milliseconds the stream took.  `fault`
     "lost" or "doubled" drops or repeats the effect of one admitted token hit;
-    "leaky" takes four tokens too many from a leaky bucket, once."""
+    "leaky" takes four tokens too many from a leaky bucket, once;
+    "calendar_ignored" reads a calendar token bucket back with creation plus
+    `duration_ms` for its reset time, as a program that ignores the bit would;
+    "daily_by_the_hour" leaks a daily leaky bucket at an hour's rate."""
     orc = oracle.Oracle()
-    now = 1_700_000_000_000
+    now = start_ms
     key = [pop.unique_key(i) for i in range(pop.n)]
+    quota = pop.behavior == gregorian.GREGORIAN
+    hourly = quota & (pop.algo == reference.LEAKY) & (pop.duration == gregorian.UNITS["days"]) \
+        if fault == "daily_by_the_hour" else np.zeros(pop.n, bool)
+
+    def step(lo, hi):  # a step that cannot be 0 stays at least 1 ms at any pace
+        return max(min(lo, 1), round(int(rng.integers(lo, hi)) * pace))
 
     def apply(i, hits, t):
-        a = orc.apply(key[i], int(pop.algo[i]), hits, int(pop.limit[i]), pop.duration_ms, t)
+        duration, behavior = (pop.duration_ms, 0) if hourly[i] else (int(pop.duration[i]), int(pop.behavior[i]))
+        a = orc.apply(key[i], int(pop.algo[i]), hits, int(pop.limit[i]), duration, t, behavior)
         return a.status, a.limit, a.remaining, a.reset_time
 
     load_lo = np.empty(pop.n)
     load_hi = np.empty(pop.n)
     for i in range(pop.n):
-        now += int(rng.integers(0, 3))
+        now += step(0, 3)
         apply(i, 1, now)
         load_lo[i], load_hi[i] = now - 2, now + 3
+    loaded_at = load_lo + 2
     rows = []
-    faulted = fault is None
+    faulted = fault not in ("lost", "doubled", "leaky")
     kind = reference.LEAKY if fault == "leaky" else reference.TOKEN
     hot = next(int(k) for k in pop.key_of_rank if pop.algo[k] == kind)
     for _ in range(60):
-        now += int(rng.integers(1, 2000))
+        now += step(1, 2000)
         for i in pop.draw(rng, 64).tolist():
             t = now + int(rng.integers(0, 5))
             got = apply(i, 1, t)
@@ -148,38 +169,63 @@ def drive_oracle(pop, rng, fault=None):
     r = np.array(rows, dtype=np.int64)
     answers = reference.Answers(r[:, 0].astype(np.int32), r[:, 1], r[:, 2], r[:, 3],
                                 r[:, 4].astype(np.float64), r[:, 5].astype(np.float64))
-    now += 500
+    now += step(500, 501)
     sample = np.arange(pop.n)
     back = np.array([apply(i, 0, now) for i in range(pop.n)], dtype=np.int64)
+    if fault == "calendar_ignored":
+        ignored = quota & (pop.algo == reference.TOKEN)
+        back[ignored, 3] = loaded_at[ignored] + pop.duration_ms
     compared, asked = reference.token_accounting(pop, answers)
     compared += reference.readback(
         pop, asked, answers, sample, load_lo, load_hi, np.full(pop.n, now - 3.0),
         np.full(pop.n, now + 4.0), back[:, 0], back[:, 1], back[:, 2], back[:, 3])
     compared.append(reference.leaky_admissions(pop, answers, load_lo, float(now)))
-    return compared
+    return compared, now - start_ms
 
 
 def check_accounting() -> None:
     worst_leaky = 0.0
     for seed in range(8):
         pop = Population(POP_SPEC, 200, seed)
-        compared = drive_oracle(pop, np.random.default_rng(seed))
+        compared, _ = drive_oracle(pop, np.random.default_rng(seed))
         bad = [c.line() for c in compared if not c.ok]
         worst_leaky = max(worst_leaky, next(
             c.value for c in compared if c.name == "readback.leaky_tokens_outside_bracket"))
         check(not bad, f"seed {seed}: the sequential oracle's answers pass every comparison {bad}")
     print(f"      (largest leaky reading outside the continuous bracket, 8 seeds: {worst_leaky:g} tokens)")
-    roomy = dict(POP_SPEC, limit_min=2000, limit_max=4000)  # no bucket runs dry: the read-back sees it too
+    roomy = dict(POP_SPEC, **ROOMY)  # no bucket runs dry: the read-back sees it too
     for fault in ("lost", "doubled"):
         pop = Population(roomy, 200, 99)
-        failing = [c.name for c in drive_oracle(pop, np.random.default_rng(99), fault) if not c.ok]
+        failing = failing_of(pop, fault)
         check(any(n.startswith("accounting.token") for n in failing)
               and "readback.token_keys_wrong" in failing,
               f"one {fault} hit fails the accounting and the read-back: {failing}")
-    failing = [c.name for c in drive_oracle(Population(roomy, 200, 99), np.random.default_rng(99), "leaky")
-               if not c.ok]
+    failing = failing_of(Population(roomy, 200, 99), "leaky")
     check(failing == ["readback.leaky_tokens_outside_bracket"],
           f"four tokens taken too many from a leaky bucket fail its bracket, and only it: {failing}")
+
+
+def failing_of(pop, fault, seed=99) -> list:
+    return [c.name for c in drive_oracle(pop, np.random.default_rng(seed), fault)[0] if not c.ok]
+
+
+def check_calendar() -> None:
+    """Calendar quotas (`population.calendar`): daily and monthly keys among
+    plain ones, through the oracle's Gregorian branch."""
+    roomy = dict(CALENDAR_SPEC, **ROOMY)
+    bad = []
+    for seed in range(16):
+        pop = Population(CALENDAR_SPEC if seed < 8 else roomy, 200, seed)
+        bad += [f"seed {seed}: {c.line()}" for c in drive_oracle(pop, np.random.default_rng(seed))[0]
+                if not c.ok]
+    check(not bad, f"16 seeds: a sound stream of daily and monthly quotas passes every comparison {bad}")
+    failing = failing_of(Population(roomy, 200, 99), "calendar_ignored")
+    check(failing == ["readback.token_keys_born_outside_load"],
+          "calendar token buckets that reset at creation + duration_ms (the bit ignored) fail "
+          f"the reset time, and only it: {failing}")
+    failing = failing_of(Population(roomy, 200, 99), "daily_by_the_hour")
+    check(failing == ["readback.leaky_tokens_outside_bracket"],
+          f"daily leaky buckets that leak at an hour's rate fail their bracket, and only it: {failing}")
 
 
 # ----------------------------------------------------------------------
@@ -224,8 +270,10 @@ def check_bytes() -> None:
     table = tuple(np.zeros(buckets.DICT_TABLE_ROWS, np.int64) for _ in range(7))
     wire = np.asarray(buckets.pack_dict_wire(z, z, z, z.astype(np.uint8), z, z, table))
     check(wire.dtype == np.int32
-          and wire.shape[1] - buckets.DICT_WIRE_TABLE_WORDS == roofline.WIRE_WORDS_IN_PER_LANE * p,
-          f"the dictionary wire carries {roofline.WIRE_WORDS_IN_PER_LANE} i32 words a lane beside its table")
+          and wire.shape[1] - buckets.DICT_WIRE_TABLE_WORDS - buckets.WIRE_HEADER_WORDS
+          == roofline.WIRE_WORDS_IN_PER_LANE * p,
+          f"the dictionary wire carries {roofline.WIRE_WORDS_IN_PER_LANE} i32 words a lane beside its "
+          "table and its header")
     want = 4 * (3 + 4) * 4096 + 3 * 32 * 3000
     check(roofline.dict_wire_dispatch_bytes(4096, 3000) == want,
           f"a 4096-lane dispatch over 3,000 distinct keys must move {want} bytes")
@@ -240,6 +288,7 @@ def main() -> int:
     bench = check_benchmark_json()
     check_files(bench)
     check_accounting()
+    check_calendar()
     check_trace()
     check_bytes()
     print(f"{len(FAILURES)} wrong" if FAILURES else "all hold")

@@ -2,6 +2,10 @@
 keys, CPU backend) passes every comparison, and the same run with the
 configuration's control population (twice the table's slots, so acknowledged
 buckets are evicted) fails the accounting, the read-back and the cache count.
+`greg-10m.frames`, the candidate of `chipbench/candidates/`, is rehearsed among
+them: every lane of its load, window and read-back a calendar quota.  Its
+calendar guarantee no population can break; the run that must fail for it is
+`test_broken_path.py`'s, on a daemon that drops the bit.
 
     python3 -m pytest chipbench/tests -q        (about a minute a test)
 """
@@ -29,7 +33,8 @@ def rehearse(workload: str, seed: int, *flags: str) -> "tuple[dict, str]":
     return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
 
 
-@pytest.mark.parametrize("workload", ["v5e1-1m.frames", "v5e1-1m.singles", "v5e4-mesh-1m.frames"])
+@pytest.mark.parametrize("workload", ["v5e1-1m.frames", "v5e1-1m.singles", "v5e4-mesh-1m.frames",
+                                      "greg-10m.frames"])
 def test_sound_rehearsal_passes_every_comparison_and_is_never_correct(workload):
     line, out = rehearse(workload, 7)
     assert line["checks_ok"] is True, out[-3000:]
@@ -37,9 +42,10 @@ def test_sound_rehearsal_passes_every_comparison_and_is_never_correct(workload):
     assert line["failed"] == 0
 
 
-@pytest.mark.parametrize("seed", [11, 2**31 + 5, 13])
-def test_control_population_fails(seed):
-    line, out = rehearse("v5e1-1m.frames", seed, "--control")
+@pytest.mark.parametrize("workload,seed", [("v5e1-1m.frames", 11), ("v5e1-1m.frames", 2**31 + 5),
+                                           ("v5e1-1m.frames", 13), ("greg-10m.frames", 2**31 + 7)])
+def test_control_population_fails(workload, seed):
+    line, out = rehearse(workload, seed, "--control")
     assert line["checks_ok"] is False and line["correct"] is False
     failing = [row.split()[1] for row in out.splitlines() if row.endswith("WRONG")]
     assert "daemon.cache_rows_missing" in failing  # (c) nothing evicted

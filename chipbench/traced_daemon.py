@@ -2,10 +2,12 @@
 """The daemon of a `--trace 1` run: the same `gubernator_tpu.cmd.server` entry,
 called in this process, plus one thread that on SIGUSR1 records a device trace
 of CHIPBENCH_TRACE_SECONDS into CHIPBENCH_TRACE_DIR.  Only the process that
-holds the chip can trace it, and `POST /debug/profile` needs GUBER_TRACE_SAMPLE
-> 0, which switches the native ingress lane off: a run traced that way would
-serve on another path than the one timed.  With CHIPBENCH_LOG_PADS=<file> it
-also appends the pad of every columnar dispatch to that file (rehearsals)."""
+holds the chip can trace it.  The daemon's own `POST /debug/profile` would not
+do: it needs GUBER_TRACE_SAMPLE > 0 (sampled spans that the timed runs do not
+pay; the native ingress lane stays on under it), and it writes its dump into a
+directory of `mkdtemp`'s choosing, outside the checkout, where a run may write
+only inside it.  With CHIPBENCH_LOG_PADS=<file> it also appends the pad of
+every columnar dispatch to that file (rehearsals)."""
 
 from __future__ import annotations
 
@@ -42,8 +44,8 @@ def _log_pads(path: str) -> None:
 
     inner = mesh.MeshBucketStore._prepare_columns
 
-    def logged(self, keys, cols, now_ms, force_wire=None):
-        prep = inner(self, keys, cols, now_ms, force_wire)
+    def logged(self, keys, cols, now_ms, force_wire=None, bt=None):
+        prep = inner(self, keys, cols, now_ms, force_wire, bt)
         with open(path, "a") as f:
             f.write(f"{prep.n} {prep.padded} {prep.n_rounds}\n")
         return prep
