@@ -38,6 +38,7 @@ from .config import (
     MAX_BATCH_SIZE,
     PEER_COLUMNS_MAX_LANES,
 )
+from .models.shard import greg_lanes, resolve_greg_columns
 from .service import ApiError, ColumnarResult, IngressColumns, V1Service
 from .types import Algorithm, RateLimitRequest, UpdatePeerGlobal, _parse_behavior
 
@@ -945,16 +946,19 @@ class NativeIngressPump:
     already-columnar common case."""
 
     # Behavior bits that demand the Python router (GLOBAL replica
-    # path, MULTI_REGION hit queueing, Gregorian resolution — and
-    # NO_BATCHING direct dispatch when the express lane is off): any
-    # lane carrying one makes the whole frame fall back.  This mask is
-    # the PR 13 set; with GUBER_EXPRESS on, NO_BATCHING moves out of
-    # the fallback mask and into the native EXPRESS queue instead
-    # (frames jump the ring, never the Python path — the bit means
-    # "skip coalescing waits", which the native loop satisfies
-    # directly).
-    FALLBACK_BEHAVIOR = 1 | 2 | 4 | 16
-    EXPRESS_FALLBACK_BEHAVIOR = 2 | 4 | 16
+    # path, MULTI_REGION hit queueing — and NO_BATCHING direct dispatch
+    # when the express lane is off): any lane carrying one makes the
+    # whole frame fall back.  With GUBER_EXPRESS on, NO_BATCHING moves
+    # out of the fallback mask and into the native EXPRESS queue
+    # instead (frames jump the ring, never the Python path — the bit
+    # means "skip coalescing waits", which the native loop satisfies
+    # directly).  DURATION_IS_GREGORIAN (4) is in neither mask: a
+    # calendar lane stays on this lane and `_submit` resolves it; the
+    # native submit hands a frame over whole only when such a lane's
+    # duration is not an interval upstream resolves (weeks, or outside
+    # 0-5), because the Python path owns the per-lane error wording.
+    FALLBACK_BEHAVIOR = 1 | 2 | 16
+    EXPRESS_FALLBACK_BEHAVIOR = 2 | 16
     EXPRESS_MASK = 1  # Behavior.NO_BATCHING
 
     #: Lane ceiling of one coalesced take = the device dispatch
@@ -1215,11 +1219,28 @@ class NativeIngressPump:
                     lane="native",
                 )
         t0 = time.perf_counter()
+        # ONE clock reading for the calendar resolve and the store: the
+        # expiry and the kernel's `greg_expire - now` cannot straddle a
+        # boundary.
+        now_ms = svc.clock.now_ms()
+        greg_expire = greg_duration = None
+        with phase("calendar.resolve", bt) as ph:
+            greg = greg_lanes(tb.behavior)
+            lanes = int(np.count_nonzero(greg))
+            distinct = 0
+            if lanes:
+                greg_expire, greg_duration, errors, distinct = (
+                    resolve_greg_columns(greg, tb.duration, now_ms)
+                )
+                if errors:
+                    # gt_ingress_submit hands such a frame to Python whole.
+                    raise ValueError(errors[0][1])
+            ph.note(lanes=lanes, durations=distinct)
         tracing.stage_batch_trace(bt)
         try:
             handle = svc.store.apply_columns_async(
                 tb.hash_keys, tb.algorithm, tb.behavior, tb.hits, tb.limit,
-                tb.duration, svc.clock.now_ms(),
+                tb.duration, now_ms, greg_expire, greg_duration,
             )
         finally:
             # A store that raised before consuming the staged trace must

@@ -165,6 +165,20 @@ class Metrics:
             ["path"],
             registry=self.registry,
         )
+        # -- calendar quotas (saturation.MeshTally's twins) -------------
+        self.calendar_lanes = Counter(
+            "gubernator_calendar_lanes_total",
+            "Lanes that carried DURATION_IS_GREGORIAN into a columnar "
+            "dispatch (`calendarLanes` of GET /debug/device `mesh`).",
+            registry=self.registry,
+        )
+        self.wide_dispatches = Counter(
+            "gubernator_wide_dispatches_total",
+            "Columnar dispatches whose answer was i64: a value or a "
+            "time passed int32, as a monthly or yearly calendar lane's "
+            "do (`wideDispatches` of GET /debug/device `mesh`).",
+            registry=self.registry,
+        )
         self.express_hit_ratio = Gauge(
             "gubernator_express_hit_ratio",
             "Fraction of batcher/native ingress lanes that took an "
@@ -769,6 +783,9 @@ class Metrics:
             lab(stat="ratio").set(lanes / padded)
         busy, elapsed = saturation.dispatcher_busy.take()
         self.dispatcher_busy_ratio.set(min(busy / elapsed, 1.0))
+        tally = saturation.mesh_tally.wire_snapshot()
+        self._bump(self.calendar_lanes, tally["calendarLanes"])
+        self._bump(self.wide_dispatches, tally["wideDispatches"])
         # Express lane: per-path lane deltas since the last scrape plus
         # the cumulative hit rate (saturation.ExpressStats).
         for path, lanes in saturation.express.take().items():

@@ -120,6 +120,50 @@ class GregResolver:
         return cached
 
 
+_GREG_KINDS = 6  # upstream's interval kinds, 0 (minutes) .. 5 (years)
+
+
+def greg_lanes(behavior) -> np.ndarray:
+    """The lanes of a behaviour column that carry DURATION_IS_GREGORIAN."""
+    return (np.asarray(behavior) & int(Behavior.DURATION_IS_GREGORIAN)) != 0
+
+
+def resolve_greg_columns(greg: np.ndarray, duration, now_ms: int):
+    """`GregResolver` over a frame's columns at ONE clock reading.
+
+    `greg` masks the calendar lanes (`greg_lanes` of the behaviour
+    column, less whatever lanes the caller serves elsewhere).  `now` is
+    one for the take and a duration has at most six kinds, so each kind
+    the frame holds is resolved once (`distinct` of them) and the lanes
+    gather from the six-row table: no Python loop over lanes.  Returns
+    `(greg_expire, greg_duration, errors, distinct)`: two i64 columns
+    (0 on every other lane) and, for the lanes upstream answers with an
+    error (weeks, or a duration that is no interval kind), `(lane
+    indices, message)` pairs, their columns left 0."""
+    duration = np.asarray(duration)
+    # Row _GREG_KINDS of the tables: a lane off the mask, or one whose
+    # duration is no kind; row 3 (weeks) stays 0 too.
+    kind = np.where(
+        greg & (duration >= 0) & (duration < _GREG_KINDS), duration, _GREG_KINDS
+    )
+    held = np.bincount(kind, minlength=_GREG_KINDS + 1)
+    expire = np.zeros(_GREG_KINDS + 1, np.int64)
+    length = np.zeros(_GREG_KINDS + 1, np.int64)
+    errors = []
+    resolver = GregResolver(now_ms)
+    kinds = np.flatnonzero(held[:_GREG_KINDS]).tolist()
+    for k in kinds:
+        cached = resolver.resolve(k)
+        if isinstance(cached, gregorian.GregorianError):
+            errors.append((np.flatnonzero(kind == k), str(cached)))
+        else:
+            expire[k], length[k] = cached
+    invalid = greg & (kind == _GREG_KINDS)
+    if invalid.any():
+        errors.append((np.flatnonzero(invalid), gregorian.ERR_INVALID))
+    return expire[kind], length[kind], errors, len(kinds)
+
+
 def prepare_requests(
     requests: Sequence[RateLimitRequest],
     now_ms: int,
@@ -320,7 +364,7 @@ class _Columns:
     """Request fields as contiguous arrays (one slot per valid lane)."""
 
     __slots__ = ("algo", "behavior", "hits", "limit", "duration",
-                 "greg_expire", "greg_duration")
+                 "greg_expire", "greg_duration", "calendar_lanes")
 
     def __init__(self, n: int):
         self.algo = np.empty(n, dtype=np.int32)
@@ -330,6 +374,7 @@ class _Columns:
         self.duration = np.empty(n, dtype=np.int64)
         self.greg_expire = np.zeros(n, dtype=np.int64)
         self.greg_duration = np.zeros(n, dtype=np.int64)
+        self.calendar_lanes = 0
 
 
 _I32_MAX = (1 << 31) - 1
@@ -365,6 +410,11 @@ def make_columns(algorithm, behavior, hits, limit, duration, n,
     )
     cols.greg_duration = (
         z if greg_duration is None else np.ascontiguousarray(greg_duration, np.int64)
+    )
+    # Resolved calendar lanes (a resolved interval's length is never 0);
+    # a caller that resolved none passes no column, and nothing is counted.
+    cols.calendar_lanes = (
+        0 if greg_duration is None else int(np.count_nonzero(cols.greg_duration))
     )
     return cols
 
@@ -828,6 +878,7 @@ class ColumnarPipeline:
         saturation.mesh_tally.add(
             shards, prep.n, padded, fullest, prep.n_rounds,
             staged.lane_wire, staged.config_rows, staged.uploads,
+            cols.calendar_lanes, staged.wide,
         )
         self._launch_in_order(handle, staged)
         return handle
@@ -1009,11 +1060,16 @@ class ColumnarPipeline:
         # One program per group (fused or solo) — counted, not timed:
         # the zero-extra-dispatch telemetry contract asserts on this.
         self.device_dispatches += 1
-        # lazy=wide: warmup deliberately defers the wide int64 wire
-        # programs ("compile lazily" in mesh warmup), so their first
-        # post-steady compile is by design, not shape churn.
-        with telemetry.program(self._program_label(group),
-                               lazy=group[0][0].wide):
+        # lazy: the wide (i64) programs warm-up deliberately defers,
+        # the per-lane wire's and the fused launches', so their first
+        # post-steady compile is by design, not shape churn.  The
+        # dictionary wire's solo wide program is warmed (a monthly
+        # calendar lane takes it): a compile of it counts.
+        staged = group[0][0]
+        with telemetry.program(
+            self._program_label(group),
+            lazy=staged.wide and (staged.lane_wire or len(group) > 1),
+        ):
             if len(group) == 1:
                 staged, h = group[0]
                 self.state, packed = staged.solo(self.state)

@@ -3065,8 +3065,9 @@ void gt_http_free(void* sv) {
 //     tell the native loop from the PR 8 path.
 //
 // Lanes that need Python semantics (GLOBAL replication, MULTI_REGION
-// queueing, Gregorian durations, per-lane validation errors, sampled
-// traces, remote owners) make the WHOLE frame fall back: correctness
+// queueing, a Gregorian duration upstream answers with an error,
+// per-lane validation errors, sampled traces, remote owners) make the
+// WHOLE frame fall back: correctness
 // never depends on the fast lane, it only removes interpreter time
 // from the already-columnar common case.  NO_BATCHING lanes are the
 // express-lane exception (PR 14): with GUBER_EXPRESS on they stay
@@ -3234,6 +3235,8 @@ void gt_ingress_set_ring(void* bv, const uint64_t* vh, const uint8_t* vself,
   b->express_mask = express_mask;
 }
 
+constexpr int32_t kBehaviorGregorian = 4;  // Behavior.DURATION_IS_GREGORIAN
+
 // The fast-lane entry (see the banner for the contract).  Returns 0 =
 // handled natively; >0 = Python fallback reason (1 malformed/bad-utf8,
 // 2 trace trailer, 3 empty/oversize, 4 slow behavior bits, 5
@@ -3277,15 +3280,23 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   int64_t n = info.n;
   if (n == 0 || n > max_frame_lanes) return bump_fallback(3);
   const char* body = p->body.data();
-  // Slow behavior bits (GLOBAL / MULTI_REGION / Gregorian — and
-  // NO_BATCHING when the express lane is off) need the Python
-  // router's semantics.  With the express lane on, NO_BATCHING lanes
-  // instead flag the frame for the express queue below.
+  // Slow behavior bits (GLOBAL / MULTI_REGION — and NO_BATCHING when
+  // the express lane is off) need the Python router's semantics.  With
+  // the express lane on, NO_BATCHING lanes instead flag the frame for
+  // the express queue below.  A DURATION_IS_GREGORIAN lane stays here
+  // (the pump resolves its interval) unless its duration is not one
+  // upstream resolves, weeks (3) or anything outside 0-5: the Python
+  // path owns that lane's error wording (utils/gregorian.py).
   bool xpress = false;
   for (int64_t i = 0; i < n; ++i) {
     int32_t bh;
     memcpy(&bh, body + info.beh_pos + 4 * i, 4);
     if (bh & behavior_mask) return bump_fallback(4);
+    if (bh & kBehaviorGregorian) {
+      int64_t d;
+      memcpy(&d, body + info.dur_pos + 8 * i, 8);
+      if (d < 0 || d > 5 || d == 3) return bump_fallback(4);
+    }
     if (bh & express_mask) xpress = true;
   }
   // Build the packed hash keys + validation codes (the gt_frame_fill

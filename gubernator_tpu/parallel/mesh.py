@@ -1922,11 +1922,13 @@ class MeshBucketStore(ColumnarPipeline):
             # duplicate-heavy batch dispatches — without this, a
             # hot-key storm's first dispatch pays that compile or load
             # inside a client RPC deadline).  Both wires get compiled
-            # with the narrow (i32) answer: the dictionary wire and,
-            # forced by "narrow", the per-lane wire that a batch of
-            # more than 256 configurations takes (the wide int64
-            # answer of either is rare enough to pay its compile
-            # lazily).  1ms duration so the slots recycle.
+            # with the narrow (i32) answer here: the dictionary wire
+            # and, forced by "narrow", the per-lane wire that a batch
+            # of more than 256 configurations takes.  The dictionary
+            # wire's wide (i64) answer follows below; the per-lane
+            # wire's stays lazy (only a batch of more than 256
+            # configurations with a value past i32 reaches it).  1ms
+            # duration so the slots recycle.
             for lanes in sorted(set(warm_shapes or (1,))):
                 lanes = max(int(lanes), 1)
                 for keys in (
@@ -1947,12 +1949,22 @@ class MeshBucketStore(ColumnarPipeline):
             # first dispatch must not pay its executable load inside a
             # client deadline.  All-noop wires (slot=-1 lanes) thread
             # the state through unchanged.
+            # And the dictionary wire's WIDE answer at the same shapes:
+            # every frame that holds a monthly or yearly calendar lane
+            # takes it (narrow_ok: such a lane's expiry and duration
+            # pass i32), so a daemon that serves calendar quotas must
+            # not compile it inside a client's first such request.  The
+            # fused wide launches stay lazy: they need two wide takes
+            # in flight at once.
             S = self.n_shards
             with self._stats_lock:
                 shapes = sorted(self._seen_wire_shapes)
+            wide_fn = _dispatch_jit(
+                self.mesh, _rounds_packed_wide_mesh, donate_wire=self._wire_donate
+            )
             for W, narrow in shapes:
                 if not narrow:
-                    continue  # wide dict batches are rare: compile lazily
+                    continue
                 noop = np.zeros((S, W), dtype=np.int32)
                 # slot=-1: every lane inert
                 noop[:, :buckets.dict_wire_lanes(W)] = -1
@@ -1966,6 +1978,10 @@ class MeshBucketStore(ColumnarPipeline):
                         f"mesh:dispatch:fused{k}:narrow"
                     ):
                         self.state, _ = fn(self.state, *wires)
+                with self._lock, telemetry.program("mesh:dispatch:solo:wide"):
+                    self.state, _ = wide_fn(
+                        self.state, jax.device_put(noop, self._sharding)
+                    )
             if self.back is not None:
                 # Compile the tier-move program at every pad bucket the
                 # warm shapes dispatch (all-noop blocks): a plan closes

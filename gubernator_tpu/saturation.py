@@ -108,6 +108,11 @@ WATERFALL = (
     ("pump.depth_wait", 0),   # native take: the pipeline-depth semaphore
     ("pump.admit", 0),        # native take: ring counters, black-box tap, audit
                               # note, tenant fold, hot-key sketch
+    ("calendar.resolve", 0),  # DURATION_IS_GREGORIAN lanes -> greg_expire /
+                              # greg_duration at the dispatch's one clock
+                              # reading: every native take (its check for such
+                              # lanes included), and a Python-path request
+                              # that holds one
     ("express.submit", 0),    # express bypass: submit -> dispatch launched
                               # (replaces batch.window + queue.wait for
                               # express lanes — the express-vs-batched split;
@@ -411,22 +416,29 @@ class MeshTally:
     the difference from `dispatches` / `lanes`.  `configRows` sums the
     distinct configurations buckets.build_config_dict counted (0 where
     the dictionary was not tried: a forced wire, more than 255 rounds),
-    `uploads` the host-to-device transfer calls the stages made."""
+    `uploads` the host-to-device transfer calls the stages made.
+
+    And what the calendar adds: `calendarLanes` sums the lanes that
+    carried DURATION_IS_GREGORIAN, `wideDispatches` counts the
+    dispatches whose answer was i64 on either wire (a monthly or yearly
+    lane's expiry and duration pass i32: models/shard.py narrow_ok)."""
 
     WIRE_KEYS = ("dispatches", "lanes", "laneWireDispatches", "laneWireLanes",
-                 "configRows", "uploads")
+                 "configRows", "uploads", "calendarLanes", "wideDispatches")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._shards = 0
         self._sums = dict.fromkeys(
             ("dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
-             "laneWireDispatches", "laneWireLanes", "configRows", "uploads"), 0
+             "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
+             "calendarLanes", "wideDispatches"), 0
         )
 
     def add(self, shards: int, lanes: int, padded: int, fullest: int,
             rounds: int, lane_wire: bool = False, config_rows: int = 0,
-            uploads: int = 0) -> None:
+            uploads: int = 0, calendar_lanes: int = 0,
+            wide: bool = False) -> None:
         with self._lock:
             self._shards = shards
             s = self._sums
@@ -440,6 +452,8 @@ class MeshTally:
                 s["laneWireLanes"] += lanes
             s["configRows"] += config_rows
             s["uploads"] += uploads
+            s["calendarLanes"] += calendar_lanes
+            s["wideDispatches"] += wide
 
     def snapshot(self) -> Dict[str, int]:
         with self._lock:

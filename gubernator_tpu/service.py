@@ -38,6 +38,7 @@ from .config import MAX_BATCH_SIZE, PEER_COLUMNS_MAX_LANES, BehaviorConfig
 from .faults import Backoff
 from .federation import FederationManager
 from .metrics import Metrics
+from .models.shard import greg_lanes, resolve_greg_columns
 from .parallel.global_mgr import GlobalsColumns, HitColumns
 from .parallel.hash_ring import ReplicatedConsistentHash
 from .parallel.mesh import MeshBucketStore
@@ -1872,25 +1873,21 @@ class V1Service:
     # -- shared fast-lane halves of the two columnar entry points ------
     def _resolve_greg_fast(self, cols, beh, fast, result):
         """Gregorian precompute for fast lanes (slow lanes redo it in
-        prepare_requests; cheap, memoized per duration).  Mutates `fast`
-        for error lanes; returns (greg_expire, greg_duration) or Nones."""
-        n = len(cols)
-        greg_lanes = fast & ((beh & int(Behavior.DURATION_IS_GREGORIAN)) != 0)
-        if not greg_lanes.any():
+        prepare_requests): one vectorised resolve at one clock reading,
+        the one the native pump makes too.  Mutates `fast` for error
+        lanes; returns (greg_expire, greg_duration) or Nones."""
+        greg = fast & greg_lanes(beh)
+        if not greg.any():
             return None, None
-        from .models.shard import GregResolver
-        from .utils import gregorian as _greg
-
-        greg_expire = np.zeros(n, dtype=np.int64)
-        greg_duration = np.zeros(n, dtype=np.int64)
-        resolver = GregResolver(self.clock.now_ms())
-        for i in np.nonzero(greg_lanes)[0]:
-            cached = resolver.resolve(int(cols.duration[i]))
-            if isinstance(cached, _greg.GregorianError):
-                result.overrides[int(i)] = RateLimitResponse(error=str(cached))
-                fast[i] = False
-                continue
-            greg_expire[i], greg_duration[i] = cached
+        with phase("calendar.resolve") as ph:
+            greg_expire, greg_duration, errors, distinct = resolve_greg_columns(
+                greg, cols.duration, self.clock.now_ms()
+            )
+            ph.note(lanes=int(np.count_nonzero(greg)), durations=distinct)
+        for lanes, message in errors:
+            fast[lanes] = False
+            for i in lanes.tolist():
+                result.overrides[i] = RateLimitResponse(error=message)
         return greg_expire, greg_duration
 
     def _queue_mr_fast(self, cols, beh, fast, hash_keys) -> None:

@@ -130,6 +130,11 @@ EXTENSIONS = frozenset(
         "gubernator_blackbox_ring_bytes",
         "gubernator_blackbox_bundles",
         "gubernator_blackbox_last_trigger_age_seconds",
+        # PR 39: calendar quotas on the native lane: the lanes that
+        # carried DURATION_IS_GREGORIAN and the dispatches that took
+        # the i64 answer (twins of /debug/device `mesh`).
+        "gubernator_calendar_lanes",
+        "gubernator_wide_dispatches",
     }
 )
 

@@ -204,7 +204,8 @@ def test_debug_device_serves_the_mesh_block(served):
     assert mesh == saturation.mesh_tally.snapshot()
     assert set(mesh) == {
         "shards", "dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
-        "laneWireDispatches", "laneWireLanes", "configRows", "uploads"}
+        "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
+        "calendarLanes", "wideDispatches"}
     assert mesh["dispatches"] >= len(TAKE_FRAMES) and mesh["paddedLanes"] >= mesh["lanes"] > 0
 
 
