@@ -507,7 +507,7 @@ class _Staged:
     solo: "Optional[Callable]"  # state -> (state, packed); None = scalar
     fuse_key: object = None   # None = not fuse-eligible (fallback wire)
     wire_dev: object = None   # uploaded packed wire (dict-wire path)
-    wide: bool = False        # the ANSWER's width: i64, not i32 deltas
+    wide: bool = False        # the ANSWER: absolute lo/hi planes, not i32 deltas
     # The wire that carried it, for the mesh tally and the launch's
     # label: the per-lane wire (a word a value) or, by default, the
     # dictionary wire; the distinct configurations counted on the way
@@ -1016,9 +1016,10 @@ class ColumnarPipeline:
         """XLA-telemetry program identity for one launch group: solo vs
         fused-K, then the wire and the answer's width — the axes along
         which distinct programs compile.  A dictionary-wire launch is
-        named by its answer alone (`narrow`: i32 deltas, `wide`: i64);
-        a per-lane launch is `lanes` (i32 columns, narrow answer) or
-        `lanes64`."""
+        named by its answer alone (`narrow`: i32[S, 4, P] deltas,
+        `wide`: the absolute 64-bit answer as i32[S, 8, P] lo/hi
+        planes); a per-lane launch is `lanes` (i32 columns, narrow
+        answer) or `lanes64` (lo/hi pairs up, lo/hi planes down)."""
         staged = group[0][0]
         shape = "solo" if len(group) == 1 else f"fused{len(group)}"
         if staged.lane_wire:
@@ -1033,7 +1034,9 @@ class ColumnarPipeline:
         the staged wires: no host->device transfer, which
         tests/test_wire_header.py lets JAX refuse).  A multi-batch
         group rides ONE fused program; each handle's fetch reads its
-        slice of the shared stacked result, transferred once.
+        slice of the shared stacked result, transferred once.  Either
+        answer is a 32-bit array ([S, 4, P] narrow, [S, 8, P] wide;
+        [k, ...] stacked): no 64-bit array leaves the device here.
 
         A scalar-staged batch (the express singleton slot) never fuses
         (fuse_key None) and launches as a host-side evaluation instead:
@@ -1060,7 +1063,7 @@ class ColumnarPipeline:
         # One program per group (fused or solo) — counted, not timed:
         # the zero-extra-dispatch telemetry contract asserts on this.
         self.device_dispatches += 1
-        # lazy: the wide (i64) programs warm-up deliberately defers,
+        # lazy: the wide-answer programs warm-up deliberately defers,
         # the per-lane wire's and the fused launches', so their first
         # post-steady compile is by design, not shape churn.  The
         # dictionary wire's solo wide program is warmed (a monthly

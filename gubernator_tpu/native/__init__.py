@@ -1052,8 +1052,13 @@ class NativeMeshPlanner:
         return status[: self.n], remaining[: self.n], reset[: self.n]
 
     def finish_wide(self, packed_np):
-        """Decode + commit a wide i64[S, 4, P] result (absolute values)."""
-        packed_np = np.ascontiguousarray(packed_np, dtype=np.int64)
+        """Decode + commit a wide result (absolute values): the
+        i32[S, 8, P] lo/hi planes a wide program answers in
+        (ops/buckets.py WIDE_ANSWER_ROWS), handed through as they are;
+        the C++ loop composes each lane's 64 bits as it reads them."""
+        if packed_np.dtype != np.int32:
+            raise TypeError(f"the wide answer is i32 planes, not {packed_np.dtype}")
+        packed_np = np.ascontiguousarray(packed_np)
         status = np.empty(max(self.n, 1), dtype=np.int32)
         remaining = np.empty(max(self.n, 1), dtype=np.int64)
         reset = np.empty(max(self.n, 1), dtype=np.int64)

@@ -1587,9 +1587,12 @@ void gt_mesh_finish_narrow(void* mpv, const int32_t* packed, int64_t now_ms,
   }
 }
 
-// Phase 3 (wide wire): same shape over the packed i64[S, 4, P] result
-// with absolute values (ops/buckets.py _pack_output rows).
-void gt_mesh_finish_wide(void* mpv, const int64_t* packed, int32_t* status,
+// Phase 3 (wide wire): same shape over the packed wide result with
+// absolute values, as it leaves the device: i32[S, 8, P], the four rows
+// of ops/buckets.py _pack_output as their lo planes (rows 0-3) and then
+// their hi planes (rows 4-7).  A lane's 64 bits are put together here,
+// where every lane is walked anyway; the lo word is unsigned.
+void gt_mesh_finish_wide(void* mpv, const int32_t* packed, int32_t* status,
                          int64_t* remaining, int64_t* reset_time) {
   MeshPlan* mp = (MeshPlan*)mpv;
   int64_t P = mp->P;
@@ -1600,19 +1603,21 @@ void gt_mesh_finish_wide(void* mpv, const int64_t* packed, int32_t* status,
     if (m == 0) continue;
     GT_LOCK(mp->tables[s]);
     Batch* b = (Batch*)mp->batches[s];
-    const int64_t* row0 = packed + ((s * 4) + 0) * P;
-    const int64_t* row1 = packed + ((s * 4) + 1) * P;
-    const int64_t* row2 = packed + ((s * 4) + 2) * P;
-    const int64_t* row3 = packed + ((s * 4) + 3) * P;
+    const int32_t* lo = packed + s * 8 * P;
+    const int32_t* hi = lo + 4 * P;
+    auto row = [&](int k, int64_t j) {
+      return (int64_t)(((uint64_t)(uint32_t)hi[k * P + j] << 32) |
+                       (uint32_t)lo[k * P + j]);
+    };
     ne.resize(m);
     rm.resize(m);
     for (int64_t j = 0; j < m; ++j) {
       int32_t orig = mp->lanes[s][j];
-      status[orig] = (int32_t)(row0[j] & 1);
-      rm[j] = (uint8_t)((row0[j] >> 1) & 1);
-      remaining[orig] = row1[j];
-      reset_time[orig] = row2[j];
-      ne[j] = row3[j];
+      status[orig] = lo[j] & 1;
+      rm[j] = (uint8_t)((lo[j] >> 1) & 1);
+      remaining[orig] = row(1, j);
+      reset_time[orig] = row(2, j);
+      ne[j] = row(3, j);
     }
     gt_batch_commit_plan(b, ne.data(), rm.data());
   }

@@ -997,16 +997,61 @@ def apply_rounds_packed(
     return apply_rounds_dict(state, reqd, rid, n_rounds, now_ms, cold_cond=cold_cond)
 
 
+# The wide answer's container.  A dispatch program hands the host 32-bit
+# words in both directions: the wide wires go up as lo/hi pairs in one
+# i32 buffer, and the wide answer comes down as i32[8, B], the four rows
+# of _pack_output as their LO planes (rows 0-3) and then their HI planes
+# (rows 4-7), the lanes the minor dimension.  Nothing is clipped or
+# turned into a delta: the same 64 bits a lane and row.  (A TPU keeps an
+# s64 array as two u32 halves and its runtime rebuilds the i64 on the
+# host, fetch by fetch: a millisecond of a 4096-lane read-back.)
+WIDE_ANSWER_ROWS = 8
+
+
+def apply_rounds_planes(
+    state: BucketState, req: RequestBatch, round_id, n_rounds, now_ms,
+    cold_cond: bool = True,
+) -> "tuple[BucketState, jax.Array]":
+    """apply_rounds with its i64[4, B] answer as i32[8, B]: lo planes,
+    then hi planes.  What the wide dispatch bodies of both wires end in."""
+    state, packed = apply_rounds(
+        state, req, round_id, n_rounds, now_ms, cold_cond=cold_cond
+    )
+    with jax.named_scope(SCOPE_ANSWER_PACK):
+        return state, jnp.concatenate((_lo32(packed), _hi32(packed)))
+
+
+def split_wide_answer(packed64):
+    """Host twin of apply_rounds_planes' split, over numpy i64[..., 4, B]."""
+    import numpy as np
+
+    return np.concatenate(
+        (packed64.astype(np.int32), (packed64 >> 32).astype(np.int32)), axis=-2
+    )
+
+
+def compose_wide_answer(planes):
+    """The wide answer's i32[..., 8, B] planes back to _pack_output's
+    i64[..., 4, B], in numpy: what gt_mesh_finish_wide does lane by
+    lane (the lo word is unsigned: its top bit is bit 31 of the value,
+    not a sign)."""
+    import numpy as np
+
+    half = WIDE_ANSWER_ROWS // 2
+    lo, hi = planes[..., :half, :], planes[..., half:, :]
+    return (hi.astype(np.int64) << 32) | (lo.astype(np.int64) & 0xFFFFFFFF)
+
+
 def apply_rounds_packed_wide(
     state: BucketState, wire, n_rounds, now_ms, cold_cond: bool = True
 ) -> "tuple[BucketState, jax.Array]":
     """Wide-output twin of apply_rounds_packed: same single-buffer wire,
-    int64 compute and a packed i64[4, B] result (the layout of
-    _pack_output).  This is what keeps monthly/yearly Gregorian
-    batches on the dict wire: their far-future expiries exceed the
-    narrow output's i32 deltas, but per-lane bytes are identical —
-    only the readback doubles.  Matches interval.go:82-146 being
-    first-class in the reference."""
+    int64 compute and the packed result of _pack_output as i32[8, B]
+    lo/hi planes (apply_rounds_planes).  This is what keeps
+    monthly/yearly Gregorian batches on the dict wire: their far-future
+    expiries exceed the narrow output's i32 deltas, but per-lane bytes
+    are identical — only the readback doubles.  Matches
+    interval.go:82-146 being first-class in the reference."""
     now = jnp.asarray(now_ms, _I64)
     P = dict_wire_lanes(wire.shape[0])
     slot, fl, cfg, occ, rid, rows = unpack_dict_wire(wire, P)
@@ -1027,7 +1072,9 @@ def apply_rounds_packed_wide(
             occ=occ.astype(_I32),
             write=(fl & 2) != 0,
         )
-    return apply_rounds(state, req, rid, n_rounds, now_ms, cold_cond=cold_cond)
+    return apply_rounds_planes(
+        state, req, rid, n_rounds, now_ms, cold_cond=cold_cond
+    )
 
 
 # The per-lane wire: what a batch the dictionary cannot hold rides (more
@@ -1135,9 +1182,9 @@ def apply_rounds_lanes(
 ) -> "tuple[BucketState, jax.Array]":
     """The rounds kernel behind the single-buffer per-lane wire:
     apply_rounds32 and its packed i32[4, B] answer (host precondition:
-    narrow_ok) or, `wide`, apply_rounds and i64[4, B]."""
+    narrow_ok) or, `wide`, apply_rounds_planes and i32[8, B]."""
     req, rid = unpack_lane_wire(wire, wide)
-    rounds = apply_rounds if wide else apply_rounds32
+    rounds = apply_rounds_planes if wide else apply_rounds32
     return rounds(state, req, rid, n_rounds, now_ms, cold_cond=cold_cond)
 
 

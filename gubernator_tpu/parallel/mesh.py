@@ -190,7 +190,8 @@ def _rounds_lanes_mesh(state, wire):
 
 def _rounds_lanes_wide_mesh(state, wire):
     """The per-lane wire of values exceeding int32: [S, 16P+4], lo/hi
-    pairs, and an i64[S, 4, B] answer."""
+    pairs, and the wide answer as it leaves the device: i32[S, 8, B],
+    lo planes then hi planes (buckets.WIDE_ANSWER_ROWS)."""
     return _rounds_over_rows(buckets.apply_rounds_lanes, state, wire, wide=True)
 
 
@@ -202,14 +203,17 @@ def _rounds_packed_mesh(state, wire):
 
 def _rounds_packed_wide_mesh(state, wire):
     """Wide-output packed dict wire (values beyond int32 — monthly/
-    yearly Gregorian expiries; i64[S, 4, B] result)."""
+    yearly Gregorian expiries): the absolute 64-bit answer as
+    i32[S, 8, B], lo planes then hi planes (buckets.WIDE_ANSWER_ROWS),
+    so no 64-bit array crosses to the host."""
     return _rounds_over_rows(buckets.apply_rounds_packed_wide, state, wire)
 
 
 def _per_device(mesh: Mesh, body, stacked: bool = False):
     """`body(state, *wires)` run by every device of `mesh` on its own
     shard's rows.  `stacked`: the answer carries a leading axis in front
-    of the shards' (the fused programs' [k, S, 4, P]).  No `check_vma`:
+    of the shards' (the fused programs' [k, S, 4, P]; [k, S, 8, P]
+    wide).  No `check_vma`:
     the rounds loop starts its carry from constants and ends it
     per-device, which the check refuses and the loop means."""
     axis = mesh.axis_names[0]
@@ -262,7 +266,7 @@ def _mesh_fused_packed_jit(mesh: Mesh, k: int, wide: bool,
             for wire in wires:
                 state, packed = base(state, wire)
                 outs.append(packed)
-            return state, jnp.stack(outs)  # [k, S, 4, P]
+            return state, jnp.stack(outs)  # [k, S, 4, P]; wide [k, S, 8, P]
 
         donate = tuple(range(k + 1)) if donate_wires else (0,)
         fn = jax.jit(_per_device(mesh, run, stacked=True), donate_argnums=donate)
@@ -887,7 +891,7 @@ class MeshBucketStore(ColumnarPipeline):
         `force_wire` set ("narrow" / "wide": the PER-LANE wire, named by
         the answer width it pins; warm-up and tests use it) rides the
         per-lane wire: one i32 buffer too, of 11 words a lane (16 with
-        the i64 answer) and no table, and ONE transfer.  Either wire is
+        the wide answer) and no table, and ONE transfer.  Either wire is
         packed by numpy on the host and unpacked by slices inside the
         jitted program, and either carries the round count and the
         clock in its header (buckets.set_wire_header): the launch passes
@@ -1006,9 +1010,10 @@ class MeshBucketStore(ColumnarPipeline):
 
     def _stage_scalar(self, prep: "_MeshPrep") -> "_Staged":
         """Express stage: locate each lane's (shard, row) from the mesh
-        plan and return the host-evaluation closure; its packed
-        [S, 4, P] wide output feeds the unchanged mp.finish_wide commit
-        (decode + slot-table commit + original-order scatter).  The
+        plan and return the host-evaluation closure; its packed wide
+        output, split into the i32[S, 8, P] planes a wide program
+        answers in, feeds the unchanged mp.finish_wide commit (decode +
+        slot-table commit + original-order scatter).  The
         closure runs at the launch turn under `_lock`
         (ColumnarPipeline._launch_group)."""
         cols, mp, padded = prep.cols, prep.mp, prep.padded
@@ -1058,7 +1063,7 @@ class MeshBucketStore(ColumnarPipeline):
                 packed[s, 1, j] = rem
                 packed[s, 2, j] = reset
                 packed[s, 3, j] = n_exp
-            return packed
+            return buckets.split_wide_answer(packed)
 
         return _Staged(solo=None, scalar=run)
 
@@ -1925,8 +1930,8 @@ class MeshBucketStore(ColumnarPipeline):
             # with the narrow (i32) answer here: the dictionary wire
             # and, forced by "narrow", the per-lane wire that a batch
             # of more than 256 configurations takes.  The dictionary
-            # wire's wide (i64) answer follows below; the per-lane
-            # wire's stays lazy (only a batch of more than 256
+            # wire's wide answer (lo/hi planes) follows below; the
+            # per-lane wire's stays lazy (only a batch of more than 256
             # configurations with a value past i32 reaches it).  1ms
             # duration so the slots recycle.
             for lanes in sorted(set(warm_shapes or (1,))):

@@ -145,6 +145,25 @@ def test_narrow_wire_compiles_for_one_v5e(one_chip):
     )
 
 
+def test_wide_dict_wire_answers_in_32_bit_words_for_one_v5e(one_chip):
+    """The program every frame with a monthly calendar quota dispatches.  The
+    TPU compiler keeps an s64 array as two u32 halves and hands them to the
+    host through an `X64Combine`, which the runtime undoes and redoes at
+    every fetch; the wide answer leaves as ONE s32[1, 8, 4096] instead, the
+    halves it was carried as side by side."""
+    compiled = _compile(
+        "dict wire, wide answer, 1M slots x 4096 lanes, one chip",
+        mesh_mod._dispatch_jit(
+            one_chip, mesh_mod._rounds_packed_wide_mesh, donate_wire=True
+        ).lower(_state(one_chip), _dict_wire(one_chip, LANES)),
+    )
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    root = entry[entry.index("ROOT"):].splitlines()[0]
+    assert f"s32[1,{buckets.WIDE_ANSWER_ROWS},{LANES}]" in root and "s64[" not in root, root
+    assert "X64Combine" not in entry
+
+
 def test_global_sync_compiles_for_four_v5e(four_chips):
     """The GLOBAL sync collective — the mesh's one cross-chip program —
     with the table sharded four ways."""

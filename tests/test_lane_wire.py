@@ -3,8 +3,11 @@
 the device unpacks bit for bit, on one device and on four shards, at the
 smallest pad bucket and at the cell's, for the i32 and the i64 answer; the
 program behind it answers what the rounds kernel answers over the same
-columns handed to it directly; and a staged batch makes the transfer calls
-`_Staged.uploads` says it made, counted from JAX and not by hand."""
+columns handed to it directly; the wide answer of either wire, and of a
+fused group of two, leaves the device as ONE 32-bit array of lo/hi planes
+that decodes bit for bit to the kernel's i64 answer; and a staged batch
+makes the transfer calls `_Staged.uploads` says it made, counted from JAX
+and not by hand."""
 
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from gubernator_tpu import native
 from gubernator_tpu.ops import buckets
 from gubernator_tpu.parallel import mesh as mesh_mod
+from gubernator_tpu.types import Behavior
 
 from .conftest import _store_over
 
@@ -168,11 +172,104 @@ def test_the_program_behind_the_buffer_answers_as_the_kernel_over_the_columns(sh
     program = mesh_mod._dispatch_jit(
         sharding.mesh, mesh_mod._rounds_lanes_wide_mesh if wide else mesh_mod._rounds_lanes_mesh)
     got_state, got = program(fresh(), put(wire))
-    assert got.dtype == want.dtype == vdt and got.shape == (shards, 4, pad)
-    assert (np.asarray(got) == np.asarray(want)).all()
-    assert np.asarray(got)[:, 1].max() > (2**32 if wide else 0)  # `remaining`: real answers
+    assert want.dtype == vdt and want.shape == (shards, 4, pad)
+    # Either answer crosses to the host as 32-bit words: the wide one as
+    # eight planes (the four rows' lo words, then their hi words).
+    assert got.dtype == np.int32 and got.shape == (shards, 8 if wide else 4, pad)
+    got = buckets.compose_wide_answer(np.asarray(got)) if wide else np.asarray(got)
+    assert (got == np.asarray(want)).all()
+    assert got[:, 1].max() > (2**32 if wide else 0)  # `remaining`: real answers
     for a, b in zip(jax.tree.leaves(got_state), jax.tree.leaves(want_state)):
         assert (np.asarray(a) == np.asarray(b)).all()
+
+
+# A clock near 1.8e12 whose low word puts bit 31 of `now + a month` and of
+# `now + a year` at one: a decode that shifts the lo word signed shows.
+NOW_WIDE = (419 << 32) + 1_000_000_000
+MONTH, YEAR = 31 * 86_400_000, 365 * 86_400_000
+WIDE_LIMITS = (2**31 - 1, 2**31, 2**32, 2**53 + 1, 2**62)
+RESET_REMAINING = int(Behavior.RESET_REMAINING)
+
+
+def _wide_lanes():
+    """Request columns whose answers fill both planes: every limit of
+    WIDE_LIMITS under a month's and a year's duration, met with no hit
+    (`remaining` is the limit itself) and with one; an OVER_LIMIT lane;
+    and a key met twice, whose second request removes its bucket."""
+    lanes = [(f"wl_{lim}_{dur}_{hits}", 0, 0, hits, lim, dur)
+             for lim in WIDE_LIMITS for dur in (MONTH, YEAR) for hits in (0, 1)]
+    lanes.append(("wl_leaky", 1, 0, 1, 2**32, MONTH))
+    lanes.append(("wl_over", 0, 0, 2**32 + 5, 2**32, YEAR))
+    lanes.append(("wl_gone", 0, 0, 1, 2**31, MONTH))
+    lanes.append(("wl_gone", 0, RESET_REMAINING, 0, 2**31, MONTH))
+    keys = [lane[0] for lane in lanes]
+    algo, behavior = (np.array([lane[k] for lane in lanes], np.int32) for k in (1, 2))
+    hits, limit, duration = (np.array([lane[k] for lane in lanes], np.int64) for k in (3, 4, 5))
+    return keys, algo, behavior, hits, limit, duration
+
+
+@pytest.mark.skipif(not native.available(), reason="the columnar path needs the native host runtime")
+@pytest.mark.parametrize("wire", ["dictionary", "lanes", "fused2"])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_wide_answer_leaves_the_device_as_lo_hi_planes(shards, wire):
+    """The program a wide batch launches (the dictionary wire's, the
+    per-lane wire's, a fused group of two) answers i32[S, 8, P] /
+    [2, S, 8, P], which decodes bit for bit to the i64[S, 4, P] of
+    `buckets.apply_rounds` over the same plan and columns: absolute times,
+    exact `remaining`, nothing clipped or turned into a delta."""
+    from gubernator_tpu.models.shard import make_columns
+
+    store = _store_over(shards, 2048)
+    keys, algo, behavior, hits, limit, duration = _wide_lanes()
+    n = len(keys)
+    cols = make_columns(algo, behavior, hits, limit, duration, n)
+    prep = store._prepare_columns(keys, cols, NOW_WIDE, "wide" if wire == "lanes" else None)
+    staged = store._stage_columns(prep)
+    assert staged.wide and staged.lane_wire == (wire == "lanes") and prep.n_rounds >= 2
+    mp, pad = prep.mp, prep.padded
+    place = lambda col: _placed((shards, pad), prep.pos, col, col.dtype)  # noqa: E731
+    req = buckets.RequestBatch(
+        mp.slot, mp.exists.astype(bool), place(algo), place(behavior), place(hits), place(limit),
+        place(duration), np.zeros((shards, pad), np.int64), np.zeros((shards, pad), np.int64),
+        occ=mp.occ, write=mp.write.astype(bool))
+    direct = jax.jit(jax.vmap(
+        lambda st, rq, rd: buckets.apply_rounds(st, rq, rd, prep.n_rounds, NOW_WIDE, cold_cond=False)))
+    sharding = _sharding(shards)
+    put = lambda tree: jax.device_put(tree, sharding)  # noqa: E731
+    ref_state = put(jax.tree.map(np.asarray, store.state))
+    k = 2 if wire == "fused2" else 1
+    want = []
+    for _ in range(k):
+        ref_state, packed = direct(ref_state, put(req), put(mp.rid))
+        assert packed.dtype == np.int64 and packed.shape == (shards, 4, pad)
+        want.append(np.asarray(packed))
+
+    if wire == "fused2":
+        assert staged.fuse_key is not None
+        _, got = store._fused_launch_fn(2, True)(store.state, staged.wire_dev, staged.wire_dev)
+        assert got.shape == (2, shards, 8, pad)
+    else:
+        _, got = staged.solo(store.state)
+        assert got.shape == (shards, 8, pad)
+    assert got.dtype == np.int32
+    planes = np.asarray(got).reshape(k, shards, 8, pad)
+    for i in range(k):
+        assert (buckets.compose_wide_answer(planes[i]) == want[i]).all(), i
+        assert (buckets.split_wide_answer(want[i]) == planes[i]).all(), i
+
+    # The values are the ones this container exists for, lane by lane.
+    lane = lambda row: want[0][:, row].reshape(-1)[prep.pos]  # noqa: E731
+    status, remaining, reset, expire = lane(0) & 1, lane(1), lane(2), lane(3)
+    tokens = 4 * len(WIDE_LIMITS)
+    assert (remaining[:tokens] == limit[:tokens] - hits[:tokens]).all()
+    assert set(WIDE_LIMITS) <= set(remaining.tolist())
+    assert (reset[:tokens] == NOW_WIDE + duration[:tokens]).all()
+    assert status[keys.index("wl_over")] == 1 and remaining[keys.index("wl_over")] == 2**32
+    assert (lane(0)[-1] >> 1) & 1 and expire[-1] == 0  # the removed lane
+    lo, hi = planes[0][:, :4], planes[0][:, 4:]
+    assert (lo[:, 2] < 0).any() and (hi[:, 2] > 0).any()  # `reset_time`: lo's bit 31, a hi word
+    assert (hi[:, 1] == 2**30).any() and (lo[:, 1] == -(2**31)).any()  # `remaining` 2**62, 2**31
+    assert not hi[:, 0].any()
 
 
 def _frame(lanes: int, configurations: int):

@@ -10,6 +10,7 @@ import pytest
 
 from gubernator_tpu import native
 from gubernator_tpu.models.slot_table import SlotTable
+from gubernator_tpu.ops import buckets
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, SECOND
 from gubernator_tpu.utils import hashing
 
@@ -706,7 +707,7 @@ def test_mesh_plan_gives_the_python_plans_arrays_lane_for_lane(case, cell_popula
             if f == len(frames) - 1:
                 saw["expired"] += sum(1 for p in chunk if not p.exists)
         assert n_rounds == want_rounds, f
-        mp.finish_wide(packed)
+        mp.finish_wide(buckets.split_wide_answer(packed))
         saw["rounds"] = max(saw["rounds"], n_rounds)
         saw["evictions"] += sum(t.evictions for t in py) - ev
         for s in range(S):
@@ -718,6 +719,61 @@ def test_mesh_plan_gives_the_python_plans_arrays_lane_for_lane(case, cell_popula
         assert sorted(nat[s].keys()) == sorted(py[s].keys())
         stats = nat[s].index_stats
         assert stats["refused"] == 0 and stats["probes"] < 2 * stats["lookups"]
+
+
+# What a wide answer holds, a value a lane: both planes of a row at work.
+# A time a month and a year past a clock near 1.8e12 whose lo word has bit
+# 31 set (a signed lo would borrow from the hi word), 0 (a removed lane).
+_WIDE_NOW = (419 << 32) + 1_000_000_000
+_WIDE_VALUES = (
+    0, 1, 2**31 - 1, 2**31, 2**32 - 1, 2**32, 2**53 + 1, 2**62, 2**63 - 1, -1, -(2**63),
+    _WIDE_NOW, _WIDE_NOW + 31 * 86_400_000, _WIDE_NOW + 365 * 86_400_000,
+)
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_finish_wide_puts_the_planes_together_as_numpy_does(shards):
+    """`gt_mesh_finish_wide` reads the wide answer as it leaves the device,
+    i32[S, 8, P] lo planes then hi planes, and composes a lane's 64 bits in
+    its loop: held to `buckets.compose_wide_answer` (numpy, the path with
+    no compiler) on `remaining`, `reset_time`, the status bit, and on what
+    the slot table keeps of `new_expire` and of the removed bit."""
+    from gubernator_tpu.models.shard import pad_size
+
+    rng = np.random.default_rng([40, shards])
+    n = 6 * len(_WIDE_VALUES)
+    keys = [f"fw{i:04d}" for i in range(n)]
+    nat = [native.NativeSlotTable(4 * n) for _ in range(shards)]
+    mp = native.NativeMeshPlanner(nat, keys, _WIDE_NOW)
+    P = pad_size(int(mp.counts.max()))
+    assert mp.plan_grouped(_Cols(n), int(Behavior.RESET_REMAINING), P) == 1
+    lane_rows = np.stack([
+        rng.integers(0, 4, n),  # status | removed << 1
+        *(rng.permutation(np.resize(np.array(_WIDE_VALUES, np.int64), n)) for _ in range(2)),
+        np.resize(np.array(_WIDE_VALUES[-3:], np.int64), n),  # new_expire: live times
+    ])
+    flat = np.zeros((4, shards * P), np.int64)
+    flat[:, mp.pos[:n]] = lane_rows
+    packed = np.ascontiguousarray(flat.reshape(4, shards, P).transpose(1, 0, 2))
+    planes = buckets.split_wide_answer(packed)
+    assert planes.dtype == np.int32 and planes.shape == (shards, 8, P)
+    assert (planes[:, 1:4] < 0).any() and (planes[:, 5:] != 0).any() and not planes[:, 4].any()
+    assert (buckets.compose_wide_answer(planes) == packed).all()
+    with pytest.raises(TypeError, match="i32 planes"):
+        mp.finish_wide(packed)
+
+    status, remaining, reset = mp.finish_wide(planes)
+    want = buckets.compose_wide_answer(planes).transpose(1, 0, 2).reshape(4, -1)[:, mp.pos[:n]]
+    assert (status == (want[0] & 1)).all()
+    assert (remaining == want[1]).all() and set(_WIDE_VALUES) <= set(remaining.tolist())
+    assert (reset == want[2]).all()
+    removed = (want[0] >> 1) & 1
+    for i, key in enumerate(keys):
+        table = nat[int(mp.pos[i]) // P]
+        slot = table.get_slot(key)
+        assert (slot is None) == bool(removed[i]), key
+        if slot is not None:
+            assert table.get_expire_bulk([slot])[0] == want[3][i], key
 
 
 def test_occupancy_rows_serve_the_key_indexs_health():

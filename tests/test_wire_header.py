@@ -93,6 +93,37 @@ def test_no_device_waits_for_another_inside_a_dispatch(body):
         assert f" {op}(" not in text and f" {op}-start(" not in text, op
 
 
+@pytest.mark.parametrize("body", [
+    "_rounds_packed_mesh", "_rounds_packed_wide_mesh", "_rounds_lanes_mesh",
+    "_rounds_lanes_wide_mesh", "fused2-narrow", "fused2-wide"])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_no_64_bit_array_leaves_the_device_on_the_dispatch_path(shards, body):
+    """What a launch hands the host to read back is 32-bit words at either
+    answer width, on either wire, solo and fused: the narrow answer's
+    [S, 4, P] deltas, the wide answer's [S, 8, P] lo/hi planes.  (A TPU
+    keeps an s64 array as two u32 halves and its runtime rebuilds the
+    64-bit array on the host at every fetch.)"""
+    mesh = mesh_mod.make_mesh(jax.devices()[:shards])
+    rows = lambda *shape, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+        (shards, *shape), dtype, sharding=_sharding(shards))
+    state = jax.tree.map(lambda a: rows(*a.shape, dtype=a.dtype),
+                         jax.eval_shape(lambda: buckets.init_state(256)))
+    header, lanes, wide = buckets.WIRE_HEADER_WORDS, 64, "wide" in body
+    if body.startswith("fused2"):
+        wire = rows(3 * lanes + buckets.DICT_WIRE_TABLE_WORDS + header)
+        program = mesh_mod._mesh_fused_packed_jit(mesh, 2, wide, donate_wires=False)
+        _, answer = jax.eval_shape(program, state, wire, wire)
+        lead = (2, shards)
+    else:
+        per_lane = buckets.LANE_WIRE_WORDS_WIDE if wide else buckets.LANE_WIRE_WORDS
+        words = 3 * lanes + buckets.DICT_WIRE_TABLE_WORDS if "packed" in body else per_lane * lanes
+        program = mesh_mod._dispatch_jit(mesh, getattr(mesh_mod, body))
+        _, answer = jax.eval_shape(program, state, rows(words + header))
+        lead = (shards,)
+    assert answer.dtype == jnp.int32
+    assert answer.shape == (*lead, buckets.WIDE_ANSWER_ROWS if wide else 4, lanes)
+
+
 def test_a_packed_wire_nobody_stamped_runs_no_round():
     """The packers leave the header zero, and zero rounds answer nothing: a
     caller that forgets `set_wire_header` gets an inert wire, not a stale
