@@ -247,6 +247,7 @@ class FederationManager:
         self._carry: Dict[str, Dict[str, RateLimitRequest]] = {}
         self._fanout_pool = None
         # Status counters (hit totals, for GET /debug/status).
+        self.queued_hits = 0  # every hit queue_hits / queue_columns took
         self.sent_hits = 0
         self.requeued_hits = 0
         self.dropped_hits = 0
@@ -270,14 +271,24 @@ class FederationManager:
         Reaching multi_region_batch_limit distinct keys flushes
         immediately instead of waiting out the window — the reference's
         queue-full flush, previously unenforced."""
+        self.queue_columns((0,), (r.hash_key(),), (r.hits,), lambda _: replace(r))
+
+    def queue_columns(self, lanes, hash_keys, hits, request_at) -> None:
+        """`queue_hits` a batch at a time: its MULTI_REGION `lanes`
+        under ONE hold of the lock, hits summed a key; `request_at(i)`
+        materialises lane i's request (a copy the queue may keep and
+        add to), and is called only for a key the queue does not hold
+        yet."""
         limit = self.service.conf.behaviors.multi_region_batch_limit
         with self._lock:
-            key = r.hash_key()
-            cur = self._hits.get(key)
-            if cur is None:
-                self._hits[key] = replace(r)
-            else:
-                cur.hits += r.hits
+            for i in lanes:
+                key = hash_keys[i]
+                cur = self._hits.get(key)
+                if cur is None:
+                    self._hits[key] = request_at(i)
+                else:
+                    cur.hits += int(hits[i])
+                self.queued_hits += int(hits[i])
             kick = (
                 limit > 0
                 and len(self._hits) >= limit
@@ -528,6 +539,7 @@ class FederationManager:
             "carryKeyTotal": sum(carry.values()),
             "flushes": self.flushes,
             "lastFlushAgeS": age,
+            "queuedHits": self.queued_hits,
             "sentHits": self.sent_hits,
             "requeuedHits": self.requeued_hits,
             "droppedHits": self.dropped_hits,

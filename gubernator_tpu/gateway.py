@@ -40,7 +40,13 @@ from .config import (
 )
 from .models.shard import greg_lanes, resolve_greg_columns
 from .service import ApiError, ColumnarResult, IngressColumns, V1Service
-from .types import Algorithm, RateLimitRequest, UpdatePeerGlobal, _parse_behavior
+from .types import (
+    Algorithm,
+    Behavior,
+    RateLimitRequest,
+    UpdatePeerGlobal,
+    _parse_behavior,
+)
 
 
 
@@ -939,27 +945,39 @@ class NativeIngressPump:
     and socket write.
 
     Lanes needing Python semantics never reach here — the native
-    submit falls back to the ordinary gateway path for them (slow
-    behavior bits, validation errors, remote owners, sampled traces,
-    malformed frames), so correctness is identical with the pump on or
-    off; the pump only removes interpreter time from the
-    already-columnar common case."""
+    submit falls back to the ordinary gateway path for them (GLOBAL or
+    MULTI_REGION lanes in a ring of more than one node, validation
+    errors, remote owners, sampled traces, malformed frames), so
+    correctness is identical with the pump on or off; the pump only
+    removes interpreter time from the already-columnar common case."""
 
-    # Behavior bits that demand the Python router (GLOBAL replica
-    # path, MULTI_REGION hit queueing — and NO_BATCHING direct dispatch
-    # when the express lane is off): any lane carrying one makes the
-    # whole frame fall back.  With GUBER_EXPRESS on, NO_BATCHING moves
-    # out of the fallback mask and into the native EXPRESS queue
-    # instead (frames jump the ring, never the Python path — the bit
-    # means "skip coalescing waits", which the native loop satisfies
-    # directly).  DURATION_IS_GREGORIAN (4) is in neither mask: a
-    # calendar lane stays on this lane and `_submit` resolves it; the
-    # native submit hands a frame over whole only when such a lane's
-    # duration is not an interval upstream resolves (weeks, or outside
-    # 0-5), because the Python path owns the per-lane error wording.
-    FALLBACK_BEHAVIOR = 1 | 2 | 16
-    EXPRESS_FALLBACK_BEHAVIOR = 2 | 16
-    EXPRESS_MASK = 1  # Behavior.NO_BATCHING
+    # Behavior bits that make the native submit hand a frame to the
+    # Python router whole (`_push` picks the mask).  GLOBAL and
+    # MULTI_REGION (OWNER_BEHAVIOR) do so only in a ring of more than
+    # one node: there a GLOBAL lane may be owned elsewhere and needs
+    # the replica path.  Where every vnode is this daemon's (the
+    # snapshot's all_self) their lanes are the owner's own, change no
+    # answer and stay: `_submit` queues the MULTI_REGION hits and the
+    # store's plan does the GLOBAL owner's book-keeping, in the take's
+    # one dispatch.  NO_BATCHING falls back only when the express lane
+    # is off (direct dispatch is then the Python router's); with
+    # GUBER_EXPRESS on it flags its frame for the native EXPRESS queue
+    # instead (frames jump the ring — the bit means "skip coalescing
+    # waits", which the native loop satisfies directly).
+    # DURATION_IS_GREGORIAN (4) is in no mask: a calendar lane stays on
+    # this lane and `_submit` resolves it; the native submit hands a
+    # frame over whole only when such a lane's duration is not an
+    # interval upstream resolves (weeks, or outside 0-5), because the
+    # Python path owns the per-lane error wording.
+    OWNER_BEHAVIOR = int(Behavior.GLOBAL) | int(Behavior.MULTI_REGION)
+    EXPRESS_MASK = int(Behavior.NO_BATCHING)
+
+    @classmethod
+    def fallback_mask(cls, all_self: bool, express: bool) -> int:
+        """The bits that send a frame to the Python router whole."""
+        return (0 if all_self else cls.OWNER_BEHAVIOR) | (
+            0 if express else cls.EXPRESS_MASK
+        )
 
     #: Lane ceiling of one coalesced take = the device dispatch
     #: ceiling (ColumnarBatcher.MAX_LANES — an oversized dispatch
@@ -1082,10 +1100,7 @@ class NativeIngressPump:
             vh, vself, all_self=all_self, enabled=enabled,
             cap_lanes=getattr(b, "ingress_queue_lanes", 0),
             max_frame_lanes=INGRESS_COLUMNS_MAX_LANES,
-            behavior_mask=(
-                self.EXPRESS_FALLBACK_BEHAVIOR if express
-                else self.FALLBACK_BEHAVIOR
-            ),
+            behavior_mask=self.fallback_mask(all_self, express),
             hash_variant=variant,
             express_mask=self.EXPRESS_MASK if express else 0,
         )
@@ -1236,6 +1251,23 @@ class NativeIngressPump:
                     # gt_ingress_submit hands such a frame to Python whole.
                     raise ValueError(errors[0][1])
             ph.note(lanes=lanes, durations=distinct)
+        # The callers' behaviour bits.  Every lane of a take is owned
+        # here (gt_ingress_submit keeps a frame with a GLOBAL or
+        # MULTI_REGION lane only in an all-self ring), so none changes
+        # an answer and all ride the one dispatch: a MULTI_REGION lane's
+        # hits are queued for the other regions here, a GLOBAL lane's
+        # owner book-keeping is the store's, inside the plan
+        # (`dispatch.global_note`); NO_BATCHING chose the take's queue in
+        # C++ and has nothing left to ask.
+        with phase("behavior.handle", bt) as ph:
+            owed = tb.behavior & self.OWNER_BEHAVIOR
+            if owed.any():
+                mr = np.flatnonzero(owed & int(Behavior.MULTI_REGION)).tolist()
+                if mr:
+                    svc.multi_region_mgr.queue_columns(
+                        mr, tb.hash_keys, tb.hits, tb.request_at
+                    )
+                ph.note(lanes=int(np.count_nonzero(owed)), multi_region=len(mr))
         tracing.stage_batch_trace(bt)
         try:
             handle = svc.store.apply_columns_async(

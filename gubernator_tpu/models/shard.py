@@ -364,7 +364,8 @@ class _Columns:
     """Request fields as contiguous arrays (one slot per valid lane)."""
 
     __slots__ = ("algo", "behavior", "hits", "limit", "duration",
-                 "greg_expire", "greg_duration", "calendar_lanes")
+                 "greg_expire", "greg_duration", "calendar_lanes",
+                 "flagged_lanes", "global_lanes", "sent_behavior")
 
     def __init__(self, n: int):
         self.algo = np.empty(n, dtype=np.int32)
@@ -375,6 +376,11 @@ class _Columns:
         self.greg_expire = np.zeros(n, dtype=np.int64)
         self.greg_duration = np.zeros(n, dtype=np.int64)
         self.calendar_lanes = 0
+        # Routing bits (split_routing_bits): lanes that carried one, the
+        # GLOBAL lanes' indices, the column as the caller sent it.
+        self.flagged_lanes = 0
+        self.global_lanes = None
+        self.sent_behavior = None
 
 
 _I32_MAX = (1 << 31) - 1
@@ -417,6 +423,37 @@ def make_columns(algorithm, behavior, hits, limit, duration, n,
         0 if greg_duration is None else int(np.count_nonzero(cols.greg_duration))
     )
     return cols
+
+
+# The behaviour bits that say WHERE and WHEN a check is applied and never
+# what it answers: upstream's owner applies a GLOBAL or MULTI_REGION
+# request to its own bucket like any other (gubernator.go:339-345, then
+# QueueUpdate / QueueHits), and NO_BATCHING only skips a coalescing wait.
+# The kernel reads DURATION_IS_GREGORIAN and RESET_REMAINING alone.
+ROUTING_BEHAVIOR = (
+    int(Behavior.NO_BATCHING) | int(Behavior.GLOBAL) | int(Behavior.MULTI_REGION)
+)
+
+
+def split_routing_bits(cols: "_Columns") -> None:
+    """Take the routing bits off a batch's behaviour column before it is
+    planned: the plan calls a key's lanes one uniform group (answered in
+    closed form, round 0) only if their behaviour words are equal, so a
+    hot key with one flagged lane among plain ones would otherwise run a
+    kernel round a lane; and the dictionary wire spends a row a distinct
+    word.  Leaves behind what the owner's book-keeping needs: the count
+    of flagged lanes, the GLOBAL lanes, and the column as it was sent.
+    A batch without such a bit is untouched; the caller's array is never
+    written."""
+    flagged = cols.behavior & ROUTING_BEHAVIOR
+    if not flagged.any():
+        return
+    cols.flagged_lanes = int(np.count_nonzero(flagged))
+    glob = np.flatnonzero(flagged & int(Behavior.GLOBAL))
+    if glob.size:
+        cols.global_lanes = glob
+    cols.sent_behavior = cols.behavior
+    cols.behavior = cols.behavior & ~ROUTING_BEHAVIOR
 
 
 # ---------------------------------------------------------------------
@@ -878,7 +915,7 @@ class ColumnarPipeline:
         saturation.mesh_tally.add(
             shards, prep.n, padded, fullest, prep.n_rounds,
             staged.lane_wire, staged.config_rows, staged.uploads,
-            cols.calendar_lanes, staged.wide,
+            cols.calendar_lanes, staged.wide, cols.flagged_lanes,
         )
         self._launch_in_order(handle, staged)
         return handle

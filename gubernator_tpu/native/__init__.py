@@ -1349,6 +1349,21 @@ class IngressTakenBatch:
     def _uk_at(self, i: int) -> str:
         return bytes(self._ub[self._uo[i]:self._uo[i + 1]]).decode("utf-8")
 
+    def request_at(self, i: int):
+        """Lane i as a dataclass, a copy that outlives the batch (what
+        the MULTI_REGION hit queue keeps of a lane)."""
+        from ..types import RateLimitRequest
+
+        return RateLimitRequest(
+            name=self._name_at(i),
+            unique_key=self._uk_at(i),
+            hits=int(self.hits[i]),
+            limit=int(self.limit[i]),
+            duration=int(self.duration[i]),
+            algorithm=int(self.algorithm[i]),
+            behavior=int(self.behavior[i]),
+        )
+
 
 class IngressBatcher:
     """The native ingress ring (gt_ingress_*): gateway workers submit

@@ -3069,16 +3069,20 @@ void gt_http_free(void* sv) {
 //     no-override/no-owner case (golden-tested), so a client cannot
 //     tell the native loop from the PR 8 path.
 //
-// Lanes that need Python semantics (GLOBAL replication, MULTI_REGION
-// queueing, a Gregorian duration upstream answers with an error,
-// per-lane validation errors, sampled traces, remote owners) make the
-// WHOLE frame fall back: correctness
-// never depends on the fast lane, it only removes interpreter time
-// from the already-columnar common case.  NO_BATCHING lanes are the
-// express-lane exception (PR 14): with GUBER_EXPRESS on they stay
-// native and jump the queue (express_mask / xq below) — the bit means
-// "skip coalescing waits", which is satisfiable entirely in this loop
-// — and only fall back (the PR 13 behavior) when the lane is off.
+// Lanes that need Python semantics (a Gregorian duration upstream
+// answers with an error, per-lane validation errors, sampled traces,
+// remote owners, and a bit of behavior_mask) make the WHOLE frame fall
+// back: correctness never depends on the fast lane, it only removes
+// interpreter time from the already-columnar common case.  The pump
+// sets behavior_mask with the ring, in one call: GLOBAL and
+// MULTI_REGION are in it only while the ring has another node (a
+// GLOBAL lane may then be a replica's); in an all-self ring their lanes
+// are the owner's own, change no answer and stay, and the pump does the
+// owner's book-keeping a take at a time.  NO_BATCHING lanes stay native
+// with GUBER_EXPRESS on and jump the queue (express_mask / xq below) —
+// the bit means "skip coalescing waits", which is satisfiable entirely
+// in this loop — and fall back (the PR 13 behavior) when the lane is
+// off.
 // ======================================================================
 
 namespace {
@@ -3285,13 +3289,15 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   int64_t n = info.n;
   if (n == 0 || n > max_frame_lanes) return bump_fallback(3);
   const char* body = p->body.data();
-  // Slow behavior bits (GLOBAL / MULTI_REGION — and NO_BATCHING when
-  // the express lane is off) need the Python router's semantics.  With
-  // the express lane on, NO_BATCHING lanes instead flag the frame for
-  // the express queue below.  A DURATION_IS_GREGORIAN lane stays here
-  // (the pump resolves its interval) unless its duration is not one
-  // upstream resolves, weeks (3) or anything outside 0-5: the Python
-  // path owns that lane's error wording (utils/gregorian.py).
+  // A bit of behavior_mask needs the Python router's semantics: GLOBAL
+  // and MULTI_REGION while the ring has another node (the pump clears
+  // them from the mask of an all-self ring, whose lanes are all the
+  // owner's), NO_BATCHING when the express lane is off.  With the
+  // express lane on, NO_BATCHING lanes instead flag the frame for the
+  // express queue below.  A DURATION_IS_GREGORIAN lane stays here (the
+  // pump resolves its interval) unless its duration is not one upstream
+  // resolves, weeks (3) or anything outside 0-5: the Python path owns
+  // that lane's error wording (utils/gregorian.py).
   bool xpress = false;
   for (int64_t i = 0; i < n; ++i) {
     int32_t bh;

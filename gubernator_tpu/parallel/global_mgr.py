@@ -228,6 +228,37 @@ class GlobalKeyTable:
         self.unique_keys[g] = None
         return g, evicted
 
+    def assign_columns(self, keys: List[str], owner_shard: np.ndarray):
+        """`lookup_or_assign` a take at a time: `keys` are the take's
+        DISTINCT GLOBAL keys, `owner_shard[i]` the shard that owns
+        `keys[i]`.  Returns (gslots i64[len(keys)], the gslots evicted
+        to make room, whose device rows the caller must clear before
+        they are reused)."""
+        gslots = np.empty(len(keys), dtype=np.int64)
+        evicted: List[int] = []
+        for i, key in enumerate(keys):
+            gslots[i], ev = self.lookup_or_assign(key, int(owner_shard[i]))
+            if ev is not None:
+                evicted.append(ev)
+        return gslots, evicted
+
+    def update_config_columns(self, g: np.ndarray, algorithm, behavior, limit,
+                              duration, greg_expire, greg_duration) -> None:
+        """`update_config` a take at a time, for the columnar path: one
+        row a distinct gslot of `g`, each column that key's LAST lane in
+        the take (last writer wins, as above).  `behavior` arrives with
+        the GLOBAL bit stripped.  The wire template (`names`,
+        `unique_keys`) is left as it is: only hits forwarded to a REMOTE
+        owner are templated from it, and the columnar path serves keys
+        this daemon owns; a key whose owner later moves away is
+        templated by the dataclass path's first request for it."""
+        self.algorithm[g] = algorithm
+        self.behavior[g] = behavior
+        self.limit[g] = limit
+        self.duration[g] = duration
+        self.greg_expire[g] = greg_expire
+        self.greg_duration[g] = greg_duration
+
     def update_config(self, g: int, req, greg_expire: int, greg_duration: int) -> None:
         """Last-writer-wins config mirror.  (The reference keeps the
         FIRST queued request's config per window and sums hits,

@@ -54,6 +54,7 @@ from ..models.shard import (
     pad_size,
     plan_grouped_python,
     prepare_requests,
+    split_routing_bits,
 )
 from ..ops import scalar as scalar_ops
 from ..models.slot_table import SlotTable
@@ -794,8 +795,20 @@ class MeshBucketStore(ColumnarPipeline):
         C++), each shard's stream round-plans in its own C++ table, and
         ALL shards' rounds run in ONE fused dispatch.  Returns a dict of
         numpy arrays (status/limit/remaining/reset_time) aligned with
-        `keys`.  GLOBAL lanes are rejected — their replica-cache
-        semantics live on the dataclass path (`apply`)."""
+        `keys`.
+
+        Every lane is taken as owned by this daemon and entered at its
+        owner shard, a GLOBAL lane too: it is applied to the owner's
+        bucket like any other lane of the dispatch, and the plan does
+        the owner's book-keeping for it (`_note_global_owners`: its
+        gslot, its configuration, the owner row dirty for the next sync
+        pass; upstream's getRateLimit + QueueUpdate,
+        gubernator.go:339-341).  What only `apply` can say keeps the
+        dataclass path: a GLOBAL lane whose owner is another daemon
+        (`remote_global`: answered from the replica, hits forwarded) and
+        a lane that entered at another shard than its owner's
+        (`home_shard`).  NO_BATCHING and MULTI_REGION change nothing
+        here (the caller queues a MULTI_REGION lane's hits)."""
         return self.apply_columns_async(
             keys, algorithm, behavior, hits, limit, duration, now_ms,
             greg_expire, greg_duration, force_wire=force_wire,
@@ -825,9 +838,31 @@ class MeshBucketStore(ColumnarPipeline):
             algorithm, behavior, hits, limit, duration, len(keys),
             greg_expire, greg_duration,
         )
-        if (cols.behavior & int(Behavior.GLOBAL)).any():
-            raise ValueError("GLOBAL lanes must take the dataclass path (apply)")
+        split_routing_bits(cols)
         return self._submit_pipelined(keys, cols, now_ms, force_wire)
+
+    def _note_global_owners(self, keys, cols, pos, padded: int) -> None:
+        """The owner's book-keeping of a batch's GLOBAL lanes, a batch
+        at a time (caller holds the plan lock, which a sync pass holds
+        too: the pass that takes this dirt has drained this batch, so
+        the status it broadcasts holds these hits).  A distinct key is
+        looked up or assigned once; its LAST lane's configuration wins,
+        as a request at a time would leave it."""
+        last = {keys[i]: i for i in cols.global_lanes.tolist()}
+        idx = np.fromiter(last.values(), np.int64, len(last))
+        owner = pos[idx] // padded  # the shard the plan put the lane on
+        g, evicted = self.gtable.assign_columns(list(last), owner)
+        for ev in evicted:
+            # One row a call: the shape `apply` clears, so no new program.
+            with self._lock:
+                self.gcols = self._clear_fn(self.gcols, np.array([ev], np.int32))
+        self.gtable.update_config_columns(
+            g, cols.algo[idx], cols.sent_behavior[idx] & ~int(Behavior.GLOBAL),
+            cols.limit[idx], cols.duration[idx], cols.greg_expire[idx],
+            cols.greg_duration[idx],
+        )
+        self.dirty[owner, g] = True
+        self._global_pending = True
 
     def _prepare_columns(self, keys, cols, now_ms: int,
                          force_wire: Optional[str] = None,
@@ -856,6 +891,9 @@ class MeshBucketStore(ColumnarPipeline):
         if self.back is not None:
             self._close_move_window(padded)
         pos = mp.pos[:n]
+        if cols.global_lanes is not None:
+            with phase("dispatch.global_note", bt, lanes=len(cols.global_lanes)):
+                self._note_global_owners(keys, cols, pos, padded)
         narrow = narrow_ok(cols, now_ms) and force_wire != "wide"
 
         def commit(packed_np):

@@ -113,6 +113,12 @@ WATERFALL = (
                               # reading: every native take (its check for such
                               # lanes included), and a Python-path request
                               # that holds one
+    ("behavior.handle", 0),   # the callers' behaviour bits of a native take: the
+                              # check for GLOBAL and MULTI_REGION lanes and, where
+                              # there are some, the MULTI_REGION hits queued for
+                              # the other regions (the GLOBAL lanes' book-keeping
+                              # is dispatch.global_note, inside the plan); a
+                              # Python-path request that holds such a lane
     ("express.submit", 0),    # express bypass: submit -> dispatch launched
                               # (replaces batch.window + queue.wait for
                               # express lanes — the express-vs-batched split;
@@ -123,6 +129,9 @@ WATERFALL = (
     ("dispatch.prepare", 0),  # slot-table planning (pipeline stage 1)
     ("dispatch.plan_wait", 1),  # waiting for the plan lock
     ("dispatch.plan_native", 1),  # the C++ slot-table plan alone (begin + grouped plan)
+    ("dispatch.global_note", 1),  # a batch's GLOBAL lanes, owned here: gslot a distinct
+                              # key, its configuration, the owner row dirty (under the
+                              # plan lock; entered only where the batch holds one)
     ("dispatch.stage", 0),    # wire encode + H2D upload start (stage 2)
     ("dispatch.upload", 1),   # the stage's transfer call alone: one device_put
                               # of one buffer, on either wire
@@ -421,7 +430,11 @@ class MeshTally:
     And what the calendar adds: `calendarLanes` sums the lanes that
     carried DURATION_IS_GREGORIAN, `wideDispatches` counts the
     dispatches whose answer was i64 on either wire (a monthly or yearly
-    lane's expiry and duration pass i32: models/shard.py narrow_ok)."""
+    lane's expiry and duration pass i32: models/shard.py narrow_ok).
+
+    And what the callers' behaviours add: `flaggedLanes` sums the lanes
+    that carried NO_BATCHING, GLOBAL or MULTI_REGION into a columnar
+    dispatch (models/shard.py split_routing_bits)."""
 
     WIRE_KEYS = ("dispatches", "lanes", "laneWireDispatches", "laneWireLanes",
                  "configRows", "uploads", "calendarLanes", "wideDispatches")
@@ -432,13 +445,13 @@ class MeshTally:
         self._sums = dict.fromkeys(
             ("dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
              "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
-             "calendarLanes", "wideDispatches"), 0
+             "calendarLanes", "wideDispatches", "flaggedLanes"), 0
         )
 
     def add(self, shards: int, lanes: int, padded: int, fullest: int,
             rounds: int, lane_wire: bool = False, config_rows: int = 0,
             uploads: int = 0, calendar_lanes: int = 0,
-            wide: bool = False) -> None:
+            wide: bool = False, flagged_lanes: int = 0) -> None:
         with self._lock:
             self._shards = shards
             s = self._sums
@@ -454,6 +467,7 @@ class MeshTally:
             s["uploads"] += uploads
             s["calendarLanes"] += calendar_lanes
             s["wideDispatches"] += wide
+            s["flaggedLanes"] += flagged_lanes
 
     def snapshot(self) -> Dict[str, int]:
         with self._lock:

@@ -1555,11 +1555,16 @@ class V1Service:
         # two ledgers reconcile exactly at quiesce (the soak asserts).
         tenant_ctx = self.tenants.fold_admit(cols)
         beh = cols.behavior
-        # GLOBAL lanes need the replica-cache/dataclass path; MULTI_REGION
-        # lanes stay columnar when locally owned (their only extra duty is
-        # async hit queueing, handled below).
-        slow = (beh & int(Behavior.GLOBAL)) != 0
-        fast = np.logical_not(slow)
+        # A lane this daemon owns stays columnar whatever its behaviour:
+        # a GLOBAL lane is applied to the owner's bucket in the one
+        # dispatch and the store's plan does the owner's book-keeping
+        # (MeshBucketStore._note_global_owners), a MULTI_REGION lane's
+        # only extra duty is async hit queueing, handled below.  A GLOBAL
+        # lane whose owner is another daemon needs the replica-cache
+        # dataclass path (`slow`, set by the ownership pass).
+        glob = (beh & int(Behavior.GLOBAL)) != 0
+        fast = np.ones(n, dtype=bool)
+        slow = np.zeros(n, dtype=bool)
 
         # Validation (gubernator.go:142-152) + hash keys in one pass.
         # The native JSON edge precomputes both (gateway
@@ -1712,7 +1717,7 @@ class V1Service:
                         # peer (churn mid-resolve) stays on the
                         # dataclass router, which re-picks; GLOBAL
                         # lanes keep the replica-cache path.
-                        plain = lanes[np.logical_not(slow[lanes])]
+                        plain = lanes[np.logical_not(glob[lanes])]
                         if plain.size:
                             addr = peer.info.grpc_address
                             remote_groups[addr] = plain
@@ -1892,21 +1897,15 @@ class V1Service:
 
     def _queue_mr_fast(self, cols, beh, fast, hash_keys) -> None:
         """MULTI_REGION fast lanes owe the async cross-region hit queue
-        (gubernator.go:343-345): aggregate per key first so the queue
-        sees one materialized request per unique key, not per lane."""
+        (gubernator.go:343-345): one hold of the queue's lock a batch,
+        one materialized request a key the queue does not hold yet."""
         mr = fast & ((beh & int(Behavior.MULTI_REGION)) != 0)
-        if not mr.any():
-            return
-        agg: Dict[str, RateLimitRequest] = {}
-        for i in np.nonzero(mr)[0]:
-            k = hash_keys[int(i)]
-            cur = agg.get(k)
-            if cur is None:
-                agg[k] = cols.request_at(int(i))
-            else:
-                cur.hits += int(cols.hits[i])
-        for r in agg.values():
-            self.multi_region_mgr.queue_hits(r)
+        if mr.any():
+            lanes = np.flatnonzero(mr).tolist()
+            with phase("behavior.handle", multi_region=len(lanes)):
+                self.multi_region_mgr.queue_columns(
+                    lanes, hash_keys, cols.hits, cols.request_at
+                )
 
     def _dispatch_fast(self, cols, beh, fast, hash_keys, result):
         """Dispatch the fast lanes (Gregorian precompute included).

@@ -11,6 +11,22 @@ functional_test.go.
 The one intentional divergence mirrored here: the production code uses
 `now + duration` for the leaky-bucket expiry refresh where the reference
 has the `now * duration` bug (algorithms.go:287), so the oracle does too.
+
+What the callers' routing bits do to an OWNER's answer: nothing, and so
+the oracle reads none of them (`ROUTING_BEHAVIOR`, held by
+tests/test_mixed_cell.py).  Upstream's owner applies a request to its
+own bucket whatever they say (`getRateLimit`, gubernator.go:330-345) and
+only then looks at them:
+
+- NO_BATCHING (1) is read by the CLIENT side of a forward alone
+  (peer_client.go: send now, not with the next batch): it changes when a
+  check is dispatched, never what it answers.
+- GLOBAL (2): the owner answers from its bucket, exactly, and queues the
+  key's status for the other peers (`QueueUpdate`, gubernator.go:339-341).
+  Only a NON-owner's answer differs (its replica's, stale by a sync
+  window): that is not an owner's answer and this oracle does not model it.
+- MULTI_REGION (16): the owner answers from its bucket, exactly, and
+  queues the hits for the other regions (`QueueHits`, gubernator.go:343-345).
 """
 
 from __future__ import annotations
@@ -214,6 +230,10 @@ def leaky_bucket(c: OracleCache, r: RateLimitRequest, now: int) -> RateLimitResp
         b.remaining = 0.0
     c.add(Item(algorithm=r.algorithm, key=key, value=b, expire_at=now + duration))
     return rl
+
+
+# The bits above: an owner's answer is the same with any of them set.
+ROUTING_BEHAVIOR = Behavior.NO_BATCHING | Behavior.GLOBAL | Behavior.MULTI_REGION
 
 
 def apply(c: OracleCache, r: RateLimitRequest, now: int) -> RateLimitResponse:

@@ -540,17 +540,26 @@ def test_a_duration_upstream_refuses_sends_the_frame_to_python_whole(daemon_at, 
 
 
 @pytest.mark.parametrize("bit", [int(Behavior.GLOBAL), int(Behavior.MULTI_REGION)])
-def test_global_and_multi_region_lanes_fall_back_with_or_without_the_calendar(daemon_at, bit):
+def test_global_and_multi_region_lanes_stay_native_with_or_without_the_calendar(daemon_at, bit):
+    """Since PR 41 (tests/test_mixed_cell.py, which also holds the two-node
+    ring where they still fall back): in a one-node ring the lane is the
+    owner's own, and a calendar lane that carries the bit is resolved and
+    answered like its plain neighbours."""
     daemon, clock, http, address = daemon_at
-    clock.freeze(T0 + 10_800_000)
+    now = T0 + 10_800_000
+    clock.freeze(now)
     keys, algo, behavior, limit, duration = _mixed_frame(f"slow{bit:02d}x", n=16)
+    cache = orc.OracleCache()
+    hits = np.ones(len(keys), np.int64)
     for with_calendar in (False, True):
         lanes = np.flatnonzero((behavior == GREG) == with_calendar)[:2]
         beh = behavior.copy()
         beh[lanes] |= bit
         before = _lane_counts(daemon)
-        _send(http, address, keys, algo, beh, np.zeros(len(keys), np.int64), limit, duration)
-        assert _lane_counts(daemon) == (before[0], before[1] + 1)
+        got = np.stack(gubc.decode_answer_frame(
+            _send(http, address, keys, algo, beh, hits, limit, duration), len(keys)), axis=1)
+        assert _lane_counts(daemon) == (before[0] + 1, before[1])
+        assert (got == _oracle_rows(cache, keys, algo, beh, hits, limit, duration, now)).all()
 
 
 # ---------------------------------------------------------------------
@@ -624,8 +633,11 @@ def test_the_resolve_is_a_top_level_phase_between_the_admit_and_the_plan():
 def test_the_pump_keeps_the_calendar_bit_off_both_fallback_masks():
     from gubernator_tpu.gateway import NativeIngressPump
 
-    for mask in (NativeIngressPump.FALLBACK_BEHAVIOR, NativeIngressPump.EXPRESS_FALLBACK_BEHAVIOR):
-        assert not mask & GREG
+    for express in (False, True):
+        for all_self in (False, True):
+            assert not NativeIngressPump.fallback_mask(all_self, express) & GREG
+        # A ring with another node: GLOBAL and MULTI_REGION still fall back.
+        mask = NativeIngressPump.fallback_mask(False, express)
         assert mask & int(Behavior.GLOBAL) and mask & int(Behavior.MULTI_REGION)
 
 
@@ -635,7 +647,7 @@ def test_the_cells_files_say_what_the_issue_says():
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     entry = next(c for c in bench["configs"] if c["name"] == "greg-10m")
     assert cell == candidate["workload"] and entry == candidate["config"]  # letter for letter
-    assert bench["workloads"][-1] == cell and bench["configs"][-1] == entry  # appended
+    assert bench["workloads"].index(cell) == 5 and bench["configs"].index(entry) == 4  # appended, by PR 39
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("greg-10m", "frames-pool4k", 1)
     config = _cell_json("configs", "greg-10m.json")
     assert config["source"] == entry["source"] and entry["reduced"] == config["reduced"] == []
@@ -652,20 +664,22 @@ def test_the_cells_files_say_what_the_issue_says():
     # a dispatch): the wire's and the stage's metrics have something to read.
     for name in ("wire.lane_share", "wire.configs_per_dispatch", "wire.uploads_per_dispatch",
                  "wire.upload_ms_per_dispatch", "mesh.stage_ms_per_dispatch"):
-        assert by_name[name]["workloads"][-1] == CELL, name
+        assert CELL in by_name[name]["workloads"][-2:], name  # appended by PR 39; PR 41 appended its cell
     # Nothing to read: the native lane bypasses the batcher, and there is one shard.
     for name in ("batcher.queue_p99_ms", "mesh.pad_fill", "mesh.shard_skew"):
         assert CELL not in by_name[name]["workloads"], name
     for name in NEW_METRICS:
         metric = by_name[name]
-        assert metric["workloads"] == [CELL, BYPASS]
+        assert metric["workloads"][:2] == [CELL, BYPASS]
         spec = _cell_json("layer_metrics", name + ".json")
         assert spec["reader"] in ("mesh_tally", "phase_ms_per", "counter_share")
         assert (spec["layer"], spec["unit"], spec["source"], spec["moves"], spec["better"]) == (
             metric["layer"], metric["unit"], metric["source"], metric["moves"], metric["better"])
         if spec["reader"] == "mesh_tally":
             assert "0" in spec["what"] and "not nothing" in spec["what"]
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW_METRICS):] == list(NEW_METRICS)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    assert names[at:at + len(NEW_METRICS)] == list(NEW_METRICS) and at == 30
 
 
 # ---------------------------------------------------------------------

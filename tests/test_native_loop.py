@@ -251,20 +251,28 @@ def test_classic_json_clients_untouched(daemons):
     assert json.loads(body_fast) == json.loads(body_pr8)
 
 
-def test_slow_behavior_bits_fall_back_to_python(daemons):
-    """GLOBAL lanes need the replica path: the native submit must
-    refuse the frame (fallback counter) and the Python edge must still
-    answer it correctly."""
-    fast, _pr8, _clock, _sock = daemons
+def test_global_lanes_of_a_one_node_ring_stay_native(daemons):
+    """A GLOBAL lane needs the replica path only where another daemon may
+    own its key.  In a one-node ring it is the owner's own: the native
+    submit keeps the frame (since PR 41; tests/test_mixed_cell.py holds
+    the two-node ring, where it still falls back), the pump does the
+    owner's book-keeping, and the answer is the Python edge's."""
+    fast, pr8, _clock, _sock = daemons
     before = fast.gateway.pump.stats()
-    frame = _frame("gl", ["g1", "g2"], behavior=int(Behavior.GLOBAL))
+    frame = _frame("gl", ["g1", "g2", "g1"], behavior=int(Behavior.GLOBAL))
     raw, body = _post(fast.gateway._edge.port, frame)
     assert raw.startswith(b"HTTP/1.1 200 OK")
     rc = wire.decode_ingress_result_frame(body)
-    assert rc.n == 2
+    assert rc.n == 3 and rc.remaining.tolist() == [999, 999, 998] and not rc.overrides
     after = fast.gateway.pump.stats()
-    assert after["fallbacks"] > before["fallbacks"]
-    assert after["frames"] == before["frames"]  # never entered the ring
+    assert after["fallbacks"] == before["fallbacks"]
+    assert after["frames"] == before["frames"] + 1
+    table = fast.service.store.gtable
+    assert table.get("gl_g1") is not None and table.get("gl_g2") is not None
+    # The PR 8 edge (no native lane) answers the same numbers.
+    _, body_pr8 = _post(pr8.gateway._edge.port, frame)
+    rc8 = wire.decode_ingress_result_frame(body_pr8)
+    assert (rc8.status.tolist(), rc8.remaining.tolist()) == (rc.status.tolist(), rc.remaining.tolist())
 
 
 def test_validation_error_lanes_fall_back_with_exact_wording(daemons):
