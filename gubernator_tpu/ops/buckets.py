@@ -1034,10 +1034,11 @@ def compose_wide_answer(planes):
     """The wide answer's i32[..., 8, B] planes back to _pack_output's
     i64[..., 4, B], in numpy: what gt_mesh_finish_wide does lane by
     lane (the lo word is unsigned: its top bit is bit 31 of the value,
-    not a sign)."""
+    not a sign).  Any even count of rows, lo planes then hi planes (the
+    sync answer's ten, global_ops.unpack_sync_answer)."""
     import numpy as np
 
-    half = WIDE_ANSWER_ROWS // 2
+    half = planes.shape[-2] // 2
     lo, hi = planes[..., :half, :], planes[..., half:, :]
     return (hi.astype(np.int64) << 32) | (lo.astype(np.int64) & 0xFFFFFFFF)
 

@@ -28,8 +28,11 @@ way the lane's hits scatter-add into ghits (duplicate gslots are safe:
 scatter-add commutes).
 
 The sync program (global_sync) is ONE shard_map over the mesh replacing
-all three RPC pipelines with collectives:
-  1. psum(ghits)            — hit aggregation to owners
+all three RPC pipelines with collectives, over the gslots TOUCHED since
+the last pass (K of them a launch, gathered by index: the host knows
+every gslot a pass can change, so a pass costs what was touched and not
+what is provisioned):
+  1. psum(ghits[touched])   — hit aggregation to owners
                               (replaces sendHits, global.go:120-160)
   2. owners apply the summed hits to their buckets via the bucket
      kernel (replaces GetPeerRateLimits -> getRateLimit)
@@ -37,7 +40,8 @@ all three RPC pipelines with collectives:
                               (replaces broadcastPeers, global.go:198-243;
                               sum works because exactly one shard owns
                               each gslot)
-  4. every shard writes its replica columns; accumulators reset.
+  4. every shard writes the touched rows of its replica columns; their
+     accumulators reset.
 """
 
 from __future__ import annotations
@@ -82,18 +86,20 @@ class GlobalBatchExtra(NamedTuple):
 
 
 class SyncConfig(NamedTuple):
-    """Per-gslot apply config for the sync step, host-provided (the host
-    mirrors the last-seen request config per GLOBAL key, standing in for
-    the full RateLimitReq the reference forwards in GetPeerRateLimits)."""
+    """Apply config of the gslots one sync launch carries, a row a lane,
+    host-provided (the host mirrors the last-seen request config per
+    GLOBAL key, standing in for the full RateLimitReq the reference
+    forwards in GetPeerRateLimits).  It goes up inside the sync wire
+    (pack_sync_wire)."""
 
-    owner_slot: jax.Array  # i32[G] owner shard's local bucket slot
-    owner_shard: jax.Array  # i32[G]
-    algorithm: jax.Array  # i32[G]
-    behavior: jax.Array  # i32[G] (GLOBAL bit stripped host-side)
-    limit: jax.Array  # i64[G]
-    duration: jax.Array  # i64[G]
-    greg_expire: jax.Array  # i64[G]
-    greg_duration: jax.Array  # i64[G]
+    owner_slot: jax.Array  # i32[K] owner shard's local bucket slot
+    owner_shard: jax.Array  # i32[K]
+    algorithm: jax.Array  # i32[K]
+    behavior: jax.Array  # i32[K] (GLOBAL bit stripped host-side)
+    limit: jax.Array  # i64[K]
+    duration: jax.Array  # i64[K]
+    greg_expire: jax.Array  # i64[K]
+    greg_duration: jax.Array  # i64[K]
 
 
 def clear_gslots(gcols: GlobalColumns, gslots) -> GlobalColumns:
@@ -196,30 +202,115 @@ def answer_batch(
     return new_state, new_gcols, out, cached
 
 
-def global_sync(
-    state: BucketState,
-    gcols: GlobalColumns,
-    cfg: SyncConfig,
-    dirty,  # bool[G] — this shard owns these gslots and touched them locally
-    now_ms,
-    *,
-    axis: str,
-):
-    """One GLOBAL sync step for one shard, meant to run inside shard_map
-    over `axis`.  Collectives replace the reference's three RPC
-    pipelines (see module docstring)."""
-    now = jnp.asarray(now_ms, _I64)
+# The sync wire: what ONE pass launch takes up, a single i32 buffer
+# [1, SYNC_WIRE_COLUMNS * K + WIRE_HEADER_WORDS], replicated over the
+# mesh (every shard needs every touched gslot's configuration; one
+# transfer call, as the dispatch wires make).  Column k lies at words
+# [kK, (k+1)K); the clock rides the header (buckets.set_wire_header), so
+# the launch uploads nothing else.
+#
+#   0  gslot (g_capacity in a lane no gslot fills: dropped on the device)
+#   1  owner_slot    3  algorithm    5  the owner row's dirty bit
+#   2  owner_shard   4  behavior     6… limit, duration, greg_expire,
+#                                       greg_duration: lo then hi of each
+(_SYNC_GSLOT, _SYNC_OWNER_SLOT, _SYNC_OWNER_SHARD, _SYNC_ALGO, _SYNC_BEHAVIOR,
+ _SYNC_DIRTY, _SYNC_VALUES) = range(7)
+SYNC_WIRE_COLUMNS = _SYNC_VALUES + 2 * 4
+
+# And what comes back: i32[SYNC_ANSWER_ROWS, K], the same on every shard
+# (each row is a psum's result), so the host fetches one copy.  Row 0 is
+# applied | removed << 1 | status << 2; then new_expire, total, limit,
+# remaining, reset_time as their LO planes (rows 1-5) and their HI planes
+# (rows 6-10): no 64-bit array leaves the device (buckets.WIDE_ANSWER_ROWS
+# says why).
+SYNC_ANSWER_ROWS = 1 + 2 * 5
+
+
+def pack_sync_wire(width: int, g_capacity: int, gslots, cfg: SyncConfig,
+                   owner_dirty, now_ms: int):
+    """Serialize one launch of the sync program into its single i32
+    buffer (numpy, host side).  `gslots` are at most `width` DISTINCT
+    gslots, `cfg` their SyncConfig rows and `owner_dirty[i]` whether the
+    owner shard's row of `gslots[i]` is dirty (the one dirty bit the
+    program reads: a non-owner's never enters `mine & dirty`)."""
+    import numpy as np
+
+    K, n = width, len(gslots)
+    w = np.zeros((1, SYNC_WIRE_COLUMNS * K + buckets.WIRE_HEADER_WORDS), np.int32)
+
+    def col(k):
+        return w[0, k * K:k * K + n]
+
+    w[0, n:K] = g_capacity
+    col(_SYNC_GSLOT)[:] = gslots
+    col(_SYNC_OWNER_SLOT)[:] = cfg.owner_slot
+    col(_SYNC_OWNER_SHARD)[:] = cfg.owner_shard
+    col(_SYNC_ALGO)[:] = cfg.algorithm
+    col(_SYNC_BEHAVIOR)[:] = cfg.behavior
+    col(_SYNC_DIRTY)[:] = owner_dirty
+    k = _SYNC_VALUES
+    for v in (cfg.limit, cfg.duration, cfg.greg_expire, cfg.greg_duration):
+        v = np.asarray(v, np.int64)
+        col(k)[:] = (v & 0xFFFFFFFF).astype(np.uint32).view(np.int32)
+        col(k + 1)[:] = (v >> 32).astype(np.int32)
+        k += 2
+    buckets.set_wire_header(w, 0, now_ms)
+    return w
+
+
+def unpack_sync_answer(answer):
+    """Host twin of global_sync's answer (numpy i32[SYNC_ANSWER_ROWS, K]):
+    (applied bool, removed bool, status i32, then new_expire, total,
+    limit, remaining, reset_time as i64), a row a lane of the wire."""
+    flags = answer[0]
+    return ((flags & 1) != 0, (flags & 2) != 0, flags >> 2,
+            *buckets.compose_wide_answer(answer[1:]))
+
+
+def global_sync(state: BucketState, gcols: GlobalColumns, wire, *, axis: str):
+    """One launch of the GLOBAL sync step for one shard, meant to run
+    inside shard_map over `axis`, over the K gslots the wire names and
+    no other row (K = the wire's width, static).  Collectives replace the
+    reference's three RPC pipelines (see module docstring).
+
+    The host names every gslot a pass can change (MeshBucketStore.
+    `_gtouched`): a row changes only if some shard holds hits for it or
+    its owner row is dirty, so `ghits` is zero outside the lanes of a
+    pass, and zeroing them is the `zeros_like(ghits)` of a pass over
+    every row."""
+    G = gcols.ghits.shape[0]
+    K = (wire.shape[1] - buckets.WIRE_HEADER_WORDS) // SYNC_WIRE_COLUMNS
+    _, now = buckets.wire_header(wire)
     my = jax.lax.axis_index(axis).astype(_I32)
 
-    total = jax.lax.psum(gcols.ghits, axis)  # hit aggregation -> owners
+    def col(k):
+        return wire[0, k * K:(k + 1) * K]
 
-    mine = cfg.owner_shard == my
+    values = [
+        buckets._compose64(col(k), col(k + 1))  # noqa: SLF001
+        for k in range(_SYNC_VALUES, SYNC_WIRE_COLUMNS, 2)
+    ]
+    gslot = col(_SYNC_GSLOT)
+    cfg = SyncConfig(
+        col(_SYNC_OWNER_SLOT), col(_SYNC_OWNER_SHARD), col(_SYNC_ALGO),
+        col(_SYNC_BEHAVIOR), *values,
+    )
+    live = gslot < G  # a lane no gslot fills carries G
+    # Distinct out-of-bounds rows for the lanes a scatter leaves out
+    # (unique_indices promises uniqueness over the whole index vector).
+    oob = G + jnp.arange(K, dtype=_I32)
+
+    # Hit aggregation -> owners.
+    total = jax.lax.psum(
+        gcols.ghits.at[gslot].get(mode="fill", fill_value=0), axis
+    )
+
     # Owners apply when there are forwarded hits or local dirt; hits==0
     # lanes are pure status reads (broadcastPeers' Hits=0 getRateLimit,
     # global.go:202-214).
-    any_dirty = jax.lax.psum(jnp.where(mine & dirty, 1, 0).astype(_I32), axis) > 0
-    active = (total > 0) | any_dirty
-    apply_mask = mine & active & (cfg.owner_slot >= 0)
+    mine = cfg.owner_shard == my
+    active = (total > 0) | (col(_SYNC_DIRTY) != 0)
+    apply_mask = live & mine & active & (cfg.owner_slot >= 0)
 
     batch = RequestBatch(
         slot=jnp.where(apply_mask, cfg.owner_slot, -1),
@@ -235,27 +326,47 @@ def global_sync(
     new_state, out = buckets.apply_batch(state, batch, now)
 
     # Authoritative broadcast: exactly one shard owns each gslot, so a
-    # masked psum is the broadcast (replaces UpdatePeerGlobals).
-    def bcast(v):
-        return jax.lax.psum(jnp.where(apply_mask, v, 0), axis)
-
-    b_status = bcast(out.status.astype(_I32))
-    b_limit = bcast(out.limit)
-    b_remaining = bcast(out.remaining)
-    b_reset = bcast(out.reset_time)
-    applied = jax.lax.psum(apply_mask.astype(_I32), axis) > 0
-
-    new_gcols = GlobalColumns(
-        rep_status=jnp.where(applied, b_status, gcols.rep_status),
-        rep_limit=jnp.where(applied, b_limit, gcols.rep_limit),
-        rep_remaining=jnp.where(applied, b_remaining, gcols.rep_remaining),
-        # Non-owner cache item expires at ResetTime (gubernator.go:268).
-        rep_reset=jnp.where(applied, b_reset, gcols.rep_reset),
-        rep_expire=jnp.where(applied, b_reset, gcols.rep_expire),
-        ghits=jnp.zeros_like(gcols.ghits),
+    # masked psum is the broadcast (replaces UpdatePeerGlobals), and one
+    # psum carries every row of it.
+    flags = (
+        apply_mask.astype(_I64)
+        | (out.removed.astype(_I64) << 1)
+        | (out.status.astype(_I64) << 2)
     )
-    # `total` is returned so the host tier can forward hits for keys
-    # whose authoritative owner is a REMOTE daemon (owner_shard == -1:
-    # no local shard applies, but the aggregated count must reach the
-    # owner via the peer transport — the sendHits leg, global.go:120-160).
-    return new_state, new_gcols, out, applied, total
+    sent = jax.lax.psum(
+        jnp.where(
+            apply_mask,
+            jnp.stack(
+                (flags, out.new_expire, out.limit, out.remaining, out.reset_time)
+            ),
+            0,
+        ),
+        axis,
+    )
+    b_flags, b_expire, b_limit, b_remaining, b_reset = sent
+    applied = (b_flags & 1) != 0
+
+    drop = dict(mode="drop", unique_indices=True)
+    put = jnp.where(applied, gslot, oob)
+    new_gcols = GlobalColumns(
+        rep_status=gcols.rep_status.at[put].set((b_flags >> 2).astype(_I32), **drop),
+        rep_limit=gcols.rep_limit.at[put].set(b_limit, **drop),
+        rep_remaining=gcols.rep_remaining.at[put].set(b_remaining, **drop),
+        # Non-owner cache item expires at ResetTime (gubernator.go:268).
+        rep_reset=gcols.rep_reset.at[put].set(b_reset, **drop),
+        rep_expire=gcols.rep_expire.at[put].set(b_reset, **drop),
+        ghits=gcols.ghits.at[jnp.where(live, gslot, oob)].set(0, **drop),
+    )
+    # `total` goes back so the host tier can forward hits for keys whose
+    # authoritative owner is a REMOTE daemon (owner_shard == -1: no
+    # local shard applies, but the aggregated count must reach the owner
+    # via the peer transport — the sendHits leg, global.go:120-160).
+    rows = jnp.stack((b_expire, total, b_limit, b_remaining, b_reset))
+    answer = jnp.concatenate(
+        (
+            b_flags.astype(_I32)[None],
+            buckets._lo32(rows),  # noqa: SLF001
+            buckets._hi32(rows),  # noqa: SLF001
+        )
+    )
+    return new_state, new_gcols, answer

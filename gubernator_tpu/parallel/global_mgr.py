@@ -166,6 +166,9 @@ class GlobalKeyTable:
 
         self.owner_shard = np.full(capacity, -1, dtype=np.int32)
         self.owner_slot = np.full(capacity, -1, dtype=np.int32)
+        # The owner table's mapping generation at which `owner_slot` was
+        # last confirmed (MeshBucketStore._resolve_owner_slots).
+        self.owner_gen = np.full(capacity, -1, dtype=np.int64)
         self.algorithm = np.zeros(capacity, dtype=np.int32)
         self.behavior = np.zeros(capacity, dtype=np.int32)  # GLOBAL bit stripped
         self.limit = np.zeros(capacity, dtype=np.int64)
@@ -294,7 +297,7 @@ class GlobalKeyTable:
     def hit_columns(self, gslots: np.ndarray, totals: np.ndarray) -> HitColumns:
         """Wire-ready hit-forward columns for `gslots` (templated lanes
         only — callers pre-filter with `templated`), hits from the
-        device accumulator `totals` (indexed by gslot)."""
+        device accumulator: `totals[i]` is what `gslots[i]` gathered."""
         g = np.asarray(gslots, dtype=np.int64)
         return HitColumns(
             names=[self.names[int(i)] for i in g],
@@ -303,7 +306,7 @@ class GlobalKeyTable:
             behavior=(
                 self.behavior[g] | np.int32(int(Behavior.GLOBAL))
             ).astype(np.int32),
-            hits=np.asarray(totals[g], dtype=np.int64),
+            hits=np.asarray(totals, dtype=np.int64),
             limit=self.limit[g].copy(),
             duration=self.duration[g].copy(),
         )

@@ -37,7 +37,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import telemetry
+from .. import saturation, telemetry
 from ..saturation import phase
 from ..models.shard import (
     ColumnarPipeline,
@@ -351,44 +351,42 @@ _SYNC_FN_CACHE: dict = {}
 _SYNC_COLLECTIVE_LOCK = threading.Lock()
 
 
+# Gslots one launch of the sync program carries (its one width: a store
+# provisioned with fewer carries them all).  A pass takes the gslots
+# touched since the last one, this many a launch, so the width is a
+# launch's fixed price (upload, kernel lanes, read-back) against the
+# launches a burst needs.  Measured on a TPU v5e (PERF.md section 6,
+# PR 42): the program takes 0.8 ms at 1024 lanes and 1.6 ms at 4096,
+# a pass's hold 5.4 against 7.0 ms, where a pass of the benchmark's
+# GLOBAL traffic takes ~100 gslots; under 1024 the launch's fixed
+# costs are all that is left.
+SYNC_WIDTH = 1024
+
+
 def _get_sync_fn(mesh: Mesh, axis: str):
-    """One compiled GLOBAL-sync collective program per (mesh, axis)."""
+    """One compiled GLOBAL-sync collective program per (mesh, axis):
+    `(state, gcols, wire) -> (state, gcols, answer)`, the wire and the
+    answer as global_ops.pack_sync_wire / unpack_sync_answer have them,
+    both replicated (every row of the answer is a psum's result)."""
     key = (mesh, axis)
     fn = _SYNC_FN_CACHE.get(key)
     if fn is None:
 
         @jax.named_scope(buckets.SCOPE_GLOBAL_SYNC)
-        def _sync_body(state, gcols, cfg, dirty, now):
+        def _sync_body(state, gcols, wire):
             sq = lambda t: jax.tree.map(lambda a: a[0], t)
-            ns, ngc, out, applied, total = global_ops.global_sync(
-                sq(state), sq(gcols), cfg, dirty[0], now, axis=axis
-            )
-            # Pack every host-bound column into one i64[8, G] per shard
-            # (one readback per sync, not nine): row 0 bit-packs
-            # removed/applied; the rep_* rows are identical across
-            # shards post-broadcast, so the host reads shard 0's copy.
-            i64 = jnp.int64
-            packed = jnp.stack(
-                (
-                    out.removed.astype(i64) | (applied.astype(i64) << 1),
-                    out.new_expire,
-                    total,
-                    ngc.rep_status.astype(i64),
-                    ngc.rep_limit,
-                    ngc.rep_remaining,
-                    ngc.rep_reset,
-                    ngc.rep_expire,
-                )
+            ns, ngc, answer = global_ops.global_sync(
+                sq(state), sq(gcols), wire, axis=axis
             )
             ex = lambda t: jax.tree.map(lambda a: a[None], t)
-            return ex(ns), ex(ngc), packed[None]
+            return ex(ns), ex(ngc), answer
 
         fn = jax.jit(
             shard_map(
                 _sync_body,
                 mesh=mesh,
-                in_specs=(P(axis), P(axis), P(), P(axis), P()),
-                out_specs=(P(axis), P(axis), P(axis)),
+                in_specs=(P(axis), P(axis), P()),
+                out_specs=(P(axis), P(axis), P()),
             ),
             donate_argnums=(0, 1),
         )
@@ -589,6 +587,17 @@ class MeshBucketStore(ColumnarPipeline):
         )
         self.gtable = GlobalKeyTable(g_capacity)
         self.dirty = np.zeros((self.n_shards, g_capacity), dtype=bool)
+        # The gslots the next sync pass takes, and the only rows it can
+        # change: a superset of {g: some shard's ghits[g] > 0, or
+        # dirty[., g]}.  Device-side ghits grow only under a lane that
+        # carries its gslot (`p.gslot = g` in `apply`) and `dirty` is set
+        # at two sites (`apply`, `_note_global_owners`); each marks the
+        # gslot here, and the pass that zeroes both clears the mark.
+        # (A gslot recycled in between keeps its mark: the pass then
+        # finds no hits and the new key's configuration, as a pass over
+        # every row would.)
+        self._gtouched = np.zeros(g_capacity, dtype=bool)
+        self._sync_width = min(SYNC_WIDTH, g_capacity)
         # A GLOBAL lane was planned since the last sync pass (owner dirt
         # OR device-side ghits).  Raised in `apply` and lowered by the
         # pass, both under the drained lock; `sync_globals` reads it
@@ -605,6 +614,7 @@ class MeshBucketStore(ColumnarPipeline):
         self.transfer_commit_dispatches = 0
 
         self._sharding = NamedSharding(self.mesh, P(self.axis))
+        self._replicated = NamedSharding(self.mesh, P())
         # Wire donation (launch stage): accelerators copy uploads, so
         # the wire buffer is recyclable; CPU zero-copies host numpy.
         self._wire_donate = _wire_donate_ok(self.mesh.devices.flat[0])
@@ -731,6 +741,7 @@ class MeshBucketStore(ColumnarPipeline):
                 if evicted is not None:
                     self.gcols = self._clear_fn(self.gcols, np.array([evicted], np.int32))
                 self.gtable.update_config(g, p.req, p.greg_expire, p.greg_duration)
+                self._gtouched[g] = True
                 non_owner = remote_global or (home_shard is not None and home_shard != owner)
                 if non_owner:
                     # Non-owner: answer locally, forward hits at sync
@@ -862,6 +873,7 @@ class MeshBucketStore(ColumnarPipeline):
             cols.greg_duration[idx],
         )
         self.dirty[owner, g] = True
+        self._gtouched[g] = True
         self._global_pending = True
 
     def _prepare_columns(self, keys, cols, now_ms: int,
@@ -1665,7 +1677,12 @@ class MeshBucketStore(ColumnarPipeline):
         `_drain_then_lock` waits for: every in-flight batch's commit,
         then both locks — serving-pipeline backpressure) and
         `global.sync` (locks held: dispatch, blocking read-back,
-        decode/commit — the real recurring cost of a pass).
+        decode/commit — the real recurring cost of a pass).  The pass
+        works on the gslots TOUCHED since the last one (`_gtouched`) and
+        on no other row: one upload of their configuration, one program
+        of fixed width over them (again for every further `SYNC_WIDTH`
+        of them), one read-back of their rows, so it costs what was
+        touched and not what `g_capacity` provisions.
 
         Sets `last_sync_cost_s` to the `global.sync` reading.  The
         GlobalManager's window tuner reads this instead of its own wall
@@ -1688,234 +1705,156 @@ class MeshBucketStore(ColumnarPipeline):
         finally:
             self._unlock_drained()
 
-    def _sync_globals_locked(self, now_ms: int) -> "SyncResult":
-        active = self.gtable.active_gslots()
+    def _resolve_owner_slots(self, touched: np.ndarray, now_ms: int) -> None:
+        """Resolve each touched GLOBAL key's slot in its owner shard's
+        table (`gtable.owner_slot`), assigning one to a key that has
+        none.
 
-        # Owner-slot resolution fast path: re-verifying every active
-        # gslot's slot each pass is O(active) host work — at 50k-gslot
-        # working sets that is the sync's dominant cost.  A shard whose
-        # table reports an unchanged mapping GENERATION since the end of
-        # the last sync cannot have moved/evicted/removed any key, so
-        # its already-resolved gslots (owner_slot >= 0) are still valid;
-        # only unresolved gslots and shards with mapping churn pay the
-        # per-key verification.  (generation is bumped by assign/remap/
-        # evict/remove in both table twins; value/expire writes and
-        # in-place expiry reuse keep slot ownership and don't bump.)
-        gens = [getattr(t, "generation", None) for t in self.tables]
-        last = getattr(self, "_sync_gen", None)
-        shard_clean = [
-            last is not None and g is not None and last[o] == g
-            for o, g in enumerate(gens)
+        A slot the table confirmed stays valid while that table's
+        mapping GENERATION stands (bumped by assign/remap/evict/remove
+        in both table twins; value/expire writes and in-place expiry
+        reuse keep slot ownership and don't bump), so each gslot keeps
+        the generation it was confirmed at (`gtable.owner_gen`) and a
+        pass with no mapping churn since looks nothing up.  The
+        generation is a gslot's own: a gslot no pass took for a while
+        is held against the generation of ITS last confirmation, not
+        the last pass's.
+
+        Assigning one key can evict another's slot under capacity
+        pressure, so iterate to a fixed point (bounded), then drop any
+        still-unstable entries from this sync."""
+        gt = self.gtable
+        local = [
+            (int(g), int(o))
+            for g, o in zip(touched, gt.owner_shard[touched])
+            if o >= 0  # a remote daemon's key has no local slot
         ]
-
-        # Resolve each GLOBAL key's slot in its owner shard's table.
-        # Assigning one key can evict another's slot under capacity
-        # pressure, so iterate to a fixed point (bounded), then drop any
-        # still-unstable entries from this sync.
+        gens = [t.generation for t in self.tables]
         for _ in range(3):
             changed = False
-            for g in active:
-                o = int(self.gtable.owner_shard[g])
-                if o < 0:
-                    continue  # remote daemon owns it: no local slot
-                if shard_clean[o] and self.gtable.owner_slot[g] >= 0:
+            for g, o in local:
+                if gt.owner_slot[g] >= 0 and gt.owner_gen[g] == gens[o]:
                     continue
-                key = self.gtable.key_of(g)
+                key = gt.key_of(g)
                 slot = self.tables[o].get_slot(key)
                 if slot is None:
                     slot, _ = self.tables[o].lookup_or_assign(key, now_ms)
+                    gens[o] = self.tables[o].generation
                     changed = True
-                    shard_clean[o] = False  # assignment may have evicted
-                self.gtable.owner_slot[g] = slot
+                gt.owner_slot[g] = slot
+                gt.owner_gen[g] = gens[o]
             if not changed:
                 break
-        for g in active:
-            o = int(self.gtable.owner_shard[g])
-            if o < 0 or (shard_clean[o] and self.gtable.owner_slot[g] >= 0):
-                continue
-            key = self.gtable.key_of(g)
-            if self.tables[o].get_slot(key) != int(self.gtable.owner_slot[g]):
-                self.gtable.owner_slot[g] = -1
+        for g, o in local:
+            if gt.owner_gen[g] != gens[o] and (
+                self.tables[o].get_slot(gt.key_of(g)) != int(gt.owner_slot[g])
+            ):
+                gt.owner_slot[g] = -1
 
+    def _sync_globals_locked(self, now_ms: int) -> "SyncResult":
+        gt = self.gtable
+        touched = np.flatnonzero(self._gtouched)
+        self._resolve_owner_slots(touched, now_ms)
         # Owner-slot resolution above may promote demoted GLOBAL keys;
         # their rows must be in the front table before the collective
         # reads them.
         self._drain_moves()
-        cfg = global_ops.SyncConfig(
-            owner_slot=jnp.asarray(self.gtable.owner_slot),
-            owner_shard=jnp.asarray(self.gtable.owner_shard),
-            algorithm=jnp.asarray(self.gtable.algorithm),
-            behavior=jnp.asarray(self.gtable.behavior),
-            limit=jnp.asarray(self.gtable.limit),
-            duration=jnp.asarray(self.gtable.duration),
-            greg_expire=jnp.asarray(self.gtable.greg_expire),
-            greg_duration=jnp.asarray(self.gtable.greg_duration),
-        )
+
+        # The sync program has ONE width: a pass of more touched gslots
+        # launches it again on the next `K`, under the same locks.
+        # Launches hold disjoint gslots, hence disjoint owner slots, so
+        # their order changes nothing.
+        K = self._sync_width
+        owner = gt.owner_shard[touched]
+        owner_dirty = (owner >= 0) & self.dirty[np.maximum(owner, 0), touched]
+        answers = []
         with _SYNC_COLLECTIVE_LOCK:
-            dirty_dev = jax.device_put(jnp.asarray(self.dirty), self._sharding)
-            self.state, self.gcols, packed = self._sync_fn(
-                self.state, self.gcols, cfg, dirty_dev, now_ms
+            for lo in range(0, max(len(touched), 1), K):
+                g = touched[lo:lo + K]
+                wire = global_ops.pack_sync_wire(
+                    K, self.g_capacity, g,
+                    global_ops.SyncConfig(
+                        gt.owner_slot[g], owner[lo:lo + K], gt.algorithm[g],
+                        gt.behavior[g], gt.limit[g], gt.duration[g],
+                        gt.greg_expire[g], gt.greg_duration[g],
+                    ),
+                    owner_dirty[lo:lo + K], now_ms,
+                )
+                self.state, self.gcols, answer = self._sync_fn(
+                    self.state, self.gcols,
+                    jax.device_put(wire, self._replicated),
+                )
+                answers.append((answer, len(g)))
+            # The blocking transfers, one a launch: i32[11, K] each.
+            (applied, removed, status, new_expire, totals, limit, remaining,
+             reset) = global_ops.unpack_sync_answer(
+                np.concatenate(
+                    [host_readback(a)[:, :m] for a, m in answers], axis=1
+                )
             )
-            packed_np = host_readback(packed)  # [S, 8, G] — the one blocking transfer
-        out_rm = (packed_np[:, 0] & 1).astype(bool)
-        out_exp = packed_np[:, 1]
-        # psum results are replicated across shards; read shard 0's copy.
-        applied_np = ((packed_np[0, 0] >> 1) & 1).astype(bool)
-        totals_np = packed_np[0, 2]
-        rep_status = packed_np[0, 3]
-        rep_limit = packed_np[0, 4]
-        rep_remaining = packed_np[0, 5]
-        rep_reset = packed_np[0, 6]
-        self.gtable.rep_expire[:] = packed_np[0, 7]
+            # That was ONE shard's copy: every shard is done with the last
+            # launch before the next collective may start.
+            jax.block_until_ready(self.gcols.ghits)
+        saturation.mesh_tally.add_sync(K * len(answers), len(touched))
+        # Every array above is aligned with `touched`.  A row no shard
+        # applied keeps its replica columns, so its mirror stands too.
+        gt.rep_expire[touched[applied]] = reset[applied]
 
         result = SyncResult()
-        # Vectorized decode tail: the all-gslot Python loop was O(active)
-        # per pass; numpy masks select the (typically sparse) gslots
-        # that actually need host work — remote hit totals, applied
-        # owner commits, broadcasts.
-        act = np.fromiter(active, dtype=np.int64, count=len(active))
-        owner_np = self.gtable.owner_shard[act]
         # Remote daemons' keys with aggregated hits: sendHits payloads
         # (global.go:120-160), emitted as wire-ready COLUMNS straight
         # from the template arrays — no per-key dataclasses.
-        rsel = act[(owner_np < 0) & (totals_np[act] > 0)]
+        rsel = np.flatnonzero((owner < 0) & (totals > 0))
         if rsel.size:
-            rsel = rsel[self.gtable.templated(rsel)]
+            rsel = rsel[gt.templated(touched[rsel])]
         if rsel.size:
-            result.remote_hit_cols = self.gtable.hit_columns(rsel, totals_np)
-        local = act[owner_np >= 0]
-        sel = local[applied_np[local] & (self.gtable.owner_slot[local] >= 0)]
-        sel_shard = self.gtable.owner_shard[sel]
-        for o in np.unique(sel_shard):
+            result.remote_hit_cols = gt.hit_columns(touched[rsel], totals[rsel])
+        # Applied rows: their owner is a local shard and holds a slot.
+        sel = np.flatnonzero(applied)
+        for o in np.unique(owner[sel]):
             o = int(o)
-            idx = sel[sel_shard == o]
-            slots = self.gtable.owner_slot[idx]
-            keys = [self.gtable.key_of(int(g)) for g in idx]
+            at = sel[owner[sel] == o]
+            idx = touched[at]
+            slots = gt.owner_slot[idx].tolist()
+            keys = [gt.key_of(g) for g in idx.tolist()]
             if self.store is not None:
                 # Store SPI parity: the owner-side apply of forwarded
                 # hits fires OnChange/Remove per key in the reference
                 # (algorithms.go:64-68,38-40) — keep the per-key path.
-                for k, g, slot in zip(keys, idx, slots):
-                    g, slot = int(g), int(slot)
+                for k, g, slot, i in zip(keys, idx.tolist(), slots, at.tolist()):
                     self.tables[o].commit(
-                        [slot], [out_exp[o, g]], [out_rm[o, g]], keys=[k]
+                        [slot], [int(new_expire[i])], [bool(removed[i])], keys=[k]
                     )
-                    req = self.gtable.request_template(g, int(totals_np[g]))
-                    if out_rm[o, g]:
+                    req = gt.request_template(g, int(totals[i]))
+                    if removed[i]:
                         self.store.remove(k)
                     elif req is not None:
                         rows = self._read_shard_rows(o, [slot])
                         self.store.on_change(req, _rows_to_items([k], rows)[0])
             else:
                 self.tables[o].commit(
-                    [int(s) for s in slots],
-                    [int(e) for e in out_exp[o, idx]],
-                    [bool(r) for r in out_rm[o, idx]],
-                    keys=keys,
+                    slots, new_expire[at].tolist(), removed[at].tolist(), keys=keys
                 )
-            # Commit-removals unmapped their keys: invalidate now so the
-            # post-commit generation snapshot below can't let a clean
-            # shard skip re-resolving them next pass.
-            for g in idx[out_rm[o, idx]]:
-                self.gtable.owner_slot[int(g)] = -1
+            # Commit-removals unmapped their keys: the next pass that
+            # takes them resolves anew.
+            gt.owner_slot[idx[removed[at]]] = -1
         # Authoritative statuses for the host broadcast leg, in column
-        # form straight from the packed sync readback (the sender
-        # encodes these ONCE and fans the same payload to every peer).
+        # form straight from the sync readback (the sender encodes
+        # these ONCE and fans the same payload to every peer).
         if sel.size:
+            g = touched[sel]
             result.broadcast_cols = GlobalsColumns(
-                keys=[self.gtable.key_of(int(g)) for g in sel],
-                algorithm=self.gtable.algorithm[sel].astype(np.int32),
-                status=rep_status[sel].astype(np.int32),
-                limit=np.asarray(rep_limit[sel], dtype=np.int64),
-                remaining=np.asarray(rep_remaining[sel], dtype=np.int64),
-                reset_time=np.asarray(rep_reset[sel], dtype=np.int64),
+                keys=[gt.key_of(x) for x in g.tolist()],
+                algorithm=gt.algorithm[g].astype(np.int32),
+                status=status[sel].astype(np.int32),
+                limit=limit[sel],
+                remaining=remaining[sel],
+                reset_time=reset[sel],
             )
-        # Snapshot AFTER our own commits (which may bump generations):
-        # shards untouched until the next sync verify nothing then.
-        self._sync_gen = [getattr(t, "generation", None) for t in self.tables]
-        self.dirty[:] = False
+        self.dirty[:, touched] = False
+        self._gtouched[touched] = False
         self._global_pending = False
         return result
-
-    # ------------------------------------------------------------------
-    def measure_sync_cost_s(self, now_ms: int, iters: int = 6) -> float:
-        """BENCHMARK UTILITY: device-only steady-state cost (seconds)
-        of ONE GLOBAL sync collective on this mesh (the reference's
-        sync is a map drain, global.go:163-195; here it is a device
-        collective).  Enqueues `iters` syncs back-to-back (donated
-        state chains them on device) and forces completion with one
-        small readback — the only reliable barrier on a remote device.
-
-        Do NOT call on a store serving GLOBAL traffic: the timed raw
-        syncs drain device-side hit accumulations without the
-        host-side commit/broadcast legs (the serving tuner instead
-        times its real sync passes in situ, service.GlobalManager).
-        Refuses (RuntimeError) if the store already tracks GLOBAL keys
-        beyond its own calibration key — losing their accumulated hits
-        would silently corrupt live traffic.  The authoritative check
-        runs under the store lock (after the pipeline drain) so a key
-        registered by a racing serving thread cannot slip past it."""
-
-        req = RateLimitRequest(
-            name="__synccal__", unique_key="__synccal__", hits=1,
-            limit=1_000_000, duration=60_000, behavior=Behavior.GLOBAL,
-        )
-        cal_key = req.hash_key()
-
-        def _guard():
-            live = [
-                k
-                for k in (
-                    self.gtable.key_of(g) for g in self.gtable.active_gslots()
-                )
-                if k is not None and k != cal_key
-            ]
-            if live:
-                raise RuntimeError(
-                    "measure_sync_cost_s would drain device-side GLOBAL hit "
-                    "accumulations without the host commit/broadcast legs; "
-                    f"refusing with {len(live)} live GLOBAL key(s), e.g. {live[:3]}"
-                )
-
-        _guard()  # fast fail before any device work
-        self.apply([req], now_ms)
-        self._drain_then_lock()
-        try:
-            _guard()  # authoritative: under the lock, pipeline drained
-            # Resolve owner slots + compile the collective, under the
-            # same lock (only the calibration key can exist here, so
-            # discarding the SyncResult's host legs loses nothing).
-            self._sync_globals_locked(now_ms)
-            import time as _time
-
-            cfg = global_ops.SyncConfig(
-                owner_slot=jnp.asarray(self.gtable.owner_slot),
-                owner_shard=jnp.asarray(self.gtable.owner_shard),
-                algorithm=jnp.asarray(self.gtable.algorithm),
-                behavior=jnp.asarray(self.gtable.behavior),
-                limit=jnp.asarray(self.gtable.limit),
-                duration=jnp.asarray(self.gtable.duration),
-                greg_expire=jnp.asarray(self.gtable.greg_expire),
-                greg_duration=jnp.asarray(self.gtable.greg_duration),
-            )
-            dirty_dev = jax.device_put(jnp.asarray(self.dirty), self._sharding)
-
-            def one():
-                self.state, self.gcols, packed = self._sync_fn(
-                    self.state, self.gcols, cfg, dirty_dev, now_ms
-                )
-                return packed
-
-            with _SYNC_COLLECTIVE_LOCK:
-                packed = one()
-                np.asarray(packed[:1, :1, :1])  # drain queue + honest mode
-                t0 = _time.perf_counter()
-                for _ in range(iters):
-                    packed = one()
-                np.asarray(packed[:1, :1, :1])
-                return (_time.perf_counter() - t0) / iters
-        finally:
-            self._unlock_drained()
 
     # ------------------------------------------------------------------
     def warmup(self, now_ms: int, warm_shapes: Optional[Sequence[int]] = None) -> None:

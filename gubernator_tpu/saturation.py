@@ -434,7 +434,13 @@ class MeshTally:
 
     And what the callers' behaviours add: `flaggedLanes` sums the lanes
     that carried NO_BATCHING, GLOBAL or MULTI_REGION into a columnar
-    dispatch (models/shard.py split_routing_bits)."""
+    dispatch (models/shard.py split_routing_bits).
+
+    And what the GLOBAL sync passes carried (MeshBucketStore.
+    _sync_globals_locked): `syncPasses` counts the passes that ran,
+    `syncTouched` sums the gslots they took (touched since the pass
+    before), `syncRows` the rows their launches carried (the program's
+    width a launch: what a pass pays for)."""
 
     WIRE_KEYS = ("dispatches", "lanes", "laneWireDispatches", "laneWireLanes",
                  "configRows", "uploads", "calendarLanes", "wideDispatches")
@@ -445,7 +451,8 @@ class MeshTally:
         self._sums = dict.fromkeys(
             ("dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
              "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
-             "calendarLanes", "wideDispatches", "flaggedLanes"), 0
+             "calendarLanes", "wideDispatches", "flaggedLanes",
+             "syncPasses", "syncRows", "syncTouched"), 0
         )
 
     def add(self, shards: int, lanes: int, padded: int, fullest: int,
@@ -468,6 +475,13 @@ class MeshTally:
             s["calendarLanes"] += calendar_lanes
             s["wideDispatches"] += wide
             s["flaggedLanes"] += flagged_lanes
+
+    def add_sync(self, rows: int, touched: int) -> None:
+        with self._lock:
+            s = self._sums
+            s["syncPasses"] += 1
+            s["syncRows"] += rows
+            s["syncTouched"] += touched
 
     def snapshot(self) -> Dict[str, int]:
         with self._lock:

@@ -176,10 +176,12 @@ class ServiceConfig:
     # bucket-table capacity (clamped [4096, 65536]): the reference has
     # NO separate GLOBAL key cap — GLOBAL keys share its 50k cache
     # (global.go:83-91) — so a working set that fits the cache must fit
-    # the replica table.  The sync collective scans every gslot each
-    # pass (cost is linear in this capacity), and the auto-tuned
-    # GlobalSyncWait stretches to keep that overhead ≤10%, so
-    # convergence lag grows with the capacity you provision.
+    # the replica table.  A sync pass works on the gslots TOUCHED since
+    # the last one (cost is linear in those, a launch of 1024 at a
+    # time, not in this capacity), and the auto-tuned GlobalSyncWait
+    # stretches to keep that overhead ≤10%, so convergence lag grows
+    # with the GLOBAL keys hit a window, not with what you provision
+    # (which costs 44 B a gslot of device memory per shard).
     global_cache_size: Optional[int] = None
     behaviors: BehaviorConfig = field(default_factory=BehaviorConfig)
     advertise_address: str = ""

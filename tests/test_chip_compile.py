@@ -164,27 +164,50 @@ def test_wide_dict_wire_answers_in_32_bit_words_for_one_v5e(one_chip):
     assert "X64Combine" not in entry
 
 
+def _sync_wire(mesh):
+    words = global_ops.SYNC_WIRE_COLUMNS * mesh_mod.SYNC_WIDTH + buckets.WIRE_HEADER_WORDS
+    return jax.ShapeDtypeStruct((1, words), jnp.int32, sharding=NamedSharding(mesh, P()))
+
+
+def _gcols(mesh):
+    return _sharded(mesh, jax.eval_shape(lambda: global_ops.init_global_columns(G_CAPACITY)))
+
+
 def test_global_sync_compiles_for_four_v5e(four_chips):
     """The GLOBAL sync collective — the mesh's one cross-chip program —
-    with the table sharded four ways."""
-    gcols = _sharded(
-        four_chips, jax.eval_shape(lambda: global_ops.init_global_columns(G_CAPACITY))
-    )
-    replicated = NamedSharding(four_chips, P())
-    g = lambda dtype: jax.ShapeDtypeStruct((G_CAPACITY,), dtype, sharding=replicated)  # noqa: E731
-    cfg = global_ops.SyncConfig(
-        owner_slot=g(jnp.int32), owner_shard=g(jnp.int32), algorithm=g(jnp.int32),
-        behavior=g(jnp.int32), limit=g(jnp.int64), duration=g(jnp.int64),
-        greg_expire=g(jnp.int64), greg_duration=g(jnp.int64),
-    )
-    dirty = _sharded(four_chips, jax.ShapeDtypeStruct((G_CAPACITY,), jnp.bool_))
+    with the table sharded four ways: one launch over the SYNC_WIDTH gslots
+    its wire names, of the 65,536 provisioned.  Two collectives (the hits to
+    their owners, the owners' statuses to everyone), and the answer leaves
+    replicated as 32-bit words."""
     compiled = _compile(
-        "GLOBAL sync, 4 x 262,144 slots, four chips",
+        f"GLOBAL sync, 4 x 262,144 slots, {mesh_mod.SYNC_WIDTH} of 65,536 gslots, four chips",
         mesh_mod._get_sync_fn(four_chips, "shard").lower(
-            _state(four_chips), gcols, cfg, dirty, NOW_MS
+            _state(four_chips), _gcols(four_chips), _sync_wire(four_chips)
         ),
     )
-    assert "all-reduce" in compiled.as_text()
+    text = compiled.as_text()
+    assert 1 <= text.count(" all-reduce(") + text.count(" all-reduce-start(") <= 2
+    # What the host fetches is 32-bit words (the gslot columns stay s64, and
+    # stay on the device).
+    entry = text[text.index("ENTRY"):]
+    root = entry[entry.index("ROOT"):].splitlines()[0]
+    assert f"s32[{global_ops.SYNC_ANSWER_ROWS},{mesh_mod.SYNC_WIDTH}]" in root, root
+
+
+def test_global_sync_compiles_for_one_v5e_and_updates_its_columns_in_place(one_chip):
+    """The same program as every one-chip daemon warms it.  State and gslot
+    columns are donated: a launch may not copy either table (64 MB and
+    2.9 MB) to change a launch's rows."""
+    compiled = _compile(
+        f"GLOBAL sync, 1M slots, {mesh_mod.SYNC_WIDTH} of 65,536 gslots, one chip",
+        mesh_mod._get_sync_fn(one_chip, "shard").lower(
+            _state(one_chip), _gcols(one_chip), _sync_wire(one_chip)
+        ),
+    )
+    mem = compiled.memory_analysis()
+    tables = 64 * SLOTS + G_CAPACITY * (4 + 5 * 8)
+    assert mem.alias_size_in_bytes == tables
+    assert mem.temp_size_in_bytes < tables // 16
 
 
 def test_tier_moves_compile_for_one_v5e_and_copy_no_tier(one_chip):

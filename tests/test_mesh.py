@@ -158,29 +158,3 @@ def test_fused_duplicates_match_sequential(fused_native):
             assert (g.status, g.remaining, g.reset_time) == (
                 w.status, w.remaining, w.reset_time,
             ), (step, i, reqs[i], g, w)
-
-
-def test_measure_sync_cost_refuses_live_global_traffic():
-    """measure_sync_cost_s drains device-side GLOBAL accumulations
-    without the host commit/broadcast legs, so it must refuse to run on
-    a store already serving GLOBAL keys (mesh.py documents the contract;
-    this pins it as an assertion, not a comment)."""
-    from gubernator_tpu.types import Behavior
-
-    store = MeshBucketStore(capacity_per_shard=64, g_capacity=16)
-    now = 1_700_000_000_000
-    store.apply(
-        [
-            RateLimitRequest(
-                name="mesh", unique_key="live_global", hits=1, limit=10,
-                duration=5000, behavior=Behavior.GLOBAL,
-            )
-        ],
-        now,
-    )
-    with pytest.raises(RuntimeError, match="live GLOBAL"):
-        store.measure_sync_cost_s(now + 1, iters=1)
-
-    # A fresh store (no GLOBAL traffic) measures fine.
-    clean = MeshBucketStore(capacity_per_shard=64, g_capacity=16)
-    assert clean.measure_sync_cost_s(now, iters=1) > 0

@@ -205,7 +205,8 @@ def test_debug_device_serves_the_mesh_block(served):
     assert set(mesh) == {
         "shards", "dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
         "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
-        "calendarLanes", "wideDispatches", "flaggedLanes"}
+        "calendarLanes", "wideDispatches", "flaggedLanes",
+        "syncPasses", "syncRows", "syncTouched"}
     assert mesh["dispatches"] >= len(TAKE_FRAMES) and mesh["paddedLanes"] >= mesh["lanes"] > 0
 
 

@@ -36,7 +36,7 @@ from gubernator_tpu.models.shard import (
     pad_size,
     split_routing_bits,
 )
-from gubernator_tpu.parallel.mesh import MeshBucketStore, shard_of_key
+from gubernator_tpu.parallel.mesh import SYNC_WIDTH, MeshBucketStore, shard_of_key
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest
 
 from . import oracle as orc
@@ -70,6 +70,7 @@ BYPASS = "v5e1-1m.frames"
 SHARDS = [1, 2, 4]
 NEW_METRICS = ("behavior.flagged_lane_share", "behavior.handle_ms_per_dispatch",
                "global.sync_hold_ms_per_pass", "global.sync_ms_per_req")
+SYNC_ROWS = "global.sync_rows_per_pass"  # PR 42
 NATIVE_INGRESS = "gubernator_native_ingress_batches_total"
 
 
@@ -465,11 +466,19 @@ def test_the_native_lane_keeps_every_mixed_frame_in_one_dispatch_and_keeps_the_o
     # The pass that is owed runs on the manager's tick, takes the dirt and,
     # with no peer to tell, sends nothing.
     syncs_before = http.get_json("/debug/latency")["phases"].get("global.sync", {"count": 0})["count"]
+    assert int(store._gtouched.sum()) == len(want)
     assert daemon.service.global_mgr.run_once()
     assert not store.dirty.any() and not store._global_pending
     latency = http.get_json("/debug/latency")
     assert latency["phases"]["global.sync"]["count"] == syncs_before + 1
+    # What the pass carried: the touched gslots and no other row, in one
+    # launch of the program's one width.
+    mesh = http.get_json("/debug/device")["mesh"]
+    assert store._sync_width == min(store.g_capacity, SYNC_WIDTH) > len(want)
+    assert {k: mesh[k] - before["mesh"][k] for k in ("syncPasses", "syncRows", "syncTouched")} == {
+        "syncPasses": 1, "syncRows": store._sync_width, "syncTouched": len(want)}
     assert not daemon.service.global_mgr.run_once()  # idle: nothing pending
+    assert http.get_json("/debug/device")["mesh"]["syncPasses"] == mesh["syncPasses"]
     # The read-back after the pass: the pass moved no bucket.
     idx, behavior, hits, now = takes[-1]
     assert (_send(http, address, pop, idx, behavior, hits) == expected[-1]).all()
@@ -620,7 +629,14 @@ def test_the_cells_files_say_what_the_issue_says(traffic):
             metric["layer"], metric["unit"], metric["source"], metric["moves"], metric["better"])
     assert [by_name[name]["moves"] for name in NEW_METRICS] == [
         "req_p50_ms", "req_p50_ms", "req_p99_ms", "checks_per_s"]
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW_METRICS):] == list(NEW_METRICS)
+    # Appended in PR 41's order, and PR 42's one after them: the rows a pass
+    # carried, read where a pass runs.
+    assert [m["name"] for m in bench["per_layer"]][-len(NEW_METRICS) - 1:] == [*NEW_METRICS, SYNC_ROWS]
+    rows, spec = by_name[SYNC_ROWS], _cell_json("layer_metrics", SYNC_ROWS + ".json")
+    assert rows["workloads"] == [CELL] and spec["reader"] == "mesh_tally"
+    assert (spec["layer"], spec["unit"], spec["source"], spec["moves"], spec["better"]) == (
+        rows["layer"], rows["unit"], rows["source"], rows["moves"], rows["better"]) == (
+        by_name["global.sync_hold_ms_per_pass"]["layer"], "rows", "program_counter", "req_p99_ms", "lower")
     assert bench["workloads"][-1] == cell and bench["configs"][-1] == entry  # appended
 
 
@@ -672,6 +688,27 @@ def test_the_bypass_reads_the_check_alone_no_flagged_lane_and_no_pass():
     assert _read("behavior.handle_ms_per_dispatch", ctx) == pytest.approx(0.005)
     assert _read("global.sync_ms_per_req", ctx) == 0.0
     assert _read("global.sync_hold_ms_per_pass", ctx) is None
+
+
+def test_the_rows_a_pass_carried_are_read_from_the_sync_counters_and_a_parent_reads_nothing():
+    """`global.sync_rows_per_pass`: 60 passes in ramp and window, two of them
+    bursts of two launches, at the width 4096.  A program from before PR 42
+    counts no pass (its `mesh` block has no such counter), so there is nothing
+    to divide by: None, and no raise; so with no snapshot at all."""
+    loaded = dict(LOADED, syncPasses=1, syncRows=4096, syncTouched=1)
+    window = dict(WINDOW, syncPasses=61, syncRows=4096 * 63, syncTouched=1 + 60 * 85)
+    ctx = {"before": _snap(loaded, PHASES_LOADED), "after": _snap(window, PHASES_WINDOW), "requests": 3_000}
+    assert _read(SYNC_ROWS, ctx) == pytest.approx(4096 * 62 / 60)
+    ctx = {"before": _snap(loaded, PHASES_LOADED), "after": _snap(dict(window, syncRows=4096 * 61), PHASES_WINDOW),
+           "requests": 3_000}
+    assert _read(SYNC_ROWS, ctx) == 4096.0
+    parent = {"before": _snap(LOADED, PHASES_LOADED), "after": _snap(WINDOW, PHASES_WINDOW), "requests": 3_000}
+    assert _read(SYNC_ROWS, parent) is None
+    assert _read(SYNC_ROWS, {"before": _snap(), "after": _snap(), "requests": 0}) is None
+    # The bypass: the counters are there and no pass ran between the snapshots.
+    idle = {"before": _snap(loaded, PHASES_LOADED), "after": _snap(dict(WINDOW, **{
+        k: loaded[k] for k in ("syncPasses", "syncRows", "syncTouched")}), PHASES_LOADED), "requests": 3_000}
+    assert _read(SYNC_ROWS, idle) is None
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
