@@ -191,6 +191,19 @@ def _build() -> Optional[ctypes.CDLL]:
         c.c_void_p, c.c_void_p, c.c_void_p,  # slot, rid, exists
         c.c_void_p, c.c_void_p, c.c_void_p,  # occ, write, pos
     ]
+    lib.gt_mesh_encode_wire.restype = c.c_int32
+    lib.gt_mesh_encode_wire.argtypes = [
+        c.c_int64, c.c_int64, c.c_int64,  # S, P, n
+        c.c_void_p, c.c_void_p, c.c_void_p,  # slot, exists, write
+        c.c_void_p, c.c_void_p, c.c_void_p,  # occ, rid, pos
+        c.c_void_p, c.c_void_p,  # algo, behavior
+        c.c_void_p, c.c_void_p, c.c_void_p,  # hits, limit, duration
+        c.c_void_p, c.c_void_p,  # greg_expire, greg_duration
+        c.c_int64, c.c_int64,  # now_ms, n_rounds
+        c.c_int32, c.c_int32,  # narrow, force_lanes
+        c.c_int64, c.c_int64,  # dict_row, lane_row (words)
+        c.c_void_p, c.c_void_p,  # wire out, config_rows out
+    ]
     lib.gt_mesh_finish_narrow.argtypes = [
         c.c_void_p, c.c_void_p, c.c_int64,
         c.c_void_p, c.c_void_p, c.c_void_p,
@@ -984,7 +997,8 @@ class NativeMeshPlanner:
     FIFO resolver — the per-table C++ mutex makes a finish safe
     against the NEXT batch's concurrent plan):
         mp = NativeMeshPlanner(tables, keys, now_ms)   # begin: counts
-        plan = mp.plan_grouped(cols, reset_mask)       # padded arrays
+        n_rounds = mp.plan_grouped(cols, reset_mask, P)  # padded arrays
+        wire, lane_wire, rows = mp.encode_wire(...)    # the upload's buffer
         ... device dispatch ...
         status, remaining, reset = mp.finish_narrow(packed_np, now_ms)
     """
@@ -1036,6 +1050,42 @@ class NativeMeshPlanner:
             self.write.ctypes.data, self.pos.ctypes.data,
         )
         return int(n_rounds)
+
+    def encode_wire(self, cols, now_ms: int, n_rounds: int, narrow: bool,
+                    force_lanes: bool, dict_row: int, lane_row: int):
+        """The planned batch as the ONE i32 buffer the stage uploads,
+        header included, in one call (gt_mesh_encode_wire: it interns
+        the lanes' configurations, counts them, picks the wire by the
+        rule and fills it).  `dict_row` and `lane_row` are the words of
+        a shard's row on the dictionary wire and on the per-lane wire
+        of this answer width (ops/buckets.py dict_wire_words,
+        lane_wire_words): the layout is buckets', and a width the
+        native side would not write raises.  Returns (wire i32[S, W],
+        lane_wire, config_rows): a view of a fresh buffer a call (on
+        the CPU backend the device array aliases it), sized for the
+        wider of the two rows."""
+        S, P = self.slot.shape
+        buf = np.empty(S * max(dict_row, lane_row), dtype=np.int32)
+        config_rows = ctypes.c_int64()
+        lane_wire = self._lib.gt_mesh_encode_wire(
+            S, P, self.n,
+            self.slot.ctypes.data, self.exists.ctypes.data,
+            self.write.ctypes.data, self.occ.ctypes.data,
+            self.rid.ctypes.data, self.pos.ctypes.data,
+            cols.algo.ctypes.data, cols.behavior.ctypes.data,
+            cols.hits.ctypes.data, cols.limit.ctypes.data,
+            cols.duration.ctypes.data,
+            cols.greg_expire.ctypes.data, cols.greg_duration.ctypes.data,
+            now_ms, n_rounds, narrow, force_lanes, dict_row, lane_row,
+            buf.ctypes.data, ctypes.byref(config_rows),
+        )
+        if lane_wire < 0:
+            raise ValueError(
+                f"wire rows of {dict_row} / {lane_row} words for {P} lanes "
+                "are not the native encoder's layout"
+            )
+        row = lane_row if lane_wire else dict_row
+        return buf[: S * row].reshape(S, row), bool(lane_wire), config_rows.value
 
     def finish_narrow(self, packed_np, now_ms: int):
         """Decode + commit a narrow i32[S, 4, P] result; returns

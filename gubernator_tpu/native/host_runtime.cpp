@@ -1393,8 +1393,9 @@ namespace {
 //   gt_mesh_begin(tables[S], keys, n)    -> handle + per-shard counts
 //   gt_mesh_plan_grouped(h, cols, P, ..) -> padded [S,P] plan arrays,
 //                                           pos[n] (lane -> padded idx)
-//   ... device dispatch (Python/numpy packs the wire from the padded
-//       arrays with vectorized ops) ...
+//   gt_mesh_encode_wire(plan arrays, cols, ..) -> the ONE i32 buffer the
+//                                           stage uploads (either wire)
+//   ... device dispatch ...
 //   gt_mesh_finish_{narrow,wide}(h, ..)  -> response columns in ORIGINAL
 //                                           order + slot-table commit
 //   gt_mesh_free(h)
@@ -1410,6 +1411,49 @@ struct MeshPlan {
   std::vector<std::vector<int32_t>> pslot;   // per-shard planned slots [m]
   std::vector<std::vector<int64_t>> pre_exp; // plan-time expiry snapshot [m]
 };
+
+// The wire's encode (gt_mesh_encode_wire).  The layouts are those of
+// ops/buckets.py, which keeps the numpy packers as the reference the
+// tests hold this to: pack_dict_wire / pack_lane_wire / set_wire_header
+// on the host, unpack_dict_wire / unpack_lane_wire inside the program.
+constexpr int64_t kDictTableRows = 256;  // DICT_TABLE_ROWS
+constexpr int64_t kDictTableWords = 2 * kDictTableRows + 5 * 2 * kDictTableRows;
+constexpr int64_t kWireHeaderWords = 4;  // WIRE_HEADER_WORDS
+constexpr int64_t kLaneWords = 11;       // LANE_WIRE_WORDS
+constexpr int64_t kLaneWordsWide = 16;   // LANE_WIRE_WORDS_WIDE
+
+// One configuration: what the dictionary wire's table holds a row of.
+// v[0], v[1] are algorithm and behaviour; v[5] is greg_expire as the
+// delta from now (0 where greg_duration, v[6], is 0).
+struct WireConfig {
+  int64_t v[7];
+  bool operator==(const WireConfig& o) const {
+    uint64_t d = 0;
+    for (int k = 0; k < 7; ++k) d |= (uint64_t)(v[k] ^ o.v[k]);
+    return d == 0;
+  }
+};
+
+// Seven independent multiplies (odd constants), then one mix: the
+// table below is probed by the low bits.
+inline uint64_t wire_config_hash(const WireConfig& c) {
+  static const uint64_t K[7] = {
+      0x9E3779B97F4A7C15ull, 0xC2B2AE3D27D4EB4Full, 0x165667B19E3779F9ull,
+      0xFF51AFD7ED558CCDull, 0xC4CEB9FE1A85EC53ull, 0xD6E8FEB86659FD93ull,
+      0x27D4EB2F165667C5ull};
+  uint64_t h = 0;
+  for (int k = 0; k < 7; ++k) h += (uint64_t)c.v[k] * K[k];
+  h ^= h >> 29;
+  h *= 0xBF58476D1CE4E5B9ull;
+  return h ^ (h >> 32);
+}
+
+inline int32_t lo32(int64_t v) { return (int32_t)(uint32_t)(uint64_t)v; }
+inline int32_t hi32(int64_t v) { return (int32_t)(v >> 32); }
+// a - b as numpy's i64 takes it: it wraps.
+inline int64_t sub64(int64_t a, int64_t b) {
+  return (int64_t)((uint64_t)a - (uint64_t)b);
+}
 
 }  // namespace
 
@@ -1533,6 +1577,157 @@ int64_t gt_mesh_plan_grouped(void* mpv, const int32_t* algo,
     }
   }
   return n_rounds;
+}
+
+// Phase 2b: the wire.  One pass over the n request lanes interns each
+// lane's configuration in an open-addressed table that compares the
+// seven values themselves (exact: two configurations never share a
+// row, whatever they hash to) and counts ALL the frame's distinct
+// ones; then the rule picks the wire and the buffer is filled, header
+// included, every word of it written once, in order.  The DICTIONARY
+// wire where the frame has at most 256 configurations, at most 255
+// rounds, no `occ` past 65,535 and the caller does not force the
+// per-lane one: rows of 3P + kDictTableWords + 4 words (slot; occ |
+// flags << 16 | cfg << 24; round id; the table, rows in order of first
+// appearance, a copy a shard row).  Else the PER-LANE wire: rows of
+// 11P + 4 words, 16P + 4 where the answer is wide (`narrow` 0):
+// greg_expire as the delta from now on the narrow one, absolute on the
+// wide one.  Where the rule is decided before any count (forced, or
+// past 255 rounds) nothing is interned and *config_rows is 0; an empty
+// frame takes the per-lane wire.  `wire` holds S * max(dict_row,
+// lane_row) words; the S rows are written back to back at the chosen
+// width.  The caller passes the two widths it allocated by, and a
+// width this file would not write is refused (-1) before a word is
+// written.  Returns 1 for the per-lane wire, 0 for the dictionary's.
+int32_t gt_mesh_encode_wire(
+    int64_t S, int64_t P, int64_t n, const int32_t* slot,
+    const uint8_t* exists, const uint8_t* write, const int32_t* occ,
+    const int32_t* rid, const int64_t* pos, const int32_t* algo,
+    const int32_t* behavior, const int64_t* hits, const int64_t* limit,
+    const int64_t* duration, const int64_t* greg_e, const int64_t* greg_d,
+    int64_t now_ms, int64_t n_rounds, int32_t narrow, int32_t force_lanes,
+    int64_t dict_row, int64_t lane_row, int32_t* wire,
+    int64_t* config_rows) {
+  const int64_t lane_words = narrow ? kLaneWords : kLaneWordsWide;
+  if (dict_row != 3 * P + kDictTableWords + kWireHeaderWords ||
+      lane_row != lane_words * P + kWireHeaderWords)
+    return -1;
+
+  std::vector<WireConfig> configs;
+  std::vector<uint8_t> cfg_at;  // [S, P]: a lane's row of the table
+  bool dict = !force_lanes && n_rounds <= 255 && n > 0;
+  if (dict) {
+    size_t cap = 16;
+    while (cap < (size_t)n * 2) cap <<= 1;
+    std::vector<int32_t> index(cap, -1);
+    cfg_at.assign((size_t)(S * P), 0);
+    configs.reserve((size_t)kDictTableRows + 1);
+    for (int64_t i = 0; i < n; ++i) {
+      WireConfig c{{algo[i], behavior[i], hits[i], limit[i], duration[i],
+                    greg_d[i] != 0 ? sub64(greg_e[i], now_ms) : 0, greg_d[i]}};
+      size_t at = (size_t)wire_config_hash(c) & (cap - 1);
+      while (index[at] >= 0 && !(configs[(size_t)index[at]] == c))
+        at = (at + 1) & (cap - 1);
+      if (index[at] < 0) {
+        index[at] = (int32_t)configs.size();
+        // A frame past the table (a limit a key) may hold n: one move
+        // of the 257, not a doubling's copies all the way up.
+        if ((int64_t)configs.size() == kDictTableRows + 1)
+          configs.reserve((size_t)n);
+        configs.push_back(c);
+      }
+      // Past 255 the frame has left the dictionary: the count goes on,
+      // the rows are not used.
+      cfg_at[(size_t)pos[i]] = (uint8_t)index[at];
+    }
+    dict = (int64_t)configs.size() <= kDictTableRows;
+    if (dict) {
+      int32_t top = 0;
+      for (int64_t k = 0; k < S * P; ++k) top = std::max(top, occ[k]);
+      dict = top <= 65535;
+    }
+  }
+  *config_rows = (int64_t)configs.size();
+
+  const int64_t W = dict ? dict_row : lane_row;
+  auto header = [&](int32_t* row) {
+    int32_t* h = row + W - kWireHeaderWords;
+    h[0] = (int32_t)n_rounds;
+    h[1] = lo32(now_ms);
+    h[2] = hi32(now_ms);
+    h[3] = 0;
+  };
+
+  if (dict) {
+    // Shard 0's table, then a copy a row.
+    int32_t* table = wire + 3 * P;
+    std::memset(table, 0, sizeof(int32_t) * kDictTableWords);
+    for (size_t k = 0; k < configs.size(); ++k) {
+      const int64_t* v = configs[k].v;
+      table[k] = (int32_t)v[0];
+      table[kDictTableRows + k] = (int32_t)v[1];
+      for (int f = 2; f < 7; ++f) {
+        int32_t* pair = table + (2 + 2 * (f - 2)) * kDictTableRows;
+        pair[k] = lo32(v[f]);
+        pair[kDictTableRows + k] = hi32(v[f]);
+      }
+    }
+    for (int64_t s = 0; s < S; ++s) {
+      int32_t* row = wire + s * W;
+      const int64_t base = s * P;
+      std::memcpy(row, slot + base, sizeof(int32_t) * P);
+      int32_t* meta = row + P;
+      for (int64_t j = 0; j < P; ++j)
+        meta[j] = (int32_t)((uint32_t)(occ[base + j] & 0xFFFF) |
+                            (uint32_t)(exists[base + j] | (write[base + j] << 1)) << 16 |
+                            (uint32_t)cfg_at[(size_t)(base + j)] << 24);
+      std::memcpy(row + 2 * P, rid + base, sizeof(int32_t) * P);
+      if (s) std::memcpy(row + 3 * P, table, sizeof(int32_t) * kDictTableWords);
+      header(row);
+    }
+    return 0;
+  }
+
+  // The request at each place of the plan (-1: padding, which reads
+  // zero in every column but slot's), so the rows are written through
+  // in order and no place is divided back into shard and lane.
+  std::vector<int32_t> req_at((size_t)(S * P), -1);
+  for (int64_t i = 0; i < n; ++i) req_at[(size_t)pos[i]] = (int32_t)i;
+  for (int64_t s = 0; s < S; ++s) {
+    int32_t* row = wire + s * W;
+    const int64_t base = s * P;
+    const int32_t* at = req_at.data() + base;
+    std::memcpy(row, slot + base, sizeof(int32_t) * P);
+    int32_t* flags = row + P;
+    for (int64_t j = 0; j < P; ++j)
+      flags[j] = exists[base + j] | (write[base + j] << 1);
+    std::memcpy(row + 4 * P, occ + base, sizeof(int32_t) * P);
+    std::memcpy(row + 5 * P, rid + base, sizeof(int32_t) * P);
+    auto column = [&](int64_t k, auto value) {
+      int32_t* col = row + k * P;
+      for (int64_t j = 0; j < P; ++j) col[j] = at[j] >= 0 ? value(at[j]) : 0;
+    };
+    column(2, [&](int32_t i) { return algo[i]; });
+    column(3, [&](int32_t i) { return behavior[i]; });
+    if (narrow) {
+      column(6, [&](int32_t i) { return lo32(hits[i]); });
+      column(7, [&](int32_t i) { return lo32(limit[i]); });
+      column(8, [&](int32_t i) { return lo32(duration[i]); });
+      column(9, [&](int32_t i) {
+        return greg_d[i] != 0 ? lo32(sub64(greg_e[i], now_ms)) : 0;
+      });
+      column(10, [&](int32_t i) { return lo32(greg_d[i]); });
+    } else {
+      const int64_t* values[5] = {hits, limit, duration, greg_e, greg_d};
+      for (int f = 0; f < 5; ++f) {
+        const int64_t* v = values[f];
+        column(6 + 2 * f, [&](int32_t i) { return lo32(v[i]); });
+        column(7 + 2 * f, [&](int32_t i) { return hi32(v[i]); });
+      }
+    }
+    header(row);
+  }
+  return 1;
 }
 
 // Phase 3 (narrow wire): decode the packed i32[S, 4, P] device result,

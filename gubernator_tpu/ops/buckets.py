@@ -851,7 +851,9 @@ def apply_rounds_dict(
 DICT_WIRE_TABLE_WORDS = 2 * DICT_TABLE_ROWS + 5 * 2 * DICT_TABLE_ROWS
 
 # The wire's header: the last words of every shard's row, on either
-# wire.  What a dispatch program reads besides the state and the lanes
+# wire (written by the native encode with the rest of the buffer;
+# set_wire_header is its numpy twin, for the reference packers).  What
+# a dispatch program reads besides the state and the lanes
 # rides the ONE buffer the stage uploads, so a launch hands the runtime
 # device arrays alone and makes no host->device transfer of its own (a
 # Python or numpy scalar argument is a transfer call each, 0.2 ms on a
@@ -888,6 +890,11 @@ def wire_header(wire):
     return h[0], _compose64(h[1], h[2])
 
 
+def dict_wire_words(P: int) -> int:
+    """Words of a shard's row on the dictionary wire of P lanes."""
+    return 3 * P + DICT_WIRE_TABLE_WORDS + WIRE_HEADER_WORDS
+
+
 def dict_wire_lanes(words: int) -> int:
     """P of a dictionary wire whose shard row is `words` long."""
     return (words - DICT_WIRE_TABLE_WORDS - WIRE_HEADER_WORDS) // 3
@@ -895,6 +902,12 @@ def dict_wire_lanes(words: int) -> int:
 
 def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array":
     """Serialize one dict-wire batch into a SINGLE i32 buffer.
+
+    The numpy REFERENCE of the layout: a dispatch's wire is filled by
+    the native encode beside the plan (host_runtime.cpp
+    gt_mesh_encode_wire, one call for count, rule, buffer and header),
+    which tests/test_native_encode.py holds to this function; the
+    served path does not call it.
 
     The dict wire's 12 separate arrays cost 12 host->device transfers
     per dispatch; at service batch sizes (<=4096 lanes) the per-call
@@ -924,9 +937,7 @@ def pack_dict_wire(slot, exists, write, cfg, occ, round_id, table) -> "jax.Array
     import numpy as np
 
     S, P = slot.shape
-    w = np.empty(
-        (S, 3 * P + DICT_WIRE_TABLE_WORDS + WIRE_HEADER_WORDS), dtype=np.int32
-    )
+    w = np.empty((S, dict_wire_words(P)), dtype=np.int32)
     w[:, :P] = slot
     meta = occ.astype(np.int32) & 0xFFFF
     meta |= (exists.astype(np.int32) | (write.astype(np.int32) << 1)) << 16
@@ -1089,10 +1100,17 @@ LANE_WIRE_WORDS = _LANE_VALUES + 5
 LANE_WIRE_WORDS_WIDE = _LANE_VALUES + 2 * 5
 
 
+def lane_wire_words(P: int, wide: bool) -> int:
+    """Words of a shard's row on the per-lane wire of P lanes."""
+    return (LANE_WIRE_WORDS_WIDE if wide else LANE_WIRE_WORDS) * P + WIRE_HEADER_WORDS
+
+
 def pack_lane_wire(slot, exists, write, occ, round_id, pos, values, wide: bool):
     """Serialize one per-lane batch into a SINGLE i32 buffer, as
     pack_dict_wire does for the dictionary wire and for the same
-    reason: a transfer call costs the host more than its bytes.  The
+    reason: a transfer call costs the host more than its bytes.  Like
+    it, the numpy REFERENCE of the layout: gt_mesh_encode_wire fills a
+    dispatch's buffer and is held to this byte for byte.  The
     buffer is [S, words * P + WIRE_HEADER_WORDS], column k of a shard
     at words [kP, (k+1)P), the header (n_rounds, now_ms: zero here,
     set_wire_header fills it) in the row's last four:
@@ -1115,8 +1133,7 @@ def pack_lane_wire(slot, exists, write, occ, round_id, pos, values, wide: bool):
     import numpy as np
 
     S, P = slot.shape
-    words = LANE_WIRE_WORDS_WIDE if wide else LANE_WIRE_WORDS
-    row = words * P + WIRE_HEADER_WORDS
+    row = lane_wire_words(P, wide)
     w = np.zeros((S, row), dtype=np.int32)
 
     def col(k):
@@ -1193,8 +1210,12 @@ def build_config_dict(cols, now_ms: int):
     """Host half of the dict wire: map each lane's 7 value columns to a
     row index in a <=256-row table.  Returns (rows, enc): `rows` the
     distinct configs counted, `enc` = (cfg_idx u8[B], table 7x
-    i64[DICT_TABLE_ROWS]) or None when the batch has too many (caller
-    falls back to RequestBatch32).  Exact by construction: lanes group
+    i64[DICT_TABLE_ROWS]) or None when the batch has too many (the
+    per-lane wire's case).  The numpy REFERENCE of the count and the
+    rule: a dispatch's are the native encode's (gt_mesh_encode_wire,
+    which interns the seven values themselves, so a collision costs it
+    nothing), held to this in tests/test_native_encode.py; the served
+    path does not call it.  Exact by construction: lanes group
     by a 64-bit polynomial mix of the columns, then every lane is
     VERIFIED equal to its group representative — a hash collision
     degrades to fallback, never to a wrong config."""

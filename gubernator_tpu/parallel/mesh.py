@@ -183,8 +183,9 @@ def _rounds_over_rows(kernel, state, wire, **kw):
 
 def _rounds_lanes_mesh(state, wire):
     """Per-lane rounds behind the single-buffer wire ([S, 11P+4] i32,
-    see buckets.pack_lane_wire): what a batch the dictionary cannot
-    hold dispatches, and one sharded transfer like the dictionary's.
+    the layout buckets.pack_lane_wire documents and the native encode
+    fills): what a batch the dictionary cannot hold dispatches, and one
+    sharded transfer like the dictionary's.
     One i32[S, 4, B] packed result."""
     return _rounds_over_rows(buckets.apply_rounds_lanes, state, wire)
 
@@ -198,7 +199,8 @@ def _rounds_lanes_wide_mesh(state, wire):
 
 def _rounds_packed_mesh(state, wire):
     """Dict-wire rounds behind the single-buffer wire ([S, 3P+1796]
-    i32, see buckets.pack_dict_wire): one sharded transfer per batch."""
+    i32, the layout buckets.pack_dict_wire documents and the native
+    encode fills): one sharded transfer per batch."""
     return _rounds_over_rows(buckets.apply_rounds_packed, state, wire)
 
 
@@ -937,39 +939,37 @@ class MeshBucketStore(ColumnarPipeline):
         picks the program on either wire.  The DICTIONARY wire holds a
         batch of at most DICT_TABLE_ROWS distinct configurations: one
         i32 buffer of 3 words a lane plus the table, ONE transfer.  A
-        batch of more (a limit a key), of more than 255 rounds, or with
-        `force_wire` set ("narrow" / "wide": the PER-LANE wire, named by
-        the answer width it pins; warm-up and tests use it) rides the
-        per-lane wire: one i32 buffer too, of 11 words a lane (16 with
-        the wide answer) and no table, and ONE transfer.  Either wire is
-        packed by numpy on the host and unpacked by slices inside the
-        jitted program, and either carries the round count and the
-        clock in its header (buckets.set_wire_header): the launch passes
+        batch of more (a limit a key), of more than 255 rounds, with an
+        `occ` past 65,535, or with `force_wire` set ("narrow" / "wide":
+        the PER-LANE wire, named by the answer width it pins; warm-up
+        and tests use it) rides the per-lane wire: one i32 buffer too,
+        of 11 words a lane (16 with the wide answer) and no table, and
+        ONE transfer.  Either wire is encoded by ONE native call beside
+        the plan (NativeMeshPlanner.encode_wire: it counts the
+        configurations, applies this rule and fills the buffer, header
+        and all, with the interpreter released; buckets.pack_dict_wire,
+        pack_lane_wire and build_config_dict are the numpy reference
+        the tests hold it to, and nothing here calls them) and unpacked
+        by slices inside the jitted program, and either carries the
+        round count and the clock in its header: the launch passes
         device arrays alone and uploads nothing.  `dispatch.upload`
         times the one transfer call; what the stage takes beyond it is
         the encode.  The wire taken, the configurations counted and the
         transfer calls made ride the _Staged into the mesh tally."""
-        cols, now_ms, padded = prep.cols, prep.now_ms, prep.padded
-        mp, pos, n_rounds, narrow = prep.mp, prep.pos, prep.n_rounds, prep.narrow
-        S = self.n_shards
-        dict_enc, config_rows = None, 0
-        if prep.force_wire is None and n_rounds <= 255:
+        padded, narrow = prep.padded, prep.narrow
+        wire, lane_wire, config_rows = prep.mp.encode_wire(
+            prep.cols, prep.now_ms, prep.n_rounds, narrow,
+            prep.force_wire is not None,
+            buckets.dict_wire_words(padded),
+            buckets.lane_wire_words(padded, wide=not narrow),
+        )
+        if not lane_wire:
             # Values live in the dict wire's 256-row i64 table, so wide
             # batches (monthly/yearly Gregorian) stay on it too — only
             # the output width switches (apply_rounds_packed_wide).
-            config_rows, dict_enc = buckets.build_config_dict(cols, now_ms)
-
-        if dict_enc is not None and int(mp.occ.max()) <= 65535:
-            cfg_full, cfg_table = dict_enc
-            cfg_a = np.zeros((S, padded), dtype=np.uint8)
-            cfg_a.reshape(-1)[pos] = cfg_full
             # Single-buffer wire: ONE sharded host->device transfer per
             # batch instead of 12 (per-call overhead dominates at
             # service batch sizes).
-            wire = buckets.pack_dict_wire(
-                mp.slot, mp.exists, mp.write, cfg_a, mp.occ, mp.rid, cfg_table
-            )
-            buckets.set_wire_header(wire, n_rounds, now_ms)
             with phase("dispatch.upload", prep.bt, wire="dict"):
                 wire_dev = jax.device_put(wire, self._sharding)
             # (A single-round compacted scatter — commit only the write
@@ -988,21 +988,7 @@ class MeshBucketStore(ColumnarPipeline):
                 wide=not narrow, config_rows=config_rows, uploads=1,
             )
         # The per-lane wire: a word a value for a narrow answer, a lo/hi
-        # pair for a wide one, packed into one buffer like the
-        # dictionary's.
-        if narrow:
-            ge = np.where(
-                cols.greg_duration != 0, cols.greg_expire - now_ms, 0
-            )
-        else:
-            ge = cols.greg_expire
-        wire = buckets.pack_lane_wire(
-            mp.slot, mp.exists, mp.write, mp.occ, mp.rid, pos,
-            (cols.algo, cols.behavior, cols.hits, cols.limit, cols.duration,
-             ge, cols.greg_duration),
-            wide=not narrow,
-        )
-        buckets.set_wire_header(wire, n_rounds, now_ms)
+        # pair for a wide one, in one buffer like the dictionary's.
         with phase("dispatch.upload", prep.bt, wire="lanes"):
             wire_dev = jax.device_put(wire, self._sharding)
         fn_lanes = _dispatch_jit(

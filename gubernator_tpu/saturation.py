@@ -423,8 +423,9 @@ class MeshTally:
     wire (a word a value, one buffer and one transfer too) and counts under
     `laneWireDispatches` / `laneWireLanes`, so the dictionary's share is
     the difference from `dispatches` / `lanes`.  `configRows` sums the
-    distinct configurations buckets.build_config_dict counted (0 where
-    the dictionary was not tried: a forced wire, more than 255 rounds),
+    distinct configurations the native encode counted
+    (NativeMeshPlanner.encode_wire; 0 where the dictionary was not
+    tried: a forced wire, more than 255 rounds),
     `uploads` the host-to-device transfer calls the stages made.
 
     And what the calendar adds: `calendarLanes` sums the lanes that

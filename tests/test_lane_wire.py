@@ -316,3 +316,54 @@ def test_a_staged_batch_makes_the_transfer_calls_it_counts(shards, wire, monkeyp
     assert st.config_rows == {"too-many-rows": 300, "dictionary": 16}.get(wire, 0)
     assert calls == ["device_put"]
     assert st.uploads == len(calls)
+
+
+@pytest.mark.skipif(not native.available(), reason="the columnar path needs the native host runtime")
+@pytest.mark.parametrize("wire", ["dictionary", "dictionary-wide", "lanes", "lanes-wide"])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_served_path_has_one_encoder_and_it_is_not_numpys(shards, wire, monkeypatch):
+    """`buckets.build_config_dict`, `pack_dict_wire` and `pack_lane_wire` stay
+    as the reference the native encode is held to (tests/test_native_encode.py)
+    and nothing a dispatch runs calls them: with all three raising, two staged
+    frames of each wire and answer width, keys met twice in a frame and again
+    in the next, answer lane by lane as the sequential oracle does."""
+    from gubernator_tpu.types import Algorithm, RateLimitRequest
+
+    from . import oracle as orc
+
+    def numpy_encoder(name):
+        def raises(*a, **kw):
+            raise AssertionError(f"buckets.{name} ran inside a dispatch")
+        return raises
+
+    for name in ("build_config_dict", "pack_dict_wire", "pack_lane_wire"):
+        monkeypatch.setattr(buckets, name, numpy_encoder(name))
+    store = _store_over(shards, 2048)
+    staged, real_stage = [], store._stage_columns
+    monkeypatch.setattr(store, "_stage_columns", lambda prep: staged.append(real_stage(prep)) or staged[-1])
+
+    lanes, distinct = 512, 400
+    wide, configurations = wire.endswith("wide"), 300 if wire.startswith("lanes") else 16
+    rng = np.random.default_rng([SEED, shards, wide, configurations])
+    cache = orc.OracleCache()
+    for frame in range(2):
+        now = NOW + 7_000 * frame
+        key = rng.integers(0, distinct, lanes)
+        keys = [f"one_encoder_{k}" for k in key.tolist()]
+        algo = (np.zeros(lanes) if wide else key % 2).astype(np.int32)
+        hits = rng.integers(0, 3, lanes)
+        limit = 5 + key % configurations + (2**40 if wide else 0)
+        duration = np.full(lanes, 60_000, np.int64)
+        got = store.apply_columns(keys, algo, np.zeros(lanes, np.int32), hits, limit, duration, now)
+        want = np.array([
+            (int(r.status), r.limit, r.remaining, r.reset_time) for r in (
+                orc.apply(cache, RateLimitRequest(
+                    name="one_encoder", unique_key=str(k), hits=int(h), limit=int(lim), duration=60_000,
+                    algorithm=Algorithm(int(a))), now)
+                for k, h, lim, a in zip(key.tolist(), hits.tolist(), limit.tolist(), algo.tolist()))], np.int64)
+        for col, name in enumerate(("status", "limit", "remaining", "reset_time")):
+            assert (np.asarray(got[name]) == want[:, col]).all(), (frame, name)
+    assert len(staged) == 2
+    for st in staged:
+        assert st.lane_wire == wire.startswith("lanes") and st.wide == wide and st.uploads == 1
+        assert st.config_rows >= (257 if st.lane_wire else 2)
