@@ -646,10 +646,6 @@ def life(name: str, extra_env: dict, pop: Population, seed: int, chips: int,
     and runs every query phase; otherwise one frame and the small requests.
     Fills `device_out` as soon as the daemon says what it holds; returns the
     seconds warmup spent in the compiler, and those of its slowest program."""
-    if rehearse:
-        # The CPU's host scalar slot would answer the singles without a
-        # device program; off, they take the path they take on a TPU.
-        extra_env = {**extra_env, "GUBER_EXPRESS_SCALAR": "0"}
     say(f"== {name}: GUBER_WARMUP_SHAPES={pop.lanes} GUBER_CACHE_SIZE={CACHE_SIZE} {extra_env}")
     daemon = DaemonProc(f"rehearse_{name}" if rehearse else name, pop.lanes, extra_env)
     try:

@@ -175,11 +175,10 @@ class BehaviorConfig:
     # -- millisecond express lane (architecture.md "Express lane") -----
     # Shallow-queue latency bypass: small submissions dispatch
     # IMMEDIATELY (no coalescing window) when the batcher queue and the
-    # dispatch pipeline are shallow, singleton checks on CPU backends
-    # take the host-side scalar path (ops/scalar.py, zero device
-    # programs), NO_BATCHING frames ride the native express queue
-    # instead of the Python fallback, and GUBER_LATENCY_TARGET_MS caps
-    # the effective coalescing window (see latency_target_ms below).
+    # dispatch pipeline are shallow, NO_BATCHING frames ride the native
+    # express queue instead of the Python fallback, and
+    # GUBER_LATENCY_TARGET_MS caps the effective coalescing window (see
+    # latency_target_ms below).
     # False = exact pre-express behavior: every submission waits out
     # the window, NO_BATCHING frames on the native edge fall back to
     # Python, the window is occupancy-sized only (the interop/A-B off
@@ -187,24 +186,11 @@ class BehaviorConfig:
     # WHEN a dispatch launches, never what it computes).
     # Env: GUBER_EXPRESS.
     express: bool = True
-    # Once the bypass's shallow-queue threshold, in queued lanes.  The
-    # admission rule (service._ExpressPolicy) now reads what it
-    # observes — a submission bypasses only when no dispatch is under
-    # way and NOTHING is queued at its batcher — so this value is
-    # parsed, validated and reported (/debug/status) and changes no
-    # outcome.  Env: GUBER_EXPRESS_QUEUE_DEPTH.
-    express_queue_depth: int = 64
     # Bypass small-batch ceiling, in lanes: submissions wider than this
     # always take the window (a wide batch amortizes its own dispatch;
     # the bypass exists for the 1-4 lane interactive shapes the fused
     # size-1/2/4 programs serve).  Env: GUBER_EXPRESS_MAX_LANES.
     express_max_lanes: int = 4
-    # Host-side scalar fast path for singleton checks on CPU backends
-    # (ops/scalar.py): skip device dispatch entirely, same ticket-order
-    # commit discipline.  Only meaningful with express on; exists as a
-    # separate switch so the bypass can be A/B-tested with and without
-    # the scalar slot.  Env: GUBER_EXPRESS_SCALAR.
-    express_scalar: bool = True
 
     # -- latency SLO engine (saturation.py) ----------------------------
     # Ingress latency target in ms.  > 0 turns on the SLO burn-rate
@@ -782,18 +768,6 @@ def setup_daemon_config(
             )
         b.trace_sample = rate
     b.express = _env_bool(merged, "GUBER_EXPRESS", b.express)
-    b.express_queue_depth = _env_int(
-        merged, "GUBER_EXPRESS_QUEUE_DEPTH", b.express_queue_depth
-    )
-    # Loud, not clamped: 0 would make the bypass unreachable while the
-    # knob reads enabled (GUBER_EXPRESS=0 is the off switch), and a
-    # threshold past the ingress-queue cap is a misconfiguration, not
-    # a latency plan.
-    if not 1 <= b.express_queue_depth <= 1_000_000:
-        raise ValueError(
-            f"GUBER_EXPRESS_QUEUE_DEPTH must be in [1, 1000000], "
-            f"got '{b.express_queue_depth}'"
-        )
     b.express_max_lanes = _env_int(
         merged, "GUBER_EXPRESS_MAX_LANES", b.express_max_lanes
     )
@@ -805,9 +779,6 @@ def setup_daemon_config(
             f"GUBER_EXPRESS_MAX_LANES must be in [1, 64], "
             f"got '{b.express_max_lanes}'"
         )
-    b.express_scalar = _env_bool(
-        merged, "GUBER_EXPRESS_SCALAR", b.express_scalar
-    )
     v = merged.get("GUBER_LATENCY_TARGET_MS", "")
     if v:
         try:

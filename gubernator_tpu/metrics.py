@@ -158,10 +158,10 @@ class Metrics:
         self.express_lanes = Counter(
             "gubernator_express_lanes_total",
             "Ingress lanes by dispatch path (bypass = batcher "
-            "shallow-queue bypass, scalar = host-side small-batch "
-            "slot, native = NO_BATCHING frames on the native express "
-            "queue, windowed = lanes that rode a coalesced batch — a "
-            "window flush or the native ring's bulk path).",
+            "shallow-queue bypass, native = NO_BATCHING frames on the "
+            "native express queue, windowed = lanes that rode a "
+            "coalesced batch — a window flush or the native ring's "
+            "bulk path).",
             ["path"],
             registry=self.registry,
         )

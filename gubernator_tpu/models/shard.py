@@ -541,7 +541,7 @@ class _Staged:
     while same-`fuse_key` neighbors waiting at the launch gate can ride
     one fused program instead (ColumnarPipeline._launch_in_order)."""
 
-    solo: "Optional[Callable]"  # state -> (state, packed); None = scalar
+    solo: Callable            # state -> (state, packed)
     fuse_key: object = None   # None = not fuse-eligible (fallback wire)
     wire_dev: object = None   # uploaded packed wire (dict-wire path)
     wide: bool = False        # the ANSWER: absolute lo/hi planes, not i32 deltas
@@ -554,12 +554,6 @@ class _Staged:
     lane_wire: bool = False
     config_rows: int = 0
     uploads: int = 0
-    # Express scalar slot (ops/scalar.py): a host-side closure that
-    # evaluates the single lane and writes its bucket row in place,
-    # returning the packed output array the ordinary commit closure
-    # decodes.  Runs at this ticket's launch turn under the store lock
-    # — no device program, no fusion, ticket-order commit unchanged.
-    scalar: "Optional[Callable]" = None
 
 
 class ColumnsHandle:
@@ -762,25 +756,6 @@ class ColumnarPipeline:
         # pinned by COUNTING this (tests/test_observability.py), the
         # replica_commit_dispatches playbook.
         self.device_dispatches = 0
-        # Express scalar applies (ops/scalar.py): batches answered by
-        # the host-side singleton path — counted separately so the
-        # zero-extra-device-programs pins keep holding (a scalar apply
-        # is NOT a device dispatch) and /debug/status can report the
-        # express hit rate.
-        self.scalar_applies = 0
-        # Master switch for the scalar singleton path, default OFF at
-        # the store level: the SERVICE enables it from GUBER_EXPRESS
-        # (config.py), so bare-store users and every pre-express test
-        # see exactly the old dispatch behavior unless they opt in.
-        self.scalar_fast_path = False
-        # Widest batch the scalar slot serves (the service syncs this
-        # with GUBER_EXPRESS_MAX_LANES).  Lanes apply SEQUENTIALLY in
-        # submission order — the semantics the kernel's round/group
-        # machinery exists to reproduce — so the slot stays
-        # oracle-equivalent at any width; the cap keeps the host loop
-        # to the small interactive shapes where it beats a program.
-        self.scalar_max_lanes = 4
-        self._scalar_ok: "Optional[bool]" = None  # lazy capability probe
 
     # -- observability (metrics.observe_dispatch scrapes these) --------
     def _observe_stage(self, stage: str, dt: float) -> None:
@@ -864,22 +839,13 @@ class ColumnarPipeline:
         # dispatch — the earlier-layer twin of the applied-hits count at
         # commit decode (applied <= dispatched is the device invariant).
         audit.note("dispatched_hits", int(cols.hits.sum()))
-        # Express scalar slot: a singleton on a capable CPU backend
-        # skips device dispatch — planned, ticketed and committed like
-        # any batch (the wide commit decode), but its "launch" is the
-        # host-side evaluation in ops/scalar.py.  Decided BEFORE the
-        # plan so the prepare can pin the wide decode path.
-        use_scalar = force_wire is None and self._scalar_eligible(cols)
         # dispatch.prepare keeps its extent (its clock starts before the
         # plan lock); dispatch.plan_wait inside it is the lock alone.
         with phase("dispatch.prepare", bt) as ph:
             with phase("dispatch.plan_wait", bt):
                 self._plan_lock.acquire()
             try:
-                prep = self._prepare_columns(
-                    keys, cols, now_ms, "wide" if use_scalar else force_wire,
-                    bt,
-                )
+                prep = self._prepare_columns(keys, cols, now_ms, force_wire, bt)
                 handle = ColumnsHandle(self, prep.commit, cols.limit, cols.hits)
                 handle._trace = bt
                 handle.ticket = self._next_ticket
@@ -902,10 +868,7 @@ class ColumnarPipeline:
         try:
             with phase("dispatch.stage", bt, ticket=handle.ticket,
                        shards=shards, fullest=fullest, padded=padded) as ph:
-                staged = (
-                    self._stage_scalar(prep) if use_scalar
-                    else self._stage_columns(prep)
-                )
+                staged = self._stage_columns(prep)
             self._observe_stage("stage", ph.dt_s)
         except BaseException as e:
             self._abort_launch_turn(handle, e)
@@ -1039,16 +1002,6 @@ class ColumnarPipeline:
         device topology."""
         raise NotImplementedError
 
-    # -- express scalar hooks (ops/scalar.py; stores override) ---------
-    def _scalar_eligible(self, cols) -> bool:
-        """Whether this batch may take the host-side scalar slot
-        instead of a device program.  Default: never (stores with a
-        scalar implementation override)."""
-        return False
-
-    def _stage_scalar(self, prep) -> "_Staged":
-        raise NotImplementedError
-
     def _program_label(self, group) -> str:
         """XLA-telemetry program identity for one launch group: solo vs
         fused-K, then the wire and the answer's width — the axes along
@@ -1073,29 +1026,7 @@ class ColumnarPipeline:
         group rides ONE fused program; each handle's fetch reads its
         slice of the shared stacked result, transferred once.  Either
         answer is a 32-bit array ([S, 4, P] narrow, [S, 8, P] wide;
-        [k, ...] stacked): no 64-bit array leaves the device here.
-
-        A scalar-staged batch (the express singleton slot) never fuses
-        (fuse_key None) and launches as a host-side evaluation instead:
-        no device program, no XLA — the bucket row mutates in place
-        under this same lock, at this same ticket turn, so interleaved
-        scalar and device batches commit in plan order exactly like two
-        device batches would."""
-        if len(group) == 1 and group[0][0].scalar is not None:
-            staged, h = group[0]
-            # Dispatch is ASYNC on every backend (CPU included): an
-            # older ticket's program may still be executing on the XLA
-            # thread pool even though its launch returned and released
-            # the lock.  The scalar slot mutates the state buffers
-            # directly, so it must wait for the arrays to be DEFINED —
-            # a no-op when the pipeline already quiesced (the express
-            # shallow-queue case), the correctness wait otherwise.
-            jax.block_until_ready(self.state)
-            packed = staged.scalar()
-            self.scalar_applies += 1
-            saturation.note_express("scalar", len(h._limit))
-            h._launch_ok(lambda: packed)
-            return
+        [k, ...] stacked): no 64-bit array leaves the device here."""
         self._pre_launch()
         # One program per group (fused or solo) — counted, not timed:
         # the zero-extra-dispatch telemetry contract asserts on this.

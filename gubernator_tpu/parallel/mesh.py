@@ -56,7 +56,6 @@ from ..models.shard import (
     prepare_requests,
     split_routing_bits,
 )
-from ..ops import scalar as scalar_ops
 from ..models.slot_table import SlotTable
 from ..ops import buckets, global_ops
 from ..types import (
@@ -1015,93 +1014,6 @@ class MeshBucketStore(ColumnarPipeline):
         return _mesh_fused_packed_jit(
             self.mesh, k, wide, donate_wires=self._wire_donate
         )
-
-    # -- express scalar slot (ops/scalar.py) ---------------------------
-    def _scalar_eligible(self, cols) -> bool:
-        """Small batches on a CPU backend take the host scalar path
-        when the service enabled it (scalar_fast_path) and the one-time
-        writable-buffer capability probe passed.  Each lane lives in
-        exactly one shard, so the host evaluates them sequentially, in
-        submission order, against the shards' rows through writable
-        shard views — no mesh-wide program; that order is exactly what
-        the kernel's round/duplicate-group machinery reproduces, so
-        width is a cost cap, not a correctness bound.  Two-tier stores
-        are excluded (their plans queue tier moves that only the device
-        launch drains)."""
-        if not self.scalar_fast_path:
-            return False
-        if not 1 <= len(cols.hits) <= self.scalar_max_lanes:
-            return False
-        if not (self._native and self.store is None) or self.back is not None:
-            return False
-        if self._scalar_ok is None:
-            with self._lock:
-                # In-flight async programs must finish before the probe
-                # writes a spare lane of the live buffer.
-                jax.block_until_ready(self.state)
-                self._scalar_ok = scalar_ops.device_is_cpu(
-                    self.mesh.devices.flat[0]
-                ) and scalar_ops.probe(self.state.hot)
-        return self._scalar_ok
-
-    def _stage_scalar(self, prep: "_MeshPrep") -> "_Staged":
-        """Express stage: locate each lane's (shard, row) from the mesh
-        plan and return the host-evaluation closure; its packed wide
-        output, split into the i32[S, 8, P] planes a wide program
-        answers in, feeds the unchanged mp.finish_wide commit (decode +
-        slot-table commit + original-order scatter).  The
-        closure runs at the launch turn under `_lock`
-        (ColumnarPipeline._launch_group)."""
-        cols, mp, padded = prep.cols, prep.mp, prep.padded
-        n = prep.n
-        pos = prep.pos[:n].copy()
-        now_ms = prep.now_ms
-        S = self.n_shards
-
-        def run():
-            views: dict = {}
-            packed = np.zeros((S, 4, padded), dtype=np.int64)
-            for i in range(n):
-                p = int(pos[i])
-                s, j = p // padded, p % padded
-                if s not in views:
-                    hot = scalar_ops.shard_view(self.state.hot, s)
-                    cold = scalar_ops.shard_view(self.state.cold, s)
-                    if hot is None or cold is None:
-                        raise RuntimeError(
-                            "scalar fast path: state view unavailable"
-                        )
-                    views[s] = (hot, cold)
-                hot, cold = views[s]
-                slot = int(mp.slot[s, j])
-                # Exists per lane: the planner's claim, EXCEPT that a
-                # later occurrence of an analytic duplicate group
-                # (occ > 0) shares the FIRST occurrence's pre-group
-                # claim — sequentially, the prior occurrence's write
-                # made the row live.  Round-1+ same-key lanes already
-                # carry exists=True from the planner, and a mid-batch
-                # slot TAKEOVER (different key, occ == 0,
-                # exists=False) must keep creating.
-                ex = bool(mp.exists[s, j]) or int(mp.occ[s, j]) > 0
-                st, rem, reset, n_exp, removed = scalar_ops.apply_one(
-                    hot[slot], cold[slot],
-                    exists=ex,
-                    algorithm=int(cols.algo[i]),
-                    behavior=int(cols.behavior[i]),
-                    hits=int(cols.hits[i]),
-                    limit=int(cols.limit[i]),
-                    duration=int(cols.duration[i]),
-                    greg_expire=int(cols.greg_expire[i]),
-                    greg_duration=int(cols.greg_duration[i]),
-                    now_ms=now_ms,
-                )
-                packed[s, 0, j] = st | (int(removed) << 1)
-                packed[s, 1, j] = rem
-                packed[s, 2, j] = reset
-                packed[s, 3, j] = n_exp
-            return buckets.split_wide_answer(packed)
-
-        return _Staged(solo=None, scalar=run)
 
     # ------------------------------------------------------------------
     def _apply_fused(self, by_shard, now_ms: int, responses) -> None:

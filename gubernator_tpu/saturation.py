@@ -550,8 +550,6 @@ class ExpressStats:
 
       * ``bypass``   — batcher shallow-queue bypass (direct dispatch,
                        no coalescing window)
-      * ``scalar``   — the host-side singleton slot (ops/scalar.py;
-                       also counted as whichever submit path fed it)
       * ``native``   — NO_BATCHING frames served by the native ingress
                        express queue (gt_ingress_*)
       * ``windowed`` — lanes that rode a coalesced batch: a Python
@@ -571,7 +569,7 @@ class ExpressStats:
     counters; `snapshot()` serves cumulative counts + the hit rate at
     /debug/latency and /debug/status."""
 
-    PATHS = ("bypass", "scalar", "native", "windowed")
+    PATHS = ("bypass", "native", "windowed")
     DECLINED = ("wide", "launching", "queued")
 
     def __init__(self):

@@ -1039,9 +1039,9 @@ class ColumnarBatcher:
         """Express bypass: dispatch NOW (no coalescing window) on the
         caller's thread — the pipelined apply the flush would have run
         for a one-submission window, launched on the warm solo/fused
-        small-batch programs (or the host scalar slot for a capable
-        singleton).  The future resolves immediately with the handle
-        slice; the caller's readback overlaps like any other waiter's.
+        small-batch programs.  The future resolves immediately with the
+        handle slice; the caller's readback overlaps like any other
+        waiter's.
         Only unsampled submissions arrive here (submit gates on
         trace_links), so no span bookkeeping is owed.  The policy
         admitted the lanes at the gate; they leave it once launched."""
@@ -1340,19 +1340,6 @@ class V1Service:
         self.columnar_batcher = ColumnarBatcher(
             self.store, conf.behaviors, self.clock, metrics=self.metrics
         )
-        # Express lane (architecture.md "Express lane"): the host-side
-        # scalar singleton slot is a SERVICE policy — bare stores keep
-        # it off so their dispatch counting is unchanged; the store
-        # probes its own capability (CPU backend, writable buffers)
-        # lazily on the first eligible singleton.
-        if (
-            getattr(conf.behaviors, "express", False)
-            and getattr(conf.behaviors, "express_scalar", False)
-        ):
-            self.store.scalar_fast_path = True
-            self.store.scalar_max_lanes = int(
-                getattr(conf.behaviors, "express_max_lanes", 4)
-            )
         # Saturation & SLO plane (saturation.py): the latency-SLO burn
         # engine (GUBER_LATENCY_TARGET_MS; disabled at 0) judges every
         # ingress RPC via metrics.observe_latency, and the hot-key
@@ -3033,19 +3020,14 @@ class V1Service:
                 "deviceDispatches": store.device_dispatches,
             },
             "slo": self.slo.snapshot(),
-            # Express lane: knobs + hit rate + the host scalar slot's
-            # apply count (zero device programs by construction).
+            # Express lane: knobs + hit rate by path.
             "express": {
                 "enabled": bool(
                     getattr(self.conf.behaviors, "express", False)
                 ),
-                "queueDepth": int(
-                    getattr(self.conf.behaviors, "express_queue_depth", 0)
-                ),
                 "maxLanes": int(
                     getattr(self.conf.behaviors, "express_max_lanes", 0)
                 ),
-                "scalarApplies": store.scalar_applies,
                 **saturation.express_snapshot(),
             },
             # Which wire the columnar dispatches took (the `mesh` block

@@ -1,5 +1,5 @@
 """Millisecond express lane (PR 14): shallow-queue bypass equivalence,
-the host scalar slot's oracle equivalence against the device kernel,
+small batches' sequential semantics on the device path,
 audit-ledger balance with express and batched dispatches interleaving,
 the chaos DELAY-on-batched-path isolation, the GUBER_EXPRESS knobs, and
 NO_BATCHING on the native hot path."""
@@ -207,14 +207,19 @@ def _submit_reqs(svc: V1Service, reqs):
     )
 
 
-def _triples(fut):
-    handle, lo, hi = fut.result(timeout=60)
-    out = handle.result()
+def _rows(out, lo: int = 0, hi=None):
+    """(status, remaining, reset_time) of a columnar answer's lanes."""
+    hi = len(out["status"]) if hi is None else hi
     return [
         (int(out["status"][i]), int(out["remaining"][i]),
          int(out["reset_time"][i]))
         for i in range(lo, hi)
     ]
+
+
+def _triples(fut):
+    handle, lo, hi = fut.result(timeout=60)
+    return _rows(handle.result(), lo, hi)
 
 
 def _wait_for(cond, what: str, timeout_s: float = 30.0) -> None:
@@ -253,16 +258,20 @@ def test_bypass_vs_windowed_byte_identical(store_kind, seed):
         return _service(BehaviorConfig(express=express), store=store,
                         clock=_FixedClock())
 
+    def bypassed():
+        return saturation.express_snapshot()["lanes"]["bypass"]
+
+    saturation.reset()
     on, off = mk(True), mk(False)
     try:
         got_on = _drive_stream(on, seed)
+        # The on-service actually exercised the lane while the
+        # off-service stayed fully classic.
+        lanes_on = bypassed()
+        assert lanes_on > 0
         got_off = _drive_stream(off, seed)
+        assert bypassed() == lanes_on
         assert got_on == got_off
-        # The on-service actually exercised the lane (bypass + the
-        # host scalar slot) while the off-service stayed fully classic.
-        assert on.store.scalar_applies > 0
-        assert off.store.scalar_applies == 0
-        assert off.store.scalar_fast_path is False
         burst_on = [_triples(f) for f in _held_burst(on)]
         burst_off = [_triples(f) for f in _held_burst(off)]
         assert burst_on == burst_off
@@ -270,6 +279,7 @@ def test_bypass_vs_windowed_byte_identical(store_kind, seed):
     finally:
         on.close()
         off.close()
+        saturation.reset()
 
 
 # ---------------------------------------------------------------------
@@ -294,10 +304,8 @@ def test_admission_rule(make_store, case):
     clock = _FixedClock()
     now = clock.now_ms()
     store = make_store(512)
-    # The scalar slot off, as on the chip: every dispatch is a program.
     svc = _service(
-        BehaviorConfig(batch_wait_s=0.25, express_scalar=False),
-        store=store, clock=clock,
+        BehaviorConfig(batch_wait_s=0.25), store=store, clock=clock,
     )
     cache = oracle.OracleCache()
     asked = []  # every request, in the order the store must apply them
@@ -431,10 +439,7 @@ def test_steady_concurrency_coalesces_and_accounts_exactly():
         return stage(prep)
 
     store._stage_columns = stage_at_the_chips_cost
-    svc = _service(
-        BehaviorConfig(express_scalar=False), store=store,
-        clock=_FixedClock(),
-    )
+    svc = _service(BehaviorConfig(), store=store, clock=_FixedClock())
     granted = [[0] * keys for _ in range(threads)]
     errors = []
     deadline = time.monotonic() + 120.0  # this test's own time limit
@@ -509,15 +514,15 @@ def test_steady_concurrency_coalesces_and_accounts_exactly():
 
 
 # ---------------------------------------------------------------------
-# Scalar fast path vs the device kernel (the oracle pin)
+# Small batches on the device path: sequential semantics, the oracle's
 # ---------------------------------------------------------------------
 
-def _drive_store(store, seed: int, steps: int = 150):
-    """Randomized small batches against the bulk columnar API: expiry
-    edges (clock jumps past short durations), duplicate-heavy batches,
-    token + leaky, RESET_REMAINING."""
+def _small_batches(seed: int, steps: int = 150):
+    """Randomized batches of 1-4 lanes: expiry edges (clock jumps past
+    short durations), duplicate-heavy batches, token + leaky,
+    RESET_REMAINING.  Yields (keys, algo, behavior, hits, limit,
+    duration, now)."""
     rng = random.Random(seed)
-    out = []
     now = 1_000_000
     for step in range(steps):
         n = rng.choice([1, 1, 2, 3, 4])
@@ -531,79 +536,120 @@ def _drive_store(store, seed: int, steps: int = 150):
         limit = np.full(n, rng.choice([1, 3, 10, 30]), np.int64)
         dur = np.full(n, rng.choice([7, 50, 100, 1000]), np.int64)
         now += rng.choice([0, 0, 1, 3, 60, 120, 1500])  # expiry edges
-        r = store.apply_columns(ks, algo, beh, hits, limit, dur, now)
-        out.append(tuple(
-            (int(r["status"][i]), int(r["remaining"][i]),
-             int(r["reset_time"][i]))
-            for i in range(n)
-        ))
+        yield ks, algo, beh, hits, limit, dur, now
+
+
+def _drive_store(store, seed: int, steps: int = 150, width: int = 4):
+    """The stream's answers, a tuple a batch, from `store` fed each
+    batch in calls of at most `width` lanes: 4 is the batch whole, 1 is
+    one lane a call in the batch's order."""
+    out = []
+    for ks, algo, beh, hits, limit, dur, now in _small_batches(seed, steps):
+        got = []
+        for lo in range(0, len(ks), width):
+            cut = slice(lo, lo + width)
+            got += _rows(store.apply_columns(
+                ks[cut], algo[cut], beh[cut], hits[cut], limit[cut], dur[cut],
+                now,
+            ))
+        out.append(tuple(got))
     return out
 
 
-@pytest.mark.parametrize("seed", [31, 32])
-def test_scalar_oracle_one_device(seed):
-    a = one_device_store(64)
-    b = one_device_store(64)
-    b.scalar_fast_path = True
-    ra, rb = _drive_store(a, seed), _drive_store(b, seed)
-    if not b.scalar_applies:
-        pytest.skip("scalar fast path unavailable on this backend")
-    assert b.device_dispatches == 0  # zero programs: the whole point
-    assert ra == rb
+def _drive_oracle(seed: int, steps: int = 150):
+    """The same stream through tests/oracle.py, a lane at a time."""
+    cache = oracle.OracleCache()
+    return [
+        tuple(_oracle_triples(cache, [
+            RateLimitRequest(
+                name="", unique_key=ks[i], algorithm=int(algo[i]),
+                behavior=int(beh[i]), hits=int(hits[i]), limit=int(limit[i]),
+                duration=int(dur[i]),
+            )
+            for i in range(len(ks))
+        ], now))
+        for ks, algo, beh, hits, limit, dur, now in _small_batches(seed, steps)
+    ]
+
+
+def _batched_and_lane_at_a_time(mk, seed: int, steps: int = 150):
+    """A store fed the stream's batches and a second one fed the same
+    lanes one a call; every small batch was a device program."""
+    a, b = mk(), mk()
+    ra = _drive_store(a, seed, steps)
+    rb = _drive_store(b, seed, steps, width=1)
+    assert a.device_dispatches == steps
+    assert b.device_dispatches == sum(len(t) for t in rb)
+    return ra, rb
 
 
 @pytest.mark.parametrize("seed", [31, 32])
-def test_scalar_oracle_mesh(seed):
-    a = MeshBucketStore(capacity_per_shard=32)
-    b = MeshBucketStore(capacity_per_shard=32)
-    b.scalar_fast_path = True
-    ra, rb = _drive_store(a, seed), _drive_store(b, seed)
-    if not b.scalar_applies:
-        pytest.skip("scalar fast path unavailable on this backend")
-    assert b.device_dispatches == 0
+def test_small_batches_equal_one_lane_at_a_time_one_device(seed):
+    """A batch of 1-4 lanes answers as its lanes would one after the
+    other (what the kernel's rounds reproduce), and as the reference:
+    six keys in 64 slots, none evicted."""
+    ra, rb = _batched_and_lane_at_a_time(lambda: one_device_store(64), seed)
     assert ra == rb
+    assert ra == _drive_oracle(seed)
 
 
-def test_scalar_oracle_eviction_pressure():
+@pytest.mark.parametrize("seed", [31, 32])
+def test_small_batches_equal_one_lane_at_a_time_mesh(seed):
+    ra, rb = _batched_and_lane_at_a_time(
+        lambda: MeshBucketStore(capacity_per_shard=32), seed
+    )
+    assert ra == rb
+    assert ra == _drive_oracle(seed)
+
+
+def test_small_batches_under_eviction_pressure_equal_one_lane_at_a_time():
     """A tiny table forces mid-batch slot takeovers (a different key's
-    create evicting into a just-written slot) — the case the
-    sequential-exists rule must not confuse with a duplicate group."""
-    a, b = one_device_store(4), one_device_store(4)
-    b.scalar_fast_path = True
-    ra, rb = _drive_store(a, 41, steps=120), _drive_store(b, 41, steps=120)
-    if not b.scalar_applies:
-        pytest.skip("scalar fast path unavailable on this backend")
+    create evicting into a just-written slot), which must not be
+    confused with a duplicate group."""
+    ra, rb = _batched_and_lane_at_a_time(
+        lambda: one_device_store(4), 41, steps=120
+    )
     assert ra == rb
 
 
-def test_scalar_gregorian_lane():
-    """DURATION_IS_GREGORIAN lanes carry host-precomputed expiry; the
-    scalar slot must select them exactly like the kernel."""
+def test_a_calendar_lane_in_a_one_lane_batch():
+    """DURATION_IS_GREGORIAN lanes carry host-precomputed expiry: a
+    one-lane batch selects it on the device path."""
     now = 1_700_000_000_000
     ge = np.array([now + 3_600_000], np.int64)
     gd = np.array([3_600_000], np.int64)
+    store = one_device_store(16)
+    out = []
+    for i in range(4):
+        out += _rows(store.apply_columns(
+            ["gk"], np.zeros(1, np.int32),
+            np.full(1, int(Behavior.DURATION_IS_GREGORIAN), np.int32),
+            np.ones(1, np.int64), np.full(1, 10, np.int64),
+            np.full(1, 4, np.int64),  # calendar enum, not ms
+            now + i, greg_expire=ge, greg_duration=gd,
+        ))
+    assert store.device_dispatches == 4
+    assert out == [(0, 10 - hits, now + 3_600_000) for hits in (1, 2, 3, 4)]
 
-    def drive(store):
-        out = []
-        for i in range(4):
-            r = store.apply_columns(
-                ["gk"], np.zeros(1, np.int32),
-                np.full(1, int(Behavior.DURATION_IS_GREGORIAN), np.int32),
-                np.ones(1, np.int64), np.full(1, 10, np.int64),
-                np.full(1, 4, np.int64),  # calendar enum, not ms
-                now + i, greg_expire=ge, greg_duration=gd,
-            )
-            out.append((int(r["status"][0]), int(r["remaining"][0]),
-                        int(r["reset_time"][0])))
-        return out
 
-    a, b = one_device_store(16), one_device_store(16)
-    b.scalar_fast_path = True
-    ra, rb = drive(a), drive(b)
-    if not b.scalar_applies:
-        pytest.skip("scalar fast path unavailable on this backend")
-    assert ra == rb
-    assert rb[0] == (0, 9, now + 3_600_000)
+def test_a_one_lane_request_is_one_device_dispatch(make_store):
+    """Default behaviours: the bypass answers a one-lane request with
+    one device program, here as on a TPU."""
+    saturation.reset()
+    svc = _service(BehaviorConfig(), store=make_store(64))
+    try:
+        d0 = svc.store.device_dispatches
+        resp = svc.get_rate_limits(GetRateLimitsRequest(requests=[
+            RateLimitRequest(name="one", unique_key="k", hits=1, limit=10,
+                             duration=60_000)
+        ])).responses[0]
+        assert (resp.error, resp.status, resp.remaining) == ("", 0, 9)
+        assert svc.store.device_dispatches == d0 + 1
+        snap = saturation.express_snapshot()
+        assert snap["dispatches"]["bypass"] == snap["lanes"]["bypass"] == 1
+    finally:
+        svc.close()
+        saturation.reset()
 
 
 # ---------------------------------------------------------------------
@@ -611,6 +657,7 @@ def test_scalar_gregorian_lane():
 # ---------------------------------------------------------------------
 
 def test_audit_balanced_with_express_interleaving():
+    saturation.reset()
     svc = _service(BehaviorConfig())
     try:
         rng = random.Random(7)
@@ -626,11 +673,14 @@ def test_audit_balanced_with_express_interleaving():
                 duration=np.full(n, 60_000, np.int64),
             )
             svc.get_rate_limits_columns(cols)
-        assert svc.store.scalar_applies > 0  # the lane really ran
+        # The lane really ran, and on the device.
+        assert saturation.express_snapshot()["lanes"]["bypass"] > 0
+        assert svc.store.device_dispatches > 0
         violations = svc.auditor.check_now()
         assert violations == [], violations
     finally:
         svc.close()
+        saturation.reset()
 
 
 # ---------------------------------------------------------------------
@@ -700,26 +750,18 @@ def test_chaos_delay_on_batched_path_does_not_stall_express():
 def test_express_knobs_env_plumbing():
     conf = setup_daemon_config(env={
         "GUBER_EXPRESS": "0",
-        "GUBER_EXPRESS_QUEUE_DEPTH": "128",
         "GUBER_EXPRESS_MAX_LANES": "8",
-        "GUBER_EXPRESS_SCALAR": "0",
     })
     b = conf.behaviors
     assert b.express is False
-    assert b.express_queue_depth == 128
     assert b.express_max_lanes == 8
-    assert b.express_scalar is False
     # Defaults: the lane ships ON.
     d = setup_daemon_config(env={})
     assert d.behaviors.express is True
-    assert d.behaviors.express_queue_depth == 64
     assert d.behaviors.express_max_lanes == 4
-    assert d.behaviors.express_scalar is True
 
 
 @pytest.mark.parametrize("env", [
-    {"GUBER_EXPRESS_QUEUE_DEPTH": "0"},
-    {"GUBER_EXPRESS_QUEUE_DEPTH": "2000000"},
     {"GUBER_EXPRESS_MAX_LANES": "0"},
     {"GUBER_EXPRESS_MAX_LANES": "65"},
 ])
@@ -729,13 +771,12 @@ def test_express_knobs_loud_validation(env):
 
 
 def test_express_off_is_pre_express_behavior():
-    """GUBER_EXPRESS=0: no bypass, no scalar slot, windows uncapped —
+    """GUBER_EXPRESS=0: no bypass, windows uncapped —
     every submission waits out the coalescing window exactly as before
     the lane existed."""
     saturation.reset()
     svc = _service(BehaviorConfig(express=False, latency_target_ms=5.0))
     try:
-        assert svc.store.scalar_fast_path is False
         assert svc.columnar_batcher._express.enabled is False
         assert svc.columnar_batcher._window.cap_s is None
         for i in range(4):
@@ -745,9 +786,8 @@ def test_express_off_is_pre_express_behavior():
             ]))
         snap = saturation.express_snapshot()
         assert snap["lanes"]["bypass"] == 0
-        assert snap["lanes"]["scalar"] == 0
         assert snap["lanes"]["windowed"] > 0
-        assert svc.store.scalar_applies == 0
+        assert sorted(snap["lanes"]) == ["bypass", "native", "windowed"]
     finally:
         svc.close()
         saturation.reset()
@@ -821,6 +861,10 @@ def test_debug_surfaces_report_express():
             time.sleep(0.05)
         assert status["express"]["enabled"] is True
         assert status["express"]["lanes"]["native"] >= 1
+        assert sorted(status["express"]) == [
+            "declined", "dispatches", "enabled", "hitRate", "lanes",
+            "maxLanes",
+        ]
         with urllib.request.urlopen(
             f"http://127.0.0.1:{port}/debug/latency", timeout=5
         ) as f:
@@ -835,8 +879,7 @@ def test_debug_surfaces_report_express():
 def test_native_take_is_express_pure():
     """An express frame queued behind bulk backlog jumps the queue AND
     its take never keeps filling from the bulk queue — otherwise the
-    express response would wait out a full coalesced dispatch and
-    outgrow the scalar slot."""
+    express response would wait out a full coalesced dispatch."""
     from tests.test_native_loop import (
         _connect, _edge_with_batcher, _frame, _http_post,
     )
