@@ -980,8 +980,10 @@ class NativeIngressPump:
         )
 
     #: Lane ceiling of one coalesced take = the device dispatch
-    #: ceiling (ColumnarBatcher.MAX_LANES — an oversized dispatch
-    #: would pad into a brand-new XLA bucket and compile mid-traffic).
+    #: ceiling (ColumnarBatcher.MAX_LANES).  A take is also kept inside
+    #: what warm-up compiled (`take_bound`): queued frames coalesced
+    #: past the widest warmed pad bucket would pad into a brand-new XLA
+    #: bucket and compile it inside a client's request.
     TAKE_LANES = 64_000
     #: Overlapping dispatches in flight (the PR 3 pipeline overlaps
     #: host work behind device compute underneath this bound; 6 keeps
@@ -1004,8 +1006,10 @@ class NativeIngressPump:
 
         self.service = service
         self.batcher = _nat.IngressBatcher()
-        self.take_lanes = take_lanes or self.TAKE_LANES
+        self.take_lanes = take_lanes or self.take_bound(service.store)
         self._sem = threading.Semaphore(self.DEPTH)
+        self._in_flight = 0  # takes admitted and not yet committed
+        self._flight_lock = threading.Lock()
         self._stopped = threading.Event()
         self._threads: list = []
         self._done_pool = ThreadPoolExecutor(
@@ -1022,6 +1026,18 @@ class NativeIngressPump:
         self._lanes_seen = 0
         # The set_peers hook: the service pushes ring snapshots here.
         service.native_ingress = self
+
+    @classmethod
+    def take_bound(cls, store) -> int:
+        """The most lanes one take may hold: every shard's share of the
+        widest pad bucket warm-up compiled for the shapes it was given
+        (`store.warm_bucket`), `TAKE_LANES` at the most, and
+        `TAKE_LANES` where warm-up was given no shape.  The first frame
+        of a take always fits (gt_ingress_take), so a frame wider than
+        every warmed bucket is still served, alone."""
+        if not store.warm_bucket:
+            return cls.TAKE_LANES
+        return min(cls.TAKE_LANES, store.n_shards * store.warm_bucket)
 
     @property
     def active(self) -> bool:
@@ -1143,13 +1159,23 @@ class NativeIngressPump:
             bt = tracing.new_batch(roll=True)
             with phase("pump.depth_wait", bt):
                 self._sem.acquire()
+            with self._flight_lock:
+                self._in_flight += 1
+                in_flight = self._in_flight
+            saturation.mesh_tally.add_take(tb.n_frames, in_flight)
             try:
                 args = self._submit(tb, bt)
             except BaseException as e:  # noqa: BLE001
-                self._sem.release()
+                self._release_slot()
                 self._fail(tb, e)
                 continue
             self._done_pool.submit(self._complete, *args, time.perf_counter())
+
+    def _release_slot(self) -> None:
+        """A take's slot of the pipeline's depth, given back."""
+        with self._flight_lock:
+            self._in_flight -= 1
+        self._sem.release()
 
     def _surface_stats(self) -> None:
         """Overload-signal parity with the Python gate: native sheds
@@ -1366,7 +1392,7 @@ class NativeIngressPump:
             except BaseException as e:  # noqa: BLE001
                 self._fail(tb, e)
         finally:
-            self._sem.release()
+            self._release_slot()
 
     def _fail(self, tb, exc: BaseException) -> None:
         nf = tb.n_frames

@@ -751,6 +751,10 @@ class ColumnarPipeline:
         self._stats_lock = threading.Lock()
         self._depth_hwm = 0
         self._seen_wire_shapes: set = set()  # (W, narrow) staged so far
+        # The widest per-shard pad bucket warm-up compiled for the shapes
+        # it was GIVEN (MeshBucketStore.warmup; 0: it was given none).
+        # The native ingress pump keeps a take inside it.
+        self.warm_bucket = 0
         # Device programs launched by this store's columnar pipeline —
         # the "telemetry adds zero device dispatches" contract is
         # pinned by COUNTING this (tests/test_observability.py), the
@@ -1031,6 +1035,7 @@ class ColumnarPipeline:
         # One program per group (fused or solo) — counted, not timed:
         # the zero-extra-dispatch telemetry contract asserts on this.
         self.device_dispatches += 1
+        saturation.mesh_tally.add_launch(len(group))
         # lazy: the wide-answer programs warm-up deliberately defers,
         # the per-lane wire's and the fused launches', so their first
         # post-steady compile is by design, not shape churn.  The

@@ -1839,6 +1839,15 @@ class MeshBucketStore(ColumnarPipeline):
             S = self.n_shards
             with self._stats_lock:
                 shapes = sorted(self._seen_wire_shapes)
+            if warm_shapes:
+                # What the deployment said it expects, after pad_size:
+                # the per-lane wire was warmed at the same buckets.  The
+                # native ingress pump takes no more lanes at once than
+                # S of the widest (a wider take would compile its bucket
+                # inside a client's request).
+                self.warm_bucket = max(
+                    buckets.dict_wire_lanes(W) for W, narrow in shapes if narrow
+                )
             wide_fn = _dispatch_jit(
                 self.mesh, _rounds_packed_wide_mesh, donate_wire=self._wire_donate
             )

@@ -441,7 +441,17 @@ class MeshTally:
     _sync_globals_locked): `syncPasses` counts the passes that ran,
     `syncTouched` sums the gslots they took (touched since the pass
     before), `syncRows` the rows their launches carried (the program's
-    width a launch: what a pass pays for)."""
+    width a launch: what a pass pays for).
+
+    And how the takes ran through the pipeline: `launches` counts the
+    programs launched for columnar dispatches (ColumnarPipeline.
+    _launch_group; a fused group of 2 or 4 dispatches is one) and
+    `fusedDispatches` the dispatches that rode such a group; `takes` and
+    `takeFrames` count the native ingress lane's takes and the frames
+    they held, `inFlightSum` sums, at each take's admission, the takes
+    admitted and not yet committed, that one included
+    (gateway.NativeIngressPump), so over `takes` it is the pipeline's
+    mean depth as a take finds it."""
 
     WIRE_KEYS = ("dispatches", "lanes", "laneWireDispatches", "laneWireLanes",
                  "configRows", "uploads", "calendarLanes", "wideDispatches")
@@ -453,7 +463,9 @@ class MeshTally:
             ("dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
              "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
              "calendarLanes", "wideDispatches", "flaggedLanes",
-             "syncPasses", "syncRows", "syncTouched"), 0
+             "syncPasses", "syncRows", "syncTouched",
+             "launches", "fusedDispatches", "takes", "takeFrames",
+             "inFlightSum"), 0
         )
 
     def add(self, shards: int, lanes: int, padded: int, fullest: int,
@@ -483,6 +495,20 @@ class MeshTally:
             s["syncPasses"] += 1
             s["syncRows"] += rows
             s["syncTouched"] += touched
+
+    def add_launch(self, dispatches: int) -> None:
+        with self._lock:
+            s = self._sums
+            s["launches"] += 1
+            if dispatches > 1:
+                s["fusedDispatches"] += dispatches
+
+    def add_take(self, frames: int, in_flight: int) -> None:
+        with self._lock:
+            s = self._sums
+            s["takes"] += 1
+            s["takeFrames"] += frames
+            s["inFlightSum"] += in_flight
 
     def snapshot(self) -> Dict[str, int]:
         with self._lock:

@@ -3014,6 +3014,9 @@ class V1Service:
                 "windowWaitS": round(
                     self.columnar_batcher._window.effective_wait_s(), 6
                 ),
+                # The most lanes the native ingress pump coalesces into
+                # one take (NativeIngressPump.take_bound; 0: no pump).
+                "takeLanes": int(getattr(self.native_ingress, "take_lanes", 0)),
             },
             "dispatch": {
                 "inflight": store.pipeline_depth(),

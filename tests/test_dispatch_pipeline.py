@@ -167,32 +167,79 @@ def test_interleaved_matches_serial_under_fault_delays(seed):
     )
 
 
-def test_launch_fusion_under_backlog(monkeypatch):
-    """Stall ticket 0 in its STAGE step; tickets 1..3 stage behind it
-    and wait at the launch gate, so ticket 0's launch fuses all four
-    into one program — and the results still match the serial replay."""
-    store = one_device_store(4096)
-    batches = _make_batches(21, n_batches=4, lanes=64, n_keys=32,
-                            wide=False)
+def _oracle_results(batches, raced):
+    """The raced batches applied by tests/oracle.py one lane after another
+    in ticket order: [(status, remaining, reset_time)] a batch."""
+    from gubernator_tpu.types import Algorithm, RateLimitRequest
+
+    from . import oracle as orc
+
+    cache = orc.OracleCache()
+    out = []
+    for _ticket, bi, _ in raced:
+        b = batches[bi]
+        rows = np.empty((len(b["keys"]), 3), np.int64)
+        for lane, key in enumerate(b["keys"]):
+            name, _, unique = key.partition(":")
+            r = orc.apply(cache, RateLimitRequest(
+                name=name, unique_key=unique, hits=int(b["hits"][lane]),
+                limit=int(b["limit"][lane]), duration=int(b["duration"][lane]),
+                algorithm=Algorithm(int(b["algorithm"][lane]))), b["now"])
+            rows[lane] = (int(r.status), r.remaining, r.reset_time)
+        out.append(rows)
+    return out
+
+
+# (fused group, lanes a batch): the group sizes the gate forms, at a small
+# width and at the width the frames cells serve (4096 lanes, their one warm
+# bucket: the fused programs every cold start compiles).
+@pytest.mark.parametrize("group,lanes", [(4, 64), (2, 64), (2, 4096), (4, 4096)])
+def test_launch_fusion_under_backlog(monkeypatch, group, lanes):
+    """Stall ticket 0 in its STAGE step; the tickets behind it stage and
+    wait at the launch gate, so ticket 0's launch fuses them all into ONE
+    program, and the results still match the serial replay and, lane by
+    lane, the sequential oracle: each handle's fetch reads its own rows
+    of the shared answer."""
+    from gubernator_tpu import saturation
+
+    store = one_device_store(8192)
+    batches = _make_batches(21, n_batches=group, lanes=lanes,
+                            n_keys=lanes // 2, wide=False)
+    for b in batches:
+        b["keys"] = [k.replace("orc:", "orc:k") for k in b["keys"]]
+        b["hits"][:] = 1  # uniform duplicate groups: one round a dispatch
+        b["limit"][:] = 3  # and buckets that run dry inside the group
     orig = store._stage_columns
     stalled = threading.Event()
 
     def slow_stage(prep):
         if not stalled.is_set():
             stalled.set()
-            time.sleep(0.4)  # let tickets 1..3 reach the gate
+            # let the other tickets reach the gate
+            deadline = time.monotonic() + 20.0
+            while len(store._launch_gate) < group - 1 and time.monotonic() < deadline:
+                time.sleep(0.005)
         return orig(prep)
 
     monkeypatch.setattr(store, "_stage_columns", slow_stage)
     store.take_pipeline_stats()
-    raced = _race(store, batches, n_threads=4, force_wire=None)
+    tally = saturation.mesh_tally.snapshot()
+    raced = _race(store, batches, n_threads=group, force_wire=None)
     stats, _depth, _hwm = store.take_pipeline_stats()
-    # 4 dispatches, fewer launches than dispatches = fusion happened.
-    assert stats["prepare"][0] == 4
-    assert stats["launch"][0] < 4, stats
+    grown = {k: v - tally[k] for k, v in saturation.mesh_tally.snapshot().items()}
+    # `group` dispatches in ONE launch.
+    assert stats["prepare"][0] == group
+    assert stats["launch"][0] == 1, stats
+    assert (grown["dispatches"], grown["launches"], grown["fusedDispatches"]) == (group, 1, group)
     _assert_matches_serial(
-        lambda: one_device_store(4096), batches, raced, None
+        lambda: one_device_store(8192), batches, raced, None
     )
+    over = 0
+    for (ticket, bi, got), want in zip(raced, _oracle_results(batches, raced)):
+        for col, f in enumerate(("status", "remaining", "reset_time")):
+            assert np.array_equal(np.asarray(got[f]), want[:, col]), (f, bi, ticket)
+        over += int(want[:, 0].sum())
+    assert over > 0
 
 
 def test_fused_kernel_matches_solo_sequence():

@@ -439,7 +439,8 @@ def test_the_cells_files_say_what_the_issue_says():
     assert not listed & {"launch.sync_stall_ms", "batcher.queue_p99_ms", "mesh.pad_fill", "mesh.shard_skew"}
     for metric in bench["per_layer"]:
         if metric["name"].startswith("wire.") and metric["name"] != "wire.wide_share":  # PR 39's
-            assert metric["workloads"] == [CELL, TWIN, "greg-10m.frames", "v5e1-1m-mixed.frames"]  # PR 41's
+            assert metric["workloads"] == [CELL, TWIN, "greg-10m.frames", "v5e1-1m-mixed.frames",
+                                           "v5e1-1m-gw4.frames"]  # PRs 39, 41 and 45 appended theirs
             assert metric["moves"] == "req_p50_ms"
             spec = _cell_json("layer_metrics", metric["name"] + ".json")
             assert spec["reader"] in ("mesh_tally", "phase_ms_per")
