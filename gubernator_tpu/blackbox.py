@@ -15,10 +15,10 @@ Three pieces:
 * **Taps** — per-wire byte-budgeted in-memory rings (public / peer /
   global / transfer / region, classified from the frame's kind byte).
   `tap()` records (wall ns, mono ns, direction, peer, kind, raw frame
-  bytes); `tap_taken()` reconstructs the kind-5 frames a native-edge
-  take batch coalesced (the one choke point that no longer holds the
-  original bytes).  Disabled (`GUBER_BLACKBOX=0` or force_disable) the
-  tap is one branch per frame.
+  bytes); `tap_taken()` copies the kind-5 frames a native-edge take
+  batch coalesced out of the take's handle, which holds the bytes the
+  clients sent until it is completed.  Disabled (`GUBER_BLACKBOX=0` or
+  force_disable) the tap is one branch per frame.
 
 * **Bundles** — `on_trigger` rides tracing.Recorder.dump_hooks: every
   _DUMP_KINDS event (plus POST /debug/incident) wakes an off-thread
@@ -55,8 +55,6 @@ import threading
 import time
 import zlib
 from typing import Dict, List, Optional, Tuple
-
-import numpy as np
 
 from .utils.logging import category_logger
 
@@ -237,47 +235,6 @@ class _WireRing:
             return len(self.frames), self.nbytes, self.frames_total
 
 
-def _frames_from_taken(tb) -> List[bytes]:
-    """Reconstruct the original kind-5 ingress frames a native take
-    batch (gateway.NativeIngressPump) coalesced: the C++ edge parsed
-    and freed the original bytes, but the batch keeps every column plus
-    per-frame lane counts, so the frames re-encode byte-identically to
-    wire.encode_ingress_frame's layout (no trace trailer — the fast
-    lane never carries sampled frames).  Must run BEFORE complete():
-    the batch's views die inside it."""
-    from . import wire as wire_mod
-
-    nf = int(tb.n_frames)
-    if nf <= 0:
-        return []
-    lanes = np.asarray(tb.frame_lanes, dtype=np.int64)
-    bounds = np.zeros(nf + 1, dtype=np.int64)
-    np.cumsum(lanes, out=bounds[1:])
-    no = np.asarray(tb._no, dtype=np.int64)
-    uo = np.asarray(tb._uo, dtype=np.int64)
-    frames: List[bytes] = []
-    for fi in range(nf):
-        lo, hi = int(bounds[fi]), int(bounds[fi + 1])
-        n = hi - lo
-        n_off = (no[lo:hi + 1] - no[lo]).astype(np.uint32)
-        n_blob = bytes(tb._nb[no[lo]:no[hi]])
-        u_off = (uo[lo:hi + 1] - uo[lo]).astype(np.uint32)
-        u_blob = bytes(tb._ub[uo[lo]:uo[hi]])
-        frames.append(b"".join((
-            _GUBC_MAGIC,
-            struct.pack("<BBI", wire_mod.FRAME_VERSION,
-                        wire_mod._FRAME_KIND_INGRESS_REQ, n),
-            struct.pack("<I", len(n_blob)), n_off.tobytes(), n_blob,
-            struct.pack("<I", len(u_blob)), u_off.tobytes(), u_blob,
-            np.ascontiguousarray(tb.algorithm[lo:hi], np.int32).tobytes(),
-            np.ascontiguousarray(tb.behavior[lo:hi], np.int32).tobytes(),
-            np.ascontiguousarray(tb.hits[lo:hi], np.int64).tobytes(),
-            np.ascontiguousarray(tb.limit[lo:hi], np.int64).tobytes(),
-            np.ascontiguousarray(tb.duration[lo:hi], np.int64).tobytes(),
-        )))
-    return frames
-
-
 # ---------------------------------------------------------------------
 # The black box
 # ---------------------------------------------------------------------
@@ -343,13 +300,15 @@ class BlackBox:
         )
 
     def tap_taken(self, tb) -> None:
-        """Native-edge tap: reconstruct and record the kind-5 frames a
-        NativeIngressPump take batch coalesced.  Fenced — diagnostics
-        must never fail the pump."""
+        """Native-edge tap: record the kind-5 frames a NativeIngressPump
+        take batch coalesced, the bytes the clients sent (one copy a
+        frame of `IngressFrame::body`, which lives until the batch's
+        complete()/fail(); the pump calls this once the take has
+        launched).  Fenced — diagnostics must never fail the pump."""
         if _FORCE_DISABLED or not (self._on and _ENABLED):
             return
         try:
-            frames = _frames_from_taken(tb)
+            frames = tb.frame_bytes()
         except Exception:  # noqa: BLE001
             logger.exception("blackbox native tap failed")
             return

@@ -47,6 +47,9 @@ from .types import (
     UpdatePeerGlobal,
     _parse_behavior,
 )
+from .utils.logging import category_logger
+
+logger = category_logger("gateway")
 
 
 
@@ -971,6 +974,10 @@ class NativeIngressPump:
     # Python path owns the per-lane error wording.
     OWNER_BEHAVIOR = int(Behavior.GLOBAL) | int(Behavior.MULTI_REGION)
     EXPRESS_MASK = int(Behavior.NO_BATCHING)
+    #: The bits for which `_submit` does work of its own on a take's way
+    #: to its launch (a calendar resolve, the owner's book-keeping); a
+    #: take whose lanes carry none is a plain take (`plain_takes`).
+    TAKE_BEHAVIOR = OWNER_BEHAVIOR | int(Behavior.DURATION_IS_GREGORIAN)
 
     @classmethod
     def fallback_mask(cls, all_self: bool, express: bool) -> int:
@@ -1009,6 +1016,9 @@ class NativeIngressPump:
         self.take_lanes = take_lanes or self.take_bound(service.store)
         self._sem = threading.Semaphore(self.DEPTH)
         self._in_flight = 0  # takes admitted and not yet committed
+        # Takes that held no calendar and no owner lane, so did no numpy
+        # of the pump's own (/debug/status ingress.plainTakes).
+        self.plain_takes = 0
         self._flight_lock = threading.Lock()
         self._stopped = threading.Event()
         self._threads: list = []
@@ -1021,6 +1031,7 @@ class NativeIngressPump:
         self._ring = None
         self._eligible = False
         self._enable_at = 0.0
+        self._stats_lock = threading.Lock()
         self._shed_seen = 0
         self._express_seen = 0
         self._lanes_seen = 0
@@ -1162,11 +1173,19 @@ class NativeIngressPump:
             with self._flight_lock:
                 self._in_flight += 1
                 in_flight = self._in_flight
+                self.plain_takes += not tb.beh_or & self.TAKE_BEHAVIOR
             saturation.mesh_tally.add_take(tb.n_frames, in_flight)
             try:
                 args = self._submit(tb, bt)
             except BaseException as e:  # noqa: BLE001
                 self._release_slot()
+                # The incident frame is what the black box is for: a take
+                # whose dispatch raised is tapped and folded all the same,
+                # before its clients are answered.
+                try:
+                    self._observe(tb, bt)
+                except Exception:  # noqa: BLE001
+                    logger.exception("native pump: observers of a failed take")
                 self._fail(tb, e)
                 continue
             self._done_pool.submit(self._complete, *args, time.perf_counter())
@@ -1182,68 +1201,51 @@ class NativeIngressPump:
         happen entirely in C++, so the pump surfaces them into the
         flight recorder (the automatic-dump trigger shedding exists
         for) and samples the ring depth for /debug/status."""
-        st = self.batcher.stats()
-        saturation.observe_queue_depth(st["pendingLanes"])
+        (_, lanes, _, _, shed, _, _, pending, _, express_lanes
+         ) = self.batcher.counters()
+        saturation.observe_queue_depth(pending)
+        # The last-seen values are shared by every caller (a done-pool
+        # worker a take, an idle pump thread): a delta is noted once.
+        with self._stats_lock:
+            d_express = express_lanes - self._express_seen
+            d_bulk = (lanes - self._lanes_seen) - d_express
+            d_shed = shed - self._shed_seen
+            self._express_seen = max(express_lanes, self._express_seen)
+            self._lanes_seen = max(lanes, self._lanes_seen)
+            self._shed_seen = max(shed, self._shed_seen)
         # Express-lane attribution: NO_BATCHING frames served by
         # the native express queue (counted in C++ at submit), and
         # the ring's BULK lanes into the batched denominator — the
         # hit-rate gauge must reflect the native edge's coalesced
         # traffic, not just the batchers' windows.
-        xl = st.get("expressLanes", 0)
-        tl = st.get("lanes", 0)
-        d_express = xl - self._express_seen
-        d_bulk = (tl - self._lanes_seen) - d_express
         if d_express > 0:
             saturation.note_express("native", d_express)
         if d_bulk > 0:
             saturation.note_express("windowed", d_bulk)
-        self._express_seen = xl
-        self._lanes_seen = tl
-        shed = st["shedLanes"]
-        if shed > self._shed_seen:
+        if d_shed > 0:
             tracing.record_event(
-                "shed", lanes=shed - self._shed_seen,
-                queued=st["pendingLanes"],
+                "shed", lanes=d_shed, queued=pending,
                 cap=getattr(
                     self.service.conf.behaviors,
                     "ingress_queue_lanes", 0,
                 ),
             )
-            self._shed_seen = shed
 
     def _submit(self, tb, bt):
-        """One batch through the funnel's batch-granularity duties
-        (`pump.admit`): the ring's counters, the black-box tap,
-        conservation ledger (the take summed its hits in C++), tenant
-        fold and hot-key sketch (both native passes over the take's own
-        columns, the sketch riding the hashes the native route already
-        computed — zero extra hashing, no interpreter in the lanes),
-        the attribution of what C++ timed, then ONE columnar
-        dispatch."""
+        """A take's way to its launch: only what the answer needs.  The
+        conservation ledger's note (the take summed its hits in C++; its
+        order against `dispatched_hits` stays), the edge's stamps of a
+        take that is being traced, ONE clock reading, the calendar
+        resolve and the MULTI_REGION queueing where the take holds such
+        lanes (`tb.beh_or`, the OR of its behaviour words: a plain take
+        does no numpy here), then ONE columnar dispatch.  Whatever only
+        observes the take runs once it has launched (`_observe`)."""
         svc = self.service
         with phase("pump.admit", bt, frames=tb.n_frames, lanes=tb.n) as ph:
             # The anchor that ties the C++ edge's stamps to the trace's
             # clock is read beside the event's start (_trace_edge).
             anchor_ns = time.monotonic_ns() if ph.traced else 0
-            self._surface_stats()
-            bb = getattr(svc, "blackbox", None)
-            if bb is not None:
-                # Black-box native tap, BEFORE the dispatch: the batch's
-                # zero-copy views die at complete()/fail(), and this is
-                # the only point where the coalesced frames' bytes can
-                # still be reconstructed (express-lane singles answered
-                # entirely in C++ never surface here — documented
-                # capture slack, architecture.md "Incident black box").
-                bb.tap_taken(tb)
             audit_mod.note("ingress_hits", tb.hits_total)
-            tenant_ctx = svc.tenants.fold_admit(tb)
-            svc.hotkeys.update(tb.hashes, tb.hash_keys)
-            # Measured in C++ (parse by the worker, a frame's age from its
-            # arrival to this take), so observed, not timed, here.
-            nf = max(tb.n_frames, 1)
-            saturation.observe_phase("ingress.parse", tb.parse_ns_total / 1e9 / nf)
-            for age_us in tb.frame_age_us:
-                saturation.observe_phase("batch.window", float(age_us) / 1e6)
             # The C++ edge's stamps are observed after the answers have
             # left (`pump.account`); only a take that is being traced
             # reads them here, where its event is open.
@@ -1264,12 +1266,16 @@ class NativeIngressPump:
         # expiry and the kernel's `greg_expire - now` cannot straddle a
         # boundary.
         now_ms = svc.clock.now_ms()
+        beh_or = tb.beh_or
         greg_expire = greg_duration = None
+        # Both phases are entered once a take, whatever it holds (their
+        # counts are the per-dispatch metrics' divisors); a take without
+        # the bit holds a flag test.
         with phase("calendar.resolve", bt) as ph:
-            greg = greg_lanes(tb.behavior)
-            lanes = int(np.count_nonzero(greg))
-            distinct = 0
-            if lanes:
+            lanes = distinct = 0
+            if beh_or & int(Behavior.DURATION_IS_GREGORIAN):
+                greg = greg_lanes(tb.behavior)
+                lanes = int(np.count_nonzero(greg))
                 greg_expire, greg_duration, errors, distinct = (
                     resolve_greg_columns(greg, tb.duration, now_ms)
                 )
@@ -1286,10 +1292,11 @@ class NativeIngressPump:
         # (`dispatch.global_note`); NO_BATCHING chose the take's queue in
         # C++ and has nothing left to ask.
         with phase("behavior.handle", bt) as ph:
-            owed = tb.behavior & self.OWNER_BEHAVIOR
-            if owed.any():
-                mr = np.flatnonzero(owed & int(Behavior.MULTI_REGION)).tolist()
-                if mr:
+            if beh_or & self.OWNER_BEHAVIOR:
+                owed = tb.behavior & self.OWNER_BEHAVIOR
+                mr = ()
+                if beh_or & int(Behavior.MULTI_REGION):
+                    mr = np.flatnonzero(owed & int(Behavior.MULTI_REGION)).tolist()
                     svc.multi_region_mgr.queue_columns(
                         mr, tb.hash_keys, tb.hits, tb.request_at
                     )
@@ -1304,7 +1311,40 @@ class NativeIngressPump:
             # A store that raised before consuming the staged trace must
             # not leak it into this thread's next dispatch.
             tracing.take_batch_trace()
-        return tb, handle, tenant_ctx, t0, bt
+        return tb, handle, t0, bt
+
+    def _observe(self, tb, bt):
+        """What only OBSERVES a take, run once it has launched and before
+        its answer is waited for (the head of `_complete`, on a done-pool
+        worker: the device computes meanwhile), or before `_fail` answers
+        a take whose dispatch raised: the ring's counters, the black-box
+        tap (the frames' own bytes, which live with the batch's views
+        until complete()/fail(); express-lane singles answered entirely
+        in C++ never surface here — documented capture slack,
+        architecture.md "Incident black box"), the tenant fold and the
+        hot-key sketch (both native passes over the take's own columns,
+        the sketch riding the hashes the native route already computed),
+        and the attribution of what C++ timed.  By the time a client has
+        its answer its take has been tapped, folded and sketched.  Booked
+        under `pump.account`: no request waits on it.  Returns what the
+        outcome needs of it: the tenant fold's context and the frames'
+        ages in seconds."""
+        svc = self.service
+        with phase("pump.account", bt):
+            self._surface_stats()
+            bb = getattr(svc, "blackbox", None)
+            if bb is not None:
+                bb.tap_taken(tb)
+            tenant_ctx = svc.tenants.fold_admit(tb)
+            svc.hotkeys.update(tb.hashes, tb.hash_keys)
+            # Measured in C++ (parse by the worker, a frame's age from its
+            # arrival to this take), so observed, not timed, here.
+            saturation.observe_phase(
+                "ingress.parse", tb.parse_ns_total / 1e9 / max(tb.n_frames, 1)
+            )
+            ages_s = (tb.frame_age_us / 1e6).tolist()
+            saturation.observe_phases("batch.window", ages_s)
+        return tenant_ctx, ages_s
 
     @staticmethod
     def _trace_edge(ph, bt, anchor_ns, take_ns, stamps) -> None:
@@ -1337,7 +1377,7 @@ class NativeIngressPump:
         if ph.traced:
             ph.note(**saturation.edge_trace_note(anchor_ns, sends=sends))
 
-    def _complete(self, tb, handle, tenant_ctx, t0, bt, t_handoff) -> None:
+    def _complete(self, tb, handle, t0, bt, t_handoff) -> None:
         # pump.handoff: queued behind the done pool's two workers.  It
         # crosses threads, so it is read from the pump's stamp.
         saturation.observe_phase("pump.handoff", time.perf_counter() - t_handoff)
@@ -1346,13 +1386,14 @@ class NativeIngressPump:
         rpc = "/pb.gubernator.V1/GetRateLimits"
         try:
             try:
-                out = handle.result()
+                # The take has launched: its observers run here, while
+                # the device computes, with the copies of everything
+                # needed past complete() (the batch's views die inside it).
+                tenant_ctx, ages_s = self._observe(tb, bt)
+                edge_stamps = tb.frame_stamps[:, 1:].tolist()
                 nf = tb.n_frames
+                out = handle.result()
                 with phase("pump.outcome", bt):
-                    # Copies of everything needed past complete() — the
-                    # batch's views die inside it.
-                    ages_s = tb.frame_age_us.astype(np.float64) / 1e6
-                    edge_stamps = tb.frame_stamps[:, 1:].tolist()
                     result = ColumnarResult(
                         n=tb.n,
                         status=np.asarray(out["status"], dtype=np.int32),
@@ -1378,7 +1419,7 @@ class NativeIngressPump:
                     m.request_counts.labels(status="0", method=rpc).inc(nf)
                     duration = m.request_duration.labels(method=rpc)
                     for age in ages_s:
-                        dt = float(age) + dt_disp
+                        dt = age + dt_disp
                         duration.observe(dt)
                         m.observe_latency(rpc, dt)
                     # The C++ edge's stamps of each frame: its socket

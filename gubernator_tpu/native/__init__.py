@@ -1306,40 +1306,68 @@ class HttpEdge:
 
 
 class _GtTakenInfo(ctypes.Structure):
+    # Pointers as plain addresses (c_void_p reads as an int): a view is
+    # made from the address when a column is first read.
     _fields_ = [
         ("n", ctypes.c_int64),
         ("n_frames", ctypes.c_int64),
-        ("algo", ctypes.POINTER(ctypes.c_int32)),
-        ("beh", ctypes.POINTER(ctypes.c_int32)),
-        ("hits", ctypes.POINTER(ctypes.c_int64)),
-        ("limit", ctypes.POINTER(ctypes.c_int64)),
-        ("duration", ctypes.POINTER(ctypes.c_int64)),
-        ("hk", ctypes.POINTER(ctypes.c_uint8)),
-        ("hkoff", ctypes.POINTER(ctypes.c_int64)),
+        ("algo", ctypes.c_void_p),
+        ("beh", ctypes.c_void_p),
+        ("hits", ctypes.c_void_p),
+        ("limit", ctypes.c_void_p),
+        ("duration", ctypes.c_void_p),
+        ("hk", ctypes.c_void_p),
+        ("hkoff", ctypes.c_void_p),
         ("hk_bytes", ctypes.c_int64),
-        ("hashes", ctypes.POINTER(ctypes.c_uint64)),
-        ("name_blob", ctypes.POINTER(ctypes.c_uint8)),
-        ("name_off", ctypes.POINTER(ctypes.c_int64)),
+        ("hashes", ctypes.c_void_p),
+        ("name_blob", ctypes.c_void_p),
+        ("name_off", ctypes.c_void_p),
         ("name_bytes", ctypes.c_int64),
-        ("uk_blob", ctypes.POINTER(ctypes.c_uint8)),
-        ("uk_off", ctypes.POINTER(ctypes.c_int64)),
+        ("uk_blob", ctypes.c_void_p),
+        ("uk_off", ctypes.c_void_p),
         ("uk_bytes", ctypes.c_int64),
-        ("frame_lanes", ctypes.POINTER(ctypes.c_int64)),
-        ("frame_age_us", ctypes.POINTER(ctypes.c_int64)),
-        ("frame_stamps", ctypes.POINTER(ctypes.c_int64)),
+        ("frame_lanes", ctypes.c_void_p),
+        ("frame_age_us", ctypes.c_void_p),
+        ("frame_stamps", ctypes.c_void_p),
         ("parse_ns_total", ctypes.c_int64),
         ("hits_total", ctypes.c_int64),
+        ("frame_body", ctypes.c_void_p),
+        ("beh_or", ctypes.c_int64),
     ]
 
 
-def _view(ptr, n, dtype):
-    """Zero-copy numpy view over a C pointer (no ownership)."""
-    if n == 0:
+# C++-owned memory at an address, as a buffer numpy can view: ONE ctypes
+# type of no real length, of which `_view` reads `count` items (a type a
+# length would be made anew for every take's key bytes).
+_MEMORY = ctypes.c_char * (1 << 40)
+
+
+def _view(addr, n, dtype):
+    """Zero-copy numpy view of `n` items at a C address (no ownership)."""
+    if n == 0 or not addr:
         return np.zeros(0, dtype=dtype)
-    return np.ctypeslib.as_array(
-        ctypes.cast(ptr, ctypes.POINTER(ctypes.c_uint8)),
-        shape=((n * np.dtype(dtype).itemsize),),
-    ).view(dtype)
+    return np.frombuffer(_MEMORY.from_address(addr), dtype, n)
+
+
+# A take's columns beyond the seven a dispatch reads, made when first
+# read (IngressTakenBatch.__getattr__): attribute -> (pointer field,
+# items as a function of the batch, dtype, row width or 0 for a flat
+# column).
+_LAZY_VIEWS = {
+    "hashes": ("hashes", lambda tb: tb.n, np.uint64, 0),
+    "_nb": ("name_blob", lambda tb: int(tb._info.name_bytes), np.uint8, 0),
+    "_no": ("name_off", lambda tb: tb.n + 1, np.int64, 0),
+    "_ub": ("uk_blob", lambda tb: int(tb._info.uk_bytes), np.uint8, 0),
+    "_uo": ("uk_off", lambda tb: tb.n + 1, np.int64, 0),
+    "frame_lanes": ("frame_lanes", lambda tb: tb.n_frames, np.int64, 0),
+    "frame_age_us": ("frame_age_us", lambda tb: tb.n_frames, np.int64, 0),
+    # A row a frame: (token, t_first_byte, t_body, arrival), the C++
+    # edge's stamps on the clock of time.monotonic_ns(); a frame's
+    # arrival plus its age is the take's own clock reading.
+    "frame_stamps": ("frame_stamps", lambda tb: tb.n_frames * 4, np.int64, 4),
+    # A row a frame: (address, length) of the bytes the client sent.
+    "frame_body": ("frame_body", lambda tb: tb.n_frames * 2, np.int64, 2),
+}
 
 
 class IngressTakenBatch:
@@ -1348,19 +1376,22 @@ class IngressTakenBatch:
     numpy views of C++-owned buffers.  Valid ONLY until
     IngressBatcher.complete()/fail() releases the handle — the pump is
     the sole owner and must not let views escape the dispatch round.
+    The seven columns a dispatch reads are viewed at once; the others
+    when something first reads them (the folds, the tap, the outcome:
+    off the take's way to its launch), under the same lifetime rule.
 
     Quacks like wire.FrameIngressColumns where the batch-granularity
     folds need it (len, .hits/.behavior/..., `_nb`/`_no`/`_uo` name
     columns for the tenant fold, packed hash keys + ring hashes for
     the hot-key sketch)."""
 
-    __slots__ = ("_ptr", "n", "n_frames", "algorithm", "behavior", "hits",
-                 "limit", "duration", "hash_keys", "hashes", "frame_lanes",
-                 "frame_age_us", "frame_stamps", "parse_ns_total",
-                 "hits_total", "_nb", "_no", "_ub", "_uo", "trace_ctx")
+    __slots__ = ("_ptr", "_info", "n", "n_frames", "algorithm", "behavior",
+                 "hits", "limit", "duration", "hash_keys", "parse_ns_total",
+                 "hits_total", "beh_or", "trace_ctx", *_LAZY_VIEWS)
 
     def __init__(self, ptr, info: _GtTakenInfo):
         self._ptr = ptr
+        self._info = info
         n = int(info.n)
         self.n = n
         self.n_frames = int(info.n_frames)
@@ -1373,22 +1404,34 @@ class IngressTakenBatch:
             _view(info.hk, int(info.hk_bytes), np.uint8),
             _view(info.hkoff, n + 1, np.int64),
         )
-        self.hashes = _view(info.hashes, n, np.uint64)
-        self._nb = _view(info.name_blob, int(info.name_bytes), np.uint8)
-        self._no = _view(info.name_off, n + 1, np.int64)
-        self._ub = _view(info.uk_blob, int(info.uk_bytes), np.uint8)
-        self._uo = _view(info.uk_off, n + 1, np.int64)
-        self.frame_lanes = _view(info.frame_lanes, self.n_frames, np.int64)
-        self.frame_age_us = _view(info.frame_age_us, self.n_frames, np.int64)
-        # A row a frame: (token, t_first_byte, t_body, arrival), the C++
-        # edge's stamps on the clock of time.monotonic_ns(); a frame's
-        # arrival plus its age is the take's own clock reading.
-        self.frame_stamps = _view(
-            info.frame_stamps, self.n_frames * 4, np.int64
-        ).reshape(-1, 4)
         self.parse_ns_total = int(info.parse_ns_total)
         self.hits_total = int(info.hits_total)
+        # The OR of every lane's behaviour word (gt_ingress_submit read
+        # them all): what the take holds, without a pass over the column.
+        self.beh_or = int(info.beh_or)
         self.trace_ctx = None  # fast lane never carries sampled frames
+
+    def __getattr__(self, name: str):
+        # Reached only for a slot not yet filled: a lazy view's first read.
+        spec = _LAZY_VIEWS.get(name)
+        if spec is None:
+            raise AttributeError(name)
+        if self._ptr is None:
+            raise ValueError(f"{name}: the batch was completed, its views are dead")
+        field, length, dtype, row = spec
+        view = _view(getattr(self._info, field), length(self), dtype)
+        if row:
+            view = view.reshape(-1, row)
+        setattr(self, name, view)
+        return view
+
+    def frame_bytes(self) -> "List[bytes]":
+        """The kind-5 frames of the take as the clients sent them, a copy
+        each (IngressFrame::body, which lives until complete()/fail())."""
+        return [
+            ctypes.string_at(addr, length)
+            for addr, length in self.frame_body.tolist()
+        ]
 
     def __len__(self) -> int:
         return self.n
@@ -1509,11 +1552,15 @@ class IngressBatcher:
         self.stopped = True
         self._lib.gt_ingress_stop(self._ptr)
 
-    def stats(self) -> dict:
-        out = np.zeros(10, dtype=np.int64)
+    def counters(self) -> "List[int]":
+        """The ring's ten cumulative counters, in STAT_KEYS' order."""
+        out = (ctypes.c_int64 * len(self.STAT_KEYS))()
         if self._ptr:  # freed batchers read as all-zero, never crash
-            self._lib.gt_ingress_stats(self._ptr, out.ctypes.data)
-        return dict(zip(self.STAT_KEYS, (int(v) for v in out)))
+            self._lib.gt_ingress_stats(self._ptr, out)
+        return list(out)
+
+    def stats(self) -> dict:
+        return dict(zip(self.STAT_KEYS, self.counters()))
 
     def free(self) -> None:
         ptr, self._ptr = self._ptr, None

@@ -3327,6 +3327,7 @@ struct IngressFrame {
   int acceptor;
   bool keep_alive;
   bool express = false;  // NO_BATCHING lane(s): rides the express queue
+  int32_t beh_or = 0;    // OR of the lanes' behaviour words (gt_ingress_submit)
   std::string body;   // owns the frame bytes; columns view into it
   GtFrameInfo info;
   int64_t n;
@@ -3352,7 +3353,11 @@ struct TakenBatch {
   // Per frame {token, t_first_byte, t_body, arrival} (mono_ns): the
   // edge's stamps of the request, for edge.recv and edge.handoff.
   std::vector<int64_t> frame_stamps;
+  // Per frame {address, length} of IngressFrame::body, the bytes the
+  // client sent: the black box copies them (blackbox.tap_taken).
+  std::vector<int64_t> frame_body;
   int64_t parse_ns_total = 0;
+  int32_t beh_or = 0;  // OR of the frames' beh_or: what the take holds
 };
 
 struct IngressBatcher {
@@ -3409,6 +3414,8 @@ typedef struct {
   const int64_t* frame_stamps;  // i64[n_frames * 4], see TakenBatch
   int64_t parse_ns_total;
   int64_t hits_total;  // sum of `hits`: the audit's ingress_hits
+  const int64_t* frame_body;  // i64[n_frames * 2], see TakenBatch
+  int64_t beh_or;  // OR of every lane's behaviour word
 } GtTakenInfo;
 
 void* gt_ingress_new(void) { return new IngressBatcher; }
@@ -3494,9 +3501,11 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   // resolves, weeks (3) or anything outside 0-5: the Python path owns
   // that lane's error wording (utils/gregorian.py).
   bool xpress = false;
+  int32_t beh_or = 0;
   for (int64_t i = 0; i < n; ++i) {
     int32_t bh;
     memcpy(&bh, body + info.beh_pos + 4 * i, 4);
+    beh_or |= bh;
     if (bh & behavior_mask) return bump_fallback(4);
     if (bh & kBehaviorGregorian) {
       int64_t d;
@@ -3555,6 +3564,7 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   frame->acceptor = p->acceptor;
   frame->keep_alive = p->keep_alive;
   frame->n = n;
+  frame->beh_or = beh_or;
   frame->info = info;
   frame->arrival = t0;
   frame->t_first_byte = p->t_first_byte;
@@ -3655,7 +3665,7 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
     // NO_BATCHING frame never waits behind coalesced bulk backlog —
     // an express take must not keep filling from the bulk queue, or
     // the express response would wait out a full up-to-max_lanes
-    // dispatch and outgrow the host scalar slot).  Express frames
+    // dispatch).  Express frames
     // coalesce among THEMSELVES (window-free coalescing); bulk frames
     // ride the next take — with multiple pump threads, usually a
     // concurrent one.  NO_BATCHING callers opting out of batching pay
@@ -3685,6 +3695,7 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
   tb->frame_lanes.resize(tb->frames.size());
   tb->frame_age_us.resize(tb->frames.size());
   tb->frame_stamps.resize(tb->frames.size() * 4);
+  tb->frame_body.resize(tb->frames.size() * 2);
   auto now = std::chrono::steady_clock::now();
   int64_t lo = 0;
   tb->hkoff[0] = tb->name_off[0] = tb->uk_off[0] = 0;
@@ -3723,7 +3734,10 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
     tb->frame_stamps[fi * 4 + 1] = f->t_first_byte;
     tb->frame_stamps[fi * 4 + 2] = f->t_body;
     tb->frame_stamps[fi * 4 + 3] = ns_of(f->arrival);
+    tb->frame_body[fi * 2 + 0] = (int64_t)(intptr_t)body;
+    tb->frame_body[fi * 2 + 1] = (int64_t)f->body.size();
     tb->parse_ns_total += f->parse_ns;
+    tb->beh_or |= f->beh_or;
     lo += m;
   }
   out->n = n;
@@ -3749,6 +3763,8 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
   out->parse_ns_total = tb->parse_ns_total;
   out->hits_total = 0;
   for (int64_t h : tb->hits) out->hits_total += h;
+  out->frame_body = tb->frame_body.data();
+  out->beh_or = tb->beh_or;
   *out_tb = tb.release();
   return 1;
 }

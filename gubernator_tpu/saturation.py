@@ -106,13 +106,14 @@ WATERFALL = (
     ("batch.window", 0),      # submit -> coalescing-window flush (batchers; a
                               # native frame: its arrival -> the take)
     ("pump.depth_wait", 0),   # native take: the pipeline-depth semaphore
-    ("pump.admit", 0),        # native take: ring counters, black-box tap, audit
-                              # note, tenant fold, hot-key sketch
+    ("pump.admit", 0),        # native take: what stands in front of its launch
+                              # besides the two phases below (the audit note; a
+                              # traced take's edge stamps)
     ("calendar.resolve", 0),  # DURATION_IS_GREGORIAN lanes -> greg_expire /
                               # greg_duration at the dispatch's one clock
-                              # reading: every native take (its check for such
-                              # lanes included), and a Python-path request
-                              # that holds one
+                              # reading: every native take (a plain one holds
+                              # a test of the take's beh_or), and a Python-path
+                              # request that holds one
     ("behavior.handle", 0),   # the callers' behaviour bits of a native take: the
                               # check for GLOBAL and MULTI_REGION lanes and, where
                               # there are some, the MULTI_REGION hits queued for
@@ -149,7 +150,10 @@ WATERFALL = (
     ("edge.send", 0),         # C++ edge: the answer handed to the acceptor ->
                               # the kernel has accepted its last byte (eventfd
                               # wake, staging copy, EPOLLOUT round, sends)
-    ("pump.account", 0),      # native take: request metrics, after the answers left
+    ("pump.account", 0),      # native take, what no request waits on: its observers
+                              # (ring counters, black-box tap, tenant fold, hot-key
+                              # sketch) once it has launched, while the device
+                              # computes; request metrics after the answers left
     ("ingress.total", 0),     # whole-request wall time (GetRateLimits)
     ("global.sync_drain", 0),  # GLOBAL tick: pipeline drain + both locks
     ("global.sync", 0),       # GLOBAL tick, locks held: dispatch, read-back, commit

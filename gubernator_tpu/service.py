@@ -3017,6 +3017,10 @@ class V1Service:
                 # The most lanes the native ingress pump coalesces into
                 # one take (NativeIngressPump.take_bound; 0: no pump).
                 "takeLanes": int(getattr(self.native_ingress, "take_lanes", 0)),
+                # Its takes whose lanes carried no calendar and no owner
+                # bit (`beh_or`): they did no numpy of the pump's own.
+                # Against /debug/device mesh.takes, the share that did none.
+                "plainTakes": int(getattr(self.native_ingress, "plain_takes", 0)),
             },
             "dispatch": {
                 "inflight": store.pipeline_depth(),

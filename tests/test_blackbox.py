@@ -482,3 +482,123 @@ def test_seeded_incident_bundle_replays_deterministically(tmp_path):
     src = out.read_text()
     compile(src, str(out), "exec")
     assert "def test_" in src and os.path.basename(bundle) in src
+
+
+# ---------------------------------------------------------------------
+# The native ingress lane's tap: the bytes the client sent
+# ---------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def native_daemon():
+    import jax
+
+    from gubernator_tpu import native
+    from gubernator_tpu.cluster import fast_test_behaviors
+    from gubernator_tpu.config import DaemonConfig
+    from gubernator_tpu.daemon import Daemon
+
+    if not native.available():
+        pytest.skip("the native ingress lane needs the host runtime")
+    clock = Clock()
+    clock.freeze(1_790_000_000_000)
+    daemon = Daemon(DaemonConfig(
+        listen_address="127.0.0.1:0", grpc_listen_address="127.0.0.1:0",
+        cache_size=4096, behaviors=fast_test_behaviors(),
+        peer_discovery_type="static", native_http=True,
+        devices=jax.devices()[:1], warmup_shapes=[],
+    ), clock=clock).start()
+    daemon.set_peers([daemon.peer_info])
+    try:
+        yield daemon
+    finally:
+        daemon.close()
+
+
+def _ingress_frame(tag: str, n: int, calendar_lane: bool = False) -> bytes:
+    import numpy as np
+
+    from gubernator_tpu.types import Behavior
+
+    behavior = np.zeros(n, np.int32)
+    duration = np.full(n, 60_000, np.int64)
+    if calendar_lane:  # an hour of the calendar on lane 1
+        behavior[1] = int(Behavior.DURATION_IS_GREGORIAN)
+        duration[1] = 1
+    return wire.encode_ingress_frame((
+        ["bbn"] * n, [f"{tag}-{i}" for i in range(n)], np.zeros(n, np.int32),
+        behavior, np.arange(1, n + 1, dtype=np.int64),
+        np.full(n, 1_000, np.int64), duration,
+    ))
+
+
+@pytest.mark.parametrize("shape", ["one-frame", "three-frames-a-take",
+                                   "calendar-lane"])
+def test_native_take_records_the_sent_bytes_and_a_bundle_replays_them(
+        native_daemon, tmp_path, shape):
+    """What the black box holds of a native take is what the clients
+    sent, byte for byte (the tap copies `IngressFrame::body`; it used to
+    re-encode the take's columns), a record a frame, also of a take that
+    coalesced three frames; and a bundle of them replays: every frame
+    drives the public endpoint of a fresh service and is answered 200."""
+    import threading
+    import urllib.request
+
+    from gubernator_tpu.gateway import NativeIngressPump
+
+    daemon = native_daemon
+    svc, pump = daemon.service, daemon.gateway.pump
+    port = daemon.gateway._edge.port
+    svc.blackbox.path = str(tmp_path)
+    svc.blackbox.coalesce_s = 0.02
+    ring = svc.blackbox.rings["public"]
+    tapped, takes = ring.stats()[2], pump.stats()["batches"]
+
+    def post(frame: bytes) -> None:
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{port}/v1/GetRateLimits", data=frame,
+            headers={"Content-Type": wire.COLUMNS_CONTENT_TYPE},
+        )
+        with urllib.request.urlopen(req, timeout=60) as r:
+            assert r.status == 200
+            assert wire.decode_ingress_result_frame(r.read()).n > 0
+
+    def until(read, want):
+        deadline = time.monotonic() + 30.0
+        while read() != want:
+            assert time.monotonic() < deadline, (read(), want)
+            time.sleep(0.002)
+
+    if shape == "three-frames-a-take":
+        # Both pump threads on a take of one frame each, held at the depth
+        # semaphore; the next three queue and ride ONE take.
+        frames = [_ingress_frame(f"{shape}-{f}", 2 + f) for f in range(5)]
+        threads = [threading.Thread(target=post, args=(f,)) for f in frames]
+        for _ in range(NativeIngressPump.DEPTH):
+            pump._sem.acquire()
+        try:
+            for i, t in enumerate(threads):
+                t.start()
+                if i < 2:
+                    until(lambda: pump.stats()["batches"], takes + i + 1)
+            until(lambda: pump.stats()["pendingFrames"], 3)
+        finally:
+            for _ in range(NativeIngressPump.DEPTH):
+                pump._sem.release()
+        for t in threads:
+            t.join(60.0)
+        assert pump.stats()["batches"] - takes == 3
+    else:
+        frames = [_ingress_frame(shape, 9, shape == "calendar-lane")]
+        post(frames[0])
+    assert ring.stats()[2] - tapped == len(frames)
+    records = ring.freeze()[-len(frames):]
+    assert sorted(r[5] for r in records) == sorted(frames)
+    assert {(r[2], r[4]) for r in records} == {("in", 5)}
+
+    svc.blackbox.trigger_manual("drill")
+    bundle = _wait_bundles(str(tmp_path), 1)[-1]
+    assert _script("blackbox_fsck").main([bundle]) == 0
+    captured = [r[5] for r in blackbox.load_bundle(bundle).frames["public"]]
+    assert sorted(captured[-len(frames):]) == sorted(frames)
+    report = _script("replay").replay_bundle(bundle)
+    assert report["driven"]["public"] == len(captured)
+    assert report["responseStatuses"] == {"200": len(captured)}
