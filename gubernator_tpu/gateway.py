@@ -937,20 +937,23 @@ class NativeIngressPump:
     (host_runtime.cpp gt_ingress_*, architecture.md "Native service
     loop").
 
-    Gateway workers feed kind-5 frames into the native ring without
-    ever copying their bytes into Python (HttpEdge.next(ingress=...));
+    Gateway workers feed kind-5 frames and, since PR 47, classic JSON
+    calls (parsed in C++ into the frame of their checks) into the native
+    ring without ever copying their bytes into Python
+    (HttpEdge.next(ingress=...));
     this pump is the ONLY Python in the steady-state hot path: one
     take per coalesced batch (zero-copy column views), the
     batch-granularity observability folds (audit ledger, tenant
     ledger, hot-key sketch, phase attribution — the PR 6/9/12 planes
     stay honest), one store dispatch, and one complete that hands the
-    result arrays back to C++ for the per-frame kind-6 response fill
-    and socket write.
+    result arrays back to C++ for the per-frame response fill (kind-6
+    for a frame, JSON for a call) and socket write.
 
     Lanes needing Python semantics never reach here — the native
     submit falls back to the ordinary gateway path for them (GLOBAL or
     MULTI_REGION lanes in a ring of more than one node, validation
-    errors, remote owners, sampled traces, malformed frames), so
+    errors, remote owners, sampled traces, malformed frames, JSON the
+    native parser refuses), so
     correctness is identical with the pump on or off; the pump only
     removes interpreter time from the already-columnar common case."""
 
@@ -1201,7 +1204,7 @@ class NativeIngressPump:
         happen entirely in C++, so the pump surfaces them into the
         flight recorder (the automatic-dump trigger shedding exists
         for) and samples the ring depth for /debug/status."""
-        (_, lanes, _, _, shed, _, _, pending, _, express_lanes
+        (_, lanes, _, _, shed, _, _, pending, _, express_lanes, _, _
          ) = self.batcher.counters()
         saturation.observe_queue_depth(pending)
         # The last-seen values are shared by every caller (a done-pool
@@ -1391,7 +1394,7 @@ class NativeIngressPump:
                 # needed past complete() (the batch's views die inside it).
                 tenant_ctx, ages_s = self._observe(tb, bt)
                 edge_stamps = tb.frame_stamps[:, 1:].tolist()
-                nf = tb.n_frames
+                nf, n_calls = tb.n_frames, tb.n_calls
                 out = handle.result()
                 with phase("pump.outcome", bt):
                     result = ColumnarResult(
@@ -1415,7 +1418,9 @@ class NativeIngressPump:
                 with phase("pump.account", bt) as ph:
                     anchor_ns = time.monotonic_ns() if ph.traced else 0
                     dt_disp = time.perf_counter() - t0
-                    m.ingress_columns_batches.labels(encoding="frame").inc(nf)
+                    # A take's entries are requests, kind-5 frames and
+                    # classic calls alike; only its frames are columnar.
+                    m.ingress_columns_batches.labels(encoding="frame").inc(nf - n_calls)
                     m.request_counts.labels(status="0", method=rpc).inc(nf)
                     duration = m.request_duration.labels(method=rpc)
                     for age in ages_s:
@@ -1549,8 +1554,9 @@ class NativeGatewayServer:
         arrivals: list = []
         while not self._stopped.is_set():
             # The native fast lane: when the pump is attached, a kind-5
-            # ingress frame is validated/hashed/routed/enqueued INSIDE
-            # edge.next and this worker never sees its bytes.  That is
+            # ingress frame or a classic JSON call is parsed, validated,
+            # hashed, routed and enqueued INSIDE edge.next and this
+            # worker never sees its bytes.  That is
             # two GIL-released native calls with the interpreter between
             # them (gt_http_next, the body's sniff in Python, then
             # gt_ingress_submit): Python's per-frame cost is the token

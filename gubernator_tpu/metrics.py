@@ -150,7 +150,9 @@ class Metrics:
             "Coalesced batches the native ingress service loop handed "
             "to the Python pump (stat = frames/lanes/batches/fallbacks; "
             "fallbacks = kind-5 frames that took the Python path for "
-            "semantics the fast lane does not serve).",
+            "semantics the fast lane does not serve; calls = classic "
+            "JSON calls the lane kept, callFallbacks = those it was "
+            "offered and handed to the Python path).",
             ["stat"],
             registry=self.registry,
         )
@@ -860,7 +862,8 @@ class Metrics:
         if pump is None:
             return
         stats = pump.stats()
-        for stat in ("frames", "lanes", "batches", "fallbacks"):
+        for stat in ("frames", "lanes", "batches", "fallbacks", "calls",
+                     "callFallbacks"):
             self._bump(
                 self.native_ingress_batches.labels(stat=stat), stats[stat]
             )

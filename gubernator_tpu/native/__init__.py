@@ -1140,6 +1140,7 @@ _HTTP_METHODS = {0: "GET", 1: "POST"}
 FAST_LANE = object()
 
 _INGRESS_SNIFF = b"GUBC\x01\x05"  # magic + version + kind-5
+_JSON_WS = b" \t\n\r"
 
 
 class HttpEdge:
@@ -1242,11 +1243,13 @@ class HttpEdge:
         (token, method, path, body_bytes, (t_first_byte_ns, t_body_ns)),
         None (timeout/stopping), or FAST_LANE when `ingress` (an
         IngressBatcher) consumed the request natively — a POST
-        /v1/GetRateLimits whose body sniffs as a kind-5 frame goes
-        through gt_ingress_submit WITHOUT copying the body into Python;
-        any fallback reason (malformed, slow lanes, remote owners,
-        disabled) falls through to the ordinary copy-out so the Python
-        path serves it unchanged.  That is two GIL-released native calls
+        /v1/GetRateLimits whose body sniffs as a kind-5 frame, or as a
+        classic JSON call (`{` after JSON whitespace), goes through
+        gt_ingress_submit WITHOUT copying the body into Python (the C++
+        side picks the parser by the same first bytes); any fallback
+        reason (malformed, exotic JSON, slow lanes, validation errors,
+        remote owners, disabled) falls through to the ordinary copy-out
+        so the Python path serves it unchanged.  That is two GIL-released native calls
         with the interpreter between them: gt_http_next hands the request
         over, this thread takes the interpreter back to sniff the body,
         then gt_ingress_submit parses and enqueues (`edge.handoff` runs
@@ -1264,10 +1267,15 @@ class HttpEdge:
             ingress is not None
             and req.method == 1
             and req.body_len >= 10
-            and ctypes.string_at(req.body, 6) == _INGRESS_SNIFF
             and req.path == b"/v1/GetRateLimits"
         ):
-            if self._lib.gt_ingress_submit(
+            head = ctypes.string_at(req.body, 6)
+            if (
+                head == _INGRESS_SNIFF
+                # A classic call: `{` after JSON whitespace (a head that
+                # is all whitespace is the C++ parser's to read on).
+                or head.lstrip(_JSON_WS)[:1] in (b"{", b"")
+            ) and self._lib.gt_ingress_submit(
                 self._ptr, ingress._ptr, req.token
             ) == 0:
                 return FAST_LANE
@@ -1333,6 +1341,8 @@ class _GtTakenInfo(ctypes.Structure):
         ("hits_total", ctypes.c_int64),
         ("frame_body", ctypes.c_void_p),
         ("beh_or", ctypes.c_int64),
+        ("frame_call", ctypes.c_void_p),
+        ("n_calls", ctypes.c_int64),
     ]
 
 
@@ -1367,6 +1377,8 @@ _LAZY_VIEWS = {
     "frame_stamps": ("frame_stamps", lambda tb: tb.n_frames * 4, np.int64, 4),
     # A row a frame: (address, length) of the bytes the client sent.
     "frame_body": ("frame_body", lambda tb: tb.n_frames * 2, np.int64, 2),
+    # 1 where the frame is a classic JSON call, 0 a kind-5 frame.
+    "frame_call": ("frame_call", lambda tb: tb.n_frames, np.uint8, 0),
 }
 
 
@@ -1385,7 +1397,7 @@ class IngressTakenBatch:
     columns for the tenant fold, packed hash keys + ring hashes for
     the hot-key sketch)."""
 
-    __slots__ = ("_ptr", "_info", "n", "n_frames", "algorithm", "behavior",
+    __slots__ = ("_ptr", "_info", "n", "n_frames", "n_calls", "algorithm", "behavior",
                  "hits", "limit", "duration", "hash_keys", "parse_ns_total",
                  "hits_total", "beh_or", "trace_ctx", *_LAZY_VIEWS)
 
@@ -1395,6 +1407,8 @@ class IngressTakenBatch:
         n = int(info.n)
         self.n = n
         self.n_frames = int(info.n_frames)
+        # Of them, the classic JSON calls (answered as JSON).
+        self.n_calls = int(info.n_calls)
         self.algorithm = _view(info.algo, n, np.int32)
         self.behavior = _view(info.beh, n, np.int32)
         self.hits = _view(info.hits, n, np.int64)
@@ -1427,11 +1441,12 @@ class IngressTakenBatch:
 
     def frame_bytes(self) -> "List[bytes]":
         """The kind-5 frames of the take as the clients sent them, a copy
-        each (IngressFrame::body, which lives until complete()/fail())."""
-        return [
-            ctypes.string_at(addr, length)
-            for addr, length in self.frame_body.tolist()
-        ]
+        each (IngressFrame::body, which lives until complete()/fail());
+        its classic JSON calls are left out."""
+        bodies = self.frame_body
+        if self.n_calls:
+            bodies = bodies[self.frame_call == 0]
+        return [ctypes.string_at(addr, length) for addr, length in bodies.tolist()]
 
     def __len__(self) -> int:
         return self.n
@@ -1460,14 +1475,15 @@ class IngressTakenBatch:
 
 class IngressBatcher:
     """The native ingress ring (gt_ingress_*): gateway workers submit
-    kind-5 frames GIL-free; the NativeIngressPump takes coalesced
-    batches, dispatches them at batch granularity, and completes them
-    back into native kind-6 response fills.  See host_runtime.cpp
+    kind-5 frames and classic JSON calls GIL-free; the NativeIngressPump
+    takes coalesced batches, dispatches them at batch granularity, and
+    completes them back into native response fills (kind-6 for a frame,
+    JSON for a call).  See host_runtime.cpp
     'Native ingress service loop' for the full contract."""
 
     STAT_KEYS = ("frames", "lanes", "batches", "shedFrames", "shedLanes",
                  "fallbacks", "pendingFrames", "pendingLanes",
-                 "expressFrames", "expressLanes")
+                 "expressFrames", "expressLanes", "calls", "callFallbacks")
 
     def __init__(self):
         lib = _get_lib()
@@ -1553,7 +1569,7 @@ class IngressBatcher:
         self._lib.gt_ingress_stop(self._ptr)
 
     def counters(self) -> "List[int]":
-        """The ring's ten cumulative counters, in STAT_KEYS' order."""
+        """The ring's cumulative counters, in STAT_KEYS' order."""
         out = (ctypes.c_int64 * len(self.STAT_KEYS))()
         if self._ptr:  # freed batchers read as all-zero, never crash
             self._lib.gt_ingress_stats(self._ptr, out)

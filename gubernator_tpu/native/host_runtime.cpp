@@ -3251,6 +3251,13 @@ void gt_http_free(void* sv) {
 //     is untouched and Python serves the request exactly as before,
 //     which is what keeps every error's wording and the mixed-version
 //     interop byte-identical.
+//     A classic JSON call of the same endpoint is offered here too
+//     (the body's first bytes say which): parsed by gt_json_parse on
+//     this thread, it becomes the frame of its checks (call_as_frame)
+//     and from there passes the same checks, queues and take; what
+//     Python alone answers exactly (JSON the parser refuses, a lane
+//     with a validation code, 0 or more than 1000 checks) falls back
+//     the same way, counted as call_fallbacks.
 //   gt_ingress_take — the Python pump thread blocks here (GIL
 //     released) and receives ONE coalesced batch: contiguous
 //     kernel-ready column arrays spanning every pending frame (plus
@@ -3262,7 +3269,9 @@ void gt_http_free(void* sv) {
 //     -> HTTP wrap -> stage on the owning acceptor.  The bytes are
 //     identical to wire.encode_ingress_result_frame for the
 //     no-override/no-owner case (golden-tested), so a client cannot
-//     tell the native loop from the PR 8 path.
+//     tell the native loop from the PR 8 path.  A classic call's slice
+//     is rendered by gt_json_render instead, the bytes the Python
+//     route's render_result_native gives (tests/test_native_calls.py).
 //
 // Lanes that need Python semantics (a Gregorian duration upstream
 // answers with an error, per-lane validation errors, sampled traces,
@@ -3327,9 +3336,15 @@ struct IngressFrame {
   int acceptor;
   bool keep_alive;
   bool express = false;  // NO_BATCHING lane(s): rides the express queue
+  bool call = false;     // a classic JSON call: answered as JSON, not kind-6
   int32_t beh_or = 0;    // OR of the lanes' behaviour words (gt_ingress_submit)
-  std::string body;   // owns the frame bytes; columns view into it
-  GtFrameInfo info;
+  std::string body;   // the bytes the client sent; a frame's columns view into it
+  // A call's columns, parsed out of its JSON into a kind-5 frame's layout
+  // (offset and blob of the names, of the unique keys, then the five
+  // numeric columns), so that `info` positions them as it does a frame's.
+  std::string cols;
+  GtFrameInfo info;   // positions against base()
+  const char* base() const { return call ? cols.data() : body.data(); }
   int64_t n;
   std::string hk;                 // packed hash keys (name + '_' + uk)
   std::vector<int64_t> hkoff;     // n+1
@@ -3356,6 +3371,9 @@ struct TakenBatch {
   // Per frame {address, length} of IngressFrame::body, the bytes the
   // client sent: the black box copies them (blackbox.tap_taken).
   std::vector<int64_t> frame_body;
+  // Per frame: 1 = a classic JSON call, 0 = a kind-5 frame.
+  std::vector<uint8_t> frame_call;
+  int64_t n_calls = 0;
   int64_t parse_ns_total = 0;
   int32_t beh_or = 0;  // OR of the frames' beh_or: what the take holds
 };
@@ -3384,6 +3402,10 @@ struct IngressBatcher {
   int64_t shed_frames = 0, shed_lanes = 0;
   int64_t fallbacks = 0;
   int64_t express_frames = 0, express_lanes = 0;
+  // Classic JSON calls: kept by the lane, and offered but handed back.
+  // (`frames` and `fallbacks` count kind-5 frames only; `lanes`,
+  // `batches` and the express and shed counts are the lane's, of both.)
+  int64_t calls = 0, call_fallbacks = 0;
 };
 
 void ingress_free_frame(IngressFrame* f) { delete f; }
@@ -3416,6 +3438,8 @@ typedef struct {
   int64_t hits_total;  // sum of `hits`: the audit's ingress_hits
   const int64_t* frame_body;  // i64[n_frames * 2], see TakenBatch
   int64_t beh_or;  // OR of every lane's behaviour word
+  const uint8_t* frame_call;  // u8[n_frames], 1 = a classic JSON call
+  int64_t n_calls;
 } GtTakenInfo;
 
 void* gt_ingress_new(void) { return new IngressBatcher; }
@@ -3447,12 +3471,69 @@ void gt_ingress_set_ring(void* bv, const uint64_t* vh, const uint8_t* vself,
 }
 
 constexpr int32_t kBehaviorGregorian = 4;  // Behavior.DURATION_IS_GREGORIAN
+// config.MAX_BATCH_SIZE: a classic call of more checks is Python's to
+// answer (OutOfRange and its wording).
+constexpr int64_t kMaxCallChecks = 1000;
 
-// The fast-lane entry (see the banner for the contract).  Returns 0 =
-// handled natively; >0 = Python fallback reason (1 malformed/bad-utf8,
-// 2 trace trailer, 3 empty/oversize, 4 slow behavior bits, 5
-// validation-error lanes, 6 disabled, 7 remote-owned lanes); -1 =
-// unknown token.
+}  // extern "C"
+
+namespace {
+
+// A parsed classic call as the kind-5 frame of the same checks
+// (wire.encode_ingress_frame's layout), so that one code path validates,
+// hashes, routes and takes it: name and unique-key columns (u32 blob
+// length, u32 offsets[n+1], blob), then algorithm, behaviour, hits,
+// limit and duration.
+std::string call_as_frame(const JsonBatch& jb, const char* body) {
+  size_t n = jb.algo.size();
+  size_t nbytes = 0, ubytes = 0;
+  for (size_t i = 0; i < n; ++i) {
+    nbytes += (size_t)jb.nspan[2 * i + 1];
+    ubytes += (size_t)jb.ukspan[2 * i + 1];
+  }
+  std::string out;
+  out.reserve(10 + 2 * (4 + 4 * (n + 1)) + nbytes + ubytes + n * 32);
+  auto u32 = [&](uint32_t v) { out.append((const char*)&v, 4); };
+  out.append("GUBC\x01\x05", 6);
+  u32((uint32_t)n);
+  auto str_col = [&](const std::vector<int64_t>& span, size_t bytes) {
+    u32((uint32_t)bytes);
+    uint32_t off = 0;
+    u32(off);
+    for (size_t i = 0; i < n; ++i) u32(off += (uint32_t)span[2 * i + 1]);
+    for (size_t i = 0; i < n; ++i)
+      out.append(body + span[2 * i], (size_t)span[2 * i + 1]);
+  };
+  str_col(jb.nspan, nbytes);
+  str_col(jb.ukspan, ubytes);
+  out.append((const char*)jb.algo.data(), n * 4);
+  out.append((const char*)jb.behavior.data(), n * 4);
+  out.append((const char*)jb.hits.data(), n * 8);
+  out.append((const char*)jb.limit.data(), n * 8);
+  out.append((const char*)jb.duration.data(), n * 8);
+  return out;
+}
+
+}  // namespace
+
+extern "C" {
+
+// The fast-lane entry (see the banner for the contract).  The body's
+// first bytes say what it is: the GUBC magic a kind-5 frame, anything
+// else a classic JSON call (HttpEdge.next offers no other).  A call is
+// parsed here, on the worker's thread with the interpreter released
+// (gt_json_parse), and from there on IS the frame of its checks
+// (call_as_frame): it passes the same checks in the same order, rides
+// the same queues and the same take, and differs again only in how it
+// is answered (gt_ingress_complete) and counted (calls / call_fallbacks).
+// What Python alone answers exactly goes back whole: JSON the parser
+// refuses (escapes in a name or key, floats, a behaviour list, nested
+// values, duplicate `requests`, trailing bytes), a lane with a
+// validation code (its error object and wording), no check or more than
+// kMaxCallChecks.  Returns 0 = handled natively; >0 = Python fallback
+// reason (1 malformed/bad-utf8, 2 trace trailer, 3 empty/oversize, 4
+// slow behavior bits, 5 validation-error lanes, 6 disabled, 7
+// remote-owned lanes); -1 = unknown token.
 int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   auto* s = (HttpServer*)sv;
   auto* b = (IngressBatcher*)bv;
@@ -3476,21 +3557,43 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
     behavior_mask = b->behavior_mask;
     express_mask = b->express_mask;
   }
+  const bool call =
+      p->body.size() < 4 || memcmp(p->body.data(), "GUBC", 4) != 0;
   auto bump_fallback = [&](int code) {
     std::lock_guard<std::mutex> lk(b->mu);
-    ++b->fallbacks;
+    ++(call ? b->call_fallbacks : b->fallbacks);
     return code;
   };
   if (!enabled || !ring) return bump_fallback(6);
   auto t0 = std::chrono::steady_clock::now();
+  auto frame = std::make_unique<IngressFrame>();
+  const std::string* src = &p->body;
+  if (call) {
+    std::unique_ptr<JsonBatch> jb((JsonBatch*)gt_json_parse(
+        p->body.data(), (int64_t)p->body.size()));
+    if (!jb) return bump_fallback(1);  // Python's json.loads and its 400
+    int64_t checks = (int64_t)jb->algo.size();
+    if (checks == 0 || checks > kMaxCallChecks) return bump_fallback(3);
+    for (uint8_t e : jb->err)
+      if (e) return bump_fallback(5);  // empty field, bad enum: Python's words
+    // Each JSON string on its own (the frame check below reads a column's
+    // blob whole, where one string's torn tail could borrow the next's head).
+    const char* raw = p->body.data();
+    for (int64_t i = 0; i < checks; ++i)
+      if (!utf8_valid(raw + jb->nspan[2 * i], (size_t)jb->nspan[2 * i + 1]) ||
+          !utf8_valid(raw + jb->ukspan[2 * i], (size_t)jb->ukspan[2 * i + 1]))
+        return bump_fallback(1);
+    frame->cols = call_as_frame(*jb, raw);
+    src = &frame->cols;
+  }
   GtFrameInfo info;
-  void* h = gt_frame_parse(p->body.data(), (int64_t)p->body.size(), 5, &info);
+  void* h = gt_frame_parse(src->data(), (int64_t)src->size(), 5, &info);
   if (!h) return bump_fallback(1);  // Python owns the 400 wording
   gt_frame_free(h);                 // positions captured in `info`
   if (info.trace_count > 0) return bump_fallback(2);  // sampled: span links
   int64_t n = info.n;
   if (n == 0 || n > max_frame_lanes) return bump_fallback(3);
-  const char* body = p->body.data();
+  const char* body = src->data();
   // A bit of behavior_mask needs the Python router's semantics: GLOBAL
   // and MULTI_REGION while the ring has another node (the pump clears
   // them from the mask of an all-self ring, whose lanes are all the
@@ -3517,7 +3620,6 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   // Build the packed hash keys + validation codes (the gt_frame_fill
   // pass, inlined so an error lane can bail early), then the UTF-8
   // parity check the Python decode edge makes.
-  auto frame = std::make_unique<IngressFrame>();
   frame->hk.reserve((size_t)info.hk_bytes);
   frame->hkoff.resize((size_t)n + 1);
   const char* noff = body + info.name_off_pos;
@@ -3564,6 +3666,7 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   frame->acceptor = p->acceptor;
   frame->keep_alive = p->keep_alive;
   frame->n = n;
+  frame->call = call;
   frame->beh_or = beh_or;
   frame->info = info;
   frame->arrival = t0;
@@ -3594,10 +3697,11 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
       } else {
         verdict = 0;
         b->pending_lanes += n;
-        ++b->frames;
+        ++(call ? b->calls : b->frames);
         b->lanes += n;
-        // The columns keep viewing the moved body; ownership transfers
-        // to the queue inside the lock so no stop() can slip between.
+        // A frame's columns keep viewing the moved body; ownership
+        // transfers to the queue inside the lock so no stop() can slip
+        // between.
         frame->body = std::move(p->body);
         frame->express = xpress;
         if (xpress) {
@@ -3633,7 +3737,7 @@ int gt_ingress_submit(void* sv, void* bv, uint64_t token) {
   {
     std::lock_guard<std::mutex> lk(s->mu);
     s->inflight.erase(token);
-    if ((size_t)p->acceptor < s->acceptors.size()) {
+    if (!call && (size_t)p->acceptor < s->acceptors.size()) {
       HttpAcceptor* a = s->acceptors[(size_t)p->acceptor].get();
       ++a->ingress_frames;
       a->ingress_lanes += n;
@@ -3696,13 +3800,14 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
   tb->frame_age_us.resize(tb->frames.size());
   tb->frame_stamps.resize(tb->frames.size() * 4);
   tb->frame_body.resize(tb->frames.size() * 2);
+  tb->frame_call.resize(tb->frames.size());
   auto now = std::chrono::steady_clock::now();
   int64_t lo = 0;
   tb->hkoff[0] = tb->name_off[0] = tb->uk_off[0] = 0;
   for (size_t fi = 0; fi < tb->frames.size(); ++fi) {
     IngressFrame* f = tb->frames[fi];
     int64_t m = f->n;
-    const char* body = f->body.data();
+    const char* body = f->base();
     memcpy(tb->algo.data() + lo, body + f->info.algo_pos, (size_t)m * 4);
     memcpy(tb->beh.data() + lo, body + f->info.beh_pos, (size_t)m * 4);
     memcpy(tb->hits.data() + lo, body + f->info.hits_pos, (size_t)m * 8);
@@ -3734,8 +3839,10 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
     tb->frame_stamps[fi * 4 + 1] = f->t_first_byte;
     tb->frame_stamps[fi * 4 + 2] = f->t_body;
     tb->frame_stamps[fi * 4 + 3] = ns_of(f->arrival);
-    tb->frame_body[fi * 2 + 0] = (int64_t)(intptr_t)body;
+    tb->frame_body[fi * 2 + 0] = (int64_t)(intptr_t)f->body.data();
     tb->frame_body[fi * 2 + 1] = (int64_t)f->body.size();
+    tb->frame_call[fi] = f->call;
+    tb->n_calls += f->call;
     tb->parse_ns_total += f->parse_ns;
     tb->beh_or |= f->beh_or;
     lo += m;
@@ -3765,40 +3872,54 @@ int gt_ingress_take(void* bv, int64_t max_lanes, int64_t timeout_ms,
   for (int64_t h : tb->hits) out->hits_total += h;
   out->frame_body = tb->frame_body.data();
   out->beh_or = tb->beh_or;
+  out->frame_call = tb->frame_call.data();
+  out->n_calls = tb->n_calls;
   *out_tb = tb.release();
   return 1;
 }
 
-// Response fill: slice the result arrays per frame, encode each kind-6
-// frame (byte-identical to wire.encode_ingress_result_frame with no
-// overrides and no owner columns — the fast lane's invariant), wrap in
-// the HTTP envelope gt_http_respond emits, and stage on the owning
+// Response fill: slice the result arrays per frame and answer each in
+// the encoding it came in.  A kind-5 frame: the kind-6 frame
+// (byte-identical to wire.encode_ingress_result_frame with no overrides
+// and no owner columns — the fast lane's invariant).  A classic call:
+// the JSON body gt_json_render gives its slice with no overrides, what
+// gateway.render_result_native renders on the Python route.  Wrapped in
+// the HTTP envelope gt_http_respond emits and staged on the owning
 // acceptor.  One call per batch; releases the handle.
 void gt_ingress_complete(void* tbv, const int32_t* status,
                          const int64_t* limit, const int64_t* remaining,
                          const int64_t* reset) {
   auto* tb = (TakenBatch*)tbv;
   int64_t lo = 0;
+  std::string frame;
   for (IngressFrame* f : tb->frames) {
     int64_t m = f->n;
-    size_t flen = 10 + (size_t)m * (4 + 8 + 8 + 8) + 8;
-    std::string frame;
-    frame.reserve(flen);
-    frame.append("GUBC", 4);
-    uint8_t vk[2] = {1, 6};
-    frame.append((const char*)vk, 2);
-    uint32_t m32 = (uint32_t)m;
-    frame.append((const char*)&m32, 4);
-    frame.append((const char*)(status + lo), (size_t)m * 4);
-    frame.append((const char*)(limit + lo), (size_t)m * 8);
-    frame.append((const char*)(remaining + lo), (size_t)m * 8);
-    frame.append((const char*)(reset + lo), (size_t)m * 8);
-    uint32_t zero = 0;
-    frame.append((const char*)&zero, 4);  // n_owner_addrs = 0
-    frame.append((const char*)&zero, 4);  // n_overrides = 0
-    std::string resp =
-        http_envelope(200, "OK", "application/x-gubernator-columns",
-                      frame.data(), (int64_t)frame.size());
+    frame.clear();
+    const char* ctype = "application/x-gubernator-columns";
+    if (f->call) {
+      ctype = "application/json";
+      frame.resize((size_t)m * 160 + 32);  // gt_json_render's worst case
+      int64_t len = gt_json_render(status + lo, limit + lo, remaining + lo,
+                                   reset + lo, m, nullptr, 0, nullptr, nullptr,
+                                   &frame[0], (int64_t)frame.size());
+      frame.resize((size_t)(len < 0 ? 0 : len));
+    } else {
+      frame.reserve(10 + (size_t)m * (4 + 8 + 8 + 8) + 8);
+      frame.append("GUBC", 4);
+      uint8_t vk[2] = {1, 6};
+      frame.append((const char*)vk, 2);
+      uint32_t m32 = (uint32_t)m;
+      frame.append((const char*)&m32, 4);
+      frame.append((const char*)(status + lo), (size_t)m * 4);
+      frame.append((const char*)(limit + lo), (size_t)m * 8);
+      frame.append((const char*)(remaining + lo), (size_t)m * 8);
+      frame.append((const char*)(reset + lo), (size_t)m * 8);
+      uint32_t zero = 0;
+      frame.append((const char*)&zero, 4);  // n_owner_addrs = 0
+      frame.append((const char*)&zero, 4);  // n_overrides = 0
+    }
+    std::string resp = http_envelope(200, "OK", ctype, frame.data(),
+                                     (int64_t)frame.size());
     http_stage_response(f->srv, f->token, std::move(resp));
     lo += m;
     ingress_free_frame(f);
@@ -3846,10 +3967,10 @@ void gt_ingress_stop(void* bv) {
   }
 }
 
-// out: i64[10] = {frames, lanes, batches, shed_frames, shed_lanes,
+// out: i64[12] = {frames, lanes, batches, shed_frames, shed_lanes,
 // fallbacks, pending_frames, pending_lanes, express_frames,
-// express_lanes}.  Cumulative; the Python scrape keeps last-seen
-// values and feeds deltas into the prometheus counters.
+// express_lanes, calls, call_fallbacks}.  Cumulative; the Python scrape
+// keeps last-seen values and feeds deltas into the prometheus counters.
 void gt_ingress_stats(void* bv, int64_t* out) {
   auto* b = (IngressBatcher*)bv;
   std::lock_guard<std::mutex> lk(b->mu);
@@ -3863,6 +3984,8 @@ void gt_ingress_stats(void* bv, int64_t* out) {
   out[7] = b->pending_lanes;
   out[8] = b->express_frames;
   out[9] = b->express_lanes;
+  out[10] = b->calls;
+  out[11] = b->call_fallbacks;
 }
 
 void gt_ingress_free(void* bv) {

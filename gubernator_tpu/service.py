@@ -2937,6 +2937,13 @@ class V1Service:
                 total[k] = total.get(k, 0) + v
         return {"edge": total}
 
+    def _native_call_stats(self) -> dict:
+        """`calls` and `callFallbacks` of the native ingress ring; zeros
+        where the daemon runs no pump."""
+        pump = self.native_ingress
+        stats = pump.stats() if pump is not None else {}
+        return {k: int(stats.get(k, 0)) for k in ("calls", "callFallbacks")}
+
     def debug_status(self) -> dict:
         """The cluster-status surface (GET /debug/status): one JSON doc
         aggregating version, health, per-peer breaker state, bucket-
@@ -3021,6 +3028,9 @@ class V1Service:
                 # bit (`beh_or`): they did no numpy of the pump's own.
                 # Against /debug/device mesh.takes, the share that did none.
                 "plainTakes": int(getattr(self.native_ingress, "plain_takes", 0)),
+                # Classic JSON calls the native lane kept, and those it
+                # was offered and handed to the Python route.
+                **self._native_call_stats(),
             },
             "dispatch": {
                 "inflight": store.pipeline_depth(),

@@ -23,24 +23,29 @@ PIPELINE = {
     "response.encode", "epoll.wait", "global.tick_idle",
 }
 SYNC_PASS = {"global.sync_drain", "global.sync"}
+PUMP = {"pump.take", "pump.depth_wait", "pump.admit", "pump.outcome", "pump.account"}
 CROSSED = {
     # One connection, GUBC frames: the native ingress lane.
-    "v5e1-1m.frames": PIPELINE | {
-        "pump.take", "pump.depth_wait", "pump.admit", "pump.outcome", "pump.account"},
-    # 32 connections, JSON calls: gateway workers, express bypass, the window.
-    "v5e1-1m.singles": PIPELINE | {"ingress.parse", "express.submit", "window.idle"},
+    "v5e1-1m.frames": PIPELINE | PUMP,
+    # 32 connections, classic JSON calls: since PR 47 the same lane (until
+    # then gateway workers, the express bypass and the batcher's window:
+    # `ingress.parse`, `express.submit`, which a plain call no longer enters).
+    "v5e1-1m.singles": PIPELINE | PUMP,
 }
+# The route every classic call took before PR 47, and a fallback's since.
+PYTHON_ROUTE = {"ingress.parse", "express.submit"}
 METRICS = {
     "v5e1-1m.frames": {
         "plan.lock_wait_ms", "plan.native_ms_per_dispatch", "launch.lock_wait_ms",
         "batcher.pump_ms_per_take", "edge.unattributed_ms_per_req",
         "device.idle_unattributed_share", "device.idle_no_request_share", "xla.program_load_s",
         "edge.recv_ms_per_req", "edge.handoff_ms_per_req", "edge.send_ms_per_req", "device.idle_edge_io_share"},
-    # No take on the native lane: the edge's phases are observed on the JSON
-    # path, and no `pump.admit` event carries stamps into the trace.
+    # Every call is a take's on the native lane (`ingress.native_call_share`),
+    # where the edge's phases are observed too; `device.idle_edge_io_share`
+    # and the pump's metric do not list the cell.
     "v5e1-1m.singles": {
         "plan.lock_wait_ms", "plan.native_ms_per_dispatch", "launch.lock_wait_ms", "device.idle_unattributed_share",
-        "device.idle_no_request_share", "xla.program_load_s",
+        "device.idle_no_request_share", "xla.program_load_s", "ingress.native_call_share",
         "edge.recv_ms_per_req", "edge.handoff_ms_per_req", "edge.send_ms_per_req"},
 }
 
@@ -72,6 +77,7 @@ def test_traced_rehearsal_holds_the_phases_its_path_crosses(cell):
                 <= line["metrics"]["device.idle_no_request_share"]["value"])
     else:
         assert "device.idle_edge_io_share" not in line["metrics"]
+        assert line["metrics"]["ingress.native_call_share"]["value"] == 100.0  # every call kept
     (path,) = glob.glob(os.path.join(
         REPO, "chipbench", "out", f"{cell}.seed{seed}.trace1.trace", "plugins", "profile", "*", "*.xplane.pb"))
     found = set()
@@ -80,4 +86,5 @@ def test_traced_rehearsal_holds_the_phases_its_path_crosses(cell):
             for ln in plane.lines:
                 found.update(ev.name for ev in ln.events)
     assert CROSSED[cell] <= found, sorted(CROSSED[cell] - found)
+    assert not PYTHON_ROUTE & found, sorted(PYTHON_ROUTE & found)  # no request left the lane
     assert not SYNC_PASS & found, sorted(SYNC_PASS & found)

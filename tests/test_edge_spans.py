@@ -323,21 +323,27 @@ def _counts():
     return {p: snap.get(p, {}).get("count", 0) for p in ("edge.recv", "edge.handoff", "edge.send")}
 
 
-@pytest.mark.parametrize("path", ["native-lane", "json"])
+@pytest.mark.parametrize("path", ["native-lane", "native-lane-call", "json"])
 def test_both_paths_observe_all_three_phases(daemon, path):
+    """A frame and (since PR 47) a plain classic call ride the native lane; a
+    call the lane hands back (a float) takes the JSON path, worker and all."""
     if path == "native-lane":
         data, ctype = _frame(16, name="both"), wire.COLUMNS_CONTENT_TYPE
     else:
-        data = json.dumps({"requests": [{"name": "both", "uniqueKey": "j", "hits": 1,
+        data = json.dumps({"requests": [{"name": "both", "uniqueKey": "j",
+                                         "hits": 1.0 if path == "json" else 1,
                                          "limit": 10, "duration": 60000}]}).encode()
         ctype = "application/json"
-    frames_before = daemon.gateway.pump.stats()["frames"]
+    before = daemon.gateway.pump.stats()
     req = urllib.request.Request(
         f"http://{daemon.gateway.address}/v1/GetRateLimits", data=data,
         headers={"Content-Type": ctype})
     with urllib.request.urlopen(req, timeout=30) as resp:
         assert resp.status == 200
-    assert daemon.gateway.pump.stats()["frames"] - frames_before == (1 if path == "native-lane" else 0)
+    after = daemon.gateway.pump.stats()
+    grown = {k: after[k] - before[k] for k in ("frames", "calls", "callFallbacks", "fallbacks")}
+    assert grown == {"frames": path == "native-lane", "calls": path == "native-lane-call",
+                     "callFallbacks": path == "json", "fallbacks": 0}
     # The stamps are observed after the answer has left (`pump.account`; on the
     # JSON path when the worker has gathered EDGE_FLUSH requests or idles), the
     # answer's own record by the next of those or the pump's idle tick.
@@ -357,10 +363,13 @@ def test_a_burst_on_the_json_path_is_observed_once_a_request_not_drained_once_a_
     drain = native.HttpEdge.drain_sends
     monkeypatch.setattr(native.HttpEdge, "drain_sends", lambda self: drains.append(1) or drain(self))
     n = 3 * gateway.NativeGatewayServer.EDGE_FLUSH
-    data = json.dumps({"requests": [{"name": "burst", "uniqueKey": "b", "hits": 0,
+    # A float: the native lane hands such a call to the JSON path whole (a
+    # plain call rides the lane since PR 47 and is observed once a take).
+    data = json.dumps({"requests": [{"name": "burst", "uniqueKey": "b", "hits": 0.0,
                                      "limit": 10, "duration": 60000}]}).encode()
     raw = _post(data, "application/json")
     before = _counts()
+    handed_back = daemon.gateway.pump.stats()["callFallbacks"]
     s = socket.create_connection(("127.0.0.1", int(daemon.gateway.address.rsplit(":", 1)[1])))
     s.settimeout(30.0)
     try:
@@ -373,6 +382,7 @@ def test_a_burst_on_the_json_path_is_observed_once_a_request_not_drained_once_a_
     finally:
         s.close()
     _wait_for(lambda: all(_counts()[p] == before[p] + n for p in before))
+    assert daemon.gateway.pump.stats()["callFallbacks"] - handed_back == n
     # A flush a worker per EDGE_FLUSH requests, and one per 200 ms that a
     # worker (four) or the pump idles: not one a request.
     assert in_burst <= n // gateway.NativeGatewayServer.EDGE_FLUSH + 5 + 25 * burst_s, (in_burst, burst_s)

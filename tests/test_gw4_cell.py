@@ -460,7 +460,9 @@ def test_the_cells_files_say_what_the_issue_says():
         assert {k: spec[k] for k in ("unit", "better", "layer", "source", "moves")} == {
             k: by_name[name][k] for k in ("unit", "better", "layer", "source", "moves")}, name
         assert os.path.exists(os.path.join(REPO, "chipbench", "readers", spec["reader"] + ".py"))
-    assert [m["name"] for m in bench["per_layer"][-len(NEW_METRICS):]] == list(NEW_METRICS)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW_METRICS[0])  # appended by PR 45; PR 47 appended its one after them
+    assert names[at:at + len(NEW_METRICS)] == list(NEW_METRICS) and at == 39
     for m in bench["per_layer"]:
         if m["name"] in NEW_METRICS or "workloads" not in m:
             continue
