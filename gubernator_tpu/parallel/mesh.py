@@ -857,10 +857,14 @@ class MeshBucketStore(ColumnarPipeline):
         """The owner's book-keeping of a batch's GLOBAL lanes, a batch
         at a time (caller holds the plan lock, which a sync pass holds
         too: the pass that takes this dirt has drained this batch, so
-        the status it broadcasts holds these hits).  A distinct key is
-        looked up or assigned once; its LAST lane's configuration wins,
-        as a request at a time would leave it."""
+        the status it broadcasts holds these hits).  What is done a
+        LANE: its key is read from `keys` and stored in a dict, so that
+        a key's LAST lane wins, as a request at a time would leave it.
+        What is done a DISTINCT key: its gslot is looked up or assigned,
+        its configuration written, its owner row marked dirty.  The mesh
+        tally counts both (`globalLanes`, `globalKeys`)."""
         last = {keys[i]: i for i in cols.global_lanes.tolist()}
+        saturation.mesh_tally.add_global_note(len(cols.global_lanes), len(last))
         idx = np.fromiter(last.values(), np.int64, len(last))
         owner = pos[idx] // padded  # the shard the plan put the lane on
         g, evicted = self.gtable.assign_columns(list(last), owner)

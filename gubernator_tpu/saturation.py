@@ -130,9 +130,13 @@ WATERFALL = (
     ("dispatch.prepare", 0),  # slot-table planning (pipeline stage 1)
     ("dispatch.plan_wait", 1),  # waiting for the plan lock
     ("dispatch.plan_native", 1),  # the C++ slot-table plan alone (begin + grouped plan)
-    ("dispatch.global_note", 1),  # a batch's GLOBAL lanes, owned here: gslot a distinct
-                              # key, its configuration, the owner row dirty (under the
-                              # plan lock; entered only where the batch holds one)
+    ("dispatch.global_note", 1),  # a batch's GLOBAL lanes, owned here (under the plan
+                              # lock; entered only where the batch holds one).  A LANE:
+                              # its key taken from the packed keys and stored in a dict
+                              # (the last lane of a key wins).  A DISTINCT KEY: its gslot
+                              # looked up or assigned, its configuration, the owner row
+                              # dirty.  The mesh tally's globalLanes over globalKeys says
+                              # how many lanes a key's work was paid for
     ("dispatch.stage", 0),    # wire encode + H2D upload start (stage 2)
     ("dispatch.upload", 1),   # the stage's transfer call alone: one device_put
                               # of one buffer, on either wire
@@ -447,6 +451,13 @@ class MeshTally:
     before), `syncRows` the rows their launches carried (the program's
     width a launch: what a pass pays for).
 
+    And what the owner's book-keeping of GLOBAL lanes was handed
+    (MeshBucketStore._note_global_owners, once a dispatch that holds
+    such a lane): `globalLanes` sums the GLOBAL lanes, each of which
+    costs a key read and a dict store, `globalKeys` the DISTINCT keys
+    among a dispatch's, each of which costs a gslot lookup or
+    assignment and a configuration row.
+
     And how the takes ran through the pipeline: `launches` counts the
     programs launched for columnar dispatches (ColumnarPipeline.
     _launch_group; a fused group of 2 or 4 dispatches is one) and
@@ -468,6 +479,7 @@ class MeshTally:
              "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
              "calendarLanes", "wideDispatches", "flaggedLanes",
              "syncPasses", "syncRows", "syncTouched",
+             "globalLanes", "globalKeys",
              "launches", "fusedDispatches", "takes", "takeFrames",
              "inFlightSum"), 0
         )
@@ -499,6 +511,12 @@ class MeshTally:
             s["syncPasses"] += 1
             s["syncRows"] += rows
             s["syncTouched"] += touched
+
+    def add_global_note(self, lanes: int, keys: int) -> None:
+        with self._lock:
+            s = self._sums
+            s["globalLanes"] += lanes
+            s["globalKeys"] += keys
 
     def add_launch(self, dispatches: int) -> None:
         with self._lock:

@@ -432,9 +432,9 @@ def test_a_take_that_fails_gives_its_slot_back(pop):
 def test_the_cells_files_say_what_the_issue_says():
     bench = harness.load_json(REPO, "BENCHMARK.json")
     cell, config, traffic = harness.find_cell(bench, CELL)
-    assert cell == bench["workloads"][-1] and cell["chips"] == 1
+    assert cell == bench["workloads"][7] and cell["chips"] == 1  # appended by PR 45, one cell since
     assert (cell["config"], cell["traffic"]) == ("v5e1-1m-gw4", "frames-x4")
-    entry = bench["configs"][-1]
+    entry = bench["configs"][6]
     assert entry["name"] == "v5e1-1m-gw4" and entry["reduced"] == [] == config["reduced"]
     assert entry["source"] == config["source"] and len(entry["source"]) <= 200
     twin = _cell_json("configs", "v5e1-1m.json")

@@ -206,7 +206,7 @@ def test_debug_device_serves_the_mesh_block(served):
         "shards", "dispatches", "lanes", "paddedLanes", "fullestShardLanes", "rounds",
         "laneWireDispatches", "laneWireLanes", "configRows", "uploads",
         "calendarLanes", "wideDispatches", "flaggedLanes",
-        "syncPasses", "syncRows", "syncTouched",
+        "syncPasses", "syncRows", "syncTouched", "globalLanes", "globalKeys",
         "launches", "fusedDispatches", "takes", "takeFrames", "inFlightSum"}
     assert mesh["dispatches"] >= len(TAKE_FRAMES) and mesh["paddedLanes"] >= mesh["lanes"] > 0
 

@@ -662,7 +662,7 @@ def _read_share(before: list, after: list):
 def test_the_call_share_metric_loads_under_the_harness_and_is_the_singles_cells():
     harness, spec = _metric_spec()
     bench = harness.load_json(harness.REPO, "BENCHMARK.json")
-    entry = bench["per_layer"][-1]  # appended, nothing before it moved
+    entry = bench["per_layer"][45]  # appended by PR 47, nothing before it moved (PR 48's six follow)
     assert entry["name"] == spec["name"] == METRIC
     assert entry["workloads"] == ["v5e1-1m.singles"]
     for key in ("unit", "better", "source", "layer", "moves"):
